@@ -85,7 +85,7 @@ def compile_events():
     guarded-serve incident) and ones invisible to backend_compile
     alone (the same fresh wrapper under JAX_COMPILATION_CACHE_DIR
     hits the disk cache instead of the compiler)."""
-    from jax._src import monitoring
+    from jax import monitoring
 
     rec = CompileEvents()
 
@@ -101,18 +101,8 @@ def compile_events():
     try:
         yield rec
     finally:
-        for unregister, cb in (
-            (monitoring._unregister_event_duration_listener_by_callback,
-             _listen),
-            (monitoring._unregister_event_listener_by_callback,
-             _listen_plain),
-        ):
-            try:
-                unregister(cb)
-            except Exception:
-                # last-resort: a leaked listener only over-counts
-                # future blocks; never take the audited run down
-                pass
+        monitoring.unregister_event_duration_listener(_listen)
+        monitoring.unregister_event_listener(_listen_plain)
 
 
 # ---------------------------------------------------------------------------

@@ -40,6 +40,7 @@ from __future__ import annotations
 import argparse
 
 from libgrape_lite_tpu.runner import QueryArgs, run_app
+from libgrape_lite_tpu.utils.compile_cache import place_compile_cache
 
 
 def make_parser() -> argparse.ArgumentParser:
@@ -526,6 +527,7 @@ def serve_main(argv=None):
 
     ns = make_serve_parser().parse_args(argv)
     _apply_platform(ns.platform, ns.cpu_devices)
+    place_compile_cache()
     if ns.trace or ns.metrics:
         from libgrape_lite_tpu import obs
 
@@ -1140,8 +1142,9 @@ def _serve_summary(ns, sess, pump, reqs, results, wall, delta_ops,
                     f"{i} {req.app_key} {ok_flag} {rounds} {digest}\n"
                 )
     print(json.dumps(record), flush=True)
-    if results and not ok:
-        print("[serve] every query failed", file=sys.stderr)
+    if record["failed"]:
+        print(f"[serve] {record['failed']} of {len(results)} queries "
+              "failed", file=sys.stderr)
         sys.exit(1)
 
     from libgrape_lite_tpu import obs
@@ -1275,6 +1278,7 @@ def main(argv=None):
         return calibrate_main(argv[1:])
     ns = make_parser().parse_args(argv)
     _apply_platform(ns.platform, ns.cpu_devices)
+    place_compile_cache()
     args = QueryArgs(
         **{k: v for k, v in vars(ns).items()
            if k not in ("platform", "cpu_devices")}

@@ -1,32 +1,14 @@
-"""JAX version compatibility shims.
-
-The codebase targets the current `jax.shard_map` API (public since
-jax 0.6, `check_vma=` keyword); older runtimes only ship the
-experimental entry point (`jax.experimental.shard_map.shard_map`,
-`check_rep=` keyword).  Both trace identically for the SPMD programs
-used here — `check_vma`/`check_rep` gate the same replication-rule
-checker, which every call site disables anyway (collectives like
-`all_to_all` have no rule on the older versions).
-"""
+"""The one spelling of `shard_map` the SPMD call sites share: the
+public `jax.shard_map` with its `check_vma=` keyword (every call site
+disables the checker — collectives like `all_to_all` have no rule)."""
 
 from __future__ import annotations
 
 import jax
 
-_HAS_PUBLIC_SHARD_MAP = hasattr(jax, "shard_map")
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """`jax.shard_map` with the modern keyword surface on any
-    supported JAX version."""
-    if _HAS_PUBLIC_SHARD_MAP:
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
+        check_vma=check_vma,
     )

@@ -1,8 +1,9 @@
 """ctypes binding to the native C++ loader (native/loader.cc).
 
 The shared library is built lazily with `make -C native` on first use;
-all callers fall back to the Python/pandas parser when the toolchain or
-build is unavailable (`read_edge_file` handles the dispatch).
+where the toolchain, the build or the dlopen fails, a RuntimeWarning
+carries the reason and callers take the Python/pandas parser
+(`read_edge_file` handles the dispatch).
 """
 
 from __future__ import annotations
@@ -11,6 +12,7 @@ import ctypes
 import os
 import subprocess
 import threading
+import warnings
 
 import numpy as np
 
@@ -44,24 +46,32 @@ def _load() -> ctypes.CDLL | None:
             try:
                 subprocess.run(
                     ["make", "-C", _NATIVE_DIR],
-                    check=True,
-                    capture_output=True,
+                    check=True, capture_output=True, text=True,
                     timeout=120,
                 )
-            except Exception:
+            except (OSError, subprocess.SubprocessError) as e:
+                why = f"({e})\n{getattr(e, 'stderr', None) or ''}"
                 if not os.path.exists(_SO_PATH):
-                    return None  # no prebuilt fallback at all
-                import warnings
-
+                    warnings.warn(
+                        "native library build failed; file loads take "
+                        f"the slower Python parsers {why}",
+                        RuntimeWarning,
+                    )
+                    return None
                 warnings.warn(
                     "native library rebuild failed; loading the stale "
                     f"{_SO_PATH} — newer symbol groups (and their "
-                    "speedups) may be unavailable",
+                    f"speedups) may be unavailable {why}",
                     RuntimeWarning,
                 )
         try:
             lib = ctypes.CDLL(_SO_PATH)
-        except OSError:
+        except OSError as e:
+            warnings.warn(
+                f"native library {_SO_PATH} did not load ({e}); file "
+                "loads take the slower Python parsers",
+                RuntimeWarning,
+            )
             return None
         lib.gl_parse.restype = ctypes.c_void_p
         lib.gl_parse.argtypes = [

@@ -139,8 +139,10 @@ class PageRank(BatchShuffleAppBase):
         #   pack   — the pack-gather Pallas pipeline (ops/spmv_pack.py),
         #            f32 + single-shard; the round-2 perf design
         #   strict — the strict-tile kernel (ops/spmv.py)
-        #   auto   — XLA segment_sum until a hardware A/B flips the
-        #            default (docs/PERF_NOTES.md tracks measurements)
+        #   auto   — XLA segment_sum, EXCEPT on a TPU backend with f32
+        #            state where `strict_worthwhile` holds on the worst
+        #            tile span: there `plan_for_app` engages the strict
+        #            kernel (ops/spmv.py); no chip A/B has judged it yet
         import os
 
         self._spmv_mode = os.environ.get("GRAPE_SPMV", "auto")

@@ -12,7 +12,7 @@ replaces the scatter with MXU work:
   * each tile's row span [row_lo, row_lo + rmax) is known on the host
     (`plan_tiles`); `rmax` is the worst span over tiles;
   * a Pallas program per tile builds the one-hot indicator
-    `[tile, rmax]` (edge e hits local row src[e]-row_lo) and contracts
+    `[rmax, tile]` (edge e hits local row src[e]-row_lo) and contracts
     it with the per-edge values on the MXU — per-tile partial row sums,
     no scatter;
   * a single XLA scatter-add of `[num_tiles, rmax]` partials (≪ E
@@ -84,18 +84,24 @@ def plan_tiles(edge_src_sorted: np.ndarray, tile: int, vp: int):
     return row_lo, rmax, num_tiles
 
 
-def _spmv_tile_kernel(row_lo_ref, src_ref, val_ref, out_ref, *, rmax):
-    t = pl.program_id(0)
-    row_lo = row_lo_ref[t]
-    src = src_ref[0]  # [1, tile] int32 (block [1, 1, tile])
+def _spmv_tile_kernel(local_ref, val_ref, out_ref, *, rmax):
+    local = local_ref[0]  # [1, tile] int32: edge row - the tile's row_lo
     val = val_ref[0].astype(jnp.float32)  # [1, tile]
-    tile = src.shape[-1]
-    # local row of each edge, one-hot against the tile's row window
-    local = (src - row_lo).reshape(tile, 1)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (tile, rmax), 1)
-    onehot = (local == rows).astype(jnp.float32)
-    # [1, tile] @ [tile, rmax] on the MXU -> per-row partial sums
-    out_ref[0] = jnp.dot(val, onehot, preferred_element_type=jnp.float32)
+    tile = local.shape[-1]
+    # one-hot of each edge against the tile's row window, rows down the
+    # sublanes: the [1, tile] edge row broadcasts along them as it lies
+    rows = jax.lax.broadcasted_iota(jnp.int32, (rmax, tile), 0)
+    onehot_t = (rows == local).astype(jnp.float32)
+    # [1, tile] x [rmax, tile]^T on the MXU -> per-row partial sums.
+    # HIGHEST: the MXU's default single bf16 pass rounds `val` to 8
+    # mantissa bits (2e-3 relative on row sums of uniform values on a
+    # v5e, against 1e-7 here: chip run, PR 22); the one-hot side is
+    # exact in any precision.
+    out_ref[0] = jax.lax.dot_general(
+        val, onehot_t, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
 
 
 @functools.partial(
@@ -111,15 +117,21 @@ def _spmv_partials(values, edge_src, row_lo, tile, rmax, num_tiles, vp,
         edge_src = jnp.concatenate(
             [edge_src, jnp.full((pad,), vp, edge_src.dtype)]
         )
+    # each edge's row relative to its tile's window, taken here in XLA:
+    # in the kernel `row_lo_ref[program_id]` is a dynamic scalar read of
+    # a VMEM vector, which the chip's compiler refuses ("cannot
+    # statically prove that index in dimension 0 is a multiple of 256")
+    local = (
+        edge_src.astype(jnp.int32).reshape(num_tiles, tile)
+        - row_lo.astype(jnp.int32)[:, None]
+    )
     # Mosaic requires the last two block dims to be (8,128)-divisible
     # or equal to the array dims — a singleton middle dim satisfies
-    # that for per-tile [1, tile] blocks (r1 shipped (1, tile) 2-D
-    # blocks, which never compiled on hardware; tests/
-    # test_pallas_lowering.py now guards this offline)
+    # that for per-tile [1, tile] blocks (tests/test_pallas_lowering.py
+    # guards the lowering offline, chip_smoke.py the chip's compile)
     grid_spec = pl.GridSpec(
         grid=(num_tiles,),
         in_specs=[
-            pl.BlockSpec((num_tiles,), lambda i: (0,)),
             pl.BlockSpec((1, 1, tile), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, 1, tile), lambda i: (i, 0, 0)),
         ],
@@ -131,8 +143,7 @@ def _spmv_partials(values, edge_src, row_lo, tile, rmax, num_tiles, vp,
         out_shape=jax.ShapeDtypeStruct((num_tiles, 1, rmax), jnp.float32),
         interpret=interpret,
     )(
-        row_lo,
-        edge_src.astype(jnp.int32).reshape(num_tiles, 1, tile),
+        local.reshape(num_tiles, 1, tile),
         values.reshape(num_tiles, 1, tile),
     )
     return out.reshape(num_tiles, rmax)

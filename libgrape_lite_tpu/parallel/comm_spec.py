@@ -40,13 +40,14 @@ def put_global(x, sharding: NamedSharding):
     assemble the global array from this process's full host copy via
     `make_array_from_callback` — every process loads identical arrays
     (deterministic loader), the multi-host form of the reference's
-    per-rank loading contract.  Single-process: plain device_put."""
+    per-rank loading contract.  Single-process: plain device_put of
+    the HOST array, so each device receives only its own shard (a
+    `jnp.asarray` first lands the whole stacked array on device 0 and
+    reshards from there)."""
     if x is None:
         return None
     if sharding.is_fully_addressable:
-        import jax.numpy as jnp
-
-        return jax.device_put(jnp.asarray(x), sharding)
+        return jax.device_put(x, sharding)
     arr = np.asarray(x)
     return jax.make_array_from_callback(
         arr.shape, sharding, lambda idx: arr[idx]

@@ -44,10 +44,10 @@ def get_memory_stats(device=None) -> MemoryStats:
     in_use = peak = 0
     devs = [device] if device is not None else jax.local_devices()
     for d in devs:
-        try:
-            ms = d.memory_stats()
-        except Exception:  # CPU backend has no allocator stats
-            ms = None
+        # None on a backend without allocator stats (the CPU); a call
+        # that fails on an accelerator raises — zeros there would read
+        # as "nothing resident"
+        ms = d.memory_stats()
         if ms:
             in_use += ms.get("bytes_in_use", 0)
             peak += ms.get("peak_bytes_in_use", 0)

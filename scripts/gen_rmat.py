@@ -76,20 +76,9 @@ def main(argv=None) -> int:
     print(f"[gen_rmat] generated {len(src):,} edges over {n:,} vertices "
           f"in {time.perf_counter() - t0:.1f}s", flush=True)
 
-    import pandas as pd
-
-    rng = np.random.default_rng(args.seed + 1)
     t0 = time.perf_counter()
-    chunk = 1 << 24
-    with open(args.out, "w") as f:
-        for lo in range(0, len(src), chunk):
-            hi = min(lo + chunk, len(src))
-            cols = {"s": src[lo:hi], "d": dst[lo:hi]}
-            if args.weighted:
-                cols["w"] = rng.integers(1, 11, hi - lo)
-            pd.DataFrame(cols).to_csv(
-                f, sep=" ", header=False, index=False
-            )
+    w = edge_weights(len(src), args.seed) if args.weighted else None
+    write_edge_file(args.out, src, dst, w)
     print(f"[gen_rmat] wrote {args.out} "
           f"({os.path.getsize(args.out) / (1 << 30):.2f} GiB) in "
           f"{time.perf_counter() - t0:.1f}s", flush=True)
@@ -116,6 +105,37 @@ def main(argv=None) -> int:
               f"{args.delta_out} in {time.perf_counter() - t0:.1f}s",
               flush=True)
     return 0
+
+
+_WRITE_CHUNK = 1 << 24
+
+
+def edge_weights(n_edges: int, seed: int) -> np.ndarray:
+    """The file's weight column: integers 1..10 from `seed + 1`, drawn
+    in write-chunk order — the stream `--weighted` has always written,
+    so the file and an in-memory twin (chip_smoke.py's plain
+    references) agree edge for edge."""
+    rng = np.random.default_rng(seed + 1)
+    return np.concatenate(
+        [rng.integers(1, 11, min(_WRITE_CHUNK, n_edges - lo))
+         for lo in range(0, n_edges, _WRITE_CHUNK)]
+        or [np.zeros(0, dtype=np.int64)]
+    )
+
+
+def write_edge_file(path: str, src, dst, w=None) -> None:
+    """`src dst [w]` lines, written in bounded chunks."""
+    import pandas as pd
+
+    with open(path, "w") as f:
+        for lo in range(0, len(src), _WRITE_CHUNK):
+            hi = min(lo + _WRITE_CHUNK, len(src))
+            cols = {"s": src[lo:hi], "d": dst[lo:hi]}
+            if w is not None:
+                cols["w"] = w[lo:hi]
+            pd.DataFrame(cols).to_csv(
+                f, sep=" ", header=False, index=False
+            )
 
 
 def shuffle_perm(n: int, seed: int = 53) -> np.ndarray:

@@ -63,6 +63,10 @@ def main(argv=None):
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
 
+    from libgrape_lite_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
+
     import numpy as np
 
     from libgrape_lite_tpu.fragment.loader import LoadGraph, LoadGraphSpec

@@ -25,6 +25,12 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_enable_x64", True)
+# the entry points place a persistent compile cache in the checkout
+# (utils/compile_cache.py) and tests call them in-process and in
+# children: this lane compiles everything itself, as it always has, so
+# its pinned compile counts never depend on an earlier run's disk
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+jax.config.update("jax_enable_compilation_cache", False)
 
 assert len(jax.devices()) == 8, (
     "tests need the 8-device virtual CPU mesh; jax backend was initialised "

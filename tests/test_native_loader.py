@@ -121,3 +121,47 @@ def test_varint_native_matches_numpy_and_detects_corruption():
     if bad[-1] & 0x80:
         with pytest.raises(ValueError, match="corrupt varint"):
             varint_decode_native(bad, False)
+
+
+def test_failed_native_build_warns_before_the_python_parser(monkeypatch, tmp_path):
+    """No library and a failing `make`: the loader says why (with the
+    compiler's output) before callers take the Python parser — it used
+    to return None without a word."""
+    import subprocess
+
+    from libgrape_lite_tpu.io import native
+
+    def failing_make(cmd, **kw):
+        raise subprocess.CalledProcessError(
+            2, cmd, stderr="loader.cc:1: error: no such toolchain")
+
+    monkeypatch.setattr(native, "_SO_PATH", str(tmp_path / "absent.so"))
+    monkeypatch.setattr(native, "_tried", False)
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native.subprocess, "run", failing_make)
+    with pytest.warns(RuntimeWarning, match="no such toolchain"):
+        assert native.available() is False
+    # a library that exists but does not dlopen warns too
+    (tmp_path / "bad.so").write_bytes(b"not an ELF")
+    monkeypatch.setattr(native, "_SO_PATH", str(tmp_path / "bad.so"))
+    monkeypatch.setattr(native, "_tried", False)
+    with pytest.warns(RuntimeWarning, match="did not load"):
+        assert native.available() is False
+
+
+def test_memory_stats_failure_is_not_zeros():
+    """`None` (the CPU backend) reads as no allocator stats; a call
+    that FAILS raises instead of reporting an empty device."""
+    from libgrape_lite_tpu.utils.memory import get_memory_stats
+
+    class NoStats:
+        def memory_stats(self):
+            return None
+
+    class Broken:
+        def memory_stats(self):
+            raise RuntimeError("allocator unreachable")
+
+    assert get_memory_stats(NoStats()).device_bytes_in_use == 0
+    with pytest.raises(RuntimeError, match="allocator unreachable"):
+        get_memory_stats(Broken())

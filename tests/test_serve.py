@@ -427,6 +427,48 @@ def test_cli_serve_scripted_stream(capsys):
     assert rec["cache"]["runner"]["misses"] >= 1
 
 
+def test_cli_serve_exits_nonzero_when_any_query_failed(capsys):
+    """One breached lane of three: the record still prints, and the
+    exit code and stderr say that a query failed (it used to take EVERY
+    query failing)."""
+    import jax
+
+    from libgrape_lite_tpu.cli import serve_main
+    from libgrape_lite_tpu.serve import batch as serve_batch
+
+    orig = serve_batch.run_guarded_batch
+
+    def poisoned(worker, args_list, mr, cfg, **kw):
+        def hook(carry, rounds):
+            if rounds != 2:
+                return None
+            dist = np.array(jax.device_get(carry["dist"]))
+            dist[0, 0, :4] = -5.0  # negative distance: in_range breach
+            return {"dist": dist}
+
+        return orig(worker, args_list, mr, cfg, chunk_hook=hook)
+
+    serve_batch.run_guarded_batch = poisoned
+    try:
+        with pytest.raises(SystemExit) as exc:
+            serve_main([
+                "--efile", dataset_path("p2p-31.e"),
+                "--vfile", dataset_path("p2p-31.v"),
+                "--fnum", "2", "--application", "sssp",
+                "--sources", "6,17,3", "--max_batch", "4",
+                "--guard", "halt",
+            ])
+    finally:
+        serve_batch.run_guarded_batch = orig
+    assert exc.value.code == 1
+    cap = capsys.readouterr()
+    rec = json.loads(
+        [l for l in cap.out.splitlines() if l.startswith("{")][-1]
+    )
+    assert rec["queries"] == 3 and rec["failed"] == 1
+    assert "1 of 3 queries failed" in cap.err
+
+
 # ---- review-pass hardening (each with the failure it pins) ---------------
 
 

@@ -179,6 +179,17 @@ def test_put_global_matches_device_put():
     assert a.dtype == b.dtype and a.shape == b.shape
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     assert a.sharding.is_equivalent_to(b.sharding, a.ndim)
+    # the HOST array goes straight to its shards — one row per device —
+    # and dtypes canonicalise as jnp.asarray's detour did, under x64
+    # (this lane) and under x32 (a chip run: int64 oids land as int32)
+    assert len({s.device for s in a.addressable_shards}) == 4
+    assert all(s.data.shape == (1, 8) for s in a.addressable_shards)
+    for dt in (np.int64, np.float64, np.float32, np.int32, np.bool_, np.uint8):
+        y = x.astype(dt)
+        assert put_global(y, sh).dtype == jnp.asarray(y).dtype
+        with jax.enable_x64(False):
+            assert put_global(y, sh).dtype == jnp.asarray(y).dtype
+            assert put_global(y, sh).dtype.itemsize <= 4
 
     # the multi-process branch, forced on the same mesh: idx slicing
     # and values must match device_put exactly
