@@ -34,13 +34,17 @@ from __future__ import annotations
 
 from typing import Dict, FrozenSet
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
 from libgrape_lite_tpu.fragment.edgecut import DeviceFragment
 from libgrape_lite_tpu.parallel.comm_spec import FRAG_AXIS
-from libgrape_lite_tpu.parallel.communicator import Communicator
+from libgrape_lite_tpu.parallel.communicator import (
+    Communicator,
+    collective_scope,
+)
 from libgrape_lite_tpu.utils.types import LoadStrategy, MessageStrategy
 
 
@@ -54,7 +58,8 @@ class StepContext(Communicator):
         `SyncInnerVertices` + `UpdateOuterVertices`
         (`batch_shuffle_message_manager.h:237,264`): one `all_gather`
         over ICI replaces per-neighbor mirror buffers."""
-        return lax.all_gather(x_local, FRAG_AXIS, tiled=True)
+        with collective_scope():
+            return lax.all_gather(x_local, FRAG_AXIS, tiled=True)
 
     @staticmethod
     def fid():
@@ -71,11 +76,14 @@ class StepContext(Communicator):
         `parallel/mirror.MirrorPlan`).  Returns the compact
         [vp + fnum*m] table addressed by the plan's `nbr_compact`
         columns — O(vp + mirrors) instead of O(fnum*vp)."""
-        vals = x_local[send_idx]
-        recv = lax.all_to_all(
-            vals, FRAG_AXIS, split_axis=0, concat_axis=0, tiled=True
-        )
-        return jnp.concatenate([x_local, recv.reshape(-1)])
+        with jax.named_scope("grape.exchange.pack"):
+            vals = x_local[send_idx]
+        with collective_scope():
+            recv = lax.all_to_all(
+                vals, FRAG_AXIS, split_axis=0, concat_axis=0, tiled=True
+            )
+        with jax.named_scope("grape.exchange.unpack"):
+            return jnp.concatenate([x_local, recv.reshape(-1)])
 
 
 def source_lane_array(frag, source, app_name: str, fill, hit, dtype):

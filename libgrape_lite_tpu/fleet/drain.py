@@ -143,13 +143,6 @@ def rejoin_lost(router, checkpoint_dir: str, *, session_factory):
         )
     sess = session_factory(replicate_fragment(live[0].session.fragment))
     r = router.add_replica(sess)
-    tr = obs.tracer()
-    if tr.enabled:
-        tr.instant(
-            "fleet_rejoin_lost", replica=r.idx,
-            ckpt_rounds=int(meta["rounds"]),
-            ckpt_ranks=int(meta.get("ranks", 0)),
-        )
     FLEET_STATS.record(
         "rejoin", replica=r.idx, lost_process=True,
         ckpt_rounds=int(meta["rounds"]),

@@ -704,7 +704,6 @@ def restore_resharded(
         sp.set(round=int(meta.get("rounds", -1)))
     m = obs.metrics()
     m.counter("grape_checkpoint_restores_total").inc()
-    m.counter("grape_checkpoint_reshards_total").inc()
     m.histogram("grape_checkpoint_restore_seconds").observe(
         time.perf_counter() - t0
     )

@@ -142,7 +142,6 @@ class GuardMonitor:
         # (checkpointing MutationContext apps is rejected up front),
         # but cheap insurance against that restriction loosening.
         self.ckpt = None
-        obs.tracer().instant("guard_mutation_reset")
         glog.vlog(
             1, "guard: mutation boundary — watchdog history reset, "
             "probe re-resolves against the mutated fragment",

@@ -11,10 +11,12 @@ vertices keep the int sentinel and print as the reference's
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from libgrape_lite_tpu.app.base import ParallelAppBase, StepContext
+from libgrape_lite_tpu.ops.segment import pull_gather
 from libgrape_lite_tpu.utils.types import LoadStrategy, MessageStrategy
 
 _SENTINEL = np.iinfo(np.int32).max
@@ -132,28 +134,25 @@ class BFS(ParallelAppBase):
                 jnp.isfinite(red), red.astype(jnp.int32), sent
             )
         else:
-            nbr_d = full[nbr]
-            cand = jnp.where(
-                jnp.logical_and(ie.edge_mask, nbr_d != sent),
-                nbr_d + 1, sent,
-            )
+            cand = pull_gather(full, nbr, ie.edge_mask, sent, add=1,
+                               absent=sent)
             relaxed = self.segment_reduce(cand, ie.edge_src, frag.vp,
                                           "min")
         if "dyn_ie_nbr" in state:
             # staged delta edges (dyn/): extra unit-weight candidates
             # merged at the fold; `full` is pid-addressed in overlay
             # mode (init_state disables mirror compaction)
-            dv = full[state["dyn_ie_nbr"]]
-            dcand = jnp.where(
-                jnp.logical_and(state["dyn_ie_mask"], dv != sent),
-                dv + 1, sent,
+            dcand = pull_gather(
+                full, state["dyn_ie_nbr"], state["dyn_ie_mask"], sent,
+                add=1, absent=sent,
             )
             relaxed = self.dyn_min_fold(
                 relaxed, state, frag.vp, "dyn_ie_", dcand
             )
-        new = jnp.minimum(depth, relaxed)
-        changed = jnp.logical_and(new < depth, frag.inner_mask)
-        active = ctx.sum(changed.sum().astype(jnp.int32))
+        with jax.named_scope("grape.app.update"):
+            new = jnp.minimum(depth, relaxed)
+            changed = jnp.logical_and(new < depth, frag.inner_mask)
+            active = ctx.sum(changed.sum().astype(jnp.int32))
         return {"depth": new}, active
 
     def inceval_pipelined(self, ctx: StepContext, frag, state, xbuf):
@@ -180,10 +179,9 @@ class BFS(ParallelAppBase):
         if pl.pack_b is not None:
             rel_b = pack_relax(pl.pack_b)
         else:
-            nb = full[state["pl_b_nbr"]]
-            cand_b = jnp.where(
-                jnp.logical_and(state["pl_b_val"], nb != sent),
-                nb + 1, sent,
+            cand_b = pull_gather(
+                full, state["pl_b_nbr"], state["pl_b_val"], sent,
+                add=1, absent=sent,
             )
             rel_b = self.segment_reduce(
                 cand_b, state["pl_b_src"], frag.vp, "min"
@@ -195,18 +193,18 @@ class BFS(ParallelAppBase):
         if pl.pack_i is not None:
             rel_i = pack_relax(pl.pack_i)
         else:
-            ni = full[state["pl_i_nbr"]]
-            cand_i = jnp.where(
-                jnp.logical_and(state["pl_i_val"], ni != sent),
-                ni + 1, sent,
+            cand_i = pull_gather(
+                full, state["pl_i_nbr"], state["pl_i_val"], sent,
+                add=1, absent=sent,
             )
             rel_i = self.segment_reduce(
                 cand_i, state["pl_i_src"], frag.vp, "min"
             )
-        new_i = jnp.minimum(depth, rel_i)
-        new = jnp.where(bmask, new_b, new_i)
-        changed = jnp.logical_and(new < depth, frag.inner_mask)
-        active = ctx.sum(changed.sum().astype(jnp.int32))
+        with jax.named_scope("grape.app.update"):
+            new_i = jnp.minimum(depth, rel_i)
+            new = jnp.where(bmask, new_b, new_i)
+            changed = jnp.logical_and(new < depth, frag.inner_mask)
+            active = ctx.sum(changed.sum().astype(jnp.int32))
         return {"depth": new}, active, xbuf2
 
     def invariants(self, frag, state):

@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -146,7 +147,8 @@ class LCC(ParallelAppBase):
         if getattr(self, "lcc_backend", "intersect") == "spgemm":
             tri = self._tri_spgemm(ctx, frag, state)
         else:
-            tri = self._tri_intersect(ctx, frag, state)
+            with jax.named_scope("grape.lcc.intersect"):
+                tri = self._tri_intersect(ctx, frag, state)
         return self._emit(ctx, frag, state, tri)
 
     def _tri_spgemm(self, ctx: StepContext, frag, state):

@@ -20,10 +20,12 @@ sparse-dense SpMV the XLA scheduler pipelines with the collective.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from libgrape_lite_tpu.app.base import BatchShuffleAppBase, StepContext
+from libgrape_lite_tpu.ops.segment import pull_gather
 from libgrape_lite_tpu.utils.types import LoadStrategy, MessageStrategy
 
 
@@ -277,6 +279,10 @@ class PageRank(BatchShuffleAppBase):
         shared by the pull path (inceval) and the push/SyncBuffer path
         (PageRankAuto): base/dangling bookkeeping, degree division, and
         the final-round rank*deg re-multiplication (pagerank.h:102-156)."""
+        with jax.named_scope("grape.app.update"):
+            return self._round_update(frag, state, cur)
+
+    def _round_update(self, frag, state, cur):
         n = frag.total_vnum
         d = self.delta
         dt = state["rank"].dtype
@@ -337,7 +343,7 @@ class PageRank(BatchShuffleAppBase):
             # plan time, so no mask multiply is needed)
             cur = self._pack.reduce(full, state, "sum").astype(dt)
             return self.round_update(frag, state, cur)
-        contrib = jnp.where(ie.edge_mask, full[nbr], jnp.asarray(0, dt))
+        contrib = pull_gather(full, nbr, ie.edge_mask, jnp.asarray(0, dt))
         from libgrape_lite_tpu.ops.spmv import segment_sum_auto
 
         plan = (
@@ -365,7 +371,7 @@ class PageRank(BatchShuffleAppBase):
         full = pl.splice(ctx, rank, state, xbuf)
         bmask = state["pl_bmask"]
         cur_b = self.segment_reduce(
-            jnp.where(state["pl_b_val"], full[state["pl_b_nbr"]], zero),
+            pull_gather(full, state["pl_b_nbr"], state["pl_b_val"], zero),
             state["pl_b_src"], frag.vp, "sum",
         ).astype(dt)
         st_b, _ = self.round_update(frag, state, cur_b)
@@ -375,7 +381,7 @@ class PageRank(BatchShuffleAppBase):
         # ---- pipelined window: carry reads below are named in
         # parallel/pipeline.PIPELINE_WINDOW_READS (grape-lint R6) ----
         cur_i = self.segment_reduce(
-            jnp.where(state["pl_i_val"], full[state["pl_i_nbr"]], zero),
+            pull_gather(full, state["pl_i_nbr"], state["pl_i_val"], zero),
             state["pl_i_src"], frag.vp, "sum",
         ).astype(dt)
         cur = jnp.where(bmask, cur_b, cur_i)

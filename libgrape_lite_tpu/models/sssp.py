@@ -16,10 +16,12 @@ MPI_Allreduce, `parallel_message_manager.h:123-138`).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from libgrape_lite_tpu.app.base import ParallelAppBase, StepContext
+from libgrape_lite_tpu.ops.segment import pull_gather
 from libgrape_lite_tpu.utils.types import LoadStrategy, MessageStrategy
 
 
@@ -176,13 +178,11 @@ class SSSP(ParallelAppBase):
             # one gather pass: the pre-masked weight stream (wf_eff,
             # inf at masked edges) folds the relax-mask select into the
             # add — bit-identical to the where() form
-            cand = full[nbr] + state["wf_eff"]
+            cand = pull_gather(full, nbr, add=state["wf_eff"])
             relaxed = self.segment_reduce(cand, ie.edge_src, frag.vp, "min")
         else:
             inf = jnp.asarray(jnp.inf, dist.dtype)
-            cand = jnp.where(
-                ie.edge_mask, full[nbr] + ie.edge_w, inf
-            )
+            cand = pull_gather(full, nbr, ie.edge_mask, inf, add=ie.edge_w)
             relaxed = self.segment_reduce(cand, ie.edge_src, frag.vp, "min")
         if "dyn_ie_nbr" in state:
             # staged delta edges (dyn/): one extra gather + segment_min
@@ -190,16 +190,17 @@ class SSSP(ParallelAppBase):
             # is pid-addressed here (mirror compaction is off in
             # overlay mode, see init_state)
             inf = jnp.asarray(jnp.inf, dist.dtype)
-            dcand = jnp.where(
-                state["dyn_ie_mask"],
-                full[state["dyn_ie_nbr"]] + state["dyn_ie_w"], inf,
+            dcand = pull_gather(
+                full, state["dyn_ie_nbr"], state["dyn_ie_mask"], inf,
+                add=state["dyn_ie_w"],
             )
             relaxed = self.dyn_min_fold(
                 relaxed, state, frag.vp, "dyn_ie_", dcand
             )
-        new = jnp.minimum(dist, relaxed)
-        changed = jnp.logical_and(new < dist, frag.inner_mask)
-        active = ctx.sum(changed.sum().astype(jnp.int32))
+        with jax.named_scope("grape.app.update"):
+            new = jnp.minimum(dist, relaxed)
+            changed = jnp.logical_and(new < dist, frag.inner_mask)
+            active = ctx.sum(changed.sum().astype(jnp.int32))
         return {"dist": new}, active
 
     def inceval_pipelined(self, ctx: StepContext, frag, state, xbuf):
@@ -216,9 +217,9 @@ class SSSP(ParallelAppBase):
         if pl.pack_b is not None:
             rel_b = pl.pack_b.reduce(full, state, "min")
         else:
-            cand_b = jnp.where(
-                state["pl_b_val"],
-                full[state["pl_b_nbr"]] + state["pl_b_w"], inf,
+            cand_b = pull_gather(
+                full, state["pl_b_nbr"], state["pl_b_val"], inf,
+                add=state["pl_b_w"],
             )
             rel_b = self.segment_reduce(
                 cand_b, state["pl_b_src"], frag.vp, "min"
@@ -230,17 +231,18 @@ class SSSP(ParallelAppBase):
         if pl.pack_i is not None:
             rel_i = pl.pack_i.reduce(full, state, "min")
         else:
-            cand_i = jnp.where(
-                state["pl_i_val"],
-                full[state["pl_i_nbr"]] + state["pl_i_w"], inf,
+            cand_i = pull_gather(
+                full, state["pl_i_nbr"], state["pl_i_val"], inf,
+                add=state["pl_i_w"],
             )
             rel_i = self.segment_reduce(
                 cand_i, state["pl_i_src"], frag.vp, "min"
             )
-        new_i = jnp.minimum(dist, rel_i)
-        new = jnp.where(bmask, new_b, new_i)
-        changed = jnp.logical_and(new < dist, frag.inner_mask)
-        active = ctx.sum(changed.sum().astype(jnp.int32))
+        with jax.named_scope("grape.app.update"):
+            new_i = jnp.minimum(dist, rel_i)
+            new = jnp.where(bmask, new_b, new_i)
+            changed = jnp.logical_and(new < dist, frag.inner_mask)
+            active = ctx.sum(changed.sum().astype(jnp.int32))
         return {"dist": new}, active, xbuf2
 
     def invariants(self, frag, state):

@@ -14,10 +14,12 @@ partition-isomorphism, `misc/wcc_check.cc`).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from libgrape_lite_tpu.app.base import ParallelAppBase, StepContext
+from libgrape_lite_tpu.ops.segment import pull_gather
 from libgrape_lite_tpu.utils.types import LoadStrategy, MessageStrategy
 
 
@@ -156,15 +158,15 @@ class WCC(ParallelAppBase):
                 jnp.isfinite(red), red.astype(jnp.int32), big
             )
         else:
-            cand = jnp.where(csr.edge_mask, full[nbr], big)
+            cand = pull_gather(full, nbr, csr.edge_mask, big)
             red = self.segment_reduce(cand, csr.edge_src, frag.vp, "min")
         if dyn_prefix is not None and dyn_prefix + "nbr" in state:
             # staged delta edges (dyn/): extra label candidates merged
             # at the fold; `full` is pid-addressed in overlay mode
             # (init_state disables mirror compaction)
-            dcand = jnp.where(
-                state[dyn_prefix + "mask"],
-                full[state[dyn_prefix + "nbr"]], big,
+            dcand = pull_gather(
+                full, state[dyn_prefix + "nbr"],
+                state[dyn_prefix + "mask"], big,
             )
             red = self.dyn_min_fold(red, state, frag.vp, dyn_prefix,
                                     dcand)
@@ -188,9 +190,10 @@ class WCC(ParallelAppBase):
                 self._pull(ctx, frag, new, frag.oe, self._pack_oe, state,
                            self._mx_oe, "mx_oe_", dyn_prefix="dyn_oe_"),
             )
-        new = self._post_pull(ctx, frag, new)
-        changed = jnp.logical_and(new < comp, frag.inner_mask)
-        active = ctx.sum(changed.sum().astype(jnp.int32))
+        with jax.named_scope("grape.app.update"):
+            new = self._post_pull(ctx, frag, new)
+            changed = jnp.logical_and(new < comp, frag.inner_mask)
+            active = ctx.sum(changed.sum().astype(jnp.int32))
         return {"comp": new}, active
 
     def inceval_pipelined(self, ctx: StepContext, frag, state, xbuf):
@@ -217,8 +220,8 @@ class WCC(ParallelAppBase):
         if pl.pack_b is not None:
             rel_b = pack_fold(pl.pack_b)
         else:
-            cand_b = jnp.where(
-                state["pl_b_val"], full[state["pl_b_nbr"]], big
+            cand_b = pull_gather(
+                full, state["pl_b_nbr"], state["pl_b_val"], big
             )
             rel_b = self.segment_reduce(
                 cand_b, state["pl_b_src"], frag.vp, "min"
@@ -230,16 +233,17 @@ class WCC(ParallelAppBase):
         if pl.pack_i is not None:
             rel_i = pack_fold(pl.pack_i)
         else:
-            cand_i = jnp.where(
-                state["pl_i_val"], full[state["pl_i_nbr"]], big
+            cand_i = pull_gather(
+                full, state["pl_i_nbr"], state["pl_i_val"], big
             )
             rel_i = self.segment_reduce(
                 cand_i, state["pl_i_src"], frag.vp, "min"
             )
-        new_i = jnp.minimum(comp, rel_i)
-        new = jnp.where(bmask, new_b, new_i)
-        changed = jnp.logical_and(new < comp, frag.inner_mask)
-        active = ctx.sum(changed.sum().astype(jnp.int32))
+        with jax.named_scope("grape.app.update"):
+            new_i = jnp.minimum(comp, rel_i)
+            new = jnp.where(bmask, new_b, new_i)
+            changed = jnp.logical_and(new < comp, frag.inner_mask)
+            active = ctx.sum(changed.sum().astype(jnp.int32))
         return {"comp": new}, active, xbuf2
 
     def _inceval_pipelined_directed(self, ctx: StepContext, frag,
@@ -260,8 +264,8 @@ class WCC(ParallelAppBase):
         # leg 1 (ie): last round kicked this exchange; splice + fold
         # the boundary rows' edges first
         full1 = pl.splice(ctx, comp, state, xbuf)
-        cand = jnp.where(
-            state["pl_b_val"], full1[state["pl_b_nbr"]], big
+        cand = pull_gather(
+            full1, state["pl_b_nbr"], state["pl_b_val"], big
         )
         rel1_b = self.segment_reduce(
             cand, state["pl_b_src"], frag.vp, "min"
@@ -272,8 +276,8 @@ class WCC(ParallelAppBase):
         )
         # ---- pipelined window: carry reads below are named in
         # parallel/pipeline.PIPELINE_WINDOW_READS (grape-lint R6) ----
-        cand = jnp.where(
-            state["pl_i_val"], full1[state["pl_i_nbr"]], big
+        cand = pull_gather(
+            full1, state["pl_i_nbr"], state["pl_i_val"], big
         )
         rel1_i = self.segment_reduce(
             cand, state["pl_i_src"], frag.vp, "min"
@@ -282,23 +286,24 @@ class WCC(ParallelAppBase):
         # leg 2 (oe): remote rows of full2 come from x_oe, current at
         # every remotely-read row (all boundary); local rows are live
         full2 = pl.splice(ctx, new1, state, x_oe, leg=2)
-        cand = jnp.where(
-            state["pl2_b_val"], full2[state["pl2_b_nbr"]], big
+        cand = pull_gather(
+            full2, state["pl2_b_nbr"], state["pl2_b_val"], big
         )
         rel2_b = self.segment_reduce(
             cand, state["pl2_b_src"], frag.vp, "min"
         )
         new2_b = jnp.minimum(new1, rel2_b)
         xbuf2 = pl.kickoff(ctx, jnp.where(bmask, new2_b, new1), state)
-        cand = jnp.where(
-            state["pl2_i_val"], full2[state["pl2_i_nbr"]], big
+        cand = pull_gather(
+            full2, state["pl2_i_nbr"], state["pl2_i_val"], big
         )
         rel2_i = self.segment_reduce(
             cand, state["pl2_i_src"], frag.vp, "min"
         )
-        new = jnp.where(bmask, new2_b, jnp.minimum(new1, rel2_i))
-        changed = jnp.logical_and(new < comp, frag.inner_mask)
-        active = ctx.sum(changed.sum().astype(jnp.int32))
+        with jax.named_scope("grape.app.update"):
+            new = jnp.where(bmask, new2_b, jnp.minimum(new1, rel2_i))
+            changed = jnp.logical_and(new < comp, frag.inner_mask)
+            active = ctx.sum(changed.sum().astype(jnp.int32))
         return {"comp": new}, active, xbuf2
 
     def inc_value_map(self, key, values, old_frag, new_frag):

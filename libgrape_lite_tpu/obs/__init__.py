@@ -12,7 +12,10 @@ metrics dumps.  docs/OBSERVABILITY.md is the user guide;
 scripts/trace_report.py renders the per-superstep table.
 
 Off by default: `obs.tracer()` returns a disabled singleton whose
-`span()` is a sub-microsecond no-op (pinned by test), and arming is a
+`span()` is a sub-microsecond no-op (pinned by test; while a
+`jax.profiler` session records it is a `TraceAnnotation` named
+`grape.<name>`, so the span lands in the profiler's trace), and
+arming is a
 host-side decision invisible to jit tracing — the fused hot path's
 lowered HLO is byte-identical disarmed vs armed (pinned by test).
 
@@ -72,7 +75,7 @@ from libgrape_lite_tpu.obs.metrics import (
     MetricsRegistry,
 )
 from libgrape_lite_tpu.obs.recorder import RECORDER, FlightRecorder
-from libgrape_lite_tpu.obs.tracer import NULL_SPAN, Span, Tracer
+from libgrape_lite_tpu.obs.tracer import Span, Tracer
 
 __all__ = [
     "federation",
@@ -102,7 +105,6 @@ __all__ = [
     "write_chrome_trace",
     "MetricsRegistry",
     "NULL_METRICS",
-    "NULL_SPAN",
     "Span",
     "Tracer",
 ]

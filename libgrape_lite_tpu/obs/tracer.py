@@ -9,8 +9,9 @@ Two design constraints rule this file:
    and the *compiled* fused path must be byte-identical to an
    obs-less build (pinned by the lowered-HLO test) — the same
    discipline guard/ established for guards-off.  A disabled tracer
-   therefore returns one shared no-op span object from a two-branch
-   method; no allocation, no clock read, no buffering.
+   therefore returns one shared no-op span object, or under a
+   profiler session the profiler mirror alone (below); no clock read,
+   no buffering.
 
 2. **Armed cost stays off the device path.**  Spans buffer into a
    `collections.deque` — append is a single GIL-atomic bytecode, so
@@ -35,6 +36,18 @@ cache MISS, so the span carries `compiled_us` and downstream readers
 (the overlap truth meter, trace_report) can EXCLUDE compile rounds
 from overlap accounting rather than silently folding compile time
 into the measurement.
+
+One span system, two sinks, one clock: every span is also a
+`jax.profiler.TraceAnnotation` named `grape.<name>` with the span's
+keyword arguments as its stats, armed or not.  Under a `jax.profiler`
+session the interval lands in the `.xplane.pb` beside the device's
+operations (benchmarks/reduce_scopes.py reads it).  An annotation
+costs about 0.3 µs even with no session, which would more than double
+the disarmed span, so the disarmed tracer asks the profiler first
+(`TraceAnnotation.is_enabled()`, 20 ns) and makes none when nothing
+records; an annotation opened outside a session is dropped by the
+profiler anyway.  The JSONL sink keeps what no profiler window covers
+(set-up, operators' long runs).
 """
 
 from __future__ import annotations
@@ -46,6 +59,8 @@ import uuid
 from collections import deque
 from typing import Any, Dict, Optional
 
+from jax.profiler import TraceAnnotation
+
 from libgrape_lite_tpu.obs.events import (
     FRAG_TID_BASE,
     counter_event,
@@ -56,8 +71,12 @@ from libgrape_lite_tpu.obs.events import (
 )
 
 
+MIRROR_PREFIX = "grape."
+_profiling = TraceAnnotation.is_enabled  # is a profiler session recording?
+
+
 class _NullSpan:
-    """Shared no-op span: the entire disabled-tracer surface."""
+    """Shared no-op span: the disarmed surface while nothing records."""
 
     __slots__ = ()
 
@@ -77,7 +96,24 @@ class _NullSpan:
         pass
 
 
-NULL_SPAN = _NullSpan()
+_NULL_SPAN = _NullSpan()
+
+
+class _MirrorSpan(TraceAnnotation):
+    """The disarmed span under a profiler session: the mirror alone.
+    `set` reaches it too (an argument known only inside the span, as
+    `worker.runner`'s `miss`), `mark` and `close` do nothing."""
+
+    __slots__ = ()
+
+    def mark(self, label: str) -> None:
+        pass
+
+    def set(self, **args) -> None:
+        self.set_metadata(**args)
+
+    def close(self) -> None:
+        pass
 
 
 class Span:
@@ -85,7 +121,7 @@ class Span:
     context manager (or an explicit `close()`)."""
 
     __slots__ = ("_tracer", "name", "args", "tid", "t0_ns", "dur_ns",
-                 "_marks")
+                 "_marks", "_mirror")
 
     def __init__(self, tracer: "Tracer", name: str, tid: int,
                  args: Dict[str, Any]):
@@ -93,6 +129,10 @@ class Span:
         self.name = name
         self.args = args
         self.tid = tid
+        # the mirror opens and closes beside the span's own clock
+        # reads, so both sinks hold the same interval
+        self._mirror = TraceAnnotation(MIRROR_PREFIX + name, **args)
+        self._mirror.__enter__()
         self.t0_ns = time.perf_counter_ns()
         self.dur_ns = 0
         self._marks = None
@@ -107,8 +147,10 @@ class Span:
         self._marks.append((label, time.perf_counter_ns()))
 
     def set(self, **args) -> None:
-        """Attach/overwrite args (visible in the exported event)."""
+        """Attach/overwrite args (visible in the exported event, and
+        among the mirror's stats)."""
         self.args.update(args)
+        self._mirror.set_metadata(**args)
 
     def __enter__(self):
         return self
@@ -121,6 +163,7 @@ class Span:
 
     def close(self) -> None:
         end = time.perf_counter_ns()
+        self._mirror.__exit__(None, None, None)
         self.dur_ns = end - self.t0_ns
         if self._marks:
             for label, t in self._marks:
@@ -135,10 +178,11 @@ class Tracer:
     """Buffered per-process span/instant/counter recorder.
 
     `enabled` is fixed at construction: the global disarmed tracer is a
-    singleton whose `span()`/`instant()`/`counter()` are two-branch
-    no-ops, and arming (obs.configure) swaps in a fresh enabled
-    instance — call sites hold no state, they re-read the global
-    through `obs.tracer()` per query."""
+    singleton whose `instant()`/`counter()` are two-branch no-ops and
+    whose `span()` is a shared no-op (the profiler mirror alone while a
+    profiler session records), and arming (obs.configure) swaps in a
+    fresh enabled instance — call sites hold no state, they re-read
+    the global through `obs.tracer()` per query."""
 
     def __init__(self, enabled: bool = True, *, rank: int | None = None,
                  nprocs: int | None = None):
@@ -265,7 +309,9 @@ class Tracer:
 
     def span(self, name: str, **args):
         if not self.enabled:
-            return NULL_SPAN
+            if not _profiling():
+                return _NULL_SPAN
+            return _MirrorSpan(MIRROR_PREFIX + name, **args)
         return Span(self, name, self._tid(), args)
 
     def _emit_span(self, span: Span) -> None:

@@ -162,18 +162,19 @@ def spmv_strict(values, edge_src, row_lo, vp: int, tile: int, rmax: int,
 
         interpret = not use_pallas()
     num_tiles = row_lo.shape[0]
-    partials = _spmv_partials(
-        values, edge_src, jnp.asarray(row_lo), tile, rmax, num_tiles, vp,
-        interpret=interpret,
-    )
-    # fold tile partials: rows of tile t live at row_lo[t] + [0, rmax)
-    idx = jnp.asarray(row_lo, jnp.int32)[:, None] + jnp.arange(
-        rmax, dtype=jnp.int32
-    )
-    idx = jnp.minimum(idx, vp)  # clamp into the overflow row
-    out = jnp.zeros((vp + 1,), jnp.float32)
-    out = out.at[idx.reshape(-1)].add(partials.reshape(-1))
-    return out[:vp]
+    with jax.named_scope("grape.pull.strict"):
+        partials = _spmv_partials(
+            values, edge_src, jnp.asarray(row_lo), tile, rmax, num_tiles,
+            vp, interpret=interpret,
+        )
+        # fold tile partials: rows of tile t live at row_lo[t] + [0, rmax)
+        idx = jnp.asarray(row_lo, jnp.int32)[:, None] + jnp.arange(
+            rmax, dtype=jnp.int32
+        )
+        idx = jnp.minimum(idx, vp)  # clamp into the overflow row
+        out = jnp.zeros((vp + 1,), jnp.float32)
+        out = out.at[idx.reshape(-1)].add(partials.reshape(-1))
+        return out[:vp]
 
 
 def strict_worthwhile(rmax: int, tile: int) -> bool:

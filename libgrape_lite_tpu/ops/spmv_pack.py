@@ -2406,6 +2406,8 @@ class PackDispatch:
     def reduce(self, x, state, kind: str = "sum",
                interpret: bool | None = None):
         """y[vp] = segment-reduce of x over the planned edges."""
+        import jax
+
         if self.mode == "const":
             import jax.numpy as jnp
 
@@ -2414,15 +2416,16 @@ class PackDispatch:
                     k: jnp.asarray(v[0])
                     for k, v in self.mplan.host_streams.items()
                 }
+            streams, prefix = self._const, ""
+        else:
+            streams = {
+                k: state[k] for k in self.mplan.state_keys(self.prefix)
+            }
+            prefix = self.prefix
+        with jax.named_scope("grape.pull.pack"):
             return segment_reduce_pack_sharded(
-                x, self.mplan, self._const, kind, interpret, prefix=""
+                x, self.mplan, streams, kind, interpret, prefix=prefix
             )
-        streams = {
-            k: state[k] for k in self.mplan.state_keys(self.prefix)
-        }
-        return segment_reduce_pack_sharded(
-            x, self.mplan, streams, kind, interpret, prefix=self.prefix
-        )
 
 
 # resolve-path counters: how often a pack resolve was served from the

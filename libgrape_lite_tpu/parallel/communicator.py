@@ -14,6 +14,13 @@ from jax import lax
 from libgrape_lite_tpu.parallel.comm_spec import FRAG_AXIS
 
 
+def collective_scope():
+    """The device-trace name of every collective of the exchange layer
+    (metadata only).  A new scope object per use: one instance keeps
+    the context it replaced and is not safe to nest or to share."""
+    return jax.named_scope("grape.exchange.collective")
+
+
 class Communicator:
     """Mixin/namespace of in-step collectives. Methods must be called
     inside `shard_map` tracing over the frag axis."""
@@ -22,31 +29,38 @@ class Communicator:
 
     @staticmethod
     def sum(x):
-        return lax.psum(x, FRAG_AXIS)
+        with collective_scope():
+            return lax.psum(x, FRAG_AXIS)
 
     @staticmethod
     def min(x):
-        return lax.pmin(x, FRAG_AXIS)
+        with collective_scope():
+            return lax.pmin(x, FRAG_AXIS)
 
     @staticmethod
     def max(x):
-        return lax.pmax(x, FRAG_AXIS)
+        with collective_scope():
+            return lax.pmax(x, FRAG_AXIS)
 
     @staticmethod
     def all_gather(x, tiled: bool = True):
         """Gather per-shard blocks into the full array (the analogue of
         BatchShuffle's whole-array sync, `batch_shuffle_message_manager.h:237`)."""
-        return lax.all_gather(x, FRAG_AXIS, tiled=tiled)
+        with collective_scope():
+            return lax.all_gather(x, FRAG_AXIS, tiled=tiled)
 
     @staticmethod
     def all_to_all(x, split_axis=0, concat_axis=0):
-        return lax.all_to_all(
-            x, FRAG_AXIS, split_axis=split_axis, concat_axis=concat_axis, tiled=True
-        )
+        with collective_scope():
+            return lax.all_to_all(
+                x, FRAG_AXIS, split_axis=split_axis,
+                concat_axis=concat_axis, tiled=True,
+            )
 
     @staticmethod
     def ppermute(x, perm):
-        return lax.ppermute(x, FRAG_AXIS, perm)
+        with collective_scope():
+            return lax.ppermute(x, FRAG_AXIS, perm)
 
     @staticmethod
     def axis_index():

@@ -33,11 +33,13 @@ from __future__ import annotations
 
 from typing import Dict
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
 from libgrape_lite_tpu.parallel.comm_spec import FRAG_AXIS
+from libgrape_lite_tpu.parallel.communicator import collective_scope
 
 
 class MessageManagerBase:
@@ -65,8 +67,10 @@ class AutoParallelMessageManager(MessageManagerBase):
         fid = lax.axis_index(FRAG_AXIS)
         out = {}
         for k, prop in proposals.items():
-            combined = cls._REDUCERS[ops[k]](prop)
-            out[k] = lax.dynamic_slice(combined, (fid * vp,), (vp,))
+            with collective_scope():
+                combined = cls._REDUCERS[ops[k]](prop)
+            with jax.named_scope("grape.exchange.unpack"):
+                out[k] = lax.dynamic_slice(combined, (fid * vp,), (vp,))
         return out
 
 
@@ -88,59 +92,61 @@ class AllToAllMessageManager(MessageManagerBase):
         destination are dropped and flagged (callers retry with a
         bigger capacity or fall back to the dense path).
         """
-        m = dest_fid.shape[0]
-        big = jnp.int32(fnum)
-        d = jnp.where(valid, dest_fid.astype(jnp.int32), big)
-        order = jnp.argsort(d)  # stable: groups by destination
-        d_s = d[order]
-        lid_s = lid[order]
-        pay_s = payload[order]
+        with jax.named_scope("grape.exchange.pack"):
+            m = dest_fid.shape[0]
+            big = jnp.int32(fnum)
+            d = jnp.where(valid, dest_fid.astype(jnp.int32), big)
+            order = jnp.argsort(d)  # stable: groups by destination
+            d_s = d[order]
+            lid_s = lid[order]
+            pay_s = payload[order]
 
-        # rank within destination group
-        idx = jnp.arange(m, dtype=jnp.int32)
-        first_of_group = jnp.zeros(m, jnp.int32).at[1:].set(
-            (d_s[1:] != d_s[:-1]).astype(jnp.int32)
-        )
-        # start index of each message's group (running max of group heads)
-        starts = jnp.where(first_of_group > 0, idx, 0)
-        starts = lax.associative_scan(jnp.maximum, starts)
-        rank = idx - starts
+            # rank within destination group
+            idx = jnp.arange(m, dtype=jnp.int32)
+            first_of_group = jnp.zeros(m, jnp.int32).at[1:].set(
+                (d_s[1:] != d_s[:-1]).astype(jnp.int32)
+            )
+            # start index of each message's group (running max of group heads)
+            starts = jnp.where(first_of_group > 0, idx, 0)
+            starts = lax.associative_scan(jnp.maximum, starts)
+            rank = idx - starts
 
-        ok = jnp.logical_and(d_s < big, rank < capacity)
-        slot_d = jnp.where(ok, d_s, big)
-        slot_r = jnp.where(ok, rank, 0)
+            ok = jnp.logical_and(d_s < big, rank < capacity)
+            slot_d = jnp.where(ok, d_s, big)
+            slot_r = jnp.where(ok, rank, 0)
 
-        send_lid = jnp.zeros((fnum + 1, capacity), lid.dtype)
-        send_pay = jnp.zeros((fnum + 1, capacity), payload.dtype)
-        send_val = jnp.zeros((fnum + 1, capacity), jnp.bool_)
-        send_lid = send_lid.at[slot_d, slot_r].set(
-            jnp.where(ok, lid_s, 0)
-        )[:fnum]
-        send_pay = send_pay.at[slot_d, slot_r].set(
-            jnp.where(ok, pay_s, 0)
-        )[:fnum]
-        send_val = send_val.at[slot_d, slot_r].set(ok)[:fnum]
+            send_lid = jnp.zeros((fnum + 1, capacity), lid.dtype)
+            send_pay = jnp.zeros((fnum + 1, capacity), payload.dtype)
+            send_val = jnp.zeros((fnum + 1, capacity), jnp.bool_)
+            send_lid = send_lid.at[slot_d, slot_r].set(
+                jnp.where(ok, lid_s, 0)
+            )[:fnum]
+            send_pay = send_pay.at[slot_d, slot_r].set(
+                jnp.where(ok, pay_s, 0)
+            )[:fnum]
+            send_val = send_val.at[slot_d, slot_r].set(ok)[:fnum]
 
-        overflow_local = jnp.logical_and(
-            d_s < big, rank >= capacity
-        ).any().astype(jnp.int32)
-        overflowed = lax.psum(overflow_local, FRAG_AXIS)
-
-        recv_lid = lax.all_to_all(
-            send_lid, FRAG_AXIS, split_axis=0, concat_axis=0, tiled=True
-        )
-        recv_pay = lax.all_to_all(
-            send_pay, FRAG_AXIS, split_axis=0, concat_axis=0, tiled=True
-        )
-        recv_val = lax.all_to_all(
-            send_val, FRAG_AXIS, split_axis=0, concat_axis=0, tiled=True
-        )
-        return (
-            recv_lid.reshape(-1),
-            recv_pay.reshape(-1),
-            recv_val.reshape(-1),
-            overflowed,
-        )
+            overflow_local = jnp.logical_and(
+                d_s < big, rank >= capacity
+            ).any().astype(jnp.int32)
+        with collective_scope():
+            overflowed = lax.psum(overflow_local, FRAG_AXIS)
+            recv_lid = lax.all_to_all(
+                send_lid, FRAG_AXIS, split_axis=0, concat_axis=0, tiled=True
+            )
+            recv_pay = lax.all_to_all(
+                send_pay, FRAG_AXIS, split_axis=0, concat_axis=0, tiled=True
+            )
+            recv_val = lax.all_to_all(
+                send_val, FRAG_AXIS, split_axis=0, concat_axis=0, tiled=True
+            )
+        with jax.named_scope("grape.exchange.unpack"):
+            return (
+                recv_lid.reshape(-1),
+                recv_pay.reshape(-1),
+                recv_val.reshape(-1),
+                overflowed,
+            )
 
 
 def plan_initial_capacity(frag, requested: int | None, learned) -> int:

@@ -295,14 +295,18 @@ class PipelinePlan:
         """The full pull table for this round: LIVE local rows overlaid
         on the buffered remote rows — local reads are bitwise the
         serial value, remote reads hit only (current) boundary rows."""
+        import jax
         import jax.numpy as jnp
         from jax import lax
 
         mode, _ = self._leg(leg)
-        if mode == "mirror":
-            return jnp.concatenate([x_local, xbuf])
-        fid = ctx.fid()
-        return lax.dynamic_update_slice(xbuf, x_local, (fid * self.vp,))
+        with jax.named_scope("grape.exchange.unpack"):
+            if mode == "mirror":
+                return jnp.concatenate([x_local, xbuf])
+            fid = ctx.fid()
+            return lax.dynamic_update_slice(
+                xbuf, x_local, (fid * self.vp,)
+            )
 
     # ---- host side ----
 

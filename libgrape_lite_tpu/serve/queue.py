@@ -488,11 +488,17 @@ class AdmissionQueue:
         request has waited `max_wait_s`, or when `force`d (drain).
         Returns the delivered results, including any deadline-expired
         failures ([] = nothing was ready)."""
-        batch = self._pop_ready(now, force=force)
-        out = self.take_expired()
+        from libgrape_lite_tpu import obs
+
+        tr = obs.tracer()
+        with tr.span("serve.pop"):
+            batch = self._pop_ready(now, force=force)
+            out = self.take_expired()
         if not batch:
             return out
-        out.extend(self.deliver(batch, self._dispatch(batch)))
+        results = self._dispatch(batch)
+        with tr.span("serve.deliver", batch=len(batch)):
+            out.extend(self.deliver(batch, results))
         return out
 
     def drain(self) -> List[ServeResult]:

@@ -583,7 +583,8 @@ class ServeSession:
             t0 = _time.perf_counter_ns()
             w.query(req.max_rounds, guard=guard, **req.args)
             t_exec = _time.perf_counter_ns()
-            vals = w.result_values()
+            with obs.tracer().span("serve.harvest", batch=1):
+                vals = w.result_values()
             stages = self._exec_stages(w, t_exec - t0)
             stages["harvest_us"] = (
                 _time.perf_counter_ns() - t_exec
@@ -641,24 +642,25 @@ class ServeSession:
             _calibration_harvester()(w, stages, rounds * len(batch))
         results = []
         breaches = w.batch_breaches or [None] * len(batch)
-        for b, req in enumerate(batch):
-            if breaches[b] is not None:
-                self.stats["failed"] += 1
-                results.append(ServeResult(
-                    request_id=req.id, app_key=req.app_key, ok=False,
-                    error=breaches[b], rounds=int(w.batch_rounds[b]),
-                    lane=b, batch_size=len(batch),
-                    stages=dict(stages),
-                ))
-            else:
-                results.append(ServeResult(
-                    request_id=req.id, app_key=req.app_key, ok=True,
-                    values=w.batch_result_values(b),
-                    rounds=int(w.batch_rounds[b]),
-                    terminate_code=int(w.batch_terminate[b]),
-                    lane=b, batch_size=len(batch),
-                    stages=dict(stages),
-                ))
+        with obs.tracer().span("serve.harvest", batch=len(batch)):
+            for b, req in enumerate(batch):
+                if breaches[b] is not None:
+                    self.stats["failed"] += 1
+                    results.append(ServeResult(
+                        request_id=req.id, app_key=req.app_key, ok=False,
+                        error=breaches[b], rounds=int(w.batch_rounds[b]),
+                        lane=b, batch_size=len(batch),
+                        stages=dict(stages),
+                    ))
+                else:
+                    results.append(ServeResult(
+                        request_id=req.id, app_key=req.app_key, ok=True,
+                        values=w.batch_result_values(b),
+                        rounds=int(w.batch_rounds[b]),
+                        terminate_code=int(w.batch_terminate[b]),
+                        lane=b, batch_size=len(batch),
+                        stages=dict(stages),
+                    ))
         # per-lane extraction happened inside the loop above: the
         # batch-level harvest stage is the whole post-sync interval
         harvest_us = (_time.perf_counter_ns() - t_exec) // 1000

@@ -38,6 +38,8 @@ from libgrape_lite_tpu.parallel.comm_spec import FRAG_AXIS
 from libgrape_lite_tpu.utils.types import state_struct
 
 _INT32_MAX = np.iinfo(np.int32).max
+# the device-trace name of every runner's loop condition (metadata only)
+_TERMINATE_SCOPE = "grape.worker.terminate"
 
 
 def _squeeze_state(state, squeezed):
@@ -173,8 +175,9 @@ class BatchDispatch:
     def lane_values(self, lane: int) -> np.ndarray:
         """Per-vertex assembled values for one lane, [fnum, vp] numpy —
         the host-sync the harvest stage pays lazily."""
-        host = jax.device_get(self.lane_state(lane))
-        return self.app.finalize(self.fragment, host)
+        with obs.tracer().span("worker.extract", lane=lane):
+            host = jax.device_get(self.lane_state(lane))
+            return self.app.finalize(self.fragment, host)
 
 
 class PreparedBatch:
@@ -415,7 +418,8 @@ class Worker:
 
             def cond(carry):
                 _, act, r = carry
-                return jnp.logical_and(act > 0, r < limit)
+                with jax.named_scope(_TERMINATE_SCOPE):
+                    return jnp.logical_and(act > 0, r < limit)
 
             def body(carry):
                 s, _, r = carry
@@ -479,7 +483,8 @@ class Worker:
 
             def cond(carry):
                 _, _, act, r = carry
-                return jnp.logical_and(act > 0, r < limit)
+                with jax.named_scope(_TERMINATE_SCOPE):
+                    return jnp.logical_and(act > 0, r < limit)
 
             def body(carry):
                 s, xb, _, r = carry
@@ -536,7 +541,8 @@ class Worker:
 
             def cond(carry):
                 _, act, r = carry
-                return jnp.logical_and(act > 0, r < stop)
+                with jax.named_scope(_TERMINATE_SCOPE):
+                    return jnp.logical_and(act > 0, r < stop)
 
             def body(carry):
                 s, _, r = carry
@@ -594,7 +600,8 @@ class Worker:
 
             def cond(carry):
                 _, _, act, r = carry
-                return jnp.logical_and(act > 0, r < stop)
+                with jax.named_scope(_TERMINATE_SCOPE):
+                    return jnp.logical_and(act > 0, r < stop)
 
             def body(carry):
                 s, xb, _, r = carry
@@ -685,6 +692,28 @@ class Worker:
         return self._cached_runner(
             key, lambda: make(max_rounds)(state)
         )
+
+    def _staged(self, make_state, place, runner_for):
+        """The host half of a fused or batched dispatch under its three
+        spans: build the state, place it, look the runner up (`miss=1`
+        when it had to be traced: the enqueue that follows compiles).
+        Returns (runner, carry, eph_part, eph)."""
+        tr = obs.tracer()
+        with tr.span("worker.init_state"):
+            state = make_state()
+        with tr.span("worker.place_state"):
+            state = place(state)
+        with tr.span("worker.runner") as sp:
+            runner = runner_for(state)
+            if self._last_runner_miss:
+                sp.set(miss=1)
+        # AFTER init_state: overlay-contracted apps extend their
+        # ephemeral set there (dyn edge streams ride as shared eph
+        # leaves)
+        eph = frozenset(getattr(self.app, "ephemeral_keys", ()) or ())
+        carry = {k: v for k, v in state.items() if k not in eph}
+        eph_part = {k: v for k, v in state.items() if k in eph}
+        return runner, carry, eph_part, eph
 
     # ---- batched multi-source execution (serve/) -------------------------
 
@@ -787,16 +816,17 @@ class Worker:
         def body(carry):
             s, act, rv, r = carry
             s2, a2 = jax.vmap(lambda st: lane_inc(frag, st))(s)
-            live = act > 0
+            with jax.named_scope("grape.worker.freeze"):
+                live = act > 0
 
-            def sel(new, old):
-                mask = live.reshape((batch,) + (1,) * (new.ndim - 1))
-                return jnp.where(mask, new, old)
+                def sel(new, old):
+                    mask = live.reshape((batch,) + (1,) * (new.ndim - 1))
+                    return jnp.where(mask, new, old)
 
-            s3 = jtu.tree_map(sel, s2, s)
-            a3 = jnp.where(live, a2, act)
-            r2 = r + jnp.int32(1)
-            return s3, a3, jnp.where(live, r2, rv), r2
+                s3 = jtu.tree_map(sel, s2, s)
+                a3 = jnp.where(live, a2, act)
+                r2 = r + jnp.int32(1)
+                return s3, a3, jnp.where(live, r2, rv), r2
 
         return body
 
@@ -829,7 +859,8 @@ class Worker:
 
             def cond(carry):
                 _, act, _, r = carry
-                return jnp.logical_and(jnp.any(act > 0), r < limit)
+                with jax.named_scope(_TERMINATE_SCOPE):
+                    return jnp.logical_and(jnp.any(act > 0), r < limit)
 
             body = self._lane_body(lane_inc, frag, batch)
             st, active, rounds_v, _ = lax.while_loop(
@@ -881,7 +912,8 @@ class Worker:
 
             def cond(carry):
                 _, act, _, r = carry
-                return jnp.logical_and(jnp.any(act > 0), r < stop)
+                with jax.named_scope(_TERMINATE_SCOPE):
+                    return jnp.logical_and(jnp.any(act > 0), r < stop)
 
             body = self._lane_body(lane_inc, frag, batch)
             st, active, rv, r = lax.while_loop(
@@ -1001,25 +1033,26 @@ class Worker:
 
         t_host0 = _time.perf_counter_ns()
         batch = len(args_list)
-        state = self._place_state_batch(
-            app.init_state_batch(frag, args_list)
-        )
-        runner = self._batched_runner_for(mr, batch, state)
-        eph = frozenset(getattr(app, "ephemeral_keys", ()) or ())
-        carry = {k: v for k, v in state.items() if k not in eph}
-        eph_part = {k: v for k, v in state.items() if k in eph}
         tr = obs.tracer()
         try:
             with tr.span("query", mode="batched",
                          app=type(app).__name__, batch=batch) as sp:
-                out_state, rounds_v, active_v = runner(
-                    frag.dev, carry, eph_part
+                runner, carry, eph_part, _ = self._staged(
+                    lambda: app.init_state_batch(frag, args_list),
+                    self._place_state_batch,
+                    lambda st: self._batched_runner_for(mr, batch, st),
                 )
+                with tr.span("worker.enqueue"):
+                    out_state, rounds_v, active_v = runner(
+                        frag.dev, carry, eph_part
+                    )
                 t_enq = _time.perf_counter_ns()
                 sp.mark("dispatched")
-                out_state = jax.block_until_ready(out_state)
-                rv = np.asarray(rounds_v)
-                av = np.asarray(active_v)
+                with tr.span("worker.wait"):
+                    out_state = jax.block_until_ready(out_state)
+                with tr.span("worker.readback"):
+                    rv = np.asarray(rounds_v)
+                    av = np.asarray(active_v)
                 self.last_stage_ns = {
                     "dispatch": t_enq - t_host0,
                     "device": _time.perf_counter_ns() - t_enq,
@@ -1060,8 +1093,9 @@ class Worker:
 
     def batch_result_values(self, lane: int) -> np.ndarray:
         """Per-vertex assembled values for one lane, [fnum, vp] numpy."""
-        host = jax.device_get(self.batch_lane_state(lane))
-        return self.app.finalize(self.fragment, host)
+        with obs.tracer().span("worker.extract", lane=lane):
+            host = jax.device_get(self.batch_lane_state(lane))
+            return self.app.finalize(self.fragment, host)
 
     def query_batch_prepare(self, args_list,
                             max_rounds: int | None = None, *,
@@ -1098,16 +1132,11 @@ class Worker:
                 guard_args=(list(args_list), mr, guard_cfg),
             )
 
-        state = self._place_state_batch(
-            app.init_state_batch(frag, args_list)
+        runner, carry, eph_part, eph = self._staged(
+            lambda: app.init_state_batch(frag, args_list),
+            self._place_state_batch,
+            lambda st: self._batched_runner_for(mr, batch, st),
         )
-        runner = self._batched_runner_for(mr, batch, state)
-        # AFTER init_state_batch: overlay-contracted apps extend their
-        # ephemeral set there (dyn edge streams ride as shared eph
-        # leaves), exactly as query_batch reads it
-        eph = frozenset(getattr(app, "ephemeral_keys", ()) or ())
-        carry = {k: v for k, v in state.items() if k not in eph}
-        eph_part = {k: v for k, v in state.items() if k in eph}
         return PreparedBatch(
             worker=self, app=app, fragment=frag, eph=eph,
             runner=runner, carry=carry, eph_part=eph_part, batch=batch,
@@ -1248,37 +1277,40 @@ class Worker:
         import time as _time
 
         t_host0 = _time.perf_counter_ns()
-        state = self._place_state(
-            self._seeded(app.init_state(frag, **query_args))
-        )
-        runner = self._runner_for(mr, state)
-        eph = frozenset(getattr(app, "ephemeral_keys", ()) or ())
-        carry = {k: v for k, v in state.items() if k not in eph}
-        eph_part = {k: v for k, v in state.items() if k in eph}
         # the whole PEval+IncEval loop is one dispatch: the span's
         # dispatch/device split is the honest granularity here (per-
-        # superstep spans need the stepwise or guarded-chunked paths)
+        # superstep spans need the stepwise or guarded-chunked paths);
+        # the host's own stages are its child spans, which reach the
+        # profiler's trace too (obs/tracer.py)
         try:
             with tr.span("query", mode="fused",
                          app=type(app).__name__) as sp:
+                runner, carry, eph_part, _ = self._staged(
+                    lambda: self._seeded(app.init_state(frag, **query_args)),
+                    self._place_state,
+                    lambda st: self._runner_for(mr, st),
+                )
                 if tr.enabled and self._pipelined() is not None:
                     # modeled overlap next to the measured dispatch/
                     # device split, in the same record (r9):
                     # trace_report derives overlap_hidden_us from it
                     sp.set(pipeline=self._pipelined().span_brief())
-                out_state, rounds, active = runner(
-                    frag.dev, carry, eph_part
-                )
+                with tr.span("worker.enqueue"):
+                    out_state, rounds, active = runner(
+                        frag.dev, carry, eph_part
+                    )
                 t_enq = _time.perf_counter_ns()
-                if getattr(self, "_last_runner_miss", False):
+                if self._last_runner_miss:
                     # fresh compile rode inside this enqueue: stamp it
                     # so truth.py excludes the query from the measured
                     # round wall (compile would launder the claim)
                     sp.mark("compiled")
                 sp.mark("dispatched")
-                out_state = jax.block_until_ready(out_state)
-                self.rounds = int(rounds)
-                self._terminate_code = min(0, int(active))
+                with tr.span("worker.wait"):
+                    out_state = jax.block_until_ready(out_state)
+                with tr.span("worker.readback"):
+                    self.rounds = int(rounds)
+                    self._terminate_code = min(0, int(active))
                 self.last_stage_ns = {
                     "dispatch": t_enq - t_host0,
                     "device": _time.perf_counter_ns() - t_enq,
@@ -1351,7 +1383,6 @@ class Worker:
         mode, reason = incremental_plan(app, delta)
         self.inc_report = {"mode": mode, "reason": reason}
         self.inc_stats[mode] += 1
-        obs.tracer().instant("query_incremental", mode=mode)
         if mode == "cold":
             glog.vlog(
                 1, "query_incremental: cold recompute (%s)", reason
@@ -2436,24 +2467,25 @@ class Worker:
         """Per-vertex assembled values, [fnum, vp] numpy."""
         if self._result_state is None:
             raise RuntimeError("query() first")
-        if jax.process_count() > 1:
-            # the carry spans non-addressable devices in a
-            # jax.distributed run; gather each sharded leaf to a full
-            # host copy so finalize sees the same [fnum, vp] view a
-            # single-process run would
-            from jax.experimental import multihost_utils
+        with obs.tracer().span("worker.extract"):
+            if jax.process_count() > 1:
+                # the carry spans non-addressable devices in a
+                # jax.distributed run; gather each sharded leaf to a full
+                # host copy so finalize sees the same [fnum, vp] view a
+                # single-process run would
+                from jax.experimental import multihost_utils
 
-            host_state = {}
-            for k, v in self._result_state.items():
-                if getattr(v, "is_fully_addressable", True):
-                    host_state[k] = np.asarray(jax.device_get(v))
-                else:
-                    host_state[k] = np.asarray(
-                        multihost_utils.process_allgather(v)
-                    )
-        else:
-            host_state = jax.device_get(self._result_state)
-        return self.app.finalize(self.fragment, host_state)
+                host_state = {}
+                for k, v in self._result_state.items():
+                    if getattr(v, "is_fully_addressable", True):
+                        host_state[k] = np.asarray(jax.device_get(v))
+                    else:
+                        host_state[k] = np.asarray(
+                            multihost_utils.process_allgather(v)
+                        )
+            else:
+                host_state = jax.device_get(self._result_state)
+            return self.app.finalize(self.fragment, host_state)
 
     def output(self, prefix: str) -> None:
         """Write per-fragment result files `result_frag_<fid>` with
