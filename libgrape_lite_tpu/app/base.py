@@ -346,14 +346,16 @@ class AppBase:
     # ---- shared compute helpers ----
 
     @staticmethod
-    def segment_reduce(values, edge_src, vp, kind="sum"):
+    def segment_reduce(values, edge_src, vp, kind="sum", row_ptr=None):
         """Reduce per-edge values into per-vertex rows; padded edges fall
         into the overflow row `vp` which is sliced off.  This is the TPU
         ForEachEdge: edge-parallel, degree-oblivious (the role of the
-        reference CUDA LB kernels, `cuda/parallel/parallel_engine.h`)."""
+        reference CUDA LB kernels, `cuda/parallel/parallel_engine.h`).
+        A pull over a whole CSR hands over its `indptr` as `row_ptr`
+        and folds by scan; see `ops/segment.segment_reduce`."""
         from libgrape_lite_tpu.ops.segment import segment_reduce
 
-        return segment_reduce(values, edge_src, vp, kind)
+        return segment_reduce(values, edge_src, vp, kind, row_ptr=row_ptr)
 
     @staticmethod
     def dyn_min_fold(relaxed, state: Dict, vp: int, prefix: str, cand):

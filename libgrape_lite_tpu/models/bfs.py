@@ -98,7 +98,7 @@ class BFS(ParallelAppBase):
             self._pipeline = resolve_pipeline(
                 frag, app_name="BFS", key="depth", direction="ie",
                 mirror=self._mx, mx_prefix="mx_", pack=self._pack,
-                fold="min", with_weights=False,
+                with_weights=False,
             )
             if self._pipeline is not None:
                 eph_entries.update(self._pipeline.host_entries)
@@ -137,7 +137,7 @@ class BFS(ParallelAppBase):
             cand = pull_gather(full, nbr, ie.edge_mask, sent, add=1,
                                absent=sent)
             relaxed = self.segment_reduce(cand, ie.edge_src, frag.vp,
-                                          "min")
+                                          "min", row_ptr=ie.indptr)
         if "dyn_ie_nbr" in state:
             # staged delta edges (dyn/): extra unit-weight candidates
             # merged at the fold; `full` is pid-addressed in overlay

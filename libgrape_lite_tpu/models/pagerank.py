@@ -46,10 +46,6 @@ class PageRank(BatchShuffleAppBase):
     # there is no fixed point to reuse at finite rounds, so the
     # incremental contract is an honest counted restart
     inc_mode = "restart"
-    # r9: the rank pull pipelines on the plain XLA segment-sum path
-    # (per-row addend order is preserved by the stable row partition);
-    # the pack/strict backends regroup float partials and stay serial
-    pipeline_state_key = "rank"
 
     def __init__(self, delta: float = 0.85, max_round: int = 10):
         self.delta = delta
@@ -202,32 +198,21 @@ class PageRank(BatchShuffleAppBase):
                 state["spmv_row_lo"] = row_lo
         else:
             self._spmv_tile = self._spmv_rmax = 0
-        # superstep pipelining (r9): only the plain gather+segment_sum
-        # path splits bit-stably (a sorted segment sum consumes each
-        # row's addends in stream order; the strict-tile and pack
-        # backends regroup partials across a split — pinned in
-        # tests/test_pipeline.py)
-        self._pipeline = None
+        # superstep pipelining (r9) needs a fold that splits bit-stably
+        # into a boundary and an interior slice.  No serial sum here
+        # does: the scan of ops/segment.py, the strict-tile kernel and
+        # the pack backend all group a row's addends by tile, and tile
+        # partial sums regroup under a split.  So PageRank declines, on
+        # the record, and runs its serial round whatever GRAPE_PIPELINE
+        # says (pinned in tests/test_pipeline.py)
         if not batched:
             from libgrape_lite_tpu.parallel.pipeline import resolve_pipeline
 
-            self._pipeline = resolve_pipeline(
-                frag, app_name="PageRank", key="rank", direction="ie",
-                mirror=self._mx, mx_prefix="mx_", pack=self._pack,
-                fold="sum", with_weights=False,
-                eligible="spmv_row_lo" not in state,
-                reason="strict-tile spmv plan engaged (tile partial "
+            resolve_pipeline(
+                frag, app_name="PageRank", key="rank", eligible=False,
+                reason="the serial sum folds by tile (tile partial "
                        "sums regroup under a split)",
             )
-            if self._pipeline is not None:
-                state.update(self._pipeline.host_entries)
-                self.ephemeral_keys = frozenset(
-                    set(self.ephemeral_keys)
-                    | set(self._pipeline.host_entries)
-                )
-        self._pipeline_uid = (
-            self._pipeline.uid if self._pipeline is not None else -1
-        )
         return state
 
     def peval(self, ctx: StepContext, frag, state):
@@ -351,42 +336,10 @@ class PageRank(BatchShuffleAppBase):
             if "spmv_row_lo" in state
             else None
         )
-        cur = segment_sum_auto(contrib, ie.edge_src, frag.vp, plan).astype(dt)
+        cur = segment_sum_auto(
+            contrib, ie.edge_src, frag.vp, plan, row_ptr=ie.indptr
+        ).astype(dt)
         return self.round_update(frag, state, cur)
-
-    def inceval_pipelined(self, ctx: StepContext, frag, state, xbuf):
-        """Double-buffered round (parallel/pipeline.py): the boundary
-        slice's rank sum runs first, `round_update` lifts it to the
-        boundary rows' NEW ranks (the update is elementwise per row
-        given the round's replicated scalars, so the boundary rows of
-        the partial update equal the joined update bitwise), the
-        exchange kicks off, and the interior sum overlaps it.  The
-        final `round_update` runs ONCE on the joined sums — scalars
-        (step, dangling_sum) and the vote come from that single call,
-        exactly like the serial round."""
-        pl = self._pipeline
-        rank = state["rank"]
-        dt = rank.dtype
-        zero = jnp.asarray(0, dt)
-        full = pl.splice(ctx, rank, state, xbuf)
-        bmask = state["pl_bmask"]
-        cur_b = self.segment_reduce(
-            pull_gather(full, state["pl_b_nbr"], state["pl_b_val"], zero),
-            state["pl_b_src"], frag.vp, "sum",
-        ).astype(dt)
-        st_b, _ = self.round_update(frag, state, cur_b)
-        xbuf2 = pl.kickoff(
-            ctx, jnp.where(bmask, st_b["rank"], rank), state
-        )
-        # ---- pipelined window: carry reads below are named in
-        # parallel/pipeline.PIPELINE_WINDOW_READS (grape-lint R6) ----
-        cur_i = self.segment_reduce(
-            pull_gather(full, state["pl_i_nbr"], state["pl_i_val"], zero),
-            state["pl_i_src"], frag.vp, "sum",
-        ).astype(dt)
-        cur = jnp.where(bmask, cur_b, cur_i)
-        st2, active = self.round_update(frag, state, cur)
-        return st2, active, xbuf2
 
     # PageRank is a probability distribution: within each round the
     # stored form is rank/deg (dangling vertices hold the raw base), so

@@ -143,7 +143,7 @@ class SSSP(ParallelAppBase):
             self._pipeline = resolve_pipeline(
                 frag, app_name="SSSP", key="dist", direction="ie",
                 mirror=self._mx, mx_prefix="mx_", pack=self._pack,
-                fold="min", with_weights=True,
+                with_weights=True,
             )
             if self._pipeline is not None:
                 eph_entries.update(self._pipeline.host_entries)
@@ -179,11 +179,13 @@ class SSSP(ParallelAppBase):
             # inf at masked edges) folds the relax-mask select into the
             # add — bit-identical to the where() form
             cand = pull_gather(full, nbr, add=state["wf_eff"])
-            relaxed = self.segment_reduce(cand, ie.edge_src, frag.vp, "min")
+            relaxed = self.segment_reduce(cand, ie.edge_src, frag.vp, "min",
+                                          row_ptr=ie.indptr)
         else:
             inf = jnp.asarray(jnp.inf, dist.dtype)
             cand = pull_gather(full, nbr, ie.edge_mask, inf, add=ie.edge_w)
-            relaxed = self.segment_reduce(cand, ie.edge_src, frag.vp, "min")
+            relaxed = self.segment_reduce(cand, ie.edge_src, frag.vp, "min",
+                                          row_ptr=ie.indptr)
         if "dyn_ie_nbr" in state:
             # staged delta edges (dyn/): one extra gather + segment_min
             # over the dense overlay slots, merged at the fold — `full`

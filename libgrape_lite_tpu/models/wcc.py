@@ -117,7 +117,7 @@ class WCC(ParallelAppBase):
             self._pipeline = resolve_pipeline(
                 frag, app_name="WCC", key="comp", direction="ie",
                 mirror=self._mx_ie, mx_prefix="mx_ie_",
-                pack=self._pack_ie, fold="min", with_weights=False,
+                pack=self._pack_ie, with_weights=False,
                 direction2="oe" if frag.directed else None,
                 mirror2=self._mx_oe if frag.directed else None,
                 eligible=(type(self)._post_pull is WCC._post_pull),
@@ -159,7 +159,8 @@ class WCC(ParallelAppBase):
             )
         else:
             cand = pull_gather(full, nbr, csr.edge_mask, big)
-            red = self.segment_reduce(cand, csr.edge_src, frag.vp, "min")
+            red = self.segment_reduce(cand, csr.edge_src, frag.vp, "min",
+                                      row_ptr=csr.indptr)
         if dyn_prefix is not None and dyn_prefix + "nbr" in state:
             # staged delta edges (dyn/): extra label candidates merged
             # at the fold; `full` is pid-addressed in overlay mode

@@ -54,6 +54,7 @@ EXPECTED: Dict[str, str] = {
     "autopilot": "libgrape_lite_tpu.autopilot.signals",
     "vc_tiles": "libgrape_lite_tpu.fragment.vertexcut",
     "gang": "libgrape_lite_tpu.obs.gang",
+    "fold": "libgrape_lite_tpu.ops.segment",
 }
 
 

@@ -5,16 +5,29 @@ The reference parallelises per-edge work with its CPU ParallelEngine
 kernel catalog (`grape/cuda/parallel/parallel_engine.h:42-1444`,
 cm/wm/cta/strict policies).  On TPU the same problem — distribute
 variable-degree adjacency work evenly — is solved by *edge-major*
-layout: per-edge values keyed by their row id, reduced with XLA segment
-ops, which lower to sorted-scatter kernels the compiler tiles evenly.
+layout: per-edge values keyed by their row id and reduced per row.
 A Pallas row-blocked variant lives alongside for the hot SpMV path.
+
+`segment_reduce` has two folds.  XLA's segment ops are scatters, and
+on the TPU v5e a scatter steps through its updates one at a time, 8.7
+ns an element at Graph500 scale 10 as at scale 21, sorted ids or not
+(PERF.md, section 5): `indices_are_sorted` does not turn it into a
+scan.  So where the rows are a CSR's, sorted and with their offsets at
+hand, the fold is a scan written out in dense XLA: `_segmented_scan`
+over tiles of 128 places, then one V-wide gather of the row ends
+(0.83 ns an element at scale 21).  Everything else keeps the scatter:
+ids that are not sorted, streams without offsets (the dyn overlay, the
+pipeline's boundary and interior slices), and the query lanes of an
+exact fold under `jax.vmap`, whose gather XLA fuses into the scatter.
+A float sum's lanes scan, as their single queries do: its bits depend
+on the grouping, and a lane answers with its single query's bytes.
 
 The two halves of a pull carry `jax.named_scope` names, which reach the
 device trace as the operations' `tf_op` (metadata only: the compiled
 program is the same with and without them): `grape.pull.gather` on the
-E-wide gather, `grape.pull.fold` on the segment fold.  A fusion takes
-its root's name, so where XLA fuses the gather into the fold the whole
-fusion reads as the fold.
+E-wide gather, `grape.pull.fold` on the segment fold, by scan or by
+scatter.  A fusion takes its root's name, so where XLA fuses the
+gather into the fold the whole fusion reads as the fold.
 """
 
 from __future__ import annotations
@@ -22,6 +35,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import jax.ops as jops
+from jax import lax
+from jax.custom_batching import custom_vmap
+
+from libgrape_lite_tpu.obs.federation import FederatedStats as _FedStats
 
 
 def pull_gather(full, nbr, mask=None, fill=None, add=None, absent=None):
@@ -42,27 +59,171 @@ def pull_gather(full, nbr, mask=None, fill=None, add=None, absent=None):
         return vals if ok is None else jnp.where(ok, vals, fill)
 
 
+# places in one tile of the segmented scan: the lanes of the chip's
+# vector registers, and the unit fragment/edgecut.py rounds a CSR's Ep
+# up to
+SCAN_TILE = 128
+
+_FOLDS = {
+    # kind: (scatter, combine, identity of an empty row as the scatter
+    # gives it, given the dtype)
+    "sum": (jops.segment_sum, jnp.add, lambda dt: 0),
+    "prod": (jops.segment_prod, jnp.multiply, lambda dt: 1),
+    "min": (jops.segment_min, jnp.minimum,
+            lambda dt: jnp.inf if jnp.issubdtype(dt, jnp.floating)
+            else jnp.iinfo(dt).max),
+    "max": (jops.segment_max, jnp.maximum,
+            lambda dt: -jnp.inf if jnp.issubdtype(dt, jnp.floating)
+            else jnp.iinfo(dt).min),
+}
+
+# which fold each `segment_reduce` call took, counted where it is
+# decided: at trace time, once per call site per traced program
+FOLD_STATS = _FedStats("fold", {"scan": 0, "scatter": 0})
+
+
+def _shift(x, d: int, fill):
+    """`x` moved `d` places up its last axis, `fill` in the first `d`."""
+    cfg = [(0, 0, 0)] * (x.ndim - 1) + [(d, -d, 0)]
+    return lax.pad(x, jnp.asarray(fill, x.dtype), cfg)
+
+
+def _segmented_scan(values, ids, combine, identity):
+    """Inclusive scan of the 1-D `values` that restarts where the
+    sorted, non-negative `ids` change.
+
+    Tiles of SCAN_TILE places are scanned side by side in
+    log2(SCAN_TILE) dense steps: at distance d a place takes in the
+    one d below it when both hold one id (the ids are sorted, so the
+    places between do too).  Each tile's last place is then a partial
+    of the row that leaves the tile; the scan of those partials, one
+    level up, is what every tile still lacks from the tiles before it,
+    and it is folded into the places of the tile's first row."""
+    n = ids.shape[0]
+    pad = -n % SCAN_TILE
+    if pad:
+        # only above the first level: a CSR's Ep is whole tiles
+        values = lax.pad(values, jnp.asarray(identity, values.dtype),
+                         [(0, pad, 0)])
+        ids = lax.pad(ids, ids[-1], [(0, pad, 0)])
+    v = values.reshape(-1, SCAN_TILE)
+    i = ids.reshape(-1, SCAN_TILE)
+    d = 1
+    while d < SCAN_TILE:
+        v = jnp.where(i == _shift(i, d, -1),
+                      combine(v, _shift(v, d, identity)), v)
+        d *= 2
+    if v.shape[0] > 1:
+        tail_i = i[:, -1]
+        above = _segmented_scan(v[:, -1], tail_i, combine, identity)
+        carry_i = _shift(tail_i, 1, -1)[:, None]
+        carry = _shift(above, 1, identity)[:, None]
+        v = jnp.where(i == carry_i, combine(carry, v), v)
+    return v.reshape(-1)[:n]
+
+
+def _scatter_fold(values, segment_ids, num_rows: int, kind: str,
+                  sorted_ids: bool):
+    out = _FOLDS[kind][0](
+        values, segment_ids, num_segments=num_rows + 1,
+        indices_are_sorted=sorted_ids,
+    )
+    return out[:num_rows]
+
+
+def _scan_rows(values, segment_ids, row_ptr, num_rows: int, kind: str):
+    """The scan fold of one lane: `_segmented_scan`, then each row's
+    fold from the row's last place."""
+    _, combine, ident = _FOLDS[kind]
+    identity = ident(values.dtype)
+    scanned = _segmented_scan(values, segment_ids, combine, identity)
+    # an empty row has no last place
+    last = row_ptr[1:num_rows + 1] - 1
+    out = scanned.at[jnp.maximum(last, 0)].get(
+        mode="promise_in_bounds", indices_are_sorted=True)
+    return jnp.where(last >= row_ptr[:num_rows], out,
+                     jnp.asarray(identity, values.dtype))
+
+
+def _grouping_shows(kind: str, dtype) -> bool:
+    """Whether a fold's bits depend on how its operands are grouped:
+    float sums and products do; integer, min and max folds are exact
+    under any grouping."""
+    return kind in ("sum", "prod") and jnp.issubdtype(dtype, jnp.inexact)
+
+
+def _scan_fold(num_rows: int, kind: str):
+    """The fold of one `segment_reduce` call that came with offsets:
+    the scan, with its own rule under `jax.vmap`.  Made anew for each
+    call, because it carries the call's entry in FOLD_STATS."""
+    took = ["scan"]
+    FOLD_STATS["scan"] += 1
+
+    @custom_vmap
+    def fold(values, segment_ids, row_ptr):
+        return _scan_rows(values, segment_ids, row_ptr, num_rows, kind)
+
+    @fold.def_vmap
+    def lanes(axis_size, in_batched, values, segment_ids, row_ptr):
+        if in_batched[1] or in_batched[2]:
+            raise NotImplementedError(
+                "segment_reduce: lanes share segment_ids and row_ptr")
+        if _grouping_shows(kind, values.dtype):
+            # a float sum's lanes scan too, each with the arithmetic of
+            # its own single query, so that a lane keeps that query's
+            # bits (docs/SERVING.md)
+            return jax.vmap(
+                lambda v: _scan_rows(v, segment_ids, row_ptr, num_rows,
+                                     kind)
+            )(values), True
+        # Exact folds keep the scatter for query lanes over one CSR
+        # (the batched runner).  XLA gathers all lanes of an entry at
+        # once and fuses that into the scatter; ahead of a scan the
+        # gathered [Ep, lanes] block has to stand in memory, lanes
+        # minor and padded to 128: 4.4 GB where the scatter's round
+        # holds 0.27 (four lanes, Ep 8.4M; the chip's compiler,
+        # PERF.md section 6)
+        if took[0] == "scan":
+            # moved once, however often the rule runs for this call (a
+            # loop's batching rule may run it again)
+            took[0] = "scatter"
+            FOLD_STATS["scan"] -= 1
+            FOLD_STATS["scatter"] += 1
+        return jax.vmap(
+            lambda v: _scatter_fold(v, segment_ids, num_rows, kind, True)
+        )(values), True
+
+    return fold
+
+
 def segment_reduce(values, segment_ids, num_rows: int, kind: str = "sum",
-                   sorted_ids: bool = True):
+                   sorted_ids: bool = True, row_ptr=None):
     """Reduce `values` by `segment_ids` into `num_rows` rows.
 
     Ids equal to `num_rows` (padding convention) land in an overflow row
     that is sliced off — mirroring the reference's convention of routing
     invalid work to a trash slot rather than branching.
 
-    `sorted_ids` defaults True because CSR edge arrays are built sorted
-    by row (graph/csr.py) — XLA lowers sorted segment reductions to a
-    cheaper scan-style kernel than the general scatter.
+    Two folds, chosen by what the caller hands over.  With `row_ptr`
+    (a CSR's `indptr`: `segment_ids` sorted, row r at places
+    `row_ptr[r]:row_ptr[r + 1]`, padding behind the last row) the
+    fold is a tile-segmented scan and one V-wide gather of the row
+    ends: dense E-wide work, no scatter.  Float sums then group by
+    tile, not in stream order; integer, min and max folds are exact
+    either way.  Without it the fold is `jax.ops.segment_*`, a
+    scatter, for ids that are not sorted (`sorted_ids=False`) or that
+    come without offsets.  Under `jax.vmap` (query lanes over one CSR)
+    a fold that is exact under any grouping goes back to the scatter,
+    and a float sum scans lane by lane as its single query does, so a
+    lane's answer has that query's bytes either way (see `_scan_fold`).
+    FOLD_STATS counts which fold a call took.
     """
-    fn = {
-        "sum": jops.segment_sum,
-        "min": jops.segment_min,
-        "max": jops.segment_max,
-        "prod": jops.segment_prod,
-    }[kind]
+    if row_ptr is not None and not sorted_ids:
+        raise ValueError(
+            "segment_reduce: row_ptr describes sorted segment_ids")
     with jax.named_scope("grape.pull.fold"):
-        out = fn(
-            values, segment_ids, num_segments=num_rows + 1,
-            indices_are_sorted=sorted_ids,
-        )
-        return out[:num_rows]
+        if row_ptr is not None:
+            return _scan_fold(num_rows, kind)(values, segment_ids, row_ptr)
+        FOLD_STATS["scatter"] += 1
+        return _scatter_fold(values, segment_ids, num_rows, kind,
+                             sorted_ids)

@@ -246,14 +246,15 @@ def plan_for_app(frag, vp: int, dtype, tile: int = 2048,
     return row_lo, tile, rmax
 
 
-def segment_sum_auto(values, edge_src, vp: int, plan=None):
+def segment_sum_auto(values, edge_src, vp: int, plan=None, row_ptr=None):
     """Sorted segment-sum routed per the host plan: the strict-tile
     Pallas kernel when `plan` is a (row_lo_local, tile, rmax) triple
-    (row_lo_local = this shard's [num_tiles] slice), the XLA
-    gather+segment_sum otherwise."""
+    (row_lo_local = this shard's [num_tiles] slice), otherwise
+    `ops/segment.segment_reduce`, by scan where the caller has the
+    CSR's `row_ptr`."""
     if plan is None:
         from libgrape_lite_tpu.ops.segment import segment_reduce
 
-        return segment_reduce(values, edge_src, vp, "sum")
+        return segment_reduce(values, edge_src, vp, "sum", row_ptr=row_ptr)
     row_lo, tile, rmax = plan
     return spmv_strict(values, edge_src, row_lo, vp, tile, rmax)

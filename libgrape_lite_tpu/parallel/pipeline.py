@@ -76,12 +76,10 @@ _MIN_BYTES_DEFAULT = 1 << 20
 # means auditing it and naming it here, in review.
 PIPELINE_WINDOW_READS = frozenset({
     # live carry leaves the interior slice folds against
-    "dist", "depth", "comp", "rank",
+    "dist", "depth", "comp",
     # CDLP's carry label plane (the join selector of the mode fold)
     # and its replicated rank LUT (read by both part folds)
     "labels", "lut",
-    # PageRank's replicated scalars (read by the joined round_update)
-    "step", "seed", "dangling_sum", "total_dangling",
     # the boundary mask (the join selector) and the interior streams
     "pl_bmask", "pl_i_src", "pl_i_nbr", "pl_i_val", "pl_i_w",
     # the second-direction streams of the directed double-pull round
@@ -98,8 +96,6 @@ PIPELINE_WINDOW_READS = frozenset({
 # whole-carry escape; each name here was audited by hand:
 #   reduce        PackDispatch.reduce — reads only its own pk*_ stream
 #                 leaves (pki_*/pkb_ prefixes) plus the table argument
-#   round_update  PageRank — reads the replicated scalar keys named in
-#                 PIPELINE_WINDOW_READS above, elementwise per row
 #   kickoff       PipelinePlan.kickoff — reads only its send_key leaf
 #                 (the mirror send table, a static host stream), never
 #                 a live carry value; the directed double-pull round
@@ -108,7 +104,7 @@ PIPELINE_WINDOW_READS = frozenset({
 #                 dict at all (mirror mode concatenates its explicit
 #                 args; gather mode reads only ctx.fid())
 PIPELINE_WINDOW_CALLEES = frozenset({
-    "reduce", "round_update", "kickoff", "splice",
+    "reduce", "kickoff", "splice",
 })
 
 # resolve-path registry: the last pipeline decision + split stats, so
@@ -411,7 +407,7 @@ def _split_streams(frag, bmask: np.ndarray, direction: str, mirror,
 def resolve_pipeline(frag, *, app_name: str, key: str,
                      direction: str = "ie", mirror=None,
                      mx_prefix: str = "mx_", pack=None,
-                     fold: str = "min", with_weights: bool = False,
+                     with_weights: bool = False,
                      eligible: bool = True, reason: str = "",
                      direction2: str | None = None, mirror2=None,
                      mx2_prefix: str = "mx_oe_"):
@@ -420,8 +416,10 @@ def resolve_pipeline(frag, *, app_name: str, key: str,
     `mirror`/`pack` are the app's ALREADY-RESOLVED exchange and SpMV
     backends — the pipelined round must use the same exchange mode and
     the same fold machinery as the serial one, or byte-identity is
-    off the table.  Decline reasons are recorded in
-    PIPELINE_STATS["last_decision"] (and vlogged), never silent.
+    off the table.  Every fold that pipelines is exact under a split
+    (min, CDLP's mode); a float sum is not, so PageRank passes
+    `eligible=False` with its reason.  Decline reasons are recorded
+    in PIPELINE_STATS["last_decision"] (and vlogged), never silent.
 
     `direction2` requests the directed DOUBLE-PULL round (WCC on a
     directed graph: an ie pull then an oe pull per superstep).  The
@@ -454,12 +452,6 @@ def resolve_pipeline(frag, *, app_name: str, key: str,
     ov = getattr(frag, "dyn_overlay", None)
     if ov is not None:
         return declined("dyn overlay attached (pid-addressed reads)")
-    if fold == "sum" and pack is not None:
-        # split pack sub-plans regroup float partial sums — exact for
-        # min/max folds, only allclose for sums (the documented pack
-        # float-parity limit); byte-identity wins
-        return declined("sum fold over the pack backend is not "
-                        "bit-stable under a split plan")
     if direction2 is not None and pack is not None:
         # the double-pull round would need FOUR pack sub-plans (b/i per
         # direction) whose split fold order is unaudited against the

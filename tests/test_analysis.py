@@ -345,18 +345,17 @@ def test_r6_trips_on_whole_carry_escape():
 
 
 def test_r6_passes_on_audited_callees():
-    # reduce (pack sub-plan dispatch) and round_update (PageRank) are
-    # named in PIPELINE_WINDOW_CALLEES — whole-carry passes to them
-    # are audited, in the main body and in nested helpers alike
+    # reduce (pack sub-plan dispatch), kickoff and splice are named in
+    # PIPELINE_WINDOW_CALLEES — whole-carry passes to them are
+    # audited, in the main body and in nested helpers alike
     src = """
     def inceval_pipelined(self, ctx, frag, state, xbuf):
         def pack_fold(dispatch, table):
             return dispatch.reduce(table, state, "min")
-        full = self._pipeline.splice(ctx, state["rank"], state, xbuf)
-        xbuf2 = self._pipeline.kickoff(ctx, state["rank"], state)
+        full = self._pipeline.splice(ctx, state["dist"], state, xbuf)
+        xbuf2 = self._pipeline.kickoff(ctx, state["dist"], state)
         cur = pack_fold(self._pipeline.pack_i, full)
-        st2, active = self.round_update(frag, state, cur)
-        return st2, active, xbuf2
+        return {"dist": cur}, 1, xbuf2
     """
     assert "R6" not in _rules(src)
 
@@ -404,7 +403,7 @@ def test_r6_shipped_incevals_are_clean():
 
     root = os.path.dirname(libgrape_lite_tpu.__file__)
     for mod in ("models/sssp.py", "models/bfs.py", "models/wcc.py",
-                "models/pagerank.py"):
+                "models/cdlp.py"):
         path = os.path.join(root, mod)
         with open(path) as fh:
             src = fh.read()

@@ -177,13 +177,18 @@ def test_boundary_stats_partition():
 def test_byte_identity_matrix(app_name, fnum, monkeypatch):
     """The acceptance matrix: GRAPE_PIPELINE results byte-identical to
     serial on all four apps at fnum 1/2/4 (gather exchange, XLA SpMV).
-    fnum=1 must DECLINE (no exchange to overlap) and still match."""
+    fnum=1 must DECLINE (no exchange to overlap) and still match, and
+    so must PageRank at every fnum: its serial sum groups by tile
+    (ops/segment.py) and would regroup under a split (the reason is
+    pinned in tests/test_segment_fold.py)."""
     frag = _rand_frag(fnum)
     serial, rounds_s, _ = _run(app_name, frag, monkeypatch, "0")
     piped, rounds_p, app = _run(app_name, frag, monkeypatch, "force")
     assert piped == serial
     assert rounds_p == rounds_s
-    assert (app._pipeline is not None) == (fnum > 1)
+    assert (app._pipeline is not None) == (
+        fnum > 1 and app_name != "pagerank"
+    )
 
 
 @pytest.mark.parametrize("app_name,env", [
@@ -204,6 +209,10 @@ def test_byte_identity_exchange_modes(app_name, env, monkeypatch):
     serial, _, _ = _run(app_name, frag, monkeypatch, "0", **env)
     piped, _, app = _run(app_name, frag, monkeypatch, "force", **env)
     assert piped == serial
+    if app_name == "pagerank":
+        # the tiled serial sum declines under every exchange mode
+        assert app._pipeline is None
+        return
     assert app._pipeline is not None
     want_mode = "mirror" if "GRAPE_EXCHANGE" in env else "gather"
     assert app._pipeline.mode == want_mode

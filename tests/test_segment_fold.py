@@ -1,0 +1,245 @@
+"""The sorted fold without a scatter (`ops/segment.segment_reduce`).
+
+Handed a CSR's row offsets, the fold is a tile-segmented scan and one
+gather of the row ends; without them it is `jax.ops.segment_*`, as it
+always was, and as it stays for the query lanes of an exact fold under
+`jax.vmap`.  Pinned here: the scan against the scatter (byte-equal for
+integers, min and max; float sums against an f64 NumPy fold) over the
+shapes a CSR takes; that exact lanes take the scatter and a float
+sum's lanes the scan, bit for bit their single calls; that `row_ptr`
+with unsorted ids is refused; that a call without
+`row_ptr` lowers to the text it lowered to before; and, through the
+trace-time counter `FOLD_STATS`, which fold each app's round takes.
+"""
+
+import jax
+import jax.numpy as jnp
+import jax.ops as jops
+import numpy as np
+import pytest
+
+from libgrape_lite_tpu.models import APP_REGISTRY
+from libgrape_lite_tpu.ops.segment import (
+    FOLD_STATS,
+    SCAN_TILE,
+    segment_reduce,
+)
+from libgrape_lite_tpu.worker.worker import Worker
+
+T = SCAN_TILE
+
+# name -> (row degrees, Ep): the shapes a CSR takes.  Places behind the
+# last row are padding with id = num_rows.
+SHAPES = {
+    "empty_rows": ([0, 3, 0, 0, 130, 0, 5, 0], 2 * T),
+    "hub_three_tiles": ([2, 1, 3 * T + 40, 7, 1], 4 * T),
+    # 130 whole tiles of one row: its partials fill more than one tile
+    # one level up, so a third level carries them
+    "hub_two_levels": ([5, 130 * T + 9, 3, 0, 11], 131 * T),
+    "row_ends_on_last_lane": ([100, 28, T, 60, 3 * T - 60, 1], 6 * T),
+    "pad_tail": ([9, 0, 33, 70], 3 * T),
+    "one_tile": ([40, 0, 50, 20], T),
+    "degree_one": ([1] * (2 * T), 2 * T),
+}
+
+FOLDS = [(k, d) for k in ("sum", "min", "max")
+         for d in ("float32", "int32", "float64")] + [("prod", "int32")]
+
+_SCATTER = {"sum": jops.segment_sum, "min": jops.segment_min,
+            "max": jops.segment_max, "prod": jops.segment_prod}
+
+
+def _csr(shape: str):
+    deg, ep = SHAPES[shape]
+    deg = np.asarray(deg, np.int64)
+    rows = len(deg)
+    ptr = np.zeros(rows + 1, np.int32)
+    ptr[1:] = np.cumsum(deg)
+    assert ptr[-1] <= ep and ep % T == 0
+    ids = np.full(ep, rows, np.int32)
+    ids[:ptr[-1]] = np.repeat(np.arange(rows, dtype=np.int32), deg)
+    return rows, ptr, ids
+
+
+def _values(kind: str, dtype: str, shape, seed: int):
+    rng = np.random.default_rng(seed)
+    if kind == "prod":  # exact under any grouping
+        return rng.choice(np.asarray([-1, 1], dtype), shape)
+    if dtype == "int32":
+        return rng.integers(-1000, 1000, shape).astype(dtype)
+    return rng.uniform(1.0, 100.0, shape).astype(dtype)
+
+
+@pytest.mark.parametrize("lanes", [None, 4], ids=["single", "vmap4"])
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+@pytest.mark.parametrize("kind,dtype", FOLDS)
+def test_scan_fold_matches_the_scatter(kind, dtype, shape, lanes):
+    rows, ptr, ids = _csr(shape)
+    ep = ids.shape[0]
+    vals = _values(kind, dtype, (ep,) if lanes is None else (lanes, ep),
+                   seed=ep + rows)
+
+    def scan_one(v):
+        return segment_reduce(v, jnp.asarray(ids), rows, kind,
+                              row_ptr=jnp.asarray(ptr))
+
+    def scatter(v):
+        return segment_reduce(v, jnp.asarray(ids), rows, kind)
+
+    scan = scan_one
+    if lanes is not None:
+        scan, scatter = jax.vmap(scan_one), jax.vmap(scatter)
+    float_sum = kind == "sum" and dtype != "int32"
+    before = FOLD_STATS.snapshot()
+    got = np.asarray(jax.jit(scan)(vals))
+    # lanes over one CSR keep the scatter where the fold is exact; a
+    # float sum's lanes scan
+    took = "scan" if lanes is None or float_sum else "scatter"
+    assert FOLD_STATS.snapshot() == {**before, took: before[took] + 1}
+    want = np.asarray(jax.jit(scatter)(vals))
+    assert got.dtype == want.dtype and got.shape == want.shape
+    if float_sum and lanes is not None:
+        # each lane with the bytes of its own single call
+        one = jax.jit(scan_one)
+        assert got.tobytes() == np.stack(
+            [np.asarray(one(v)) for v in vals]).tobytes()
+    if float_sum:
+        # groups by tile, so not the scatter's bits: an f64 fold of the
+        # same addends is the judge (empty rows hold 0, as reduceat
+        # cannot say)
+        v64 = np.asarray(vals, np.float64).reshape(-1, ep)
+        full = np.nonzero(np.diff(ptr))[0]
+        ref = np.zeros((v64.shape[0], rows))
+        ref[:, full] = np.add.reduceat(
+            v64[:, :ptr[-1]], ptr[full], axis=1)
+        np.testing.assert_allclose(
+            got.reshape(-1, rows), ref, rtol=1e-6, atol=0)
+        np.testing.assert_array_equal(got == 0, want == 0)
+    else:
+        assert got.tobytes() == want.tobytes()
+
+
+@pytest.mark.parametrize("kind,sorted_ids", [
+    ("sum", True), ("min", True), ("max", True), ("prod", True),
+    ("min", False),
+])
+def test_no_row_ptr_lowers_to_the_scatter_as_before(kind, sorted_ids):
+    """Without `row_ptr` the function lowers to the text it lowered to
+    before it knew another fold (the old body, spelled out here)."""
+    rows, ep = 37, 2 * T
+
+    def before(values, segment_ids):
+        with jax.named_scope("grape.pull.fold"):
+            out = _SCATTER[kind](
+                values, segment_ids, num_segments=rows + 1,
+                indices_are_sorted=sorted_ids,
+            )
+            return out[:rows]
+
+    def fold(values, segment_ids):
+        return segment_reduce(values, segment_ids, rows, kind,
+                              sorted_ids=sorted_ids)
+
+    before.__name__ = before.__qualname__ = "fold"
+    args = (jax.ShapeDtypeStruct((ep,), jnp.float32),
+            jax.ShapeDtypeStruct((ep,), jnp.int32))
+    assert (jax.jit(fold).lower(*args).as_text()
+            == jax.jit(before).lower(*args).as_text())
+
+
+# ---- which fold each round takes ------------------------------------------
+
+QUERY = {"pagerank": {}, "sssp": {"source": 6}, "bfs": {"source": 6},
+         "wcc": {}}
+
+
+def _folds_traced(worker, **query_args) -> dict:
+    """FOLD_STATS' rise over the tracing of `worker`'s fused runner."""
+    frag = worker.fragment
+    state = worker._place_state(worker.app.init_state(frag, **query_args))
+    eph = frozenset(getattr(worker.app, "ephemeral_keys", ()) or ())
+    carry = {k: v for k, v in state.items() if k not in eph}
+    eph_part = {k: v for k, v in state.items() if k in eph}
+    before = FOLD_STATS.snapshot()
+    worker._runner_for(0, state).lower(frag.dev, carry, eph_part)
+    return {k: v - before[k] for k, v in FOLD_STATS.snapshot().items()}
+
+
+@pytest.mark.parametrize("fnum", [1, 4])
+@pytest.mark.parametrize("app", sorted(QUERY))
+def test_whole_csr_pulls_fold_by_scan(app, fnum, graph_cache):
+    """The four pulls over a whole CSR hand over its offsets: one scan
+    in the round (the undirected graph has one pull), no scatter."""
+    w = Worker(APP_REGISTRY[app](), graph_cache(fnum))
+    assert _folds_traced(w, **QUERY[app]) == {"scan": 1, "scatter": 0}
+
+
+@pytest.mark.parametrize("app,took", [
+    ("sssp", "scatter"), ("bfs", "scatter"), ("pagerank", "scan"),
+])
+def test_query_lanes_fold_by_kind(app, took, graph_cache):
+    """The batched runner's lanes share one CSR under `jax.vmap`.  An
+    exact fold keeps the scatter XLA fuses their gather into; a float
+    sum scans, as each lane's single query does (tests/test_serve.py
+    pins the bytes)."""
+    w = Worker(APP_REGISTRY[app](), graph_cache(1))
+    before = FOLD_STATS.snapshot()
+    w.query_batch([{"source": s} for s in (6, 17, 5229, 31)])
+    after = FOLD_STATS.snapshot()
+    assert {k: after[k] - before[k] for k in after} == {
+        "scan": 0, "scatter": 0, took: 1}
+
+
+def test_row_ptr_with_unsorted_ids_is_refused():
+    rows, ptr, ids = _csr("pad_tail")
+    with pytest.raises(ValueError, match="sorted"):
+        segment_reduce(jnp.zeros(ids.shape[0]), jnp.asarray(ids), rows,
+                       "min", sorted_ids=False, row_ptr=jnp.asarray(ptr))
+
+
+@pytest.mark.parametrize("app", ["sssp", "bfs", "wcc"])
+def test_dyn_overlay_folds_by_scatter(app):
+    """The overlay's slots come sorted but without offsets: its fold
+    stays a scatter beside the base CSR's scan."""
+    from libgrape_lite_tpu.dyn import DynGraph, RepackPolicy
+    from tests.test_dyn import ADDS, build_graph
+
+    dg = DynGraph(build_graph(1), RepackPolicy(threshold=0.9, capacity=64))
+    assert dg.ingest(ADDS)["mode"] == "overlay"
+    w = Worker(APP_REGISTRY[app](), dg.fragment)
+    kw = {} if app == "wcc" else {"source": 0}
+    assert _folds_traced(w, **kw) == {"scan": 1, "scatter": 1}
+
+
+@pytest.mark.parametrize("app", ["sssp", "bfs", "wcc"])
+def test_pipelined_slices_fold_by_scatter(app, monkeypatch):
+    """The boundary and interior slices of a pipelined round have no
+    offsets of their own: two scatters, no scan, and (min being exact
+    under any grouping) the bytes of the serial round's scan."""
+    from tests.test_pipeline import _rand_frag, _run
+
+    frag = _rand_frag(2)
+    monkeypatch.setenv("GRAPE_PIPELINE", "force")
+    w = Worker(APP_REGISTRY[app](), frag)
+    kw = {} if app == "wcc" else {"source": 0}
+    assert _folds_traced(w, **kw) == {"scan": 0, "scatter": 2}
+    assert w.app._pipeline is not None
+    serial, _, _ = _run(app, frag, monkeypatch, "0")
+    piped, _, _ = _run(app, frag, monkeypatch, "force")
+    assert piped == serial
+
+
+def test_pipelined_pagerank_declines_with_its_reason(monkeypatch):
+    """A float sum that groups by tile regroups under a split, so
+    PageRank declines the pipeline and runs its serial round."""
+    from libgrape_lite_tpu.parallel.pipeline import PIPELINE_STATS
+    from tests.test_pipeline import _rand_frag, _run
+
+    frag = _rand_frag(2)
+    serial, _, _ = _run("pagerank", frag, monkeypatch, "0")
+    piped, _, app = _run("pagerank", frag, monkeypatch, "force")
+    assert app._pipeline is None
+    assert piped == serial
+    decision = PIPELINE_STATS["last_decision"]
+    assert decision["app"] == "PageRank" and not decision["engaged"]
+    assert "tile partial sums regroup under a split" in decision["reason"]
