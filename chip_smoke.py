@@ -62,8 +62,9 @@ RULES = {
 }
 NOT_RUN = {
     "cdlp_at_size": "its two runs were 150-300 s of an earlier smoke at "
-                    "scale 21-22; CDLP's sort is ROADMAP S4's subject. "
-                    "Exact on p2p-31 in Stage A",
+                    "scale 21-22; the benchmark cell g500-cdlp.cdlp-10r "
+                    "runs and checks it at size (PERF.md). Exact on "
+                    "p2p-31 in Stage A",
     "lcc_at_size": "docs/SCALE_NOTES.md sizes its ELL past one chip at "
                    "scale 22 and nothing smaller is sized (ROADMAP "
                    "R1/S5). Exact on p2p-31 in Stages A and C",

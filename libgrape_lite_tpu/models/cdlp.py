@@ -23,10 +23,12 @@ contribute multiplicity exactly like the reference's neighbor scan.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from libgrape_lite_tpu.app.base import ParallelAppBase, StepContext
+from libgrape_lite_tpu.ops.segment import pull_gather
 from libgrape_lite_tpu.utils.types import LoadStrategy, MessageStrategy
 
 class CDLP(ParallelAppBase):
@@ -99,7 +101,42 @@ class CDLP(ParallelAppBase):
         both pipelined parts (the fold only ever groups edges of equal
         src, so any edge subset CLOSED over destination rows — the
         full set, the boundary part, the interior part — yields the
-        per-row result of the full fold for the rows it covers)."""
+        per-row result of the full fold for the rows it covers).
+
+        Named for the device trace (metadata only, like the pull's):
+        `grape.cdlp.universe` on the distinct-label predicate of the
+        dynamic branch, `grape.cdlp.sort` on key building, the sort
+        (whichever branch) and the decode to `(ss, ll)`,
+        `grape.cdlp.count` on the run-length pass around the three
+        folds, which keep `grape.pull.fold`."""
+        with jax.named_scope("grape.cdlp.sort"):
+            ss, ll = self._sorted_pairs(src, lab, full, lut, vp)
+        dt = lab.dtype
+        big = jnp.asarray(np.iinfo(np.dtype(dt).name).max, dt)
+        with jax.named_scope("grape.cdlp.count"):
+            valid = ss != jnp.int32(vp)
+            first = jnp.ones_like(ss, dtype=bool).at[1:].set(
+                jnp.logical_or(ss[1:] != ss[:-1], ll[1:] != ll[:-1])
+            )
+            run_id = jnp.cumsum(first.astype(jnp.int32)) - 1
+        run_len = self.segment_reduce(
+            valid.astype(jnp.int32), run_id, ss.shape[0], "sum"
+        )  # runs <= E, so size the table with E rows — when every
+        # (src,label) pair is distinct, run_id reaches e-1 and must not
+        # land in the sliced-off overflow segment
+        with jax.named_scope("grape.cdlp.count"):
+            c_e = run_len[run_id]
+        cmax = self.segment_reduce(c_e, ss, vp, "max")
+        with jax.named_scope("grape.cdlp.count"):
+            is_best = jnp.logical_and(
+                valid, c_e == cmax[jnp.minimum(ss, vp - 1)]
+            )
+            cand = jnp.where(is_best, ll, big)
+        return self.segment_reduce(cand, ss, vp, "min")
+
+    def _sorted_pairs(self, src, lab, full, lut, vp):
+        """The (src, label) pairs in lexicographic order, by whichever
+        of the three sorts the shapes admit."""
         dt = lab.dtype
         big = jnp.asarray(np.iinfo(np.dtype(dt).name).max, dt)
         n_pad = full.shape[0]
@@ -131,7 +168,8 @@ class CDLP(ParallelAppBase):
                 jnp.minimum(key & jnp.uint32((1 << rank_bits) - 1),
                             jnp.uint32(n_pad)).astype(jnp.int32)
             ]
-        elif 32 - src_bits >= 10 and not self._force_wide:
+            return ss, ll
+        if 32 - src_bits >= 10 and not self._force_wide:
             # Dynamic label-universe compression (VERDICT r4 next #2;
             # reference XL-graph counterpart: cdlp_opt.h): when the
             # STATIC universe (n_pad ids) outgrows the 32-bit pack, the
@@ -157,9 +195,10 @@ class CDLP(ParallelAppBase):
             # by scatter into the static lut positions — O(n_pad)
             # searchsorted + scatter, no sort.  The universe SORT runs
             # inside the packed branch only.
-            pos = jnp.searchsorted(lut, full)
-            mark = jnp.zeros((n_pad + 1,), jnp.int32).at[pos].set(1)
-            n_distinct = mark.sum()
+            with jax.named_scope("grape.cdlp.universe"):
+                pos = jnp.searchsorted(lut, full)
+                mark = jnp.zeros((n_pad + 1,), jnp.int32).at[pos].set(1)
+                n_distinct = mark.sum()
 
             def _packed(args):
                 src, lab, full = args
@@ -181,32 +220,13 @@ class CDLP(ParallelAppBase):
                 ]
                 return ss, ll
 
-            ss, ll = jlax.cond(
+            return jlax.cond(
                 n_distinct <= jnp.int32(u_budget), _packed,
                 lambda args: _wide(args[0], args[1]), (src, lab, full),
             )
-        else:
-            # wide path (vertices/shard beyond even the dynamic pack,
-            # or forced): see _wide
-            ss, ll = _wide(src, lab)
-        valid = ss != jnp.int32(vp)
-
-        first = jnp.ones_like(ss, dtype=bool).at[1:].set(
-            jnp.logical_or(ss[1:] != ss[:-1], ll[1:] != ll[:-1])
-        )
-        run_id = jnp.cumsum(first.astype(jnp.int32)) - 1
-        e = ss.shape[0]
-        run_len = self.segment_reduce(
-            valid.astype(jnp.int32), run_id, e, "sum"
-        )  # runs <= E, so size the table with e rows — when every
-        # (src,label) pair is distinct, run_id reaches e-1 and must not
-        # land in the sliced-off overflow segment
-        c_e = run_len[run_id]
-
-        cmax = self.segment_reduce(c_e, ss, vp, "max")
-        is_best = jnp.logical_and(valid, c_e == cmax[jnp.minimum(ss, vp - 1)])
-        cand = jnp.where(is_best, ll, big)
-        return self.segment_reduce(cand, ss, vp, "min")
+        # wide path (vertices/shard beyond even the dynamic pack, or
+        # forced): see _wide
+        return _wide(src, lab)
 
     def _propagate(self, ctx, frag, labels, lut):
         oe = frag.oe
@@ -215,13 +235,17 @@ class CDLP(ParallelAppBase):
         big = jnp.asarray(np.iinfo(np.dtype(dt).name).max, dt)
 
         full = ctx.gather_state(labels)
-        lab = jnp.where(oe.edge_mask, full[oe.edge_nbr], big)
-        src = jnp.where(oe.edge_mask, oe.edge_src, jnp.int32(vp))
+        lab = pull_gather(full, oe.edge_nbr, mask=oe.edge_mask, fill=big)
+        with jax.named_scope("grape.cdlp.sort"):
+            src = jnp.where(oe.edge_mask, oe.edge_src, jnp.int32(vp))
         new_lab = self._mode_fold(src, lab, full, lut, vp)
 
-        has_out = frag.out_degree > 0
-        keep = jnp.logical_or(~frag.inner_mask, ~has_out)
-        return jnp.where(jnp.logical_or(keep, new_lab == big), labels, new_lab)
+        with jax.named_scope("grape.app.update"):
+            has_out = frag.out_degree > 0
+            keep = jnp.logical_or(~frag.inner_mask, ~has_out)
+            return jnp.where(
+                jnp.logical_or(keep, new_lab == big), labels, new_lab
+            )
 
     def inceval_pipelined(self, ctx: StepContext, frag, state, xbuf):
         """Double-buffered round (parallel/pipeline.py, r9): fold the
@@ -242,8 +266,8 @@ class CDLP(ParallelAppBase):
         has_out = frag.out_degree > 0
         keep = jnp.logical_or(~frag.inner_mask, ~has_out)
         full = pl.splice(ctx, labels, state, xbuf)
-        lab_b = jnp.where(
-            state["pl_b_val"], full[state["pl_b_nbr"]], big
+        lab_b = pull_gather(
+            full, state["pl_b_nbr"], mask=state["pl_b_val"], fill=big
         )
         fold_b = self._mode_fold(
             state["pl_b_src"], lab_b, full, lut, vp
@@ -254,8 +278,8 @@ class CDLP(ParallelAppBase):
         xbuf2 = pl.kickoff(ctx, jnp.where(bmask, new_b, labels), state)
         # ---- pipelined window: carry reads below are named in
         # parallel/pipeline.PIPELINE_WINDOW_READS (grape-lint R6) ----
-        lab_i = jnp.where(
-            state["pl_i_val"], full[state["pl_i_nbr"]], big
+        lab_i = pull_gather(
+            full, state["pl_i_nbr"], mask=state["pl_i_val"], fill=big
         )
         fold_i = self._mode_fold(
             state["pl_i_src"], lab_i, full, lut, vp
@@ -362,7 +386,7 @@ class CDLPOpt(CDLP):
         dt = labels.dtype
         big = jnp.asarray(np.iinfo(np.dtype(dt).name).max, dt)
         full = ctx.gather_state(labels)
-        cand = jnp.where(oe.edge_mask, full[oe.edge_nbr], big)
+        cand = pull_gather(full, oe.edge_nbr, mask=oe.edge_mask, fill=big)
         mn = self.segment_reduce(cand, oe.edge_src, frag.vp, "min")
         has_out = frag.out_degree > 0
         keep = jnp.logical_or(~frag.inner_mask, ~has_out)
