@@ -20,7 +20,10 @@ calls it drives:
            every vertex against a plain NumPy/SciPy reference; then a
            `ServeSession` answers 4 SSSP + 4 BFS point queries.
   Stage C  the two Pallas kernels a default path reaches on a TPU
-           (strict-tile SpMV, bitmap intersect), compiled, on p2p-31.
+           (strict-tile SpMV, bitmap intersect), compiled, on p2p-31;
+           and the pull's gather (`ops/segment.pull_gather`, which
+           every stage's pulls already went through) alone, compiled,
+           against XLA's `full[nbr]` on a four-chip shard's shapes.
 
 Any failure raises: nothing is caught and continued.  The walls it
 prints are set-up and health readings, never a benchmark metric.
@@ -196,6 +199,13 @@ def pallas_spy():
         yield seen
     finally:
         pl.pallas_call = real
+
+
+def spy_summary(calls: list) -> dict:
+    """What `pallas_spy` saw, in a stage's terms."""
+    return {"pallas_calls": len(calls),
+            "interpret": any(calls) if calls else None,
+            "compiled": bool(calls) and not any(calls)}
 
 
 def device_memory() -> list:
@@ -455,11 +465,48 @@ def stage_c(on_tpu: bool) -> dict:
             require(not any(calls), f"stage C {kernel}: ran interpreted")
         out[kernel] = {
             "ok": True, "app": name, "check": check_golden(golden, result),
-            "pallas_calls": len(calls),
-            "interpret": any(calls) if calls else None,
-            "compiled": bool(calls) and not any(calls), "cold_wall_s": wall,
+            **spy_summary(calls), "cold_wall_s": wall,
         }
         log(f"C {kernel}: {out[kernel]}")
+    out["vmem_gather"] = gather_check(on_tpu)
+    return out
+
+
+def gather_check(on_tpu: bool) -> dict:
+    """`pull_gather` as the apps call it, on the shapes of the x4
+    cell's shard (17,192,832 indices, a ragged last block, into the
+    mirror exchange's 1,388,544 places): on the TPU the kernel must be
+    what was traced, compiled, and its output `full[nbr]` bit for bit,
+    the ends of the index range and beyond included.  Off it (a
+    rehearsal) the choice is XLA's gather and no kernel is expected."""
+    import jax
+    import jax.numpy as jnp
+
+    from libgrape_lite_tpu.ops.segment import GATHER_STATS, pull_gather
+
+    v, n = 1_388_544, 134_319 * 128
+    rng = np.random.default_rng(27)
+    nbr = rng.integers(0, v, n).astype(np.int32)
+    nbr[:6] = [0, v - 1, -1, -v, v, np.iinfo(np.int32).max]
+    out = {"ok": True, "indices": n, "table": v}
+    before = GATHER_STATS.snapshot()
+    with pallas_spy() as calls:
+        for dtype in (np.float32, np.int32):
+            full = jnp.asarray(rng.integers(-9, 9, v).astype(dtype))
+            (got, wall) = timed(jax.jit(pull_gather), full, nbr)
+            require(bool(jnp.array_equal(got, full[jnp.asarray(nbr)])),
+                    f"stage C vmem_gather: {np.dtype(dtype).name} output "
+                    "is not full[nbr]")
+            out[f"{np.dtype(dtype).name}_cold_wall_s"] = wall
+    took = GATHER_STATS.snapshot()
+    out["took"] = {k: took[k] - before[k] for k in took}
+    if on_tpu:
+        require(out["took"] == {"kernel": 2, "xla": 0},
+                f"stage C vmem_gather: the choice was {out['took']}")
+        require(len(calls) == 2 and not any(calls),
+                "stage C vmem_gather: not compiled")
+    out.update(spy_summary(calls))
+    log(f"C vmem_gather: {out}")
     return out
 
 

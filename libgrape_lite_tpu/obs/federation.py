@@ -55,6 +55,7 @@ EXPECTED: Dict[str, str] = {
     "vc_tiles": "libgrape_lite_tpu.fragment.vertexcut",
     "gang": "libgrape_lite_tpu.obs.gang",
     "fold": "libgrape_lite_tpu.ops.segment",
+    "gather": "libgrape_lite_tpu.ops.segment",
 }
 
 

@@ -14,6 +14,12 @@ VPU — no HBM round-trip for the intermediate AND, which is what the
 `jnp` path materialises.  `row_and_popcount` takes the compiled kernel
 when `use_pallas()` (the TPU backend) and the fused jnp path anywhere
 else; tests exercise the kernel in interpret mode.
+
+`vmem_gather` is the pull's `full[nbr]` from a table that stays in VMEM
+for the length of the call: XLA's gather steps through its indices one
+at a time, twelve bundles an element whatever the table's size, where
+one vector load of a table row per index is the work.
+`ops/segment.pull_gather` chooses between the two.
 """
 
 from __future__ import annotations
@@ -97,3 +103,157 @@ def use_pallas() -> bool:
     callers take the fused jnp path, or interpret mode where a caller
     asks for the kernel by name."""
     return jax.default_backend() == "tpu"
+
+
+# ---- the pull's gather from a table resident in VMEM ----------------------
+
+LANES = 128
+SUBLANES = 8
+# A chunk is one output vreg, 8 x 128 indices, and one SMEM slot; two
+# slots are two copies of the unrolled body, which is what the kernel
+# costs to compile (4-5 s).  Chunk pairs per grid step: 32,768 indices,
+# so that the one DMA wait a step leaves exposed, and the step itself,
+# are under 2% of it
+_PAIRS = 16
+
+
+def gather_table_budget() -> int:
+    """Bytes of table `vmem_gather` may hold in VMEM: half of what the
+    device reports (`pltpu.get_tpu_info()`; 64 MiB of the v5e's 128).
+    The kernel asks for the table and 4 MiB of blocks as its scoped
+    limit, and the other half stays XLA's, which keeps a shard's fold
+    levels and CDLP's loop operands there (PERF.md section 5).  A
+    Graph500 scale-22 table is 16 MiB, scale 24 exactly the budget;
+    what is larger takes XLA's gather."""
+    return pltpu.get_tpu_info().vmem_capacity_bytes // 2
+
+
+def _gather_kernel(idx_ref, tab_hbm, out_ref, tab, tab_sem, stage, rows,
+                   sems, *, v: int, pairs: int):
+    """One grid step: `pairs` times two chunks of 1024 indices.
+
+    Per index the work is one `(1, 128)` load of table row `idx >> 7`,
+    replicated over the sublanes (`vld` with sublane stride 0), and one
+    select that puts it in its sublane; per eight indices one in-vreg
+    lane gather by `idx & 127` (a single source vreg, which the chip's
+    compiler accepts) and one select into the output vreg.  The address
+    comes from a scalar, and the scalar slots are what bounded the
+    first formulations (two a bundle): so the row numbers are taken in
+    vregs, a chunk at a time, and moved VMEM -> SMEM by a local DMA
+    into one of two slots, whose offsets are static (`sld` takes one
+    register, and a slot's base or a loop's counter would use it).
+    What is left an index is `sld`, the table's `scalar_lea` and the
+    `vld`: 1.08 bundles, the one load slot being the limit (0.80 ns at
+    Graph500 scale 21 against 8.61, PERF.md section 6)."""
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        # once per call, one buffer: the table stays for every step
+        cp = pltpu.make_async_copy(tab_hbm, tab, tab_sem)
+        cp.start()
+        cp.wait()
+
+    def norm(i):
+        # `full[nbr]`'s own rule, so that the two agree on every int32:
+        # a negative index counts from the end, what is still outside
+        # is clamped.  It also keeps the rows a ragged last block reads
+        # inside the table.
+        i = jnp.where(i < 0, i + v, i)
+        return jnp.clip(i, 0, v - 1)
+
+    def slot_copy(slot):
+        return pltpu.make_async_copy(
+            stage.at[slot], rows.at[slot], sems.at[slot])
+
+    def send(slot, r0):
+        stage[slot] = norm(idx_ref[pl.ds(r0, SUBLANES), :]) >> 7
+        slot_copy(slot).start()
+
+    lane = lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 1)
+    sub = lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 0)
+
+    def chunk(slot, r0):
+        lanes = norm(idx_ref[pl.ds(r0, SUBLANES), :]) & (LANES - 1)
+
+        def lane_step(j, acc):
+            t = jnp.zeros((SUBLANES, LANES), tab.dtype)
+            for s in range(SUBLANES):
+                t = jnp.where(sub == s, tab[pl.ds(rows[slot, s, j], 1), :],
+                              t)
+            return jnp.where(lane == j,
+                             jnp.take_along_axis(t, lanes, axis=1), acc)
+
+        # unrolled where the kernel is lowered, not where it is traced:
+        # `j` becomes a constant there, so the SMEM offsets are static,
+        # and the trace holds one step, not 128 (traced out in Python
+        # the body cost every process 24 s on the chip's host)
+        out_ref[pl.ds(r0, SUBLANES), :] = lax.fori_loop(
+            0, LANES, lane_step,
+            jnp.zeros((SUBLANES, LANES), tab.dtype), unroll=True)
+
+    send(0, 0)
+
+    def pair(k, carry):
+        ra = pl.multiple_of(k * (2 * SUBLANES), SUBLANES)
+        rb = pl.multiple_of(ra + SUBLANES, SUBLANES)
+        slot_copy(0).wait()
+        send(1, rb)
+        chunk(0, ra)
+        slot_copy(1).wait()
+
+        @pl.when(k + 1 < pairs)
+        def _():
+            send(0, pl.multiple_of(rb + SUBLANES, SUBLANES))
+
+        chunk(1, rb)
+        return carry
+
+    lax.fori_loop(0, pairs, pair, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def vmem_gather(full, nbr, interpret: bool = False):
+    """`full[nbr]` for a 1-D 32-bit table and int32 indices, bit for
+    bit, on every int32 index.
+
+    The table, viewed `[ceil(V / 128), 128]`, is copied to VMEM once
+    and stays; the indices stream through in `[256, 128]` blocks and
+    the output leaves in the same blocks (2-D: the chip's compiler
+    refuses 1-D ones).  The last block may be ragged; a stream that is
+    not whole 128s is padded first, which a CSR's never is."""
+    v, n = full.shape[0], nbr.shape[0]
+    vpad, npad = -v % LANES, -n % LANES
+    tab = (jnp.pad(full, (0, vpad)) if vpad else full).reshape(-1, LANES)
+    idx = (jnp.pad(nbr, (0, npad)) if npad else nbr).reshape(-1, LANES)
+    nrows = idx.shape[0]
+    pairs = min(_PAIRS, pl.cdiv(nrows, 2 * SUBLANES))
+    blk = 2 * SUBLANES * pairs
+    out = pl.pallas_call(
+        functools.partial(_gather_kernel, v=v, pairs=pairs),
+        grid=(pl.cdiv(nrows, blk),),
+        in_specs=[
+            pl.BlockSpec((blk, LANES), lambda g: (g, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((blk, LANES), lambda g: (g, 0)),
+        # inside a `shard_map` that checks them, the output varies over
+        # the mesh axes its operands vary over
+        out_shape=jax.ShapeDtypeStruct(
+            (nrows, LANES), full.dtype,
+            vma=jax.typeof(idx).vma | jax.typeof(tab).vma),
+        scratch_shapes=[
+            pltpu.VMEM(tab.shape, tab.dtype),
+            pltpu.SemaphoreType.DMA(()),
+            pltpu.VMEM((2, SUBLANES, LANES), jnp.int32),
+            pltpu.SMEM((2, SUBLANES, LANES), jnp.int32),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            # the table is primed at step 0 and carried
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=tab.size * 4 + (4 << 20),
+        ),
+        interpret=interpret,
+        name="vmem_gather",
+    )(idx, tab)
+    out = out.reshape(-1)
+    return out[:n] if npad else out

@@ -22,12 +22,27 @@ exact fold under `jax.vmap`, whose gather XLA fuses into the scatter.
 A float sum's lanes scan, as their single queries do: its bits depend
 on the grouping, and a lane answers with its single query's bytes.
 
+The module makes a third choice, in `pull_gather`: how `full[nbr]` is
+read.  XLA's gather also steps through its indices one at a time (8.6
+ns an index at scale 10 as at scale 21, whatever the element is and
+wherever it reads), while the table is a sixteenth of the chip's VMEM
+or less.  So on the TPU backend a 1-D 32-bit table that fits half of
+VMEM is gathered by `ops/pallas_kernels.vmem_gather`, which keeps the
+table in VMEM for the length of the call and streams the indices
+through it (0.80 ns an index).  Everything else keeps `full[nbr]`:
+other backends, 64-bit tables, tables of rows, tables over the budget,
+and the query lanes of a batched call under `jax.vmap`, whose gather
+XLA fuses into their scatter.  What the choice observes is the
+backend, the arguments' shapes and dtypes and the device's VMEM size;
+no option selects it.  GATHER_STATS counts which gather each call
+took, as FOLD_STATS does for the fold (docs/OBSERVABILITY.md).
+
 The two halves of a pull carry `jax.named_scope` names, which reach the
 device trace as the operations' `tf_op` (metadata only: the compiled
 program is the same with and without them): `grape.pull.gather` on the
-E-wide gather, `grape.pull.fold` on the segment fold, by scan or by
-scatter.  A fusion takes its root's name, so where XLA fuses the
-gather into the fold the whole fusion reads as the fold.
+E-wide gather, by kernel or by XLA, `grape.pull.fold` on the segment
+fold, by scan or by scatter.  A fusion takes its root's name, so where
+XLA fuses the gather into the fold the whole fusion reads as the fold.
 """
 
 from __future__ import annotations
@@ -39,6 +54,63 @@ from jax import lax
 from jax.custom_batching import custom_vmap
 
 from libgrape_lite_tpu.obs.federation import FederatedStats as _FedStats
+from libgrape_lite_tpu.ops.pallas_kernels import (
+    gather_table_budget,
+    use_pallas,
+    vmem_gather,
+)
+
+
+# which gather each `pull_gather` call took, counted where it is
+# decided: at trace time, once per call site per traced program
+GATHER_STATS = _FedStats("gather", {"kernel": 0, "xla": 0})
+
+
+def _recount(stats, took: list, to: str) -> None:
+    """Move a call's one entry in `stats` from `took[0]` to `to`: once,
+    however often a `vmap` rule runs for the call (a loop's batching
+    rule may run it again)."""
+    if took[0] != to:
+        stats[took[0]] -= 1
+        stats[to] += 1
+        took[0] = to
+
+
+def _kernel_gathers(full, nbr) -> bool:
+    """Whether `full[nbr]` goes through `pallas_kernels.vmem_gather`:
+    on the TPU backend, for a 1-D 32-bit table that fits the kernel's
+    VMEM budget and a 1-D int32 stream.  Everything here is read off
+    the arguments and the backend at trace time."""
+    return (
+        use_pallas()
+        and full.ndim == 1 and nbr.ndim == 1 and nbr.shape[0] > 0
+        and full.dtype.itemsize == 4 and nbr.dtype == jnp.int32
+        and 0 < full.size * 4 <= gather_table_budget()
+    )
+
+
+def _kernel_gather():
+    """The gather of one `pull_gather` call that chose the kernel, with
+    its own rule under `jax.vmap`.  Made anew for each call, because it
+    carries the call's entry in GATHER_STATS."""
+    took = ["kernel"]
+    GATHER_STATS["kernel"] += 1
+
+    @custom_vmap
+    def gather(full, nbr):
+        return vmem_gather(full, nbr)
+
+    @gather.def_vmap
+    def lanes(axis_size, in_batched, full, nbr):
+        # Query lanes (the batched runner) keep XLA's gather: it writes
+        # all lanes of an entry at once and XLA fuses it into the
+        # lanes' scatter fold; a standalone [Ep, lanes] block is 4.4 GB
+        # at four lanes and Ep 8.4M (PERF.md section 6, PR 25 (3))
+        _recount(GATHER_STATS, took, "xla")
+        axes = tuple(0 if b else None for b in in_batched)
+        return jax.vmap(lambda f, i: f[i], in_axes=axes)(full, nbr), True
+
+    return gather
 
 
 def pull_gather(full, nbr, mask=None, fill=None, add=None, absent=None):
@@ -47,9 +119,18 @@ def pull_gather(full, nbr, mask=None, fill=None, add=None, absent=None):
     `full[nbr]`, plus `add` (a per-entry weight or a constant) where
     given, with `fill` where `mask` is false or, where `absent` is
     given, where the neighbour holds that sentinel and proposes
-    nothing."""
+    nothing.
+
+    Two gathers, chosen by what the call can see (`_kernel_gathers`):
+    the Pallas kernel that keeps the table in VMEM, or XLA's gather,
+    which is also what query lanes under `jax.vmap` go back to.  Both
+    move the same bits.  GATHER_STATS counts which one a call took."""
     with jax.named_scope("grape.pull.gather"):
-        vals = full[nbr]
+        if _kernel_gathers(full, nbr):
+            vals = _kernel_gather()(full, nbr)
+        else:
+            GATHER_STATS["xla"] += 1
+            vals = full[nbr]
         ok = mask
         if absent is not None:
             ok = vals != absent if ok is None else jnp.logical_and(
@@ -183,12 +264,7 @@ def _scan_fold(num_rows: int, kind: str):
         # minor and padded to 128: 4.4 GB where the scatter's round
         # holds 0.27 (four lanes, Ep 8.4M; the chip's compiler,
         # PERF.md section 6)
-        if took[0] == "scan":
-            # moved once, however often the rule runs for this call (a
-            # loop's batching rule may run it again)
-            took[0] = "scatter"
-            FOLD_STATS["scan"] -= 1
-            FOLD_STATS["scatter"] += 1
+        _recount(FOLD_STATS, took, "scatter")
         return jax.vmap(
             lambda v: _scatter_fold(v, segment_ids, num_rows, kind, True)
         )(values), True

@@ -56,6 +56,9 @@ def test_smoke_rehearsal_runs_every_stage():
     # intersect kernel's dispatcher takes the jnp path
     assert s["stage_c"]["spmv_strict"]["interpret"] is True
     assert s["stage_c"]["intersect_count"]["pallas_calls"] == 0
+    # and the pull's gather is XLA's, checked against itself
+    assert s["stage_c"]["vmem_gather"]["pallas_calls"] == 0
+    assert s["stage_c"]["vmem_gather"]["took"] == {"kernel": 0, "xla": 2}
     assert not any(v["compiled"] for v in s["stage_c"].values())
 
 

@@ -170,3 +170,57 @@ def test_legacy_kernels_lower_for_tpu():
     assert "SPMV_STRICT_LOWERED" in r.stdout
     assert "INTERSECT_LOWERED_128" in r.stdout
     assert "INTERSECT_LOWERED_197" in r.stdout
+
+
+# the pull's gather at the cells' shapes: Graph500 scale 21 on one
+# chip, and a four-chip shard, whose stream is whole 128s but not whole
+# blocks and whose table is the mirror exchange's compact one
+SCRIPT3 = r"""
+import jax
+import jax.numpy as jnp
+
+import sys
+sys.path.insert(0, %(repo)r)
+
+from libgrape_lite_tpu.ops.pallas_kernels import vmem_gather
+
+for name, v, n in (("S21", 2097152, 67108864),
+                   ("X4", 1388544, 17192832)):
+    for dt in (jnp.float32, jnp.int32):
+        low = jax.jit(vmem_gather).trace(
+            jax.ShapeDtypeStruct((v,), dt),
+            jax.ShapeDtypeStruct((n,), jnp.int32),
+        ).lower(lowering_platforms=('tpu',))
+        assert "tpu_custom_call" in low.as_text()
+        print(f"VMEM_GATHER_LOWERED_{name}_{jnp.dtype(dt).name}",
+              len(low.as_text()))
+
+# per shard inside a shard_map that checks varying axes: the kernel's
+# output has to say over which it varies
+import numpy as np
+from jax.sharding import Mesh, PartitionSpec as P
+
+mesh = Mesh(np.array(jax.devices()[:4]), ("f",))
+for check in (True, False):
+    low = jax.jit(jax.shard_map(
+        lambda f, i: vmem_gather(f[0], i[0])[None], mesh=mesh,
+        in_specs=(P("f"), P("f")), out_specs=P("f"), check_vma=check,
+    )).trace(
+        jax.ShapeDtypeStruct((4, 1388544), jnp.float32),
+        jax.ShapeDtypeStruct((4, 17192832), jnp.int32),
+    ).lower(lowering_platforms=('tpu',))
+    assert "tpu_custom_call" in low.as_text()
+    print(f"VMEM_GATHER_LOWERED_SHARD_MAP_{check}")
+"""
+
+
+def test_vmem_gather_lowers_for_tpu():
+    r = _run_offline(
+        SCRIPT3 % {"repo": REPO},
+        XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-4000:]
+    assert "VMEM_GATHER_LOWERED_SHARD_MAP_True" in r.stdout
+    assert "VMEM_GATHER_LOWERED_SHARD_MAP_False" in r.stdout
+    for name in ("S21", "X4"):
+        for dt in ("float32", "int32"):
+            assert f"VMEM_GATHER_LOWERED_{name}_{dt}" in r.stdout

@@ -1,0 +1,262 @@
+"""The pull's gather by kernel or by XLA (`ops/segment.pull_gather`).
+
+On the TPU backend a 1-D 32-bit table that fits the VMEM budget is
+gathered by `ops/pallas_kernels.vmem_gather`; everything else, and the
+query lanes of a batched call under `jax.vmap`, by XLA's `full[nbr]`.
+Pinned here: the kernel (interpret mode) bit-equal to `full[nbr]` over
+the dtypes, table lengths and stream lengths it meets, on every kind of
+int32 index; the choice, through the trace-time counter
+`GATHER_STATS`; the `vmap` rule (lanes lower to the text they lowered
+to before, answer with their single calls' bytes and count once); and
+one app's whole query through the kernel on one and four fragments.
+The choice reads the backend, so the tests steer it here.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from libgrape_lite_tpu.models import APP_REGISTRY
+from libgrape_lite_tpu.ops import pallas_kernels, segment
+from libgrape_lite_tpu.ops.segment import GATHER_STATS, pull_gather
+from libgrape_lite_tpu.worker.worker import Worker
+
+# name -> (dtype, table length, stream length).  A CSR's stream is
+# whole 128s (the loader's rule) but not whole 1024s on a shard; the
+# dyn overlay's and a pipelined slice's may be anything.
+KERNEL_CASES = {
+    "f32_whole_1024s": ("float32", 40 * 128, 2 * 1024),
+    "s32_table_ragged_whole_128s": ("int32", 5000, 5 * 128),
+    "u32_both_ragged": ("uint32", 777, 1000),
+    "f32_one_row_table": ("float32", 128, 17 * 128),
+    # two grid steps, the second ragged: 300 rows of 128 in blocks of
+    # 256; the table must still be there in the second
+    "s32_two_steps": ("int32", 3000, 300 * 128),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel_case(name: str):
+    dtype, v, n = KERNEL_CASES[name]
+    rng = np.random.default_rng(v + n)
+    if dtype == "float32":
+        full = rng.standard_normal(v).astype(dtype)
+    else:
+        full = rng.integers(0, np.iinfo(dtype).max, v).astype(dtype)
+    nbr = rng.integers(0, v, n).astype(np.int32)
+    # both ends, a tile's edge, the loader's padding value (0), and
+    # what `full[nbr]` wraps or clamps
+    edge = np.asarray([0, v - 1, 127, 128 % v, -1, -v, -v - 1, v, v + 5,
+                       np.iinfo(np.int32).max, np.iinfo(np.int32).min],
+                      np.int32)
+    nbr[:edge.size] = edge
+    nbr[-1] = v - 1
+    got = pallas_kernels.vmem_gather(full, nbr, interpret=True)
+    return full, nbr, edge.size, np.asarray(got)
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_kernel_bit_equal_in_bounds(case):
+    full, nbr, edges, got = _kernel_case(case)
+    assert got.dtype == full.dtype and got.shape == nbr.shape
+    assert got[edges:].tobytes() == full[nbr[edges:]].tobytes()
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_kernel_follows_xla_at_the_edges(case):
+    """Negative indices count from the end and what is still outside
+    is clamped, as `full[nbr]` has it: the two agree on every int32."""
+    full, nbr, edges, got = _kernel_case(case)
+    want = np.asarray(jnp.asarray(full)[jnp.asarray(nbr[:edges])])
+    assert got[:edges].tobytes() == want.tobytes()
+    v = full.shape[0]
+    assert got[0] == full[0] and got[1] == full[v - 1]
+    assert got[4] == full[v - 1] and got[5] == full[0]
+
+
+# ---- the choice -----------------------------------------------------------
+
+BUDGET = 64 << 20
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """`pull_gather` as it chooses on the TPU backend; the kernel is a
+    stand-in that notes its calls (the choice is what is under test)."""
+    calls = []
+
+    def kernel(full, nbr):
+        calls.append((full.shape, nbr.shape))
+        return full[nbr]
+
+    monkeypatch.setattr(segment, "use_pallas", lambda: True)
+    monkeypatch.setattr(segment, "gather_table_budget", lambda: BUDGET)
+    monkeypatch.setattr(segment, "vmem_gather", kernel)
+    return calls
+
+
+def _took(fn) -> dict:
+    before = GATHER_STATS.snapshot()
+    fn()
+    return {k: v - before[k] for k, v in GATHER_STATS.snapshot().items()}
+
+
+CHOICES = {
+    # name: (table shape, table dtype, index dtype, what it takes)
+    "f32": ((4096,), "float32", "int32", "kernel"),
+    "s32": ((4096,), "int32", "int32", "kernel"),
+    "u32": ((4096,), "uint32", "int32", "kernel"),
+    "at_the_budget": ((BUDGET // 4,), "float32", "int32", "kernel"),
+    "over_the_budget": ((BUDGET // 4 + 1,), "float32", "int32", "xla"),
+    "f64": ((4096,), "float64", "int32", "xla"),
+    "s64": ((4096,), "int64", "int32", "xla"),
+    "bool": ((4096,), "bool", "int32", "xla"),
+    "f16": ((4096,), "float16", "int32", "xla"),
+    "rows_of_four": ((4096, 4), "float32", "int32", "xla"),
+    "i64_indices": ((4096,), "float32", "int64", "xla"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CHOICES))
+def test_choice_on_tpu(name, on_tpu):
+    shape, dtype, idtype, want = CHOICES[name]
+    full = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+    nbr = jax.ShapeDtypeStruct((512,), jnp.dtype(idtype))
+    took = _took(lambda: jax.eval_shape(
+        lambda f, i: pull_gather(f, i), full, nbr))
+    assert took == {"kernel": 0, "xla": 0, want: 1}
+    assert len(on_tpu) == (want == "kernel")
+
+
+@pytest.mark.parametrize("name", ["f32", "s32", "u32", "f64"])
+def test_choice_off_tpu_is_xla(name, monkeypatch):
+    """Every other backend keeps the parent's program, to the text."""
+    shape, dtype, idtype, _ = CHOICES[name]
+    monkeypatch.setattr(
+        segment, "vmem_gather",
+        lambda *a: pytest.fail("the kernel off the TPU backend"))
+    full = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+    nbr = jax.ShapeDtypeStruct((512,), jnp.dtype(idtype))
+    mask = jax.ShapeDtypeStruct((512,), jnp.bool_)
+    took = _took(lambda: jax.eval_shape(
+        lambda f, i: pull_gather(f, i), full, nbr))
+    assert took == {"kernel": 0, "xla": 1}
+
+    def parent(full, nbr, mask):
+        with jax.named_scope("grape.pull.gather"):
+            return jnp.where(mask, full[nbr] + 1, 0)
+
+    def text(pull):
+        return jax.jit(lambda *a: pull(*a)).lower(full, nbr, mask).as_text()
+
+    assert text(lambda f, i, m: pull_gather(f, i, m, 0, add=1)) == text(parent)
+
+
+def test_empty_stream_is_xla(on_tpu):
+    full = jnp.arange(256, dtype=jnp.float32)
+    took = _took(lambda: pull_gather(full, jnp.zeros((0,), jnp.int32)))
+    assert took == {"kernel": 0, "xla": 1}
+
+
+ARGS = {
+    "bare": {},
+    "mask_fill": {"mask": True, "fill": 7},
+    "add": {"add": 3},
+    "absent": {"absent": 5, "fill": -1},
+    "mask_absent_add": {"mask": True, "absent": 5, "add": 1, "fill": 9},
+}
+
+
+@pytest.mark.parametrize("args", sorted(ARGS))
+def test_what_follows_the_gather_is_the_same(args, on_tpu, monkeypatch):
+    """`mask`, `add`, `absent` and `fill` act on the kernel's output as
+    they acted on XLA's."""
+    rng = np.random.default_rng(3)
+    full = jnp.asarray(rng.integers(0, 9, 640).astype(np.int32))
+    nbr = jnp.asarray(rng.integers(0, 640, 1024).astype(np.int32))
+    kw = dict(ARGS[args])
+    if kw.pop("mask", False):
+        kw["mask"] = jnp.asarray(rng.integers(0, 2, 1024).astype(bool))
+    got = jax.jit(lambda f, i: pull_gather(f, i, **kw))(full, nbr)
+    assert len(on_tpu) == 1
+    monkeypatch.setattr(segment, "use_pallas", lambda: False)
+    want = pull_gather(full, nbr, **kw)
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+
+
+# ---- query lanes ----------------------------------------------------------
+
+
+@pytest.mark.parametrize("dtype", ["float32", "int32"])
+def test_lanes_take_xla(dtype, on_tpu, monkeypatch):
+    """Under `jax.vmap` the batched call goes back to `full[nbr]`: the
+    lowered text is the one the lanes had before (XLA fuses their
+    gather into their fold), each lane has its single call's bytes,
+    and the call counts once, as `xla`."""
+    rng = np.random.default_rng(5)
+    full = jnp.asarray(rng.integers(0, 99, (4, 640)).astype(dtype))
+    nbr = jnp.asarray(rng.integers(0, 640, 1024).astype(np.int32))
+    mask = jnp.asarray(rng.integers(0, 2, 1024).astype(bool))
+
+    def one(f):
+        return pull_gather(f, nbr, mask, jnp.asarray(0, f.dtype), add=1)
+
+    took = _took(lambda: jax.jit(jax.vmap(one)).lower(full))
+    assert took == {"kernel": 0, "xla": 1}
+    text = jax.jit(jax.vmap(one)).lower(full).as_text()
+    monkeypatch.setattr(segment, "use_pallas", lambda: False)
+    assert text == jax.jit(jax.vmap(one)).lower(full).as_text()
+    got = np.asarray(jax.jit(jax.vmap(one))(full))
+    want = np.stack([np.asarray(one(f)) for f in full])
+    assert got.tobytes() == want.tobytes()
+
+
+def test_lanes_rule_moves_the_count_once(on_tpu):
+    """A loop's batching rule may run the `vmap` rule again for one
+    call: the entry moves from `kernel` to `xla` once."""
+    full = jnp.zeros((4, 640), jnp.float32)
+    nbr = jnp.zeros((1024,), jnp.int32)
+
+    def rounds(f):
+        return jax.lax.fori_loop(
+            0, 3, lambda _, x: x + pull_gather(x, nbr)[:640], f)
+
+    took = _took(lambda: jax.jit(jax.vmap(rounds)).lower(full))
+    assert took == {"kernel": 0, "xla": 1}
+
+
+def test_batched_indices_take_xla(on_tpu):
+    """Lanes that bring their own indices (no caller does) still get
+    `full[nbr]`, lane by lane."""
+    rng = np.random.default_rng(7)
+    full = jnp.asarray(rng.standard_normal(640).astype(np.float32))
+    nbr = jnp.asarray(rng.integers(0, 640, (3, 1024)).astype(np.int32))
+    got = jax.vmap(lambda i: pull_gather(full, i))(nbr)
+    assert np.asarray(got).tobytes() == np.asarray(full[nbr]).tobytes()
+
+
+# ---- a whole query through the kernel -------------------------------------
+
+
+@pytest.mark.parametrize("fnum", [1, 4])
+def test_bfs_through_the_kernel(fnum, graph_cache, monkeypatch):
+    """BFS's depths are int32 in this lane too: its round through the
+    interpreted kernel, inside `shard_map(while_loop)`, answers with
+    the bytes of the round through XLA's gather."""
+    frag = graph_cache(fnum)
+    want = Worker(APP_REGISTRY["bfs"](), frag)
+    want.query(source=6)
+    monkeypatch.setattr(segment, "use_pallas", lambda: True)
+    monkeypatch.setattr(segment, "gather_table_budget", lambda: BUDGET)
+    monkeypatch.setattr(
+        segment, "vmem_gather",
+        functools.partial(pallas_kernels.vmem_gather, interpret=True))
+    got = Worker(APP_REGISTRY["bfs"](), frag)
+    took = _took(lambda: got.query(source=6))
+    assert took == {"kernel": 1, "xla": 0}
+    assert got.rounds == want.rounds
+    assert (np.asarray(got.result_values()).tobytes()
+            == np.asarray(want.result_values()).tobytes())
