@@ -7,34 +7,13 @@ second north star as a nested object under "sssp" (VERDICT r3 next
 #5): SSSP MTEPS/chip = single-pass edge count / query wall-clock on
 the same graph with uniform(0.1,10) weights.
 
-The bench A/Bs the SpMV backends ITSELF (VERDICT r2 weak #1: the pack
-pipeline must never hide behind an env var): on an accelerator it
-measures both the XLA gather+segment_sum path and the pack-gather
-Pallas path, reports the best honest number, and says which path won in
-the metric name.  The backend is initialised once, in this process; on
-a CPU the bench exits non-zero unless GRAPE_BENCH_NO_PROBE=1 asked for
-the CPU by name — then only the XLA path is timed (interpret-mode
-Pallas at RMAT-20 is not a measurement) and the metric says `_cpu`.
+The backend is initialised once, in this process; on a CPU the bench
+exits non-zero unless GRAPE_BENCH_NO_PROBE=1 asked for the CPU by name
+— then the metric says `_cpu`.
 Env knobs:
-  GRAPE_SPMV=xla|pack          pin one backend
   GRAPE_BENCH_SCALE=N          RMAT scale (default 20)
-  GRAPE_BENCH_NO_PROBE=1       run on the CPU, XLA only (the schema
-                               checks of scripts/app_tests.sh)
-  GRAPE_PACK_SCAN=mxu|shift    pack segmented-scan backend (default
-                               mxu: triangular-matmul prefix on the
-                               matrix unit; shift: the log-stage
-                               ladder, kept for A/B)
-
-BENCH-json ledger fields (r7): `pack_ledger` carries the planner's
-static op budget at bench geometry with SPLIT engine columns —
-`vpu_ops_per_edge` (vector-ALU work), `mxu_elems_per_edge` (matmul
-output elements of the MXU scan), `bytes_per_edge` (every shipped
-stream table at its real narrowed dtype), `gather_slots_per_edge`,
-`per_stage_ops_per_edge` (VPU attribution: overlay/route/flags/scan/
-extract), the modeled MTEPS bracket under `modeled`, and
-`ledger_recount_mismatch` (planner annotations vs the cost model's
-independent recount from the shipped arrays; > 5% on either engine
-column fails the bench after the measurements are printed).
+  GRAPE_BENCH_NO_PROBE=1       run on the CPU (the schema checks of
+                               scripts/app_tests.sh)
 
 BENCH-json obs fields (r8): `obs` carries the per-phase span rollup
 from the in-memory tracer armed for the whole bench — `spans` maps
@@ -125,8 +104,8 @@ vc2d.py, docs/PARTITION2D.md) on a hub-heavy RMAT at fnum 4 (k=2) —
 max-tile edge count vs the raw 1-D hub fragment (the SCALE_NOTES
 pathology), modeled exchange bytes under the shared ledgers,
 serial-vs-2D wall, SSSP byte-identity / PageRank eps-identity
-verdicts, the planner's recorded auto decision against the measured
-winner, and the per-tile pack-plan ledger recount (the 5% gate).
+verdicts, and the planner's recorded auto decision against the
+measured winner.
 Env knobs: GRAPE_BENCH_NO_P2D=1 skips, GRAPE_BENCH_P2D_SCALE sizes
 the twin (default 12 regardless of GRAPE_BENCH_SCALE — hub
 statistics under-develop below that).
@@ -225,12 +204,11 @@ def build_bench_inputs(scale: int | None = None):
 
 
 def build_bench_fragment(scale: int | None = None):
-    """The bench graph + fragment, shared with scripts/seed_pack_plans.py
-    so the pre-seeded plan-cache digests stay bit-identical by
-    construction.  The real load path: hash-partitioned vertex map over
-    the native open-addressing idxer (round 1 bypassed VertexMap with an
-    identity idxer because the dict path was load-bound; the native
-    table is ~30x faster, so the bench exercises the honest path).
+    """The bench graph + fragment.  The real load path: hash-partitioned
+    vertex map over the native open-addressing idxer (round 1 bypassed
+    VertexMap with an identity idxer because the dict path was
+    load-bound; the native table is ~30x faster, so the bench exercises
+    the honest path).
     `scale` overrides GRAPE_BENCH_SCALE (the serve lane runs a smaller
     twin of the same construction)."""
     from libgrape_lite_tpu.fragment.edgecut import ShardedEdgecutFragment
@@ -247,10 +225,9 @@ def build_bench_fragment(scale: int | None = None):
 
 def build_bench_weighted_fragment(src, dst, comm_spec, vm,
                                   retain_edge_list=False):
-    """The SSSP lane's weighted twin (seed-11 uniform(0.1,10) f32) —
-    also shared with the plan-cache seeder.  The dyn lane builds its
-    twin with retain_edge_list=True (the repack path edits the host
-    edge list)."""
+    """The SSSP lane's weighted twin (seed-11 uniform(0.1,10) f32).
+    The dyn lane builds its twin with retain_edge_list=True (the repack
+    path edits the host edge list)."""
     from libgrape_lite_tpu.fragment.edgecut import ShardedEdgecutFragment
     from libgrape_lite_tpu.utils.types import LoadStrategy
 
@@ -501,9 +478,7 @@ def partition2d_lane(scale: int) -> dict:
         shared rate/byte ledgers) against the measured winner — walls
         within PARTITION_TIE_BAND count as agreeing with the planner:
         the model prices TPU rates, and a CPU-fallback wall split
-        finer than the band is collective-dispatch noise, not signal;
-      * the per-tile pack sub-plan ledger recount
-        (pack_cost_model.tile_plan_recount), gated at the same 5%.
+        finer than the band is collective-dispatch noise, not signal.
     """
     import jax
 
@@ -534,7 +509,6 @@ def partition2d_lane(scale: int) -> dict:
     if scripts not in sys.path:
         sys.path.insert(0, scripts)
     from gen_rmat import shuffle_perm
-    from pack_cost_model import tile_plan_recount
 
     n, src_raw, dst_raw = rmat_edges(scale, EDGE_FACTOR)
     # the recorded pathology: max 1-D shard ie-edge count on the raw
@@ -616,24 +590,6 @@ def partition2d_lane(scale: int) -> dict:
         <= PARTITION_TIE_BAND
     decision_matches = (planner_choice == measured_winner) or tie
 
-    # tile-plan availability and recount drift are DISTINCT verdicts:
-    # a failed resolve must not masquerade as ledger drift
-    disp = None
-    try:
-        from libgrape_lite_tpu.ops.spmv_pack import resolve_pack_dispatch
-
-        disp = resolve_pack_dispatch(
-            frag_2d, direction="ie", prefix="pk_ie_",
-            with_weights=True, role=f"vc2d-k{k}",
-        )
-    except Exception as e:
-        print(f"[bench] partition2d: tile plan failed: {e}",
-              file=sys.stderr)
-    recount = (
-        tile_plan_recount(disp.mplan) if disp is not None
-        else {"tile_recount_mismatch": 1.0}
-    )
-
     return {
         "scale": scale,
         "fnum": fnum,
@@ -661,8 +617,6 @@ def partition2d_lane(scale: int) -> dict:
         "planner_t2d_s": costs["2d"]["t_round_s"],
         "measured_winner": measured_winner,
         "decision_matches": decision_matches,
-        "tile_plan_ok": disp is not None,
-        "tile_recount_mismatch": recount["tile_recount_mismatch"],
     }
 
 
@@ -1050,9 +1004,8 @@ def main():
             metrics_path=os.environ.get(obs.METRICS_ENV) or None,
         )
 
-    # persist pack plans across bench invocations: a live-TPU window is
-    # scarce, and re-running the O(E log E) host planner on every A/B
-    # wastes minutes of it (explicit GRAPE_PACK_PLAN_CACHE wins)
+    # persist spgemm plans across bench invocations (explicit
+    # GRAPE_PACK_PLAN_CACHE wins)
     os.environ.setdefault("GRAPE_PACK_PLAN_CACHE", PLAN_CACHE_DIR)
 
     t_load0 = time.perf_counter()
@@ -1062,80 +1015,39 @@ def main():
 
     rounds = 10
 
-    def measure(name: str, mode: str, app_factory, bench_frag, kwargs):
-        """Time one app with the given SpMV backend pinned; returns
-        (best seconds, engaged backend name) or None on failure."""
-        prev = os.environ.get("GRAPE_SPMV")
-        os.environ["GRAPE_SPMV"] = mode
+    def measure(name: str, app_factory, bench_frag, kwargs):
+        """Time one app; returns the best seconds or None on failure."""
         try:
-            app = app_factory()
-            worker = Worker(app, bench_frag)
+            worker = Worker(app_factory(), bench_frag)
             t_c0 = time.perf_counter()
             worker.query(**kwargs)  # warmup (compile + plan)
             t_compile = time.perf_counter() - t_c0
-            engaged = (
-                "pack" if getattr(app, "_pack", None) is not None
-                else "xla"
-            )
-            if mode == "pack" and engaged != "pack":
-                print(f"[bench] {name}: pack requested but not engaged",
-                      file=sys.stderr)
-                return None
             best = float("inf")
             for _ in range(3):
                 t0 = time.perf_counter()
                 worker.query(**kwargs)
                 best = min(best, time.perf_counter() - t0)
             print(
-                f"[bench] {name}: mode={mode} engaged={engaged} "
-                f"best={best:.4f}s warm+compile={t_compile:.1f}s "
-                f"rounds={worker.rounds}",
+                f"[bench] {name}: best={best:.4f}s "
+                f"warm+compile={t_compile:.1f}s rounds={worker.rounds}",
                 file=sys.stderr,
             )
-            return best, engaged
-        except Exception as e:  # a failed backend must not kill the bench
+            return best
+        except Exception as e:  # a failed app must not kill the bench
             print(
-                f"[bench] {name}: mode {mode} failed: "
-                f"{type(e).__name__}: {e}",
+                f"[bench] {name}: failed: {type(e).__name__}: {e}",
                 file=sys.stderr,
             )
             return None
-        finally:
-            if prev is None:
-                os.environ.pop("GRAPE_SPMV", None)
-            else:
-                os.environ["GRAPE_SPMV"] = prev
 
-    # the A/B: both backends on a live TPU; XLA only on the CPU
-    # fallback (interpret-mode Pallas is not a measurement) — unless
-    # GRAPE_SPMV pins a single path explicitly
-    forced = os.environ.get("GRAPE_SPMV")
-    if forced:
-        modes = [forced]
-    elif alive:
-        modes = ["xla", "pack"]
-    else:
-        modes = ["xla"]
-
-    def ab(name, app_factory, bench_frag, kwargs):
-        results = {}
-        for mode in modes:
-            r = measure(name, mode, app_factory, bench_frag, kwargs)
-            if r is not None:
-                results[mode] = r
-        if not results:
-            return None
-        return min(results.values(), key=lambda r: r[0])
-
-    pr = ab("pagerank", lambda: PageRank(delta=0.85, max_round=rounds),
-            frag, {"max_round": rounds})
-    if pr is None:
-        raise RuntimeError("no SpMV backend produced a measurement")
-    best_time, winner = pr
+    best_time = measure(
+        "pagerank", lambda: PageRank(delta=0.85, max_round=rounds),
+        frag, {"max_round": rounds})
+    if best_time is None:
+        raise RuntimeError("PageRank produced no measurement")
     mteps = e_sym * rounds / best_time / 1e6
-    tag = f"_{winner}" if len(modes) > 1 or forced else ""
     record = {
-        "metric": f"pagerank_rmat{SCALE}_mteps_per_chip{tag}{suffix}",
+        "metric": f"pagerank_rmat{SCALE}_mteps_per_chip{suffix}",
         "value": round(mteps, 1),
         "unit": "MTEPS/chip",
         "vs_baseline": round(mteps / BASELINE_MTEPS_PER_CHIP, 3),
@@ -1164,28 +1076,20 @@ def main():
         # decision is now measured, not assumed
         picked, reason = select_sssp_variant(frag_w, 0)
         print(f"[bench] sssp_select -> {picked}: {reason}", file=sys.stderr)
-        ss = ab("sssp", APP_REGISTRY[picked], frag_w, {"source": 0})
-        if ss is not None:
-            ss_time, ss_winner = ss
+        ss_time = measure("sssp", APP_REGISTRY[picked], frag_w,
+                          {"source": 0})
+        if ss_time is not None:
             ss_mteps = e_sym / ss_time / 1e6
-            ss_tag = f"_{ss_winner}" if len(modes) > 1 or forced else ""
             record["sssp"] = {
                 "metric":
-                    f"sssp_rmat{SCALE}_mteps_per_chip{ss_tag}{suffix}",
+                    f"sssp_rmat{SCALE}_mteps_per_chip{suffix}",
                 "value": round(ss_mteps, 1),
                 "unit": "MTEPS/chip",
                 "variant": picked,
                 # r6: the dense pull pre-masks the weight stream at
                 # init (one gather pass/round instead of gather +
-                # mask-select); GRAPE_SSSP_FUSE=0 reverts for A/B.
-                # Only the dense-pull variant on the XLA backend HAS
-                # the fused form (sssp_delta never does; the pack
-                # backend bakes weights into the plan instead)
-                "fused_pull": (
-                    picked == "sssp" and ss_winner == "xla"
-                    and os.environ.get(
-                        "GRAPE_SSSP_FUSE", "1") not in ("0", "")
-                ),
+                # mask-select); sssp_delta has no fused form
+                "fused_pull": picked == "sssp",
                 "vs_baseline":
                     round(ss_mteps / SSSP_BASELINE_MTEPS_PER_CHIP, 3),
             }
@@ -2299,12 +2203,6 @@ def main():
                 f"{p2d['measured_winner']}",
                 file=sys.stderr,
             )
-            scripts = os.path.join(
-                os.path.dirname(os.path.abspath(__file__)), "scripts")
-            if scripts not in sys.path:
-                sys.path.insert(0, scripts)
-            from pack_cost_model import MISMATCH_TOLERANCE as _TOL2
-
             for bad, why in (
                 (not p2d["sssp_byte_identical"],
                  "2-D SSSP diverged from the 1-D result"),
@@ -2315,12 +2213,6 @@ def main():
                 (not p2d["exchange_reduced"],
                  "modeled 2-D exchange bytes not below the 1-D "
                  "gather"),
-                (not p2d["tile_plan_ok"],
-                 "per-tile pack plan unavailable (resolve failed — "
-                 "see the lane's stderr)"),
-                (p2d["tile_plan_ok"]
-                 and p2d["tile_recount_mismatch"] > _TOL2,
-                 "tile pack-plan ledger recount drifted"),
                 (not p2d["decision_matches"],
                  "planner decision contradicts the measured winner"),
             ):
@@ -2440,47 +2332,6 @@ def main():
         except Exception as e:  # the lane must not cost the bench
             print(
                 f"[bench] spgemm lane failed: {type(e).__name__}: {e}",
-                file=sys.stderr,
-            )
-
-    # static op-budget ledger (r6): the planner's exact per-stage ALU
-    # counts at the bench geometry ride in the BENCH json, and the
-    # cost model's independent recount must agree within 5% — the
-    # op budget is a pinned contract, so a drift fails the bench LOUDLY
-    # (after every measurement is already printed).  First run pays the
-    # O(E log E) planner; the summary is cached under the plan-cache
-    # dir afterwards.  GRAPE_BENCH_NO_LEDGER=1 skips the lane.
-    ledger_mismatch = None
-    if not os.environ.get("GRAPE_BENCH_NO_LEDGER"):
-        try:
-            sys.path.insert(
-                0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                                "scripts"))
-            from pack_cost_model import (
-                MISMATCH_TOLERANCE,
-                bench_ledger_summary,
-            )
-
-            summ = bench_ledger_summary(SCALE, EDGE_FACTOR,
-                                        cache_dir=PLAN_CACHE_DIR)
-            record["pack_ledger"] = {
-                "vpu_ops_per_edge": summ["vpu_ops_per_edge"],
-                "mxu_elems_per_edge": summ["mxu_elems_per_edge"],
-                "gather_slots_per_edge": summ["gather_slots_per_edge"],
-                "bytes_per_edge": summ["bytes_per_edge"],
-                "per_stage_ops_per_edge": summ["per_stage_ops_per_edge"],
-                "scan_mode": os.environ.get("GRAPE_PACK_SCAN", "mxu"),
-                "modeled": summ["scenarios"],
-                "ledger_recount_mismatch":
-                    summ["ledger_recount_mismatch"],
-            }
-            _emit_record(record)
-            if summ["ledger_recount_mismatch"] > MISMATCH_TOLERANCE:
-                ledger_mismatch = summ["ledger_recount_mismatch"]
-        except Exception as e:  # the ledger lane must not cost the bench
-            print(
-                f"[bench] pack ledger lane failed: "
-                f"{type(e).__name__}: {e}",
                 file=sys.stderr,
             )
 
@@ -2659,14 +2510,6 @@ def main():
                 file=sys.stderr,
             )
 
-    if ledger_mismatch is not None:
-        print(
-            f"[bench] FATAL: op-budget ledger and cost-model recount "
-            f"disagree by {ledger_mismatch:.1%} (> 5%) — the planner's "
-            "annotations have drifted from the shipped kernels",
-            file=sys.stderr,
-        )
-        sys.exit(2)
     if pipeline_mismatch is not None:
         print(
             f"[bench] FATAL: pipeline overlap term drifted "

@@ -19,11 +19,11 @@ calls it drives:
            WCC through `Worker.query`, cold then warm (no compile),
            every vertex against a plain NumPy/SciPy reference; then a
            `ServeSession` answers 4 SSSP + 4 BFS point queries.
-  Stage C  the two Pallas kernels a default path reaches on a TPU
-           (strict-tile SpMV, bitmap intersect), compiled, on p2p-31;
-           and the pull's gather (`ops/segment.pull_gather`, which
-           every stage's pulls already went through) alone, compiled,
-           against XLA's `full[nbr]` on a four-chip shard's shapes.
+  Stage C  the Pallas kernel LCC's default path reaches on a TPU
+           (bitmap intersect), compiled, on p2p-31; and the pull's
+           gather (`ops/segment.pull_gather`, which every stage's pulls
+           already went through) alone, compiled, against XLA's
+           `full[nbr]` on a four-chip shard's shapes.
 
 Any failure raises: nothing is caught and continued.  The walls it
 prints are set-up and health readings, never a benchmark metric.
@@ -71,8 +71,6 @@ NOT_RUN = {
     "lcc_at_size": "docs/SCALE_NOTES.md sizes its ELL past one chip at "
                    "scale 22 and nothing smaller is sized (ROADMAP "
                    "R1/S5). Exact on p2p-31 in Stages A and C",
-    "pack_kernel": "opt-in (GRAPE_SPMV=pack) and refused by the chip's "
-                   "compiler (CHANGES.md PR 22); ROADMAP S2",
 }
 
 
@@ -232,12 +230,6 @@ def check_golden(app: str, result: dict) -> str:
     return f"golden, {rule}"
 
 
-def spmv_backend(app) -> str:
-    if getattr(app, "_pack", None) is not None:
-        return "pack"
-    return "strict" if getattr(app, "_spmv_tile", 0) else "xla"
-
-
 # ---- Stage A: the CLI on p2p-31 ----
 
 
@@ -387,7 +379,6 @@ def stage_b(frag, refs: dict) -> dict:
                           f"plain reference ({rule})")
         out[name] = {
             "ok": True, "rounds": int(worker.rounds),
-            "backend": spmv_backend(worker.app),
             "check": f"every vertex vs NumPy/SciPy, {rule}"
                      + (f" {eps:g}" if eps else ""),
             "cold_wall_s": cold, "warm_wall_s": warm,
@@ -421,7 +412,7 @@ def stage_b(frag, refs: dict) -> dict:
     return out
 
 
-# ---- Stage C: the two reachable Pallas kernels, compiled ----
+# ---- Stage C: the reachable Pallas kernels, compiled ----
 
 
 def stage_c(on_tpu: bool) -> dict:
@@ -437,37 +428,20 @@ def stage_c(on_tpu: bool) -> dict:
         CommSpec(fnum=1),
         LoadGraphSpec(directed=False, weighted=True, edata_dtype=np.float32),
     )
-    # kernel -> (app, golden, query, the switch that selects it).  Off
-    # the TPU (a rehearsal) strict is interpreted and row_and_popcount
-    # takes the fused jnp path: no kernel is expected there
-    kernels = {
-        "spmv_strict": ("pagerank", "pagerank",
-                        {"delta": PR_DELTA, "max_round": PR_ROUNDS},
-                        {"GRAPE_SPMV": "strict"}),
-        "intersect_count": ("lcc_bitmap", "lcc", {}, {}),
-    }
-    jax.clear_caches()  # an earlier stage's trace must not hide these
-    out = {}
-    for kernel, (name, golden, kwargs, switch) in kernels.items():
-        os.environ.update(switch)
-        try:
-            with pallas_spy() as calls:
-                app = APP_REGISTRY[name]()
-                (result, wall) = timed(
-                    collect_worker_result, app, frag, **kwargs)
-        finally:
-            for k in switch:
-                del os.environ[k]
-        if kernel == "spmv_strict":
-            require(spmv_backend(app) == "strict", "strict did not engage")
-        if on_tpu:
-            require(calls, f"stage C {kernel}: no pallas_call was traced")
-            require(not any(calls), f"stage C {kernel}: ran interpreted")
-        out[kernel] = {
-            "ok": True, "app": name, "check": check_golden(golden, result),
-            **spy_summary(calls), "cold_wall_s": wall,
-        }
-        log(f"C {kernel}: {out[kernel]}")
+    # off the TPU (a rehearsal) row_and_popcount takes the fused jnp
+    # path: no kernel is expected there
+    jax.clear_caches()  # an earlier stage's trace must not hide this
+    with pallas_spy() as calls:
+        (result, wall) = timed(
+            collect_worker_result, APP_REGISTRY["lcc_bitmap"](), frag)
+    if on_tpu:
+        require(calls, "stage C intersect_count: no pallas_call was traced")
+        require(not any(calls), "stage C intersect_count: ran interpreted")
+    out = {"intersect_count": {
+        "ok": True, "app": "lcc_bitmap", "check": check_golden("lcc", result),
+        **spy_summary(calls), "cold_wall_s": wall,
+    }}
+    log(f"C intersect_count: {out['intersect_count']}")
     out["vmem_gather"] = gather_check(on_tpu)
     return out
 

@@ -143,7 +143,7 @@ class AppBase:
     # state keys that are read-only trace INPUTS, not loop state: they
     # enter the jitted superstep sharded like normal leaves but are
     # excluded from the while_loop carry and from the outputs (the
-    # pack pipeline's per-shard stream tables ride in this way —
+    # mirror and pipeline plans' per-shard tables ride in this way —
     # constants can't, because closing over an array under shard_map
     # replicates it to every device)
     ephemeral_keys: FrozenSet[str] = frozenset()

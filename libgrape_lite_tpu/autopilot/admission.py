@@ -1,11 +1,11 @@
 """Priced per-query admission: shed or defer tenants past budget.
 
-`fleet/budget.py` already prices SESSIONS from the pack ledgers
+`fleet/budget.py` already prices SESSIONS from the plan ledgers
 (SparseP discipline: price from a cost model, never hand-tune a
 watermark).  This module extends the same ledger geometry to
 INDIVIDUAL queries: one round of a point query costs what the
-fragment's resolved pack plan says it moves/computes
-(`spmv_pack.plan_ledger` totals), scaled by the round limit — so the
+fragment's resolved plans say they move/compute (their `ledger`
+totals), scaled by the round limit — so the
 admission controller knows what a request will cost BEFORE the fleet
 pays for it.
 
@@ -46,31 +46,30 @@ from libgrape_lite_tpu.autopilot.signals import (
 DEFAULT_PRICED_ROUNDS = 16
 
 
+def _plan_totals(fragment) -> list:
+    """Ledger totals of every plan resolved for `fragment` (the
+    per-fragment cache of ops/spgemm_pack.py)."""
+    from libgrape_lite_tpu.ops.spgemm_pack import _frag_cache
+
+    out = []
+    for plan in _frag_cache(fragment).values():
+        led = getattr(plan, "ledger", None)
+        if isinstance(led, dict) and isinstance(led.get("totals"), dict):
+            out.append(led["totals"])
+    return out
+
+
 def query_cost(fragment, max_rounds: Optional[int] = None) -> float:
     """Estimated cost of one point query on `fragment`, in
-    HBM-bytes-per-query: the resolved pack plans' per-round ledger
-    bytes (`spmv_pack.plan_ledger` — the SAME totals the HBM budget
-    prices sessions from) times the round limit.  Falls back to the
-    fragment's CSR byte size per round when no plan has been resolved
-    yet (a fresh session priced before its first query)."""
+    HBM-bytes-per-query: the resolved plans' per-round ledger bytes
+    times the round limit.  Falls back to the fragment's CSR byte size
+    per round when no plan has been resolved (the default pull
+    resolves none)."""
     rounds = int(max_rounds) if max_rounds else DEFAULT_PRICED_ROUNDS
-    per_round = 0.0
-    try:
-        from libgrape_lite_tpu.ops.spmv_pack import (
-            _frag_cache,
-            plan_ledger,
-        )
-
-        for plan in _frag_cache(fragment).values():
-            try:
-                totals = plan_ledger(plan)["totals"]
-                per_round = max(
-                    per_round, float(totals.get("hbm_bytes", 0))
-                )
-            except Exception:
-                continue
-    except Exception:
-        per_round = 0.0
+    per_round = max(
+        (float(t.get("hbm_bytes", 0)) for t in _plan_totals(fragment)),
+        default=0.0,
+    )
     if per_round <= 0.0:
         from libgrape_lite_tpu.fleet.budget import fragment_bytes
 
@@ -83,30 +82,17 @@ def query_wall_s(fragment, max_rounds: Optional[int] = None,
                  profile=None) -> float:
     """Estimated WALL seconds of one point query on `fragment` under
     `profile` (default: the active RateProfile) — the widest resolved
-    pack plan's full ledger columns priced through the profile's
-    additive wall model, times the round limit.  0.0 when no plan has
-    been resolved yet (the byte fallback has no op columns to price);
+    plan's full ledger columns priced through the profile's additive
+    wall model, times the round limit.  0.0 when no plan has been
+    resolved (the byte fallback has no op columns to price);
     byte-based `query_cost` stays the load-shaped metric, this is the
     latency-shaped one a fitted profile keeps honest."""
     from libgrape_lite_tpu.ops.calibration import active_profile
 
     p = profile or active_profile()
     rounds = int(max_rounds) if max_rounds else DEFAULT_PRICED_ROUNDS
-    best = 0.0
-    try:
-        from libgrape_lite_tpu.ops.spmv_pack import (
-            _frag_cache,
-            plan_ledger,
-        )
-
-        for plan in _frag_cache(fragment).values():
-            try:
-                totals = plan_ledger(plan)["totals"]
-            except Exception:
-                continue
-            best = max(best, p.wall_s(totals))
-    except Exception:
-        return 0.0
+    best = max((p.wall_s(t) for t in _plan_totals(fragment)),
+               default=0.0)
     return best * rounds
 
 

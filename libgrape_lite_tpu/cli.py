@@ -1047,8 +1047,7 @@ def _serve_summary(ns, sess, pump, reqs, results, wall, delta_ops,
     for s in sessions:
         for k, v in s.queue.batch_hist.items():
             batch_hist[k] = batch_hist.get(k, 0) + v
-    cache = {"runner": {"hits": 0, "misses": 0},
-             "pack": sess.cache_stats()["pack"]}
+    cache = {"runner": {"hits": 0, "misses": 0}}
     for s in sessions:
         st = s.cache_stats()["runner"]
         cache["runner"]["hits"] += st["hits"]

@@ -1,6 +1,6 @@
 """Delta-edge buffer: a bounded, typed staging area for graph updates.
 
-The frozen packed CSR is the fast path's whole value — pack plans,
+The frozen packed CSR is the fast path's whole value — plans,
 mirror tables, and compiled runners are all keyed to its byte layout —
 so mutations never touch it directly.  Instead they stage here:
 
@@ -219,9 +219,8 @@ class DeltaBuffer:
 
     def to_mutator(self, directed: bool = True):
         """The staged ops as a `fragment/mutation.BasicFragmentMutator`
-        — the repack path reuses the existing rebuild machinery (pack
-        planner + rebalancer run on the rebuilt fragment's next
-        init_state, re-keying the v3 plan cache by content digest).
+        — the repack path reuses the existing rebuild machinery (the
+        rebuilt fragment's next init_state plans afresh).
 
         On undirected graphs, remove/update ops apply to BOTH
         orientations (the reference rule, `ev_fragment_mutator.h:

@@ -12,7 +12,7 @@ staged updates:
     extra edges into their pull reduction with one gather +
     `segment_min` per round, merged at the fold — `min` is
     associative and exact, so the query result is byte-identical to a
-    cold run on the rebuilt graph while the pack plans, mirror
+    cold run on the rebuilt graph while the plans, mirror
     tables, and compiled runners stay untouched (fixed shapes: the
     second query after an ingest is a cache hit, pinned by
     tests/test_dyn.py).

@@ -9,9 +9,8 @@ CSR's locality, so their per-edge cost is a large constant multiple of
 the planned streams'), and the buffer folds into the base arrays via
 the existing mutation machinery: `BasicFragmentMutator.mutate` edits
 the retained host edge list and rebuilds the padded shards, the next
-`init_state` re-runs the pack planner + rebalancer against the new
-content, and the v3 plan cache re-keys itself by content digest — a
-counted recompile event, never a silent one.
+`init_state` re-plans against the new content — a counted recompile
+event, never a silent one.
 
 Non-additive ops (removals, weight updates, vertex changes) force a
 repack regardless of ratio: a tropical min-fold cannot "un-min" a

@@ -9,9 +9,9 @@ rather than inventing a new byte model:
     priced from their HOST twins (`ShardedEdgecutFragment.host_oe/ie`,
     the same geometry `_check_hbm_budget` bills at load time), so an
     EVICTED session prices identically to a resident one;
-  * **plan-stream bytes** — every pack / spgemm plan resolved for the
-    fragment (`spmv_pack._frag_cache`), the `host_streams` tables the
-    multi-shard path ships as ephemeral state;
+  * **plan-stream bytes** — every spgemm plan resolved for the
+    fragment (`spgemm_pack._frag_cache`), the `host_streams` tables
+    the dispatch ships as ephemeral state;
   * **overlay bytes** — the dyn delta overlay's dense
     [fnum, capacity] side planes (dyn/ingest.py);
   * **runner bytes** — the resident workers' retained result carries
@@ -156,11 +156,10 @@ def fragment_bytes(frag) -> int:
 
 
 def plan_stream_bytes(frag) -> int:
-    """Bytes of every pack/spgemm plan resolved for `frag` — the
-    `host_streams` tables the multi-shard dispatch ships as ephemeral
-    state leaves (spmv_pack `MultiPackPlan` and spgemm `SpGemmPlan`
-    entries share one per-fragment cache)."""
-    from libgrape_lite_tpu.ops.spmv_pack import _frag_cache
+    """Bytes of every spgemm plan resolved for `frag` — the
+    `host_streams` tables the dispatch ships as ephemeral state
+    leaves (`SpGemmPlan` entries of the per-fragment cache)."""
+    from libgrape_lite_tpu.ops.spgemm_pack import _frag_cache
 
     seen, total = set(), 0
     for plan in _frag_cache(frag).values():

@@ -106,7 +106,7 @@ def boundary_stats(frag, bmask: np.ndarray, direction: str = "ie") -> dict:
     direction (edges classified by their DESTINATION row: a boundary
     edge feeds a boundary vertex's fold, so it belongs to the slice
     that must finish before the exchange kickoff).  Surfaced through
-    spmv_pack.plan_stats(), Worker.pack_ledger() and trace_report."""
+    PIPELINE_STATS, Worker.pack_ledger() and trace_report."""
     inner = frag.host_inner_mask()
     csrs = frag.host_ie if direction == "ie" else frag.host_oe
     per_frag = []
@@ -295,9 +295,9 @@ class ShardedEdgecutFragment:
     def release_device(self) -> bool:
         """Evict: delete the stacked device arrays and drop `dev`.
         Every host artifact survives — host CSRs, vertex map, the
-        per-fragment pack-plan cache weak-keyed on THIS object — so
+        per-fragment plan caches weak-keyed on THIS object — so
         `restore_device` re-places byte-identical content with zero
-        pack re-planning.  Returns False when already released."""
+        re-planning.  Returns False when already released."""
         if self.dev is None:
             return False
         self._dev_meta = (self.dev.total_vnum, self.dev.total_enum)

@@ -1,8 +1,7 @@
 """1-D edge-cut vs 2-D vertex-cut partition planner (ROADMAP item 2).
 
-The pack planner prices its kernels from a static cost ledger; this
-module applies the same discipline one level up: given the host edge
-list, price BOTH partition layouts and choose — `GRAPE_PARTITION`:
+Given the host edge list, price BOTH partition layouts from a static
+cost ledger and choose — `GRAPE_PARTITION`:
 
   * unset / "" / "0" / "1d"  — 1-D edge-cut, the serial path,
     bit-for-bit untouched (lowered-HLO pinned in

@@ -134,21 +134,14 @@ class ImmutableVertexcutFragment:
 
     # ---- per-tile CSR views -------------------------------------------
     #
-    # The pack planner (ops/spmv_pack.resolve_pack_dispatch) and the ft
-    # fingerprint read fragments through the host_ie/host_oe CSR-list
-    # protocol; the vertex-cut tiles expose the same shape so the MXU
-    # scan / stream-diet machinery of PRs 2/4 applies per tile:
+    # The ft fingerprint reads fragments through the host_ie/host_oe
+    # CSR-list protocol; the vertex-cut tiles expose the same shape:
     #   host_ie[f]: rows = dst offsets in chunk-j space, cols = src
     #               offsets in chunk-i space (the dst-side pull whose
     #               gather table is the [vc] column-broadcast chunk);
     #   host_oe[f]: the transposed orientation (src-side pull — the
     #               directed-WCC second direction).
-    # Both index LOCAL [vc] tables, so pack plans are built with
-    # n_cols = vc (`pack_n_cols`), not fnum * vp.
-
-    @property
-    def pack_n_cols(self) -> int:
-        return self.vc
+    # Both index LOCAL [vc] tables.
 
     def _tile_csrs(self, orientation: str):
         if orientation in self._host_csrs:
@@ -264,9 +257,9 @@ class ImmutableVertexcutFragment:
     def release_device(self) -> bool:
         """Evict: delete the stacked COO tile buffers and drop `dev`.
         Every host artifact survives — `_host_tiles`, the cached
-        per-tile CSR views, the pack-plan cache weak-keyed on THIS
+        per-tile CSR views, the plan caches weak-keyed on THIS
         object — so `restore_device` re-places byte-identical content
-        with zero pack re-planning (the 1-D fleet contract).  Returns
+        with zero re-planning (the 1-D fleet contract).  Returns
         False when already released."""
         if self.dev is None:
             return False

@@ -24,8 +24,8 @@ FINGERPRINT_FORMAT = 1
 
 def stable_config_digest(obj: Any) -> str:
     """sha256 hex of a canonical-JSON rendering of `obj` — the shared
-    config-fingerprint primitive for cache keys (pack plan cache keys
-    its entries by PackConfig + dtype through this).  Non-JSON leaves
+    config-fingerprint primitive for cache keys (the spgemm plan cache
+    keys its entries through this).  Non-JSON leaves
     fall back to str(), so dataclass asdict() payloads with numpy
     scalars stay stable across processes."""
     return hashlib.sha256(
@@ -124,7 +124,10 @@ def compute_fingerprint(app, frag, query_args: Dict[str, Any]) -> Dict[str, Any]
         "query_args": canonical_query_args(query_args),
         # numeric config that changes result bytes
         "x64": bool(jax.config.jax_enable_x64),
-        "spmv_mode": os.environ.get("GRAPE_SPMV", "auto"),
+        # a constant since the one pull: a checkpoint an earlier tree
+        # wrote under its default still restores, one it wrote under
+        # another mode is still refused
+        "spmv_mode": "auto",
         # mesh geometry beyond fnum/vp: the partition layout and the
         # process topology.  A 2-D-partition snapshot must never
         # silently restore into a 1-D worker (the carry layouts

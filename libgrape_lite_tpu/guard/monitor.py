@@ -124,7 +124,7 @@ class GuardMonitor:
         clear or a legitimately re-visited state raises a
         false-positive DivergenceError.  The compiled probe is also
         dropped: state shapes and the fragment arrays it binds may
-        have been rebuilt.  `ledger` is the re-resolved pack ledger
+        have been rebuilt.  `ledger` is the re-resolved plan ledger
         for post-mutation breach bundles — the pre-mutation snapshot
         would misattribute modeled cost, so absent a fresh one it is
         nulled rather than left stale."""

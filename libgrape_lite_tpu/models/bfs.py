@@ -37,8 +37,6 @@ class BFS(ParallelAppBase):
     pipeline_state_key = "depth"
 
     def init_state(self, frag, source=0):
-        import os
-
         from libgrape_lite_tpu.app.base import source_lane_array
 
         # a SEQUENCE of sources builds the batched [k, fnum, vp] carry
@@ -67,29 +65,7 @@ class BFS(ParallelAppBase):
         if self._mx is not None:
             eph_entries.update(self._mx.state_entries("mx_"))
         self._mx_uid = self._mx.uid if self._mx is not None else -1
-        # pack-gather min pull (GRAPE_SPMV=pack): unit-weight tropical
-        # relaxation — min(nbr)+1 == min(nbr+1), so the plan needs no
-        # weight stream; unreached vertices travel as +inf
-        self._pack = None
-        if os.environ.get("GRAPE_SPMV") == "pack":
-            from libgrape_lite_tpu.ops.spmv_pack import (
-                resolve_pack_dispatch,
-                warn_pack_ineligible,
-            )
-
-            if frag.fnum * frag.vp > (1 << 24):
-                warn_pack_ineligible(
-                    "BFS", "depth range exceeds exact f32 range (2^24)"
-                )
-            else:
-                self._pack = resolve_pack_dispatch(
-                    frag, direction="ie", mirror=self._mx
-                )
-                if self._pack is None:
-                    warn_pack_ineligible("BFS", "no pack plan buildable")
-                else:
-                    eph_entries.update(self._pack.state_entries())
-        # superstep pipelining (r9): after the exchange/SpMV decisions,
+        # superstep pipelining (r9): after the exchange decision,
         # which the pipelined round reuses verbatim (see SSSP)
         self._pipeline = None
         if not batched and not self._dyn:
@@ -97,8 +73,7 @@ class BFS(ParallelAppBase):
 
             self._pipeline = resolve_pipeline(
                 frag, app_name="BFS", key="depth", direction="ie",
-                mirror=self._mx, mx_prefix="mx_", pack=self._pack,
-                with_weights=False,
+                mirror=self._mx, mx_prefix="mx_", with_weights=False,
             )
             if self._pipeline is not None:
                 eph_entries.update(self._pipeline.host_entries)
@@ -108,7 +83,6 @@ class BFS(ParallelAppBase):
         if eph_entries:
             state.update(eph_entries)
             self.ephemeral_keys = frozenset(eph_entries)
-        self._pack_uid = self._pack.uid if self._pack is not None else -1
         return state
 
     def peval(self, ctx: StepContext, frag, state):
@@ -124,20 +98,10 @@ class BFS(ParallelAppBase):
         else:
             full = ctx.gather_state(depth)
             nbr = ie.edge_nbr
-        if self._pack is not None:
-            full_f = jnp.where(
-                full == sent, jnp.float32(jnp.inf),
-                full.astype(jnp.float32),
-            )
-            red = self._pack.reduce(full_f, state, "min") + 1.0
-            relaxed = jnp.where(
-                jnp.isfinite(red), red.astype(jnp.int32), sent
-            )
-        else:
-            cand = pull_gather(full, nbr, ie.edge_mask, sent, add=1,
-                               absent=sent)
-            relaxed = self.segment_reduce(cand, ie.edge_src, frag.vp,
-                                          "min", row_ptr=ie.indptr)
+        cand = pull_gather(full, nbr, ie.edge_mask, sent, add=1,
+                           absent=sent)
+        relaxed = self.segment_reduce(cand, ie.edge_src, frag.vp,
+                                      "min", row_ptr=ie.indptr)
         if "dyn_ie_nbr" in state:
             # staged delta edges (dyn/): extra unit-weight candidates
             # merged at the fold; `full` is pid-addressed in overlay
@@ -165,41 +129,24 @@ class BFS(ParallelAppBase):
         sent = jnp.int32(_SENTINEL)
         full = pl.splice(ctx, depth, state, xbuf)
         bmask = state["pl_bmask"]
-
-        def pack_relax(dispatch):
-            full_f = jnp.where(
-                full == sent, jnp.float32(jnp.inf),
-                full.astype(jnp.float32),
-            )
-            red = dispatch.reduce(full_f, state, "min") + 1.0
-            return jnp.where(
-                jnp.isfinite(red), red.astype(jnp.int32), sent
-            )
-
-        if pl.pack_b is not None:
-            rel_b = pack_relax(pl.pack_b)
-        else:
-            cand_b = pull_gather(
-                full, state["pl_b_nbr"], state["pl_b_val"], sent,
-                add=1, absent=sent,
-            )
-            rel_b = self.segment_reduce(
-                cand_b, state["pl_b_src"], frag.vp, "min"
-            )
+        cand_b = pull_gather(
+            full, state["pl_b_nbr"], state["pl_b_val"], sent,
+            add=1, absent=sent,
+        )
+        rel_b = self.segment_reduce(
+            cand_b, state["pl_b_src"], frag.vp, "min"
+        )
         new_b = jnp.minimum(depth, rel_b)
         xbuf2 = pl.kickoff(ctx, jnp.where(bmask, new_b, depth), state)
         # ---- pipelined window: carry reads below are named in
         # parallel/pipeline.PIPELINE_WINDOW_READS (grape-lint R6) ----
-        if pl.pack_i is not None:
-            rel_i = pack_relax(pl.pack_i)
-        else:
-            cand_i = pull_gather(
-                full, state["pl_i_nbr"], state["pl_i_val"], sent,
-                add=1, absent=sent,
-            )
-            rel_i = self.segment_reduce(
-                cand_i, state["pl_i_src"], frag.vp, "min"
-            )
+        cand_i = pull_gather(
+            full, state["pl_i_nbr"], state["pl_i_val"], sent,
+            add=1, absent=sent,
+        )
+        rel_i = self.segment_reduce(
+            cand_i, state["pl_i_src"], frag.vp, "min"
+        )
         with jax.named_scope("grape.app.update"):
             new_i = jnp.minimum(depth, rel_i)
             new = jnp.where(bmask, new_b, new_i)

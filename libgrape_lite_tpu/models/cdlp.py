@@ -84,7 +84,7 @@ class CDLP(ParallelAppBase):
 
         self._pipeline = resolve_pipeline(
             frag, app_name=type(self).__name__, key="labels",
-            direction="oe", mirror=None, pack=None, with_weights=False,
+            direction="oe", mirror=None, with_weights=False,
         )
         if self._pipeline is not None:
             state.update(self._pipeline.host_entries)

@@ -13,10 +13,10 @@ budget AS the hop bound — after k `inceval` rounds the depth plane
 holds exactly the <= k-hop ball around the source.  Everything BFS
 earned rides along for free: the `batch_query_key="source"` contract
 (serve/ coalesces k sources into one vmapped dispatch), the dyn
-overlay fold (staged delta edges join the neighborhood exactly), the
-pack-gather SpMV, and the guard invariants.  `k` is a constructor
-hyperparameter (it is baked into the while_loop bound, so it rides
-`trace_key` and two k's never share a compile).
+overlay fold (staged delta edges join the neighborhood exactly) and
+the guard invariants.  `k` is a constructor hyperparameter (it is baked
+into the while_loop bound, so it rides `trace_key` and two k's never
+share a compile).
 
 Result: hop distance for members of the ball, -1 outside (the
 reference sampler emits empty lists for unreached frontiers).

@@ -29,8 +29,8 @@ r11 (ops/spgemm_pack.py): the promised successor landed as the tiled
 masked-SpGEMM backend — GRAPE_LCC_BACKEND = intersect | spgemm | auto
 routes the triangle-credit pass through pruned [128, 128] bitmap-tile
 products reduced on the MXU instead of the O(N/32)-per-row popcount
-sweep; `auto` prices both static ledgers at the pack cost model's
-rates and records the decision (declines too — never silent) in
+sweep; `auto` prices both static ledgers at the active rate
+profile and records the decision (declines too — never silent) in
 spgemm_pack.SPGEMM_STATS.  Per-vertex triangle counts are
 integer-identical across backends (same 3-credit algebra over the same
 oriented dedup edge set), so the lcc output is BIT-exact either way:

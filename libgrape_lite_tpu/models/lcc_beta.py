@@ -161,7 +161,7 @@ class LCCBeta(ParallelAppBase):
         if eperm is not None:
             state["eperm"] = eperm
             # read-only schedule table: keep it out of the fused-loop
-            # carry and the result state (the spmv_pack stream-table
+            # carry and the result state (the ephemeral stream-table
             # convention, worker.py eph_part)
             self.ephemeral_keys = frozenset({"eperm"})
         return state

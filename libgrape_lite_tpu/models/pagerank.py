@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from libgrape_lite_tpu.app.base import BatchShuffleAppBase, StepContext
-from libgrape_lite_tpu.ops.segment import pull_gather
+from libgrape_lite_tpu.ops.segment import pull_gather, segment_reduce
 from libgrape_lite_tpu.utils.types import LoadStrategy, MessageStrategy
 
 
@@ -133,18 +133,6 @@ class PageRank(BatchShuffleAppBase):
                 }
             else:
                 state["seed"] = seed[0]
-        # SpMV path selection (GRAPE_SPMV env: auto|xla|strict|pack):
-        #   pack   — the pack-gather Pallas pipeline (ops/spmv_pack.py),
-        #            f32 + single-shard; the round-2 perf design
-        #   strict — the strict-tile kernel (ops/spmv.py)
-        #   auto   — XLA segment_sum, EXCEPT on a TPU backend with f32
-        #            state where `strict_worthwhile` holds on the worst
-        #            tile span: there `plan_for_app` engages the strict
-        #            kernel (ops/spmv.py); no chip A/B has judged it yet
-        import os
-
-        self._spmv_mode = os.environ.get("GRAPE_SPMV", "auto")
-        self._pack = None
         eph_entries = {}
         # mirror-compressed exchange (GRAPE_EXCHANGE): sync only
         # outer-vertex rows instead of all_gathering the full state
@@ -154,57 +142,15 @@ class PageRank(BatchShuffleAppBase):
         if self._mx is not None:
             eph_entries.update(self._mx.state_entries("mx_"))
         self._mx_uid = self._mx.uid if self._mx is not None else -1
-        if self._spmv_mode == "pack":
-            from libgrape_lite_tpu.ops.spmv_pack import (
-                resolve_pack_dispatch,
-                warn_pack_ineligible,
-            )
-
-            if self.dtype != np.float32:
-                warn_pack_ineligible(
-                    "PageRank", f"state dtype {self.dtype} is not float32"
-                )
-            else:
-                # single-shard: stream tables close over the trace;
-                # multi-shard: they enter as sharded ephemeral state
-                self._pack = resolve_pack_dispatch(frag, mirror=self._mx)
-                if self._pack is None:
-                    warn_pack_ineligible(
-                        "PageRank", "no pack plan buildable"
-                    )
-                else:
-                    eph_entries.update(self._pack.state_entries())
         if eph_entries:
             state.update(eph_entries)
             self.ephemeral_keys = frozenset(eph_entries)
-        # bake the plan identity into the trace key: a cached runner
-        # must never pair with a different fragment's closed-over plan
-        self._pack_plan_uid = (
-            self._pack.uid if self._pack is not None else -1
-        )
-        if self._pack is None:
-            from libgrape_lite_tpu.ops.spmv import plan_for_app
-
-            plan = plan_for_app(frag, frag.vp, self.dtype)
-            self._spmv_tile = plan[1] if plan else 0
-            self._spmv_rmax = plan[2] if plan else 0
-            if plan:
-                row_lo = plan[0]
-                if batched:
-                    # pass-through carry leaves need the lane axis too
-                    row_lo = np.broadcast_to(
-                        row_lo, (len(sources),) + row_lo.shape
-                    ).copy()
-                state["spmv_row_lo"] = row_lo
-        else:
-            self._spmv_tile = self._spmv_rmax = 0
         # superstep pipelining (r9) needs a fold that splits bit-stably
         # into a boundary and an interior slice.  No serial sum here
-        # does: the scan of ops/segment.py, the strict-tile kernel and
-        # the pack backend all group a row's addends by tile, and tile
-        # partial sums regroup under a split.  So PageRank declines, on
-        # the record, and runs its serial round whatever GRAPE_PIPELINE
-        # says (pinned in tests/test_pipeline.py)
+        # does: the scan of ops/segment.py groups a row's addends by
+        # tile, and tile partial sums regroup under a split.  So
+        # PageRank declines, on the record, and runs its serial round
+        # whatever GRAPE_PIPELINE says (pinned in tests/test_pipeline.py)
         if not batched:
             from libgrape_lite_tpu.parallel.pipeline import resolve_pipeline
 
@@ -251,7 +197,7 @@ class PageRank(BatchShuffleAppBase):
         )
         total_dangling = ctx.sum(dangling.sum().astype(dt))
         state = dict(
-            state,  # preserve pass-through keys (e.g. spmv_row_lo)
+            state,  # preserve pass-through keys (e.g. seed, mx_*)
             rank=rank,
             step=jnp.int32(0),
             dangling_sum=p * total_dangling,
@@ -301,7 +247,7 @@ class PageRank(BatchShuffleAppBase):
         finald = jnp.where(deg > 0, nxt * deg.astype(dt), nxt)
         rank_out = jnp.where(is_last, finald, nxt)
         new_state = dict(
-            state,  # preserve pass-through keys (e.g. spmv_row_lo)
+            state,  # preserve pass-through keys (e.g. seed, mx_*)
             rank=rank_out,
             step=step,
             dangling_sum=dangling_sum,
@@ -322,22 +268,9 @@ class PageRank(BatchShuffleAppBase):
         else:
             full = ctx.gather_state(rank)
             nbr = ie.edge_nbr
-        if self._pack is not None:
-            # pack-gather pipeline: the plan owns BOTH the x[nbr]
-            # gather and the row reduction (pad edges were excluded at
-            # plan time, so no mask multiply is needed)
-            cur = self._pack.reduce(full, state, "sum").astype(dt)
-            return self.round_update(frag, state, cur)
         contrib = pull_gather(full, nbr, ie.edge_mask, jnp.asarray(0, dt))
-        from libgrape_lite_tpu.ops.spmv import segment_sum_auto
-
-        plan = (
-            (state["spmv_row_lo"], self._spmv_tile, self._spmv_rmax)
-            if "spmv_row_lo" in state
-            else None
-        )
-        cur = segment_sum_auto(
-            contrib, ie.edge_src, frag.vp, plan, row_ptr=ie.indptr
+        cur = segment_reduce(
+            contrib, ie.edge_src, frag.vp, "sum", row_ptr=ie.indptr
         ).astype(dt)
         return self.round_update(frag, state, cur)
 

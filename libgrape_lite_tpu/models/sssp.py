@@ -41,8 +41,6 @@ class SSSP(ParallelAppBase):
     pipeline_state_key = "dist"
 
     def init_state(self, frag, source=0):
-        import os
-
         import jax
 
         if not frag.weighted or frag.host_ie[0].edge_w is None:
@@ -65,20 +63,8 @@ class SSSP(ParallelAppBase):
             frag, source, "SSSP", np.inf, 0.0, dtype
         )
         dist = dist if batched else dist[0]
-        # tropical pack pipeline (ops/spmv_pack.py, GRAPE_SPMV=pack):
-        # min-relaxation with the f32 weight stream baked into the plan
-        self._pack = None
         state = {"dist": dist}
         eph_entries = {}
-        # fused dense pull (r6): pre-mask the weight stream ONCE at init
-        # (inf at masked edges), so the per-round relax is one gather +
-        # one add — the separate edge_mask select pass is gone and the
-        # result is bit-identical (x + inf == inf == the old masked
-        # lane; distances never reach -inf, so no NaN).  The host CSRs
-        # are already padded to the device Ep, so the stream stacks
-        # uniformly.  GRAPE_SSSP_FUSE=0 reverts for A/B.
-        self._fuse = os.environ.get("GRAPE_SSSP_FUSE", "1") not in (
-            "0", "")
         from libgrape_lite_tpu.parallel.mirror import resolve_mirror_plan
 
         # dyn/ overlay: staged delta edges ride as ephemeral side
@@ -101,40 +87,21 @@ class SSSP(ParallelAppBase):
         if self._mx is not None:
             eph_entries.update(self._mx.state_entries("mx_"))
         self._mx_uid = self._mx.uid if self._mx is not None else -1
-        if os.environ.get("GRAPE_SPMV") == "pack":
-            from libgrape_lite_tpu.ops.spmv_pack import (
-                resolve_pack_dispatch,
-                warn_pack_ineligible,
-            )
-
-            if np.dtype(dtype) != np.float32:
-                warn_pack_ineligible(
-                    "SSSP", f"state dtype {np.dtype(dtype)} is not float32"
-                )
-            elif not frag.weighted:
-                warn_pack_ineligible(
-                    "SSSP", "fragment has no edge weights"
-                )
-            else:
-                self._pack = resolve_pack_dispatch(
-                    frag, with_weights=True, mirror=self._mx
-                )
-                if self._pack is None:
-                    warn_pack_ineligible("SSSP", "no pack plan buildable")
-                else:
-                    eph_entries.update(self._pack.state_entries())
-        if self._pack is not None:
-            self._fuse = False  # pack bakes the weight stream already
-        if self._fuse:
-            eph_entries["wf_eff"] = np.stack([
-                np.where(frag.host_ie[f].edge_mask,
-                         frag.host_ie[f].edge_w,
-                         np.asarray(np.inf, frag.host_ie[f].edge_w.dtype))
-                for f in range(frag.fnum)
-            ])
-        # superstep pipelining (r9): resolved AFTER the exchange mode
-        # and SpMV backend, because the pipelined round must reuse both
-        # decisions verbatim for byte-identity; batched lanes keep the
+        # fused dense pull (r6): pre-mask the weight stream ONCE at init
+        # (inf at masked edges), so the per-round relax is one gather +
+        # one add with no separate edge_mask select pass (x + inf ==
+        # inf; distances never reach -inf, so no NaN).  The host CSRs
+        # are already padded to the device Ep, so the stream stacks
+        # uniformly.
+        eph_entries["wf_eff"] = np.stack([
+            np.where(frag.host_ie[f].edge_mask,
+                     frag.host_ie[f].edge_w,
+                     np.asarray(np.inf, frag.host_ie[f].edge_w.dtype))
+            for f in range(frag.fnum)
+        ])
+        # superstep pipelining (r9): resolved AFTER the exchange mode,
+        # because the pipelined round must reuse that decision
+        # verbatim for byte-identity; batched lanes keep the
         # serial body (the vmapped runner is not pipelined)
         self._pipeline = None
         if not batched and not self._dyn:
@@ -142,20 +109,15 @@ class SSSP(ParallelAppBase):
 
             self._pipeline = resolve_pipeline(
                 frag, app_name="SSSP", key="dist", direction="ie",
-                mirror=self._mx, mx_prefix="mx_", pack=self._pack,
-                with_weights=True,
+                mirror=self._mx, mx_prefix="mx_", with_weights=True,
             )
             if self._pipeline is not None:
                 eph_entries.update(self._pipeline.host_entries)
         self._pipeline_uid = (
             self._pipeline.uid if self._pipeline is not None else -1
         )
-        if eph_entries:
-            state.update(eph_entries)
-            self.ephemeral_keys = frozenset(eph_entries)
-        self._pack_plan_uid = (
-            self._pack.uid if self._pack is not None else -1
-        )
+        state.update(eph_entries)
+        self.ephemeral_keys = frozenset(eph_entries)
         return state
 
     def peval(self, ctx: StepContext, frag, state):
@@ -172,20 +134,11 @@ class SSSP(ParallelAppBase):
         else:
             full = ctx.gather_state(dist)
             nbr = ie.edge_nbr
-        if self._pack is not None:
-            relaxed = self._pack.reduce(full, state, "min")
-        elif self._fuse:
-            # one gather pass: the pre-masked weight stream (wf_eff,
-            # inf at masked edges) folds the relax-mask select into the
-            # add — bit-identical to the where() form
-            cand = pull_gather(full, nbr, add=state["wf_eff"])
-            relaxed = self.segment_reduce(cand, ie.edge_src, frag.vp, "min",
-                                          row_ptr=ie.indptr)
-        else:
-            inf = jnp.asarray(jnp.inf, dist.dtype)
-            cand = pull_gather(full, nbr, ie.edge_mask, inf, add=ie.edge_w)
-            relaxed = self.segment_reduce(cand, ie.edge_src, frag.vp, "min",
-                                          row_ptr=ie.indptr)
+        # one gather pass: the pre-masked weight stream (wf_eff, inf
+        # at masked edges) folds the relax-mask select into the add
+        cand = pull_gather(full, nbr, add=state["wf_eff"])
+        relaxed = self.segment_reduce(cand, ie.edge_src, frag.vp, "min",
+                                      row_ptr=ie.indptr)
         if "dyn_ie_nbr" in state:
             # staged delta edges (dyn/): one extra gather + segment_min
             # over the dense overlay slots, merged at the fold — `full`
@@ -216,30 +169,24 @@ class SSSP(ParallelAppBase):
         full = pl.splice(ctx, dist, state, xbuf)
         inf = jnp.asarray(jnp.inf, dist.dtype)
         bmask = state["pl_bmask"]
-        if pl.pack_b is not None:
-            rel_b = pl.pack_b.reduce(full, state, "min")
-        else:
-            cand_b = pull_gather(
-                full, state["pl_b_nbr"], state["pl_b_val"], inf,
-                add=state["pl_b_w"],
-            )
-            rel_b = self.segment_reduce(
-                cand_b, state["pl_b_src"], frag.vp, "min"
-            )
+        cand_b = pull_gather(
+            full, state["pl_b_nbr"], state["pl_b_val"], inf,
+            add=state["pl_b_w"],
+        )
+        rel_b = self.segment_reduce(
+            cand_b, state["pl_b_src"], frag.vp, "min"
+        )
         new_b = jnp.minimum(dist, rel_b)
         xbuf2 = pl.kickoff(ctx, jnp.where(bmask, new_b, dist), state)
         # ---- pipelined window: every carry read below is named in
         # parallel/pipeline.PIPELINE_WINDOW_READS (grape-lint R6) ----
-        if pl.pack_i is not None:
-            rel_i = pl.pack_i.reduce(full, state, "min")
-        else:
-            cand_i = pull_gather(
-                full, state["pl_i_nbr"], state["pl_i_val"], inf,
-                add=state["pl_i_w"],
-            )
-            rel_i = self.segment_reduce(
-                cand_i, state["pl_i_src"], frag.vp, "min"
-            )
+        cand_i = pull_gather(
+            full, state["pl_i_nbr"], state["pl_i_val"], inf,
+            add=state["pl_i_w"],
+        )
+        rel_i = self.segment_reduce(
+            cand_i, state["pl_i_src"], frag.vp, "min"
+        )
         with jax.named_scope("grape.app.update"):
             new_i = jnp.minimum(dist, rel_i)
             new = jnp.where(bmask, new_b, new_i)

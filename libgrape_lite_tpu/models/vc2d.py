@@ -24,9 +24,7 @@ Layout (fragment (i, j) = mesh device (i, j), fid = i*k + j):
 Per round (inceval):
 
   1. local scatter-reduce: candidates over the tile's edges fold into
-     [vc] row partials for chunk j via ops/segment.py (or the per-tile
-     pack plan — resolve_pack_dispatch runs on the tile's COO->CSR
-     block, so the MXU scan + stream-diet wins of PRs 2/4 carry over);
+     [vc] row partials for chunk j via ops/segment.py;
   2. pmin along the row axis completes chunk j (column-sharded);
   3. ONE transpose ppermute ((i,j) -> (j,i)) re-aligns the completed
      fold row-sharded, and the master fold + termination vote run on
@@ -132,15 +130,14 @@ def vc_finalize_rows(frag, flat: np.ndarray) -> np.ndarray:
 
 class VC2DMinAppBase(GatherScatterAppBase):
     """Shared scaffolding of the tropical-min vertex-cut apps: the
-    row-sharded carry, the per-tile pack resolve, the SUMMA round and
-    the diagonal-master finalize.  Subclasses declare `state_key` and
-    the candidate builder."""
+    row-sharded carry, the SUMMA round and the diagonal-master
+    finalize.  Subclasses declare `state_key` and the candidate
+    builder."""
 
     load_strategy = LoadStrategy.kNullLoadStrategy
     message_strategy = MessageStrategy.kGatherScatter
     mesh_kind = "vc2d"
     state_key = ""          # the carry leaf ("dist"/"depth"/"comp")
-    needs_weights = False
 
     def custom_specs(self):
         return {
@@ -151,12 +148,10 @@ class VC2DMinAppBase(GatherScatterAppBase):
     # ---- shared init scaffolding ----
 
     def _init_common(self, frag, carry: np.ndarray):
-        """Carry + ephemeral leaves, per-tile pack resolve, and the
-        partition fingerprint facts that key the compiled-runner cache
-        (a 1-D and a 2-D compile must never share an entry — `k` and
-        the mode ride in trace_key as primitive attributes)."""
-        import os
-
+        """Carry + ephemeral leaves and the partition fingerprint facts
+        that key the compiled-runner cache (a 1-D and a 2-D compile
+        must never share an entry — `k` and the mode ride in trace_key
+        as primitive attributes)."""
         state = {self.state_key: carry}
         eph_entries = {"vmask_row": frag.vertex_mask()}
         self._partition = "2d"
@@ -165,18 +160,12 @@ class VC2DMinAppBase(GatherScatterAppBase):
         # decided on the HOST fragment (the traced VCDeviceFragment
         # carries only geometry); a primitive, so it rides trace_key
         self._src_pull = self._wants_src_pull(frag)
-        self._pack_ie = self._pack_oe = None
-        if os.environ.get("GRAPE_SPMV") == "pack":
-            self._resolve_tile_packs(frag, eph_entries)
-        self._pack_uid = (
-            self._pack_ie.uid if self._pack_ie is not None else -1
-        )
         from libgrape_lite_tpu.parallel.pipeline import (
             resolve_vc2d_pipeline,
         )
 
         self._pipeline = resolve_vc2d_pipeline(
-            frag, app_name=type(self).__name__, pack=self._pack_ie,
+            frag, app_name=type(self).__name__,
             src_pull=self._src_pull,
             dtype_bytes=int(np.dtype(carry.dtype).itemsize),
         )
@@ -190,43 +179,6 @@ class VC2DMinAppBase(GatherScatterAppBase):
         state.update(eph_entries)
         self.ephemeral_keys = frozenset(eph_entries)
         return state
-
-    def _pack_eligible(self, frag) -> str | None:
-        """None = eligible; otherwise the warn_pack_ineligible reason."""
-        if frag.k * frag.vc > (1 << 24):
-            return "gpid value space exceeds exact f32 range (2^24)"
-        return None
-
-    def _resolve_tile_packs(self, frag, eph_entries: dict):
-        from libgrape_lite_tpu.ops.spmv_pack import (
-            resolve_pack_dispatch,
-            warn_pack_ineligible,
-        )
-
-        name = type(self).__name__
-        why = self._pack_eligible(frag)
-        if why is not None:
-            warn_pack_ineligible(name, why)
-            return
-        role = f"vc2d-k{frag.k}"
-        ie = resolve_pack_dispatch(
-            frag, direction="ie", prefix="pk_ie_", role=role,
-            with_weights=self.needs_weights,
-        )
-        oe = (
-            resolve_pack_dispatch(
-                frag, direction="oe", prefix="pk_oe_", role=role,
-                with_weights=self.needs_weights,
-            )
-            if self._src_pull else None
-        )
-        if ie is None or (self._src_pull and oe is None):
-            warn_pack_ineligible(name, "no tile pack plan buildable")
-            return
-        self._pack_ie, self._pack_oe = ie, oe
-        eph_entries.update(ie.state_entries())
-        if oe is not None:
-            eph_entries.update(oe.state_entries())
 
     def _wants_src_pull(self, frag) -> bool:
         """Directed WCC pulls the src side too (weak connectivity needs
@@ -242,7 +194,7 @@ class VC2DMinAppBase(GatherScatterAppBase):
 
     def _dst_partial(self, ctx, frag, val_row, state):
         """Tile-local candidates folded into [vc] chunk-j partials
-        (pull into dst) — the pack plan or the XLA segment machinery."""
+        (pull into dst)."""
         raise NotImplementedError
 
     def _src_partial(self, ctx, frag, val_col, state):
@@ -315,17 +267,7 @@ class SSSPVC2D(VC2DMinAppBase):
     state_key = "dist"
     result_format = "sssp_infinity"
     needs_edata = True
-    needs_weights = True
     batch_query_key = "source"
-
-    def _pack_eligible(self, frag):
-        import jax
-
-        if jax.config.jax_enable_x64:
-            return "state dtype float64 is not float32"
-        if not frag.weighted:
-            return "fragment has no edge weights"
-        return None
 
     def init_state(self, frag, source=0):
         import jax
@@ -347,8 +289,6 @@ class SSSPVC2D(VC2DMinAppBase):
 
     def _dst_partial(self, ctx, frag, val_row, state):
         vc = frag.vc
-        if self._pack_ie is not None:
-            return self._pack_ie.reduce(val_row, state, "min")
         inf = jnp.asarray(jnp.inf, val_row.dtype)
         cand = jnp.where(frag.mask, val_row[frag.src % vc] + frag.w, inf)
         return self.segment_reduce(cand, frag.dst % vc, vc, "min")
@@ -381,17 +321,6 @@ class BFSVC2D(VC2DMinAppBase):
     def _dst_partial(self, ctx, frag, val_row, state):
         vc = frag.vc
         sent = jnp.int32(_INT_SENT)
-        if self._pack_ie is not None:
-            # unit-weight tropical relax over the pack routes:
-            # min(nbr) + 1 == min(nbr + 1); unreached rides as +inf
-            val_f = jnp.where(
-                val_row == sent, jnp.float32(jnp.inf),
-                val_row.astype(jnp.float32),
-            )
-            red = self._pack_ie.reduce(val_f, state, "min") + 1.0
-            return jnp.where(
-                jnp.isfinite(red), red.astype(jnp.int32), sent
-            )
         nb = val_row[frag.src % vc]
         cand = jnp.where(
             jnp.logical_and(frag.mask, nb != sent), nb + 1, sent
@@ -440,27 +369,20 @@ class WCCVC2D(VC2DMinAppBase):
         )
         return self._init_common(frag, comp)
 
-    def _label_partial(self, ctx, frag, table, rows, cols, state, pack):
+    def _label_partial(self, ctx, frag, table, rows, cols):
         vc = frag.vc
         big = jnp.int32(_INT_SENT)
-        if pack is not None:
-            # labels travel as exact f32 ints (gpid space < 2^24);
-            # rows with no edges come back +inf
-            red = pack.reduce(table.astype(jnp.float32), state, "min")
-            return jnp.where(
-                jnp.isfinite(red), red.astype(jnp.int32), big
-            )
         cand = jnp.where(frag.mask, table[cols % vc], big)
         return self.segment_reduce(cand, rows % vc, vc, "min")
 
     def _dst_partial(self, ctx, frag, val_row, state):
         return self._label_partial(
-            ctx, frag, val_row, frag.dst, frag.src, state, self._pack_ie
+            ctx, frag, val_row, frag.dst, frag.src
         )
 
     def _src_partial(self, ctx, frag, val_col, state):
         return self._label_partial(
-            ctx, frag, val_col, frag.src, frag.dst, state, self._pack_oe
+            ctx, frag, val_col, frag.src, frag.dst
         )
 
     def invariants(self, frag, state):

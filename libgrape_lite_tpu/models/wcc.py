@@ -43,8 +43,6 @@ class WCC(ParallelAppBase):
     pipeline_state_key = "comp"
 
     def init_state(self, frag, **_):
-        import os
-
         vp = frag.vp
         pids = np.arange(frag.fnum * vp, dtype=np.int32).reshape(frag.fnum, vp)
         # padded rows get a big sentinel so they never win a min
@@ -78,36 +76,6 @@ class WCC(ParallelAppBase):
                 if self._mx_oe is not None:
                     eph_entries.update(self._mx_oe.state_entries("mx_oe_"))
         self._mx_uid = self._mx_ie.uid if self._mx_ie is not None else -1
-        # pack-gather min pull (GRAPE_SPMV=pack): the label space must
-        # stay exactly representable in f32 (labels are pids < 2^24)
-        self._pack_ie = self._pack_oe = None
-        if os.environ.get("GRAPE_SPMV") == "pack":
-            from libgrape_lite_tpu.ops.spmv_pack import (
-                resolve_pack_dispatch,
-                warn_pack_ineligible,
-            )
-
-            if frag.fnum * vp > (1 << 24):
-                warn_pack_ineligible(
-                    "WCC", "pid label space exceeds exact f32 range (2^24)"
-                )
-            else:
-                ie = resolve_pack_dispatch(frag, direction="ie",
-                                           prefix="pk_ie_",
-                                           mirror=self._mx_ie)
-                oe = (
-                    resolve_pack_dispatch(frag, direction="oe",
-                                          prefix="pk_oe_",
-                                          mirror=self._mx_oe)
-                    if frag.directed else None
-                )
-                if ie is None or (frag.directed and oe is None):
-                    warn_pack_ineligible("WCC", "no pack plan buildable")
-                else:
-                    self._pack_ie, self._pack_oe = ie, oe
-                    eph_entries.update(ie.state_entries())
-                    if oe is not None:
-                        eph_entries.update(oe.state_entries())
         # superstep pipelining (r9): undirected single-pull split, or
         # the directed two-kickoff double-pull form (leg 2 = oe)
         self._pipeline = None
@@ -117,7 +85,7 @@ class WCC(ParallelAppBase):
             self._pipeline = resolve_pipeline(
                 frag, app_name="WCC", key="comp", direction="ie",
                 mirror=self._mx_ie, mx_prefix="mx_ie_",
-                pack=self._pack_ie, with_weights=False,
+                with_weights=False,
                 direction2="oe" if frag.directed else None,
                 mirror2=self._mx_oe if frag.directed else None,
                 eligible=(type(self)._post_pull is WCC._post_pull),
@@ -133,15 +101,12 @@ class WCC(ParallelAppBase):
         if eph_entries:
             state.update(eph_entries)
             self.ephemeral_keys = frozenset(eph_entries)
-        self._pack_uid = (
-            self._pack_ie.uid if self._pack_ie is not None else -1
-        )
         return state
 
     def peval(self, ctx: StepContext, frag, state):
         return state, jnp.int32(1)
 
-    def _pull(self, ctx, frag, comp, csr, pack=None, state=None,
+    def _pull(self, ctx, frag, comp, csr, state=None,
               mx=None, mx_prefix="mx_ie_", dyn_prefix=None):
         big = jnp.int32(np.iinfo(np.int32).max)
         if mx is not None:
@@ -150,17 +115,9 @@ class WCC(ParallelAppBase):
         else:
             full = ctx.gather_state(comp)
             nbr = csr.edge_nbr
-        if pack is not None:
-            # tropical min over the static pack routes: labels travel
-            # as exact f32 ints; rows with no edges come back +inf
-            red = pack.reduce(full.astype(jnp.float32), state, "min")
-            red = jnp.where(
-                jnp.isfinite(red), red.astype(jnp.int32), big
-            )
-        else:
-            cand = pull_gather(full, nbr, csr.edge_mask, big)
-            red = self.segment_reduce(cand, csr.edge_src, frag.vp, "min",
-                                      row_ptr=csr.indptr)
+        cand = pull_gather(full, nbr, csr.edge_mask, big)
+        red = self.segment_reduce(cand, csr.edge_src, frag.vp, "min",
+                                  row_ptr=csr.indptr)
         if dyn_prefix is not None and dyn_prefix + "nbr" in state:
             # staged delta edges (dyn/): extra label candidates merged
             # at the fold; `full` is pid-addressed in overlay mode
@@ -182,13 +139,13 @@ class WCC(ParallelAppBase):
         comp = state["comp"]
         new = jnp.minimum(
             comp,
-            self._pull(ctx, frag, comp, frag.ie, self._pack_ie, state,
+            self._pull(ctx, frag, comp, frag.ie, state,
                        self._mx_ie, "mx_ie_", dyn_prefix="dyn_ie_"),
         )
         if frag.directed:
             new = jnp.minimum(
                 new,
-                self._pull(ctx, frag, new, frag.oe, self._pack_oe, state,
+                self._pull(ctx, frag, new, frag.oe, state,
                            self._mx_oe, "mx_oe_", dyn_prefix="dyn_oe_"),
             )
         with jax.named_scope("grape.app.update"):
@@ -211,35 +168,22 @@ class WCC(ParallelAppBase):
         big = jnp.int32(np.iinfo(np.int32).max)
         full = pl.splice(ctx, comp, state, xbuf)
         bmask = state["pl_bmask"]
-
-        def pack_fold(dispatch):
-            red = dispatch.reduce(full.astype(jnp.float32), state, "min")
-            return jnp.where(
-                jnp.isfinite(red), red.astype(jnp.int32), big
-            )
-
-        if pl.pack_b is not None:
-            rel_b = pack_fold(pl.pack_b)
-        else:
-            cand_b = pull_gather(
-                full, state["pl_b_nbr"], state["pl_b_val"], big
-            )
-            rel_b = self.segment_reduce(
-                cand_b, state["pl_b_src"], frag.vp, "min"
-            )
+        cand_b = pull_gather(
+            full, state["pl_b_nbr"], state["pl_b_val"], big
+        )
+        rel_b = self.segment_reduce(
+            cand_b, state["pl_b_src"], frag.vp, "min"
+        )
         new_b = jnp.minimum(comp, rel_b)
         xbuf2 = pl.kickoff(ctx, jnp.where(bmask, new_b, comp), state)
         # ---- pipelined window: carry reads below are named in
         # parallel/pipeline.PIPELINE_WINDOW_READS (grape-lint R6) ----
-        if pl.pack_i is not None:
-            rel_i = pack_fold(pl.pack_i)
-        else:
-            cand_i = pull_gather(
-                full, state["pl_i_nbr"], state["pl_i_val"], big
-            )
-            rel_i = self.segment_reduce(
-                cand_i, state["pl_i_src"], frag.vp, "min"
-            )
+        cand_i = pull_gather(
+            full, state["pl_i_nbr"], state["pl_i_val"], big
+        )
+        rel_i = self.segment_reduce(
+            cand_i, state["pl_i_src"], frag.vp, "min"
+        )
         with jax.named_scope("grape.app.update"):
             new_i = jnp.minimum(comp, rel_i)
             new = jnp.where(bmask, new_b, new_i)

@@ -43,7 +43,6 @@ _LOCK = threading.Lock()
 # (or registered with a broken snapshot) is an error, exactly the
 # check_bench_schema discipline for bench blocks.
 EXPECTED: Dict[str, str] = {
-    "plan": "libgrape_lite_tpu.ops.spmv_pack",
     "spgemm": "libgrape_lite_tpu.ops.spgemm_pack",
     "partition": "libgrape_lite_tpu.fragment.partition",
     "pipeline": "libgrape_lite_tpu.parallel.pipeline",

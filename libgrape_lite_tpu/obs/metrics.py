@@ -3,7 +3,7 @@ Prometheus-text and JSON snapshot.
 
 The registry is the query-end complement to the tracer's timeline:
 spans say *when*, metrics say *how much in total* — rounds, active
-vertices per round, bytes streamed from the pack ledger, guard probe
+vertices per round, bytes streamed from the plan ledger, guard probe
 verdicts, checkpoint save/restore latency, retry attempts, rollback
 count.  Instruments are created on first use (`registry.counter(name)`
 is get-or-create), so call sites never coordinate registration.

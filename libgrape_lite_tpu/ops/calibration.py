@@ -5,10 +5,9 @@ model — the 1-D/2-D partition ledger (fragment/partition.py), the
 ``GRAPE_LCC_BACKEND=auto`` intersect-vs-spgemm choice
 (ops/spgemm_pack.py), the pipeline engage model
 (parallel/pipeline.overlap_model), autopilot admission
-(autopilot/admission.py), the fleet HBM budget (fleet/budget.py) and
-the analytic SpMV model (scripts/pack_cost_model.py).  Until r17 each
-carried its own private copy of the hand-pinned v5e rates; this
-module makes ONE :class:`RateProfile` the single source of pricing
+(autopilot/admission.py) and the fleet HBM budget (fleet/budget.py).
+Until r17 each carried its own private copy of the hand-pinned v5e
+rates; this module makes ONE :class:`RateProfile` the single source of pricing
 constants, and adds the machinery to *fit* those rates from measured
 device walls instead of faith (the SparseP discipline: measured-rate-
 driven selection, applied to the whole selector family):
@@ -28,15 +27,14 @@ driven selection, applied to the whole selector family):
   wall measurement.  Ill-conditioned sample sets FAIL loudly
   (:class:`CalibrationError`); the fitter never silently
   extrapolates a rate the samples cannot identify.
-* :func:`microbench_samples` — the seeded sweep: real jitted pack
-  SpMV (both scan modes) and masked-SpGEMM dispatches across a small
-  geometry grid, walls taken sync-before-close
-  (``block_until_ready``), regressors read from each plan's shipped
+* :func:`microbench_samples` — the seeded sweep: real jitted
+  masked-SpGEMM dispatches across a small geometry grid, walls taken
+  sync-before-close (``block_until_ready``), regressors read from each plan's shipped
   op-budget ledger.
 * :func:`harvest_dispatch` / :func:`harvested_samples` — live
   harvest: the telemetry plane's per-dispatch ``device_us`` stage
   stamp (serve/session.py) joined to the dispatching worker's
-  already-shipped pack-ledger recount.  Armed via
+  already-shipped ledger recount.  Armed via
   ``GRAPE_CALIBRATE_HARVEST=1``; disarmed it is one cached env read.
 * :func:`drift_report` — modeled-vs-measured drift per priced
   surface under a profile; the bench ``calibration`` lane and
@@ -50,10 +48,8 @@ The calibration wall model is the ADDITIVE form
          + gather/(rows_per_cycle*clock) + hbm_bytes/hbm_bps
 
 — conservative (no compute/HBM overlap assumed), linear in the
-regressors, and therefore exactly fittable.  The analytic
-MTEPS bracket in scripts/pack_cost_model.py keeps its
-``max(compute, hbm)`` form for reporting; both read their rates from
-the same profile.  docs/CALIBRATION.md is the user guide.
+regressors, and therefore exactly fittable.  docs/CALIBRATION.md is
+the user guide.
 """
 
 from __future__ import annotations
@@ -70,7 +66,7 @@ HARVEST_ENV = "GRAPE_CALIBRATE_HARVEST"
 PROFILE_SCHEMA_VERSION = 1
 
 #: modeled-vs-measured drift past this fraction fails the gate
-#: (the same 5% the pack op-budget ledger recount gates at)
+#: (the same 5% the op-budget ledger recount gates at)
 DRIFT_TOLERANCE = 0.05
 
 #: column-normalized design matrices worse than this are refused —
@@ -688,7 +684,7 @@ def _bench_fragment(scale: int, ef: int, seed: int):
     rng = np.random.default_rng(seed)
     n = 1 << scale
     e = n * ef
-    # hub-skewed draw so plans exercise the hub tier + fold levels
+    # hub-skewed draw so the oriented DAG has dense and sparse tiles
     src = np.minimum(
         rng.integers(0, n, e),
         rng.integers(0, n, e),
@@ -718,49 +714,6 @@ def _timed_call(fn, args, repeats: int) -> float:
         jax.block_until_ready(out)
         best = min(best, time.perf_counter() - t0)
     return best
-
-
-def _spmv_sample(scale: int, ef: int, seed: int, scan_mode: str,
-                 repeats: int) -> Optional[dict]:
-    import jax
-    import jax.numpy as jnp
-
-    from libgrape_lite_tpu.ops.spmv_pack import (
-        resolve_pack_dispatch,
-    )
-
-    prev = os.environ.get("GRAPE_PACK_SCAN")
-    os.environ["GRAPE_PACK_SCAN"] = scan_mode
-    try:
-        frag = _bench_fragment(scale, ef, seed)
-        disp = resolve_pack_dispatch(frag)
-        if disp is None:
-            return None
-        led = disp.ledger()
-        if not led:
-            return None
-        fn = jax.jit(lambda x: disp.reduce(x, {}, "sum"))
-        x = jnp.asarray(
-            np.random.default_rng(seed + 1).normal(
-                size=frag.vp
-            ).astype(np.float32)
-        )
-        wall = _timed_call(fn, (x,), repeats)
-        t = led["totals"]
-        return {
-            "surface": "spmv",
-            "geometry": f"s{scale}ef{ef}:{scan_mode}",
-            "wall_s": wall,
-            "vpu_ops": int(t["vpu_ops"]),
-            "mxu_ops": int(t["mxu_ops"]),
-            "gather_rows": int(t["gather_rows"]),
-            "hbm_bytes": int(t["hbm_bytes"]),
-        }
-    finally:
-        if prev is None:
-            os.environ.pop("GRAPE_PACK_SCAN", None)
-        else:
-            os.environ["GRAPE_PACK_SCAN"] = prev
 
 
 def _spgemm_sample(scale: int, ef: int, seed: int,
@@ -803,25 +756,16 @@ def _spgemm_sample(scale: int, ef: int, seed: int,
 
 def microbench_samples(scales: Sequence[int] = (8, 9, 10),
                        ef: int = 8, seed: int = 7,
-                       repeats: int = 3,
-                       scan_modes: Sequence[str] = ("shift", "mxu"),
-                       spgemm: bool = True) -> List[dict]:
-    """The seeded sweep: pack SpMV (per scan mode — shift levels ship
-    zero MXU planes, mxu levels a fixed 3/slot, so the two modes
-    decorrelate the vpu/mxu columns) and masked-SpGEMM dispatches
-    across a small geometry grid.  Exchange dispatches need a >1
-    device mesh; on a 1-device backend the exchange rates stay
-    inherited (recorded in ``profile.unfitted`` by the fit)."""
+                       repeats: int = 3) -> List[dict]:
+    """The seeded sweep: masked-SpGEMM dispatches across a small
+    geometry grid.  Exchange dispatches need a >1 device mesh; on a
+    1-device backend the exchange rates stay inherited (recorded in
+    ``profile.unfitted`` by the fit)."""
     samples: List[dict] = []
     for i, scale in enumerate(scales):
-        for mode in scan_modes:
-            s = _spmv_sample(scale, ef, seed + 13 * i, mode, repeats)
-            if s is not None:
-                samples.append(s)
-        if spgemm:
-            s = _spgemm_sample(scale, ef, seed + 13 * i, repeats)
-            if s is not None:
-                samples.append(s)
+        s = _spgemm_sample(scale, ef, seed + 13 * i, repeats)
+        if s is not None:
+            samples.append(s)
     return samples
 
 
@@ -838,7 +782,7 @@ def harvest_armed() -> bool:
 def harvest_dispatch(stages: Optional[dict], totals: Optional[dict],
                      rounds: int) -> Optional[dict]:
     """Join one dispatch's telemetry stage stamp (``device_us``) to
-    its worker's shipped pack-ledger recount: the ledger totals are
+    its worker's shipped ledger recount: the ledger totals are
     per ROUND, the device stamp covers the whole fused while_loop, so
     the regressor columns scale by `rounds`.  Returns the sample (and
     appends it to the harvest buffer), or None when the dispatch
@@ -902,8 +846,8 @@ def harvest_overlap(plan_brief: Optional[dict],
 def harvest_from_worker(worker, stages: Optional[dict],
                         rounds: int) -> Optional[dict]:
     """The serve-session hook: pull the dispatching worker's merged
-    pack-ledger totals and harvest the stamp (no-op when the worker
-    has no pack ledger — XLA-path apps ship no recount columns)."""
+    ledger totals and harvest the stamp (no-op when the worker
+    has no ledger — the pull apps ship no recount columns)."""
     try:
         led = worker.pack_ledger()
     except Exception:

@@ -1,9 +1,9 @@
 """Tiled masked SpGEMM for triangle-style workloads on the MXU.
 
 ROADMAP item 5a: the GraphBLAS triangle-count formulation
-``B = (A · Aᵀ) ∘ A`` over the degree-oriented DAG, lowered the way the
-pack machinery lowers SpMV — all irregularity compiled into static
-streams at plan time, the per-round dataflow dense vector/matrix work.
+``B = (A · Aᵀ) ∘ A`` over the degree-oriented DAG, with all
+irregularity compiled into static streams at plan time and the
+per-round dataflow dense vector/matrix work.
 
 Formulation (the output-stationary form of masked SpGEMM): with ``D``
 the deduplicated degree-oriented adjacency (v → u iff (deg, id) orders
@@ -24,8 +24,7 @@ enumerates mask edges directly and tiles the CONTRACTION dimension:
   * the kernel processes items in chunks of ``cfg.chunk``: gather the
     two packed rows' k-tile words, expand to dense uint8 [chunk, 128]
     blocks, AND them, and reduce the hit block to per-edge counts with
-    one ``[chunk, 128] @ [128, 128]`` matmul — the same MXU lowering
-    shape PR 4 validated for the pack scan (a VPU tree-reduce would
+    one ``[chunk, 128] @ [128, 128]`` matmul (a VPU tree-reduce would
     work too; the matmul keeps the reduction off the vector unit);
   * credits scatter per item: ``cnt`` to the apex v and middle u pids,
     the hit VECTOR to the far-end pids of tile k (a static
@@ -41,7 +40,7 @@ streams padded to the cross-shard max (shard_map needs one static
 program).  Credits accumulate in a pid-indexed vector folded by one
 ``psum`` — exactly the popcount kernel's credit exchange.
 
-Cost: the static op-budget ledger carries the PR 4 split columns
+Cost: the static op-budget ledger carries split engine columns
 (``vpu_ops`` / ``mxu_ops`` / ``hbm_bytes``) under conventions mirrored
 (and independently recounted) by scripts/pack_cost_model.py.  The
 popcount intersect pays 3 · n_pad/32 word-ops per edge per pass —
@@ -51,7 +50,7 @@ lifts: the item count scales with the pruned tile products instead
 discipline follows SparseP, arxiv 2201.05072).
 
 `GRAPE_LCC_BACKEND` = intersect | spgemm | auto selects the LCC
-backend; `auto` prices both ledgers at the pack cost model's rates.
+backend; `auto` prices both ledgers at the active rate profile.
 Declines are RECORDED in SPGEMM_STATS — never silent.
 """
 
@@ -148,8 +147,7 @@ class SpGemmPlan:
     uid: int = field(default_factory=lambda: next(_PLAN_COUNTER))
 
 
-# stream-name -> dtype table (fingerprinted in the disk-cache digest,
-# like spmv_pack._STREAM_DTYPES)
+# stream-name -> dtype table (fingerprinted in the disk-cache digest)
 _SG_DTYPES = {
     "bm": "uint32", "vrow": "int32", "urow": "int32", "kt": "int32",
     "apex": "int32", "mid": "int32", "valid": "int8", "colpid": "int32",
@@ -158,10 +156,9 @@ _SG_DTYPES = {
 
 def _ledger_from_counts(items: int, mask_edges: int, n_chunks: int,
                         hbm_bytes: int) -> dict:
-    """The op-budget ledger under the conventions above — the same
-    shape spmv_pack.plan_ledger emits (split engine columns, per-stage
-    attribution, one level), so Worker.pack_ledger and the bench
-    consume both interchangeably."""
+    """The op-budget ledger under the conventions above: split engine
+    columns, per-stage attribution, one level — the shape
+    Worker.pack_ledger and the bench consume."""
     per_stage = {
         k: v * C * items for k, v in _ITEM_VPU_PLANES.items()
     }
@@ -517,11 +514,24 @@ def spgemm_credits(state: dict, prefix: str, n_pad: int, chunk: int):
 # dispatch resolution: per-fragment cache + persistent plan cache
 # --------------------------------------------------------------------------
 
+_FRAG_PLAN_CACHE = None
+
+
+def _frag_cache(frag):
+    """The plans resolved for `frag`, weak-keyed on the fragment so they
+    go with it (fleet/budget.plan_stream_bytes prices them)."""
+    global _FRAG_PLAN_CACHE
+    import weakref
+
+    if _FRAG_PLAN_CACHE is None:
+        _FRAG_PLAN_CACHE = weakref.WeakKeyDictionary()
+    return _FRAG_PLAN_CACHE.setdefault(frag, {})
+
 
 class SpGemmDispatch:
     """Resolved spgemm backend for one fragment: the plan plus the
     state-entry plumbing (streams ride as ephemeral [fnum, ...] state
-    leaves, the spmv_pack PackDispatch convention)."""
+    leaves)."""
 
     def __init__(self, plan: SpGemmPlan, prefix: str = "sg_"):
         self.plan = plan
@@ -559,11 +569,8 @@ def resolve_spgemm_dispatch(frag, degree_threshold: int = 0,
                             prefix: str = "sg_") -> SpGemmDispatch:
     """Resolve (and cache) the spgemm plan for `frag`: per-fragment
     memo first, then the persistent plan cache (GRAPE_PACK_PLAN_CACHE,
-    `spgemmplan_*` entries — digest-disjoint from pack plans by
-    construction), then the host planner.  Counters in SPGEMM_STATS
-    mirror spmv_pack.PLAN_STATS."""
-    from libgrape_lite_tpu.ops.spmv_pack import _frag_cache
-
+    `spgemmplan_*` entries), then the host planner.  SPGEMM_STATS
+    counts which of the three served."""
     cfg = cfg or SpGemmConfig.from_env()
     per_frag = _frag_cache(frag)
     key = ("spgemm", cfg, int(degree_threshold))
@@ -586,10 +593,8 @@ def resolve_spgemm_dispatch(frag, degree_threshold: int = 0,
 
 
 def _spgemm_digest(v, u, frag, thr: int, cfg: SpGemmConfig) -> str:
-    """Content key for cached spgemm plans.  `backend: spgemm` and the
-    spgemm schema version are IN the digest (and the filename prefix
-    differs), so a pack plan and a spgemm plan can never share a disk
-    entry even for identical edge streams."""
+    """Content key for cached spgemm plans: `backend: spgemm` and the
+    spgemm schema version are IN the digest."""
     import hashlib
 
     from libgrape_lite_tpu.ft.fingerprint import stable_config_digest
@@ -831,8 +836,6 @@ def resolve_lcc_backend(app_name: str, frag,
     # "price" tag) so serve-style Worker churn re-prices for free; an
     # already-engaged materialized plan is reused directly — its
     # ledger is the exact one the recount gate validates
-    from libgrape_lite_tpu.ops.spmv_pack import _frag_cache
-
     cfg = SpGemmConfig.from_env()
     per_frag = _frag_cache(frag)
     plan = per_frag.get(("spgemm", cfg, int(degree_threshold)))

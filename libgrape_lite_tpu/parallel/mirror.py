@@ -21,10 +21,6 @@ TPU formulation (static shapes, one collective):
   [fnum, M], one `all_to_all`, one concat -> x_compact.  ICI bytes
   drop from fnum*vp to fnum*M per device per round; state never
   materialises at O(fnum*vp).
-
-The compact column space composes with the pack-gather SpMV: pack
-plans built over `nbr_compact` gather from x_compact, shrinking the
-pass table from fnum*vp to vp + fnum*M entries.
 """
 
 from __future__ import annotations

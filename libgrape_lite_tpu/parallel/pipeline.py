@@ -86,16 +86,12 @@ PIPELINE_WINDOW_READS = frozenset({
     # (WCC oe leg) — both parts fold inside the window, which opens at
     # the FIRST kickoff of the round
     "pl2_*",
-    # interior pack sub-plan streams (read inside PackDispatch.reduce)
-    "pki_*",
 })
 
 # Callees AUDITED to receive the whole carry dict inside the window.
 # R6 cannot see into another module's function body, so passing the
 # full `state` to an un-named callee after the kickoff is flagged as a
 # whole-carry escape; each name here was audited by hand:
-#   reduce        PackDispatch.reduce — reads only its own pk*_ stream
-#                 leaves (pki_*/pkb_ prefixes) plus the table argument
 #   kickoff       PipelinePlan.kickoff — reads only its send_key leaf
 #                 (the mirror send table, a static host stream), never
 #                 a live carry value; the directed double-pull round
@@ -104,7 +100,7 @@ PIPELINE_WINDOW_READS = frozenset({
 #                 dict at all (mirror mode concatenates its explicit
 #                 args; gather mode reads only ctx.fid())
 PIPELINE_WINDOW_CALLEES = frozenset({
-    "reduce", "kickoff", "splice",
+    "kickoff", "splice",
 })
 
 # resolve-path registry: the last pipeline decision + split stats, so
@@ -152,8 +148,8 @@ VPU_LANES_PER_CYCLE = _default_profile().vpu_lanes_per_cycle
 CLOCK_HZ = _default_profile().clock_hz
 ICI_BPS = _default_profile().ici_bps
 DEFAULT_OPS_PER_EDGE = 30.0     # op COUNT per edge (XLA gather+segment
-#                                 fold, no pack ledger) — a counting
-#                                 convention, not a rate; stays literal
+#                                 fold) — a counting convention, not a
+#                                 rate; stays literal
 
 
 def pipeline_min_hidden_us() -> float:
@@ -207,10 +203,10 @@ def _round_up(x: int, m: int) -> int:
 class PipelinePlan:
     """One resolved boundary/interior pipeline for an app's pull.
 
-    Host side: the split edge streams (or pack sub-dispatches) ride as
-    ephemeral state leaves — `host_entries` merges into the app's init
-    state exactly like mirror/pack tables (closure capture would trip
-    grape-lint R1 and replicate under shard_map).  Traced side:
+    Host side: the split edge streams ride as ephemeral state leaves —
+    `host_entries` merges into the app's init state exactly like
+    mirror tables (closure capture would trip grape-lint R1 and
+    replicate under shard_map).  Traced side:
     `exchange`/`kickoff`/`splice` are the three collective touchpoints
     of the pipelined round (see module docstring)."""
 
@@ -221,13 +217,10 @@ class PipelinePlan:
     m: int                     # mirror slots (0 in gather mode)
     send_key: str              # state key of the mirror send table
     prefix: str = "pl_"
-    pack_b: Optional[object] = None   # boundary PackDispatch
-    pack_i: Optional[object] = None   # interior PackDispatch
     stats: dict = field(default_factory=dict)
     exchange_bytes: int = 0
     decision: dict = field(default_factory=dict)
     host_entries: dict = field(default_factory=dict)
-    ops_per_edge: Optional[float] = None
     # second exchange leg of the directed double-pull round (WCC oe):
     # None when single-direction.  leg=2 on exchange/kickoff/splice
     # routes through these instead — same wiring, second direction.
@@ -244,12 +237,11 @@ class PipelinePlan:
         re-resolves of the same plan: a per-resolve counter here made
         every query recompile (trace_key changed each init_state),
         which turned the bench A/B into a compile-time measurement.
-        Stream SHAPES (split sizes, sub-plan skeletons) already key
-        the runner cache via the state struct; this only needs the
-        routing facts the struct cannot see."""
+        Stream SHAPES (split sizes) already key the runner cache via
+        the state struct; this only needs the routing facts the struct
+        cannot see."""
         return (
             f"{self.mode}:{self.fnum}:{self.vp}:{self.m}:"
-            f"{'pack' if self.pack_b is not None else 'xla'}:"
             f"{self.mode2 or '-'}"
         )
 
@@ -312,7 +304,7 @@ class PipelinePlan:
         t = self.stats.get("totals", {})
         model = overlap_model(
             t.get("boundary_edges", 0), t.get("interior_edges", 0),
-            self.exchange_bytes, self.ops_per_edge,
+            self.exchange_bytes,
         )
         return {
             "engaged": True,
@@ -337,7 +329,7 @@ class PipelinePlan:
         t = self.stats.get("totals", {})
         model = overlap_model(
             t.get("boundary_edges", 0), t.get("interior_edges", 0),
-            self.exchange_bytes, self.ops_per_edge,
+            self.exchange_bytes,
         )
         return round(
             min(model["compute_interior_s"], model["exchange_s"]) * 1e6,
@@ -406,19 +398,18 @@ def _split_streams(frag, bmask: np.ndarray, direction: str, mirror,
 
 def resolve_pipeline(frag, *, app_name: str, key: str,
                      direction: str = "ie", mirror=None,
-                     mx_prefix: str = "mx_", pack=None,
+                     mx_prefix: str = "mx_",
                      with_weights: bool = False,
                      eligible: bool = True, reason: str = "",
                      direction2: str | None = None, mirror2=None,
                      mx2_prefix: str = "mx_oe_"):
     """Resolve the superstep pipeline for one app's pull, or None.
 
-    `mirror`/`pack` are the app's ALREADY-RESOLVED exchange and SpMV
-    backends — the pipelined round must use the same exchange mode and
-    the same fold machinery as the serial one, or byte-identity is
-    off the table.  Every fold that pipelines is exact under a split
-    (min, CDLP's mode); a float sum is not, so PageRank passes
-    `eligible=False` with its reason.  Decline reasons are recorded
+    `mirror` is the app's ALREADY-RESOLVED exchange — the pipelined
+    round must use the same exchange mode as the serial one, or
+    byte-identity is off the table.  Every fold that pipelines is
+    exact under a split (min, CDLP's mode); a float sum is not, so
+    PageRank passes `eligible=False` with its reason.  Decline reasons are recorded
     in PIPELINE_STATS["last_decision"] (and vlogged), never silent.
 
     `direction2` requests the directed DOUBLE-PULL round (WCC on a
@@ -452,14 +443,6 @@ def resolve_pipeline(frag, *, app_name: str, key: str,
     ov = getattr(frag, "dyn_overlay", None)
     if ov is not None:
         return declined("dyn overlay attached (pid-addressed reads)")
-    if direction2 is not None and pack is not None:
-        # the double-pull round would need FOUR pack sub-plans (b/i per
-        # direction) whose split fold order is unaudited against the
-        # serial two-pull round; the XLA stream path is the pipelined
-        # form until that audit lands
-        return declined("directed double-pull over the pack backend "
-                        "is unaudited; XLA streams only")
-
     xmode = "mirror" if mirror is not None else "gather"
     bytes_ledger = exchange_bytes_ledger(
         frag.fnum, frag.vp, mirror.m if mirror is not None else None
@@ -515,8 +498,7 @@ def resolve_pipeline(frag, *, app_name: str, key: str,
         # PipelinePlan.uid; re-stamped authoritatively on engage)
         decision["plan_uid"] = (
             f"{xmode}:{frag.fnum}:{frag.vp}:"
-            f"{mirror.m if mirror is not None else 0}:"
-            f"{'pack' if pack is not None else 'xla'}:{xmode2 or '-'}"
+            f"{mirror.m if mirror is not None else 0}:{xmode2 or '-'}"
         )
         if hidden_us < min_hidden:
             return declined(
@@ -525,50 +507,23 @@ def resolve_pipeline(frag, *, app_name: str, key: str,
                 f"GRAPE_PIPELINE_MIN_HIDDEN_US={min_hidden:g} floor"
             )
 
-    pack_b = pack_i = None
-    host_entries = {}
-    ops_per_edge = None
-    if pack is not None:
-        from libgrape_lite_tpu.ops.spmv_pack import resolve_pack_dispatch
-
-        inner = frag.host_inner_mask()
-        pack_b = resolve_pack_dispatch(
-            frag, direction=direction, prefix="pkb_", mirror=mirror,
-            with_weights=with_weights, role="boundary", row_mask=bmask,
+    host_entries = _split_streams(
+        frag, bmask, direction, mirror, with_weights, "pl_"
+    )
+    if direction2 is not None:
+        h2 = _split_streams(
+            frag, bmask, direction2, mirror2, with_weights, "pl2_"
         )
-        pack_i = resolve_pack_dispatch(
-            frag, direction=direction, prefix="pki_", mirror=mirror,
-            with_weights=with_weights, role="interior",
-            row_mask=np.logical_and(inner, ~bmask),
-        )
-        if pack_b is None or pack_i is None:
-            return declined("pack split sub-plans not buildable "
-                            "(empty partition?)")
-        led = pack.ledger()
-        if led and led.get("edges"):
-            ops_per_edge = led["totals"]["vpu_ops"] / led["edges"]
-        host_entries.update(pack_b.state_entries())
-        host_entries.update(pack_i.state_entries())
-        host_entries["pl_bmask"] = bmask
-    else:
-        host_entries.update(_split_streams(
-            frag, bmask, direction, mirror, with_weights, "pl_"
-        ))
-        if direction2 is not None:
-            h2 = _split_streams(
-                frag, bmask, direction2, mirror2, with_weights, "pl2_"
-            )
-            h2.pop("pl2_bmask")  # one joint mask, already under pl_
-            host_entries.update(h2)
+        h2.pop("pl2_bmask")  # one joint mask, already under pl_
+        host_entries.update(h2)
 
     decision["engaged"] = True
     plan = PipelinePlan(
         mode=xmode, key=key, fnum=frag.fnum, vp=frag.vp,
         m=mirror.m if mirror is not None else 0,
         send_key=mx_prefix + "send",
-        pack_b=pack_b, pack_i=pack_i,
         stats=stats, exchange_bytes=xbytes, decision=decision,
-        host_entries=host_entries, ops_per_edge=ops_per_edge,
+        host_entries=host_entries,
         mode2=xmode2,
         m2=mirror2.m if mirror2 is not None else 0,
         send_key2=mx2_prefix + "send",
@@ -624,7 +579,6 @@ class VC2DPipelinePlan:
     exchange_bytes: int = 0
     decision: dict = field(default_factory=dict)
     host_entries: dict = field(default_factory=dict)
-    ops_per_edge: Optional[float] = None
     mode: str = "vc2d"
 
     @property
@@ -637,7 +591,7 @@ class VC2DPipelinePlan:
         t = self.stats.get("totals", {})
         model = overlap_model(
             t.get("boundary_edges", 0), t.get("interior_edges", 0),
-            self.exchange_bytes, self.ops_per_edge,
+            self.exchange_bytes,
         )
         return {
             "engaged": True,
@@ -656,7 +610,7 @@ class VC2DPipelinePlan:
         t = self.stats.get("totals", {})
         model = overlap_model(
             t.get("boundary_edges", 0), t.get("interior_edges", 0),
-            self.exchange_bytes, self.ops_per_edge,
+            self.exchange_bytes,
         )
         return round(
             min(model["compute_interior_s"], model["exchange_s"]) * 1e6,
@@ -664,7 +618,7 @@ class VC2DPipelinePlan:
         )
 
 
-def resolve_vc2d_pipeline(frag, *, app_name: str, pack=None,
+def resolve_vc2d_pipeline(frag, *, app_name: str,
                           src_pull: bool = False,
                           dtype_bytes: int = 4):
     """Resolve the pipelined SUMMA round for a vc2d app, or None.
@@ -677,8 +631,6 @@ def resolve_vc2d_pipeline(frag, *, app_name: str, pack=None,
       * `src_pull` (directed WCC's column-axis pull) declines — the
         second pull folds the TRANSPOSED relax of the first, a
         dependent chain with no independent work to overlap;
-      * a resolved per-tile pack plan declines — it is one fused
-        dispatch whose phase split is unaudited;
       * a tile ring too small to split in two 128-multiple phases
         declines (nothing to overlap).
 
@@ -711,13 +663,6 @@ def resolve_vc2d_pipeline(frag, *, app_name: str, pack=None,
             "the transposed row relax — a dependent chain with no "
             "independent fold to overlap"
         )
-    if pack is not None:
-        return declined(
-            "per-tile pack plan resolved: a single fused dispatch "
-            "whose phase split is unaudited; unset GRAPE_SPMV=pack "
-            "to pipeline the 2-D round"
-        )
-
     _, _, _, m_arr = frag._host_tiles
     ep = int(m_arr.shape[1])
     split = min(_round_up(max(ep // 2, 1), 128), ep)

@@ -1,7 +1,7 @@
 """serve/ — the multi-query serving runtime (ROADMAP item 1).
 
-A `ServeSession` pins one loaded graph — HBM-resident CSR shards, pack
-plans, compiled fused runners — and serves many queries against it
+A `ServeSession` pins one loaded graph — HBM-resident CSR shards and
+compiled fused runners — and serves many queries against it
 with zero re-planning and zero recompilation after the first hit of
 each (app, state-shape, max_rounds).  An `AdmissionQueue` coalesces
 compatible point queries into vmapped multi-source batches

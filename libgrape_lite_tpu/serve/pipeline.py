@@ -115,8 +115,8 @@ class PumpStats:
         self.events = []
 
 
-#: module-level record shared by every pump in the process (like the
-#: pack plan_stats counters): tests/bench read it, reset() between runs
+#: module-level record shared by every pump in the process:
+#: tests/bench read it, reset() between runs
 PUMP_STATS = PumpStats()
 
 # federated as "pump" (obs/federation.py): the class keeps its own

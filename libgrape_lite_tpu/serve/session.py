@@ -6,9 +6,6 @@ expensive per-graph artifacts are pinned ONCE and every query reuses
 them —
 
   * the HBM-resident sharded fragment (`frag.dev` device CSRs),
-  * pack plans (ops/spmv_pack resolves through its per-fragment cache
-    + the v3 on-disk plan cache; `plan_stats()` proves the planner
-    never re-runs),
   * compiled fused runners, keyed by (app hyperparameters, state
     shape, max_rounds) in each app's resident Worker
     (`Worker._runner_cache` — the session owns the workers, so the
@@ -49,7 +46,7 @@ def _calibration_harvester():
     """The live-harvest hook (ops/calibration.py): when
     GRAPE_CALIBRATE_HARVEST is armed, returns the callable that joins
     a dispatch's telemetry `device_us` stamp to its worker's shipped
-    pack-ledger recount; None (the common case) costs one env read."""
+    ledger recount; None (the common case) costs one env read."""
     from libgrape_lite_tpu.ops import calibration
 
     if not calibration.harvest_armed():
@@ -142,16 +139,13 @@ class ServeSession:
 
     def cache_stats(self) -> dict:
         """Aggregated cache counters: compiled-runner hits/misses over
-        every resident worker plus the pack resolve-path counters —
-        the numbers the zero-recompile/zero-replanning acceptance
-        asserts on."""
-        from libgrape_lite_tpu.ops.spmv_pack import plan_stats
-
+        every resident worker — the numbers the zero-recompile
+        acceptance asserts on."""
         runner = {"hits": 0, "misses": 0}
         for w in self._workers.values():
             runner["hits"] += w.runner_cache_stats["hits"]
             runner["misses"] += w.runner_cache_stats["misses"]
-        return {"runner": runner, "pack": plan_stats()}
+        return {"runner": runner}
 
     # ---- lifecycle: eviction / re-admission / close (fleet/) --------------
 
@@ -170,11 +164,10 @@ class ServeSession:
         shared with a sibling session (`release_fragment=False`, the
         FleetManager's call) — delete the fragment's device arrays.
 
-        Everything HOST-side stays warm: the per-fragment pack-plan
-        cache (weak-keyed on this very fragment object), the v3 disk
-        plan cache, the compiled-runner caches, the mirror plans.
-        `restore_device` therefore re-admits with ZERO pack
-        re-planning and ZERO XLA recompiles — counter- and
+        Everything HOST-side stays warm: the per-fragment plan cache
+        (weak-keyed on this very fragment object), the compiled-runner
+        caches, the mirror plans.  `restore_device` therefore re-admits
+        with ZERO re-planning and ZERO XLA recompiles — counter- and
         compile_events-pinned by tests/test_fleet.py."""
         if self._pump is not None and self._pump.inflight():
             self._pump.quiesce(reason="release_device")
@@ -216,8 +209,8 @@ class ServeSession:
         pumped loop makes this a superstep boundary by construction —
         no query is ever mid-flight here).  Below the repack threshold
         the staged edges ride the overlay side-path and the next query
-        of a warmed shape compiles NOTHING (runner cache hit, zero
-        pack planning — pinned by tests/test_dyn.py); at a repack the
+        of a warmed shape compiles NOTHING (runner cache hit —
+        pinned by tests/test_dyn.py); at a repack the
         rebuilt fragment is adopted into every resident worker and the
         recompiles that follow are COUNTED in cache_stats, never
         silent.  Returns the DynGraph report ({mode, staged, ...})."""
@@ -298,7 +291,7 @@ class ServeSession:
             return (app_key, "?unknown", tenant)
         # batch_query_key is a CLASS attribute: read it off the
         # registered app class directly — instantiating the resident
-        # Worker here (as this method once did) built state and pack
+        # Worker here (as this method once did) built state and
         # plans while the queue was merely PICKING a batch, so a bare
         # submit of a never-dispatched app paid a full worker warmup.
         # The tenant tag joins the key so requests of DIFFERENT

@@ -339,21 +339,17 @@ class Worker:
 
     def release_buffers(self) -> None:
         """Drop this worker's device-resident references — the last
-        query's result carry, its fragment provenance, the guard
-        monitor, and any device copies of const-mode pack streams
-        (they lazily rebuild from the cached host plan) — so a fleet
-        eviction (ServeSession.release_device) actually frees the
-        HBM.  The compiled-runner cache is KEPT: re-admission must
-        compile nothing (tests/test_fleet.py pins it)."""
+        query's result carry, its fragment provenance and the guard
+        monitor — so a fleet eviction (ServeSession.release_device)
+        actually frees the HBM.  The compiled-runner cache is KEPT:
+        re-admission must compile nothing (tests/test_fleet.py pins
+        it)."""
         self._result_state = None
         self._result_fragment = None
         self._guard_monitor = None
         self.batch_rounds = None
         self.batch_terminate = None
         self.batch_breaches = None
-        pack = getattr(self.app, "_pack", None)
-        if pack is not None and hasattr(pack, "_const"):
-            pack._const = None
 
     def get_terminate_info(self):
         """(success, info) — reference `Worker::GetTerminateInfo`
@@ -401,7 +397,7 @@ class Worker:
 
         def stepper(frag_stacked, state, eph_state, squeezed):
             frag = frag_stacked.local()
-            # ephemeral leaves (pack stream tables etc.) ride in a
+            # ephemeral leaves (mirror/plan stream tables etc.) ride in a
             # separate, NON-donated argument: they are stripped from the
             # outputs, so donating them could never alias and would only
             # draw 'unusable donation' warnings on the largest buffers
@@ -834,7 +830,7 @@ class Worker:
         """Fused multi-source runner: the SAME PEval+IncEval loop as
         _make_runner, vmapped over a leading lane axis of the carry.
         Each lane is an independent query against the shared HBM-
-        resident fragment and ephemeral streams (pack tables, mirror
+        resident fragment and ephemeral streams (plan tables, mirror
         send tables, pre-masked weights ride once, not per lane); the
         while_loop runs until EVERY lane's active vote has settled, and
         the freeze mask (see _lane_body) keeps finished lanes pinned so
@@ -1417,7 +1413,7 @@ class Worker:
             self._seed_fn = None
 
     def _ledger_brief(self):
-        """Scalar totals of the engaged pack ledger (the query span's
+        """Scalar totals of the engaged plan ledger (the query span's
         modeled-cost attachment: modeled ops/bytes sit next to the
         measured wall/device time in ONE record — the side-by-side the
         SparseP-style roofline accounting needs)."""
@@ -1993,7 +1989,7 @@ class Worker:
         state = self._place_state(state_np)
         led = self.pack_ledger() if glog.vlog_level() >= 1 else None
         if led:
-            # per-stage ALU attribution for the engaged pack plan — the
+            # per-stage ALU attribution for the engaged spgemm plan — the
             # stepwise profile's wall-clock lines read against these
             # modeled shares (first-light playbook step 3); the whole
             # block is gated on the level so a silent run never pays
@@ -2012,19 +2008,6 @@ class Worker:
                 f"{t['blocks']} blocks / {len(led['levels'])} levels "
                 f"(per-stage VPU ops/edge: {stages})",
             )
-            if "pipeline" in led:
-                p = led["pipeline"]
-                glog.vlog(
-                    1,
-                    "pipeline split: %d boundary / %d interior "
-                    "vertices (%d / %d edges), %s exchange, "
-                    "%d B/round",
-                    p.get("boundary_vertices", 0),
-                    p.get("interior_vertices", 0),
-                    p.get("boundary_edges", 0),
-                    p.get("interior_edges", 0),
-                    p.get("mode", "?"), p.get("exchange_bytes", 0),
-                )
         inc_fn = self._single_step_for("inceval", state)
         # a fresh-compiled inc_fn means the FIRST superstep dispatch
         # below includes trace+compile: that round's span gets a
@@ -2362,68 +2345,12 @@ class Worker:
         return state
 
     def pack_ledger(self):
-        """The engaged pack backend's static op-budget ledger
-        (spmv_pack.plan_ledger form), or None when no pack dispatch is
-        resolved on the app — the stepwise profiling hook and external
-        harnesses read per-stage ALU attribution from here.  Apps that
-        resolve SEVERAL dispatches (WCC pulls both directions) get the
-        SUM of their ledgers: the per-round bill is every engaged
-        plan's ops, and attributing only one would mislead the
-        measured-vs-modeled comparison.
-
-        With a superstep pipeline resolved (r9) the ledger carries the
-        boundary-set stats under "pipeline" — boundary/interior
-        vertex+edge totals, exchange mode and modeled bytes — so the
-        plan's split is readable wherever the ledger is (the stepwise
-        vlog, obs query spans, trace_report)."""
-        def with_pipeline(led):
-            pl = self._pipelined()
-            if pl is None:
-                return led
-            return {**led, "pipeline": {
-                **pl.stats.get("totals", {}),
-                "mode": pl.mode,
-                "exchange_bytes": pl.exchange_bytes,
-            }}
-
-        # the pipelined round dispatches the split sub-plans instead
-        # of the full plan, but the split partitions the edge set, so
-        # the full plan's ledger below remains the honest per-round
-        # bill either way.  `_spgemm` (r11, ops/spgemm_pack.py) ships
-        # the same split-column ledger shape, so the masked-SpGEMM
-        # backend's bill surfaces through the identical path
-        ledgers = []
-        for attr in ("_pack", "_pack_ie", "_pack_oe", "_spgemm"):
-            d = getattr(self.app, attr, None)
-            if d is not None and callable(getattr(d, "ledger", None)):
-                led = d.ledger()
-                if led:
-                    ledgers.append(led)
-        if not ledgers:
-            return None
-        if len(ledgers) == 1:
-            return with_pipeline(ledgers[0])
-        totals = {"vpu_ops": 0, "mxu_ops": 0, "gather_rows": 0,
-                  "hbm_bytes": 0, "blocks": 0, "per_stage": {}}
-        out = {"edges": 0, "levels": [], "totals": totals}
-        for di, led in enumerate(ledgers):
-            out["edges"] += led["edges"]
-            # re-index so merged level keys stay unique across plans
-            # (a reader attributing wall clock per level must not see
-            # two colliding "level 0" rows)
-            out["levels"] += [
-                {**lv, "level": len(out["levels"]) + i,
-                 "dispatch": di}
-                for i, lv in enumerate(led["levels"])
-            ]
-            for k in ("vpu_ops", "mxu_ops", "gather_rows",
-                      "hbm_bytes", "blocks"):
-                totals[k] += led["totals"][k]
-            for k, v in led["totals"].get("per_stage", {}).items():
-                totals["per_stage"][k] = (
-                    totals["per_stage"].get(k, 0) + v
-                )
-        return with_pipeline(out)
+        """The engaged spgemm plan's static op-budget ledger
+        (ops/spgemm_pack `_ledger_from_counts` form), or None when the
+        app resolved none — the stepwise profiling hook, guard/ and
+        obs/ read per-stage ALU attribution from here."""
+        d = getattr(self.app, "_spgemm", None)
+        return (d.ledger() or None) if d is not None else None
 
     def resume(self, checkpoint_dir: str, max_rounds: int | None = None, *,
                checkpoint_every: int | None = None, fault_plan=None,

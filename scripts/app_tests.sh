@@ -420,7 +420,7 @@ print(f"  OK (clean; {rec['suppressed']} named suppression(s))")
 EOF
 
 echo "== BENCH record schema (fresh small-scale bench incl. serve block + archived r05) =="
-GRAPE_BENCH_SCALE=10 GRAPE_BENCH_NO_PROBE=1 GRAPE_BENCH_NO_LEDGER=1 \
+GRAPE_BENCH_SCALE=10 GRAPE_BENCH_NO_PROBE=1 \
   GRAPE_BENCH_NO_GUARD=1 python bench.py > "$OUT/bench.json" 2>/dev/null
 python scripts/check_bench_schema.py "$OUT/bench.json" BENCH_r05.json
 python - "$OUT/bench.json" <<'EOF'
@@ -497,7 +497,7 @@ test "$CAL_RC" -eq 2 \
 # corrupted profile exits 2 (every other lane skipped — this tests
 # the gate, not the measurements)
 BENCH_CAL="GRAPE_BENCH_SCALE=10 GRAPE_BENCH_NO_PROBE=1 \
-  GRAPE_BENCH_NO_LEDGER=1 GRAPE_BENCH_NO_GUARD=1 GRAPE_BENCH_NO_SERVE=1 \
+  GRAPE_BENCH_NO_GUARD=1 GRAPE_BENCH_NO_SERVE=1 \
   GRAPE_BENCH_NO_SERVE_ASYNC=1 GRAPE_BENCH_NO_DYN=1 \
   GRAPE_BENCH_NO_PIPELINE=1 GRAPE_BENCH_NO_P2D=1 GRAPE_BENCH_NO_SPGEMM=1 \
   GRAPE_BENCH_NO_FLEET=1 GRAPE_BENCH_NO_AUTOPILOT=1 \

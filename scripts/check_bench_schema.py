@@ -60,9 +60,9 @@ _GUARD = {
     "probes": (int, True),
 }
 
-# the r7 split-engine columns are REQUIRED whenever the block appears:
-# a ledger without the vpu/mxu split is the pre-split format the cost
-# model can no longer recount
+# bench.py stopped emitting this block with the pack pipeline (PR 28);
+# the archived BENCH_r* records still carry it.  The r7 split-engine
+# columns are REQUIRED whenever the block appears
 _PACK_LEDGER = {
     "vpu_ops_per_edge": (_NUM, True),
     "mxu_elems_per_edge": (_NUM, True),
@@ -190,9 +190,9 @@ _OVERLAP_TRUTH = {
 # models/vc2d.py, docs/PARTITION2D.md): hub-heavy RMAT A/B at fnum 4
 # (k=2) — max-tile vs the raw 1-D hub fragment, modeled exchange
 # bytes under the shared ledgers, serial-vs-2D wall, byte/eps
-# identity verdicts, the planner's recorded auto decision vs the
-# measured winner, and the per-tile pack-plan recount drift (the 5%
-# gate).  Verdict fields are DECLARED bool, like the pipeline lane's.
+# identity verdicts, and the planner's recorded auto decision vs the
+# measured winner.  Verdict fields are DECLARED bool, like the
+# pipeline lane's.
 _PARTITION2D = {
     "scale": (int, True),
     "fnum": (int, True),
@@ -217,8 +217,10 @@ _PARTITION2D = {
     "planner_t2d_s": (_NUM, True),
     "measured_winner": (str, True),
     "decision_matches": (bool, True),
-    "tile_plan_ok": (bool, True),
-    "tile_recount_mismatch": (_NUM, True),
+    # emitted until the pack pipeline went (PR 28); archived records
+    # still carry them
+    "tile_plan_ok": (bool, False),
+    "tile_recount_mismatch": (_NUM, False),
 }
 
 # the PR 19 pipelined-SUMMA lane (parallel/pipeline.py

@@ -7,12 +7,11 @@ set -eo pipefail
 cd "$(dirname "$0")/.."
 OUT=${1:-scratch/first_light}
 mkdir -p "$OUT"
-# plans persist across every step below AND later bench re-runs
+# spgemm plans persist across every step below AND later bench re-runs
 export GRAPE_PACK_PLAN_CACHE="$PWD/scratch/pack_plans"
 
 echo "== probe =="
-# must see a REAL accelerator: on a CPU the pack A/B would run
-# interpret-mode, which is not a measurement
+# must see a REAL accelerator: a CPU wall is not a measurement
 if ! timeout 120 python -c "
 import jax
 d = jax.devices()
@@ -38,19 +37,10 @@ echo "== primitive rates (prices the sublane dynamic_gather — the
 cost-model unknown; see docs/PERF_NOTES.md r4 section) =="
 timeout 900 python scripts/pallas_probe.py 2> "$OUT/probe.err" | tee "$OUT/probe.json" || true
 
-echo "== bench A/B (xla vs pack, PageRank + SSSP) =="
+echo "== bench (PageRank + SSSP) =="
 timeout 3600 python bench.py \
   2> "$OUT/bench.err" | tee "$OUT/bench.json" \
   || { tail -20 "$OUT/bench.err" >&2; exit 1; }
-# pack-ineligibility / fallback warnings matter even on success — a
-# silent xla-only A/B must not read as a pack measurement
-grep -iE "pack|warn" "$OUT/bench.err" | tail -10 || true
-
-echo "== scan A/B (mxu triangular-matmul scan vs shift ladder; both
-plans pre-seeded by scripts/seed_pack_plans.py) =="
-GRAPE_SPMV=pack GRAPE_PACK_SCAN=shift \
-  timeout 3600 python bench.py \
-  2> "$OUT/bench_shift.err" | tee "$OUT/bench_shift.json" || true
 
 echo "== pipeline A/B (GRAPE_PIPELINE=0 vs 1 — superstep software
 pipelining, parallel/pipeline.py; the bench's own pipeline lane runs
@@ -90,7 +80,7 @@ grep -h "\[bench\] serve_async" "$OUT/bench_serve_async.err" \
   | tail -8 || true
 
 echo "== per-stage profile (stepwise mode, per-round wall clock) =="
-GRAPE_SPMV=pack GRAPE_TPU_VLOG=1 timeout 1200 python - <<'EOF' 2>&1 | tee "$OUT/profile.log" || true
+GRAPE_TPU_VLOG=1 timeout 1200 python - <<'EOF' 2>&1 | tee "$OUT/profile.log" || true
 import sys
 sys.path.insert(0, ".")
 import numpy as np
@@ -117,14 +107,6 @@ w = Worker(app, frag)
 w.query_stepwise(max_rounds=10)   # logs per-round wall clock
 EOF
 
-echo "== op-budget ledger vs measurement (offline-safe; the stepwise
-profile above logs the same per-stage attribution via the worker's
-pack op-budget vlog line) =="
-timeout 1800 python scripts/pack_cost_model.py \
-  2> "$OUT/cost_model.err" | tee "$OUT/cost_model.json" || {
-  echo "LEDGER/COST-MODEL MISMATCH (see $OUT/cost_model.err)" >&2
-}
-
 echo "== calibrate-then-recheck (r17, ops/calibration.py,
 docs/CALIBRATION.md): fit the FIRST real-TPU rate profile from
 measured device walls, persist profile + sweep, then re-run the
@@ -144,7 +126,6 @@ if [ -f "$OUT/rates.json" ]; then
   GRAPE_BENCH_NO_PIPELINE=1 GRAPE_BENCH_NO_P2D=1 \
   GRAPE_BENCH_NO_SPGEMM=1 GRAPE_BENCH_NO_FLEET=1 \
   GRAPE_BENCH_NO_AUTOPILOT=1 GRAPE_BENCH_NO_TELEMETRY=1 \
-  GRAPE_BENCH_NO_LEDGER=1 \
   timeout 1800 python bench.py \
     > "$OUT/bench_calibrated.json" 2> "$OUT/bench_calibrated.err" || {
     echo "CALIBRATED DRIFT GATE FAILED — the fitted profile drifts" \

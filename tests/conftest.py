@@ -68,3 +68,78 @@ def graph_cache():
         return cache[key]
 
     return get
+
+
+# half of a v5e's VMEM, as `gather_table_budget` reads it on the chip
+GATHER_BUDGET = 64 << 20
+
+
+@pytest.fixture
+def pull_kernel(monkeypatch):
+    """Arms `ops/segment.pull_gather` as it chooses on the TPU backend.
+
+    `pull_kernel("interpreted")` puts `pallas_kernels.vmem_gather` in
+    interpret mode behind the choice (XLA:CPU compiles its unrolled
+    body for 30-75 s wherever it stands in a runner, whatever the
+    graph's size); `pull_kernel("stand_in")` puts `full[nbr]` there
+    (the choice and what the kernel is handed are under test, not the
+    kernel's bits: tests/test_pull_gather.py pins those).  Returns the
+    list the kernel's calls are noted in, as (table dtype, table
+    shape, stream shape); either way a call refuses what the real
+    kernel cannot take."""
+    from libgrape_lite_tpu.ops import pallas_kernels, segment
+
+    def arm(kind: str) -> list:
+        calls = []
+
+        def kernel(full, nbr):
+            assert full.ndim == 1 and full.dtype.itemsize == 4, full
+            assert nbr.ndim == 1 and nbr.dtype == np.int32, nbr
+            calls.append((str(full.dtype), full.shape, nbr.shape))
+            if kind == "interpreted":
+                return pallas_kernels.vmem_gather(full, nbr, interpret=True)
+            assert kind == "stand_in", kind
+            return full[nbr]
+
+        monkeypatch.setattr(segment, "use_pallas", lambda: True)
+        monkeypatch.setattr(segment, "gather_table_budget",
+                            lambda: GATHER_BUDGET)
+        monkeypatch.setattr(segment, "vmem_gather", kernel)
+        return calls
+
+    return arm
+
+
+def gather_took(fn) -> dict:
+    """What `fn()` moved GATHER_STATS by."""
+    from libgrape_lite_tpu.ops.segment import GATHER_STATS
+
+    before = GATHER_STATS.snapshot()
+    fn()
+    return {k: v - before[k] for k, v in GATHER_STATS.snapshot().items()}
+
+
+def rand_frag(fnum, n=900, e=7000, seed=11, weighted=True, directed=False):
+    """A random multigraph with f32 weights (so SSSP's and PageRank's
+    state is 32-bit under this lane's x64), cut over `fnum` fragments."""
+    from libgrape_lite_tpu.fragment.edgecut import ShardedEdgecutFragment
+    from libgrape_lite_tpu.parallel.comm_spec import CommSpec
+    from libgrape_lite_tpu.utils.types import LoadStrategy
+    from libgrape_lite_tpu.vertex_map.partitioner import MapPartitioner
+    from libgrape_lite_tpu.vertex_map.vertex_map import VertexMap
+
+    rng = np.random.default_rng(seed)
+    src = rng.integers(0, n, e)
+    dst = rng.integers(0, n, e)
+    w = (
+        rng.uniform(0.5, 4.0, e).astype(np.float32)
+        if weighted
+        else np.ones(e, dtype=np.float32)
+    )
+    oids = np.arange(n, dtype=np.int64)
+    comm = CommSpec(fnum=fnum)
+    vm = VertexMap.build(oids, MapPartitioner(fnum, oids))
+    return ShardedEdgecutFragment.build(
+        comm, vm, src, dst, w, directed=directed,
+        load_strategy=LoadStrategy.kBothOutIn,
+    )

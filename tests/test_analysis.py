@@ -302,12 +302,12 @@ def test_r6_trips_on_pre_kickoff_alias_read_in_window():
 def test_r6_passes_on_contract_named_reads():
     # every window read is in the shipped contract: the carry leaf
     # ("dist"), the join mask ("pl_bmask"), the interior streams
-    # ("pl_i_*") and the pack sub-plan prefix ("pki_*")
+    # ("pl_i_*") and the second leg's prefix ("pl2_*")
     src = """
     def inceval_pipelined(self, ctx, frag, state, xbuf):
         dist = state["dist"]
         xbuf2 = self._pipeline.kickoff(ctx, dist, state)
-        cand = state["pl_i_nbr"] + state["pki_l0_rows"]
+        cand = state["pl_i_nbr"] + state["pl2_i_nbr"]
         new = cand * state["pl_bmask"] + dist
         return {"dist": new}, 1, xbuf2
     """
@@ -345,16 +345,17 @@ def test_r6_trips_on_whole_carry_escape():
 
 
 def test_r6_passes_on_audited_callees():
-    # reduce (pack sub-plan dispatch), kickoff and splice are named in
-    # PIPELINE_WINDOW_CALLEES — whole-carry passes to them are
-    # audited, in the main body and in nested helpers alike
+    # kickoff and splice are named in PIPELINE_WINDOW_CALLEES —
+    # whole-carry passes to them are audited, in the main body and in
+    # nested helpers alike (the directed round's second leg)
     src = """
     def inceval_pipelined(self, ctx, frag, state, xbuf):
-        def pack_fold(dispatch, table):
-            return dispatch.reduce(table, state, "min")
+        def second_leg(new1, x_oe):
+            return self._pipeline.splice(ctx, new1, state, x_oe, leg=2)
         full = self._pipeline.splice(ctx, state["dist"], state, xbuf)
-        xbuf2 = self._pipeline.kickoff(ctx, state["dist"], state)
-        cur = pack_fold(self._pipeline.pack_i, full)
+        x_oe = self._pipeline.kickoff(ctx, state["dist"], state, leg=2)
+        cur = second_leg(full, x_oe)
+        xbuf2 = self._pipeline.kickoff(ctx, cur, state)
         return {"dist": cur}, 1, xbuf2
     """
     assert "R6" not in _rules(src)
@@ -1210,3 +1211,56 @@ def test_cli_lint_seeded_violation_and_clean_tree(tmp_path):
     # an EMPTY --update-baseline reason (an unset shell variable) is
     # a usage error, not a silent fall-through to a plain lint run
     assert lint_main(["--update-baseline", ""]) == 2
+
+
+# ---- the options PR 28 removed are gone, not hidden -----------------------
+
+REMOVED_OPTIONS = [
+    "GRAPE_SPMV", "GRAPE_SPMV_STRICT", "GRAPE_PACK_SCAN",
+    "GRAPE_PACK_COMPOSE", "GRAPE_PACK_CFG", "GRAPE_PACK_VMEM_BUDGET",
+    "GRAPE_SSSP_FUSE",
+]
+
+
+@pytest.mark.parametrize("name", REMOVED_OPTIONS)
+def test_removed_option_is_spelled_nowhere(name):
+    """The pull has one path (ops/segment.py): no file of the library,
+    the scripts, the smoke or the README reads or offers an option that
+    selected another."""
+    import os
+    import re
+
+    root = os.path.join(os.path.dirname(__file__), "..")
+    files = [os.path.join(root, f) for f in ("chip_smoke.py", "README.md")]
+    for top in ("libgrape_lite_tpu", "scripts"):
+        for d, _, names in os.walk(os.path.join(root, top)):
+            files += [os.path.join(d, n) for n in names
+                      if n.endswith((".py", ".sh", ".md"))]
+    word = re.compile(rf"\b{name}\b")
+    hits = [os.path.relpath(f, root) for f in files
+            if word.search(open(f, encoding="utf-8").read())]
+    assert hits == [], f"{name} is still spelled in {hits}"
+
+
+def test_setting_a_removed_option_changes_nothing(monkeypatch):
+    """`GRAPE_SPMV=pack` in the environment: BFS answers with the same
+    bytes and a checkpoint's fingerprint is the same, so one written
+    under the default by an earlier tree still restores."""
+    from libgrape_lite_tpu.ft.fingerprint import compute_fingerprint
+    from libgrape_lite_tpu.models import APP_REGISTRY
+    from libgrape_lite_tpu.worker.worker import Worker
+    from tests.conftest import rand_frag
+
+    frag = rand_frag(2, weighted=False)
+
+    def run():
+        w = Worker(APP_REGISTRY["bfs"](), frag)
+        w.query(source=0)
+        return (w.result_values().tobytes(),
+                compute_fingerprint(w.app, frag, {"source": 0}))
+
+    monkeypatch.delenv("GRAPE_SPMV", raising=False)
+    values, fingerprint = run()
+    assert fingerprint["spmv_mode"] == "auto"
+    monkeypatch.setenv("GRAPE_SPMV", "pack")
+    assert run() == (values, fingerprint)

@@ -120,3 +120,57 @@ def test_cdlp_opt_single_round(graph_cache):
     base = run_worker(CDLP(), frag, max_round=1)
     opt = run_worker(CDLPOpt(), frag, max_round=1)
     assert base == opt
+
+
+# ---- the same goldens through the pull's kernel ---------------------------
+
+_X32_RULES = {
+    # app -> (query, golden, verifier, eps): tests/x32_check.py's rules
+    # for 32-bit state against the f64 goldens
+    "sssp": ({"source": 6}, "p2p-31-SSSP", eps_verify, 1e-3),
+    "bfs": ({"source": 6}, "p2p-31-BFS", exact_verify, None),
+    "pagerank": ({"delta": 0.85, "max_round": 10}, "p2p-31-PR",
+                 eps_verify, 1e-3),
+    "wcc": ({}, "p2p-31-WCC", wcc_verify, None),
+}
+# what compiles the kernel itself, interpreted (over a minute at this
+# size); the others put `full[nbr]` behind the same choice
+_INTERPRETED = {("pagerank", 1)}
+
+
+@pytest.fixture(scope="module")
+def graph_f32():
+    """p2p-31 with f32 edge data, as a chip run loads it: SSSP's and
+    PageRank's state is then 32-bit, which is what the kernel takes."""
+    from libgrape_lite_tpu.fragment.loader import LoadGraph, LoadGraphSpec
+    from libgrape_lite_tpu.parallel.comm_spec import CommSpec
+
+    cache = {}
+
+    def get(fnum: int):
+        if fnum not in cache:
+            cache[fnum] = LoadGraph(
+                dataset_path("p2p-31.e"), dataset_path("p2p-31.v"),
+                CommSpec(fnum=fnum),
+                LoadGraphSpec(directed=False, weighted=True,
+                              edata_dtype=np.float32))
+        return cache[fnum]
+
+    return get
+
+
+@pytest.mark.parametrize("fnum", [1, 4])
+@pytest.mark.parametrize("app", sorted(_X32_RULES))
+def test_golden_through_the_kernel(app, fnum, graph_f32, pull_kernel):
+    from libgrape_lite_tpu.models import APP_REGISTRY
+    from tests.conftest import gather_took
+
+    query, golden, verify, eps = _X32_RULES[app]
+    calls = pull_kernel("interpreted" if (app, fnum) in _INTERPRETED
+                        else "stand_in")
+    res = []
+    moved = gather_took(lambda: res.append(
+        run_worker(APP_REGISTRY[app](), graph_f32(fnum), **query)))
+    assert moved["kernel"] == len(calls) > 0 and moved["xla"] == 0, moved
+    verify(res[0], load_golden(dataset_path(golden)),
+           **({} if eps is None else {"eps": eps}))

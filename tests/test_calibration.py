@@ -132,7 +132,7 @@ def test_dedupe_both_call_sites_price_identically(scripts_path):
     hbm_s = totals["hbm_bytes"] / p.hbm_bps
     row_s = totals["gather_rows"] / p.gather_rows_per_cycle / p.clock_hz
 
-    priced = pcm.price(totals, edges=1 << 20, profile=p)
+    priced = pcm.price(totals, profile=p)
     assert priced["t_vpu_ms"] == round(vpu_s * 1e3, 2)
     assert priced["t_mxu_ms"] == round(mxu_s * 1e3, 2)
     assert priced["t_hbm_ms"] == round(hbm_s * 1e3, 2)
@@ -454,7 +454,7 @@ def test_admission_shed_record_carries_profile(monkeypatch):
     )
     from libgrape_lite_tpu.autopilot.signals import AUTOPILOT_STATS
     from libgrape_lite_tpu.obs.slo import SLO_STATS
-    from libgrape_lite_tpu.ops.spmv_pack import resolve_pack_dispatch
+    from libgrape_lite_tpu.ops.spgemm_pack import resolve_spgemm_dispatch
 
     # the pure decide: an over-budget tenant's request whose modeled
     # WALL exceeds max_cost_s sheds
@@ -464,7 +464,9 @@ def test_admission_shed_record_carries_profile(monkeypatch):
     assert decide_admission(0.5, 0.0, cfg, cost_s=9.9) == "admit"
 
     frag = _ring_frag(512, chords=32, fnum=1)
-    assert resolve_pack_dispatch(frag) is not None
+    # no plan resolved: no op columns to price
+    assert query_wall_s(frag, max_rounds=8) == 0.0
+    assert resolve_spgemm_dispatch(frag).plan.items > 0
     wall = query_wall_s(frag, max_rounds=8)
     assert wall > 0.0
     # a 1000x slower VPU re-prices the SAME plan 1000x up

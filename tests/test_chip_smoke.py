@@ -52,9 +52,8 @@ def test_smoke_rehearsal_runs_every_stage():
     assert all(s["stage_b"][a]["compiles_warm"] == 0
                for a in ("pagerank", "sssp", "bfs", "wcc"))
     assert s["stage_b"]["serve"]["queries"] == 8
-    # off the TPU the strict kernel is interpreted and says so; the
-    # intersect kernel's dispatcher takes the jnp path
-    assert s["stage_c"]["spmv_strict"]["interpret"] is True
+    assert set(s["stage_c"]) == {"intersect_count", "vmem_gather"}
+    # off the TPU the intersect kernel's dispatcher takes the jnp path
     assert s["stage_c"]["intersect_count"]["pallas_calls"] == 0
     # and the pull's gather is XLA's, checked against itself
     assert s["stage_c"]["vmem_gather"]["pallas_calls"] == 0

@@ -3,8 +3,8 @@
 Pins: staged additive deltas ride the overlay side-path with results
 byte-identical to a cold query on the rebuilt mutated graph (SSSP/BFS/
 WCC, fnum 1 and 2); below the repack threshold `ServeSession.ingest`
-triggers ZERO pack replanning and ZERO XLA recompiles (plan_stats /
-runner_cache_stats) while queries still see the delta; repacks are
+triggers ZERO XLA recompiles (compile_events / runner_cache_stats)
+while queries still see the delta; repacks are
 counted recompile events; `Worker.query_incremental` after staged
 deltas equals a cold full query byte-for-byte — including under
 guard=halt and through a checkpoint/kill/resume crossing the mutation
@@ -290,12 +290,12 @@ def test_worker_rejects_stale_view_for_uncontracted_app():
     assert w.rounds == 3
 
 
-# ---- serve ingest: zero replanning / zero recompiles ---------------------
+# ---- serve ingest: zero recompiles ---------------------------------------
 
 
-def _pack_fragment():
-    """f32-weighted single-shard fragment (pack-eligible under x64),
-    built mutable — the test_serve counter idiom."""
+def _mutable_fragment():
+    """f32-weighted single-shard fragment, built mutable — the
+    test_serve counter idiom."""
     from libgrape_lite_tpu.fragment.edgecut import ShardedEdgecutFragment
     from libgrape_lite_tpu.parallel.comm_spec import CommSpec
     from libgrape_lite_tpu.vertex_map.partitioner import MapPartitioner
@@ -314,29 +314,25 @@ def _pack_fragment():
 
 
 def test_session_ingest_below_threshold_zero_recompile(monkeypatch):
-    """THE acceptance pin: with the pack backend engaged, an overlay
-    ingest triggers zero pack planning and zero XLA compilation — the
-    post-ingest query is a pure cache hit AND sees the delta."""
+    """THE acceptance pin: an overlay ingest triggers zero XLA
+    compilation — the post-ingest query is a pure cache hit AND sees
+    the delta."""
     from libgrape_lite_tpu.dyn import RepackPolicy
     from libgrape_lite_tpu.serve import BatchPolicy, ServeSession
 
-    monkeypatch.setenv("GRAPE_SPMV", "pack")
-    monkeypatch.delenv("GRAPE_PACK_PLAN_CACHE", raising=False)
     sess = ServeSession(
-        _pack_fragment(), policy=BatchPolicy(max_batch=1),
+        _mutable_fragment(), policy=BatchPolicy(max_batch=1),
         dyn=RepackPolicy(threshold=0.5, capacity=128),
     )
     r1 = sess.serve([("sssp", {"source": 0})])
     assert r1[0].ok, r1[0].error
-    assert sess.worker("sssp").app._pack is not None, "pack not engaged"
     s1 = sess.cache_stats()
 
     rep = sess.ingest([("a", 0, 600, 0.001), ("a", 600, 650, 0.001)])
     assert rep["mode"] == "overlay"
     # zero XLA compilation pinned on the real compile stream
     # (analysis.compile_events) — the counter a per-dispatch re-jit
-    # cannot hide from — while the pack counters keep proving zero
-    # REPLANNING (planning is host work, invisible to compile events)
+    # cannot hide from
     from libgrape_lite_tpu.analysis import compile_events
 
     with compile_events() as ev:
@@ -345,8 +341,7 @@ def test_session_ingest_below_threshold_zero_recompile(monkeypatch):
     assert ev.compiles == 0, ("ingest caused a recompile", ev.events)
     s2 = sess.cache_stats()
     assert s2["runner"]["hits"] > s1["runner"]["hits"]
-    assert s2["pack"]["planned"] == s1["pack"]["planned"], (
-        "ingest re-ran the pack planner", s1, s2)
+    assert s2["runner"]["misses"] == s1["runner"]["misses"], (s1, s2)
     # the delta is visible, not a stale cache reuse
     assert r1[0].values.tobytes() != r2[0].values.tobytes()
 
@@ -361,6 +356,34 @@ def test_session_ingest_below_threshold_zero_recompile(monkeypatch):
     assert s3["runner"]["misses"] > s2["runner"]["misses"]
     assert sess.stats["repacks"] == 1
     assert sess.stats["overlay_applies"] == 1
+
+
+@pytest.mark.parametrize("app_name", ["sssp", "bfs"])
+def test_overlay_pull_through_the_kernel(app_name, pull_kernel):
+    """With an overlay attached a round pulls twice, the base CSR's
+    entries and the overlay's slots (which are not whole 128s): on a
+    TPU backend both gathers are the kernel's, and the answer has the
+    bytes of the round through XLA's gather.  BFS compiles the
+    interpreted kernel, SSSP puts `full[nbr]` behind the choice
+    (tests/conftest.py)."""
+    from libgrape_lite_tpu.dyn import DynGraph, RepackPolicy
+    from libgrape_lite_tpu.models import APP_REGISTRY
+    from libgrape_lite_tpu.worker.worker import Worker
+    from tests.conftest import gather_took
+
+    dg = DynGraph(_mutable_fragment(),
+                  RepackPolicy(threshold=0.9, capacity=64))
+    assert dg.ingest(ADDS)["mode"] == "overlay"
+    want = Worker(APP_REGISTRY[app_name](), dg.fragment)
+    want.query(source=0)
+    calls = pull_kernel("interpreted" if app_name == "bfs" else "stand_in")
+    got = Worker(APP_REGISTRY[app_name](), dg.fragment)
+    took = gather_took(lambda: got.query(source=0))
+    assert took == {"kernel": 2, "xla": 0}
+    ep = dg.fragment.host_ie[0].edge_nbr.shape[0]
+    assert sorted(c[2][0] for c in calls) == sorted([64, ep])
+    assert got.rounds == want.rounds
+    assert got.result_values().tobytes() == want.result_values().tobytes()
 
 
 def test_session_forced_repack_for_uncontracted_app():
@@ -742,7 +765,7 @@ def test_guard_mutation_reset_unit():
     )
     mon.watchdog.observe(1, (7, 7))
     mon._probe = object()  # stale compiled probe stand-in
-    mon._ledger = {"edges": 1}  # pre-mutation pack-ledger snapshot
+    mon._ledger = {"edges": 1}  # pre-mutation ledger snapshot
     mon.on_mutation(frag)
     assert mon.mutations == 1
     assert mon._probe is None  # re-resolves against the mutated frag

@@ -1,6 +1,6 @@
 """fleet/ — multi-tenant serving fleet (ISSUE 13 acceptance).
 
-Pins: evict -> re-admit of a resident session performs ZERO pack
+Pins: evict -> re-admit of a resident session performs ZERO
 re-planning and ZERO XLA recompiles (counter- and compile_events-
 pinned) and answers byte-identically; the budget's cost-weighted-LRU
 eviction and its recorded reject decisions; per-tenant breach
@@ -127,8 +127,8 @@ def test_budget_shared_fragment_billed_once():
 # ---- eviction -> re-admission: the zero-replanning pin -------------------
 
 
-def _pack_fragment(fnum=1, n=700, e=6000):
-    """f32-weighted fragment (pack-eligible under x64)."""
+def _weighted_fragment(fnum=1, n=700, e=6000):
+    """f32-weighted fragment."""
     from libgrape_lite_tpu.fragment.edgecut import ShardedEdgecutFragment
     from libgrape_lite_tpu.parallel.comm_spec import CommSpec
     from libgrape_lite_tpu.vertex_map.partitioner import MapPartitioner
@@ -144,24 +144,30 @@ def _pack_fragment(fnum=1, n=700, e=6000):
     )
 
 
-def test_evict_readmit_zero_replanning_zero_compiles(monkeypatch):
+def test_evict_readmit_zero_replanning_zero_compiles():
     """The acceptance pin: release_device drops the HBM arrays; the
-    next query after restore_device hits the warm per-fragment plan
-    cache (planned flat) and the warm runner cache (zero compiles on
-    the REAL XLA stream), and answers byte-identically."""
-    import libgrape_lite_tpu.ops.spmv_pack as sp
+    next query after restore_device hits the warm runner cache (zero
+    compiles on the REAL XLA stream, no runner miss) and answers
+    byte-identically, and the per-fragment plan cache (weak-keyed on
+    the fragment, ops/spgemm_pack.py) survives: a re-resolve plans
+    nothing."""
     from libgrape_lite_tpu.analysis import compile_events
+    from libgrape_lite_tpu.ops.spgemm_pack import (
+        SPGEMM_STATS,
+        resolve_spgemm_dispatch,
+    )
     from libgrape_lite_tpu.serve import BatchPolicy, ServeSession
 
-    monkeypatch.setenv("GRAPE_SPMV", "pack")
-    monkeypatch.delenv("GRAPE_PACK_PLAN_CACHE", raising=False)
-    sess = ServeSession(_pack_fragment(), policy=BatchPolicy(max_batch=1))
+    sess = ServeSession(_weighted_fragment(),
+                        policy=BatchPolicy(max_batch=1))
     r1 = sess.serve([("sssp", {"source": 0})])
     assert r1[0].ok
-    assert sess.worker("sssp").app._pack is not None
     want = r1[0].values.tobytes()
+    resolve_spgemm_dispatch(sess.fragment)
+    planned = SPGEMM_STATS["planned"]
+    hits = SPGEMM_STATS["frag_cache_hits"]
+    s1 = sess.cache_stats()
 
-    planned = sp.plan_stats()["planned"]
     rel = sess.release_device()
     assert rel["fragment_released"] and not sess.resident
     assert sess.fragment.dev is None
@@ -170,9 +176,14 @@ def test_evict_readmit_zero_replanning_zero_compiles(monkeypatch):
         r2 = sess.serve([("sssp", {"source": 0})])
     assert r2[0].ok and r2[0].values.tobytes() == want
     assert ev.compiles == 0, ("re-admission recompiled", ev.events)
-    assert sp.plan_stats()["planned"] == planned, (
-        "re-admission re-ran the pack planner"
+    s2 = sess.cache_stats()
+    assert s2["runner"]["misses"] == s1["runner"]["misses"], (s1, s2)
+    assert s2["runner"]["hits"] > s1["runner"]["hits"]
+    resolve_spgemm_dispatch(sess.fragment)
+    assert SPGEMM_STATS["planned"] == planned, (
+        "re-admission re-ran the spgemm planner"
     )
+    assert SPGEMM_STATS["frag_cache_hits"] == hits + 1
 
 
 def test_release_restore_is_idempotent():

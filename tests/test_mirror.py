@@ -9,14 +9,15 @@ from `grape/fragment/edgecut_fragment_base.h:569-602`); here that is
   (masked edges included) against a direct per-receiver reconstruction,
 * golden matrix: GRAPE_EXCHANGE=mirror x {pagerank, sssp, wcc, bfs} x
   fnum {2,4,8} against `dataset/p2p-31-*`,
-* pack x mirror composition: both envs set, compared to the default
-  gather/XLA path on a random multigraph.
+* mirror against all_gather, byte for byte, on a random multigraph:
+  {pagerank, sssp, bfs, wcc} x fnum {2,4} (the exchange feeds the
+  same per-edge operands in the same order to the one fold).
 """
 
 import numpy as np
 import pytest
 
-from tests.conftest import dataset_path
+from tests.conftest import dataset_path, rand_frag as _rand_frag
 from tests.verifiers import (
     collect_worker_result as run_worker,
     eps_verify,
@@ -26,30 +27,6 @@ from tests.verifiers import (
 )
 
 FNUMS = [2, 4, 8]
-
-
-def _rand_frag(fnum, n=900, e=7000, seed=11, weighted=True, directed=False):
-    from libgrape_lite_tpu.fragment.edgecut import ShardedEdgecutFragment
-    from libgrape_lite_tpu.parallel.comm_spec import CommSpec
-    from libgrape_lite_tpu.utils.types import LoadStrategy
-    from libgrape_lite_tpu.vertex_map.partitioner import MapPartitioner
-    from libgrape_lite_tpu.vertex_map.vertex_map import VertexMap
-
-    rng = np.random.default_rng(seed)
-    src = rng.integers(0, n, e)
-    dst = rng.integers(0, n, e)
-    w = (
-        rng.uniform(0.5, 4.0, e).astype(np.float32)
-        if weighted
-        else np.ones(e, dtype=np.float32)
-    )
-    oids = np.arange(n, dtype=np.int64)
-    comm = CommSpec(fnum=fnum)
-    vm = VertexMap.build(oids, MapPartitioner(fnum, oids))
-    return ShardedEdgecutFragment.build(
-        comm, vm, src, dst, w, directed=directed,
-        load_strategy=LoadStrategy.kBothOutIn,
-    )
 
 
 @pytest.mark.parametrize("fnum", [2, 4])
@@ -160,119 +137,39 @@ def test_wcc_mirror_golden(graph_cache, fnum, monkeypatch):
     wcc_verify(res, load_golden(dataset_path("p2p-31-WCC")))
 
 
-# ---- pack x mirror composition ----
+# ---- mirror against all_gather, byte for byte ----
 
-
-def _small_pack(monkeypatch):
-    # the mirror branch of resolve_pack_dispatch calls plan_pack_multi
-    # directly, so patch that (not plan_pack_multi_for_fragment) to
-    # force multi-block fold/hub geometry on the tiny test shards
-    import libgrape_lite_tpu.ops.spmv_pack as sp
-    from libgrape_lite_tpu.ops.spmv_pack import PackConfig
-
-    orig = sp.plan_pack_multi
-
-    def small_cfg(shards, vp, n_cols, cfg=None):
-        return orig(shards, vp, n_cols,
-                    PackConfig(sub=16, out_sub=8, hub=128))
-
-    monkeypatch.setattr(sp, "plan_pack_multi", small_cfg)
+_IDENTITY_APPS = {
+    # app -> (registry name, query kwargs, weighted graph, mirror attr)
+    "pagerank": ("pagerank", {"max_round": 6}, False, "_mx"),
+    "sssp": ("sssp", {"source": 0}, True, "_mx"),
+    "bfs": ("bfs", {"source": 0}, False, "_mx"),
+    "wcc": ("wcc", {}, False, "_mx_ie"),
+}
 
 
 @pytest.mark.parametrize("fnum", [2, 4])
-def test_pagerank_pack_mirror(monkeypatch, fnum):
-    """Pack plans built over the compact mirror columns must match the
-    default gather/XLA path."""
-    from libgrape_lite_tpu.models import PageRank
+@pytest.mark.parametrize("app_name", sorted(_IDENTITY_APPS))
+def test_mirror_byte_identical(monkeypatch, app_name, fnum):
+    """Every pull app must actually route through exchange_mirrors
+    (BFS was once silently inert — ADVICE r3 high) and answer with the
+    all_gather path's bytes: the compact table holds the same values
+    at the remapped columns, and the fold sees them in CSR order."""
+    from libgrape_lite_tpu.models import APP_REGISTRY
     from libgrape_lite_tpu.worker.worker import Worker
 
-    frag = _rand_frag(fnum, seed=80 + fnum, weighted=False)
-    monkeypatch.delenv("GRAPE_SPMV", raising=False)
+    name, kwargs, weighted, mx_attr = _IDENTITY_APPS[app_name]
+    frag = _rand_frag(fnum, seed=110 + fnum, weighted=weighted)
     monkeypatch.delenv("GRAPE_EXCHANGE", raising=False)
-    w_ref = Worker(PageRank(max_round=6), frag)
-    w_ref.query()
+    w_ref = Worker(APP_REGISTRY[name](), frag)
+    w_ref.query(**kwargs)
     ref = w_ref.result_values()
 
-    monkeypatch.setenv("GRAPE_SPMV", "pack")
     monkeypatch.setenv("GRAPE_EXCHANGE", "mirror")
-    _small_pack(monkeypatch)
-    app = PageRank(max_round=6)
+    app = APP_REGISTRY[name]()
     wk = Worker(app, frag)
-    wk.query()
-    assert app._pack is not None, "pack plan not engaged"
-    assert app._mx is not None, "mirror plan not engaged"
+    wk.query(**kwargs)
+    assert getattr(app, mx_attr) is not None, "mirror plan not engaged"
     got = wk.result_values()
-    np.testing.assert_allclose(got, ref, rtol=2e-4, atol=1e-7)
-
-
-@pytest.mark.parametrize("fnum", [2, 4])
-def test_sssp_pack_mirror(monkeypatch, fnum):
-    from libgrape_lite_tpu.models import SSSP
-    from libgrape_lite_tpu.worker.worker import Worker
-
-    frag = _rand_frag(fnum, seed=90 + fnum)
-    monkeypatch.delenv("GRAPE_SPMV", raising=False)
-    monkeypatch.delenv("GRAPE_EXCHANGE", raising=False)
-    w_ref = Worker(SSSP(), frag)
-    w_ref.query(source=0)
-    ref = w_ref.result_values()
-
-    monkeypatch.setenv("GRAPE_SPMV", "pack")
-    monkeypatch.setenv("GRAPE_EXCHANGE", "mirror")
-    _small_pack(monkeypatch)
-    app = SSSP()
-    wk = Worker(app, frag)
-    wk.query(source=0)
-    assert app._pack is not None, "pack plan not engaged"
-    assert app._mx is not None, "mirror plan not engaged"
-    got = wk.result_values()
-    finite = np.isfinite(ref)
-    np.testing.assert_allclose(got[finite], ref[finite], rtol=1e-6)
-    assert np.isinf(got[~finite]).all()
-
-
-@pytest.mark.parametrize("fnum", [2, 4])
-def test_bfs_pack_mirror(monkeypatch, fnum):
-    """The ADVICE r3 high finding: BFS with mirror+pack used to feed the
-    full gather table to a compact-column plan."""
-    from libgrape_lite_tpu.models import BFS
-    from libgrape_lite_tpu.worker.worker import Worker
-
-    frag = _rand_frag(fnum, seed=100 + fnum, weighted=False)
-    monkeypatch.delenv("GRAPE_SPMV", raising=False)
-    monkeypatch.delenv("GRAPE_EXCHANGE", raising=False)
-    w_ref = Worker(BFS(), frag)
-    w_ref.query(source=0)
-    ref = w_ref.result_values()
-
-    monkeypatch.setenv("GRAPE_SPMV", "pack")
-    monkeypatch.setenv("GRAPE_EXCHANGE", "mirror")
-    _small_pack(monkeypatch)
-    app = BFS()
-    wk = Worker(app, frag)
-    wk.query(source=0)
-    assert app._pack is not None, "pack plan not engaged"
-    assert app._mx is not None, "mirror plan not engaged"
-    np.testing.assert_array_equal(wk.result_values(), ref)
-
-
-@pytest.mark.parametrize("fnum", [2, 4])
-def test_bfs_mirror_no_pack(monkeypatch, fnum):
-    """Mirror without pack: BFS must actually route through
-    exchange_mirrors (previously silently inert — ADVICE r3 high)."""
-    from libgrape_lite_tpu.models import BFS
-    from libgrape_lite_tpu.worker.worker import Worker
-
-    frag = _rand_frag(fnum, seed=110 + fnum, weighted=False)
-    monkeypatch.delenv("GRAPE_SPMV", raising=False)
-    monkeypatch.delenv("GRAPE_EXCHANGE", raising=False)
-    w_ref = Worker(BFS(), frag)
-    w_ref.query(source=0)
-    ref = w_ref.result_values()
-
-    monkeypatch.setenv("GRAPE_EXCHANGE", "mirror")
-    app = BFS()
-    wk = Worker(app, frag)
-    wk.query(source=0)
-    assert app._mx is not None, "mirror plan not engaged"
-    np.testing.assert_array_equal(wk.result_values(), ref)
+    assert got.dtype == ref.dtype
+    assert got.tobytes() == ref.tobytes()

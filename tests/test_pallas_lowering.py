@@ -31,104 +31,6 @@ def _run_offline(script: str, **env):
     )
 
 
-SCRIPT = r"""
-import numpy as np
-import jax
-import jax.numpy as jnp
-
-import sys
-sys.path.insert(0, %(repo)r)
-
-from libgrape_lite_tpu.ops.spmv_pack import (
-    PackConfig, plan_pack, segment_sum_pack,
-)
-
-# production geometry (the shipped default config): at vp = 2^20 the
-# column space spans 4 gather passes, plus fold/final levels
-cfg = PackConfig()
-rng = np.random.default_rng(0)
-vp = 8192 * 128            # 2^20 rows: the bench shard size
-e = 200_000
-rows = np.sort(rng.integers(0, vp, e))
-cols = rng.integers(0, vp, e)
-plan = plan_pack(rows, cols, vp, vp, cfg)
-
-x = jax.ShapeDtypeStruct((vp,), jnp.float32)
-traced = jax.jit(
-    lambda x: segment_sum_pack(x, plan, interpret=False)
-).trace(x)
-low = traced.lower(lowering_platforms=('tpu',))
-print("SPMV_PACK_LOWERED", len(low.as_text()))
-
-# tropical min with baked weight stream (the SSSP relaxation)
-from libgrape_lite_tpu.ops.spmv_pack import segment_reduce_pack
-w = rng.uniform(0.1, 5.0, e).astype(np.float32)
-plan_w = plan_pack(rows, cols, vp, vp, cfg, edge_w=w)
-low = jax.jit(
-    lambda x: segment_reduce_pack(x, plan_w, "min", interpret=False)
-).trace(x).lower(lowering_platforms=('tpu',))
-print("SPMV_PACK_MIN_LOWERED", len(low.as_text()))
-"""
-
-
-@pytest.mark.parametrize("scan", ["mxu", "shift"])
-def test_spmv_pack_lowers_for_tpu(scan):
-    r = _run_offline(SCRIPT % {"repo": REPO}, GRAPE_PACK_SCAN=scan)
-    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-4000:]
-    assert "SPMV_PACK_LOWERED" in r.stdout
-    assert "SPMV_PACK_MIN_LOWERED" in r.stdout
-
-
-# the MXU scan's matmul core (triangular lane cumsum, exclusive form,
-# per-group tail broadcast + exclusive tail prefix with the chained
-# base) in isolation, so a refusal of the full kernel above can be
-# told apart from one of the scan's matmul math
-MXU_SCRIPT = r"""
-import numpy as np
-import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-
-SUB, C, GR = 2048, 128, 128
-
-def kernel(v_ref, o_ref):
-    v = v_ref[...]
-    tri = (jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
-           <= jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
-           ).astype(v.dtype)
-    rowcum = jnp.dot(v, tri, preferred_element_type=v.dtype)
-    rseg = rowcum - v  # exclusive form (restore gather probed apart)
-    e_last = (jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
-              == (C - 1)).astype(v.dtype)
-    lexc = (jax.lax.broadcasted_iota(jnp.int32, (GR, GR), 1)
-            < jax.lax.broadcasted_iota(jnp.int32, (GR, GR), 0)
-            ).astype(v.dtype)
-    parts = []
-    base = jnp.zeros((1, C), v.dtype)
-    for g in range(SUB // GR):
-        rg = rseg[g * GR:(g + 1) * GR]
-        tail_g = jnp.dot(rg, e_last, preferred_element_type=v.dtype)
-        s_exc_g = jnp.dot(lexc, tail_g, preferred_element_type=v.dtype)
-        parts.append(s_exc_g + base)
-        base = base + (s_exc_g[GR - 1:GR] + tail_g[GR - 1:GR])
-    o_ref[...] = rseg + jnp.concatenate(parts, axis=0)
-
-low = jax.jit(lambda v: pl.pallas_call(
-    kernel,
-    out_shape=jax.ShapeDtypeStruct((SUB, C), jnp.float32),
-)(v)).trace(
-    jax.ShapeDtypeStruct((SUB, C), jnp.float32),
-).lower(lowering_platforms=('tpu',))
-print("MXU_ROWCUM_LOWERED", len(low.as_text()))
-"""
-
-
-def test_mxu_scan_rowcum_lowers_for_tpu():
-    r = _run_offline(MXU_SCRIPT)
-    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-4000:]
-    assert "MXU_ROWCUM_LOWERED" in r.stdout
-
-
 SCRIPT2 = r"""
 import numpy as np
 import jax
@@ -136,21 +38,6 @@ import jax.numpy as jnp
 
 import sys
 sys.path.insert(0, %(repo)r)
-
-# strict-tile SpMV at bench-like shapes
-from libgrape_lite_tpu.ops.spmv import plan_tiles, spmv_strict
-
-rng = np.random.default_rng(0)
-vp = 1 << 18
-src = np.sort(rng.integers(0, vp, 1 << 20)).astype(np.int32)
-row_lo, rmax, num_tiles = plan_tiles(src, 2048, vp)
-vals = jax.ShapeDtypeStruct((len(src),), jnp.float32)
-srcs = jax.ShapeDtypeStruct((len(src),), jnp.int32)
-low = jax.jit(
-    lambda v, s: spmv_strict(v, s, row_lo, vp, 2048, rmax,
-                             interpret=False)
-).trace(vals, srcs).lower(lowering_platforms=('tpu',))
-print("SPMV_STRICT_LOWERED", len(low.as_text()))
 
 # LCC bitmap intersect kernel (both aligned and full-dim word counts)
 from libgrape_lite_tpu.ops.pallas_kernels import intersect_count
@@ -167,7 +54,6 @@ for words in (128, 197):
 def test_legacy_kernels_lower_for_tpu():
     r = _run_offline(SCRIPT2 % {"repo": REPO})
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-4000:]
-    assert "SPMV_STRICT_LOWERED" in r.stdout
     assert "INTERSECT_LOWERED_128" in r.stdout
     assert "INTERSECT_LOWERED_197" in r.stdout
 

@@ -6,17 +6,14 @@ exchange, overlap interior compute with the in-flight collective, join
 at the fold.  The pinned contract:
 
 * GRAPE_PIPELINE=1 results are BYTE-identical to GRAPE_PIPELINE=0 on
-  SSSP/BFS/WCC/PageRank at fnum 1/2/4, under gather, mirror and pack
-  exchange/SpMV modes, under guard=halt/rollback, through a kill@K/
+  SSSP/BFS/WCC/PageRank at fnum 1/2/4, under the gather and mirror
+  exchange modes, under guard=halt/rollback, through a kill@K/
   resume drill and a corrupt_carry drill crossing pipelined rounds,
   and with tracing armed;
 * the serial path is bit-for-bit untouched when the pipeline is off
   or declined (lowered-HLO pin);
 * the boundary split agrees with the mirror request lists (a stale
   kickoff payload would be silent corruption, not a test failure);
-* the v3 pack plan cache keys the pipeline role, so a serial (full)
-  plan is never served to a pipelined run (miss-and-roundtrip, in the
-  test_pack_budget style);
 * the exchange-bytes model is ONE ledger shared by the mirror auto
   mode and the pipeline threshold (the r9 bugfix), and the overlap
   term is max(compute_interior, exchange) + compute_boundary.
@@ -35,7 +32,7 @@ def _pipeline_env(monkeypatch):
     """Every test starts with the pipeline (and its mode knobs)
     disarmed and leaves no env or obs state behind."""
     for var in ("GRAPE_PIPELINE", "GRAPE_PIPELINE_MIN_BYTES",
-                "GRAPE_EXCHANGE", "GRAPE_SPMV", "GRAPE_PACK_PLAN_CACHE",
+                "GRAPE_EXCHANGE",
                 obs.TRACE_ENV, obs.METRICS_ENV):
         monkeypatch.delenv(var, raising=False)
     obs.reset()
@@ -176,7 +173,7 @@ def test_boundary_stats_partition():
 @pytest.mark.parametrize("app_name", ["sssp", "bfs", "wcc", "pagerank"])
 def test_byte_identity_matrix(app_name, fnum, monkeypatch):
     """The acceptance matrix: GRAPE_PIPELINE results byte-identical to
-    serial on all four apps at fnum 1/2/4 (gather exchange, XLA SpMV).
+    serial on all four apps at fnum 1/2/4 (gather exchange).
     fnum=1 must DECLINE (no exchange to overlap) and still match, and
     so must PageRank at every fnum: its serial sum groups by tile
     (ops/segment.py) and would regroup under a split (the reason is
@@ -196,15 +193,11 @@ def test_byte_identity_matrix(app_name, fnum, monkeypatch):
     ("bfs", {"GRAPE_EXCHANGE": "mirror"}),
     ("wcc", {"GRAPE_EXCHANGE": "mirror"}),
     ("pagerank", {"GRAPE_EXCHANGE": "mirror"}),
-    ("sssp", {"GRAPE_SPMV": "pack"}),
-    ("bfs", {"GRAPE_SPMV": "pack"}),
-    ("wcc", {"GRAPE_EXCHANGE": "mirror", "GRAPE_SPMV": "pack"}),
-    ("sssp", {"GRAPE_EXCHANGE": "mirror", "GRAPE_SPMV": "pack"}),
 ])
 def test_byte_identity_exchange_modes(app_name, env, monkeypatch):
     """Exchange-mode interaction: the pipelined loop is pinned
-    byte-identical under the mirror all_to_all and under the pack SpMV
-    backend (split sub-plans), not just the full all_gather."""
+    byte-identical under the mirror all_to_all, not just the full
+    all_gather."""
     frag = _rand_frag(4)
     serial, _, _ = _run(app_name, frag, monkeypatch, "0", **env)
     piped, _, app = _run(app_name, frag, monkeypatch, "force", **env)
@@ -214,30 +207,38 @@ def test_byte_identity_exchange_modes(app_name, env, monkeypatch):
         assert app._pipeline is None
         return
     assert app._pipeline is not None
-    want_mode = "mirror" if "GRAPE_EXCHANGE" in env else "gather"
-    assert app._pipeline.mode == want_mode
-    if "GRAPE_SPMV" in env:
-        assert app._pipeline.pack_b is not None
-        assert app._pipeline.pack_i is not None
+    assert app._pipeline.mode == "mirror"
 
 
 # ---- engagement / decline discipline --------------------------------------
 
 
-def test_pagerank_pack_sum_declines(monkeypatch):
-    """Sum folds over the pack backend regroup float partials across a
-    split plan — PageRank must decline (and stay correct serially)
-    rather than ship eps-identity as byte-identity."""
-    frag = _rand_frag(4)
-    serial, _, _ = _run("pagerank", frag, monkeypatch, "0",
-                        GRAPE_SPMV="pack")
-    piped, _, app = _run("pagerank", frag, monkeypatch, "force",
-                         GRAPE_SPMV="pack")
-    assert app._pipeline is None
-    assert piped == serial
-    from libgrape_lite_tpu.parallel.pipeline import PIPELINE_STATS
+@pytest.mark.parametrize("fnum", [2, 4])
+@pytest.mark.parametrize("app_name", ["sssp", "bfs", "wcc"])
+def test_pipelined_slices_through_the_kernel(app_name, fnum, monkeypatch,
+                                             pull_kernel):
+    """The boundary and the interior slice each gather through the
+    pull's kernel when the backend is a TPU (their streams are as long
+    as the split leaves them), and the round still answers with the
+    serial round's bytes through XLA's gather.  One case compiles the
+    interpreted kernel; the others put `full[nbr]` behind the choice
+    (tests/conftest.py)."""
+    from tests.conftest import gather_took
 
-    assert "sum fold" in PIPELINE_STATS["last_decision"]["reason"]
+    frag = _rand_frag(fnum)
+    serial, rounds_s, _ = _run(app_name, frag, monkeypatch, "0")
+    calls = pull_kernel("interpreted" if (app_name, fnum) == ("bfs", 2)
+                        else "stand_in")
+    out = []
+    took = gather_took(lambda: out.append(
+        _run(app_name, frag, monkeypatch, "force")))
+    piped, rounds_p, app = out[0]
+    assert app._pipeline is not None
+    assert took == {"kernel": 2, "xla": 0}
+    host = app._pipeline.host_entries
+    assert sorted(c[2] for c in calls) == sorted(
+        host[k].shape[1:] for k in ("pl_b_nbr", "pl_i_nbr"))
+    assert (piped, rounds_p) == (serial, rounds_s)
 
 
 @pytest.mark.parametrize("fnum", [2, 4])
@@ -254,22 +255,6 @@ def test_wcc_directed_two_kickoff_identity(fnum, monkeypatch):
     assert app._pipeline is not None
     assert app._pipeline.mode2 is not None
     assert piped == serial
-
-
-def test_wcc_directed_pack_declines(monkeypatch):
-    """The double-pull round over the pack backend would need four
-    sub-plans whose fold order is unaudited — directed WCC + pack
-    declines (recorded) and stays byte-identical serially."""
-    frag = _rand_frag(2, directed=True)
-    serial, _, _ = _run("wcc", frag, monkeypatch, "0",
-                        GRAPE_SPMV="pack")
-    piped, _, app = _run("wcc", frag, monkeypatch, "force",
-                         GRAPE_SPMV="pack")
-    assert app._pipeline is None
-    assert piped == serial
-    from libgrape_lite_tpu.parallel.pipeline import PIPELINE_STATS
-
-    assert "double-pull" in PIPELINE_STATS["last_decision"]["reason"]
 
 
 @pytest.mark.parametrize(
@@ -528,56 +513,6 @@ def test_pipelined_repeat_queries_reuse_runner(monkeypatch):
     assert w.runner_cache_stats["misses"] == misses_g
 
 
-# ---- plan-cache role keying (v3) ------------------------------------------
-
-
-def test_plan_digest_keys_pipeline_role():
-    """The pipeline role (full/boundary/interior) is part of the v3
-    plan digest: the cache can never hand a serial plan to a pipelined
-    run even if the filtered edge streams were to coincide."""
-    from libgrape_lite_tpu.ops.spmv_pack import PackConfig, _shards_digest
-
-    rng = np.random.default_rng(7)
-    shards = [(np.sort(rng.integers(0, 512, 4000)),
-               rng.integers(0, 512, 4000), None)]
-    cfg = PackConfig()
-    full = _shards_digest(shards, 512, 512, cfg, "full")
-    assert _shards_digest(shards, 512, 512, cfg) == full  # default role
-    assert _shards_digest(shards, 512, 512, cfg, "boundary") != full
-    assert _shards_digest(shards, 512, 512, cfg, "interior") != full
-    assert _shards_digest(shards, 512, 512, cfg, "boundary") != (
-        _shards_digest(shards, 512, 512, cfg, "interior")
-    )
-
-
-def test_plan_cache_role_miss_and_roundtrip(monkeypatch, tmp_path):
-    """Miss-and-roundtrip in the test_pack_budget style: a plan saved
-    under role='boundary' reloads exactly under the same role and
-    MISSES under 'full' — so a pipelined run can never be served the
-    serial plan (or vice versa) from the disk cache."""
-    from libgrape_lite_tpu.ops.spmv_pack import (
-        PackConfig,
-        _load_cached_mplan,
-        _save_cached_mplan,
-        plan_pack_multi,
-    )
-
-    monkeypatch.setenv("GRAPE_PACK_PLAN_CACHE", str(tmp_path))
-    rng = np.random.default_rng(9)
-    vp = 512
-    shards = [(np.sort(rng.integers(0, vp, 8000)),
-               rng.integers(0, vp, 8000), None)]
-    cfg = PackConfig()
-    mplan = plan_pack_multi(shards, vp, vp, cfg)
-    _save_cached_mplan(mplan, shards, "boundary")
-    hit = _load_cached_mplan(shards, vp, vp, cfg, "boundary")
-    assert hit is not None
-    for k, v in mplan.host_streams.items():
-        np.testing.assert_array_equal(hit.host_streams[k], v)
-    assert _load_cached_mplan(shards, vp, vp, cfg, "full") is None
-    assert _load_cached_mplan(shards, vp, vp, cfg, "interior") is None
-
-
 # ---- the shared exchange-bytes ledger + overlap model ---------------------
 
 
@@ -631,10 +566,10 @@ def _bench_pipeline_block():
         "exchange_bytes": 4096, "boundary_vertices": 805,
         "interior_vertices": 219, "boundary_edges": 32521,
         "interior_edges": 247, "overlap_recount_mismatch": 0.0,
-        "plan_uid": "gather:2:128:0:xla:-",
+        "plan_uid": "gather:2:128:0:-",
         "overlap_truth": {
             "queries": 2, "joined": 1,
-            "plan_uid": "gather:2:128:0:xla:-",
+            "plan_uid": "gather:2:128:0:-",
             "modeled_hidden_us_per_round": 12.5,
             "measured_round_us": 180.0, "claim_frac": 0.07,
             "compile_rounds_excluded": 1, "ok": True,
@@ -675,7 +610,7 @@ def test_overlap_recount_from_shipped_plan(monkeypatch):
     """pack_cost_model.overlap_recount re-derives boundary/interior
     edge counts and exchange bytes from the SHIPPED plan arrays and
     must agree with the planner's stats (the >5% drift gate bench.py
-    applies) — on both the XLA-stream and pack-sub-plan paths."""
+    applies)."""
     import os
     import sys
 
@@ -685,15 +620,14 @@ def test_overlap_recount_from_shipped_plan(monkeypatch):
     from pack_cost_model import overlap_recount
 
     frag = _rand_frag(2)
-    for env in ({}, {"GRAPE_SPMV": "pack"}):
-        _, _, app = _run("sssp", frag, monkeypatch, "force", **env)
-        assert app._pipeline is not None
-        rc = overlap_recount(app._pipeline)
-        assert rc["overlap_recount_mismatch"] <= 0.05
-        t = app._pipeline.stats["totals"]
-        assert rc["boundary_edges"] == t["boundary_edges"]
-        assert rc["interior_edges"] == t["interior_edges"]
-        assert rc["exchange_bytes"] == app._pipeline.exchange_bytes
+    _, _, app = _run("sssp", frag, monkeypatch, "force")
+    assert app._pipeline is not None
+    rc = overlap_recount(app._pipeline)
+    assert rc["overlap_recount_mismatch"] <= 0.05
+    t = app._pipeline.stats["totals"]
+    assert rc["boundary_edges"] == t["boundary_edges"]
+    assert rc["interior_edges"] == t["interior_edges"]
+    assert rc["exchange_bytes"] == app._pipeline.exchange_bytes
 
 
 def test_trace_report_overlap_column_and_drift_flag():
@@ -743,31 +677,3 @@ def test_trace_report_overlap_column_and_drift_flag():
     out = buf.getvalue()
     assert "PIPELINE DRIFT" in out and "<10%" in out
     assert flagged == 1
-
-
-# ---- boundary stats surfaced everywhere the plan is -----------------------
-
-
-def test_plan_stats_and_ledger_surface_split(monkeypatch):
-    """plan_stats() and Worker.pack_ledger() carry the boundary/
-    interior counts once a pipeline is engaged (the satellite: the
-    split is readable everywhere the plan is)."""
-    from libgrape_lite_tpu.ops import spmv_pack
-    from libgrape_lite_tpu.worker.worker import Worker
-    from libgrape_lite_tpu.models import SSSP
-
-    frag = _rand_frag(2)
-    monkeypatch.setenv("GRAPE_PIPELINE", "force")
-    monkeypatch.setenv("GRAPE_SPMV", "pack")
-    w = Worker(SSSP(), frag)
-    w.query(source=0)
-    assert w.app._pipeline is not None
-    ps = spmv_pack.plan_stats()
-    assert ps["pipeline"]["totals"]["boundary_vertices"] > 0
-    assert ps["pipeline"]["resolved"] >= 1
-    led = w.pack_ledger()
-    assert led is not None
-    p = led["pipeline"]
-    assert p["boundary_vertices"] > 0
-    assert p["mode"] in ("gather", "mirror")
-    assert p["exchange_bytes"] > 0

@@ -7,9 +7,11 @@ Pinned here: the kernel (interpret mode) bit-equal to `full[nbr]` over
 the dtypes, table lengths and stream lengths it meets, on every kind of
 int32 index; the choice, through the trace-time counter
 `GATHER_STATS`; the `vmap` rule (lanes lower to the text they lowered
-to before, answer with their single calls' bytes and count once); and
-one app's whole query through the kernel on one and four fragments.
-The choice reads the backend, so the tests steer it here.
+to before, answer with their single calls' bytes and count once).
+Whole queries through the kernel, app by app, are in
+tests/test_pull_gather_apps.py (a file of its own so that another
+worker takes it).  The choice reads the backend, so the tests steer it
+(`pull_kernel`, tests/conftest.py).
 """
 
 import functools
@@ -19,10 +21,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from libgrape_lite_tpu.models import APP_REGISTRY
 from libgrape_lite_tpu.ops import pallas_kernels, segment
-from libgrape_lite_tpu.ops.segment import GATHER_STATS, pull_gather
-from libgrape_lite_tpu.worker.worker import Worker
+from libgrape_lite_tpu.ops.segment import pull_gather
+from tests.conftest import GATHER_BUDGET as BUDGET, gather_took
 
 # name -> (dtype, table length, stream length).  A CSR's stream is
 # whole 128s (the loader's rule) but not whole 1024s on a shard; the
@@ -79,29 +80,11 @@ def test_kernel_follows_xla_at_the_edges(case):
 
 # ---- the choice -----------------------------------------------------------
 
-BUDGET = 64 << 20
-
-
 @pytest.fixture
-def on_tpu(monkeypatch):
+def on_tpu(pull_kernel):
     """`pull_gather` as it chooses on the TPU backend; the kernel is a
     stand-in that notes its calls (the choice is what is under test)."""
-    calls = []
-
-    def kernel(full, nbr):
-        calls.append((full.shape, nbr.shape))
-        return full[nbr]
-
-    monkeypatch.setattr(segment, "use_pallas", lambda: True)
-    monkeypatch.setattr(segment, "gather_table_budget", lambda: BUDGET)
-    monkeypatch.setattr(segment, "vmem_gather", kernel)
-    return calls
-
-
-def _took(fn) -> dict:
-    before = GATHER_STATS.snapshot()
-    fn()
-    return {k: v - before[k] for k, v in GATHER_STATS.snapshot().items()}
+    return pull_kernel("stand_in")
 
 
 CHOICES = {
@@ -125,7 +108,7 @@ def test_choice_on_tpu(name, on_tpu):
     shape, dtype, idtype, want = CHOICES[name]
     full = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
     nbr = jax.ShapeDtypeStruct((512,), jnp.dtype(idtype))
-    took = _took(lambda: jax.eval_shape(
+    took = gather_took(lambda: jax.eval_shape(
         lambda f, i: pull_gather(f, i), full, nbr))
     assert took == {"kernel": 0, "xla": 0, want: 1}
     assert len(on_tpu) == (want == "kernel")
@@ -141,7 +124,7 @@ def test_choice_off_tpu_is_xla(name, monkeypatch):
     full = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
     nbr = jax.ShapeDtypeStruct((512,), jnp.dtype(idtype))
     mask = jax.ShapeDtypeStruct((512,), jnp.bool_)
-    took = _took(lambda: jax.eval_shape(
+    took = gather_took(lambda: jax.eval_shape(
         lambda f, i: pull_gather(f, i), full, nbr))
     assert took == {"kernel": 0, "xla": 1}
 
@@ -157,7 +140,7 @@ def test_choice_off_tpu_is_xla(name, monkeypatch):
 
 def test_empty_stream_is_xla(on_tpu):
     full = jnp.arange(256, dtype=jnp.float32)
-    took = _took(lambda: pull_gather(full, jnp.zeros((0,), jnp.int32)))
+    took = gather_took(lambda: pull_gather(full, jnp.zeros((0,), jnp.int32)))
     assert took == {"kernel": 0, "xla": 1}
 
 
@@ -204,7 +187,7 @@ def test_lanes_take_xla(dtype, on_tpu, monkeypatch):
     def one(f):
         return pull_gather(f, nbr, mask, jnp.asarray(0, f.dtype), add=1)
 
-    took = _took(lambda: jax.jit(jax.vmap(one)).lower(full))
+    took = gather_took(lambda: jax.jit(jax.vmap(one)).lower(full))
     assert took == {"kernel": 0, "xla": 1}
     text = jax.jit(jax.vmap(one)).lower(full).as_text()
     monkeypatch.setattr(segment, "use_pallas", lambda: False)
@@ -224,7 +207,7 @@ def test_lanes_rule_moves_the_count_once(on_tpu):
         return jax.lax.fori_loop(
             0, 3, lambda _, x: x + pull_gather(x, nbr)[:640], f)
 
-    took = _took(lambda: jax.jit(jax.vmap(rounds)).lower(full))
+    took = gather_took(lambda: jax.jit(jax.vmap(rounds)).lower(full))
     assert took == {"kernel": 0, "xla": 1}
 
 
@@ -236,27 +219,3 @@ def test_batched_indices_take_xla(on_tpu):
     nbr = jnp.asarray(rng.integers(0, 640, (3, 1024)).astype(np.int32))
     got = jax.vmap(lambda i: pull_gather(full, i))(nbr)
     assert np.asarray(got).tobytes() == np.asarray(full[nbr]).tobytes()
-
-
-# ---- a whole query through the kernel -------------------------------------
-
-
-@pytest.mark.parametrize("fnum", [1, 4])
-def test_bfs_through_the_kernel(fnum, graph_cache, monkeypatch):
-    """BFS's depths are int32 in this lane too: its round through the
-    interpreted kernel, inside `shard_map(while_loop)`, answers with
-    the bytes of the round through XLA's gather."""
-    frag = graph_cache(fnum)
-    want = Worker(APP_REGISTRY["bfs"](), frag)
-    want.query(source=6)
-    monkeypatch.setattr(segment, "use_pallas", lambda: True)
-    monkeypatch.setattr(segment, "gather_table_budget", lambda: BUDGET)
-    monkeypatch.setattr(
-        segment, "vmem_gather",
-        functools.partial(pallas_kernels.vmem_gather, interpret=True))
-    got = Worker(APP_REGISTRY["bfs"](), frag)
-    took = _took(lambda: got.query(source=6))
-    assert took == {"kernel": 1, "xla": 0}
-    assert got.rounds == want.rounds
-    assert (np.asarray(got.result_values()).tobytes()
-            == np.asarray(want.result_values()).tobytes())

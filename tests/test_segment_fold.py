@@ -9,7 +9,8 @@ shapes a CSR takes; that exact lanes take the scatter and a float
 sum's lanes the scan, bit for bit their single calls; that `row_ptr`
 with unsorted ids is refused; that a call without
 `row_ptr` lowers to the text it lowered to before; and, through the
-trace-time counter `FOLD_STATS`, which fold each app's round takes.
+trace-time counter `FOLD_STATS`, which fold each app's round takes,
+PageRank's being this one and no other whatever the backend says.
 """
 
 import jax
@@ -188,6 +189,47 @@ def test_query_lanes_fold_by_kind(app, took, graph_cache):
     after = FOLD_STATS.snapshot()
     assert {k: after[k] - before[k] for k in after} == {
         "scan": 0, "scatter": 0, took: 1}
+
+
+class _NoDevice:
+    """Stands where a fragment's device arrays do while a state is
+    built: whatever is read of them is a read-back."""
+
+    def __getattr__(self, name):
+        raise AssertionError(f"init_state read frag.dev.{name}")
+
+
+@pytest.mark.parametrize("fnum,lanes", [(1, 0), (2, 0), (4, 0), (1, 4)],
+                         ids=["1", "2", "4", "lanes4"])
+def test_pagerank_fold_is_the_one_fold(fnum, lanes, monkeypatch):
+    """PageRank's f32 state on a backend that says it is a TPU, where
+    an earlier tree copied the E-wide `edge_src` to the host to plan
+    another fold: the state is built from host arrays alone and holds
+    no plan leaf, and the round (or the lanes' round) folds by scan."""
+    from libgrape_lite_tpu.ops import pallas_kernels
+    from tests.conftest import rand_frag
+
+    monkeypatch.setattr(pallas_kernels, "use_pallas", lambda: True)
+    frag = rand_frag(fnum, weighted=False)
+    app = APP_REGISTRY["pagerank"]()
+    sources = list(range(lanes))
+    with monkeypatch.context() as m:
+        m.setattr(frag, "dev", _NoDevice())
+        state = (app.init_state_batch(frag, [{"source": s}
+                                             for s in sources])
+                 if lanes else app.init_state(frag))
+    assert state["rank"].dtype == np.float32
+    assert set(state) == {"rank", "step", "dangling_sum",
+                          "total_dangling"} | ({"seed"} if lanes else set())
+    w = Worker(APP_REGISTRY["pagerank"](), frag)
+    before = FOLD_STATS.snapshot()
+    if lanes:
+        w.query_batch([{"source": s} for s in sources])
+    else:
+        w.query()
+    after = FOLD_STATS.snapshot()
+    assert {k: after[k] - before[k] for k in after} == {
+        "scan": 1, "scatter": 0}
 
 
 def test_row_ptr_with_unsorted_ids_is_refused():

@@ -63,6 +63,32 @@ def test_batched_byte_identical_per_lane(graph_cache, app_name):
         ), f"{app_name} lane {b} (source {s}) diverged from sequential"
 
 
+@pytest.mark.parametrize("app_name", ["sssp", "bfs"])
+def test_batched_lanes_keep_xla_gather_on_a_tpu(app_name, pull_kernel):
+    """On a TPU backend a single query's pull takes the kernel and the
+    batched runner's lanes go back to XLA's gather (the `vmap` rule of
+    ops/segment.py), each lane with its single query's bytes."""
+    from libgrape_lite_tpu.models import APP_REGISTRY
+    from libgrape_lite_tpu.worker.worker import Worker
+    from tests.conftest import gather_took, rand_frag
+
+    frag = rand_frag(1)  # f32 weights: SSSP's state is the kernel's kind
+    sources = [0, 5, 17, 33]
+    pull_kernel("stand_in")
+    singles = []
+    for s in sources:
+        w = Worker(APP_REGISTRY[app_name](), frag)
+        took = gather_took(lambda: w.query(source=s))
+        assert took == {"kernel": 1, "xla": 0}
+        singles.append(w.result_values().tobytes())
+    w = Worker(APP_REGISTRY[app_name](), frag)
+    took = gather_took(
+        lambda: w.query_batch([{"source": s} for s in sources]))
+    assert took == {"kernel": 0, "xla": 1}
+    for b, want in enumerate(singles):
+        assert w.batch_result_values(b).tobytes() == want
+
+
 def test_batched_rejects_host_only_apps(graph_cache):
     from libgrape_lite_tpu.models import APP_REGISTRY
     from libgrape_lite_tpu.worker.worker import Worker
@@ -72,15 +98,14 @@ def test_batched_rejects_host_only_apps(graph_cache):
         w.query_batch([{"source": 6}, {"source": 3}])
 
 
-# ---- session: resident artifacts, zero recompile / zero replanning -------
+# ---- session: resident artifacts, zero recompile ------------------------
 
 
-def test_session_second_query_compiles_and_plans_nothing(monkeypatch):
+def test_session_second_query_compiles_nothing():
     """The acceptance counter check: after the first SSSP query warms a
-    session, a second query of the same shape performs ZERO pack
-    planning (spmv_pack.plan_stats) and ZERO XLA compilation
-    (Worker.runner_cache_stats) — only cache hits."""
-    import libgrape_lite_tpu.ops.spmv_pack as sp
+    session, a second query of the same shape performs ZERO XLA
+    compilation (compile_events, Worker.runner_cache_stats) — only
+    cache hits."""
     from libgrape_lite_tpu.serve import BatchPolicy, ServeSession
     from tests.test_worker import build_fragment
 
@@ -88,17 +113,12 @@ def test_session_second_query_compiles_and_plans_nothing(monkeypatch):
     n, e = 700, 6000
     src, dst = rng.integers(0, n, e), rng.integers(0, n, e)
     frag = build_fragment(src, dst, None, n, 1)
-    # f32 weights keep the SSSP state f32 -> pack-eligible under x64
     frag = _reweight_f32(frag, src, dst, n)
 
-    monkeypatch.setenv("GRAPE_SPMV", "pack")
-    monkeypatch.delenv("GRAPE_PACK_PLAN_CACHE", raising=False)
     sess = ServeSession(frag, policy=BatchPolicy(max_batch=1))
 
     r1 = sess.serve([("sssp", {"source": 0})])
     assert r1[0].ok
-    app = sess.worker("sssp").app
-    assert app._pack is not None, "pack backend did not engage"
     s1 = sess.cache_stats()
     assert s1["runner"]["misses"] >= 1  # the warm compile
 
@@ -116,11 +136,7 @@ def test_session_second_query_compiles_and_plans_nothing(monkeypatch):
         "second query recompiled", ev.events)
     s2 = sess.cache_stats()
     assert s2["runner"]["hits"] > s1["runner"]["hits"]
-    assert s2["pack"]["planned"] == s1["pack"]["planned"], (
-        "second query re-ran the pack planner", s1, s2)
-    assert (
-        s2["pack"]["frag_cache_hits"] > s1["pack"]["frag_cache_hits"]
-    )
+    assert s2["runner"]["misses"] == s1["runner"]["misses"], (s1, s2)
     # and the answers are the real per-source answers, not a stale reuse
     assert (
         r1[0].values.tobytes() != r2[0].values.tobytes()
@@ -128,7 +144,7 @@ def test_session_second_query_compiles_and_plans_nothing(monkeypatch):
 
 
 def _reweight_f32(frag, src, dst, n):
-    """Rebuild the fragment with f32 unit weights (pack-eligible)."""
+    """Rebuild the fragment with f32 unit weights."""
     from libgrape_lite_tpu.fragment.edgecut import ShardedEdgecutFragment
     from libgrape_lite_tpu.parallel.comm_spec import CommSpec
     from libgrape_lite_tpu.utils.types import LoadStrategy

@@ -343,9 +343,9 @@ def test_backend_cache_separation_zero_recompiles(backend):
 
 
 def test_disk_plan_cache_backend_separation(tmp_path, monkeypatch):
-    """spgemm plans persist under their own digest family: a fresh
-    identical fragment loads the plan from disk byte-identically, and
-    the entry can never collide with a pack plan's."""
+    """spgemm plans persist under their own digest family
+    (`spgemmplan_*` entries): a fresh identical fragment loads the
+    plan from disk byte-identically."""
     from libgrape_lite_tpu.ops.spgemm_pack import (
         SPGEMM_STATS,
         resolve_spgemm_dispatch,

@@ -88,23 +88,6 @@ def test_vc2d_decision_carries_profile_and_hidden_us(monkeypatch):
     assert "profile" in dec
 
 
-def test_vc2d_pack_declines_and_stays_identical(monkeypatch):
-    """A resolved per-tile pack plan is one fused dispatch whose phase
-    split is unaudited: force + pack must decline (recorded) and stay
-    byte-identical to the serial pack run."""
-    from libgrape_lite_tpu.models import WCCVC2D
-    from libgrape_lite_tpu.parallel.pipeline import PIPELINE_STATS
-
-    frag = _vc_frag(4)  # int carry: pack-eligible under x64
-    monkeypatch.setenv("GRAPE_SPMV", "pack")
-    serial, _ = _vc_run(WCCVC2D, frag, monkeypatch, "0")
-    piped, w = _vc_run(WCCVC2D, frag, monkeypatch, "force")
-    assert w.app._pack_ie is not None, "tile pack plan did not engage"
-    assert w.app._pipeline is None
-    assert "pack" in PIPELINE_STATS["last_decision"]["reason"]
-    _assert_byte_identical(piped, serial)
-
-
 def test_vc2d_pipelined_runner_cached_separately(monkeypatch):
     """Serial and pipelined 2-D compiles never share a runner-cache
     entry (the plan uid rides trace_key), and the uid is a stable
