@@ -7,15 +7,19 @@ out-neighbors (previous-round values), ties broken toward the smallest
 label (the reference sorts labels ascending and keeps the first strict
 maximum).
 
-TPU formulation of the mode computation — sort-free-loop, all segment
-ops (no per-vertex hash map):
+TPU formulation of the mode computation — a sort and two scans over
+the pairs it put in order (no per-vertex hash map, no scatter):
 
   1. gather labels, read one per edge,
-  2. sort edge (src, label) pairs (`jnp.lexsort`),
-  3. run-length encode equal (src,label) runs via boundary cumsum,
-  4. per-edge run length -> per-src max run length (`segment_max`),
-  5. among runs achieving the max, take the smallest label
-     (`segment_min` over masked labels).
+  2. sort edge (src, label) pairs (`_sorted_pairs`, three branches),
+  3. give each entry its position in its run of equal (src, label)
+     pairs (`ops/segment.run_position`): at a run's last entry that is
+     the run's length, and less before it,
+  4. scan the pair (position, label) down each row under the order
+     "larger position, then smaller label"
+     (`ops/segment.segment_top_label`),
+  5. read each row's answer at the row's last entry: the smallest
+     label among its longest runs.
 
 Everything is O(E log E) on device with static shapes; multi-edges
 contribute multiplicity exactly like the reference's neighbor scan.
@@ -28,7 +32,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from libgrape_lite_tpu.app.base import ParallelAppBase, StepContext
-from libgrape_lite_tpu.ops.segment import pull_gather
+from libgrape_lite_tpu.ops.segment import (
+    pull_gather,
+    run_position,
+    segment_top_label,
+)
 from libgrape_lite_tpu.utils.types import LoadStrategy, MessageStrategy
 
 class CDLP(ParallelAppBase):
@@ -94,45 +102,42 @@ class CDLP(ParallelAppBase):
         )
         return state
 
-    def _mode_fold(self, src, lab, full, lut, vp):
+    def _mode_fold(self, src, lab, full, lut, vp, row_ptr=None):
         """Per-row mode label from one (src, label) edge multiset:
-        sort, run-length encode, max-run per row, ties to smallest
-        label — the TPU counting kernel shared by the serial round and
-        both pipelined parts (the fold only ever groups edges of equal
-        src, so any edge subset CLOSED over destination rows — the
-        full set, the boundary part, the interior part — yields the
-        per-row result of the full fold for the rows it covers).
+        sort, then count and choose by scans over the sorted pairs —
+        the TPU counting kernel shared by the serial round and both
+        pipelined parts (the fold only ever groups edges of equal src,
+        so any edge subset CLOSED over destination rows — the full
+        set, the boundary part, the interior part — yields the per-row
+        result of the full fold for the rows it covers).
+
+        After `_sorted_pairs`, `(ss, ll)` is in lexicographic order in
+        every branch: equal pairs are contiguous, rows are contiguous,
+        padding (`ss == vp`) is last.  An entry's position in its run
+        is the run's length at the run's last entry and less before
+        it, so the row's largest position is its largest run length,
+        and the answer is the smallest label that reaches it: a
+        multi-edge counts as often as it is held, ties go to the
+        smallest label, a row with no entry returns `big`.
+
+        `row_ptr` is the offsets of the sorted rows where the caller
+        knows them: for a whole padded CSR its `indptr`, because the
+        CSR's contract (graph/csr.py: real edges sorted by row, every
+        masked entry behind the last row) makes the sorted `ss` equal
+        `edge_src`.  Without it (the pipelined slices) the offsets are
+        looked up in `ss` (`ops/segment.segment_top_label`).
 
         Named for the device trace (metadata only, like the pull's):
         `grape.cdlp.universe` on the distinct-label predicate of the
         dynamic branch, `grape.cdlp.sort` on key building, the sort
         (whichever branch) and the decode to `(ss, ll)`,
-        `grape.cdlp.count` on the run-length pass around the three
-        folds, which keep `grape.pull.fold`."""
+        `grape.cdlp.count` on the positions in the runs, and
+        `grape.pull.fold` on the scan into rows."""
         with jax.named_scope("grape.cdlp.sort"):
             ss, ll = self._sorted_pairs(src, lab, full, lut, vp)
-        dt = lab.dtype
-        big = jnp.asarray(np.iinfo(np.dtype(dt).name).max, dt)
         with jax.named_scope("grape.cdlp.count"):
-            valid = ss != jnp.int32(vp)
-            first = jnp.ones_like(ss, dtype=bool).at[1:].set(
-                jnp.logical_or(ss[1:] != ss[:-1], ll[1:] != ll[:-1])
-            )
-            run_id = jnp.cumsum(first.astype(jnp.int32)) - 1
-        run_len = self.segment_reduce(
-            valid.astype(jnp.int32), run_id, ss.shape[0], "sum"
-        )  # runs <= E, so size the table with E rows — when every
-        # (src,label) pair is distinct, run_id reaches e-1 and must not
-        # land in the sliced-off overflow segment
-        with jax.named_scope("grape.cdlp.count"):
-            c_e = run_len[run_id]
-        cmax = self.segment_reduce(c_e, ss, vp, "max")
-        with jax.named_scope("grape.cdlp.count"):
-            is_best = jnp.logical_and(
-                valid, c_e == cmax[jnp.minimum(ss, vp - 1)]
-            )
-            cand = jnp.where(is_best, ll, big)
-        return self.segment_reduce(cand, ss, vp, "min")
+            pos = run_position(ss, ll)
+        return segment_top_label(pos, ll, ss, vp, row_ptr=row_ptr)
 
     def _sorted_pairs(self, src, lab, full, lut, vp):
         """The (src, label) pairs in lexicographic order, by whichever
@@ -238,7 +243,8 @@ class CDLP(ParallelAppBase):
         lab = pull_gather(full, oe.edge_nbr, mask=oe.edge_mask, fill=big)
         with jax.named_scope("grape.cdlp.sort"):
             src = jnp.where(oe.edge_mask, oe.edge_src, jnp.int32(vp))
-        new_lab = self._mode_fold(src, lab, full, lut, vp)
+        new_lab = self._mode_fold(src, lab, full, lut, vp,
+                                  row_ptr=oe.indptr)
 
         with jax.named_scope("grape.app.update"):
             has_out = frag.out_degree > 0
@@ -387,7 +393,8 @@ class CDLPOpt(CDLP):
         big = jnp.asarray(np.iinfo(np.dtype(dt).name).max, dt)
         full = ctx.gather_state(labels)
         cand = pull_gather(full, oe.edge_nbr, mask=oe.edge_mask, fill=big)
-        mn = self.segment_reduce(cand, oe.edge_src, frag.vp, "min")
+        mn = self.segment_reduce(cand, oe.edge_src, frag.vp, "min",
+                                 row_ptr=oe.indptr)
         has_out = frag.out_degree > 0
         keep = jnp.logical_or(~frag.inner_mask, ~has_out)
         new = jnp.where(jnp.logical_or(keep, mn == big), labels, mn)

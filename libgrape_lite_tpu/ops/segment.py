@@ -17,10 +17,15 @@ hand, the fold is a scan written out in dense XLA: `_segmented_scan`
 over tiles of 128 places, then one V-wide gather of the row ends
 (0.83 ns an element at scale 21).  Everything else keeps the scatter:
 ids that are not sorted, streams without offsets (the dyn overlay, the
-pipeline's boundary and interior slices), and the query lanes of an
-exact fold under `jax.vmap`, whose gather XLA fuses into the scatter.
-A float sum's lanes scan, as their single queries do: its bits depend
-on the grouping, and a lane answers with its single query's bytes.
+pull apps' pipelined boundary and interior slices), and the query lanes
+of an exact fold under `jax.vmap`, whose gather XLA fuses into the
+scatter.  A float sum's lanes scan, as their single queries do: its bits
+depend on the grouping, and a lane answers with its single query's
+bytes.  CDLP's count is a scan as well, and never a scatter:
+`run_position` and `segment_top_label` work on the (row, label) pairs
+its sort has just put in order, with the CSR's offsets where the whole
+CSR is folded and offsets found by a binary search of the sorted rows
+where a pipelined slice is.
 
 The module makes a third choice, in `pull_gather`: how `full[nbr]` is
 read.  XLA's gather also steps through its indices one at a time (8.6
@@ -203,6 +208,41 @@ def _segmented_scan(values, ids, combine, identity):
     return v.reshape(-1)[:n]
 
 
+def _segmented_scan_pair(values, ids, wins, identity):
+    """`_segmented_scan` of a pair of 1-D arrays under a total order:
+    `wins(x, y)` says where the pair `y` beats the pair `x`, and a
+    place keeps the best pair of its row so far.  `identity` is the
+    pair that every other loses to.  Same tiles, same levels."""
+    n = ids.shape[0]
+    pad = -n % SCAN_TILE
+    if pad:
+        values = tuple(
+            lax.pad(v, jnp.asarray(e, v.dtype), [(0, pad, 0)])
+            for v, e in zip(values, identity))
+        ids = lax.pad(ids, ids[-1], [(0, pad, 0)])
+    v = tuple(x.reshape(-1, SCAN_TILE) for x in values)
+    i = ids.reshape(-1, SCAN_TILE)
+
+    def take(v, other, same_row):
+        took = jnp.logical_and(same_row, wins(v, other))
+        return tuple(jnp.where(took, y, x) for x, y in zip(v, other))
+
+    d = 1
+    while d < SCAN_TILE:
+        below = tuple(_shift(x, d, e) for x, e in zip(v, identity))
+        v = take(v, below, i == _shift(i, d, -1))
+        d *= 2
+    if i.shape[0] > 1:
+        tail_i = i[:, -1]
+        above = _segmented_scan_pair(
+            tuple(x[:, -1] for x in v), tail_i, wins, identity)
+        carry_i = _shift(tail_i, 1, -1)[:, None]
+        carry = tuple(_shift(a, 1, e)[:, None]
+                      for a, e in zip(above, identity))
+        v = take(v, carry, i == carry_i)
+    return tuple(x.reshape(-1)[:n] for x in v)
+
+
 def _scatter_fold(values, segment_ids, num_rows: int, kind: str,
                   sorted_ids: bool):
     out = _FOLDS[kind][0](
@@ -303,3 +343,59 @@ def segment_reduce(values, segment_ids, num_rows: int, kind: str = "sum",
         FOLD_STATS["scatter"] += 1
         return _scatter_fold(values, segment_ids, num_rows, kind,
                              sorted_ids)
+
+
+def run_position(segment_ids, label):
+    """Each place's position, counted from 1, in its run of equal
+    `(segment_ids, label)` pairs, which are in lexicographic order.
+
+    A run opens where either half of the pair changes.  The opening
+    place comes down to the run's places by a max-scan of the opening
+    positions (positions only grow, so the latest opening is the
+    largest); the scan restarts with the sorted `segment_ids`, which
+    changes nothing, since a row's first place opens a run, and lets
+    `_segmented_scan` do it.  The position is monotone inside a run
+    and equals the run's length at its last place."""
+    first = jnp.logical_or(segment_ids != _shift(segment_ids, 1, -1),
+                           label != _shift(label, 1, 0))
+    idx = jnp.arange(segment_ids.shape[0], dtype=jnp.int32)
+    opened = _segmented_scan(jnp.where(first, idx, 0), segment_ids,
+                             jnp.maximum, 0)
+    return idx - opened + 1
+
+
+def _more_then_smaller(x, y):
+    """Where the pair `y = (count, label)` beats `x`: by the larger
+    count, then by the smaller label."""
+    (cx, lx), (cy, ly) = x, y
+    return jnp.logical_or(cy > cx, jnp.logical_and(cy == cx, ly < lx))
+
+
+def segment_top_label(count, label, segment_ids, num_rows: int,
+                      row_ptr=None):
+    """Per row, the smallest `label` among the places whose `count` is
+    the row's largest; the label dtype's largest value for a row with
+    no place.  `count` is positive.
+
+    `segment_ids` are sorted, padding (`num_rows`) last.  One scan of
+    the pair `(count, label)` under the order "larger count, then
+    smaller label", then each row's answer from its last place: exact
+    under any grouping, no scatter.  `row_ptr` is the rows' offsets
+    where the caller has them (a whole CSR's `indptr`); without it
+    they are found in the sorted ids by a V-wide binary search.
+    FOLD_STATS counts the call as a scan."""
+    FOLD_STATS["scan"] += 1
+    empty = jnp.iinfo(label.dtype).max
+    with jax.named_scope("grape.pull.fold"):
+        if row_ptr is None:
+            row_ptr = jnp.searchsorted(
+                segment_ids,
+                jnp.arange(num_rows + 1, dtype=segment_ids.dtype))
+        _, best = _segmented_scan_pair(
+            (count, label), segment_ids, _more_then_smaller, (0, empty))
+        # an empty row has no last place
+        last = row_ptr[1:num_rows + 1] - 1
+        out = best.at[jnp.maximum(last, 0)].get(
+            mode="promise_in_bounds", indices_are_sorted=True)
+        return jnp.where(last >= row_ptr[:num_rows], out,
+                         jnp.asarray(empty, label.dtype))
