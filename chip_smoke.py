@@ -68,9 +68,10 @@ NOT_RUN = {
                     "scale 21-22; the benchmark cell g500-cdlp.cdlp-10r "
                     "runs and checks it at size (PERF.md). Exact on "
                     "p2p-31 in Stage A",
-    "lcc_at_size": "docs/SCALE_NOTES.md sizes its ELL past one chip at "
-                   "scale 22 and nothing smaller is sized (ROADMAP "
-                   "R1/S5). Exact on p2p-31 in Stages A and C",
+    "lcc_at_size": "the registry's lcc runs at size in the benchmark cell "
+                   "g500-lcc.lcc, which checks every vertex (PERF.md); "
+                   "docs/SCALE_NOTES.md sizes its ELL past one chip at "
+                   "scale 22. Exact on p2p-31 in Stages A and C",
 }
 
 

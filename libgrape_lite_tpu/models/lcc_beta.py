@@ -16,6 +16,15 @@ intersects *sorted oriented neighbor lists* instead:
   * remote rows ride the same ring `ppermute` as the bitmap kernel;
     credits accumulate in a pid-indexed vector folded by one `psum`.
 
+The oriented adjacency (the ELL block, its row counts, the tier
+schedule) is a property of the resident graph, not of a query: it is
+built once per fragment and kept on the device with it
+(`_resident_adjacency`, counted in LCC_STATS), and rides every query as
+read-only ephemeral leaves, out of the fused loop's carry and of the
+result.  The step carries `jax.named_scope` names (metadata only):
+`grape.lcc.orient`, `.rows`, `.intersect`, `.credit`, and
+`grape.app.update` on the quotient (docs/OBSERVABILITY.md).
+
 Working set is O(chunk · D) — independent of vertex count.  Exactness
 matches the golden within eps like models/lcc.py: triangle enumeration
 is orientation-agnostic (each triangle is found exactly once at its
@@ -27,14 +36,41 @@ assumption documented there).
 
 from __future__ import annotations
 
+import weakref
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
 from libgrape_lite_tpu.app.base import ParallelAppBase, StepContext
+from libgrape_lite_tpu.obs.federation import FederatedStats as _FedStats
 from libgrape_lite_tpu.parallel.comm_spec import FRAG_AXIS
 from libgrape_lite_tpu.utils.types import LoadStrategy, MessageStrategy
+
+# the resident adjacency: how often it was built and how often found
+# with the fragment, and the geometry of the one the last query read
+# (docs/OBSERVABILITY.md)
+LCC_STATS = _FedStats("lcc", {
+    "builds": 0, "cache_hits": 0, "d_max": 0, "ell_bytes": 0,
+    "oriented_edges": 0, "query_lanes": 0, "tiers": 0,
+})
+
+# fragment -> {(orientation, degree_threshold, tier request): adjacency};
+# weak-keyed, so an adjacency goes with its fragment
+_ADJACENCY_CACHE = weakref.WeakKeyDictionary()
+
+
+def _chunk_rows(width: int) -> int:
+    """Edges a merge pass takes at once at query width `width`: bounded
+    so that chunk x width stays about 4M int32 entries."""
+    return max(128, min(4096, (1 << 22) // max(width, 1)))
+
+
+def _untiered_lanes(ep: int, d: int) -> int:
+    """Padded lanes of the untiered pass over `ep` oe entries."""
+    c_e = min(_chunk_rows(d), ep)
+    return max(1, -(-ep // c_e)) * c_e * d
 
 
 class LCCBeta(ParallelAppBase):
@@ -72,9 +108,10 @@ class LCCBeta(ParallelAppBase):
         return self.orientation
 
     def init_state(self, frag, degree_threshold: int = 0, **_):
-        """Host prep: dedup degree-oriented out-adjacency as sorted,
-        padded ELL blocks (the analogue of lcc.h stage-1 neighbor
-        filtering, done once against the host CSRs).
+        """The query's state: the zeroed result plus the fragment's
+        oriented adjacency (`ell`, `cnt`, the tier schedule `eperm`),
+        which is built once per fragment and rides as read-only
+        ephemeral leaves (`_resident_adjacency`).
 
         degree_threshold > 0 drops filtered (hub) vertices' lists — the
         reference's LCC cost cap (`lcc.h:234-243`, 0 = disabled)."""
@@ -89,6 +126,54 @@ class LCCBeta(ParallelAppBase):
             "spgemm lowering (use lcc_bitmap/lcc_opt)",
         )
         self.degree_threshold = int(degree_threshold)
+        adj = self._resident_adjacency(frag)
+        self._tier_info = adj["tier_info"]
+        state = {
+            "ell": adj["ell"],
+            "cnt": adj["cnt"],
+            "lcc": np.zeros((frag.fnum, frag.vp), dtype=np.float64),
+        }
+        if adj["eperm"] is not None:
+            state["eperm"] = adj["eperm"]
+        # read-only inputs, never written: out of the fused loop's
+        # carry and of the result state (the ephemeral stream-table
+        # convention, worker.py eph_part)
+        self.ephemeral_keys = frozenset(state) - {"lcc"}
+        return state
+
+    def _resident_adjacency(self, frag) -> dict:
+        """The oriented adjacency of `frag` as placed device arrays,
+        built once per (fragment, effective orientation,
+        degree_threshold, requested tier widths) and kept with the
+        fragment, as `fragment/edgecut._BOUNDARY_CACHE` keeps the
+        boundary split: it is a property of the resident graph, not of
+        a query, so a second query builds nothing on the host and
+        copies nothing to the device (`put_global` hands a placed
+        array through).  A mutated or rebuilt fragment is another
+        object and so another key."""
+        per_frag = _ADJACENCY_CACHE.setdefault(frag, {})
+        key = (self._eff_orientation(), self.degree_threshold,
+               self._tier_request())
+        if key in per_frag:
+            LCC_STATS["cache_hits"] += 1
+        else:
+            from libgrape_lite_tpu.parallel.comm_spec import put_global
+
+            adj = self._build_adjacency(frag, key[2])
+            shard = frag.comm_spec.sharded()
+            for k in ("ell", "cnt", "eperm"):
+                adj[k] = put_global(adj[k], shard)
+            per_frag[key] = adj
+            LCC_STATS["builds"] += 1
+        # the geometry the counter shows is the last query's
+        LCC_STATS.update(per_frag[key]["geometry"])
+        return per_frag[key]
+
+    def _build_adjacency(self, frag, tier_request) -> dict:
+        """Host prep: dedup degree-oriented out-adjacency as sorted,
+        padded ELL blocks (the analogue of lcc.h stage-1 neighbor
+        filtering, done against the host CSRs), and the tier schedule
+        over them."""
         fnum, vp = frag.fnum, frag.vp
         n_pad = fnum * vp
         sent = n_pad  # sorts last, never matches a valid query
@@ -100,14 +185,14 @@ class LCCBeta(ParallelAppBase):
 
         rows_per_frag = []
         cnts = np.zeros((fnum, vp), dtype=np.int32)
+        # the oe entries the orientation rule keeps, for the schedule
+        kept = np.zeros((fnum, len(frag.host_oe[0].edge_src)), dtype=bool)
         d_max = 1
         for f in range(fnum):
             c = frag.host_oe[f]
             e = c.num_edges
             v = f * vp + c.edge_src[:e].astype(np.int64)
             u = c.edge_nbr[:e].astype(np.int64)
-            pairs = np.unique(np.stack([v, u], 1), axis=0)
-            v, u = pairs[:, 0], pairs[:, 1]
             if self._eff_orientation() == "lo":
                 # low->high: out-degree bounded by degeneracy (hubs
                 # keep only higher-degree neighbors — few); the k=4
@@ -119,7 +204,11 @@ class LCCBeta(ParallelAppBase):
             keep &= u != v
             if self.degree_threshold > 0:
                 keep &= deg[v] <= self.degree_threshold
-            v, u = v[keep], u[keep]
+            kept[f, :e] = keep
+            # distinct kept (v, u) in (v, u) order: one packed key (v,
+            # u < n_pad + 1, so the key stays far inside int64)
+            packed = np.unique((v * (n_pad + 1) + u)[keep])
+            v, u = packed // (n_pad + 1), packed % (n_pad + 1)
             lid = (v - f * vp).astype(np.int64)
             cnt = np.bincount(lid, minlength=vp).astype(np.int32)
             cnts[f] = cnt
@@ -152,19 +241,24 @@ class LCCBeta(ParallelAppBase):
             col = np.arange(len(lid_s)) - starts[lid_s]
             stacked[f, lid_s, col] = u_s  # ascending per row (lexsort)
 
-        eperm = self._build_tier_perm(frag, cnts, d_max)
-        state = {
-            "ell": stacked,
-            "cnt": cnts,
-            "lcc": np.zeros((fnum, vp), dtype=np.float64),
+        eperm, tier_info = self._build_tier_perm(
+            frag, cnts, d_max, tier_request, kept
+        )
+        ep = len(frag.host_oe[0].edge_src)
+        geometry = {
+            "d_max": d_max,
+            "ell_bytes": int(stacked.nbytes),
+            "oriented_edges": int(sum(len(r[0]) for r in rows_per_frag)),
+            # the padded lanes one device's step runs: every ring pass
+            # walks the whole schedule
+            "query_lanes": fnum * (
+                sum(n * c * w for _, n, c, w in tier_info)
+                if tier_info else _untiered_lanes(ep, d_max)
+            ),
+            "tiers": len(tier_info) if tier_info else 1,
         }
-        if eperm is not None:
-            state["eperm"] = eperm
-            # read-only schedule table: keep it out of the fused-loop
-            # carry and the result state (the ephemeral stream-table
-            # convention, worker.py eph_part)
-            self.ephemeral_keys = frozenset({"eperm"})
-        return state
+        return {"ell": stacked, "cnt": cnts, "eperm": eperm,
+                "tier_info": tier_info, "geometry": geometry}
 
     # width ladder for the tiered merge passes; "0" disables tiering.
     # Subclasses that override peval with their own edge walk (the
@@ -173,35 +267,19 @@ class LCCBeta(ParallelAppBase):
     _TIER_WIDTHS = (64, 256)
     uses_tiered_pass = True
 
-    def _build_tier_perm(self, frag, cnts, d_max):
-        """Tiered edge schedule (r5): the query side of the merge pass
-        costs W_query x log(D) per edge, but the average oriented
-        out-degree is far below D (RMAT-22: mean 16 vs D 1030 — 98% of
-        searchsorted lanes probe ELL padding, on the CPU substrate and
-        the TPU VPU alike).  Bucket every oe edge by its SOURCE row's
-        ELL width and process each bucket at its own static width:
-        tier t covers rows with cnt <= W_t, so its queries slice
-        `ell[:, :W_t]` with zero semantic change (the sliced-off lanes
-        were invalid by qvalid anyway).
-
-        Produces state["eperm"] [fnum, L] int32 — per-tier segments of
-        oe-edge indices, sentinel Ep in the padding slots — plus
-        self._tier_info [(offset, n_chunks, chunk, W)] with segment
-        geometry uniform across shards (max over shards, padded to the
-        tier's chunk size), as shard_map needs one static program."""
+    def _tier_request(self):
+        """The width ladder asked for (the class's, or GRAPE_LCC_TIERS'),
+        None for no tiering: part of the resident adjacency's key."""
         import os
 
         if not self.uses_tiered_pass:
-            self._tier_info = None
             return None
         spec = os.environ.get("GRAPE_LCC_TIERS")
         if spec == "0":
-            self._tier_info = None
             return None
-        req = self._TIER_WIDTHS
         if spec:
             try:
-                req = tuple(int(x) for x in spec.split(","))
+                return tuple(int(x) for x in spec.split(","))
             except ValueError:
                 from libgrape_lite_tpu.utils import logging as glog
 
@@ -209,11 +287,35 @@ class LCCBeta(ParallelAppBase):
                     f"GRAPE_LCC_TIERS={spec!r} is not a comma-separated "
                     "int list; using the default width ladder"
                 )
+        return tuple(self._TIER_WIDTHS)
+
+    def _build_tier_perm(self, frag, cnts, d_max, req, kept):
+        """Tiered edge schedule (r5): the query side of the merge pass
+        costs W_query x log(D) per edge, but the average oriented
+        out-degree is far below D (RMAT-22: mean 16 vs D 1030 — 98% of
+        searchsorted lanes probe ELL padding, on the CPU substrate and
+        the TPU VPU alike).  Bucket the oe entries the host's own
+        orientation rule keeps (`kept` [fnum, Ep] bool: the ELL is
+        built from that rule, so the other half could only be masked
+        away inside the step) by their SOURCE row's
+        ELL width and process each bucket at its own static width:
+        tier t covers rows with cnt <= W_t, so its queries slice
+        `ell[:, :W_t]` with zero semantic change (the sliced-off lanes
+        were invalid by qvalid anyway).
+
+        Returns (eperm, tier_info): eperm [fnum, L] int32 — per-tier
+        segments of oe-edge indices, sentinel Ep in the padding slots —
+        and tier_info [(offset, n_chunks, chunk, W)] with segment
+        geometry uniform across shards (max over shards, padded to the
+        tier's chunk size), as shard_map needs one static program;
+        (None, None) where `req` (`_tier_request`) asks for no tiering
+        or leaves nothing to tier."""
+        if req is None:
+            return None, None
         widths = [w for w in req if 0 < w < d_max]
         widths = sorted(set(widths)) + [d_max]
         if len(widths) == 1:
-            self._tier_info = None  # nothing to tier
-            return None
+            return None, None  # nothing to tier
 
         fnum, vp = frag.fnum, frag.vp
         ep = len(frag.host_oe[0].edge_src)
@@ -225,7 +327,7 @@ class LCCBeta(ParallelAppBase):
             tier = np.searchsorted(bounds, c[np.minimum(src, vp)],
                                    side="left")
             per_shard.append(
-                [np.flatnonzero(tier == t).astype(np.int32)
+                [np.flatnonzero((tier == t) & kept[f]).astype(np.int32)
                  for t in range(len(widths))]
             )
 
@@ -233,7 +335,7 @@ class LCCBeta(ParallelAppBase):
         segs = [[] for _ in range(fnum)]
         offset = 0
         for t, w in enumerate(widths):
-            c_t = max(128, min(4096, (1 << 22) // max(w, 1)))
+            c_t = _chunk_rows(w)
             n_t = max(len(per_shard[f][t]) for f in range(fnum))
             n_t = -(-max(n_t, 1) // c_t) * c_t  # pad to chunk multiple
             for f in range(fnum):
@@ -243,8 +345,7 @@ class LCCBeta(ParallelAppBase):
                 segs[f].append(seg)
             info.append((offset, n_t // c_t, c_t, w))
             offset += n_t
-        self._tier_info = info
-        return np.stack([np.concatenate(s) for s in segs])
+        return np.stack([np.concatenate(s) for s in segs]), info
 
     def _oriented_edge_mask(self, ctx, frag):
         """Traced oriented-dedup edge mask over frag.oe — the SAME rule
@@ -287,15 +388,15 @@ class LCCBeta(ParallelAppBase):
         d = ell.shape[-1]
         oe = frag.oe
 
-        keep = self._oriented_edge_mask(ctx, frag)
-
         ep = oe.edge_src.shape[0]
-        # chunk size bounded so chunk*d stays ~4M int32 entries
-        c_e = max(128, min(4096, (1 << 22) // max(d, 1)))
-        c_e = min(c_e, ep)
+        c_e = min(_chunk_rows(d), ep)
         n_chunks = max(1, -(-ep // c_e))
-        nbr_fid = (oe.edge_nbr // vp).astype(jnp.int32)
-        nbr_lid = (oe.edge_nbr % vp).astype(jnp.int32)
+        # the degree gather, the oriented mask and the neighbour ids
+        # split into (fragment, row): E-wide, once a query
+        with jax.named_scope("grape.lcc.orient"):
+            keep = self._oriented_edge_mask(ctx, frag)
+            nbr_fid = (oe.edge_nbr // vp).astype(jnp.int32)
+            nbr_lid = (oe.edge_nbr % vp).astype(jnp.int32)
 
         cred = jnp.zeros((n_pad + 1,), dtype=jnp.int32)
         tier_info = getattr(self, "_tier_info", None)
@@ -312,30 +413,33 @@ class LCCBeta(ParallelAppBase):
             """Shared credit math for one chunk: q [C, W] queries from
             local rows `srcs`, targets = rot_ell rows of nlid_c."""
             sl = jnp.minimum(srcs, vp - 1)
-            tgt = rot_ell[nlid_c]               # [C, D] sorted (N+(u))
-            tcnt = rot_cnt[nlid_c]
-            pos = jax.vmap(jnp.searchsorted)(tgt, q)  # [C, W]
-            pos_c = jnp.minimum(pos, d - 1)
-            hit = jnp.take_along_axis(tgt, pos_c, axis=1) == q
-            hit = jnp.logical_and(hit, pos < tcnt[:, None])
-            hit = jnp.logical_and(hit, qv)
-            hit = jnp.logical_and(hit, sel[:, None])
+            with jax.named_scope("grape.lcc.rows"):
+                tgt = rot_ell[nlid_c]           # [C, D] sorted (N+(u))
+                tcnt = rot_cnt[nlid_c]
+            with jax.named_scope("grape.lcc.intersect"):
+                pos = jax.vmap(jnp.searchsorted)(tgt, q)  # [C, W]
+                pos_c = jnp.minimum(pos, d - 1)
+                hit = jnp.take_along_axis(tgt, pos_c, axis=1) == q
+                hit = jnp.logical_and(hit, pos < tcnt[:, None])
+                hit = jnp.logical_and(hit, qv)
+                hit = jnp.logical_and(hit, sel[:, None])
+                c1 = hit.sum(axis=1, dtype=jnp.int32)
 
-            c1 = hit.sum(axis=1, dtype=jnp.int32)
             v_pid = my_fid * vp + sl  # local row pid
-            cr = cr.at[jnp.where(sel, v_pid, n_pad)].add(
-                jnp.where(sel, c1, 0)
-            )
-            if self.credit_mode == "lcc":
-                u_pid = cur_fid * vp + nlid_c
-                cr = cr.at[jnp.where(sel, u_pid, n_pad)].add(
+            with jax.named_scope("grape.lcc.credit"):
+                cr = cr.at[jnp.where(sel, v_pid, n_pad)].add(
                     jnp.where(sel, c1, 0)
                 )
-                # far-end credits: +1 per matched member value
-                w_idx = jnp.where(hit, q, jnp.int32(n_pad))
-                cr = cr.at[w_idx.reshape(-1)].add(
-                    hit.reshape(-1).astype(jnp.int32)
-                )
+                if self.credit_mode == "lcc":
+                    u_pid = cur_fid * vp + nlid_c
+                    cr = cr.at[jnp.where(sel, u_pid, n_pad)].add(
+                        jnp.where(sel, c1, 0)
+                    )
+                    # far-end credits: +1 per matched member value
+                    w_idx = jnp.where(hit, q, jnp.int32(n_pad))
+                    cr = cr.at[w_idx.reshape(-1)].add(
+                        hit.reshape(-1).astype(jnp.int32)
+                    )
             return cr
 
         def pass_for(carry_cred, rot_ell, rot_cnt, cur_fid):
@@ -346,22 +450,24 @@ class LCCBeta(ParallelAppBase):
                 ):
                     def body(i, cr, off=off, c_t=c_t, w_t=w_t,
                              ell_t=ell_t):
-                        idx = lax.dynamic_slice(
-                            eperm, (off + i * c_t,), (c_t,)
-                        )
-                        vld = idx < ep          # Ep = padding sentinel
-                        ic = jnp.minimum(idx, ep - 1)
-                        srcs = oe.edge_src[ic]
-                        nfid_c = nbr_fid[ic]
-                        nlid_c = nbr_lid[ic]
-                        sel = jnp.logical_and(
-                            jnp.logical_and(vld, keep[ic]),
-                            nfid_c == cur_fid,
-                        )
-                        sl = jnp.minimum(srcs, vp - 1)
-                        q = ell_t[sl]           # [C, W_t]
-                        # tier rows have cnt <= W_t by construction
-                        qv = jnp.arange(w_t)[None, :] < cnt[sl][:, None]
+                        with jax.named_scope("grape.lcc.rows"):
+                            idx = lax.dynamic_slice(
+                                eperm, (off + i * c_t,), (c_t,)
+                            )
+                            vld = idx < ep      # Ep = padding sentinel
+                            ic = jnp.minimum(idx, ep - 1)
+                            srcs = oe.edge_src[ic]
+                            nfid_c = nbr_fid[ic]
+                            nlid_c = nbr_lid[ic]
+                            sel = jnp.logical_and(
+                                jnp.logical_and(vld, keep[ic]),
+                                nfid_c == cur_fid,
+                            )
+                            sl = jnp.minimum(srcs, vp - 1)
+                            q = ell_t[sl]       # [C, W_t]
+                            # tier rows have cnt <= W_t by construction
+                            qv = (jnp.arange(w_t)[None, :]
+                                  < cnt[sl][:, None])
                         return chunk_credit(
                             cr, srcs, nlid_c, sel, q, qv, rot_ell,
                             rot_cnt, cur_fid,
@@ -371,19 +477,21 @@ class LCCBeta(ParallelAppBase):
                 return cr
 
             def body(i, cr):
-                start = jnp.minimum(i * c_e, ep - c_e)
-                pos0 = start + jnp.arange(c_e, dtype=jnp.int32)
-                fresh = pos0 >= i * c_e
-                srcs = lax.dynamic_slice(oe.edge_src, (start,), (c_e,))
-                nfid = lax.dynamic_slice(nbr_fid, (start,), (c_e,))
-                nlid = lax.dynamic_slice(nbr_lid, (start,), (c_e,))
-                kept = lax.dynamic_slice(keep, (start,), (c_e,))
-                sel = jnp.logical_and(jnp.logical_and(kept, fresh),
-                                      nfid == cur_fid)
+                with jax.named_scope("grape.lcc.rows"):
+                    start = jnp.minimum(i * c_e, ep - c_e)
+                    pos0 = start + jnp.arange(c_e, dtype=jnp.int32)
+                    fresh = pos0 >= i * c_e
+                    srcs = lax.dynamic_slice(
+                        oe.edge_src, (start,), (c_e,))
+                    nfid = lax.dynamic_slice(nbr_fid, (start,), (c_e,))
+                    nlid = lax.dynamic_slice(nbr_lid, (start,), (c_e,))
+                    kept = lax.dynamic_slice(keep, (start,), (c_e,))
+                    sel = jnp.logical_and(jnp.logical_and(kept, fresh),
+                                          nfid == cur_fid)
 
-                sl = jnp.minimum(srcs, vp - 1)
-                q = ell[sl]                     # [C, D] queries (N+(v))
-                qv = jnp.arange(d)[None, :] < cnt[sl][:, None]
+                    sl = jnp.minimum(srcs, vp - 1)
+                    q = ell[sl]                 # [C, D] queries (N+(v))
+                    qv = jnp.arange(d)[None, :] < cnt[sl][:, None]
                 return chunk_credit(
                     cr, srcs, nlid, sel, q, qv, rot_ell, rot_cnt,
                     cur_fid,
@@ -408,23 +516,27 @@ class LCCBeta(ParallelAppBase):
                 0, fnum, ring_body, (cred, ell, cnt)
             )
 
-        total = ctx.sum(cred[:n_pad])
-        tri = lax.dynamic_slice(total, (my_fid * vp,), (vp,))
+        with jax.named_scope("grape.lcc.credit"):
+            total = ctx.sum(cred[:n_pad])
+            tri = lax.dynamic_slice(total, (my_fid * vp,), (vp,))
 
         if self.credit_mode == "apex":
             # raw per-apex triangle counts (k=3 clique counting) stay
             # integer end to end — float32 would round above 2^24
-            out = jnp.where(frag.inner_mask, tri, 0).astype(jnp.int32)
+            with jax.named_scope("grape.app.update"):
+                out = jnp.where(
+                    frag.inner_mask, tri, 0).astype(jnp.int32)
             return dict(state, tri=out), jnp.int32(0)
-        dt = state["lcc"].dtype
-        deg_local = frag.out_degree
-        degf = deg_local.astype(dt)
-        denom = degf * (degf - 1)
-        lcc = jnp.where(
-            jnp.logical_and(frag.inner_mask, deg_local >= 2),
-            2.0 * tri.astype(dt) / jnp.maximum(denom, 1),
-            jnp.asarray(0, dt),
-        )
+        with jax.named_scope("grape.app.update"):
+            dt = state["lcc"].dtype
+            deg_local = frag.out_degree
+            degf = deg_local.astype(dt)
+            denom = degf * (degf - 1)
+            lcc = jnp.where(
+                jnp.logical_and(frag.inner_mask, deg_local >= 2),
+                2.0 * tri.astype(dt) / jnp.maximum(denom, 1),
+                jnp.asarray(0, dt),
+            )
         return dict(state, lcc=lcc), jnp.int32(0)
 
     def inceval(self, ctx, frag, state):

@@ -46,6 +46,10 @@ def put_global(x, sharding: NamedSharding):
     reshards from there)."""
     if x is None:
         return None
+    if isinstance(x, jax.Array) and x.sharding.is_equivalent_to(
+        sharding, x.ndim
+    ):
+        return x  # placed already (a resident table): nothing to copy
     if sharding.is_fully_addressable:
         return jax.device_put(x, sharding)
     arr = np.asarray(x)

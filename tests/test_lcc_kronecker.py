@@ -1,0 +1,278 @@
+"""LCC on the benchmark's own graph, against the benchmark's plain reference.
+
+The simple Graph500 Kronecker graph of `benchmarks/configs/g500-lcc.json`
+(`benchmarks/graphs/kronecker_simple.py`) has what p2p-31 lacks: hubs, so
+that the tiered schedule runs unforced from scale 12 up, isolated vertices
+and ids permuted at random.  The registry's `lcc` is held to the
+configuration's own rule on every vertex; the plain reference is held to
+the dense definition; the generator to what it says of itself.
+
+The residency cases pin that the oriented adjacency is built once per
+fragment and rides as read-only ephemeral leaves; the lowered-text cases
+that the `grape.lcc.*` scopes are there, are metadata only, and left the
+other runners alone.
+"""
+
+import contextlib
+import json
+import os
+import types
+
+import jax
+import numpy as np
+import pytest
+
+from benchmarks.compare import mismatches
+from benchmarks.graphs import kronecker, kronecker_simple
+from benchmarks.graphs.csr import symmetric_csr
+from benchmarks.references import lcc as lcc_reference
+from libgrape_lite_tpu.fragment.loader import LoadGraph, LoadGraphSpec
+from libgrape_lite_tpu.models import APP_REGISTRY
+from libgrape_lite_tpu.models.lcc_beta import LCC_STATS, ApexTriangleCount
+from libgrape_lite_tpu.parallel.comm_spec import CommSpec
+from libgrape_lite_tpu.worker.worker import Worker
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+with open(os.path.join(ROOT, "benchmarks", "configs", "g500-lcc.json")) as f:
+    CONFIG = json.load(f)
+GEN = CONFIG["generator"]
+RULE = CONFIG["guarantees"]["lcc"]
+SCOPES = ("grape.lcc.orient", "grape.lcc.rows", "grape.lcc.intersect",
+          "grape.lcc.credit", "grape.app.update")
+
+
+@pytest.fixture(scope="module")
+def kron(tmp_path_factory):
+    """(scale, fnum) -> a fragment through LoadGraph; scale -> the graph."""
+    files, graphs = {}, {}
+
+    def graph(scale: int):
+        if scale not in graphs:
+            d = tmp_path_factory.mktemp(f"kron_simple{scale}")
+            files[scale] = str(d / "graph.e"), str(d / "graph.v")
+            kronecker_simple.write_files(GEN, scale, *files[scale])
+            n = 1 << scale
+            _, mult = symmetric_csr(n, *kronecker_simple.edges(GEN, scale))
+            graphs[scale] = types.SimpleNamespace(n=n, mult=mult)
+        return graphs[scale]
+
+    def load(scale: int, fnum: int):
+        graph(scale)
+        spec = dict(CONFIG["load_graph_spec"])
+        spec["edata_dtype"] = np.dtype(spec["edata_dtype"]).type
+        return LoadGraph(*files[scale], CommSpec(fnum=fnum), LoadGraphSpec(**spec))
+
+    return types.SimpleNamespace(graph=graph, load=load)
+
+
+def by_id(frag, values) -> np.ndarray:
+    values = np.asarray(values)
+    out = np.empty(frag.dev.total_vnum, dtype=values.dtype)
+    for f in range(frag.fnum):
+        out[frag.inner_oids(f)] = values[f, :frag.inner_vertices_num(f)]
+    return out
+
+
+# ---- the answer ------------------------------------------------------------
+
+
+@pytest.mark.parametrize("scale,fnum", [(10, 1), (10, 2), (12, 1), (12, 2)])
+def test_registry_lcc_holds_the_configurations_rule(kron, scale, fnum):
+    frag = kron.load(scale, fnum)
+    app = APP_REGISTRY["lcc"]()
+    w = Worker(app, frag)
+    w.query()
+    assert w.rounds == 0  # PEval is the whole algorithm
+    assert (app._tier_info is not None) == (scale >= 12)  # hubs: tiers unforced
+    got = lcc_reference.to_reference_form(by_id(frag, w.result_values()))
+    want = lcc_reference.reference(kron.graph(scale), {})
+    assert want.max() == 1.0 and 0.05 < want.mean() < 0.9  # triangles are there
+    assert mismatches(RULE["rule"], got, want, RULE["eps"]) == 0
+    # one lost triangle at any vertex that has one breaks the rule
+    d = np.diff(lcc_reference.simple_adjacency(kron.graph(scale).mult).indptr)
+    one_less = np.where(want > 0, want - 2.0 / np.maximum(d * (d - 1.0), 1.0), want)
+    assert mismatches(RULE["rule"], one_less, want, RULE["eps"]) == (want > 0).sum()
+
+
+def test_reference_is_the_dense_definition(kron):
+    graph = kron.graph(8)
+    a = lcc_reference.simple_adjacency(graph.mult).toarray()
+    assert (a == a.T).all() and not a.diagonal().any() and a.max() == 1
+    d = a.sum(axis=1)
+    tri = np.diag(a @ a @ a) // 2
+    assert (lcc_reference.triangles(lcc_reference.simple_adjacency(graph.mult))
+            == tri).all() and tri.sum() > 0
+    want = np.where(d >= 2, 2.0 * tri / np.maximum(d * (d - 1.0), 1.0), 0.0)
+    assert np.array_equal(lcc_reference.reference(graph, {}), want)
+
+
+def test_reference_in_row_blocks_is_the_reference_whole(kron, monkeypatch):
+    graph = kron.graph(10)
+    whole = lcc_reference.reference(graph, {})
+    monkeypatch.setattr(lcc_reference, "BLOCK_PRODUCTS", 500)  # hundreds of blocks
+    assert np.array_equal(lcc_reference.reference(graph, {}), whole)
+
+
+def test_reference_ignores_doubled_edges_and_self_loops():
+    src = np.array([0, 1, 2, 0, 0, 3, 3, 1])
+    dst = np.array([1, 2, 0, 3, 0, 3, 0, 0])  # a triangle, a tail, loops, repeats
+    _, mult = symmetric_csr(5, src, dst, np.ones(len(src)))
+    got = lcc_reference.reference(types.SimpleNamespace(mult=mult), {})
+    assert got.tolist() == [1 / 3, 1.0, 1.0, 0.0, 0.0]
+
+
+def test_kronecker_simple_is_kroneckers_draws_made_simple():
+    scale = 10
+    n = 1 << scale
+    src, dst, w = kronecker_simple.edges(GEN, scale)
+    assert (src != dst).all()
+    pairs = np.minimum(src, dst).astype(np.int64) * n + np.maximum(src, dst)
+    assert len(np.unique(pairs)) == len(pairs)
+    ds, dd, dw = kronecker.edges(GEN, scale)
+    drawn = {}
+    for s, d, x in zip(ds.tolist(), dd.tolist(), dw.tolist()):
+        if s != d:
+            drawn.setdefault((min(s, d), max(s, d)), []).append((s, d, x))
+    assert len(drawn) == len(pairs) < len(ds)  # every pair once, some were repeats
+    for s, d, x in zip(src.tolist(), dst.tolist(), w.tolist()):
+        tuples = drawn[min(s, d), max(s, d)]
+        assert (s, d, x) in tuples and x == min(t[2] for t in tuples)
+    # in drawn order
+    order = {t: i for i, t in reversed(list(enumerate(zip(ds.tolist(), dd.tolist(),
+                                                           dw.tolist()))))}
+    at = [order[t] for t in zip(src.tolist(), dst.tolist(), w.tolist())]
+    assert at == sorted(at)
+
+
+def test_kronecker_simple_files(kron, tmp_path):
+    efile, vfile = str(tmp_path / "g.e"), str(tmp_path / "g.v")
+    info = kronecker_simple.write_files(GEN, 8, efile, vfile)
+    src, dst, w = kronecker_simple.edges(GEN, 8)
+    rows = np.loadtxt(efile, dtype=np.int64)
+    assert (rows == np.stack([src, dst, w], 1)).all()
+    assert open(vfile).read().split() == [str(i) for i in range(256)]
+    assert info["vertices"] == 256 and info["edges"] == len(src) < info["drawn"] == 4096
+    assert info["pull_entries"] == 2 * len(src)
+
+
+# ---- the resident adjacency ------------------------------------------------
+
+
+def test_second_query_builds_nothing_and_a_rebuilt_fragment_builds_again(kron):
+    frag = kron.load(10, 1)
+    before = LCC_STATS.snapshot()
+    w = Worker(APP_REGISTRY["lcc"](), frag)
+    w.query()
+    first = np.asarray(w.result_values()).copy()
+    state = w.app.init_state(frag)
+    placed = w._place_state(state)
+    for k in ("ell", "cnt"):
+        assert isinstance(state[k], jax.Array), k  # on the device already
+        assert placed[k] is state[k], f"{k} was copied again"
+    w.query()
+    after = LCC_STATS.snapshot()
+    assert after["builds"] - before["builds"] == 1
+    assert after["cache_hits"] - before["cache_hits"] == 2
+    assert np.array_equal(np.asarray(w.result_values()), first)
+    assert after["ell_bytes"] == state["ell"].nbytes == frag.vp * after["d_max"] * 4
+    assert after["oriented_edges"] == frag.host_oe[0].num_edges // 2
+    # another worker, another app of the family: the fragment's adjacency
+    Worker(ApexTriangleCount(), frag).query()
+    assert LCC_STATS["builds"] == after["builds"]
+    # another threshold is another adjacency
+    Worker(APP_REGISTRY["lcc"](), frag).query(degree_threshold=5)
+    assert LCC_STATS["builds"] == after["builds"] + 1
+    # a rebuilt fragment is another key
+    again = kron.load(10, 1)
+    Worker(APP_REGISTRY["lcc"](), again).query()
+    assert LCC_STATS["builds"] == after["builds"] + 2
+
+
+def test_the_adjacency_goes_with_its_fragment(kron):
+    import gc
+
+    from libgrape_lite_tpu.models import lcc_beta
+
+    frag = kron.load(10, 1)
+    Worker(APP_REGISTRY["lcc"](), frag).query()
+    held = len(lcc_beta._ADJACENCY_CACHE)
+    del frag
+    gc.collect()
+    assert len(lcc_beta._ADJACENCY_CACHE) == held - 1
+
+
+@pytest.mark.parametrize("app", ["lcc", "apex"])
+def test_the_adjacency_is_in_no_carry_and_no_result(kron, app):
+    frag = kron.load(12, 1)
+    made = APP_REGISTRY["lcc"]() if app == "lcc" else ApexTriangleCount()
+    w = Worker(made, frag)
+    out = w.query()
+    keep = {"lcc"} | ({"tri"} if app == "apex" else set())
+    assert set(out) == keep == set(w._result_state)
+    assert made.ephemeral_keys == {"ell", "cnt", "eperm"}
+    _, carry, eph_part, _ = w._staged(
+        lambda: made.init_state(frag), w._place_state, lambda st: w._runner_for(0, st))
+    assert set(carry) == keep and set(eph_part) == {"ell", "cnt", "eperm"}
+
+
+def test_query_lanes_counts_the_schedule(kron):
+    frag = kron.load(12, 2)
+    app = APP_REGISTRY["lcc"]()
+    state = app.init_state(frag)
+    lanes = sum(n * c * w for _, n, c, w in app._tier_info)
+    assert LCC_STATS["tiers"] == len(app._tier_info) >= 2
+    assert LCC_STATS["query_lanes"] == 2 * lanes  # every ring pass walks it all
+    assert state["eperm"].shape == (2, sum(n * c for _, n, c, _ in app._tier_info))
+    assert LCC_STATS["d_max"] == state["ell"].shape[-1] == app._tier_info[-1][3]
+
+
+def test_apex_counts_are_the_references_triangles(kron):
+    frag = kron.load(10, 2)
+    w = Worker(ApexTriangleCount(), frag)
+    w.query()
+    apex = by_id(frag, w.result_values())
+    tri = lcc_reference.triangles(lcc_reference.simple_adjacency(kron.graph(10).mult))
+    assert apex.sum() * 3 == tri.sum() > 0
+
+
+# ---- the scopes, and what they left alone ----------------------------------
+
+
+def lowered(app, frag, debug_info: bool, **params) -> str:
+    w = Worker(app, frag)
+    state = w._place_state(app.init_state(frag, **params))
+    eph = frozenset(getattr(app, "ephemeral_keys", ()) or ())
+    carry = {k: v for k, v in state.items() if k not in eph}
+    eph_part = {k: v for k, v in state.items() if k in eph}
+    return w._runner_for(0, state).lower(frag.dev, carry, eph_part).as_text(
+        debug_info=debug_info)
+
+
+@pytest.mark.parametrize("tiers", ["2,8", "0"])
+@pytest.mark.parametrize("fnum", [1, 4])
+def test_lcc_names_its_step(graph_cache, monkeypatch, fnum, tiers):
+    monkeypatch.setenv("GRAPE_LCC_TIERS", tiers)  # tiered and untiered walks
+    text = lowered(APP_REGISTRY["lcc"](), graph_cache(fnum), True)
+    for scope in SCOPES:
+        assert scope in text, f"no {scope} in LCC's lowered runner"
+
+
+def test_scopes_leave_lccs_lowered_program_alone(graph_cache, monkeypatch):
+    frag = graph_cache(1)
+    scoped = lowered(APP_REGISTRY["lcc"](), frag, False)
+    assert "grape." not in scoped
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    assert "grape." not in lowered(APP_REGISTRY["lcc"](), frag, True)
+    assert lowered(APP_REGISTRY["lcc"](), frag, False) == scoped
+
+
+@pytest.mark.parametrize("app", ["pagerank", "bfs", "sssp", "wcc", "cdlp"])
+def test_the_other_runners_hold_nothing_of_lcc(app, graph_cache):
+    """A query of LCC on the fragment, its resident adjacency included,
+    leaves the program another app lowers on it as it was."""
+    frag = graph_cache(1)
+    params = {"bfs": {"source": 6}, "sssp": {"source": 6}}.get(app, {})
+    before = lowered(APP_REGISTRY[app](), frag, False, **params)
+    Worker(APP_REGISTRY["lcc"](), frag).query()
+    assert "grape.lcc" not in lowered(APP_REGISTRY[app](), frag, True, **params)
+    assert lowered(APP_REGISTRY[app](), frag, False, **params) == before
