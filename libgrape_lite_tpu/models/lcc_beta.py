@@ -1,4 +1,4 @@
-"""LCCBeta — scalable LCC via sorted-adjacency merge intersection.
+"""LCCBeta — scalable LCC via intersection of sorted adjacency lists.
 
 Re-design of `examples/analytical_apps/lcc/lcc_beta.h` (the reference's
 alternative LCC) with the round-2 scaling goal (ROADMAP item 3): the
@@ -9,10 +9,13 @@ intersects *sorted oriented neighbor lists* instead:
   * the degree-oriented DAG's out-adjacency is materialised as a padded
     ELL block `[vp, D] int32` (D = max oriented out-degree, bounded by
     graph degeneracy — O(sqrt(2E)) worst case), rows sorted ascending;
-  * for every oriented edge (v, u): a batched `searchsorted` of N+(v)
-    into N+(u) finds the common members w — one pass yields all three
-    triangle credits (v and u by count, each w by scatter on the
-    matched values), so no reverse (N−) structure and no second pass;
+  * for every oriented edge (v, u): each member of N+(v) is compared
+    with every member of N+(u), a chunk's edges along the lane axis
+    (`_members`: equality over all pairs, no search and no address
+    that depends on data), which finds the common members w — one pass
+    yields all three triangle credits (v and u by count, each w by
+    scatter on the matched values), so no reverse (N−) structure and
+    no second pass;
   * remote rows ride the same ring `ppermute` as the bitmap kernel;
     credits accumulate in a pid-indexed vector folded by one `psum`.
 
@@ -25,7 +28,7 @@ result.  The step carries `jax.named_scope` names (metadata only):
 `grape.lcc.orient`, `.rows`, `.intersect`, `.credit`, and
 `grape.app.update` on the quotient (docs/OBSERVABILITY.md).
 
-Working set is O(chunk · D) — independent of vertex count.  Exactness
+Working set is O(chunk · (W + D)) — independent of vertex count.  Exactness
 matches the golden within eps like models/lcc.py: triangle enumeration
 is orientation-agnostic (each triangle is found exactly once at its
 DAG-minimal edge and all three credits scatter), so the kernels agree
@@ -62,8 +65,8 @@ _ADJACENCY_CACHE = weakref.WeakKeyDictionary()
 
 
 def _chunk_rows(width: int) -> int:
-    """Edges a merge pass takes at once at query width `width`: bounded
-    so that chunk x width stays about 4M int32 entries."""
+    """Edges an intersection pass takes at once at query width `width`:
+    bounded so that chunk x width stays about 4M int32 entries."""
     return max(128, min(4096, (1 << 22) // max(width, 1)))
 
 
@@ -71,6 +74,21 @@ def _untiered_lanes(ep: int, d: int) -> int:
     """Padded lanes of the untiered pass over `ep` oe entries."""
     c_e = min(_chunk_rows(d), ep)
     return max(1, -(-ep // c_e)) * c_e * d
+
+
+def _members(q_t, t_t, qv_t, sel):
+    """hit[i, c]: `q_t[i, c]` is a valid query slot (`qv_t`) of a selected
+    edge (`sel[c]`) and is among `t_t[:, c]`.  `q_t`, `qv_t` [W, C],
+    `t_t` [D, C]: every pair is compared and OR-ed over the target axis
+    with the chunk's edges on the minor (lane) axis, so no address
+    depends on data (a search costs the chip 8 dependent element gathers
+    a lane, this 0.2 ns: PERF.md, PR 31), and XLA fuses
+    the compare into the reduce, so nothing W x D x C is ever written.
+    No order is assumed.  A valid id is below the sentinel that every
+    pad holds, so only a pad of `q_t` can meet a pad of `t_t`, and
+    `qv_t` drops those."""
+    hit = (t_t[:, None, :] == q_t[None, :, :]).any(axis=0)
+    return jnp.logical_and(jnp.logical_and(hit, qv_t), sel[None, :])
 
 
 class LCCBeta(ParallelAppBase):
@@ -290,11 +308,12 @@ class LCCBeta(ParallelAppBase):
         return tuple(self._TIER_WIDTHS)
 
     def _build_tier_perm(self, frag, cnts, d_max, req, kept):
-        """Tiered edge schedule (r5): the query side of the merge pass
-        costs W_query x log(D) per edge, but the average oriented
-        out-degree is far below D (RMAT-22: mean 16 vs D 1030 — 98% of
-        searchsorted lanes probe ELL padding, on the CPU substrate and
-        the TPU VPU alike).  Bucket the oe entries the host's own
+        """Tiered edge schedule (r5): the query side of the intersection
+        costs W_query x D compares per edge (and a scattered credit per
+        query lane), but the average oriented out-degree is far below D
+        (RMAT-22: mean 16 vs D 1030 — 98% of the query lanes hold ELL
+        padding, on the CPU substrate and the TPU VPU alike).  Bucket
+        the oe entries the host's own
         orientation rule keeps (`kept` [fnum, Ep] bool: the ELL is
         built from that rule, so the other half could only be masked
         away inside the step) by their SOURCE row's
@@ -408,22 +427,20 @@ class LCCBeta(ParallelAppBase):
             # side rides the ring at full width)
             tier_ells = [ell[:, :w] for (_, _, _, w) in tier_info]
 
-        def chunk_credit(cr, srcs, nlid_c, sel, q, qv, rot_ell, rot_cnt,
-                         cur_fid):
+        def chunk_credit(cr, srcs, nlid_c, sel, q, qv, rot_ell, cur_fid):
             """Shared credit math for one chunk: q [C, W] queries from
-            local rows `srcs`, targets = rot_ell rows of nlid_c."""
+            local rows `srcs` with their valid slots qv [W, C], targets
+            = rot_ell rows of nlid_c."""
             sl = jnp.minimum(srcs, vp - 1)
             with jax.named_scope("grape.lcc.rows"):
-                tgt = rot_ell[nlid_c]           # [C, D] sorted (N+(u))
-                tcnt = rot_cnt[nlid_c]
+                tgt = rot_ell[nlid_c]           # [C, D] (N+(u))
             with jax.named_scope("grape.lcc.intersect"):
-                pos = jax.vmap(jnp.searchsorted)(tgt, q)  # [C, W]
-                pos_c = jnp.minimum(pos, d - 1)
-                hit = jnp.take_along_axis(tgt, pos_c, axis=1) == q
-                hit = jnp.logical_and(hit, pos < tcnt[:, None])
-                hit = jnp.logical_and(hit, qv)
-                hit = jnp.logical_and(hit, sel[:, None])
-                c1 = hit.sum(axis=1, dtype=jnp.int32)
+                # the chunk's edges along the minor axis for the compare,
+                # and back: the scatter below is 3% of a query dearer in
+                # [W, C] order than in [C, W] (PERF.md, PR 31)
+                hit = _members(q.T, tgt.T, qv, sel)
+                c1 = hit.sum(axis=0, dtype=jnp.int32)
+                hit = hit.T
 
             v_pid = my_fid * vp + sl  # local row pid
             with jax.named_scope("grape.lcc.credit"):
@@ -442,7 +459,7 @@ class LCCBeta(ParallelAppBase):
                     )
             return cr
 
-        def pass_for(carry_cred, rot_ell, rot_cnt, cur_fid):
+        def pass_for(carry_cred, rot_ell, cur_fid):
             if tiered:
                 cr = carry_cred
                 for (off, n_chunks_t, c_t, w_t), ell_t in zip(
@@ -466,11 +483,10 @@ class LCCBeta(ParallelAppBase):
                             sl = jnp.minimum(srcs, vp - 1)
                             q = ell_t[sl]       # [C, W_t]
                             # tier rows have cnt <= W_t by construction
-                            qv = (jnp.arange(w_t)[None, :]
-                                  < cnt[sl][:, None])
+                            qv = (jnp.arange(w_t)[:, None]
+                                  < cnt[sl][None, :])
                         return chunk_credit(
-                            cr, srcs, nlid_c, sel, q, qv, rot_ell,
-                            rot_cnt, cur_fid,
+                            cr, srcs, nlid_c, sel, q, qv, rot_ell, cur_fid,
                         )
 
                     cr = lax.fori_loop(0, n_chunks_t, body, cr)
@@ -491,30 +507,26 @@ class LCCBeta(ParallelAppBase):
 
                     sl = jnp.minimum(srcs, vp - 1)
                     q = ell[sl]                 # [C, D] queries (N+(v))
-                    qv = jnp.arange(d)[None, :] < cnt[sl][:, None]
+                    qv = jnp.arange(d)[:, None] < cnt[sl][None, :]
                 return chunk_credit(
-                    cr, srcs, nlid, sel, q, qv, rot_ell, rot_cnt,
-                    cur_fid,
+                    cr, srcs, nlid, sel, q, qv, rot_ell, cur_fid,
                 )
 
             return lax.fori_loop(0, n_chunks, body, carry_cred)
 
         if fnum == 1:
-            cred = pass_for(cred, ell, cnt, jnp.int32(0))
+            cred = pass_for(cred, ell, jnp.int32(0))
         else:
             perm = [(i, (i - 1) % fnum) for i in range(fnum)]
 
             def ring_body(s, carry):
-                cr, r_ell, r_cnt = carry
+                cr, r_ell = carry
                 cur_fid = (my_fid + s) % fnum
-                cr = pass_for(cr, r_ell, r_cnt, cur_fid)
+                cr = pass_for(cr, r_ell, cur_fid)
                 r_ell = lax.ppermute(r_ell, FRAG_AXIS, perm)
-                r_cnt = lax.ppermute(r_cnt, FRAG_AXIS, perm)
-                return cr, r_ell, r_cnt
+                return cr, r_ell
 
-            cred, _, _ = lax.fori_loop(
-                0, fnum, ring_body, (cred, ell, cnt)
-            )
+            cred, _ = lax.fori_loop(0, fnum, ring_body, (cred, ell))
 
         with jax.named_scope("grape.lcc.credit"):
             total = ctx.sum(cred[:n_pad])

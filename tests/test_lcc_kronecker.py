@@ -9,13 +9,14 @@ the dense definition; the generator to what it says of itself.
 
 The residency cases pin that the oriented adjacency is built once per
 fragment and rides as read-only ephemeral leaves; the lowered-text cases
-that the `grape.lcc.*` scopes are there, are metadata only, and left the
-other runners alone.
+that the `grape.lcc.*` scopes are there, are metadata only, hold no search
+under `.intersect`, and left the other runners alone.
 """
 
 import contextlib
 import json
 import os
+import re
 import types
 
 import jax
@@ -76,7 +77,7 @@ def by_id(frag, values) -> np.ndarray:
 # ---- the answer ------------------------------------------------------------
 
 
-@pytest.mark.parametrize("scale,fnum", [(10, 1), (10, 2), (12, 1), (12, 2)])
+@pytest.mark.parametrize("scale,fnum", [(10, 1), (10, 2), (12, 1), (12, 2), (10, 4)])
 def test_registry_lcc_holds_the_configurations_rule(kron, scale, fnum):
     frag = kron.load(scale, fnum)
     app = APP_REGISTRY["lcc"]()
@@ -255,6 +256,48 @@ def test_lcc_names_its_step(graph_cache, monkeypatch, fnum, tiers):
     text = lowered(APP_REGISTRY["lcc"](), graph_cache(fnum), True)
     for scope in SCOPES:
         assert scope in text, f"no {scope} in LCC's lowered runner"
+
+
+def steps_under(text: str, scope: str):
+    """(JAX's names, StableHLO operations) of what the lowered `text`
+    holds under `scope`, the functions called from there included."""
+    # `#loc7 = loc("grape.lcc.intersect/eq"(#loc3))`: the name stack, with
+    # the enclosing loops and the primitive as components behind the scope
+    locs = {ref: name.split(scope + "/", 1)[1] for ref, name in
+            re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M)
+            if scope + "/" in name}
+    names = {part for name in locs.values() for part in name.split("/")}
+    ops, calls = set(), set()
+    for line in text.splitlines():
+        at = re.search(r"loc\((#loc\d+)\)$", line)
+        if at and at.group(1) in locs:
+            ops.update(re.findall(r"\bstablehlo\.\w+", line)[:1])
+            calls.update(re.findall(r"\bcall @([\w.]+)", line))
+    bodies = {chunk.split("(", 1)[0].split("@")[-1]: chunk
+              for chunk in text.split("\n  func.func ")[1:]}
+    seen = set()
+    while calls - seen:
+        callee = (calls - seen).pop()
+        seen.add(callee)
+        ops.update(re.findall(r"\bstablehlo\.\w+", bodies[callee]))
+        calls.update(re.findall(r"\bcall @([\w.]+)", bodies[callee]))
+    return names, ops
+
+
+@pytest.mark.parametrize("tiers", ["2,8", "0"])
+@pytest.mark.parametrize("fnum", [1, 4])
+def test_lcc_intersects_without_a_search(graph_cache, monkeypatch, fnum, tiers):
+    """Nothing under `grape.lcc.intersect` addresses by data or loops: no
+    gather, no sort, no while (a `searchsorted` is a while of gathers, a
+    `take_along_axis` a gather), so the search cannot come back unnoticed."""
+    monkeypatch.setenv("GRAPE_LCC_TIERS", tiers)
+    text = lowered(APP_REGISTRY["lcc"](), graph_cache(fnum), True)
+    names, ops = steps_under(text, "grape.lcc.intersect")
+    assert {"transpose", "eq", "reduce_or", "reduce_sum"} <= names
+    assert {"stablehlo.compare", "stablehlo.transpose", "stablehlo.reduce"} <= ops
+    for word in ("while", "gather", "sort", "scan", "search", "take_along",
+                 "dynamic_slice"):
+        assert not [n for n in names | ops if word in n], (word, names, ops)
 
 
 def test_scopes_leave_lccs_lowered_program_alone(graph_cache, monkeypatch):
