@@ -71,7 +71,9 @@ NOT_RUN = {
     "lcc_at_size": "the registry's lcc runs at size in the benchmark cell "
                    "g500-lcc.lcc, which checks every vertex (PERF.md); "
                    "docs/SCALE_NOTES.md sizes its ELL past one chip at "
-                   "scale 22. Exact on p2p-31 in Stages A and C",
+                   "scale 22, and the cell g500-lcc-x4.lcc runs it "
+                   "sharded over four chips, the target blocks on a "
+                   "ring. Exact on p2p-31 in Stages A and C",
 }
 
 

@@ -25,8 +25,9 @@ built once per fragment and kept on the device with it
 (`_resident_adjacency`, counted in LCC_STATS), and rides every query as
 read-only ephemeral leaves, out of the fused loop's carry and of the
 result.  The step carries `jax.named_scope` names (metadata only):
-`grape.lcc.orient`, `.rows`, `.intersect`, `.credit`, and
-`grape.app.update` on the quotient (docs/OBSERVABILITY.md).
+`grape.lcc.orient`, `.rows`, `.intersect`, `.credit`, `.ring` on the
+ring's `ppermute` (several fragments only), and `grape.app.update` on
+the quotient (docs/OBSERVABILITY.md).
 
 Working set is O(chunk · (W + D)) — independent of vertex count.  Exactness
 matches the golden within eps like models/lcc.py: triangle enumeration
@@ -57,6 +58,8 @@ from libgrape_lite_tpu.utils.types import LoadStrategy, MessageStrategy
 LCC_STATS = _FedStats("lcc", {
     "builds": 0, "cache_hits": 0, "d_max": 0, "ell_bytes": 0,
     "oriented_edges": 0, "query_lanes": 0, "tiers": 0,
+    "ring_passes": 0, "ring_bytes": 0, "shard_lanes": 0,
+    "shard_kept_max": 0, "shard_kept_min": 0,
 })
 
 # fragment -> {(orientation, degree_threshold, tier request): adjacency};
@@ -263,17 +266,29 @@ class LCCBeta(ParallelAppBase):
             frag, cnts, d_max, tier_request, kept
         )
         ep = len(frag.host_oe[0].edge_src)
+        # the padded lanes of one device's schedule, walked once
+        shard_lanes = (
+            sum(n * c * w for _, n, c, w in tier_info)
+            if tier_info else _untiered_lanes(ep, d_max)
+        )
+        # the ring sends its block once a pass, the last one included
+        ring_passes = fnum if fnum > 1 else 0
+        kept_per_shard = kept.sum(axis=1)
         geometry = {
             "d_max": d_max,
             "ell_bytes": int(stacked.nbytes),
             "oriented_edges": int(sum(len(r[0]) for r in rows_per_frag)),
             # the padded lanes one device's step runs: every ring pass
             # walks the whole schedule
-            "query_lanes": fnum * (
-                sum(n * c * w for _, n, c, w in tier_info)
-                if tier_info else _untiered_lanes(ep, d_max)
-            ),
+            "query_lanes": max(ring_passes, 1) * shard_lanes,
             "tiers": len(tier_info) if tier_info else 1,
+            "ring_passes": ring_passes,
+            # what one device sends a query: its [vp, D] int32 block
+            "ring_bytes": ring_passes * vp * d_max * 4,
+            "shard_lanes": shard_lanes,
+            # oe entries the orientation keeps, fullest and emptiest shard
+            "shard_kept_max": int(kept_per_shard.max()),
+            "shard_kept_min": int(kept_per_shard.min()),
         }
         return {"ell": stacked, "cnt": cnts, "eperm": eperm,
                 "tier_info": tier_info, "geometry": geometry}
@@ -523,7 +538,8 @@ class LCCBeta(ParallelAppBase):
                 cr, r_ell = carry
                 cur_fid = (my_fid + s) % fnum
                 cr = pass_for(cr, r_ell, cur_fid)
-                r_ell = lax.ppermute(r_ell, FRAG_AXIS, perm)
+                with jax.named_scope("grape.lcc.ring"):
+                    r_ell = lax.ppermute(r_ell, FRAG_AXIS, perm)
                 return cr, r_ell
 
             cred, _ = lax.fori_loop(0, fnum, ring_body, (cred, ell))

@@ -10,7 +10,10 @@ the dense definition; the generator to what it says of itself.
 The residency cases pin that the oriented adjacency is built once per
 fragment and rides as read-only ephemeral leaves; the lowered-text cases
 that the `grape.lcc.*` scopes are there, are metadata only, hold no search
-under `.intersect`, and left the other runners alone.
+under `.intersect`, and left the other runners alone.  On several fragments
+the target blocks ride a ring: `grape.lcc.ring` names its `ppermute`, and
+`LCC_STATS` counts its passes and bytes and the shards' schedules
+(`benchmarks/configs/g500-lcc-x4.json` is that deployment).
 """
 
 import contextlib
@@ -36,10 +39,13 @@ from libgrape_lite_tpu.worker.worker import Worker
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 with open(os.path.join(ROOT, "benchmarks", "configs", "g500-lcc.json")) as f:
     CONFIG = json.load(f)
+with open(os.path.join(ROOT, "benchmarks", "configs", "g500-lcc-x4.json")) as f:
+    CONFIG_X4 = json.load(f)
 GEN = CONFIG["generator"]
-RULE = CONFIG["guarantees"]["lcc"]
+RULE = CONFIG_X4["guarantees"]["lcc"]
 SCOPES = ("grape.lcc.orient", "grape.lcc.rows", "grape.lcc.intersect",
           "grape.lcc.credit", "grape.app.update")
+RING = "grape.lcc.ring"
 
 
 @pytest.fixture(scope="module")
@@ -77,7 +83,16 @@ def by_id(frag, values) -> np.ndarray:
 # ---- the answer ------------------------------------------------------------
 
 
-@pytest.mark.parametrize("scale,fnum", [(10, 1), (10, 2), (12, 1), (12, 2), (10, 4)])
+@pytest.mark.parametrize("key", ["generator", "load_graph_spec", "guarantees"])
+def test_the_four_chip_deployment_loads_the_one_chip_graph(key):
+    """At an equal scale the two configurations share graph files and the
+    reference's cache, and hold the answer to one rule."""
+    assert CONFIG_X4[key] == CONFIG[key]
+    assert (CONFIG_X4["fnum"], CONFIG_X4["chips"]) == (4, 4)
+
+
+@pytest.mark.parametrize("scale,fnum", [(10, 1), (10, 2), (12, 1), (12, 2), (10, 4),
+                                        (12, 4)])
 def test_registry_lcc_holds_the_configurations_rule(kron, scale, fnum):
     frag = kron.load(scale, fnum)
     app = APP_REGISTRY["lcc"]()
@@ -227,6 +242,29 @@ def test_query_lanes_counts_the_schedule(kron):
     assert LCC_STATS["d_max"] == state["ell"].shape[-1] == app._tier_info[-1][3]
 
 
+@pytest.mark.parametrize("scale", [10, 12])
+@pytest.mark.parametrize("fnum", [1, 2, 4])
+def test_ring_counters(kron, scale, fnum):
+    """What the ring sends and what a shard walks, from the geometry of the
+    adjacency the query read; nothing of it on one fragment."""
+    frag = kron.load(scale, fnum)
+    app = APP_REGISTRY["lcc"]()
+    state = app.init_state(frag)
+    stats = LCC_STATS.snapshot()
+    passes = fnum if fnum > 1 else 0
+    assert stats["ring_passes"] == passes
+    assert stats["ring_bytes"] == passes * frag.vp * stats["d_max"] * 4
+    assert stats["ring_bytes"] * fnum == passes * state["ell"].nbytes
+    assert stats["query_lanes"] == max(passes, 1) * stats["shard_lanes"] > 0
+    if app._tier_info is not None:
+        assert stats["shard_lanes"] == sum(n * c * w for _, n, c, w in app._tier_info)
+    # the orientation keeps each undirected edge at one of its ends
+    assert stats["shard_kept_max"] >= stats["shard_kept_min"] > 0
+    assert (stats["shard_kept_min"] * fnum <= stats["oriented_edges"]
+            <= stats["shard_kept_max"] * fnum)
+    assert stats["oriented_edges"] == kron.graph(scale).mult.nnz // 2
+
+
 def test_apex_counts_are_the_references_triangles(kron):
     frag = kron.load(10, 2)
     w = Worker(ApexTriangleCount(), frag)
@@ -300,8 +338,21 @@ def test_lcc_intersects_without_a_search(graph_cache, monkeypatch, fnum, tiers):
         assert not [n for n in names | ops if word in n], (word, names, ops)
 
 
-def test_scopes_leave_lccs_lowered_program_alone(graph_cache, monkeypatch):
-    frag = graph_cache(1)
+@pytest.mark.parametrize("tiers", ["2,8", "0"])
+@pytest.mark.parametrize("fnum", [1, 2, 4])
+def test_the_ring_is_named_where_there_is_one(graph_cache, monkeypatch, fnum, tiers):
+    monkeypatch.setenv("GRAPE_LCC_TIERS", tiers)
+    text = lowered(APP_REGISTRY["lcc"](), graph_cache(fnum), True)
+    assert (RING in text) == (fnum > 1)
+    if fnum > 1:  # the name is on the permute and on nothing else
+        names, ops = steps_under(text, RING)
+        assert names == {"ppermute"} and ops == {"stablehlo.collective_permute"}
+        assert text.count("stablehlo.collective_permute") == 1
+
+
+@pytest.mark.parametrize("fnum", [1, 4])
+def test_scopes_leave_lccs_lowered_program_alone(graph_cache, monkeypatch, fnum):
+    frag = graph_cache(fnum)
     scoped = lowered(APP_REGISTRY["lcc"](), frag, False)
     assert "grape." not in scoped
     monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
