@@ -13,9 +13,15 @@ intersects *sorted oriented neighbor lists* instead:
     with every member of N+(u), a chunk's edges along the lane axis
     (`_members`: equality over all pairs, no search and no address
     that depends on data), which finds the common members w — one pass
-    yields all three triangle credits (v and u by count, each w by
-    scatter on the matched values), so no reverse (N−) structure and
-    no second pass;
+    yields all three triangle credits, so no reverse (N−) structure and
+    no second intersection: v and u by the edge's count, and each w at
+    its *adjacency slot* — w = ell[v, j] is named by (row v, column j),
+    so an edge folds its hits as one row into the `[vp, W]` slot table
+    of its walk (`_fold_rows`), and after the walk the tables are
+    flushed by id: the schedule once more without targets, the edge
+    (v, u) reading the slot whose id is u (`_slot_of`).  Crediting each
+    w by id inside the walk is a C x W element scatter a chunk, nine
+    tenths of a query on the chip (PERF.md, PR 33);
   * remote rows ride the same ring `ppermute` as the bitmap kernel;
     credits accumulate in a pid-indexed vector folded by one `psum`.
 
@@ -60,6 +66,7 @@ LCC_STATS = _FedStats("lcc", {
     "oriented_edges": 0, "query_lanes": 0, "tiers": 0,
     "ring_passes": 0, "ring_bytes": 0, "shard_lanes": 0,
     "shard_kept_max": 0, "shard_kept_min": 0,
+    "credit_rows": 0, "flush_updates": 0,
 })
 
 # fragment -> {(orientation, degree_threshold, tier request): adjacency};
@@ -73,10 +80,10 @@ def _chunk_rows(width: int) -> int:
     return max(128, min(4096, (1 << 22) // max(width, 1)))
 
 
-def _untiered_lanes(ep: int, d: int) -> int:
-    """Padded lanes of the untiered pass over `ep` oe entries."""
+def _untiered_entries(ep: int, d: int) -> int:
+    """Padded schedule entries of the untiered pass over `ep` oe entries."""
     c_e = min(_chunk_rows(d), ep)
-    return max(1, -(-ep // c_e)) * c_e * d
+    return max(1, -(-ep // c_e)) * c_e
 
 
 def _members(q_t, t_t, qv_t, sel):
@@ -92,6 +99,35 @@ def _members(q_t, t_t, qv_t, sel):
     `qv_t` drops those."""
     hit = (t_t[:, None, :] == q_t[None, :, :]).any(axis=0)
     return jnp.logical_and(jnp.logical_and(hit, qv_t), sel[None, :])
+
+
+def _fold_rows(slot, sl, hit_t, runs):
+    """`slot[sl[c], i] += hit_t[i, c]`: a chunk's far-end credits by
+    adjacency slot, as row updates of the `[vp, W]` table where crediting
+    by id is C x W element updates (8 ns each on the chip; a whole row
+    costs what two to seven elements do: PERF.md, PR 33).  `sl` [C]
+    ascending, rows may repeat; `hit_t` [W, C] bool; `runs` a static
+    bound on the distinct rows of a chunk.  Where it is under C the runs
+    are summed first, by a one-hot `[runs, C] x [C, W]` product (0/1 in
+    bf16, f32 sums: exact, a run is at most C long), and one row a run
+    is written; a pad run adds 0 to the last row."""
+    upd = hit_t.T
+    if runs < hit_t.shape[1]:
+        opens = jnp.concatenate([jnp.ones((1,), bool), sl[1:] != sl[:-1]])
+        run = jnp.cumsum(opens, dtype=jnp.int32) - 1
+        of_run = run[None, :] == jnp.arange(runs, dtype=jnp.int32)[:, None]
+        upd = jnp.dot(of_run.astype(jnp.bfloat16), upd.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32)
+        sl = jnp.where(of_run, sl[None, :], slot.shape[0] - 1).min(axis=1)
+    return slot.at[sl].add(upd.astype(slot.dtype), indices_are_sorted=True)
+
+
+def _slot_of(rows, q, u):
+    """What the table holds for each edge (v, u) of a chunk: `rows`
+    [C, W] = slot[v] beside the ids `q` [C, W] = ell[v, :W] they
+    belong to; the column that holds `u` [C] is picked by equality (ids
+    are distinct in a row, a pad is no id), 0 where none does."""
+    return jnp.where(q == u[:, None], rows, 0).sum(axis=1, dtype=rows.dtype)
 
 
 class LCCBeta(ParallelAppBase):
@@ -266,11 +302,13 @@ class LCCBeta(ParallelAppBase):
             frag, cnts, d_max, tier_request, kept
         )
         ep = len(frag.host_oe[0].edge_src)
-        # the padded lanes of one device's schedule, walked once
-        shard_lanes = (
-            sum(n * c * w for _, n, c, w in tier_info)
-            if tier_info else _untiered_lanes(ep, d_max)
-        )
+        # the padded entries and lanes of one device's schedule, walked once
+        if tier_info:
+            shard_entries = sum(n * c for _, n, c, _ in tier_info)
+            shard_lanes = sum(n * c * w for _, n, c, w in tier_info)
+        else:
+            shard_entries = _untiered_entries(ep, d_max)
+            shard_lanes = shard_entries * d_max
         # the ring sends its block once a pass, the last one included
         ring_passes = fnum if fnum > 1 else 0
         kept_per_shard = kept.sum(axis=1)
@@ -289,6 +327,11 @@ class LCCBeta(ParallelAppBase):
             # oe entries the orientation keeps, fullest and emptiest shard
             "shard_kept_max": int(kept_per_shard.max()),
             "shard_kept_min": int(kept_per_shard.min()),
+            # the far-end credits ("lcc" mode): an entry folds one row
+            # into the slot table a pass, and the flush walks the
+            # schedule once more, one element update an entry
+            "credit_rows": max(ring_passes, 1) * shard_entries,
+            "flush_updates": shard_entries,
         }
         return {"ell": stacked, "cnt": cnts, "eperm": eperm,
                 "tier_info": tier_info, "geometry": geometry}
@@ -432,83 +475,64 @@ class LCCBeta(ParallelAppBase):
             nbr_fid = (oe.edge_nbr // vp).astype(jnp.int32)
             nbr_lid = (oe.edge_nbr % vp).astype(jnp.int32)
 
+        lcc_mode = self.credit_mode == "lcc"
         cred = jnp.zeros((n_pad + 1,), dtype=jnp.int32)
         tier_info = getattr(self, "_tier_info", None)
         tiered = tier_info is not None and "eperm" in state
+        # width of a walk -> a static bound on the distinct source rows
+        # of one of its chunks
+        runs = {d: c_e}
         if tiered:
             eperm = state["eperm"]
             # per-tier query tables: static slices of the local ELL
             # (queries always come from LOCAL rows; only the target
             # side rides the ring at full width)
             tier_ells = [ell[:, :w] for (_, _, _, w) in tier_info]
+            # a row of tier t holds more than W_(t-1) members, each an
+            # entry of the schedule, consecutive: a chunk cuts two runs
+            # and its padding is one more
+            lows = [0] + [w for (_, _, _, w) in tier_info[:-1]]
+            runs = {w: min(c_t, c_t // (lo + 1) + 3)
+                    for (_, _, c_t, w), lo in zip(tier_info, lows)}
+        # far-end credits by adjacency slot, a table a walk width (a
+        # partial row costs the chip a loop over the rows: PERF.md,
+        # PR 33): slots[W][v, j] belongs to the id ell[v, j].
+        # Temporaries of the step; apex mode credits no far end
+        slots = ({w: jnp.zeros((vp, w), dtype=jnp.int32) for w in runs}
+                 if lcc_mode else None)
 
-        def chunk_credit(cr, srcs, nlid_c, sel, q, qv, rot_ell, cur_fid):
-            """Shared credit math for one chunk: q [C, W] queries from
-            local rows `srcs` with their valid slots qv [W, C], targets
-            = rot_ell rows of nlid_c."""
-            sl = jnp.minimum(srcs, vp - 1)
-            with jax.named_scope("grape.lcc.rows"):
-                tgt = rot_ell[nlid_c]           # [C, D] (N+(u))
-            with jax.named_scope("grape.lcc.intersect"):
-                # the chunk's edges along the minor axis for the compare,
-                # and back: the scatter below is 3% of a query dearer in
-                # [W, C] order than in [C, W] (PERF.md, PR 31)
-                hit = _members(q.T, tgt.T, qv, sel)
-                c1 = hit.sum(axis=0, dtype=jnp.int32)
-                hit = hit.T
-
-            v_pid = my_fid * vp + sl  # local row pid
-            with jax.named_scope("grape.lcc.credit"):
-                cr = cr.at[jnp.where(sel, v_pid, n_pad)].add(
-                    jnp.where(sel, c1, 0)
-                )
-                if self.credit_mode == "lcc":
-                    u_pid = cur_fid * vp + nlid_c
-                    cr = cr.at[jnp.where(sel, u_pid, n_pad)].add(
-                        jnp.where(sel, c1, 0)
-                    )
-                    # far-end credits: +1 per matched member value
-                    w_idx = jnp.where(hit, q, jnp.int32(n_pad))
-                    cr = cr.at[w_idx.reshape(-1)].add(
-                        hit.reshape(-1).astype(jnp.int32)
-                    )
-            return cr
-
-        def pass_for(carry_cred, rot_ell, cur_fid):
+        def walk(carry, visit, scope):
+            """Every chunk of the device's schedule, in order, through
+            `visit(carry, sl, nfid, nlid, live, q)`: the chunk's local
+            source rows `sl` (ascending: the schedule is in `oe` order and
+            `oe` is a CSR by source), its neighbours as (fragment, row),
+            the entries that are real oriented edges (`live`) and the
+            query block q = ell[sl, :W]; the index and row gathers under
+            `scope`."""
             if tiered:
-                cr = carry_cred
-                for (off, n_chunks_t, c_t, w_t), ell_t in zip(
+                for (off, n_chunks_t, c_t, _), ell_t in zip(
                     tier_info, tier_ells
                 ):
-                    def body(i, cr, off=off, c_t=c_t, w_t=w_t,
-                             ell_t=ell_t):
-                        with jax.named_scope("grape.lcc.rows"):
+                    def body(i, carry, off=off, c_t=c_t, ell_t=ell_t):
+                        with jax.named_scope(scope):
                             idx = lax.dynamic_slice(
                                 eperm, (off + i * c_t,), (c_t,)
                             )
                             vld = idx < ep      # Ep = padding sentinel
                             ic = jnp.minimum(idx, ep - 1)
-                            srcs = oe.edge_src[ic]
+                            sl = jnp.minimum(oe.edge_src[ic], vp - 1)
                             nfid_c = nbr_fid[ic]
                             nlid_c = nbr_lid[ic]
-                            sel = jnp.logical_and(
-                                jnp.logical_and(vld, keep[ic]),
-                                nfid_c == cur_fid,
-                            )
-                            sl = jnp.minimum(srcs, vp - 1)
-                            q = ell_t[sl]       # [C, W_t]
+                            live = jnp.logical_and(vld, keep[ic])
                             # tier rows have cnt <= W_t by construction
-                            qv = (jnp.arange(w_t)[:, None]
-                                  < cnt[sl][None, :])
-                        return chunk_credit(
-                            cr, srcs, nlid_c, sel, q, qv, rot_ell, cur_fid,
-                        )
+                            q = ell_t[sl]       # [C, W_t]
+                        return visit(carry, sl, nfid_c, nlid_c, live, q)
 
-                    cr = lax.fori_loop(0, n_chunks_t, body, cr)
-                return cr
+                    carry = lax.fori_loop(0, n_chunks_t, body, carry)
+                return carry
 
-            def body(i, cr):
-                with jax.named_scope("grape.lcc.rows"):
+            def body(i, carry):
+                with jax.named_scope(scope):
                     start = jnp.minimum(i * c_e, ep - c_e)
                     pos0 = start + jnp.arange(c_e, dtype=jnp.int32)
                     fresh = pos0 >= i * c_e
@@ -517,32 +541,76 @@ class LCCBeta(ParallelAppBase):
                     nfid = lax.dynamic_slice(nbr_fid, (start,), (c_e,))
                     nlid = lax.dynamic_slice(nbr_lid, (start,), (c_e,))
                     kept = lax.dynamic_slice(keep, (start,), (c_e,))
-                    sel = jnp.logical_and(jnp.logical_and(kept, fresh),
-                                          nfid == cur_fid)
-
+                    live = jnp.logical_and(kept, fresh)
                     sl = jnp.minimum(srcs, vp - 1)
                     q = ell[sl]                 # [C, D] queries (N+(v))
-                    qv = jnp.arange(d)[:, None] < cnt[sl][None, :]
-                return chunk_credit(
-                    cr, srcs, nlid, sel, q, qv, rot_ell, cur_fid,
-                )
+                return visit(carry, sl, nfid, nlid, live, q)
 
-            return lax.fori_loop(0, n_chunks, body, carry_cred)
+            return lax.fori_loop(0, n_chunks, body, carry)
+
+        def pass_for(carry, rot_ell, cur_fid):
+            """One walk against the target block of fragment `cur_fid`."""
+
+            def chunk_credit(carry, sl, nfid_c, nlid_c, live, q):
+                cr, slots = carry
+                w = q.shape[1]
+                with jax.named_scope("grape.lcc.rows"):
+                    sel = jnp.logical_and(live, nfid_c == cur_fid)
+                    qv = (jnp.arange(w)[:, None]
+                          < cnt[sl][None, :])   # [W, C] valid query slots
+                    tgt = rot_ell[nlid_c]       # [C, D] (N+(u))
+                with jax.named_scope("grape.lcc.intersect"):
+                    # the chunk's edges along the minor axis
+                    hit = _members(q.T, tgt.T, qv, sel)
+                    c1 = hit.sum(axis=0, dtype=jnp.int32)
+
+                v_pid = my_fid * vp + sl  # local row pid
+                with jax.named_scope("grape.lcc.credit"):
+                    cr = cr.at[jnp.where(sel, v_pid, n_pad)].add(
+                        jnp.where(sel, c1, 0)
+                    )
+                    if lcc_mode:
+                        u_pid = cur_fid * vp + nlid_c
+                        cr = cr.at[jnp.where(sel, u_pid, n_pad)].add(
+                            jnp.where(sel, c1, 0)
+                        )
+                        # far-end credits: +1 at the slot of each matched
+                        # member
+                        slots = {**slots, w: _fold_rows(
+                            slots[w], sl, hit, runs[w])}
+                return cr, slots
+
+            return walk(carry, chunk_credit, "grape.lcc.rows")
 
         if fnum == 1:
-            cred = pass_for(cred, ell, jnp.int32(0))
+            cred, slots = pass_for((cred, slots), ell, jnp.int32(0))
         else:
             perm = [(i, (i - 1) % fnum) for i in range(fnum)]
 
             def ring_body(s, carry):
-                cr, r_ell = carry
+                credits, r_ell = carry
                 cur_fid = (my_fid + s) % fnum
-                cr = pass_for(cr, r_ell, cur_fid)
+                credits = pass_for(credits, r_ell, cur_fid)
                 with jax.named_scope("grape.lcc.ring"):
                     r_ell = lax.ppermute(r_ell, FRAG_AXIS, perm)
-                return cr, r_ell
+                return credits, r_ell
 
-            cred, _ = lax.fori_loop(0, fnum, ring_body, (cred, ell))
+            (cred, slots), _ = lax.fori_loop(
+                0, fnum, ring_body, ((cred, slots), ell))
+
+        if lcc_mode:
+            # the flush, once a query: the schedule once more without
+            # targets.  The scheduled edge (v, u) is the slot (v, j) with
+            # ell[v, j] == u, so each slot is read by exactly one edge
+            def flush(cr, sl, nfid_c, nlid_c, live, q):
+                with jax.named_scope("grape.lcc.credit"):
+                    u_pid = nfid_c * vp + nlid_c
+                    far = _slot_of(slots[q.shape[1]][sl], q, u_pid)
+                    return cr.at[jnp.where(live, u_pid, n_pad)].add(
+                        jnp.where(live, far, 0)
+                    )
+
+            cred = walk(cred, flush, "grape.lcc.credit")
 
         with jax.named_scope("grape.lcc.credit"):
             total = ctx.sum(cred[:n_pad])

@@ -14,6 +14,11 @@ under `.intersect`, and left the other runners alone.  On several fragments
 the target blocks ride a ring: `grape.lcc.ring` names its `ppermute`, and
 `LCC_STATS` counts its passes and bytes and the shards' schedules
 (`benchmarks/configs/g500-lcc-x4.json` is that deployment).
+
+The far-end credits go by adjacency slot (PR 33): a row of the `[vp, D]`
+table an edge inside the chunk loops, flushed by id in a walk of its own;
+the lowered text holds no element scatter of C x W updates under
+`grape.lcc.credit`, and `LCC_STATS` counts the rows and the flush.
 """
 
 import contextlib
@@ -296,14 +301,25 @@ def test_lcc_names_its_step(graph_cache, monkeypatch, fnum, tiers):
         assert scope in text, f"no {scope} in LCC's lowered runner"
 
 
+def locs_under(text: str, scope: str) -> dict:
+    """`#loc7` -> what its name stack holds behind `scope`."""
+    # `#loc7 = loc("grape.lcc.intersect/eq"(#loc3))`: the name stack, with
+    # the enclosing loops and the primitive as components behind the scope
+    return {ref: name.split(scope + "/", 1)[1] for ref, name in
+            re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M)
+            if scope + "/" in name}
+
+
+def functions(text: str) -> dict:
+    """Name -> text of each function of the lowered module but the first."""
+    return {chunk.split("(", 1)[0].split("@")[-1]: chunk
+            for chunk in text.split("\n  func.func ")[1:]}
+
+
 def steps_under(text: str, scope: str):
     """(JAX's names, StableHLO operations) of what the lowered `text`
     holds under `scope`, the functions called from there included."""
-    # `#loc7 = loc("grape.lcc.intersect/eq"(#loc3))`: the name stack, with
-    # the enclosing loops and the primitive as components behind the scope
-    locs = {ref: name.split(scope + "/", 1)[1] for ref, name in
-            re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M)
-            if scope + "/" in name}
+    locs = locs_under(text, scope)
     names = {part for name in locs.values() for part in name.split("/")}
     ops, calls = set(), set()
     for line in text.splitlines():
@@ -311,8 +327,7 @@ def steps_under(text: str, scope: str):
         if at and at.group(1) in locs:
             ops.update(re.findall(r"\bstablehlo\.\w+", line)[:1])
             calls.update(re.findall(r"\bcall @([\w.]+)", line))
-    bodies = {chunk.split("(", 1)[0].split("@")[-1]: chunk
-              for chunk in text.split("\n  func.func ")[1:]}
+    bodies = functions(text)
     seen = set()
     while calls - seen:
         callee = (calls - seen).pop()
@@ -336,6 +351,152 @@ def test_lcc_intersects_without_a_search(graph_cache, monkeypatch, fnum, tiers):
     for word in ("while", "gather", "sort", "scan", "search", "take_along",
                  "dynamic_slice"):
         assert not [n for n in names | ops if word in n], (word, names, ops)
+
+
+def ops_at(part: str, locs: dict) -> set:
+    """StableHLO operations of the lines of `part` located in `locs`."""
+    ops = set()
+    for line in part.splitlines():
+        at = re.search(r"loc\((#loc\d+)\)$", line)
+        if at and at.group(1) in locs:
+            ops.update(re.findall(r"\bstablehlo\.\w+", line)[:1])
+    return ops
+
+
+def loops(text: str):
+    """What each `stablehlo.while` of the lowered `text` runs: its regions
+    and every function called from them."""
+    bodies, lines = functions(text), text.splitlines()
+    for i, line in enumerate(lines):
+        if "stablehlo.while(" not in line:
+            continue
+        pad = line[:len(line) - len(line.lstrip())]
+        end = next(j for j in range(i + 1, len(lines))
+                   if lines[j].startswith(pad + "} loc("))
+        part, seen = "\n".join(lines[i:end]), set()
+        while (calls := set(re.findall(r"\bcall @([\w.]+)", part)) - seen):
+            seen |= calls
+            part += "".join(bodies[callee] for callee in calls)
+        yield part
+
+
+def scatters_under(text: str, scope: str):
+    """(shape of the updates, whether an update is a window and not a
+    scalar) of every `stablehlo.scatter` of the lowered `text` under `scope`."""
+    locs, lines, found = locs_under(text, scope), text.splitlines(), []
+    for i, line in enumerate(lines):
+        if '"stablehlo.scatter"' not in line:
+            continue
+        pad = line[:len(line) - len(line.lstrip())]
+        # `}) : (tensor<65536x14xi32>, tensor<4096x2xi32>, tensor<4096x8xi32>) -> ...`
+        end = next(ln for ln in lines[i + 1:] if ln.startswith(pad + "}) : ("))
+        if re.search(r"loc\((#loc\d+)\)$", end).group(1) in locs:
+            updates = re.findall(r"tensor<([\dx]+)xi\d+>", end.split("->")[0])[2]
+            window = re.search(r"update_window_dims = \[\d", line)
+            found.append((tuple(map(int, updates.split("x"))), bool(window)))
+    return found
+
+
+@pytest.mark.parametrize("tiers", ["2,8", "0"])
+@pytest.mark.parametrize("fnum", [1, 4])
+def test_lcc_credits_the_far_end_by_slot(graph_cache, monkeypatch, fnum, tiers):
+    """Under `grape.lcc.credit` a chunk writes C rows of width W into the
+    slot table and C elements into the credit table, never C x W elements
+    (8 ns each on the chip), so the element scatter cannot come back
+    unnoticed; the flush by id reads the table in a walk of its own, after
+    the chunk loops and after the ring."""
+    monkeypatch.setenv("GRAPE_LCC_TIERS", tiers)
+    app, frag = APP_REGISTRY["lcc"](), graph_cache(fnum)
+    text = lowered(app, frag, True)
+    d = LCC_STATS["d_max"]
+    walks = ({(c, w) for _, _, c, w in app._tier_info} if tiers != "0"
+             else {(min(4096, len(frag.host_oe[0].edge_src)), d)})
+    assert (len(walks) == 3) == (tiers != "0")
+    # a row of a tier holds more members than the tier below is wide, so a
+    # chunk of its entries holds few rows, summed before they are written
+    lows = dict(zip(sorted(w for _, w in walks), [0] + sorted(w for _, w in walks)))
+    folds = {(min(c, c // (lows[w] + 1) + 3), w) for c, w in walks}
+    assert (folds != walks) == (tiers != "0")
+    scatters = scatters_under(text, "grape.lcc.credit")
+    assert {shape for shape, window in scatters if window} == folds  # the row folds
+    for shape, window in scatters:
+        if not window:  # into the credit table: the edge's two ends, the flush
+            assert shape in {(c,) for c, _ in walks}, shape
+    assert not [s for s in scatters_under(text, "grape.lcc.rows")
+                + scatters_under(text, "grape.lcc.intersect")]
+    # the main walk scatters and gathers nothing under the scope; the flush
+    # gathers the table's rows, in loops that hold no intersection
+    credit = locs_under(text, "grape.lcc.credit")
+    meet = locs_under(text, "grape.lcc.intersect")
+    flushing = 0
+    for part in loops(text):
+        reads = "stablehlo.gather" in ops_at(part, credit)
+        assert not (reads and ops_at(part, meet)), "the flush is inside a chunk loop"
+        flushing += reads
+    assert flushing == len(walks)
+
+
+@pytest.mark.parametrize("fnum", [1, 4])
+def test_apex_mode_has_no_far_end_and_no_table(graph_cache, fnum):
+    text = lowered(ApexTriangleCount(), graph_cache(fnum), True)
+    scatters = scatters_under(text, "grape.lcc.credit")
+    assert scatters and not [s for s, window in scatters if window]
+    assert "stablehlo.gather" not in steps_under(text, "grape.lcc.credit")[1]
+
+
+@pytest.mark.parametrize("runs", [200, 56])
+@pytest.mark.parametrize("w", [1, 7, 64, 130])
+def test_slot_fold_and_flush_are_the_credits_by_id(w, runs):
+    """`_fold_rows` over two chunks (row by row, and with a chunk's runs
+    summed first where their number is bounded under the chunk), then
+    `_slot_of` at every real slot's edge: NumPy's `add.at` of the far-end
+    credits by id, as the parent scattered them."""
+    from libgrape_lite_tpu.models.lcc_beta import _fold_rows, _slot_of
+
+    rng = np.random.default_rng(w)
+    vp, c, n_pad = 50, 200, 1000
+    cnt = rng.integers(0, w + 1, size=vp)
+    cnt[:2] = 0, w
+    ids = np.sort(rng.random((vp, n_pad)).argsort(axis=1)[:, :w], axis=1)
+    ell = np.where(np.arange(w)[None, :] < cnt[:, None], ids, n_pad).astype(np.int32)
+    want = np.zeros(n_pad + 1, dtype=np.int64)
+    slot = jax.numpy.zeros((vp, w), dtype=np.int32)
+    for _ in range(2):
+        sl = np.sort(rng.integers(0, vp, size=c)).astype(np.int32)  # repeated rows
+        sl[-5:] = vp - 1  # as a schedule's padding
+        assert len(np.unique(sl)) <= min(runs, vp) < c
+        q = ell[sl]
+        hit = (rng.random((c, w)) < 0.3) & (q != n_pad)
+        np.add.at(want, np.where(hit, q, n_pad).reshape(-1), hit.reshape(-1))
+        slot = _fold_rows(slot, sl, hit.T, runs)
+    slot = np.asarray(slot)
+    assert slot.dtype == np.int32 and slot.sum() == want[:n_pad].sum() > 0
+    assert slot.max() > 1
+    # one edge (v, u) a real slot, in any order
+    ev, ej = np.nonzero(ell != n_pad)
+    order = rng.permutation(len(ev))
+    ev, eu = ev[order], ell[ev, ej][order]
+    far = np.asarray(_slot_of(slot[ev], ell[ev], eu))
+    got = np.zeros(n_pad + 1, dtype=np.int64)
+    np.add.at(got, eu, far)
+    assert np.array_equal(got, want) and want[n_pad] == 0
+
+
+@pytest.mark.parametrize("fnum,rows,flush", [(1, 49152, 49152), (2, 57344, 28672),
+                                             (4, 65536, 16384)])
+def test_credit_counters(kron, fnum, rows, flush):
+    """Row updates folded into the slot table a query a device (every ring
+    pass folds the whole padded schedule) and element updates of the flush
+    (the schedule once), from the geometry, at scale 12."""
+    frag = kron.load(12, fnum)
+    app = APP_REGISTRY["lcc"]()
+    app.init_state(frag)
+    stats = LCC_STATS.snapshot()
+    entries = sum(n * c for _, n, c, _ in app._tier_info)
+    assert stats["flush_updates"] == entries == flush
+    assert stats["credit_rows"] == max(stats["ring_passes"], 1) * entries == rows
+    assert stats["flush_updates"] >= stats["shard_kept_max"]
+    assert stats["credit_rows"] * app._tier_info[0][3] <= stats["query_lanes"]
 
 
 @pytest.mark.parametrize("tiers", ["2,8", "0"])
