@@ -28,6 +28,7 @@ tests (tests/test_serve.py, tests/test_dyn.py) pin on.
 from __future__ import annotations
 
 import re
+import time
 from contextlib import contextmanager
 from typing import Dict, List, Optional
 
@@ -44,6 +45,12 @@ _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # exists to catch hides behind the disk cache
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _COMPILE_EVENTS = (_BACKEND_COMPILE_EVENT, _CACHE_HIT_EVENT)
+# what a runner costs the host before the backend sees it, and what the
+# persistent cache answers: the `runner.compile` set-up phase's args
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
 
 class CompileEvents:
@@ -55,6 +62,42 @@ class CompileEvents:
 
     def __init__(self):
         self.events: List[tuple] = []
+        self.arrived: List[float] = []  # perf_counter at each event
+
+    def _covered_seconds(self, event: str) -> float:
+        """Seconds the intervals of `event` cover.  A duration arrives
+        when its interval ends, and a jit traced inside another reports
+        its own trace inside the outer one's, so the intervals are
+        merged, not summed."""
+        spans = sorted(
+            (end - dur, end)
+            for (name, dur), end in zip(self.events, self.arrived)
+            if name == event
+        )
+        covered, reach = 0.0, float("-inf")
+        for start, end in spans:
+            if end > reach:
+                covered += end - max(start, reach)
+                reach = end
+        return covered
+
+    def phase_seconds(self) -> Dict[str, float]:
+        """What the compile requests inside the block cost, by phase:
+        tracing and lowering (host work the executable cache does not
+        spare), the backend (a compile, or on a hit the fetch), the
+        persistent cache's retrieval, and its hits and misses."""
+        names = [name for name, _ in self.events]
+        return {
+            "trace_s": self._covered_seconds(_TRACE_EVENT),
+            "lower_s": self._covered_seconds(_LOWER_EVENT),
+            "backend_s": self.compile_seconds(),
+            "cache_retrieval_s": sum(
+                dur for name, dur in self.events
+                if name == _CACHE_RETRIEVAL_EVENT
+            ),
+            "cache_hits": names.count(_CACHE_HIT_EVENT),
+            "cache_misses": names.count(_CACHE_MISS_EVENT),
+        }
 
     @property
     def compiles(self) -> int:
@@ -91,10 +134,12 @@ def compile_events():
 
     def _listen(event, duration, **kw):
         rec.events.append((event, duration))
+        rec.arrived.append(time.perf_counter())
 
     def _listen_plain(event, **kw):
         # record_event stream (no duration): persistent-cache hits
         rec.events.append((event, 0.0))
+        rec.arrived.append(time.perf_counter())
 
     monitoring.register_event_duration_secs_listener(_listen)
     monitoring.register_event_listener(_listen_plain)

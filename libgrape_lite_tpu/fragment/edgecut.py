@@ -86,17 +86,22 @@ def boundary_split(frag, directions=("ie",)) -> np.ndarray:
     key = tuple(sorted(directions))
     if key in per_frag:
         return per_frag[key]
+    from libgrape_lite_tpu import obs
+
     fnum, vp = frag.fnum, frag.vp
-    read = np.zeros((fnum, vp), dtype=bool)
-    for d in key:
-        csrs = frag.host_ie if d == "ie" else frag.host_oe
-        for g in range(fnum):
-            h = csrs[g]
-            nbr = h.edge_nbr[h.edge_mask].astype(np.int64)
-            owner = nbr // vp
-            remote = owner != g
-            read[owner[remote], nbr[remote] % vp] = True
-    bmask = np.logical_and(read, frag.host_inner_mask())
+    # the miss: once per fragment and direction set, a set-up phase
+    with obs.tracer().span("derived.boundary_split", fnum=fnum,
+                           directions="+".join(key)):
+        read = np.zeros((fnum, vp), dtype=bool)
+        for d in key:
+            csrs = frag.host_ie if d == "ie" else frag.host_oe
+            for g in range(fnum):
+                h = csrs[g]
+                nbr = h.edge_nbr[h.edge_mask].astype(np.int64)
+                owner = nbr // vp
+                remote = owner != g
+                read[owner[remote], nbr[remote] % vp] = True
+        bmask = np.logical_and(read, frag.host_inner_mask())
     per_frag[key] = bmask
     return bmask
 
@@ -526,6 +531,24 @@ class ShardedEdgecutFragment:
 
     @staticmethod
     def _device_put(
+        comm_spec, vertex_map, host_oe, host_ie, vp, directed, total_vnum,
+        total_enum,
+    ) -> DeviceFragment:
+        """Stack the host CSRs and place them: set-up phase
+        `load.place`, which ends when the arrays are on the devices
+        (its record's `bytes_in_use` is what the graph holds)."""
+        from libgrape_lite_tpu import obs
+
+        with obs.tracer().span("load.place", fnum=comm_spec.fnum):
+            return jax.block_until_ready(
+                ShardedEdgecutFragment._stack_and_put(
+                    comm_spec, vertex_map, host_oe, host_ie, vp,
+                    directed, total_vnum, total_enum,
+                )
+            )
+
+    @staticmethod
+    def _stack_and_put(
         comm_spec, vertex_map, host_oe, host_ie, vp, directed, total_vnum,
         total_enum,
     ) -> DeviceFragment:

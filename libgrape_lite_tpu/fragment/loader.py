@@ -153,10 +153,13 @@ def LoadGraph(
 ) -> ShardedEdgecutFragment:
     """Entry point, mirroring `LoadGraph<FRAG_T>` (`loader.h:42-53`).
 
-    With obs/ armed, the load emits a `load_graph` span with
-    `read_edges` / `partition` / `build_fragment` / `deserialize` /
-    `serialize` children — load skew shows up on the same timeline as
-    the query it delays."""
+    The load is a `load_graph` span with `read_edges` / `partition` /
+    `build_fragment` / `deserialize` / `serialize` children, and under
+    `build_fragment` or `deserialize` the placement `load.place`
+    (`ShardedEdgecutFragment._device_put`).  All are set-up phases
+    (obs/tracer.py `SETUP_PHASES`): kept in the `setup` ledger armed or
+    not, and with obs/ armed on the same timeline as the query the
+    load delays."""
     from libgrape_lite_tpu import obs
 
     spec = _fold_rebalance_env(spec or LoadGraphSpec())
@@ -243,11 +246,6 @@ def LoadGraph(
         if spec.serialize and cache:
             with tr.span("serialize", cache=cache):
                 _serialize_fragment(frag, cache, sig)
-        if tr.enabled:
-            obs.metrics().gauge("grape_graph_edges").set(int(len(src)))
-            obs.metrics().gauge("grape_graph_vertices").set(
-                int(len(oids))
-            )
         return _validate_load(frag)
 
 

@@ -43,12 +43,16 @@ def _load() -> ctypes.CDLL | None:
         if stale:
             # a stale .so silently loses every symbol group added since
             # it was built (make is incremental, so this is cheap)
+            from libgrape_lite_tpu import obs
+
             try:
-                subprocess.run(
-                    ["make", "-C", _NATIVE_DIR],
-                    check=True, capture_output=True, text=True,
-                    timeout=120,
-                )
+                # set-up phase: g++ on loader.cc, first time in a checkout
+                with obs.tracer().span("native.build"):
+                    subprocess.run(
+                        ["make", "-C", _NATIVE_DIR],
+                        check=True, capture_output=True, text=True,
+                        timeout=120,
+                    )
             except (OSError, subprocess.SubprocessError) as e:
                 why = f"({e})\n{getattr(e, 'stderr', None) or ''}"
                 if not os.path.exists(_SO_PATH):

@@ -214,12 +214,22 @@ class LCCBeta(ParallelAppBase):
         if key in per_frag:
             LCC_STATS["cache_hits"] += 1
         else:
+            from libgrape_lite_tpu import obs
             from libgrape_lite_tpu.parallel.comm_spec import put_global
 
-            adj = self._build_adjacency(frag, key[2])
-            shard = frag.comm_spec.sharded()
-            for k in ("ell", "cnt", "eperm"):
-                adj[k] = put_global(adj[k], shard)
+            # the miss: once per fragment and key, a set-up phase; the
+            # host build is what `derived.place` leaves of it
+            tr = obs.tracer()
+            with tr.span("derived.lcc_adjacency", fnum=frag.fnum) as sp:
+                adj = self._build_adjacency(frag, key[2])
+                sp.set(d_max=adj["geometry"]["d_max"],
+                       ell_bytes=adj["geometry"]["ell_bytes"])
+                shard = frag.comm_spec.sharded()
+                with tr.span("derived.place", what="lcc_adjacency"):
+                    adj.update(jax.block_until_ready({
+                        k: put_global(adj[k], shard)
+                        for k in ("ell", "cnt", "eperm")
+                    }))
             per_frag[key] = adj
             LCC_STATS["builds"] += 1
         # the geometry the counter shows is the last query's

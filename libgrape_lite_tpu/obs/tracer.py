@@ -47,7 +47,15 @@ the disarmed span, so the disarmed tracer asks the profiler first
 (`TraceAnnotation.is_enabled()`, 20 ns) and makes none when nothing
 records; an annotation opened outside a session is dropped by the
 profiler anyway.  The JSONL sink keeps what no profiler window covers
-(set-up, operators' long runs).
+(operators' long runs).
+
+Set-up phases: a span whose name is in `SETUP_PHASES` is what a process
+pays once (`LoadGraph` and its stages, a per-fragment structure built
+on a cache miss, a runner that compiles, the native loader's build) and
+is kept armed or not: on close it also appends one record to
+`SETUP_LEDGER`, the federated namespace `setup`.  No name of that
+vocabulary is ever opened by a warm query, so the disarmed `span()`
+pays one set-membership test for it and nothing else.
 """
 
 from __future__ import annotations
@@ -61,6 +69,7 @@ from typing import Any, Dict, Optional
 
 from jax.profiler import TraceAnnotation
 
+from libgrape_lite_tpu.obs import federation
 from libgrape_lite_tpu.obs.events import (
     FRAG_TID_BASE,
     counter_event,
@@ -69,10 +78,99 @@ from libgrape_lite_tpu.obs.events import (
     metadata_event,
     span_event,
 )
+from libgrape_lite_tpu.utils.memory import fullest_bytes_in_use
 
 
 MIRROR_PREFIX = "grape."
 _profiling = TraceAnnotation.is_enabled  # is a profiler session recording?
+
+#: what a process pays once.  A span under one of these names is a
+#: set-up phase: recorded in `SETUP_LEDGER` whether or not the tracer
+#: is armed.  The rule that keeps the ledger off the hot path: a name
+#: of this set opens only in `LoadGraph`, on a cache MISS of a
+#: per-fragment structure, on a runner MISS, in the native loader's
+#: build and where the compile cache is placed, never in a warm query
+#: (docs/OBSERVABILITY.md has the table: site, args, bytes).
+SETUP_PHASES = frozenset({
+    "load_graph", "read_edges", "partition", "build_fragment",
+    "deserialize", "serialize", "load.place",
+    "native.build", "compile_cache", "runner.compile",
+    "derived.mirror_plan", "derived.boundary_split",
+    "derived.lcc_adjacency", "derived.place",
+})
+#: the phases that place arrays or load an executable: their records
+#: carry `bytes_in_use` of the fullest local device at open and at
+#: close.  All of them run with the backend up (reading the allocator
+#: would start it otherwise).
+SETUP_PLACING = frozenset({"load.place", "derived.place", "runner.compile"})
+SETUP_LEDGER_CAP = 256
+
+
+class SetupLedger:
+    """The bounded record of the set-up phases this process closed, in
+    closing order (a child before its parent).  One record: `name`,
+    `parent` (the enclosing phase on the same thread, or None), `t0_ns`
+    and `dur_ns` on `time.perf_counter_ns`, the span's `args`, and for
+    a placing phase `bytes_in_use` `{"open", "close"}` (absent on a
+    backend without allocator statistics, never 0 for unknown).  Past
+    `cap` records nothing is kept and `dropped` counts."""
+
+    def __init__(self, cap: int = SETUP_LEDGER_CAP):
+        self.cap = cap
+        self._records: list = []
+        self.dropped = 0
+        self._lock = threading.Lock()
+
+    def append(self, record: Dict[str, Any]) -> None:
+        with self._lock:
+            if len(self._records) < self.cap:
+                self._records.append(record)
+            else:
+                self.dropped += 1
+
+    def __len__(self) -> int:
+        return len(self._records)
+
+    def snapshot(self) -> Dict[str, Any]:
+        """`records` for /federation and the postmortem bundle;
+        `count`, `dropped`, `cap` and `seconds` (total by phase name)
+        are what /metrics can show."""
+        with self._lock:
+            records = [dict(r) for r in self._records]
+            dropped = self.dropped
+        seconds: Dict[str, float] = {}
+        for r in records:
+            seconds[r["name"]] = (
+                seconds.get(r["name"], 0.0) + r["dur_ns"] / 1e9
+            )
+        return {"records": records, "count": len(records),
+                "dropped": dropped, "cap": self.cap, "seconds": seconds}
+
+    def reset(self) -> None:
+        with self._lock:
+            self._records = []
+            self.dropped = 0
+
+
+SETUP_LEDGER = SetupLedger()
+federation.register("setup", SETUP_LEDGER.snapshot, SETUP_LEDGER.reset,
+                    module=__name__)
+_open_phases = threading.local()
+
+
+def _phase_stack() -> list:
+    """This thread's open set-up phases, outermost first."""
+    try:
+        return _open_phases.stack
+    except AttributeError:
+        _open_phases.stack = []
+        return _open_phases.stack
+
+
+def _plain(v):
+    """A span argument as the ledger keeps it: JSON scalars as they
+    are, anything else by its `str`."""
+    return v if isinstance(v, (str, int, float, bool, type(None))) else str(v)
 
 
 class _NullSpan:
@@ -172,6 +270,43 @@ class Span:
             if last_label == "dispatched":
                 self.args["device_wait_us"] = round((end - last_t) / 1000.0, 3)
         self._tracer._emit_span(self)
+
+
+class PhaseSpan(Span):
+    """A span of the set-up vocabulary: a `Span` (both sinks when
+    armed, the profiler mirror either way) that also leaves its record
+    in `SETUP_LEDGER` when it closes."""
+
+    __slots__ = ("parent", "_bytes_open")
+
+    def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
+        stack = _phase_stack()
+        self.parent = stack[-1].name if stack else None
+        stack.append(self)
+        self._bytes_open = (
+            fullest_bytes_in_use() if name in SETUP_PLACING else None
+        )
+        super().__init__(
+            tracer, name, tracer._tid() if tracer.enabled else 0, args
+        )
+
+    def close(self) -> None:
+        super().close()
+        stack = _phase_stack()
+        if self in stack:
+            stack.remove(self)
+        record = {
+            "name": self.name, "parent": self.parent,
+            "t0_ns": self.t0_ns, "dur_ns": self.dur_ns,
+            "args": {k: _plain(v) for k, v in self.args.items()},
+        }
+        if self._bytes_open is not None:
+            closed = fullest_bytes_in_use()
+            if closed is not None:
+                record["bytes_in_use"] = {
+                    "open": self._bytes_open, "close": closed,
+                }
+        SETUP_LEDGER.append(record)
 
 
 class Tracer:
@@ -308,6 +443,8 @@ class Tracer:
         self._buf.append(ev)
 
     def span(self, name: str, **args):
+        if name in SETUP_PHASES:
+            return PhaseSpan(self, name, args)
         if not self.enabled:
             if not _profiling():
                 return _NULL_SPAN
@@ -315,6 +452,8 @@ class Tracer:
         return Span(self, name, self._tid(), args)
 
     def _emit_span(self, span: Span) -> None:
+        if not self.enabled:
+            return  # a disarmed set-up phase: the ledger alone keeps it
         self._push(span_event(
             span.name, ts_ns=span.t0_ns, dur_ns=span.dur_ns,
             pid=self.pid, tid=span.tid,

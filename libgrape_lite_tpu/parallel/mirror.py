@@ -183,7 +183,20 @@ def build_mirror_plan(frag, direction: str = "ie") -> MirrorPlan | None:
     per_frag = _FRAG_MIRROR_CACHE.setdefault(frag, {})
     if direction in per_frag:
         return per_frag[direction]
+    from libgrape_lite_tpu import obs
 
+    # the miss: once per fragment and direction, a set-up phase
+    with obs.tracer().span("derived.mirror_plan", fnum=frag.fnum,
+                           direction=direction) as sp:
+        plan = _plan_mirrors(frag, direction)
+        sp.set(m=plan.m)
+    per_frag[direction] = plan
+    return plan
+
+
+def _plan_mirrors(frag, direction: str) -> MirrorPlan:
+    """The host planner behind `build_mirror_plan`: request lists per
+    (receiver, sender), the send indices and the compact columns."""
     fnum, vp = frag.fnum, frag.vp
     csrs = frag.host_ie if direction == "ie" else frag.host_oe
 
@@ -230,9 +243,7 @@ def build_mirror_plan(frag, direction: str = "ie") -> MirrorPlan | None:
             out[sel] = vp + g * m + pos
         nbr_compact[f] = np.where(h.edge_mask, out, 0).astype(np.int32)
 
-    plan = MirrorPlan(
+    return MirrorPlan(
         fnum=fnum, vp=vp, m=m, n_compact=vp + fnum * m,
         send_idx=send_idx, nbr_compact=nbr_compact,
     )
-    per_frag[direction] = plan
-    return plan

@@ -25,11 +25,17 @@ def place_compile_cache() -> str:
     placed the cache owns its policy — nothing is set in code.  Unset:
     `<checkout>/.jax_cache`, with JAX's 1 s compile-time floor dropped
     to 0 so the sub-second fused runners are kept too."""
-    env = os.environ.get(CACHE_ENV)
-    if env:
-        return env
-    import jax
+    from libgrape_lite_tpu import obs
 
-    jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    return DEFAULT_CACHE_DIR
+    env = os.environ.get(CACHE_ENV)
+    where = env or DEFAULT_CACHE_DIR
+    # one set-up record: where the cache is and whether the floor was set
+    with obs.tracer().span("compile_cache", dir=where, floor_set=not env):
+        if not env:
+            import jax
+
+            jax.config.update("jax_compilation_cache_dir", where)
+            jax.config.update(
+                "jax_persistent_cache_min_compile_time_secs", 0.0
+            )
+    return where

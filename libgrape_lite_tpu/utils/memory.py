@@ -52,3 +52,16 @@ def get_memory_stats(device=None) -> MemoryStats:
             in_use += ms.get("bytes_in_use", 0)
             peak += ms.get("peak_bytes_in_use", 0)
     return MemoryStats(in_use, peak, get_host_rss())
+
+
+def fullest_bytes_in_use() -> int | None:
+    """`bytes_in_use` of the fullest local device, as `hbm_peak_bytes`
+    takes its peak; None on a backend without allocator statistics
+    (the CPU), where 0 would read as "nothing resident"."""
+    in_use = [
+        (d.memory_stats() or {}).get("bytes_in_use")
+        for d in jax.local_devices()
+    ]
+    if not in_use or any(b is None for b in in_use):
+        return None
+    return max(in_use)

@@ -4,6 +4,8 @@ Re-design of `examples/analytical_apps/timer.h:43-75`: a stack of named
 phases, printed by the coordinator (process index 0).  JAX devices are
 asynchronous, so `timer_end` blocks on outstanding device work before
 reading the clock (the analogue of the reference's implicit MPI barrier).
+The printed lines are the operator's output; the spans of the same
+intervals are obs/'s (`load_graph`, `query`), opened where the work is.
 """
 
 from __future__ import annotations
@@ -13,7 +15,7 @@ from typing import List, Tuple
 
 import jax
 
-_stack: List[Tuple[str, float, object]] = []
+_stack: List[Tuple[str, float]] = []
 _is_coordinator = True
 
 
@@ -24,19 +26,12 @@ def set_coordinator(flag: bool) -> None:
 
 def timer_start(name: str) -> None:
     jax.effects_barrier()
-    # phases double as trace spans when obs/ is armed, so the driver's
-    # load/run/output breakdown lands on the same timeline as the
-    # worker's superstep spans (span() is a no-op when disarmed)
-    from libgrape_lite_tpu import obs
-
-    span = obs.tracer().span(name)
-    _stack.append((name, time.perf_counter(), span))
+    _stack.append((name, time.perf_counter()))
 
 
 def timer_end() -> float:
     jax.effects_barrier()
-    name, t0, span = _stack.pop()
-    span.close()
+    name, t0 = _stack.pop()
     dt = time.perf_counter() - t0
     if _is_coordinator:
         print(f"[timer] {name}: {dt:.6f} s")

@@ -711,6 +711,24 @@ class Worker:
         eph_part = {k: v for k, v in state.items() if k in eph}
         return runner, carry, eph_part, eph
 
+    def _enqueue(self, runner, mode: str, batch: int, *operands):
+        """The runner's dispatch.  On a runner miss the call traces,
+        lowers and compiles (or fetches) before it enqueues: that one
+        is set-up phase `runner.compile`, with what JAX's monitoring
+        events give for it as args (the listener lives for this call
+        only); a hit opens nothing."""
+        if not self._last_runner_miss:
+            return runner(*operands)
+        from libgrape_lite_tpu.analysis.artifact import compile_events
+
+        with obs.tracer().span(
+            "runner.compile", app=type(self.app).__name__, mode=mode,
+            batch=batch,
+        ) as sp, compile_events() as ev:
+            out = runner(*operands)
+            sp.set(**ev.phase_seconds())
+        return out
+
     # ---- batched multi-source execution (serve/) -------------------------
 
     def _check_batchable(self):
@@ -1039,8 +1057,9 @@ class Worker:
                     lambda st: self._batched_runner_for(mr, batch, st),
                 )
                 with tr.span("worker.enqueue"):
-                    out_state, rounds_v, active_v = runner(
-                        frag.dev, carry, eph_part
+                    out_state, rounds_v, active_v = self._enqueue(
+                        runner, "batched", batch,
+                        frag.dev, carry, eph_part,
                     )
                 t_enq = _time.perf_counter_ns()
                 sp.mark("dispatched")
@@ -1292,8 +1311,8 @@ class Worker:
                     # trace_report derives overlap_hidden_us from it
                     sp.set(pipeline=self._pipelined().span_brief())
                 with tr.span("worker.enqueue"):
-                    out_state, rounds, active = runner(
-                        frag.dev, carry, eph_part
+                    out_state, rounds, active = self._enqueue(
+                        runner, "fused", 1, frag.dev, carry, eph_part,
                     )
                 t_enq = _time.perf_counter_ns()
                 if self._last_runner_miss:
