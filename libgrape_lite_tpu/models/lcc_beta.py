@@ -67,6 +67,7 @@ LCC_STATS = _FedStats("lcc", {
     "ring_passes": 0, "ring_bytes": 0, "shard_lanes": 0,
     "shard_kept_max": 0, "shard_kept_min": 0,
     "credit_rows": 0, "flush_updates": 0,
+    "step_kept_max": 0, "step_kept_min": 0, "fold_runs_max": 0,
 })
 
 # fragment -> {(orientation, degree_threshold, tier request): adjacency};
@@ -80,9 +81,14 @@ def _chunk_rows(width: int) -> int:
     return max(128, min(4096, (1 << 22) // max(width, 1)))
 
 
+def _untiered_chunk(ep: int, d: int) -> int:
+    """Edges a chunk of the untiered pass over `ep` oe entries takes."""
+    return min(_chunk_rows(d), ep)
+
+
 def _untiered_entries(ep: int, d: int) -> int:
     """Padded schedule entries of the untiered pass over `ep` oe entries."""
-    c_e = min(_chunk_rows(d), ep)
+    c_e = _untiered_chunk(ep, d)
     return max(1, -(-ep // c_e)) * c_e
 
 
@@ -107,12 +113,13 @@ def _fold_rows(slot, sl, hit_t, runs):
     by id is C x W element updates (8 ns each on the chip; a whole row
     costs what two to seven elements do: PERF.md, PR 33).  `sl` [C]
     ascending, rows may repeat; `hit_t` [W, C] bool; `runs` a static
-    bound on the distinct rows of a chunk.  Where it is under C the runs
-    are summed first, by a one-hot `[runs, C] x [C, W]` product (0/1 in
-    bf16, f32 sums: exact, a run is at most C long), and one row a run
-    is written; a pad run adds 0 to the last row."""
+    bound on the distinct rows of a chunk.  Where it is at most half of C
+    the runs are summed first, by a one-hot `[runs, C] x [C, W]` product
+    (0/1 in bf16, f32 sums: exact, a run is at most C long), and one row
+    a run is written; a pad run adds 0 to the last row.  At half of C the
+    product costs the chip what the rows it spares do (PERF.md, PR 35)."""
     upd = hit_t.T
-    if runs < hit_t.shape[1]:
+    if 2 * runs <= hit_t.shape[1]:
         opens = jnp.concatenate([jnp.ones((1,), bool), sl[1:] != sl[:-1]])
         run = jnp.cumsum(opens, dtype=jnp.int32) - 1
         of_run = run[None, :] == jnp.arange(runs, dtype=jnp.int32)[:, None]
@@ -254,6 +261,9 @@ class LCCBeta(ParallelAppBase):
         cnts = np.zeros((fnum, vp), dtype=np.int32)
         # the oe entries the orientation rule keeps, for the schedule
         kept = np.zeros((fnum, len(frag.host_oe[0].edge_src)), dtype=bool)
+        # the ring step at which an entry's target block is on its device:
+        # step s of device f holds the block of fragment (f + s) % fnum
+        steps = np.zeros(kept.shape, dtype=np.int32)
         d_max = 1
         for f in range(fnum):
             c = frag.host_oe[f]
@@ -272,6 +282,7 @@ class LCCBeta(ParallelAppBase):
             if self.degree_threshold > 0:
                 keep &= deg[v] <= self.degree_threshold
             kept[f, :e] = keep
+            steps[f, :e] = (u // vp - f) % fnum
             # distinct kept (v, u) in (v, u) order: one packed key (v,
             # u < n_pad + 1, so the key stays far inside int64)
             packed = np.unique((v * (n_pad + 1) + u)[keep])
@@ -309,25 +320,33 @@ class LCCBeta(ParallelAppBase):
             stacked[f, lid_s, col] = u_s  # ascending per row (lexsort)
 
         eperm, tier_info = self._build_tier_perm(
-            frag, cnts, d_max, tier_request, kept
+            frag, cnts, d_max, tier_request, kept, steps
         )
         ep = len(frag.host_oe[0].edge_src)
-        # the padded entries and lanes of one device's schedule, walked once
+        # the padded entries and lanes a device walks a pass (a tiered
+        # pass walks its ring step's segments only), the entries of its
+        # whole schedule, and the widest tier's fold bound
         if tier_info:
-            shard_entries = sum(n * c for _, n, c, _ in tier_info)
-            shard_lanes = sum(n * c * w for _, n, c, w in tier_info)
+            pass_entries = sum(n * c for _, n, c, _, _ in tier_info)
+            shard_lanes = sum(n * c * w for _, n, c, w, _ in tier_info)
+            shard_entries = fnum * pass_entries
+            fold_runs = tier_info[-1][4]
         else:
-            shard_entries = _untiered_entries(ep, d_max)
+            pass_entries = shard_entries = _untiered_entries(ep, d_max)
             shard_lanes = shard_entries * d_max
+            fold_runs = _untiered_chunk(ep, d_max)
         # the ring sends its block once a pass, the last one included
         ring_passes = fnum if fnum > 1 else 0
         kept_per_shard = kept.sum(axis=1)
+        kept_per_step = np.stack([
+            np.bincount(steps[f][kept[f]], minlength=fnum)
+            for f in range(fnum)
+        ])
         geometry = {
             "d_max": d_max,
             "ell_bytes": int(stacked.nbytes),
             "oriented_edges": int(sum(len(r[0]) for r in rows_per_frag)),
-            # the padded lanes one device's step runs: every ring pass
-            # walks the whole schedule
+            # the padded lanes one device's step runs, all passes
             "query_lanes": max(ring_passes, 1) * shard_lanes,
             "tiers": len(tier_info) if tier_info else 1,
             "ring_passes": ring_passes,
@@ -337,11 +356,16 @@ class LCCBeta(ParallelAppBase):
             # oe entries the orientation keeps, fullest and emptiest shard
             "shard_kept_max": int(kept_per_shard.max()),
             "shard_kept_min": int(kept_per_shard.min()),
-            # the far-end credits ("lcc" mode): an entry folds one row
-            # into the slot table a pass, and the flush walks the
+            # the same by (shard, ring step): the imbalance that the
+            # schedule's uniform step segments pad away
+            "step_kept_max": int(kept_per_step.max()),
+            "step_kept_min": int(kept_per_step.min()),
+            # the far-end credits ("lcc" mode): an entry of a pass folds
+            # one row into the slot table, and the flush walks the whole
             # schedule once more, one element update an entry
-            "credit_rows": max(ring_passes, 1) * shard_entries,
+            "credit_rows": max(ring_passes, 1) * pass_entries,
             "flush_updates": shard_entries,
+            "fold_runs_max": fold_runs,
         }
         return {"ell": stacked, "cnt": cnts, "eperm": eperm,
                 "tier_info": tier_info, "geometry": geometry}
@@ -375,7 +399,7 @@ class LCCBeta(ParallelAppBase):
                 )
         return tuple(self._TIER_WIDTHS)
 
-    def _build_tier_perm(self, frag, cnts, d_max, req, kept):
+    def _build_tier_perm(self, frag, cnts, d_max, req, kept, steps):
         """Tiered edge schedule (r5): the query side of the intersection
         costs W_query x D compares per edge (and a scattered credit per
         query lane), but the average oriented out-degree is far below D
@@ -390,13 +414,25 @@ class LCCBeta(ParallelAppBase):
         `ell[:, :W_t]` with zero semantic change (the sliced-off lanes
         were invalid by qvalid anyway).
 
-        Returns (eperm, tier_info): eperm [fnum, L] int32 — per-tier
-        segments of oe-edge indices, sentinel Ep in the padding slots —
-        and tier_info [(offset, n_chunks, chunk, W)] with segment
-        geometry uniform across shards (max over shards, padded to the
-        tier's chunk size), as shard_map needs one static program;
-        (None, None) where `req` (`_tier_request`) asks for no tiering
-        or leaves nothing to tier."""
+        A tier is cut once more by ring step (`steps` [fnum, Ep]: the
+        one pass at which the entry's target block is on the device), so
+        that a pass walks its own entries and not the tier masked down
+        to them: `fnum` step segments side by side, each in `oe` order
+        (a chunk's source rows ascend) and each padded to the same whole
+        number of chunks.  One fragment has one step.
+
+        Returns (eperm, tier_info): eperm [fnum, L] int32 — the
+        segments' oe-edge indices, sentinel Ep in the padding slots —
+        and tier_info [(offset, n_chunks, chunk, W, runs)]: the tier
+        starts at `offset`, step s of it at `offset + s * n_chunks *
+        chunk`, `n_chunks` chunks a step; `runs` is the most distinct
+        source rows one chunk holds, its pad run included (what
+        `_fold_rows` may sum first: counted here, since how a row's
+        entries fall over the steps is the graph's).  The geometry is
+        uniform across shards and steps (the fullest segment's, padded
+        to the tier's chunk size), as shard_map needs one static
+        program; (None, None) where `req` (`_tier_request`) asks for no
+        tiering or leaves nothing to tier."""
         if req is None:
             return None, None
         widths = [w for w in req if 0 < w < d_max]
@@ -407,15 +443,16 @@ class LCCBeta(ParallelAppBase):
         fnum, vp = frag.fnum, frag.vp
         ep = len(frag.host_oe[0].edge_src)
         bounds = np.asarray(widths, dtype=np.int64)
-        per_shard = []  # [fnum][tier] -> edge index arrays
+        srcs = [np.asarray(frag.host_oe[f].edge_src) for f in range(fnum)]
+        per_shard = []  # [fnum][tier][step] -> edge index arrays
         for f in range(fnum):
-            src = np.asarray(frag.host_oe[f].edge_src, dtype=np.int64)
             c = np.append(cnts[f], 0)  # pad rows (src == vp) -> cnt 0
-            tier = np.searchsorted(bounds, c[np.minimum(src, vp)],
+            tier = np.searchsorted(bounds, c[np.minimum(srcs[f], vp)],
                                    side="left")
+            bucket = np.where(kept[f], tier * fnum + steps[f], -1)
             per_shard.append(
-                [np.flatnonzero((tier == t) & kept[f]).astype(np.int32)
-                 for t in range(len(widths))]
+                [[np.flatnonzero(bucket == t * fnum + s).astype(np.int32)
+                  for s in range(fnum)] for t in range(len(widths))]
             )
 
         info = []
@@ -423,15 +460,21 @@ class LCCBeta(ParallelAppBase):
         offset = 0
         for t, w in enumerate(widths):
             c_t = _chunk_rows(w)
-            n_t = max(len(per_shard[f][t]) for f in range(fnum))
+            n_t = max(len(idx) for shard in per_shard for idx in shard[t])
             n_t = -(-max(n_t, 1) // c_t) * c_t  # pad to chunk multiple
+            runs = 1
             for f in range(fnum):
-                seg = np.full(n_t, ep, dtype=np.int32)  # Ep = sentinel
-                idx = per_shard[f][t]
-                seg[: len(idx)] = idx
-                segs[f].append(seg)
-            info.append((offset, n_t // c_t, c_t, w))
-            offset += n_t
+                seg = np.full((fnum, n_t), ep, dtype=np.int32)  # Ep = sentinel
+                for s, idx in enumerate(per_shard[f][t]):
+                    seg[s, : len(idx)] = idx
+                segs[f].append(seg.reshape(-1))
+                # the chunks' source rows as the step reads them
+                sl = np.minimum(srcs[f][np.minimum(seg, ep - 1)], vp - 1)
+                sl = sl.reshape(-1, c_t)
+                opens = (sl[:, 1:] != sl[:, :-1]).sum(axis=1)
+                runs = max(runs, 1 + int(opens.max()))
+            info.append((offset, n_t // c_t, c_t, w, runs))
+            offset += fnum * n_t
         return np.stack([np.concatenate(s) for s in segs]), info
 
     def _oriented_edge_mask(self, ctx, frag):
@@ -476,7 +519,7 @@ class LCCBeta(ParallelAppBase):
         oe = frag.oe
 
         ep = oe.edge_src.shape[0]
-        c_e = min(_chunk_rows(d), ep)
+        c_e = _untiered_chunk(ep, d)
         n_chunks = max(1, -(-ep // c_e))
         # the degree gather, the oriented mask and the neighbour ids
         # split into (fragment, row): E-wide, once a query
@@ -497,13 +540,9 @@ class LCCBeta(ParallelAppBase):
             # per-tier query tables: static slices of the local ELL
             # (queries always come from LOCAL rows; only the target
             # side rides the ring at full width)
-            tier_ells = [ell[:, :w] for (_, _, _, w) in tier_info]
-            # a row of tier t holds more than W_(t-1) members, each an
-            # entry of the schedule, consecutive: a chunk cuts two runs
-            # and its padding is one more
-            lows = [0] + [w for (_, _, _, w) in tier_info[:-1]]
-            runs = {w: min(c_t, c_t // (lo + 1) + 3)
-                    for (_, _, c_t, w), lo in zip(tier_info, lows)}
+            tier_ells = [ell[:, :w] for (_, _, _, w, _) in tier_info]
+            # counted on the host with the schedule
+            runs = {w: r for (_, _, _, w, r) in tier_info}
         # far-end credits by adjacency slot, a table a walk width (a
         # partial row costs the chip a loop over the rows: PERF.md,
         # PR 33): slots[W][v, j] belongs to the id ell[v, j].
@@ -511,8 +550,10 @@ class LCCBeta(ParallelAppBase):
         slots = ({w: jnp.zeros((vp, w), dtype=jnp.int32) for w in runs}
                  if lcc_mode else None)
 
-        def walk(carry, visit, scope):
-            """Every chunk of the device's schedule, in order, through
+        def walk(carry, visit, scope, step=None):
+            """Every chunk of the device's schedule, in order (of ring
+            step `step`'s segments only, where one is given and the
+            schedule is tiered: the untiered walk has no segments), through
             `visit(carry, sl, nfid, nlid, live, q)`: the chunk's local
             source rows `sl` (ascending: the schedule is in `oe` order and
             `oe` is a CSR by source), its neighbours as (fragment, row),
@@ -520,9 +561,15 @@ class LCCBeta(ParallelAppBase):
             query block q = ell[sl, :W]; the index and row gathers under
             `scope`."""
             if tiered:
-                for (off, n_chunks_t, c_t, _), ell_t in zip(
+                for (off, n_chunks_t, c_t, _, _), ell_t in zip(
                     tier_info, tier_ells
                 ):
+                    if step is None:
+                        n_chunks_t *= fnum
+                    else:
+                        with jax.named_scope(scope):
+                            off = off + step * (n_chunks_t * c_t)
+
                     def body(i, carry, off=off, c_t=c_t, ell_t=ell_t):
                         with jax.named_scope(scope):
                             idx = lax.dynamic_slice(
@@ -558,8 +605,12 @@ class LCCBeta(ParallelAppBase):
 
             return lax.fori_loop(0, n_chunks, body, carry)
 
-        def pass_for(carry, rot_ell, cur_fid):
-            """One walk against the target block of fragment `cur_fid`."""
+        def pass_for(carry, rot_ell, cur_fid, step):
+            """Ring step `step`: one walk against the target block of
+            fragment `cur_fid`, over the entries whose neighbour it holds
+            (the tiered schedule's own segments of the step, where the
+            test on `nfid_c` drops the padding alone; all of the untiered
+            one)."""
 
             def chunk_credit(carry, sl, nfid_c, nlid_c, live, q):
                 cr, slots = carry
@@ -590,17 +641,17 @@ class LCCBeta(ParallelAppBase):
                             slots[w], sl, hit, runs[w])}
                 return cr, slots
 
-            return walk(carry, chunk_credit, "grape.lcc.rows")
+            return walk(carry, chunk_credit, "grape.lcc.rows", step)
 
         if fnum == 1:
-            cred, slots = pass_for((cred, slots), ell, jnp.int32(0))
+            cred, slots = pass_for((cred, slots), ell, jnp.int32(0), 0)
         else:
             perm = [(i, (i - 1) % fnum) for i in range(fnum)]
 
             def ring_body(s, carry):
                 credits, r_ell = carry
                 cur_fid = (my_fid + s) % fnum
-                credits = pass_for(credits, r_ell, cur_fid)
+                credits = pass_for(credits, r_ell, cur_fid, s)
                 with jax.named_scope("grape.lcc.ring"):
                     r_ell = lax.ppermute(r_ell, FRAG_AXIS, perm)
                 return credits, r_ell
@@ -609,7 +660,7 @@ class LCCBeta(ParallelAppBase):
                 0, fnum, ring_body, ((cred, slots), ell))
 
         if lcc_mode:
-            # the flush, once a query: the schedule once more without
+            # the flush, once a query: the whole schedule once more without
             # targets.  The scheduled edge (v, u) is the slot (v, j) with
             # ell[v, j] == u, so each slot is read by exactly one edge
             def flush(cr, sl, nfid_c, nlid_c, live, q):

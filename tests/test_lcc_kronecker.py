@@ -19,6 +19,11 @@ The far-end credits go by adjacency slot (PR 33): a row of the `[vp, D]`
 table an edge inside the chunk loops, flushed by id in a walk of its own;
 the lowered text holds no element scatter of C x W updates under
 `grape.lcc.credit`, and `LCC_STATS` counts the rows and the flush.
+
+On several fragments the tier schedule is cut by ring step (PR 35): a pass
+walks the segments of its own step, an entry sits in the one segment of the
+step at which its neighbour's block is on the device, the fold's bound is
+counted from the schedule, and the answer is the one fragment's bit for bit.
 """
 
 import contextlib
@@ -240,18 +245,23 @@ def test_query_lanes_counts_the_schedule(kron):
     frag = kron.load(12, 2)
     app = APP_REGISTRY["lcc"]()
     state = app.init_state(frag)
-    lanes = sum(n * c * w for _, n, c, w in app._tier_info)
+    lanes = sum(n * c * w for _, n, c, w, _ in app._tier_info)  # a pass: one step
     assert LCC_STATS["tiers"] == len(app._tier_info) >= 2
-    assert LCC_STATS["query_lanes"] == 2 * lanes  # every ring pass walks it all
-    assert state["eperm"].shape == (2, sum(n * c for _, n, c, _ in app._tier_info))
+    assert LCC_STATS["shard_lanes"] == lanes
+    assert LCC_STATS["query_lanes"] == 2 * lanes  # two passes, each its own segments
+    # a tier holds a segment a step, side by side
+    assert state["eperm"].shape == (2, 2 * sum(n * c for _, n, c, _, _ in app._tier_info))
+    assert [off for off, *_ in app._tier_info] == list(np.cumsum(
+        [0] + [2 * n * c for _, n, c, _, _ in app._tier_info[:-1]]))
     assert LCC_STATS["d_max"] == state["ell"].shape[-1] == app._tier_info[-1][3]
+    assert LCC_STATS["fold_runs_max"] == app._tier_info[-1][4]
 
 
 @pytest.mark.parametrize("scale", [10, 12])
 @pytest.mark.parametrize("fnum", [1, 2, 4])
 def test_ring_counters(kron, scale, fnum):
-    """What the ring sends and what a shard walks, from the geometry of the
-    adjacency the query read; nothing of it on one fragment."""
+    """What the ring sends and what a shard walks a pass, from the geometry
+    of the adjacency the query read; nothing of the ring on one fragment."""
     frag = kron.load(scale, fnum)
     app = APP_REGISTRY["lcc"]()
     state = app.init_state(frag)
@@ -261,10 +271,19 @@ def test_ring_counters(kron, scale, fnum):
     assert stats["ring_bytes"] == passes * frag.vp * stats["d_max"] * 4
     assert stats["ring_bytes"] * fnum == passes * state["ell"].nbytes
     assert stats["query_lanes"] == max(passes, 1) * stats["shard_lanes"] > 0
-    if app._tier_info is not None:
-        assert stats["shard_lanes"] == sum(n * c * w for _, n, c, w in app._tier_info)
+    if app._tier_info is not None:  # a pass walks a step's segment of each tier
+        assert stats["shard_lanes"] == sum(n * c * w for _, n, c, w, _ in app._tier_info)
+        assert state["eperm"].shape[1] == fnum * sum(n * c for _, n, c, _, _ in app._tier_info)
+        assert stats["flush_updates"] == state["eperm"].shape[1]
+        assert stats["fold_runs_max"] == app._tier_info[-1][4]
     # the orientation keeps each undirected edge at one of its ends
     assert stats["shard_kept_max"] >= stats["shard_kept_min"] > 0
+    # a shard's kept entries fall over its fnum steps
+    assert stats["step_kept_max"] >= stats["step_kept_min"] > 0
+    assert stats["step_kept_min"] * fnum <= stats["shard_kept_min"]
+    assert stats["step_kept_max"] * fnum >= stats["shard_kept_max"]
+    if fnum == 1:
+        assert stats["step_kept_max"] == stats["step_kept_min"] == stats["shard_kept_max"]
     assert (stats["shard_kept_min"] * fnum <= stats["oriented_edges"]
             <= stats["shard_kept_max"] * fnum)
     assert stats["oriented_edges"] == kron.graph(scale).mult.nnz // 2
@@ -277,6 +296,14 @@ def test_apex_counts_are_the_references_triangles(kron):
     apex = by_id(frag, w.result_values())
     tri = lcc_reference.triangles(lcc_reference.simple_adjacency(kron.graph(10).mult))
     assert apex.sum() * 3 == tri.sum() > 0
+
+
+def test_every_counter_of_the_namespace_is_in_the_inventory():
+    """docs/OBSERVABILITY.md's row of the `lcc` namespace names each field."""
+    with open(os.path.join(ROOT, "docs", "OBSERVABILITY.md")) as f:
+        row, = [ln for ln in f if ln.startswith("| federated counter | `lcc` namespace")]
+    for field in LCC_STATS.snapshot():
+        assert f"`{field}`" in row, field
 
 
 # ---- the scopes, and what they left alone ----------------------------------
@@ -409,13 +436,14 @@ def test_lcc_credits_the_far_end_by_slot(graph_cache, monkeypatch, fnum, tiers):
     app, frag = APP_REGISTRY["lcc"](), graph_cache(fnum)
     text = lowered(app, frag, True)
     d = LCC_STATS["d_max"]
-    walks = ({(c, w) for _, _, c, w in app._tier_info} if tiers != "0"
+    walks = ({(c, w) for _, _, c, w, _ in app._tier_info} if tiers != "0"
              else {(min(4096, len(frag.host_oe[0].edge_src)), d)})
     assert (len(walks) == 3) == (tiers != "0")
-    # a row of a tier holds more members than the tier below is wide, so a
-    # chunk of its entries holds few rows, summed before they are written
-    lows = dict(zip(sorted(w for _, w in walks), [0] + sorted(w for _, w in walks)))
-    folds = {(min(c, c // (lows[w] + 1) + 3), w) for c, w in walks}
+    # a chunk of a tier's entries holds as many source rows as the host
+    # counted at most, summed before they are written where that is fewer
+    # (at most half the chunk: above that the sum costs more than it spares)
+    folds = ({(r if 2 * r <= c else c, w) for _, _, c, w, r in app._tier_info}
+             if tiers != "0" else walks)
     assert (folds != walks) == (tiers != "0")
     scatters = scatters_under(text, "grape.lcc.credit")
     assert {shape for shape, window in scatters if window} == folds  # the row folds
@@ -482,21 +510,191 @@ def test_slot_fold_and_flush_are_the_credits_by_id(w, runs):
     assert np.array_equal(got, want) and want[n_pad] == 0
 
 
-@pytest.mark.parametrize("fnum,rows,flush", [(1, 49152, 49152), (2, 57344, 28672),
-                                             (4, 65536, 16384)])
+@pytest.mark.parametrize("fnum,rows,flush", [(1, 49152, 49152), (2, 40960, 40960),
+                                             (4, 32768, 32768)])
 def test_credit_counters(kron, fnum, rows, flush):
-    """Row updates folded into the slot table a query a device (every ring
-    pass folds the whole padded schedule) and element updates of the flush
-    (the schedule once), from the geometry, at scale 12."""
+    """Row updates folded into the slot table a query a device (a ring pass
+    folds the padded segments of its step) and element updates of the flush
+    (the whole schedule once: `fnum` segments a tier), from the geometry, at
+    scale 12."""
     frag = kron.load(12, fnum)
     app = APP_REGISTRY["lcc"]()
     app.init_state(frag)
     stats = LCC_STATS.snapshot()
-    entries = sum(n * c for _, n, c, _ in app._tier_info)
-    assert stats["flush_updates"] == entries == flush
+    entries = sum(n * c for _, n, c, _, _ in app._tier_info)  # a pass
+    assert stats["flush_updates"] == fnum * entries == flush
     assert stats["credit_rows"] == max(stats["ring_passes"], 1) * entries == rows
     assert stats["flush_updates"] >= stats["shard_kept_max"]
+    assert entries >= stats["step_kept_max"]
     assert stats["credit_rows"] * app._tier_info[0][3] <= stats["query_lanes"]
+
+
+# ---- the schedule cut by ring step ------------------------------------------
+
+
+def schedule(frag):
+    """(tier_info, eperm [fnum, L], cnt [fnum, vp]) of the fragment's adjacency."""
+    app = APP_REGISTRY["lcc"]()
+    state = app.init_state(frag)
+    return app._tier_info, np.asarray(state["eperm"]), np.asarray(state["cnt"])
+
+
+def kept_by_rule(frag) -> list:
+    """Per shard the `oe` entries the "lo" orientation keeps, from the host
+    CSRs alone (the graph is simple: no entry repeats)."""
+    fnum, vp = frag.fnum, frag.vp
+    deg = np.concatenate([np.diff(frag.host_oe[f].indptr) for f in range(fnum)])
+    kept = []
+    for f in range(fnum):
+        oe = frag.host_oe[f]
+        v = f * vp + oe.edge_src[:oe.num_edges].astype(np.int64)
+        u = oe.edge_nbr[:oe.num_edges].astype(np.int64)
+        kept.append(np.flatnonzero(
+            ((deg[u] > deg[v]) | ((deg[u] == deg[v]) & (u > v))) & (u != v)))
+    return kept
+
+
+def source_rows(frag, f, idx):
+    """The local source rows of schedule entries `idx` as the step reads them
+    (a pad reads the shard's last entry)."""
+    ep = len(frag.host_oe[f].edge_src)
+    return np.minimum(frag.host_oe[f].edge_src[np.minimum(idx, ep - 1)], frag.vp - 1)
+
+
+@pytest.mark.parametrize("fnum", [2, 4])
+def test_an_entry_sits_in_the_segment_of_its_ring_step(kron, fnum):
+    """Every kept entry of shard f is scheduled once, in its source row's
+    tier and in the segment of step (nbr_fid - f) % fnum, the one pass at
+    which its neighbour's block is on device f; a segment is in `oe` order
+    with its padding behind, so a chunk's source rows ascend."""
+    frag = kron.load(12, fnum)
+    info, eperm, cnt = schedule(frag)
+    vp, ep = frag.vp, len(frag.host_oe[0].edge_src)
+    assert eperm.shape[1] == info[-1][0] + fnum * info[-1][1] * info[-1][2]
+    lows = [0] + [w for _, _, _, w, _ in info[:-1]]
+    for f, kept in enumerate(kept_by_rule(frag)):
+        oe = frag.host_oe[f]
+        assert np.array_equal(np.sort(eperm[f][eperm[f] < ep]), kept)
+        for (off, n, c, w, _), low in zip(info, lows):
+            tier = eperm[f, off:off + fnum * n * c].reshape(fnum, n * c)
+            for s in range(fnum):
+                idx = tier[s][tier[s] < ep]
+                assert ((oe.edge_nbr[idx] // vp - f) % fnum == s).all()
+                width = cnt[f][oe.edge_src[idx]]
+                assert ((low < width) & (width <= w)).all()
+                assert (np.diff(idx) > 0).all() and (tier[s][len(idx):] == ep).all()
+                rows = source_rows(frag, f, tier[s]).reshape(n, c)
+                assert (np.diff(rows, axis=1) >= 0).all()
+    # the fullest segment decides every segment's chunks
+    for t, (off, n, c, _, _) in enumerate(info):
+        fullest = max(((eperm[f, off:off + fnum * n * c] < ep).reshape(fnum, -1)
+                       .sum(axis=1).max()) for f in range(fnum))
+        assert n == max(1, -(-fullest // c))
+
+
+@pytest.mark.parametrize("fnum", [2, 4])
+def test_the_fold_bound_is_counted_from_the_schedule(kron, fnum):
+    """A tier's `runs` is the most distinct source rows (the pad run among
+    them) any chunk of any shard holds: `_fold_rows` with it is the credit
+    element by element on the chunk that attains it, and one less loses that
+    chunk's last run without a word."""
+    from libgrape_lite_tpu.models.lcc_beta import _fold_rows
+
+    frag = kron.load(12, fnum)
+    info, eperm, _ = schedule(frag)
+    vp = frag.vp
+    rng = np.random.default_rng(fnum)
+    for off, n, c, w, runs in info:
+        rows = np.concatenate([
+            source_rows(frag, f, eperm[f, off:off + fnum * n * c]).reshape(-1, c)
+            for f in range(fnum)])
+        distinct = np.array([len(np.unique(r)) for r in rows])
+        assert distinct.max() == runs and 2 * (runs - 1) <= c  # the runs are summed
+        sl = rows[distinct.argmax()]
+        hit = rng.random((w, c)) < 0.3
+        want = np.zeros((vp, w), dtype=np.int64)
+        np.add.at(want, (sl[:, None], np.arange(w)[None, :]), hit.T)
+        empty = jax.numpy.zeros((vp, w), dtype=np.int32)
+        assert np.array_equal(_fold_rows(empty, sl, hit, runs), want)
+        assert np.array_equal(_fold_rows(empty, sl, hit, c), want)  # row by row
+        short = np.asarray(_fold_rows(empty, sl, hit, runs - 1))
+        assert short.sum() < want.sum()
+
+
+def test_one_fragment_keeps_the_uncut_layout(kron):
+    """One fragment has one step: a tier is one segment of the kept entries of
+    its rows in `oe` order, padded to whole chunks, as before the cut."""
+    frag = kron.load(12, 1)
+    info, eperm, cnt = schedule(frag)
+    vp, ep = frag.vp, len(frag.host_oe[0].edge_src)
+    kept, src = kept_by_rule(frag)[0], frag.host_oe[0].edge_src
+    tier = np.searchsorted([w for _, _, _, w, _ in info], cnt[0][src[kept]])
+    want = []
+    for t, (off, n, c, _, _) in enumerate(info):
+        idx = kept[tier == t]
+        assert off == sum(map(len, want)) and n == max(1, -(-len(idx) // c))
+        want.append(np.concatenate([idx, np.full(n * c - len(idx), ep)]))
+    assert np.array_equal(eperm[0], np.concatenate(want))
+    assert eperm.dtype == np.int32 and eperm.shape == (1, sum(map(len, want)))
+
+
+@pytest.fixture(scope="module")
+def answers(kron):
+    """(scale, fnum, app) -> the answer by vertex id."""
+    got = {}
+
+    def answer(scale: int, fnum: int, app: str):
+        if (scale, fnum, app) not in got:
+            frag = kron.load(scale, fnum)
+            w = Worker(APP_REGISTRY["lcc"]() if app == "lcc" else ApexTriangleCount(), frag)
+            w.query()
+            got[scale, fnum, app] = by_id(frag, w.result_values())
+        return got[scale, fnum, app]
+
+    return answer
+
+
+@pytest.mark.parametrize("app", ["lcc", "apex"])
+@pytest.mark.parametrize("fnum", [2, 4])
+@pytest.mark.parametrize("scale", [10, 12])
+def test_several_fragments_answer_as_one_bit_for_bit(answers, scale, fnum, app):
+    """Every oriented edge meets its target block once, at the step the host
+    computed: the same integer credits, so the same bytes."""
+    one, several = answers(scale, 1, app), answers(scale, fnum, app)
+    assert several.dtype == one.dtype and several.tobytes() == one.tobytes()
+    assert one.sum() > 0
+
+
+def trip_count(loop: str):
+    """The static trip count of a lowered `fori_loop`: the constant its
+    condition compares with (None where it compares with no constant)."""
+    cond = loop.split("cond {", 1)[1].split("} do {", 1)[0]
+    found = re.search(r"stablehlo\.constant dense<(\d+)>", cond)
+    return int(found.group(1)) if found else None
+
+
+@pytest.mark.parametrize("fnum", [1, 4])
+def test_a_ring_pass_walks_its_own_steps_chunks(graph_cache, monkeypatch, fnum):
+    """In the lowered runner the chunk loops that intersect run a step's
+    chunks of each tier, not the tier's `fnum` segments; the flush's loops run
+    them all; the ring runs `fnum` passes."""
+    monkeypatch.setenv("GRAPE_LCC_TIERS", "2,8")
+    app, frag = APP_REGISTRY["lcc"](), graph_cache(fnum)
+    text = lowered(app, frag, True)
+    meet = locs_under(text, "grape.lcc.intersect")
+    credit = locs_under(text, "grape.lcc.credit")
+    ring, passes, flushes = [], [], []
+    for part in loops(text):
+        if "stablehlo.collective_permute" in part:
+            ring.append(trip_count(part))
+        elif ops_at(part, meet):
+            passes.append(trip_count(part))
+        elif "stablehlo.gather" in ops_at(part, credit):
+            flushes.append(trip_count(part))
+    a_step = sorted(n for _, n, _, _, _ in app._tier_info)
+    assert len(a_step) == 3 and ring == [fnum] * (fnum > 1)
+    assert sorted(passes) == a_step
+    assert sorted(flushes) == [fnum * n for n in a_step]
 
 
 @pytest.mark.parametrize("tiers", ["2,8", "0"])
