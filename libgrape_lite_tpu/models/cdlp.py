@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from libgrape_lite_tpu.app.base import ParallelAppBase, StepContext
+from libgrape_lite_tpu.obs.federation import FederatedStats as _FedStats
 from libgrape_lite_tpu.ops.segment import (
     pull_gather,
     run_position,
@@ -39,11 +40,30 @@ from libgrape_lite_tpu.ops.segment import (
 )
 from libgrape_lite_tpu.utils.types import LoadStrategy, MessageStrategy
 
+# which sort the last extracted query's passes took (docs/OBSERVABILITY.md):
+# `branch` is what the shapes admit (`static` / `dynamic` / `wide`),
+# `universe` the distinct labels each pass saw before it sorted (-1 from
+# a pass that computes no predicate), `packed_passes` how many of them
+# fitted `u_budget` and so took the dynamic branch's packed arm
+CDLP_STATS = _FedStats("cdlp", {
+    "branch": "", "u_budget": 0, "passes": 0, "packed_passes": 0,
+    "universe": [],
+})
+
+
+def _note_pass(universe, done, n_live):
+    """`universe` with `n_live` in the slot of the pass that follows
+    `done` finished ones (a slice update: the round holds no scatter
+    but the sort's own)."""
+    return jax.lax.dynamic_update_slice(
+        universe, jnp.reshape(n_live, (1,)), (done,))
+
+
 class CDLP(ParallelAppBase):
     load_strategy = LoadStrategy.kOnlyOut
     message_strategy = MessageStrategy.kAlongOutgoingEdgeToOuterVertex
     result_format = "int"
-    replicated_keys = frozenset({"step", "lut"})
+    replicated_keys = frozenset({"step", "lut", "universe"})
     # r9: the mode fold is per-row multiset arithmetic — splitting the
     # edge set by destination row (boundary/interior) and folding each
     # part separately reproduces every row's (src,label) run structure
@@ -84,7 +104,10 @@ class CDLP(ParallelAppBase):
         # static sorted label universe (labels only ever move between
         # existing ids); +1 slot so searchsorted results stay in range
         lut = np.sort(np.append(labels.reshape(-1), big))
-        state = {"labels": labels, "step": np.int32(0), "lut": lut}
+        # one slot a pass: the distinct labels it saw (CDLP_STATS)
+        universe = np.full((max(self.max_round, 1),), -1, np.int32)
+        state = {"labels": labels, "step": np.int32(0), "lut": lut,
+                 "universe": universe}
         # superstep pipelining (r9): gather exchange, oe pull; CDLPOpt
         # inherits (its shortcut only replaces peval — round 1 runs
         # serial on either path)
@@ -102,7 +125,47 @@ class CDLP(ParallelAppBase):
         )
         return state
 
-    def _mode_fold(self, src, lab, full, lut, vp, row_ptr=None):
+    def _sort_plan(self, n_pad: int, vp: int):
+        """(branch, bits of a packed key's label field, live-universe
+        budget) the shapes admit: `static` packs (src, rank in the
+        initial id universe) into 32 bits, `dynamic` decides each pass
+        by the distinct labels left whether (src, rank in the live
+        universe) fits, `wide` sorts two keys."""
+        rank_bits = max(1, int(np.ceil(np.log2(n_pad + 2))))
+        src_bits = max(1, int(np.ceil(np.log2(vp + 2))))
+        if rank_bits + src_bits <= 32 and not (
+            self._force_wide or self._force_dynamic
+        ):
+            return "static", rank_bits, 0
+        if 32 - src_bits >= 10 and not self._force_wide:
+            dyn_bits = 32 - src_bits
+            u_budget = min(1 << dyn_bits,
+                           int(2 ** np.ceil(np.log2(n_pad + 2))))
+            if self._u_budget_override is not None:
+                u_budget = self._u_budget_override
+            return "dynamic", dyn_bits, u_budget
+        return "wide", 0, 0
+
+    def _live_labels(self, full, lut, vp):
+        """Distinct labels in the gathered state: the predicate of the
+        dynamic branch's `lax.cond`, -1 where the shapes compute none.
+
+        It must be CHEAP in the non-engaging case (RMAT's ~0.34n live
+        universe never fits any 32-src_bits budget, and a measured
+        RMAT-20 A/B put an unconditional universe sort at +23% per
+        round): count by scatter into the static lut positions —
+        O(n_pad) searchsorted + scatter, no sort.  The universe SORT
+        runs inside the packed arm only."""
+        n_pad = full.shape[0]
+        if self._sort_plan(n_pad, vp)[0] != "dynamic":
+            return jnp.int32(-1)
+        with jax.named_scope("grape.cdlp.universe"):
+            pos = jnp.searchsorted(lut, full)
+            mark = jnp.zeros((n_pad + 1,), jnp.int32).at[pos].set(1)
+            return mark.sum(dtype=jnp.int32)
+
+    def _mode_fold(self, src, lab, full, lut, vp, row_ptr=None,
+                   n_live=None):
         """Per-row mode label from one (src, label) edge multiset:
         sort, then count and choose by scans over the sorted pairs —
         the TPU counting kernel shared by the serial round and both
@@ -129,24 +192,26 @@ class CDLP(ParallelAppBase):
 
         Named for the device trace (metadata only, like the pull's):
         `grape.cdlp.universe` on the distinct-label predicate of the
-        dynamic branch, `grape.cdlp.sort` on key building, the sort
-        (whichever branch) and the decode to `(ss, ll)`,
-        `grape.cdlp.count` on the positions in the runs, and
-        `grape.pull.fold` on the scan into rows."""
+        dynamic branch (`n_live`, counted here unless the caller did),
+        `grape.cdlp.sort` on key building, the sort (whichever branch)
+        and the shifts back to `(ss, ll)`; inside it, in the dynamic
+        branch's packed arm, `grape.cdlp.live` on the V-wide build of
+        the live universe and `grape.cdlp.rank` on the two E-wide
+        reads of that table; `grape.cdlp.count` on the positions in
+        the runs, and `grape.pull.fold` on the scan into rows."""
         with jax.named_scope("grape.cdlp.sort"):
-            ss, ll = self._sorted_pairs(src, lab, full, lut, vp)
+            ss, ll = self._sorted_pairs(src, lab, full, lut, vp, n_live)
         with jax.named_scope("grape.cdlp.count"):
             pos = run_position(ss, ll)
         return segment_top_label(pos, ll, ss, vp, row_ptr=row_ptr)
 
-    def _sorted_pairs(self, src, lab, full, lut, vp):
+    def _sorted_pairs(self, src, lab, full, lut, vp, n_live=None):
         """The (src, label) pairs in lexicographic order, by whichever
-        of the three sorts the shapes admit."""
+        of the three sorts the shapes admit (`_sort_plan`)."""
         dt = lab.dtype
         big = jnp.asarray(np.iinfo(np.dtype(dt).name).max, dt)
         n_pad = full.shape[0]
-        rank_bits = max(1, int(np.ceil(np.log2(n_pad + 2))))
-        src_bits = max(1, int(np.ceil(np.log2(vp + 2))))
+        branch, bits, u_budget = self._sort_plan(n_pad, vp)
         from jax import lax as jlax
 
         def _wide(src, lab):
@@ -157,9 +222,8 @@ class CDLP(ParallelAppBase):
             # Works at any label width the dtype admits.
             return jlax.sort((src, lab), num_keys=2)
 
-        if rank_bits + src_bits <= 32 and not (
-            self._force_wide or self._force_dynamic
-        ):
+        if branch == "static":
+            rank_bits = bits
             # labels always belong to the initial id universe, so they
             # rank into a static sorted LUT; packing (src, rank) into
             # one uint32 key lets ONE sort replace the two-key lexsort,
@@ -174,7 +238,7 @@ class CDLP(ParallelAppBase):
                             jnp.uint32(n_pad)).astype(jnp.int32)
             ]
             return ss, ll
-        if 32 - src_bits >= 10 and not self._force_wide:
+        if branch == "dynamic":
             # Dynamic label-universe compression (VERDICT r4 next #2;
             # reference XL-graph counterpart: cdlp_opt.h): when the
             # STATIC universe (n_pad ids) outgrows the 32-bit pack, the
@@ -188,45 +252,35 @@ class CDLP(ParallelAppBase):
             # 2^(32 - src_bits), else the variadic wide sort.  Early
             # all-distinct rounds take the wide branch; coalesced
             # rounds (the bulk of max_round) take the packed one.
-            dyn_bits = 32 - src_bits
-            u_budget = 1 << dyn_bits
-            u_budget = min(u_budget, int(2 ** np.ceil(np.log2(n_pad + 2))))
-            if self._u_budget_override is not None:
-                u_budget = self._u_budget_override
-            # the cond predicate must be CHEAP in the non-engaging case
-            # (RMAT's ~0.34n live universe never fits any 32-src_bits
-            # budget, and a measured RMAT-20 A/B put an unconditional
-            # universe sort at +23% per round): count distinct labels
-            # by scatter into the static lut positions — O(n_pad)
-            # searchsorted + scatter, no sort.  The universe SORT runs
-            # inside the packed branch only.
-            with jax.named_scope("grape.cdlp.universe"):
-                pos = jnp.searchsorted(lut, full)
-                mark = jnp.zeros((n_pad + 1,), jnp.int32).at[pos].set(1)
-                n_distinct = mark.sum()
+            dyn_bits = bits
+            if n_live is None:
+                n_live = self._live_labels(full, lut, vp)
 
             def _packed(args):
                 src, lab, full = args
-                su = jnp.sort(full)
-                first_u = jnp.ones_like(su, dtype=bool).at[1:].set(
-                    su[1:] != su[:-1]
-                )
-                uidx = jnp.cumsum(first_u.astype(jnp.int32)) - 1
-                uniq = jnp.full((u_budget,), big, dt).at[
-                    jnp.where(first_u, uidx, u_budget)
-                ].set(su, mode="drop")
-                rank = jnp.searchsorted(uniq, lab).astype(jnp.uint32)
+                with jax.named_scope("grape.cdlp.live"):
+                    su = jnp.sort(full)
+                    first_u = jnp.ones_like(su, dtype=bool).at[1:].set(
+                        su[1:] != su[:-1]
+                    )
+                    uidx = jnp.cumsum(first_u.astype(jnp.int32)) - 1
+                    uniq = jnp.full((u_budget,), big, dt).at[
+                        jnp.where(first_u, uidx, u_budget)
+                    ].set(su, mode="drop")
+                with jax.named_scope("grape.cdlp.rank"):
+                    rank = jnp.searchsorted(uniq, lab).astype(jnp.uint32)
                 key = (src.astype(jnp.uint32) << dyn_bits) | rank
                 key = jnp.sort(key)
                 ss = (key >> dyn_bits).astype(jnp.int32)
-                ll = uniq[
-                    jnp.minimum(key & jnp.uint32((1 << dyn_bits) - 1),
-                                jnp.uint32(u_budget - 1)).astype(jnp.int32)
-                ]
+                slot = jnp.minimum(
+                    key & jnp.uint32((1 << dyn_bits) - 1),
+                    jnp.uint32(u_budget - 1)).astype(jnp.int32)
+                with jax.named_scope("grape.cdlp.rank"):
+                    ll = uniq[slot]
                 return ss, ll
 
             return jlax.cond(
-                n_distinct <= jnp.int32(u_budget), _packed,
+                n_live <= jnp.int32(u_budget), _packed,
                 lambda args: _wide(args[0], args[1]), (src, lab, full),
             )
         # wide path (vertices/shard beyond even the dynamic pack, or
@@ -234,6 +288,7 @@ class CDLP(ParallelAppBase):
         return _wide(src, lab)
 
     def _propagate(self, ctx, frag, labels, lut):
+        """One pass: (the new labels, the distinct labels it saw)."""
         oe = frag.oe
         vp = frag.vp
         dt = labels.dtype
@@ -243,15 +298,16 @@ class CDLP(ParallelAppBase):
         lab = pull_gather(full, oe.edge_nbr, mask=oe.edge_mask, fill=big)
         with jax.named_scope("grape.cdlp.sort"):
             src = jnp.where(oe.edge_mask, oe.edge_src, jnp.int32(vp))
+        n_live = self._live_labels(full, lut, vp)
         new_lab = self._mode_fold(src, lab, full, lut, vp,
-                                  row_ptr=oe.indptr)
+                                  row_ptr=oe.indptr, n_live=n_live)
 
         with jax.named_scope("grape.app.update"):
             has_out = frag.out_degree > 0
             keep = jnp.logical_or(~frag.inner_mask, ~has_out)
             return jnp.where(
                 jnp.logical_or(keep, new_lab == big), labels, new_lab
-            )
+            ), n_live
 
     def inceval_pipelined(self, ctx: StepContext, frag, state, xbuf):
         """Double-buffered round (parallel/pipeline.py, r9): fold the
@@ -272,11 +328,13 @@ class CDLP(ParallelAppBase):
         has_out = frag.out_degree > 0
         keep = jnp.logical_or(~frag.inner_mask, ~has_out)
         full = pl.splice(ctx, labels, state, xbuf)
+        n_live = self._live_labels(full, lut, vp)
+        universe = _note_pass(state["universe"], state["step"], n_live)
         lab_b = pull_gather(
             full, state["pl_b_nbr"], mask=state["pl_b_val"], fill=big
         )
         fold_b = self._mode_fold(
-            state["pl_b_src"], lab_b, full, lut, vp
+            state["pl_b_src"], lab_b, full, lut, vp, n_live=n_live
         )
         new_b = jnp.where(
             jnp.logical_or(keep, fold_b == big), labels, fold_b
@@ -288,7 +346,7 @@ class CDLP(ParallelAppBase):
             full, state["pl_i_nbr"], mask=state["pl_i_val"], fill=big
         )
         fold_i = self._mode_fold(
-            state["pl_i_src"], lab_i, full, lut, vp
+            state["pl_i_src"], lab_i, full, lut, vp, n_live=n_live
         )
         new_i = jnp.where(
             jnp.logical_or(keep, fold_i == big), labels, fold_i
@@ -298,20 +356,26 @@ class CDLP(ParallelAppBase):
             step >= jnp.int32(self.max_round), jnp.int32(0),
             jnp.int32(1),
         )
-        return {"labels": new, "step": step, "lut": lut}, active, xbuf2
+        return {"labels": new, "step": step, "lut": lut,
+                "universe": universe}, active, xbuf2
 
     def peval(self, ctx: StepContext, frag, state):
         # reference PEval: step=1, one propagation (cdlp.h PEval)
-        labels = self._propagate(ctx, frag, state["labels"], state["lut"])
-        state = dict(state, labels=labels, step=jnp.int32(1))
+        labels, n_live = self._propagate(
+            ctx, frag, state["labels"], state["lut"])
+        state = dict(state, labels=labels, step=jnp.int32(1),
+                     universe=_note_pass(state["universe"], 0, n_live))
         active = jnp.int32(1 if self.max_round > 1 else 0)
         return state, active
 
     def inceval(self, ctx: StepContext, frag, state):
         step = state["step"] + 1
-        labels = self._propagate(ctx, frag, state["labels"], state["lut"])
+        labels, n_live = self._propagate(
+            ctx, frag, state["labels"], state["lut"])
         active = jnp.where(step >= jnp.int32(self.max_round), jnp.int32(0), jnp.int32(1))
-        return dict(state, labels=labels, step=step), active
+        universe = _note_pass(state["universe"], state["step"], n_live)
+        return dict(state, labels=labels, step=step,
+                    universe=universe), active
 
     def invariants(self, frag, state):
         # Labels are NOT monotone under mode adoption (the most
@@ -343,8 +407,21 @@ class CDLP(ParallelAppBase):
             "sentinel)",
         )]
 
+    def _record_stats(self, labels, state) -> None:
+        """CDLP_STATS from the extracted state: host side, after the
+        query and outside its wall (the leaf comes with the labels)."""
+        branch, _, u_budget = self._sort_plan(labels.size, labels.shape[-1])
+        passes = int(np.asarray(state["step"]))
+        universe = np.asarray(state["universe"]).reshape(-1)[:passes]
+        CDLP_STATS.update(
+            branch=branch, u_budget=u_budget, passes=passes,
+            packed_passes=int(((universe >= 0) & (universe <= u_budget)).sum()),
+            universe=universe.tolist(),
+        )
+
     def finalize(self, frag, state):
         labels = np.asarray(state["labels"])
+        self._record_stats(labels, state)
         if frag.is_string_keyed():
             # device labels are pid surrogates (edgecut oids array);
             # map back to the original string ids for output

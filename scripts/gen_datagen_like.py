@@ -32,83 +32,60 @@ Output: TSV edge file (+ optional .v), plus a JSON line of structural
 properties so the mapping to the real dataset is checkable.  See
 docs/DATAGEN_SURROGATE.md for the RMAT<->datagen comparison this
 unblocks.
+
+The construction itself lives in `benchmarks/graphs/datagen_like.py`
+(PR 36: the benchmark's cell `datagen-like.cdlp-10r` draws its graph
+with it and may depend on nothing outside `benchmarks/`); this script
+hands it the published file's ratios (`published_block`) and keeps
+the command line, the properties line and the `%.9f` writer.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
+import os
 import sys
 import time
 
 import numpy as np
 
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
 FULL_V = 12_857_672
 FULL_E = 1_049_527_225
 
 
+def published_block(seed: int) -> dict:
+    """The construction's parameters at datagen-9_0-fb's own ratios, as
+    the `generator` block `benchmarks/graphs/datagen_like.py` reads."""
+    return {
+        "name": "datagen_like",
+        "mean_degree": 2 * FULL_E / FULL_V,  # ~163 (undirected degree)
+        "degree_sigma": 1.15,
+        "degree_clip": [1, 2000],
+        # mean size ~1500 keeps intra-community edge density ~10% —
+        # dense enough for CDLP/LCC community behavior, sparse enough
+        # that configuration-model duplicate pairs stay rare (a
+        # 150-person mean with 130 intra stubs per member degenerated
+        # into near-cliques and lost 25% of edges to dedup)
+        "vertices_per_community": 1500,
+        "community_zipf": 1.35,
+        "community_size_unit": 300,
+        "community_clip": [400, 50_000],
+        "intra_share": 0.8,
+        "weights": [1e-6, 1.0],
+        "weight_dtype": "float64",
+        "generator_seed": seed,
+    }
+
+
 def generate(scale_div: int, seed: int = 42):
-    rng = np.random.default_rng(seed)
+    """The surrogate at 1/scale_div of the full vertex count."""
+    from benchmarks.graphs import datagen_like
+
     n = FULL_V // scale_div
-    target_avg_deg = 2 * FULL_E / FULL_V  # ~163 (undirected degree)
-
-    # degree sequence
-    sigma = 1.15
-    mu = np.log(target_avg_deg) - sigma * sigma / 2
-    deg = np.clip(
-        rng.lognormal(mu, sigma, n), 1, 2000
-    ).astype(np.int64)
-    # make stub count even so the configuration model closes
-    if deg.sum() % 2:
-        deg[0] += 1
-
-    # community assignment: power-law sizes.  Mean size ~1500 keeps
-    # intra-community edge density ~10% — dense enough for CDLP/LCC
-    # community behavior, sparse enough that configuration-model
-    # duplicate pairs stay rare (a 150-person mean with 130 intra
-    # stubs per member degenerated into near-cliques and lost 25% of
-    # edges to dedup)
-    n_comm = max(n // 1500, 1)
-    raw = rng.zipf(1.35, n_comm).astype(np.float64)
-    sizes = np.clip(raw * 300, 400, 50_000)
-    sizes = (sizes / sizes.sum() * n).astype(np.int64)
-    sizes = np.maximum(sizes, 1)
-    # fix rounding drift onto the largest community
-    sizes[np.argmax(sizes)] += n - sizes.sum()
-    comm = np.repeat(np.arange(len(sizes), dtype=np.int64), sizes)
-    rng.shuffle(comm)
-
-    # stubs: vertex v appears deg[v] times
-    stubs = np.repeat(np.arange(n, dtype=np.int64), deg)
-    intra = rng.random(len(stubs)) < 0.8
-    edges = []
-    for mask, by_comm in ((intra, True), (~intra, False)):
-        s = stubs[mask]
-        if len(s) % 2:  # odd stub pool: drop one
-            s = s[:-1]
-        if by_comm:
-            order = np.lexsort((rng.random(len(s)), comm[s]))
-        else:
-            order = rng.permutation(len(s))
-        s = s[order]
-        u, v = s[0::2], s[1::2]
-        if by_comm:
-            # consecutive pairing may straddle a community boundary for
-            # one pair per community — those become (valid) inter edges
-            pass
-        edges.append((u, v))
-    src = np.concatenate([e[0] for e in edges])
-    dst = np.concatenate([e[1] for e in edges])
-
-    keep = src != dst
-    src, dst = src[keep], dst[keep]
-    # drop duplicate undirected pairs
-    lo = np.minimum(src, dst)
-    hi = np.maximum(src, dst)
-    key = lo * n + hi
-    _, first = np.unique(key, return_index=True)
-    src, dst = lo[first], hi[first]
-    w = rng.uniform(1e-6, 1.0, len(src))
+    src, dst, w, comm, deg = datagen_like.draw(published_block(seed), n)
     return n, src, dst, w, comm, deg
 
 
