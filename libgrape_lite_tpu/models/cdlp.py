@@ -10,7 +10,8 @@ maximum).
 TPU formulation of the mode computation — a sort and two scans over
 the pairs it put in order (no per-vertex hash map, no scatter):
 
-  1. gather labels, read one per edge,
+  1. gather labels, read one per edge (in the dynamic branch: each
+     label's rank in the pass's live universe, `_live_labels`),
   2. sort edge (src, label) pairs (`_sorted_pairs`, three branches),
   3. give each entry its position in its run of equal (src, label)
      pairs (`ops/segment.run_position`): at a run's last entry that is
@@ -19,7 +20,7 @@ the pairs it put in order (no per-vertex hash map, no scatter):
      "larger position, then smaller label"
      (`ops/segment.segment_top_label`),
   5. read each row's answer at the row's last entry: the smallest
-     label among its longest runs.
+     label among its longest runs (a rank, decoded a row at a time).
 
 Everything is O(E log E) on device with static shapes; multi-edges
 contribute multiplicity exactly like the reference's neighbor scan.
@@ -142,31 +143,69 @@ class CDLP(ParallelAppBase):
             u_budget = min(1 << dyn_bits,
                            int(2 ** np.ceil(np.log2(n_pad + 2))))
             if self._u_budget_override is not None:
-                u_budget = self._u_budget_override
+                u_budget = min(self._u_budget_override, 1 << dyn_bits)
             return "dynamic", dyn_bits, u_budget
         return "wide", 0, 0
 
-    def _live_labels(self, full, lut, vp):
-        """Distinct labels in the gathered state: the predicate of the
-        dynamic branch's `lax.cond`, -1 where the shapes compute none.
+    def _live_labels(self, full, vp):
+        """What a pass reads of the gathered state `full` before its
+        entries: `(n_live, values, fill, table)`.
 
-        It must be CHEAP in the non-engaging case (RMAT's ~0.34n live
-        universe never fits any 32-src_bits budget, and a measured
-        RMAT-20 A/B put an unconditional universe sort at +23% per
-        round): count by scatter into the static lut positions —
-        O(n_pad) searchsorted + scatter, no sort.  The universe SORT
-        runs inside the packed arm only."""
+        `n_live` is the distinct labels in `full` (the pad label among
+        them where a vertex holds it): the predicate of the dynamic
+        branch's `lax.cond` and the pass's slot of `universe`; -1 where
+        the shapes compute none.  `values` is the V-wide table the
+        pull's gather reads one entry an edge from, `fill` what a
+        masked entry reads instead, `table` what turns the fold's
+        answer back into a label (None: it is one already).
+
+        Outside the dynamic branch `values` is `full` itself.  In it
+        the live universe is ONE V-wide sort of `full` with the vertex
+        ids beside it, and nothing searches: a sorted value opens a run
+        where it differs from the one before it, the runs counted are
+        `n_live`, the runs before a value are its rank in the live
+        universe, a second V-wide sort by vertex id carries the ranks
+        back to vertex order (`values`), and the run openers sorted
+        again are the distinct labels in rank order (`table`, `big`
+        behind the last).  The table ascends, so rank order is label
+        order: entries sort, count and fold by rank exactly as they
+        would by label (`_mode_fold`), and ranks are dense, so the
+        packed key holds them whenever `n_live` fits the budget.
+
+        Every pass of the dynamic branch builds all of it, whichever
+        arm its `cond` then takes: on the v5e the three sorts, the
+        flags and the `cumsum` cost 0.2 ms a pass at 131,072 ids and
+        1.0 ms at 524,288, where the search this replaced
+        (`searchsorted(lut, full)`, twenty dependent V-wide gather
+        steps, and a mark scatter) cost 17.4 and 77.7 (PERF.md section
+        6, PR 37; the ranks' way back by a scatter costs three times
+        the sort's).
+
+        Named for the device trace: `grape.cdlp.universe` on the sort
+        and the count, `grape.cdlp.live` on the ranks' way back and
+        the table."""
         n_pad = full.shape[0]
+        dt = full.dtype
+        big = jnp.asarray(np.iinfo(np.dtype(dt).name).max, dt)
         if self._sort_plan(n_pad, vp)[0] != "dynamic":
-            return jnp.int32(-1)
+            return jnp.int32(-1), full, big, None
+        ids = jnp.arange(n_pad, dtype=jnp.int32)
+        # none of the three sorts needs to be stable: equal labels share
+        # a rank whichever comes first, the vertex ids are distinct,
+        # and the table has one operand (see `_sorted_pairs`)
         with jax.named_scope("grape.cdlp.universe"):
-            pos = jnp.searchsorted(lut, full)
-            mark = jnp.zeros((n_pad + 1,), jnp.int32).at[pos].set(1)
-            return mark.sum(dtype=jnp.int32)
+            su, perm = jax.lax.sort((full, ids), num_keys=1, is_stable=False)
+            first = jnp.concatenate(
+                [jnp.ones((1,), bool), su[1:] != su[:-1]])
+            n_live = first.sum(dtype=jnp.int32)
+        with jax.named_scope("grape.cdlp.live"):
+            uidx = jnp.cumsum(first.astype(jnp.int32)) - 1
+            _, rank = jax.lax.sort((perm, uidx), num_keys=1, is_stable=False)
+            table = jax.lax.sort(jnp.where(first, su, big), is_stable=False)
+        return n_live, rank, jnp.int32(0), table
 
-    def _mode_fold(self, src, lab, full, lut, vp, row_ptr=None,
-                   n_live=None):
-        """Per-row mode label from one (src, label) edge multiset:
+    def _mode_fold(self, src, val, lut, vp, n_live, table, row_ptr=None):
+        """Per-row mode label from one (src, value) edge multiset:
         sort, then count and choose by scans over the sorted pairs —
         the TPU counting kernel shared by the serial round and both
         pipelined parts (the fold only ever groups edges of equal src,
@@ -174,14 +213,25 @@ class CDLP(ParallelAppBase):
         set, the boundary part, the interior part — yields the per-row
         result of the full fold for the rows it covers).
 
-        After `_sorted_pairs`, `(ss, ll)` is in lexicographic order in
+        `val` is what the pull's gather read for each entry from
+        `_live_labels`' `values`, and `n_live` and `table` are that
+        call's: labels and no table outside the dynamic branch, in it
+        ranks in the live universe, which order, tie and repeat as
+        their labels do.
+
+        After `_sorted_pairs`, `(ss, vv)` is in lexicographic order in
         every branch: equal pairs are contiguous, rows are contiguous,
         padding (`ss == vp`) is last.  An entry's position in its run
         is the run's length at the run's last entry and less before
         it, so the row's largest position is its largest run length,
-        and the answer is the smallest label that reaches it: a
+        and the answer is the smallest value that reaches it: a
         multi-edge counts as often as it is held, ties go to the
-        smallest label, a row with no entry returns `big`.
+        smallest label, a row with no entry returns `big`.  Ranks are
+        decoded after the fold, a row at a time: `table[answer]` for
+        `vp` rows (0.94 ms a pass at 131,072 rows on the v5e), where
+        the search-and-pack this replaced ranked every entry by a
+        binary search and decoded every entry after the sort (2,270 ms
+        a pass at 18.95M entries; PERF.md section 6, PR 37).
 
         `row_ptr` is the offsets of the sorted rows where the caller
         knows them: for a whole padded CSR its `indptr`, because the
@@ -191,36 +241,45 @@ class CDLP(ParallelAppBase):
         looked up in `ss` (`ops/segment.segment_top_label`).
 
         Named for the device trace (metadata only, like the pull's):
-        `grape.cdlp.universe` on the distinct-label predicate of the
-        dynamic branch (`n_live`, counted here unless the caller did),
-        `grape.cdlp.sort` on key building, the sort (whichever branch)
-        and the shifts back to `(ss, ll)`; inside it, in the dynamic
-        branch's packed arm, `grape.cdlp.live` on the V-wide build of
-        the live universe and `grape.cdlp.rank` on the two E-wide
-        reads of that table; `grape.cdlp.count` on the positions in
-        the runs, and `grape.pull.fold` on the scan into rows."""
+        `grape.cdlp.sort` on key building, the sort (whichever branch
+        and arm) and the shifts back to `(ss, vv)`;
+        `grape.cdlp.count` on the positions in the runs;
+        `grape.pull.fold` on the scan into rows; `grape.cdlp.rank` on
+        what ranks still cost beside the gather and the sort, the
+        V-wide decode."""
         with jax.named_scope("grape.cdlp.sort"):
-            ss, ll = self._sorted_pairs(src, lab, full, lut, vp, n_live)
+            ss, vv = self._sorted_pairs(src, val, lut, vp, n_live)
         with jax.named_scope("grape.cdlp.count"):
-            pos = run_position(ss, ll)
-        return segment_top_label(pos, ll, ss, vp, row_ptr=row_ptr)
+            pos = run_position(ss, vv)
+        top = segment_top_label(pos, vv, ss, vp, row_ptr=row_ptr)
+        if table is None:
+            return top
+        with jax.named_scope("grape.cdlp.rank"):
+            # `segment_top_label`'s answer for a row with no entry is
+            # the rank dtype's largest value, and no rank
+            none = top == jnp.iinfo(top.dtype).max
+            lab = table.at[jnp.where(none, 0, top)].get(
+                mode="promise_in_bounds")
+            return jnp.where(none, jnp.iinfo(lab.dtype).max, lab)
 
-    def _sorted_pairs(self, src, lab, full, lut, vp, n_live=None):
-        """The (src, label) pairs in lexicographic order, by whichever
+    def _sorted_pairs(self, src, val, lut, vp, n_live):
+        """The (src, value) pairs in lexicographic order, by whichever
         of the three sorts the shapes admit (`_sort_plan`)."""
-        dt = lab.dtype
-        big = jnp.asarray(np.iinfo(np.dtype(dt).name).max, dt)
-        n_pad = full.shape[0]
+        n_pad = lut.shape[0] - 1
         branch, bits, u_budget = self._sort_plan(n_pad, vp)
         from jax import lax as jlax
 
-        def _wide(src, lab):
-            # ONE variadic lexicographic sort over the (src, label)
+        def _wide(pair):
+            # ONE variadic lexicographic sort over the (src, value)
             # pair — `lax.sort` with num_keys=2 compares tuples
             # directly, so no rank LUT, no permutation gather, and no
             # second stable sort (the old lexsort fallback paid both).
-            # Works at any label width the dtype admits.
-            return jlax.sort((src, lab), num_keys=2)
+            # Works at any label width the dtype admits.  Every operand
+            # is a key, so equal entries are the same entry and the
+            # sort need not be stable: a stable one carries an E-wide
+            # iota beside its operands: 46-91% more time, 76 MB and
+            # 2 MB of code at 19M entries (PERF.md section 6, PR 37).
+            return jlax.sort(pair, num_keys=2, is_stable=False)
 
         if branch == "static":
             rank_bits = bits
@@ -229,7 +288,7 @@ class CDLP(ParallelAppBase):
             # one uint32 key lets ONE sort replace the two-key lexsort,
             # and (ss, ll) decode straight from the sorted keys — no
             # permutation gather
-            rank = jnp.searchsorted(lut, lab).astype(jnp.uint32)
+            rank = jnp.searchsorted(lut, val).astype(jnp.uint32)
             key = (src.astype(jnp.uint32) << rank_bits) | rank
             key = jnp.sort(key)
             ss = (key >> rank_bits).astype(jnp.int32)
@@ -245,47 +304,29 @@ class CDLP(ParallelAppBase):
             # LIVE universe usually hasn't — label propagation
             # coalesces labels geometrically, so after the first couple
             # of rounds the distinct-label count is far below n_pad.
-            # Build the live universe each round from the gathered
-            # state (one u32 sort of n_pad values — ~E/d of the edge
-            # sort), rank edges into it, and let an in-jit lax.cond
-            # pick the packed single-key sort when the universe fits
-            # 2^(32 - src_bits), else the variadic wide sort.  Early
-            # all-distinct rounds take the wide branch; coalesced
-            # rounds (the bulk of max_round) take the packed one.
+            # The entries come as ranks in the live universe
+            # (`_live_labels`), and an in-jit lax.cond packs (src,
+            # rank) into one uint32 key when the universe fits
+            # 2^(32 - src_bits), else sorts the pair: the arms differ
+            # by their sort and by nothing else.  Early all-distinct
+            # rounds take the wide arm; coalesced rounds (the bulk of
+            # max_round on a graph with communities) the packed one.
             dyn_bits = bits
-            if n_live is None:
-                n_live = self._live_labels(full, lut, vp)
 
-            def _packed(args):
-                src, lab, full = args
-                with jax.named_scope("grape.cdlp.live"):
-                    su = jnp.sort(full)
-                    first_u = jnp.ones_like(su, dtype=bool).at[1:].set(
-                        su[1:] != su[:-1]
-                    )
-                    uidx = jnp.cumsum(first_u.astype(jnp.int32)) - 1
-                    uniq = jnp.full((u_budget,), big, dt).at[
-                        jnp.where(first_u, uidx, u_budget)
-                    ].set(su, mode="drop")
-                with jax.named_scope("grape.cdlp.rank"):
-                    rank = jnp.searchsorted(uniq, lab).astype(jnp.uint32)
-                key = (src.astype(jnp.uint32) << dyn_bits) | rank
-                key = jnp.sort(key)
-                ss = (key >> dyn_bits).astype(jnp.int32)
-                slot = jnp.minimum(
-                    key & jnp.uint32((1 << dyn_bits) - 1),
-                    jnp.uint32(u_budget - 1)).astype(jnp.int32)
-                with jax.named_scope("grape.cdlp.rank"):
-                    ll = uniq[slot]
-                return ss, ll
+            def _packed(pair):
+                src, rank = pair
+                key = (src.astype(jnp.uint32) << dyn_bits) | rank.astype(
+                    jnp.uint32)
+                key = jlax.sort(key, is_stable=False)  # as in `_wide`
+                return ((key >> dyn_bits).astype(jnp.int32),
+                        (key & jnp.uint32((1 << dyn_bits) - 1)).astype(
+                            jnp.int32))
 
             return jlax.cond(
-                n_live <= jnp.int32(u_budget), _packed,
-                lambda args: _wide(args[0], args[1]), (src, lab, full),
-            )
+                n_live <= jnp.int32(u_budget), _packed, _wide, (src, val))
         # wide path (vertices/shard beyond even the dynamic pack, or
         # forced): see _wide
-        return _wide(src, lab)
+        return _wide((src, val))
 
     def _propagate(self, ctx, frag, labels, lut):
         """One pass: (the new labels, the distinct labels it saw)."""
@@ -295,12 +336,12 @@ class CDLP(ParallelAppBase):
         big = jnp.asarray(np.iinfo(np.dtype(dt).name).max, dt)
 
         full = ctx.gather_state(labels)
-        lab = pull_gather(full, oe.edge_nbr, mask=oe.edge_mask, fill=big)
+        n_live, values, fill, table = self._live_labels(full, vp)
+        val = pull_gather(values, oe.edge_nbr, mask=oe.edge_mask, fill=fill)
         with jax.named_scope("grape.cdlp.sort"):
             src = jnp.where(oe.edge_mask, oe.edge_src, jnp.int32(vp))
-        n_live = self._live_labels(full, lut, vp)
-        new_lab = self._mode_fold(src, lab, full, lut, vp,
-                                  row_ptr=oe.indptr, n_live=n_live)
+        new_lab = self._mode_fold(src, val, lut, vp, n_live, table,
+                                  row_ptr=oe.indptr)
 
         with jax.named_scope("grape.app.update"):
             has_out = frag.out_degree > 0
@@ -316,7 +357,8 @@ class CDLP(ParallelAppBase):
         the in-flight collective, join.  Byte-identical to inceval:
         the edge split is closed over destination rows, so each part's
         (src,label) run structure matches the full fold row-for-row
-        (see _mode_fold)."""
+        (see _mode_fold); the live universe is built once a round and
+        serves both parts."""
         pl = self._pipeline
         labels = state["labels"]
         lut = state["lut"]
@@ -328,13 +370,13 @@ class CDLP(ParallelAppBase):
         has_out = frag.out_degree > 0
         keep = jnp.logical_or(~frag.inner_mask, ~has_out)
         full = pl.splice(ctx, labels, state, xbuf)
-        n_live = self._live_labels(full, lut, vp)
+        n_live, values, fill, table = self._live_labels(full, vp)
         universe = _note_pass(state["universe"], state["step"], n_live)
-        lab_b = pull_gather(
-            full, state["pl_b_nbr"], mask=state["pl_b_val"], fill=big
+        val_b = pull_gather(
+            values, state["pl_b_nbr"], mask=state["pl_b_val"], fill=fill
         )
         fold_b = self._mode_fold(
-            state["pl_b_src"], lab_b, full, lut, vp, n_live=n_live
+            state["pl_b_src"], val_b, lut, vp, n_live, table
         )
         new_b = jnp.where(
             jnp.logical_or(keep, fold_b == big), labels, fold_b
@@ -342,11 +384,11 @@ class CDLP(ParallelAppBase):
         xbuf2 = pl.kickoff(ctx, jnp.where(bmask, new_b, labels), state)
         # ---- pipelined window: carry reads below are named in
         # parallel/pipeline.PIPELINE_WINDOW_READS (grape-lint R6) ----
-        lab_i = pull_gather(
-            full, state["pl_i_nbr"], mask=state["pl_i_val"], fill=big
+        val_i = pull_gather(
+            values, state["pl_i_nbr"], mask=state["pl_i_val"], fill=fill
         )
         fold_i = self._mode_fold(
-            state["pl_i_src"], lab_i, full, lut, vp, n_live=n_live
+            state["pl_i_src"], val_i, lut, vp, n_live, table
         )
         new_i = jnp.where(
             jnp.logical_or(keep, fold_i == big), labels, fold_i

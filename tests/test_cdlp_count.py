@@ -7,7 +7,9 @@ back.  Pinned here: the fold against the scatter formulation it
 replaced (kept below as `_mode_fold`'s plain reference) and against a
 `Counter` per row, bit for bit, over the shapes that could break a scan
 (ties, empty rows, a hub over several tiles of both levels, runs = E,
-one run), in each sort branch, with the CSR's offsets and without; whole
+one run) or, since PR 37, the ranks the dynamic branch folds (an empty
+row's sentinel, the table's last slots, the budget's edge), in each sort
+branch, with the CSR's offsets and without; whole
 queries against `benchmarks/references/cdlp.py` on graphs of the same
 shapes, on one fragment and four, serial and pipelined; `CDLPOpt` equal
 to `CDLP`; the contract the offsets rest on (no masked entry inside a
@@ -80,14 +82,28 @@ ROWS = {
     "all_pairs_distinct": [list(range(r, r + 16)) for r in range(16)],
     # one run is all there is: a whole tile, no padding
     "all_pairs_equal": [[], [], [], [5] * T],
+    # the rows before and after hold entries, the one between holds none:
+    # its answer is no rank, and decodes to `big`
+    "empty_row_between": [[2, 2, 6], [], [6, 6, 2], [], []],
+    # the winning label is the largest real one: in the table of the live
+    # universe it is the last slot before `big`'s (vertices 16..31 hold it)
+    "winner_is_bigs_neighbour": [[15, 15, 0], [15], [3, 15, 15, 3, 15]],
+    # the smallest and the largest live label tie: the smallest wins
+    "tie_of_smallest_and_largest": [[0, 15, 15, 0, 7], [15, 0], [7, 15, 0]],
 }
 
+# shape -> `full` where it is not the identity: vertices past the rows'
+# labels hold the pad label, as the padded vertices of a fragment do
+BIG_HELD = {"winner_is_bigs_neighbour": 16}
 
-def _stream(shape: str):
+
+def _stream(shape: str, rows=None, vp: int = 0):
     """(src, lab, full, lut, vp, row_ptr, the answer by Counter)."""
-    rows = ROWS[shape]
-    vp = max(8, 1 << int(np.ceil(np.log2(max(
+    rows = ROWS[shape] if rows is None else rows
+    vp = max(8, vp, 1 << int(np.ceil(np.log2(max(
         [len(rows)] + [lab + 1 for r in rows for lab in r])))))
+    if shape in BIG_HELD:
+        vp *= 2
     deg = np.asarray([len(r) for r in rows] + [0] * (vp - len(rows)))
     ptr = np.zeros(vp + 1, np.int32)
     ptr[1:] = np.cumsum(deg)
@@ -98,6 +114,7 @@ def _stream(shape: str):
     src[:ptr[-1]] = np.repeat(np.arange(vp, dtype=np.int32), deg)
     lab[:ptr[-1]] = [x for r in rows for x in r]
     full = np.arange(vp, dtype=np.int32)
+    full[BIG_HELD.get(shape, vp):] = big
     lut = np.sort(np.append(full, np.int32(big)))
     want = np.full(vp, big, np.int32)
     for r, row in enumerate(rows):
@@ -126,28 +143,70 @@ def _scatter_mode_fold(ss, ll, vp, big):
     return jops.segment_min(cand, ss, num_segments=vp + 1)[:vp]
 
 
+def _fold_both_ways(app, stream, offsets: str):
+    """(`_mode_fold` as `_propagate` calls it, the scatter formulation on
+    the labels of the same sorted pairs, the distinct labels the pass
+    counted, the branch's budget).  An entry's neighbour is the vertex
+    that holds its label: `full` is the identity below the pad labels."""
+    src, lab, full, lut, vp, ptr, _ = stream
+    big = np.iinfo(np.int32).max
+    mask = lab != big
+    nbr = np.where(mask, lab, 0)
+
+    def entries(full, nbr, mask):
+        n_live, values, fill, table = app._live_labels(full, vp)
+        return n_live, jnp.where(mask, values[nbr], fill), table
+
+    def fold(src, full, lut, ptr, nbr, mask):
+        n_live, val, table = entries(full, nbr, mask)
+        return app._mode_fold(
+            src, val, lut, vp, n_live, table,
+            row_ptr=ptr if offsets == "row_ptr" else None), n_live
+
+    def before(src, full, lut, nbr, mask):
+        n_live, val, table = entries(full, nbr, mask)
+        ss, vv = app._sorted_pairs(src, val, lut, vp, n_live)
+        ll = vv if table is None else jnp.where(ss == vp, big, table[vv])
+        return _scatter_mode_fold(ss, ll, vp, big)
+
+    got, n_live = jax.jit(fold)(src, full, lut, ptr, nbr, mask)
+    return (np.asarray(got), np.asarray(jax.jit(before)(src, full, lut, nbr, mask)),
+            int(n_live), app._sort_plan(full.shape[0], vp)[2])
+
+
 @pytest.mark.parametrize("offsets", ["row_ptr", "looked_up"])
 @pytest.mark.parametrize("branch", sorted(BRANCHES))
 @pytest.mark.parametrize("shape", sorted(ROWS))
 def test_mode_fold_is_the_scatter_formulation_bit_for_bit(shape, branch,
                                                           offsets):
-    src, lab, full, lut, vp, ptr, want = _stream(shape)
-    app = _app(branch)
-    big = np.iinfo(np.int32).max
-
-    def fold(src, lab, full, lut, ptr):
-        return app._mode_fold(src, lab, full, lut, vp,
-                              row_ptr=ptr if offsets == "row_ptr" else None)
-
-    def before(src, lab, full, lut):
-        ss, ll = app._sorted_pairs(src, lab, full, lut, vp)
-        return _scatter_mode_fold(ss, ll, vp, big)
-
-    got = np.asarray(jax.jit(fold)(src, lab, full, lut, ptr))
+    stream = _stream(shape)
+    want = stream[-1]
+    got, before, _, _ = _fold_both_ways(_app(branch), stream, offsets)
     assert got.dtype == np.int32
     assert got.tobytes() == want.tobytes()
-    assert got.tobytes() == np.asarray(
-        jax.jit(before)(src, lab, full, lut)).tobytes()
+    assert got.tobytes() == before.tobytes()
+
+
+@pytest.mark.parametrize("offsets", ["row_ptr", "looked_up"])
+@pytest.mark.parametrize("over", [0, 1], ids=["n_live_is_the_budget",
+                                               "n_live_is_one_more"])
+def test_mode_fold_at_the_budgets_edge(over, offsets):
+    """The `lax.cond` packs while the distinct labels are at most the
+    budget: at exactly `u_budget` the largest rank is `u_budget - 1`, the
+    key's whole label field, and one label more takes the two-key sort."""
+    vp, budget = 32, 16
+    rows = [[r, 31, 31, r] for r in range(6)] + [[], [31, 5, 5, 31, 0]]
+    stream = _stream("budget", rows, vp)
+    full = stream[2]
+    # `budget + over` distinct labels in the state: 0..14 or 0..15, and 31
+    full[budget - 1 + over:] = 31
+    app = _app("dynamic")
+    app._u_budget_override = budget
+    got, before, n_live, u_budget = _fold_both_ways(app, stream, offsets)
+    assert (n_live, u_budget) == (budget + over, budget)
+    assert got.tobytes() == stream[-1].tobytes() == before.tobytes()
+    # the smallest label of the tie (31 is the table's last slot in use)
+    assert got[:6].tolist() == list(range(6)) and got[7] == 5
 
 
 # ---- whole queries, against the benchmark's reference ---------------------
@@ -303,22 +362,6 @@ def test_oe_holds_no_masked_entry_inside_a_row(graph, graph_cache, shaped,
 
 # ---- the program ------------------------------------------------------------
 
-def _scatters_of_the_sort(app, frag) -> int:
-    """The scatters `_sorted_pairs` holds on its own (the dynamic
-    branch marks the live labels and compacts them): not the count's."""
-    vp, n_pad = frag.vp, frag.fnum * frag.vp
-    ep = frag.host_oe[0].edge_src.shape[0]
-    dt = jnp.int64 if jax.config.jax_enable_x64 else jnp.int32
-    args = (jax.ShapeDtypeStruct((ep,), jnp.int32),
-            jax.ShapeDtypeStruct((ep,), dt),
-            jax.ShapeDtypeStruct((n_pad,), dt),
-            jax.ShapeDtypeStruct((n_pad + 1,), dt))
-    text = jax.jit(
-        lambda src, lab, full, lut: app._sorted_pairs(src, lab, full, lut, vp)
-    ).lower(*args).as_text()
-    return text.count("stablehlo.scatter")
-
-
 @pytest.mark.parametrize("fnum", [1, 4])
 @pytest.mark.parametrize("branch", ["packed", "dynamic", "wide"])
 def test_serial_round_lowers_without_a_scatter(branch, fnum, graph_cache):
@@ -328,12 +371,9 @@ def test_serial_round_lowers_without_a_scatter(branch, fnum, graph_cache):
     frag = graph_cache(fnum)
     assert _folds_traced(Worker(_app(branch), frag)) == {
         "scan": 2, "scatter": 0}
-    text = lowered(_app(branch), frag, False)
-    # PEval's pass and the loop's: each holds one sort (p2p-31 on one
-    # fragment is too wide to pack unforced and takes the dynamic branch)
-    of_the_sort = _scatters_of_the_sort(_app(branch), frag)
-    assert text.count("stablehlo.scatter") == 2 * of_the_sort
-    assert of_the_sort == 0 or branch != "wide"
+    # in no branch: since PR 37 the dynamic branch builds its live universe
+    # by V-wide sorts, where it marked and compacted the labels by scatters
+    assert "stablehlo.scatter" not in lowered(_app(branch), frag, False)
 
 
 @pytest.mark.parametrize("name,pipeline,want", [

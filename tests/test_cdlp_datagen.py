@@ -3,8 +3,9 @@
 The graph of `benchmarks/configs/datagen-like.json` has what Kronecker lacks:
 planted communities that label propagation collapses onto, so the distinct
 labels fall under the dynamic branch's budget after a pass or two and the
-`lax.cond` takes its packed arm (`grape.cdlp.live`, `grape.cdlp.rank`), which
-on a Graph500 graph it never does.  `CDLP_STATS` says which way each pass
+`lax.cond` takes its packed arm, which on a Graph500 graph it never does
+(since PR 37 both arms sort ranks in the live universe: `grape.cdlp.live` and
+`grape.cdlp.rank` name what every pass of the branch pays for them).  `CDLP_STATS` says which way each pass
 went; the state's `universe` leaf is where it reads that.
 
 Under 2^16 padded ids the shapes pack against the initial universe (`static`)
@@ -162,7 +163,7 @@ def test_opt_and_plain_write_the_same_record_but_the_first_pass(surrogate, name)
     assert (seen[0] == -1) == (name == "cdlp_opt")
 
 
-# ---- the scopes of the packed arm ------------------------------------------
+# ---- the scopes of the dynamic branch ---------------------------------------
 
 
 @pytest.mark.parametrize("force", [None, "_force_wide"])
