@@ -58,6 +58,7 @@ EXPECTED: Dict[str, str] = {
     "lcc": "libgrape_lite_tpu.models.lcc_beta",
     "cdlp": "libgrape_lite_tpu.models.cdlp",
     "setup": "libgrape_lite_tpu.obs.tracer",
+    "rounds": "libgrape_lite_tpu.worker.worker",
 }
 
 

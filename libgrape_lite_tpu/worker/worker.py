@@ -34,12 +34,54 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from libgrape_lite_tpu import compat, obs
 from libgrape_lite_tpu.app.base import AppBase, StepContext
 from libgrape_lite_tpu.fragment.edgecut import ShardedEdgecutFragment
+from libgrape_lite_tpu.obs.federation import FederatedStats as _FedStats
 from libgrape_lite_tpu.parallel.comm_spec import FRAG_AXIS
 from libgrape_lite_tpu.utils.types import state_struct
 
 _INT32_MAX = np.iinfo(np.int32).max
 # the device-trace name of every runner's loop condition (metadata only)
 _TERMINATE_SCOPE = "grape.worker.terminate"
+
+# What the rounds of the last extracted query had to do
+# (docs/OBSERVABILITY.md): the fused serial runner carries a record of
+# the `active` each IncEval voted, a few words beside the state, and
+# `Worker.result_values` reads it out with the answer, outside the
+# query's wall.  `active_bits[b]` counts the rounds whose `active` had
+# bit length b (0: nothing left; b: 2^(b-1) .. 2^b - 1; 32: a negative
+# vote, a terminate code), so the buckets sum to `rounds`;
+# `active_max` and `active_sum` are the widest round and all of them
+# (for BFS: the widest level, and the reached vertices less the
+# source).  A query through any other runner (pipelined, batched,
+# chunked, stepwise, host) leaves the initial values.
+ROUND_STATS = _FedStats("rounds", {
+    "app": "", "rounds": 0, "active_bits": [], "active_max": 0,
+    "active_sum": 0,
+})
+_RECORD_SCOPE = "grape.worker.record"
+_RECORD_BITS = 33  # bit lengths 0..32, one bucket each
+# behind the buckets: the largest vote, the sum's low and high words
+# (x32 has no 64-bit integer; a V-wide vote a round outgrows one word)
+_RECORD_MAX, _RECORD_LO, _RECORD_HI, _RECORD_WORDS = range(
+    _RECORD_BITS, _RECORD_BITS + 4)
+
+
+def _note_round(record, active):
+    """`record` after a round that voted `active`.  The record is
+    `_RECORD_WORDS` uint32 scalars and the step scalar arithmetic: an
+    array in the carry is fetched into VMEM and written back every
+    round, two asynchronous copies whose slots move every other
+    buffer of the loop (PERF.md section 6, PR 39)."""
+    with jax.named_scope(_RECORD_SCOPE):
+        bits = jnp.uint32(32) - lax.clz(active.astype(jnp.uint32))
+        a = jnp.maximum(active, 0).astype(jnp.uint32)
+        lo = record[_RECORD_LO] + a
+        return (
+            *(n + (bits == b).astype(jnp.uint32)
+              for b, n in enumerate(record[:_RECORD_BITS])),
+            jnp.maximum(record[_RECORD_MAX], a),
+            lo,
+            record[_RECORD_HI] + (lo < a).astype(jnp.uint32),
+        )
 
 
 def _squeeze_state(state, squeezed):
@@ -276,6 +318,10 @@ class Worker:
         self.runner_cache_stats = {"hits": 0, "misses": 0}
         self.rounds = 0
         self._result_state = None
+        # (result state, the runner's record of its rounds' votes): the
+        # fused serial runner's, paired so that a result of another
+        # path is never read with this one's record (ROUND_STATS)
+        self._round_record = None
         # the fragment each result was computed on: query_incremental's
         # safe prev_fragment default — a serve repack rebinds
         # self.fragment, but the PREVIOUS result's rows still live in
@@ -345,6 +391,7 @@ class Worker:
         re-admission must compile nothing (tests/test_fleet.py pins
         it)."""
         self._result_state = None
+        self._round_record = None
         self._result_fragment = None
         self._guard_monitor = None
         self.batch_rounds = None
@@ -413,19 +460,28 @@ class Worker:
             limit = jnp.int32(max_rounds if max_rounds > 0 else _INT32_MAX)
 
             def cond(carry):
-                _, act, r = carry
+                _, act, r, _ = carry
                 with jax.named_scope(_TERMINATE_SCOPE):
                     return jnp.logical_and(act > 0, r < limit)
 
             def body(carry):
-                s, _, r = carry
+                s, _, r, rec = carry
                 s2, a2 = app.inceval(ctx, frag, {**s, **eph_vals})
-                return strip(s2), jnp.int32(a2), r + jnp.int32(1)
+                a2 = jnp.int32(a2)
+                return strip(s2), a2, r + jnp.int32(1), _note_round(rec, a2)
 
-            st, active, rounds = lax.while_loop(
-                cond, body, (st, jnp.int32(active), jnp.int32(0))
+            # the record of the rounds' votes (ROUND_STATS) rides in the
+            # loop's carry, not in an app's state: any app's `active`
+            # is recorded, and the apps' own states, which the batched,
+            # pipelined, incremental and checkpointed paths share, stay
+            # as they were
+            st, active, rounds, record = lax.while_loop(
+                cond, body,
+                (st, jnp.int32(active), jnp.int32(0),
+                 (jnp.uint32(0),) * _RECORD_WORDS),
             )
-            return _unsqueeze_state(st, squeezed), rounds, active
+            return (_unsqueeze_state(st, squeezed), rounds, active,
+                    jnp.stack(record))
 
         def compile_for(state):
             specs, squeezed = self._key_specs(state)
@@ -435,7 +491,7 @@ class Worker:
                 partial(stepper, squeezed=squeezed),
                 mesh=mesh,
                 in_specs=(frag_spec, carry_specs, eph_specs),
-                out_specs=(carry_specs, P(), P()),
+                out_specs=(carry_specs, P(), P(), P()),
                 check_vma=False,
             )
             # donate the placed carry state: every query places fresh
@@ -1311,7 +1367,9 @@ class Worker:
                     # trace_report derives overlap_hidden_us from it
                     sp.set(pipeline=self._pipelined().span_brief())
                 with tr.span("worker.enqueue"):
-                    out_state, rounds, active = self._enqueue(
+                    # the serial runner hands its record back too, the
+                    # pipelined one has none
+                    out_state, rounds, active, *record = self._enqueue(
                         runner, "fused", 1, frag.dev, carry, eph_part,
                     )
                 t_enq = _time.perf_counter_ns()
@@ -1348,6 +1406,7 @@ class Worker:
             if tr.enabled:
                 obs.flush()
         self._result_state = out_state
+        self._round_record = (out_state, record[0]) if record else None
         self._result_fragment = self.fragment
         return out_state
 
@@ -2431,7 +2490,24 @@ class Worker:
                         )
             else:
                 host_state = jax.device_get(self._result_state)
+            self._record_round_stats()
             return self.app.finalize(self.fragment, host_state)
+
+    def _record_round_stats(self) -> None:
+        """ROUND_STATS from the record the extracted result's runner
+        carried: host side, after the query and outside its wall."""
+        ROUND_STATS.reset()
+        rec = self._round_record
+        if rec is None or rec[0] is not self._result_state:
+            return
+        # replicated: every device holds all of it
+        words = np.asarray(rec[1].addressable_data(0)).astype(np.int64)
+        ROUND_STATS.update(
+            app=type(self.app).__name__, rounds=int(self.rounds),
+            active_bits=words[:_RECORD_BITS].tolist(),
+            active_max=int(words[_RECORD_MAX]),
+            active_sum=int(words[_RECORD_HI] << 32 | words[_RECORD_LO]),
+        )
 
     def output(self, prefix: str) -> None:
         """Write per-fragment result files `result_frag_<fid>` with
