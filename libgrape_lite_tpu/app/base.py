@@ -234,6 +234,42 @@ class AppBase:
             "implements no inceval_pipelined"
         )
 
+    # ---- a round that follows its frontier (worker `_frontier_loop`) ----
+    #
+    # A monotone min relaxation needs only the rows that improved last
+    # round to propose again.  An app whose `init_state` sets
+    # `frontier_budget` to (B, C) offers the fused serial loop a second
+    # round, `inceval_frontier`, for a list of at most B rows whose
+    # adjacency, in the CSR `frontier_csr` names, holds at most C
+    # entries; the loop carries the list beside the state and takes
+    # that round wherever the last vote and the list's entries fit, the
+    # dense `inceval` elsewhere (ops/segment.py has the round itself).
+    # Both rounds give the same state and the same vote.  What the
+    # offer rests on is the app's to observe in `init_state`,
+    # statically; every other runner keeps `inceval`.
+    frontier_budget = None
+
+    def frontier_mask(self, state, new_state=None):
+        """V-wide mask of the rows whose proposals no round has applied
+        yet: of the state PEval returned, the rows that hold a value
+        (one such row, a query's source, is the first list; more start
+        with a dense round); given the state a dense round made of
+        `state`, the rows that round improved, the list after it."""
+        raise NotImplementedError
+
+    def frontier_csr(self, frag):
+        """The CSR the round pushes along.  Here and in
+        `inceval_frontier` `frag` is the shard's block as the runner
+        receives it, `[1, ...]` leaves unsqueezed
+        (`ops/segment._block_at`)."""
+        raise NotImplementedError
+
+    def inceval_frontier(self, frag, state, front, lo, count):
+        """`inceval` from the rows `front` lists (`lo`, `count`: their
+        `ops/segment.frontier_spans` in `frontier_csr`):
+        `(state', active, front')`, state and vote `inceval`'s own."""
+        raise NotImplementedError
+
     # 0 means "run until the termination vote fires"
     max_rounds: int = 0
 

@@ -42,6 +42,11 @@ backend, the arguments' shapes and dtypes and the device's VMEM size;
 no option selects it.  GATHER_STATS counts which gather each call
 took, as FOLD_STATS does for the fold (docs/OBSERVABILITY.md).
 
+Beside the pull, at the end of the module, a round that touches
+neither every entry nor every row: `frontier_relax` pushes from a list
+of the rows that improved last round, C entries at most, for the loops
+that can carry such a list (worker/worker.py `_frontier_loop`).
+
 The two halves of a pull carry `jax.named_scope` names, which reach the
 device trace as the operations' `tf_op` (metadata only: the compiled
 program is the same with and without them): `grape.pull.gather` on the
@@ -399,3 +404,148 @@ def segment_top_label(count, label, segment_ids, num_rows: int,
             mode="promise_in_bounds", indices_are_sorted=True)
         return jnp.where(last >= row_ptr[:num_rows], out,
                          jnp.asarray(empty, label.dtype))
+
+
+# ---- a round whose work follows its frontier -----------------------------
+#
+# The folds above touch every entry and every row whatever a round has
+# to do.  A monotone min relaxation (BFS, and by the same rule SSSP and
+# WCC) needs only the rows that improved last round to propose again:
+# `frontier_spans`, `frontier_relax` and `frontier_rows` are that round
+# at static shapes, a list of at most B rows whose adjacency holds at
+# most C entries, for one fragment's unbatched state.  Nothing in them
+# is wider than C but the update of the V-wide values in place, and
+# `frontier_rows`, which a loop runs where it turns from dense rounds to
+# these (models/bfs.py, worker/worker.py `_make_runner`).
+
+FRONTIER_SCOPE = "grape.frontier.compact"
+
+
+def _at(table, idx):
+    """`table[idx]` for indices the caller keeps in bounds."""
+    return table.at[idx].get(mode="promise_in_bounds")
+
+
+def _block_at(block, idx):
+    """`_at` for a CSR's array, which may come as a shard's block
+    `[1, N]`, its leading axis unsqueezed: on the chip squeezing such a
+    block is a copy of it into another tiling (`reduce.33`, PERF.md
+    section 6, PR 39), which a round that reads a few thousand of its
+    entries must not pay."""
+    if block.ndim == 2:
+        return block.at[0, idx].get(mode="promise_in_bounds")
+    return _at(block, idx)
+
+
+def frontier_spans(front, row_ptr):
+    """Where the listed rows' entries lie in the CSR `row_ptr` belongs
+    to: per place of `front` (row ids, the list padded with the row
+    count) the row's first entry and its entry count, 0 at a pad, and
+    the count of them all.  B-wide: one gather of the offsets' pairs.
+    `row_ptr` may be a shard's block `[1, V + 1]` (see `_block_at`)."""
+    num_rows = row_ptr.shape[-1] - 1
+    lead = row_ptr.ndim - 1
+    with jax.named_scope("grape.pull.gather"):
+        row = jnp.minimum(front, num_rows - 1)
+        ends = jax.vmap(lambda r: lax.dynamic_slice(
+            row_ptr, (jnp.zeros_like(r),) * lead + (r,),
+            (1,) * lead + (2,)).reshape(2))(row)
+        lo = ends[:, 0]
+        count = jnp.where(front < num_rows, ends[:, 1] - lo, 0)
+    with jax.named_scope(FRONTIER_SCOPE):
+        return lo, count, count.sum()
+
+
+def frontier_relax(values, front, lo, count, edge_nbr, entries: int,
+                   add=1, absent=None):
+    """One push round of a min relaxation from the rows `front` lists:
+    `(values', front', active)`.
+
+    `lo`, `count` are `frontier_spans`' and the entries they cover are
+    at most `entries` (C; the caller's to see to).  The listed rows'
+    entries are laid out in C slots: each row's offset is the counts'
+    running sum, the row that opens at a slot is scattered there (B
+    updates) and comes down to the row's other slots by a running
+    maximum, as `run_position`'s openers do.  A slot then reads its
+    row's value and first entry (one gather of pairs from the B-wide
+    table), its neighbour `edge_nbr[entry]` and what the neighbour
+    holds; the candidate is the row's value plus the constant `add`,
+    nothing where the row holds `absent`; `edge_nbr` may be a shard's
+    block `[1, Ep]` (see `_block_at`).  Candidates that improve a
+    neighbour are folded into `values` by one `.at[].min` of C updates
+    (pads fall out of bounds and are dropped, the trash row of
+    `segment_reduce`), and the neighbours that improved, each once and
+    ascending, are the next list: a sort of the C targets, the first of
+    each run kept, and a second sort that moves those to the front.
+    `active` counts them: the rows a dense round of the same relaxation
+    would find changed.
+    Where `active` exceeds the list's places the list is cut short and
+    the caller's next round has to be a dense one."""
+    rows, cap = values.shape[0], front.shape[0]
+    slot = jnp.arange(entries, dtype=jnp.int32)
+    with jax.named_scope(FRONTIER_SCOPE):
+        upto = jnp.cumsum(count)
+        first = upto - count
+        opens = jnp.zeros((entries,), jnp.int32).at[
+            jnp.where(count > 0, first, entries)
+        ].max(jnp.arange(cap, dtype=jnp.int32), mode="drop")
+        owner = lax.cummax(opens)
+        live = slot < upto[-1]
+    with jax.named_scope("grape.pull.gather"):
+        held = _at(values, jnp.minimum(front, rows - 1))
+        pair = _at(jnp.stack([lo - first, held], axis=1), owner)
+        entry = jnp.where(live, pair[:, 0] + slot, 0)
+        nbr = _block_at(edge_nbr, entry)
+        cand = pair[:, 1] + add
+        if absent is not None:
+            live = jnp.logical_and(live, pair[:, 1] != absent)
+        old = _at(values, jnp.minimum(nbr, rows - 1))
+        target = jnp.where(
+            jnp.logical_and(live, cand < old), nbr, rows)
+    with jax.named_scope("grape.pull.fold"):
+        values = values.at[target].min(cand, mode="drop")
+    with jax.named_scope(FRONTIER_SCOPE):
+        hit = lax.sort(target, is_stable=False)
+        opener = jnp.logical_and(hit != _shift(hit, 1, -1), hit < rows)
+    with jax.named_scope("grape.app.update"):
+        active = opener.sum().astype(jnp.int32)
+    with jax.named_scope(FRONTIER_SCOPE):
+        front = lax.sort(jnp.where(opener, hit, rows), is_stable=False)[:cap]
+        if entries < cap:
+            front = jnp.pad(front, (0, cap - entries), constant_values=rows)
+    return values, front, active
+
+
+def frontier_rows(mask, cap: int):
+    """The first `cap` set rows of the V-wide `mask`, ascending, the
+    list padded with the row count: what a loop needs once, where it
+    turns from dense rounds to `frontier_relax`.
+
+    No V-wide gather, scatter or sort: the set rows are counted a tile
+    of SCAN_TILE rows at a time (dense), the tiles' running sum is
+    searched for each place of the list (`cap` binary searches of V /
+    128 sums), the tile found is read whole (one gather of `cap` rows of
+    the mask) and the place's row in it is where the tile's own running
+    count reaches what the tiles before it lack."""
+    rows = mask.shape[0]
+    with jax.named_scope(FRONTIER_SCOPE):
+        pad = -rows % SCAN_TILE
+        tiles = (jnp.pad(mask, (0, pad)) if pad else mask).reshape(
+            -1, SCAN_TILE).astype(jnp.int32)
+        upto = jnp.cumsum(tiles.sum(axis=1))
+        want = jnp.arange(1, cap + 1, dtype=jnp.int32)
+        tile = jnp.minimum(jnp.searchsorted(upto, want).astype(jnp.int32),
+                           upto.shape[0] - 1)
+        before = jnp.where(tile > 0, _at(upto, jnp.maximum(tile - 1, 0)), 0)
+        # a tile's running count by one product with a triangle of ones
+        # (exact: counts to 128 in f32); a `cumsum` along the lanes is
+        # a reduce-window that costs 1.9 MB of code here, and code is
+        # HBM (PERF.md section 6, PR 40)
+        upper = jnp.triu(jnp.ones((SCAN_TILE, SCAN_TILE), jnp.float32))
+        inside = jnp.dot(_at(tiles, tile).astype(jnp.float32), upper,
+                         precision=lax.Precision.HIGHEST)
+        lane = (inside < (want - before).astype(jnp.float32)[:, None]).sum(
+            axis=1)
+        return jnp.where(want <= upto[-1],
+                         tile * SCAN_TILE + lane.astype(jnp.int32),
+                         rows).astype(jnp.int32)

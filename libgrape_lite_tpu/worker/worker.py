@@ -51,11 +51,13 @@ _TERMINATE_SCOPE = "grape.worker.terminate"
 # vote, a terminate code), so the buckets sum to `rounds`;
 # `active_max` and `active_sum` are the widest round and all of them
 # (for BFS: the widest level, and the reached vertices less the
-# source).  A query through any other runner (pipelined, batched,
-# chunked, stepwise, host) leaves the initial values.
+# source).  `frontier_rounds` counts the rounds that followed their
+# frontier (`_frontier_loop`; 0 where the app offers no such round).
+# A query through any other runner (pipelined, batched, chunked,
+# stepwise, host) leaves the initial values.
 ROUND_STATS = _FedStats("rounds", {
     "app": "", "rounds": 0, "active_bits": [], "active_max": 0,
-    "active_sum": 0,
+    "active_sum": 0, "frontier_rounds": 0,
 })
 _RECORD_SCOPE = "grape.worker.record"
 _RECORD_BITS = 33  # bit lengths 0..32, one bucket each
@@ -82,6 +84,76 @@ def _note_round(record, active):
             lo,
             record[_RECORD_HI] + (lo < a).astype(jnp.uint32),
         )
+
+
+def _frontier_loop(app, frag_stacked, inceval, cond, st, active, budget):
+    """`_make_runner`'s loop for an app that offers a round that follows
+    its frontier (app/base.py): `(state, active, rounds, record)`, the
+    record one word longer, the rounds that took that round; `cond` is
+    the plain loop's own.
+
+    The carry holds, beside the state, the list of the rows whose
+    proposals are pending (`int32[B]`, padded with `vp`) and its length;
+    after a round the length is the round's vote, and a list is whole
+    only where its length fits B.  A round reads where the listed rows'
+    entries lie (B-wide) and takes `inceval_frontier` where the length
+    fits B and the entries C; else `inceval(frag, state)`, today's dense
+    round, and after it, where the vote fits B, one V-wide compaction
+    of the rows it improved into the list.  The arms of a `cond` share
+    their temporaries' room; what is carried is B words and scalars.
+    The dense arm squeezes the fragment's blocks for itself and the
+    other round reads them as they come, `[1, Ep]`: on the chip the
+    squeeze is a copy into another tiling, and squeezed once before the
+    loop the copies a dense round reads stand in HBM for the whole loop
+    beside rounds that read a few thousand entries of them."""
+    from libgrape_lite_tpu.ops.segment import (
+        FRONTIER_SCOPE, frontier_rows, frontier_spans,
+    )
+
+    rows, entries = budget
+    row_ptr = app.frontier_csr(frag_stacked).indptr
+    # the first list, from the state PEval returned: a query's source,
+    # the one row that holds a value.  A state with more pending rows
+    # (an incremental query's seeds) starts with a dense round, whose
+    # compaction is the loop's one copy of that code, and code counts:
+    # a runner's megabytes are HBM at the peak like its state (PERF.md
+    # section 6, PR 37 and PR 40)
+    pending = app.frontier_mask(st)
+    with jax.named_scope(FRONTIER_SCOPE):
+        held = pending.sum().astype(jnp.int32)
+        n0 = jnp.where(held <= 1, held, jnp.int32(rows + 1))
+        front0 = jnp.full((rows,), pending.shape[0], jnp.int32).at[0].set(
+            jnp.where(held == 1, jnp.argmax(pending).astype(jnp.int32),
+                      pending.shape[0]))
+
+    def sparse(s, front, lo, count):
+        return app.inceval_frontier(frag_stacked, s, front, lo, count)
+
+    def dense(s, front, lo, count):
+        s2, a2 = inceval(frag_stacked.local(), s)
+        front2 = lax.cond(
+            a2 <= rows,
+            lambda: frontier_rows(app.frontier_mask(s, s2), rows),
+            lambda: front,
+        )
+        return s2, a2, front2
+
+    def body(carry):
+        s, _, r, rec, front, n, took = carry
+        lo, count, total = frontier_spans(front, row_ptr)
+        fits = jnp.logical_and(n <= rows, total <= entries)
+        s2, a2, front2 = lax.cond(fits, sparse, dense, s, front, lo, count)
+        with jax.named_scope(_RECORD_SCOPE):
+            took = took + fits.astype(jnp.uint32)
+        return (s2, a2, r + jnp.int32(1), _note_round(rec, a2), front2, a2,
+                took)
+
+    st, active, rounds, record, _, _, took = lax.while_loop(
+        cond, body,
+        (st, jnp.int32(active), jnp.int32(0),
+         (jnp.uint32(0),) * _RECORD_WORDS, front0, n0, jnp.uint32(0)),
+    )
+    return st, active, rounds, (*record, took)
 
 
 def _squeeze_state(state, squeezed):
@@ -460,26 +532,34 @@ class Worker:
             limit = jnp.int32(max_rounds if max_rounds > 0 else _INT32_MAX)
 
             def cond(carry):
-                _, act, r, _ = carry
+                _, act, r, *_ = carry
                 with jax.named_scope(_TERMINATE_SCOPE):
                     return jnp.logical_and(act > 0, r < limit)
 
+            def inceval(frag, s):
+                s2, a2 = app.inceval(ctx, frag, {**s, **eph_vals})
+                return strip(s2), jnp.int32(a2)
+
             def body(carry):
                 s, _, r, rec = carry
-                s2, a2 = app.inceval(ctx, frag, {**s, **eph_vals})
-                a2 = jnp.int32(a2)
-                return strip(s2), a2, r + jnp.int32(1), _note_round(rec, a2)
+                s2, a2 = inceval(frag, s)
+                return s2, a2, r + jnp.int32(1), _note_round(rec, a2)
 
             # the record of the rounds' votes (ROUND_STATS) rides in the
             # loop's carry, not in an app's state: any app's `active`
             # is recorded, and the apps' own states, which the batched,
             # pipelined, incremental and checkpointed paths share, stay
             # as they were
-            st, active, rounds, record = lax.while_loop(
-                cond, body,
-                (st, jnp.int32(active), jnp.int32(0),
-                 (jnp.uint32(0),) * _RECORD_WORDS),
-            )
+            budget = app.frontier_budget
+            if budget is None:
+                st, active, rounds, record = lax.while_loop(
+                    cond, body,
+                    (st, jnp.int32(active), jnp.int32(0),
+                     (jnp.uint32(0),) * _RECORD_WORDS),
+                )
+            else:
+                st, active, rounds, record = _frontier_loop(
+                    app, frag_stacked, inceval, cond, st, active, budget)
             return (_unsqueeze_state(st, squeezed), rounds, active,
                     jnp.stack(record))
 
@@ -1356,9 +1436,19 @@ class Worker:
         try:
             with tr.span("query", mode="fused",
                          app=type(app).__name__) as sp:
+                def place(state):
+                    # a worker answers for its newest query: once this
+                    # one has a state to place, the last one's result
+                    # goes, or every query holds two states in HBM at
+                    # its peak.  A query refused before that, in
+                    # `init_state`, leaves the last answer standing;
+                    # one that fails from here on leaves none
+                    self._result_state = self._round_record = None
+                    return self._place_state(state)
+
                 runner, carry, eph_part, _ = self._staged(
                     lambda: self._seeded(app.init_state(frag, **query_args)),
-                    self._place_state,
+                    place,
                     lambda st: self._runner_for(mr, st),
                 )
                 if tr.enabled and self._pipelined() is not None:
@@ -2507,6 +2597,8 @@ class Worker:
             active_bits=words[:_RECORD_BITS].tolist(),
             active_max=int(words[_RECORD_MAX]),
             active_sum=int(words[_RECORD_HI] << 32 | words[_RECORD_LO]),
+            # one word more from the loop that can follow its frontier
+            frontier_rounds=int(words[_RECORD_WORDS:].sum()),
         )
 
     def output(self, prefix: str) -> None:

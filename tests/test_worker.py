@@ -206,3 +206,36 @@ def test_put_global_matches_device_put():
     r = put_global(np.float32(3.5), NamedSharding(comm.mesh, P()))
     assert float(r) == 3.5
     assert put_global(None, sh) is None
+
+
+@pytest.mark.parametrize("fails_in", ["init_state", "runner"])
+def test_a_failed_query_and_the_last_answer(fails_in, monkeypatch):
+    """A worker lets go of the last query's result once the next query has
+    a state to place (two states in HBM at every query's peak otherwise):
+    a query refused before that, in `init_state`, leaves the last answer
+    standing; one that fails later leaves none, and says so."""
+    from libgrape_lite_tpu.models import BFS
+    from libgrape_lite_tpu.worker.worker import ROUND_STATS, Worker
+
+    rng = np.random.default_rng(0)
+    src, dst = rng.integers(0, 64, 256), rng.integers(0, 64, 256)
+    w = Worker(BFS(), build_fragment(src, dst, None, 64, 1))
+    w.query(source=3)
+    last, rounds = w.result_values().copy(), ROUND_STATS["rounds"]
+    if fails_in == "init_state":
+        with pytest.raises(TypeError):
+            w.query(sauce=3)
+        assert (w.result_values() == last).all()
+        assert ROUND_STATS["rounds"] == rounds == w.rounds
+    else:
+        def refuse(*_):
+            raise MemoryError("no room for the runner")
+
+        monkeypatch.setattr(w, "_runner_for", refuse)
+        with pytest.raises(MemoryError):
+            w.query(source=5)
+        with pytest.raises(RuntimeError, match="query"):
+            w.result_values()
+        monkeypatch.undo()
+    w.query(source=5)
+    assert (w.result_values() != last).any()
