@@ -16,12 +16,15 @@ scan.  So where the rows are a CSR's, sorted and with their offsets at
 hand, the fold is a scan written out in dense XLA: `_segmented_scan`
 over tiles of 128 places, then one V-wide gather of the row ends
 (0.83 ns an element at scale 21).  Everything else keeps the scatter:
-ids that are not sorted, streams without offsets (the dyn overlay, the
-pull apps' pipelined boundary and interior slices), and the query lanes
-of an exact fold under `jax.vmap`, whose gather XLA fuses into the
-scatter.  A float sum's lanes scan, as their single queries do: its bits
-depend on the grouping, and a lane answers with its single query's
-bytes.  CDLP's count is a scan as well, and never a scatter:
+ids that are not sorted and streams without offsets (the dyn overlay,
+the pull apps' pipelined boundary and interior slices).  Query lanes
+under `jax.vmap` (the batched runners) fold as their single queries
+do, one lane after another, wherever those queries' gather is the
+kernel below; where it is not, the lanes of an exact fold keep the
+scatter, into which XLA fuses their gather, and a float sum's lanes
+scan, because its bits depend on the grouping and a lane answers with
+its single query's bytes.  CDLP's count is a scan as well, and never a
+scatter:
 `run_position` and `segment_top_label` work on the (row, label) pairs
 its sort has just put in order, with the CSR's offsets where the whole
 CSR is folded and offsets found by a binary search of the sorted rows
@@ -35,12 +38,15 @@ or less.  So on the TPU backend a 1-D 32-bit table that fits half of
 VMEM is gathered by `ops/pallas_kernels.vmem_gather`, which keeps the
 table in VMEM for the length of the call and streams the indices
 through it (0.80 ns an index).  Everything else keeps `full[nbr]`:
-other backends, 64-bit tables, tables of rows, tables over the budget,
-and the query lanes of a batched call under `jax.vmap`, whose gather
-XLA fuses into their scatter.  What the choice observes is the
-backend, the arguments' shapes and dtypes and the device's VMEM size;
-no option selects it.  GATHER_STATS counts which gather each call
-took, as FOLD_STATS does for the fold (docs/OBSERVABILITY.md).
+other backends, 64-bit tables, tables of rows, tables over the budget.
+The query lanes of a batched call under `jax.vmap` take what a lane's
+single call takes: the kernel, once a lane, where the lanes share
+their indices (every caller's do), so that a lane runs its single
+query's pull, gather and fold (`_kernel_table` is the one predicate
+both `vmap` rules rest on).  What the choice observes is the backend,
+the arguments' shapes and dtypes and the device's VMEM size; no option
+selects it.  GATHER_STATS counts which gather each call took, as
+FOLD_STATS does for the fold (docs/OBSERVABILITY.md).
 
 Beside the pull, at the end of the module, a round that touches
 neither every entry nor every row: `frontier_relax` pushes from a list
@@ -86,16 +92,26 @@ def _recount(stats, took: list, to: str) -> None:
         took[0] = to
 
 
+def _kernel_table(dtype, rows: int) -> bool:
+    """Whether a 1-D table of `rows` values of `dtype` is one
+    `pallas_kernels.vmem_gather` keeps in VMEM: the TPU backend, 32-bit
+    values, the kernel's VMEM budget.  A single call's gather and both
+    `vmap` rules (the gather's and the fold's) rest on it; everything
+    in it is read off shapes, dtypes and the backend at trace time."""
+    return (
+        use_pallas() and jnp.dtype(dtype).itemsize == 4
+        and 0 < rows * 4 <= gather_table_budget()
+    )
+
+
 def _kernel_gathers(full, nbr) -> bool:
     """Whether `full[nbr]` goes through `pallas_kernels.vmem_gather`:
-    on the TPU backend, for a 1-D 32-bit table that fits the kernel's
-    VMEM budget and a 1-D int32 stream.  Everything here is read off
-    the arguments and the backend at trace time."""
+    a 1-D table the kernel takes (`_kernel_table`) and a 1-D int32
+    stream."""
     return (
-        use_pallas()
-        and full.ndim == 1 and nbr.ndim == 1 and nbr.shape[0] > 0
-        and full.dtype.itemsize == 4 and nbr.dtype == jnp.int32
-        and 0 < full.size * 4 <= gather_table_budget()
+        full.ndim == 1 and nbr.ndim == 1 and nbr.shape[0] > 0
+        and nbr.dtype == jnp.int32
+        and _kernel_table(full.dtype, full.size)
     )
 
 
@@ -112,13 +128,21 @@ def _kernel_gather():
 
     @gather.def_vmap
     def lanes(axis_size, in_batched, full, nbr):
-        # Query lanes (the batched runner) keep XLA's gather: it writes
-        # all lanes of an entry at once and XLA fuses it into the
-        # lanes' scatter fold; a standalone [Ep, lanes] block is 4.4 GB
-        # at four lanes and Ep 8.4M (PERF.md section 6, PR 25 (3))
-        _recount(GATHER_STATS, took, "xla")
-        axes = tuple(0 if b else None for b in in_batched)
-        return jax.vmap(lambda f, i: f[i], in_axes=axes)(full, nbr), True
+        if in_batched[1]:
+            # lanes that bring their own indices (no caller does) keep
+            # XLA's gather, lane by lane
+            _recount(GATHER_STATS, took, "xla")
+            axes = tuple(0 if b else None for b in in_batched)
+            return jax.vmap(lambda f, i: f[i], in_axes=axes)(full, nbr), True
+        # Query lanes over one CSR (the batched runner): the single
+        # query's kernel, one lane after another.  A lane's table is
+        # the 1-D one `_kernel_gathers` saw, resident in VMEM for its
+        # call, and its candidates are one dense row of the
+        # `[lanes, Ep]` block; XLA's own vmapped gather writes that
+        # block lanes minor, padded 32-fold (PERF.md section 6, PR 25
+        # (3)).  One traced body in a loop, not a copy a lane: code is
+        # HBM (PR 40).  The call stays `kernel` in GATHER_STATS.
+        return lax.map(lambda f: gather(f, nbr), full), True
 
     return gather
 
@@ -132,9 +156,11 @@ def pull_gather(full, nbr, mask=None, fill=None, add=None, absent=None):
     nothing.
 
     Two gathers, chosen by what the call can see (`_kernel_gathers`):
-    the Pallas kernel that keeps the table in VMEM, or XLA's gather,
-    which is also what query lanes under `jax.vmap` go back to.  Both
-    move the same bits.  GATHER_STATS counts which one a call took."""
+    the Pallas kernel that keeps the table in VMEM, or XLA's gather.
+    Query lanes under `jax.vmap` that share `nbr` take the kernel one
+    lane after another; lanes with indices of their own take XLA's.
+    Both move the same bits.  GATHER_STATS counts which one a call
+    took."""
     with jax.named_scope("grape.pull.gather"):
         if _kernel_gathers(full, nbr):
             vals = _kernel_gather()(full, nbr)
@@ -281,7 +307,25 @@ def _grouping_shows(kind: str, dtype) -> bool:
 def _scan_fold(num_rows: int, kind: str):
     """The fold of one `segment_reduce` call that came with offsets:
     the scan, with its own rule under `jax.vmap`.  Made anew for each
-    call, because it carries the call's entry in FOLD_STATS."""
+    call, because it carries the call's entry in FOLD_STATS.
+
+    Under `jax.vmap` (query lanes over one CSR) the rule asks the
+    question the gather's rule answered, of what it can see itself:
+    `_kernel_table` of the values' dtype and `num_rows`, which on one
+    fragment is the length of the table the lanes' candidates were
+    gathered from.  Where it holds the lanes' gather was the kernel,
+    their candidates stand lanes major, and each lane folds by its
+    single query's scan.  Where it does not, XLA's vmapped gather
+    feeds the fold, and an exact fold keeps the scatter XLA fuses that
+    gather into: ahead of a scan its `[Ep, lanes]` block would stand in
+    memory lanes minor, padded 32-fold.  The edge the fold cannot see:
+    on several fragments the gathered table is `fnum * vp` long (or
+    the mirror exchange's compact one), so where `vp * 4 <= budget <
+    full.size * 4` the gather is XLA's and the lanes scan behind it
+    all the same, PR 25's measured loss (249.5 ms a four-lane pull for
+    the scatter's 122.3, and 4.4 GB of block at Ep 8.4M; PERF.md
+    section 6).  That takes more than 16M vertices in all on a v5e;
+    no cell and no test holds such a graph."""
     took = ["scan"]
     FOLD_STATS["scan"] += 1
 
@@ -294,6 +338,12 @@ def _scan_fold(num_rows: int, kind: str):
         if in_batched[1] or in_batched[2]:
             raise NotImplementedError(
                 "segment_reduce: lanes share segment_ids and row_ptr")
+        if _kernel_table(values.dtype, num_rows):
+            # one lane after another, as the gather's rule wrote them:
+            # each lane runs its single query's operations on a dense
+            # row of the `[lanes, Ep]` block, whatever the fold's kind
+            return lax.map(
+                lambda v: fold(v, segment_ids, row_ptr), values), True
         if _grouping_shows(kind, values.dtype):
             # a float sum's lanes scan too, each with the arithmetic of
             # its own single query, so that a lane keeps that query's
@@ -302,13 +352,9 @@ def _scan_fold(num_rows: int, kind: str):
                 lambda v: _scan_rows(v, segment_ids, row_ptr, num_rows,
                                      kind)
             )(values), True
-        # Exact folds keep the scatter for query lanes over one CSR
-        # (the batched runner).  XLA gathers all lanes of an entry at
-        # once and fuses that into the scatter; ahead of a scan the
-        # gathered [Ep, lanes] block has to stand in memory, lanes
-        # minor and padded to 128: 4.4 GB where the scatter's round
-        # holds 0.27 (four lanes, Ep 8.4M; the chip's compiler,
-        # PERF.md section 6)
+        # no kernel for these lanes' tables (other backends, 64-bit
+        # values, tables over the budget): XLA gathers all lanes of an
+        # entry at once and fuses that into the scatter
         _recount(FOLD_STATS, took, "scatter")
         return jax.vmap(
             lambda v: _scatter_fold(v, segment_ids, num_rows, kind, True)
@@ -334,10 +380,11 @@ def segment_reduce(values, segment_ids, num_rows: int, kind: str = "sum",
     either way.  Without it the fold is `jax.ops.segment_*`, a
     scatter, for ids that are not sorted (`sorted_ids=False`) or that
     come without offsets.  Under `jax.vmap` (query lanes over one CSR)
-    a fold that is exact under any grouping goes back to the scatter,
-    and a float sum scans lane by lane as its single query does, so a
-    lane's answer has that query's bytes either way (see `_scan_fold`).
-    FOLD_STATS counts which fold a call took.
+    the lanes scan one after another where their gather was the
+    kernel's; elsewhere a fold that is exact under any grouping goes
+    back to the scatter and a float sum scans as its single query
+    does, so a lane's answer has that query's bytes every way (see
+    `_scan_fold`).  FOLD_STATS counts which fold a call took.
     """
     if row_ptr is not None and not sorted_ids:
         raise ValueError(

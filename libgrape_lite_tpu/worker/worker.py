@@ -1187,9 +1187,16 @@ class Worker:
         try:
             with tr.span("query", mode="batched",
                          app=type(app).__name__, batch=batch) as sp:
+                def place(state):
+                    # as `query` does: the last batch's result goes
+                    # once this one has a state to place, or every
+                    # batch holds two batched states in HBM at its peak
+                    self._result_state = None
+                    return self._place_state_batch(state)
+
                 runner, carry, eph_part, _ = self._staged(
                     lambda: app.init_state_batch(frag, args_list),
-                    self._place_state_batch,
+                    place,
                     lambda st: self._batched_runner_for(mr, batch, st),
                 )
                 with tr.span("worker.enqueue"):

@@ -1,13 +1,16 @@
 """The pull's gather by kernel or by XLA (`ops/segment.pull_gather`).
 
 On the TPU backend a 1-D 32-bit table that fits the VMEM budget is
-gathered by `ops/pallas_kernels.vmem_gather`; everything else, and the
-query lanes of a batched call under `jax.vmap`, by XLA's `full[nbr]`.
+gathered by `ops/pallas_kernels.vmem_gather`; everything else by XLA's
+`full[nbr]`.  Query lanes of a batched call under `jax.vmap` take what
+their single call takes: the kernel, one lane after another, where the
+lanes share their indices, `full[nbr]` everywhere else.
 Pinned here: the kernel (interpret mode) bit-equal to `full[nbr]` over
 the dtypes, table lengths and stream lengths it meets, on every kind of
 int32 index; the choice, through the trace-time counter
-`GATHER_STATS`; the `vmap` rule (lanes lower to the text they lowered
-to before, answer with their single calls' bytes and count once).
+`GATHER_STATS`; the `vmap` rule (kernel lanes are handed a lane's 1-D
+table each and answer with their single calls' bytes; every other lane
+lowers to the text it lowered to before; a call counts once).
 Whole queries through the kernel, app by app, are in
 tests/test_pull_gather_apps.py (a file of its own so that another
 worker takes it).  The choice reads the backend, so the tests steer it
@@ -173,42 +176,126 @@ def test_what_follows_the_gather_is_the_same(args, on_tpu, monkeypatch):
 # ---- query lanes ----------------------------------------------------------
 
 
-@pytest.mark.parametrize("dtype", ["float32", "int32"])
-def test_lanes_take_xla(dtype, on_tpu, monkeypatch):
-    """Under `jax.vmap` the batched call goes back to `full[nbr]`: the
-    lowered text is the one the lanes had before (XLA fuses their
-    gather into their fold), each lane has its single call's bytes,
-    and the call counts once, as `xla`."""
-    rng = np.random.default_rng(5)
-    full = jnp.asarray(rng.integers(0, 99, (4, 640)).astype(dtype))
-    nbr = jnp.asarray(rng.integers(0, 640, 1024).astype(np.int32))
-    mask = jnp.asarray(rng.integers(0, 2, 1024).astype(bool))
+def _lane_args(dtype, lanes=4, v=640, n=1024, seed=5):
+    rng = np.random.default_rng(seed)
+    full = jnp.asarray(rng.integers(0, 99, (lanes, v)).astype(dtype))
+    nbr = jnp.asarray(rng.integers(0, v, n).astype(np.int32))
+    mask = jnp.asarray(rng.integers(0, 2, n).astype(bool))
+    return full, nbr, mask
+
+
+@pytest.mark.parametrize("dtype,kind", [
+    ("float32", "stand_in"), ("int32", "stand_in"),
+    ("int32", "interpreted"),
+])
+def test_lanes_take_the_kernel_lane_by_lane(dtype, kind, pull_kernel):
+    """Under `jax.vmap` lanes that share their indices take the single
+    call's kernel one after another: every call of it is handed one
+    lane's 1-D table and the shared stream, the loop is one traced
+    body, the call stays `kernel` in GATHER_STATS, and each lane has
+    its single call's bytes."""
+    calls = pull_kernel(kind)
+    full, nbr, mask = _lane_args(dtype)
 
     def one(f):
         return pull_gather(f, nbr, mask, jnp.asarray(0, f.dtype), add=1)
 
     took = gather_took(lambda: jax.jit(jax.vmap(one)).lower(full))
-    assert took == {"kernel": 0, "xla": 1}
+    assert took == {"kernel": 1, "xla": 0}
+    assert calls and set(calls) == {(dtype, (640,), (1024,))}
     text = jax.jit(jax.vmap(one)).lower(full).as_text()
-    monkeypatch.setattr(segment, "use_pallas", lambda: False)
-    assert text == jax.jit(jax.vmap(one)).lower(full).as_text()
+    assert "stablehlo.while" in text  # a loop over the lanes, not four copies
     got = np.asarray(jax.jit(jax.vmap(one))(full))
-    want = np.stack([np.asarray(one(f)) for f in full])
-    assert got.tobytes() == want.tobytes()
+    want = np.stack([np.asarray(jax.jit(one)(f)) for f in full])
+    assert got.dtype == want.dtype and got.tobytes() == want.tobytes()
 
 
-def test_lanes_rule_moves_the_count_once(on_tpu):
-    """A loop's batching rule may run the `vmap` rule again for one
-    call: the entry moves from `kernel` to `xla` once."""
-    full = jnp.zeros((4, 640), jnp.float32)
-    nbr = jnp.zeros((1024,), jnp.int32)
+def _parent_lanes(full, nbr, mask):
+    """The batched call as the parent lowered it: `full[nbr]` under
+    `jax.vmap`, for XLA to fuse into the lanes' fold."""
+    axes = tuple(0 if x.ndim == 2 else None for x in (full, nbr))
 
-    def rounds(f):
-        return jax.lax.fori_loop(
-            0, 3, lambda _, x: x + pull_gather(x, nbr)[:640], f)
+    def one(f, i):
+        with jax.named_scope("grape.pull.gather"):
+            return jnp.where(mask, f[i] + 1, jnp.asarray(0, f.dtype))
 
-    took = gather_took(lambda: jax.jit(jax.vmap(rounds)).lower(full))
+    return jax.vmap(one, in_axes=axes)(full, nbr)
+
+
+# name: (table dtype, lanes bring their own indices, armed, budget)
+XLA_LANES = {
+    "own_indices": ("float32", True, True, BUDGET),
+    "own_indices_shared_table": ("int32", "only", True, BUDGET),
+    "f64": ("float64", False, True, BUDGET),
+    "s64": ("int64", False, True, BUDGET),
+    "over_the_budget": ("float32", False, True, 640 * 4 - 1),
+    "off_tpu": ("float32", False, False, BUDGET),
+}
+
+
+@pytest.mark.parametrize("name", sorted(XLA_LANES))
+def test_lanes_that_keep_xla_lower_to_the_parents_text(name, pull_kernel,
+                                                       monkeypatch):
+    """Lanes with their own indices, 64-bit tables, tables over the
+    budget and every other backend: `full[nbr]` under `jax.vmap`, to
+    the lowered text, counted once as `xla`, the kernel never called."""
+    dtype, own, armed, budget = XLA_LANES[name]
+    if armed:
+        calls = pull_kernel("stand_in")
+        monkeypatch.setattr(segment, "gather_table_budget", lambda: budget)
+    else:
+        calls = []
+        monkeypatch.setattr(
+            segment, "vmem_gather",
+            lambda *a: pytest.fail("the kernel off the TPU backend"))
+    full, nbr, mask = _lane_args(dtype)
+    if own:
+        nbr = jnp.stack([nbr, nbr[::-1], (nbr + 1) % 640, nbr])
+    if own == "only":
+        full = full[0]
+    axes = tuple(0 if x.ndim == 2 else None for x in (full, nbr))
+
+    def lanes(full, nbr, mask):
+        return jax.vmap(
+            lambda f, i: pull_gather(f, i, mask, jnp.asarray(0, f.dtype),
+                                     add=1),
+            in_axes=axes)(full, nbr)
+
+    took = gather_took(
+        lambda: jax.jit(lanes).lower(full, nbr, mask))
     assert took == {"kernel": 0, "xla": 1}
+    # (where the single call chooses the kernel, `custom_vmap` traces
+    # it once on the lane's shapes before the rule is asked)
+    assert len(calls) == bool(own)
+
+    def parent(full, nbr, mask):
+        return _parent_lanes(full, nbr, mask)
+
+    parent.__name__ = parent.__qualname__ = "lanes"
+    assert (jax.jit(lanes).lower(full, nbr, mask).as_text()
+            == jax.jit(parent).lower(full, nbr, mask).as_text())
+    got = np.asarray(jax.jit(lanes)(full, nbr, mask))
+    assert got.tobytes() == np.asarray(parent(full, nbr, mask)).tobytes()
+
+
+@pytest.mark.parametrize("own,want", [
+    (False, {"kernel": 1, "xla": 0}), (True, {"kernel": 0, "xla": 1}),
+], ids=["shared_indices", "own_indices"])
+def test_lanes_rule_moves_the_count_once(own, want, on_tpu):
+    """A loop's batching rule may run the `vmap` rule again for one
+    call: lanes that share their indices stay `kernel` however often
+    it runs, and lanes with their own move to `xla` once."""
+    full = jnp.zeros((4, 640), jnp.float32)
+    nbr = jnp.zeros((4, 1024) if own else (1024,), jnp.int32)
+
+    def rounds(f, i):
+        return jax.lax.fori_loop(
+            0, 3, lambda _, x: x + pull_gather(x, i)[:640], f)
+
+    took = gather_took(lambda: jax.jit(jax.vmap(
+        rounds, in_axes=(0, 0 if own else None))).lower(full, nbr))
+    assert took == want
+    assert set(on_tpu) == {("float32", (640,), (1024,))}
 
 
 def test_batched_indices_take_xla(on_tpu):

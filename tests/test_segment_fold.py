@@ -2,11 +2,15 @@
 
 Handed a CSR's row offsets, the fold is a tile-segmented scan and one
 gather of the row ends; without them it is `jax.ops.segment_*`, as it
-always was, and as it stays for the query lanes of an exact fold under
-`jax.vmap`.  Pinned here: the scan against the scatter (byte-equal for
-integers, min and max; float sums against an f64 NumPy fold) over the
-shapes a CSR takes; that exact lanes take the scatter and a float
-sum's lanes the scan, bit for bit their single calls; that `row_ptr`
+always was.  Under `jax.vmap` the query lanes of a fold scan one lane
+after another where their gather was the kernel's (the TPU backend,
+32-bit values, a table within the kernel's budget); elsewhere an exact
+fold's lanes keep the scatter XLA fuses their gather into and a float
+sum's lanes scan.  Pinned here: the scan against the scatter
+(byte-equal for integers, min and max; float sums against an f64 NumPy
+fold) over the shapes a CSR takes; which fold lanes take, armed and
+not, bit for bit their single calls; that lanes the kernel does not
+serve lower to the text they lowered to before; that `row_ptr`
 with unsorted ids is refused; that a call without
 `row_ptr` lowers to the text it lowered to before; and, through the
 trace-time counter `FOLD_STATS`, which fold each app's round takes,
@@ -71,10 +75,15 @@ def _values(kind: str, dtype: str, shape, seed: int):
     return rng.uniform(1.0, 100.0, shape).astype(dtype)
 
 
-@pytest.mark.parametrize("lanes", [None, 4], ids=["single", "vmap4"])
+@pytest.mark.parametrize("lanes,armed", [(None, False), (4, False),
+                                         (4, True)],
+                         ids=["single", "vmap4", "vmap4_armed"])
 @pytest.mark.parametrize("shape", sorted(SHAPES))
 @pytest.mark.parametrize("kind,dtype", FOLDS)
-def test_scan_fold_matches_the_scatter(kind, dtype, shape, lanes):
+def test_scan_fold_matches_the_scatter(kind, dtype, shape, lanes, armed,
+                                       pull_kernel):
+    if armed:  # as on the TPU backend: 32-bit lanes scan one by one
+        pull_kernel("stand_in")
     rows, ptr, ids = _csr(shape)
     ep = ids.shape[0]
     vals = _values(kind, dtype, (ep,) if lanes is None else (lanes, ep),
@@ -93,13 +102,14 @@ def test_scan_fold_matches_the_scatter(kind, dtype, shape, lanes):
     float_sum = kind == "sum" and dtype != "int32"
     before = FOLD_STATS.snapshot()
     got = np.asarray(jax.jit(scan)(vals))
-    # lanes over one CSR keep the scatter where the fold is exact; a
-    # float sum's lanes scan
-    took = "scan" if lanes is None or float_sum else "scatter"
+    # lanes over one CSR scan where their gather is the kernel's; where
+    # it is not, an exact fold keeps the scatter and a float sum scans
+    lanes_scan = float_sum or (armed and dtype != "float64")
+    took = "scan" if lanes is None or lanes_scan else "scatter"
     assert FOLD_STATS.snapshot() == {**before, took: before[took] + 1}
     want = np.asarray(jax.jit(scatter)(vals))
     assert got.dtype == want.dtype and got.shape == want.shape
-    if float_sum and lanes is not None:
+    if lanes_scan and lanes is not None:
         # each lane with the bytes of its own single call
         one = jax.jit(scan_one)
         assert got.tobytes() == np.stack(
@@ -175,20 +185,103 @@ def test_whole_csr_pulls_fold_by_scan(app, fnum, graph_cache):
     assert _folds_traced(w, **QUERY[app]) == {"scan": 1, "scatter": 0}
 
 
-@pytest.mark.parametrize("app,took", [
-    ("sssp", "scatter"), ("bfs", "scatter"), ("pagerank", "scan"),
-])
-def test_query_lanes_fold_by_kind(app, took, graph_cache):
-    """The batched runner's lanes share one CSR under `jax.vmap`.  An
-    exact fold keeps the scatter XLA fuses their gather into; a float
-    sum scans, as each lane's single query does (tests/test_serve.py
-    pins the bytes)."""
-    w = Worker(APP_REGISTRY[app](), graph_cache(1))
+# name: (values dtype, armed, budget in bytes): lanes the kernel does
+# not serve (rows is 37 below: a table of 148 bytes)
+SCATTER_LANES = {
+    "off_tpu": ("float32", False, None),
+    "f64": ("float64", True, 64 << 20),
+    "s64": ("int64", True, 64 << 20),
+    "over_the_budget": ("int32", True, 37 * 4 - 1),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SCATTER_LANES))
+def test_lanes_the_kernel_does_not_serve_lower_as_before(name, pull_kernel,
+                                                         monkeypatch):
+    """Other backends, 64-bit values, tables over the budget: the lanes
+    of an exact pull lower to the parent's text, `full[nbr]` under
+    `jax.vmap` fused into the scatter."""
+    from libgrape_lite_tpu.ops import segment
+    from libgrape_lite_tpu.ops.segment import pull_gather
+
+    dtype, armed, budget = SCATTER_LANES[name]
+    if armed:
+        pull_kernel("stand_in")
+        monkeypatch.setattr(segment, "gather_table_budget", lambda: budget)
+    rows, ep = 37, 2 * T
+    big = (jnp.iinfo(dtype).max if jnp.issubdtype(dtype, jnp.integer)
+           else jnp.inf)
+
+    def parent(full, nbr, mask, ids, ptr):
+        def one(f):
+            with jax.named_scope("grape.pull.gather"):
+                cand = jnp.where(mask, f[nbr] + 1, jnp.asarray(big, f.dtype))
+            with jax.named_scope("grape.pull.fold"):
+                return jops.segment_min(
+                    cand, ids, num_segments=rows + 1,
+                    indices_are_sorted=True)[:rows]
+        return jax.vmap(one)(full)
+
+    def lanes(full, nbr, mask, ids, ptr):
+        def one(f):
+            cand = pull_gather(f, nbr, mask, jnp.asarray(big, f.dtype),
+                               add=1)
+            return segment_reduce(cand, ids, rows, "min", row_ptr=ptr)
+        return jax.vmap(one)(full)
+
+    parent.__name__ = parent.__qualname__ = "lanes"
+    args = (jax.ShapeDtypeStruct((4, rows), jnp.dtype(dtype)),
+            jax.ShapeDtypeStruct((ep,), jnp.int32),
+            jax.ShapeDtypeStruct((ep,), jnp.bool_),
+            jax.ShapeDtypeStruct((ep,), jnp.int32),
+            jax.ShapeDtypeStruct((rows + 1,), jnp.int32))
     before = FOLD_STATS.snapshot()
-    w.query_batch([{"source": s} for s in (6, 17, 5229, 31)])
+    text = jax.jit(lanes).lower(*args).as_text()
+    assert FOLD_STATS.snapshot() == {**before,
+                                     "scatter": before["scatter"] + 1}
+    assert text == jax.jit(parent).lower(*args).as_text()
+
+
+def _lanes_graph(app, graph, graph_cache):
+    from tests.conftest import rand_frag
+
+    if graph == "p2p_f64":  # this lane's x64: SSSP's state is f64 there
+        return graph_cache(1)
+    return rand_frag(1, weighted=app == "sssp")
+
+
+@pytest.mark.parametrize("app,graph,armed,took", [
+    ("sssp", "p2p_f64", False, "scatter"),
+    ("bfs", "p2p_f64", False, "scatter"),
+    ("pagerank", "p2p_f64", False, "scan"),
+    ("sssp", "rand_f32", False, "scatter"),
+    ("sssp", "rand_f32", True, "scan"),
+    ("bfs", "rand_f32", True, "scan"),
+    ("pagerank", "rand_f32", True, "scan"),
+    ("sssp", "p2p_f64", True, "scatter"),
+])
+def test_query_lanes_fold_by_kind(app, graph, armed, took, graph_cache,
+                                  pull_kernel):
+    """The batched runner's lanes share one CSR under `jax.vmap`.
+    Where a lane's single query gathers by the kernel (armed here as
+    the TPU backend arms it: 32-bit state) the lanes take that query's
+    pull one after another, kernel and scan.  Elsewhere an exact fold
+    keeps the scatter XLA fuses the lanes' gather into, and a float sum
+    scans, as each lane's single query does (tests/test_serve.py pins
+    the bytes)."""
+    from tests.conftest import gather_took
+
+    if armed:
+        pull_kernel("stand_in")
+    w = Worker(APP_REGISTRY[app](), _lanes_graph(app, graph, graph_cache))
+    before = FOLD_STATS.snapshot()
+    gathers = gather_took(lambda: w.query_batch(
+        [{"source": s} for s in (6, 17, 522, 31)]))
     after = FOLD_STATS.snapshot()
     assert {k: after[k] - before[k] for k in after} == {
         "scan": 0, "scatter": 0, took: 1}
+    kernel = armed and graph == "rand_f32"
+    assert gathers == {"kernel": int(kernel), "xla": int(not kernel)}
 
 
 class _NoDevice:

@@ -63,28 +63,71 @@ def test_batched_byte_identical_per_lane(graph_cache, app_name):
         ), f"{app_name} lane {b} (source {s}) diverged from sequential"
 
 
-@pytest.mark.parametrize("app_name", ["sssp", "bfs"])
-def test_batched_lanes_keep_xla_gather_on_a_tpu(app_name, pull_kernel):
-    """On a TPU backend a single query's pull takes the kernel and the
-    batched runner's lanes go back to XLA's gather (the `vmap` rule of
-    ops/segment.py), each lane with its single query's bytes."""
+# (fnum, exchange, path, kernel): the fused batched runner and the
+# guarded path's chunk runner, on one fragment and inside `shard_map`
+# on two, the mirror exchange's compact table included; one case
+# compiles the kernel itself, interpreted (tests/conftest.py)
+LANE_KERNEL_CASES = [
+    (1, "allgather", "fused", "stand_in"),
+    (1, "allgather", "chunked", "stand_in"),
+    (2, "allgather", "fused", "stand_in"),
+    (2, "mirror", "chunked", "stand_in"),
+]
+
+
+@pytest.mark.parametrize(
+    "app_name,fnum,exchange,path,kernel",
+    [(a, *c) for a in ("sssp", "bfs") for c in LANE_KERNEL_CASES]
+    + [("bfs", 1, "allgather", "fused", "interpreted")],
+    ids=lambda v: str(v))
+def test_batched_lanes_take_the_kernel_on_a_tpu(app_name, fnum, exchange,
+                                                path, kernel, pull_kernel,
+                                                monkeypatch):
+    """On a TPU backend a single query's pull takes the kernel, and so
+    do the batched runners' lanes, one lane after another, with the
+    single query's scan behind it (the `vmap` rules of ops/segment.py):
+    every call of the kernel is handed one lane's 1-D table, the lanes
+    converge raggedly under the freeze mask, and each has its single
+    query's bytes and rounds."""
     from libgrape_lite_tpu.models import APP_REGISTRY
+    from libgrape_lite_tpu.ops.segment import FOLD_STATS
     from libgrape_lite_tpu.worker.worker import Worker
     from tests.conftest import gather_took, rand_frag
 
-    frag = rand_frag(1)  # f32 weights: SSSP's state is the kernel's kind
-    sources = [0, 5, 17, 33]
-    pull_kernel("stand_in")
-    singles = []
+    if exchange == "mirror":
+        monkeypatch.setenv("GRAPE_EXCHANGE", "mirror")
+    else:
+        monkeypatch.delenv("GRAPE_EXCHANGE", raising=False)
+    # f32 weights: SSSP's state is the kernel's kind
+    frag = rand_frag(fnum, weighted=app_name == "sssp")
+    sources = [0, 5, 17, 999999]  # the last one absent: one round
+    calls = pull_kernel("stand_in")  # the single queries' bits are XLA's
+    singles, rounds = [], []
     for s in sources:
         w = Worker(APP_REGISTRY[app_name](), frag)
         took = gather_took(lambda: w.query(source=s))
         assert took == {"kernel": 1, "xla": 0}
         singles.append(w.result_values().tobytes())
+        rounds.append(w.rounds)
+    tables = set(c[1] for c in calls)
+    assert len(tables) == 1 and len(next(iter(tables))) == 1
+    if kernel == "interpreted":
+        calls = pull_kernel("interpreted")
+    else:
+        del calls[:]
     w = Worker(APP_REGISTRY[app_name](), frag)
-    took = gather_took(
-        lambda: w.query_batch([{"source": s} for s in sources]))
-    assert took == {"kernel": 0, "xla": 1}
+    folds = FOLD_STATS.snapshot()
+    took = gather_took(lambda: w.query_batch(
+        [{"source": s} for s in sources],
+        guard="halt" if path == "chunked" else None))
+    # the chunk runner is lowered beside a batched PEval step
+    assert took["xla"] == 0 and took["kernel"] >= 1
+    assert FOLD_STATS["scatter"] == folds["scatter"]
+    assert FOLD_STATS["scan"] > folds["scan"]
+    # a lane's table is its single query's
+    assert calls and set(c[1] for c in calls) == tables
+    assert [int(r) for r in w.batch_rounds] == rounds
+    assert len(set(rounds)) >= 2
     for b, want in enumerate(singles):
         assert w.batch_result_values(b).tobytes() == want
 

@@ -239,3 +239,34 @@ def test_a_failed_query_and_the_last_answer(fails_in, monkeypatch):
         monkeypatch.undo()
     w.query(source=5)
     assert (w.result_values() != last).any()
+
+
+@pytest.mark.parametrize("fails_in", ["init_state", "runner"])
+def test_a_failed_batch_and_the_last_batch(fails_in, monkeypatch):
+    """`query_batch` lets go of the last batch's result as `query` does of
+    the last query's: once the next batch has a state to place, not
+    before."""
+    from libgrape_lite_tpu.models import BFS
+    from libgrape_lite_tpu.worker.worker import Worker
+
+    rng = np.random.default_rng(0)
+    src, dst = rng.integers(0, 64, 256), rng.integers(0, 64, 256)
+    w = Worker(BFS(), build_fragment(src, dst, None, 64, 1))
+    w.query_batch([{"source": 3}, {"source": 4}])
+    last = w.batch_result_values(1).copy()
+    if fails_in == "init_state":
+        with pytest.raises(TypeError):
+            w.query_batch([{"sauce": 3}, {"sauce": 4}])
+        assert (w.batch_result_values(1) == last).all()
+    else:
+        def refuse(*_):
+            raise MemoryError("no room for the runner")
+
+        monkeypatch.setattr(w, "_batched_runner_for", refuse)
+        with pytest.raises(MemoryError):
+            w.query_batch([{"source": 5}, {"source": 6}])
+        with pytest.raises(RuntimeError, match="query_batch"):
+            w.batch_result_values(1)
+        monkeypatch.undo()
+    w.query_batch([{"source": 5}, {"source": 6}])
+    assert (w.batch_result_values(1) != last).any()
