@@ -40,8 +40,8 @@ is the per-query byte-identity verdict W=4 vs W=1 (a break exits 2),
 `overlay_recompiles` counts XLA compiles during the measured
 overlay-only ingests (non-zero exits 2), `qps_win_b8` is the headline
 measured ratio, and `admission_wait_ms` carries the submit->dispatch
-p50/p99 of the W=4 b=8 run.  Unlike the pipeline/2-D lanes this win
-is MEASURED on CPU fallback, not modeled.  Env knobs:
+p50/p99 of the W=4 b=8 run.  Unlike the 2-D lane this win is
+MEASURED on CPU fallback, not modeled.  Env knobs:
 GRAPE_BENCH_NO_SERVE_ASYNC=1 skips, GRAPE_BENCH_SERVE_ASYNC_QUERIES /
 _UPDATES size the lane (scale follows GRAPE_BENCH_SERVE_SCALE).
 
@@ -336,132 +336,6 @@ def spgemm_lane(scale: int, bench_scale: int, ef: int) -> dict:
     }
 
 
-def pipeline_lane(scale: int) -> dict:
-    """The superstep-pipelining A/B (r9, parallel/pipeline.py): serial
-    vs pipelined wall on a weighted-SSSP RMAT twin at fnum>=2, with
-    the byte-identity verdict, the plan's modeled hidden-exchange
-    fraction and boundary-set sizes, and the cost model's independent
-    overlap recount (drift gated like the op-budget ledger).
-
-    The lane FORCES engagement (GRAPE_PIPELINE=force): the A/B is the
-    point, and on small CPU-fallback twins the auto byte threshold
-    would correctly decline — that gate has its own tests
-    (tests/test_pipeline.py).  Runs in-process when the active backend
-    already spans >=2 devices; main() re-invokes it in a forced
-    2-device CPU subprocess otherwise (`bench.py --pipeline-lane N`)."""
-    import jax
-
-    from libgrape_lite_tpu import obs
-    from libgrape_lite_tpu.obs import truth
-    from libgrape_lite_tpu.fragment.edgecut import ShardedEdgecutFragment
-    from libgrape_lite_tpu.parallel.comm_spec import CommSpec
-    from libgrape_lite_tpu.utils.types import LoadStrategy
-    from libgrape_lite_tpu.vertex_map.partitioner import (
-        SegmentedPartitioner,
-    )
-    from libgrape_lite_tpu.vertex_map.vertex_map import VertexMap
-    from libgrape_lite_tpu.models import SSSP
-    from libgrape_lite_tpu.worker.worker import Worker
-
-    fnum = min(jax.device_count(), 4)
-    if fnum < 2:
-        raise RuntimeError("pipeline lane needs >= 2 devices")
-    if not obs.armed():
-        # the --pipeline-lane subprocess entrypoint skips main()'s
-        # arming, and the overlap truth meter below joins the tracer's
-        # measured device waits against the plan's modeled claim
-        obs.configure(in_memory=True)
-    n, src, dst = rmat_edges(scale, EDGE_FACTOR)
-    comm_spec = CommSpec(fnum=fnum)
-    oids = np.arange(n, dtype=np.int64)
-    vm = VertexMap.build(oids, SegmentedPartitioner(fnum, oids))
-    rng_w = np.random.default_rng(11)
-    w = rng_w.uniform(0.1, 10.0, size=len(src)).astype(np.float32)
-    frag = ShardedEdgecutFragment.build(
-        comm_spec, vm, src, dst, w, directed=False,
-        load_strategy=LoadStrategy.kBothOutIn,
-    )
-
-    def best_of(pipe: str, n_meas: int = 3):
-        prev = os.environ.get("GRAPE_PIPELINE")
-        os.environ["GRAPE_PIPELINE"] = pipe
-        try:
-            app = SSSP()
-            worker = Worker(app, frag)
-            worker.query(source=0)  # warm (compile + plan)
-            best = float("inf")
-            for _ in range(n_meas):
-                t0 = time.perf_counter()
-                worker.query(source=0)
-                best = min(best, time.perf_counter() - t0)
-            return best, worker.result_values().tobytes(), app
-        finally:
-            if prev is None:
-                os.environ.pop("GRAPE_PIPELINE", None)
-            else:
-                os.environ["GRAPE_PIPELINE"] = prev
-
-    t_serial, bytes_serial, _ = best_of("0")
-    t_pipe, bytes_pipe, app = best_of("force")
-    plan = getattr(app, "_pipeline", None)
-    if plan is None:
-        # forced and still declined: surface the recorded reason (the
-        # parent gates on engaged=false — a vacuous serial-vs-serial
-        # A/B must never read as a green pipeline verdict)
-        from libgrape_lite_tpu.parallel.pipeline import PIPELINE_STATS
-
-        print(
-            f"[bench] pipeline: declined under force: "
-            f"{PIPELINE_STATS['last_decision']}",
-            file=sys.stderr,
-        )
-    block = {
-        "scale": scale,
-        "fnum": fnum,
-        "app": "sssp",
-        "engaged": plan is not None,
-        "mode": plan.mode if plan is not None else "none",
-        "plan_uid": plan.uid if plan is not None else "-",
-        "serial_s": round(t_serial, 4),
-        "pipelined_s": round(t_pipe, 4),
-        "byte_identical": bytes_pipe == bytes_serial,
-        "modeled_hidden_frac": 0.0,
-        "exchange_bytes": 0,
-        "boundary_vertices": 0,
-        "interior_vertices": 0,
-        "boundary_edges": 0,
-        "interior_edges": 0,
-        "overlap_recount_mismatch": 0.0,
-    }
-    if plan is not None:
-        brief = plan.span_brief()
-        for k in ("modeled_hidden_frac", "exchange_bytes",
-                  "boundary_vertices", "interior_vertices",
-                  "boundary_edges", "interior_edges"):
-            block[k] = brief[k]
-        scripts = os.path.join(
-            os.path.dirname(os.path.abspath(__file__)), "scripts")
-        if scripts not in sys.path:
-            sys.path.insert(0, scripts)
-        from pack_cost_model import overlap_recount
-
-        block["overlap_recount_mismatch"] = (
-            overlap_recount(plan)["overlap_recount_mismatch"]
-        )
-    # the overlap truth meter (obs/truth.py): join the pipelined
-    # queries this lane just ran against the tracer's measured
-    # device waits, per plan uid — the modeled hidden_us claim is
-    # reconciled here instead of shipping unaudited.  Joined rows
-    # also feed the calibration harvest (GRAPE_CALIBRATE_HARVEST).
-    rep = truth.truth_report(obs.history())
-    block["overlap_truth"] = truth.block_brief(rep)
-    truth.harvest_report(
-        rep,
-        pipe_brief=plan.span_brief() if plan is not None else None,
-    )
-    return block
-
-
 def partition2d_lane(scale: int) -> dict:
     """The 1-D edge-cut vs 2-D vertex-cut A/B (r10, ROADMAP item 2;
     fragment/partition.py, models/vc2d.py, docs/PARTITION2D.md) on a
@@ -563,8 +437,7 @@ def partition2d_lane(scale: int) -> dict:
     t_2d, res_2d = best_of(SSSPVC2D(), frag_2d, source=0)
     byte_identical = res_1d.tobytes() == res_2d.tobytes()
 
-    # PageRank: sum folds regroup across tiles -> eps, not bytes (the
-    # documented pipeline-SUM class of decline)
+    # PageRank: sum folds regroup across tiles -> eps, not bytes
     _, pr_1d = best_of(PageRank(delta=0.85, max_round=10), frag_1d,
                        n_meas=1, max_round=10)
     frag_2d_raw = ImmutableVertexcutFragment.build(
@@ -618,157 +491,6 @@ def partition2d_lane(scale: int) -> dict:
         "measured_winner": measured_winner,
         "decision_matches": decision_matches,
     }
-
-
-def vc2d_pipeline_lane(scale: int) -> dict:
-    """The pipelined-SUMMA A/B (PR 19; parallel/pipeline.py
-    VC2DPipelinePlan, models/vc2d.py inceval_pipelined): SSSP on the
-    fnum 4 (k=2) vertex-cut mesh, pipelined vs unpipelined vs the 1-D
-    edge-cut baseline, all three byte-compared per oid.
-
-    Verdicts are split HONESTLY: byte-identity and the decision
-    record (rate-profile label + modeled hidden-µs per round) are
-    hard gates; the measured wall is reported with the backend it ran
-    on — the CPU fallback dispatches collectives synchronously, so a
-    CPU wall is a correctness proxy, never overlap evidence (the
-    modeled TPU dividend is what `modeled_hidden_us` prices).
-
-    Like the pipeline lane, engagement is FORCED (the auto byte floor
-    would correctly decline a small CPU twin; that gate has its own
-    tests)."""
-    import jax
-
-    from libgrape_lite_tpu.fragment.edgecut import ShardedEdgecutFragment
-    from libgrape_lite_tpu.fragment.vertexcut import (
-        ImmutableVertexcutFragment,
-    )
-    from libgrape_lite_tpu.models import SSSP, SSSPVC2D
-    from libgrape_lite_tpu.parallel.comm_spec import CommSpec
-    from libgrape_lite_tpu.utils.types import LoadStrategy
-    from libgrape_lite_tpu.vertex_map.partitioner import (
-        SegmentedPartitioner,
-    )
-    from libgrape_lite_tpu.vertex_map.vertex_map import VertexMap
-    from libgrape_lite_tpu.worker.worker import Worker
-
-    fnum, k = 4, 2
-    if jax.device_count() < fnum:
-        raise RuntimeError("vc2d_pipeline lane needs >= 4 devices")
-    scripts = os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "scripts")
-    if scripts not in sys.path:
-        sys.path.insert(0, scripts)
-    from gen_rmat import shuffle_perm
-
-    n, src_raw, dst_raw = rmat_edges(scale, EDGE_FACTOR)
-    perm = shuffle_perm(n)
-    src, dst = perm[src_raw], perm[dst_raw]
-    rng_w = np.random.default_rng(11)
-    w = rng_w.uniform(0.1, 10.0, size=len(src)).astype(np.float32)
-    oids = np.arange(n, dtype=np.int64)
-
-    comm = CommSpec(fnum=fnum)
-    vm = VertexMap.build(oids, SegmentedPartitioner(fnum, oids))
-    frag_1d = ShardedEdgecutFragment.build(
-        comm, vm, src, dst, w, directed=False,
-        load_strategy=LoadStrategy.kBothOutIn,
-    )
-    frag_2d = ImmutableVertexcutFragment.build(
-        comm, oids, src, dst, w, directed=False, symmetrize=True,
-    )
-
-    def assembled(worker, frag):
-        vals = worker.result_values()
-        out = np.full(n, np.nan, dtype=vals.dtype)
-        for f in range(frag.fnum):
-            m = frag.inner_vertices_num(f)
-            if m:
-                out[np.asarray(frag.inner_oids(f))] = vals[f, :m]
-        return out
-
-    def best_of(app_cls, frag, pipe: str, n_meas=3, **kw):
-        prev = os.environ.get("GRAPE_PIPELINE")
-        os.environ["GRAPE_PIPELINE"] = pipe
-        try:
-            worker = Worker(app_cls(), frag)
-            worker.query(**kw)  # warm (compile + plan)
-            best = float("inf")
-            for _ in range(n_meas):
-                t0 = time.perf_counter()
-                worker.query(**kw)
-                best = min(best, time.perf_counter() - t0)
-            return best, assembled(worker, frag), worker.app
-        finally:
-            if prev is None:
-                os.environ.pop("GRAPE_PIPELINE", None)
-            else:
-                os.environ["GRAPE_PIPELINE"] = prev
-
-    t_1d, res_1d, _ = best_of(SSSP, frag_1d, "0", source=0)
-    t_s2d, res_s2d, _ = best_of(SSSPVC2D, frag_2d, "0", source=0)
-    t_p2d, res_p2d, app = best_of(SSSPVC2D, frag_2d, "force", source=0)
-    plan = getattr(app, "_pipeline", None)
-    if plan is None:
-        from libgrape_lite_tpu.parallel.pipeline import PIPELINE_STATS
-
-        print(
-            f"[bench] vc2d_pipeline: declined under force: "
-            f"{PIPELINE_STATS['last_decision']}",
-            file=sys.stderr,
-        )
-    dec = plan.decision if plan is not None else {}
-    brief = plan.span_brief() if plan is not None else {}
-    t = plan.stats["totals"] if plan is not None else {}
-    return {
-        "scale": scale,
-        "fnum": fnum,
-        "k": k,
-        "app": "sssp",
-        "engaged": plan is not None,
-        "phase_split": int(t.get("phase_split", 0)),
-        "edge_slots": int(t.get("edge_slots", 0)),
-        "exchange_bytes": plan.exchange_bytes if plan is not None else 0,
-        "serial_1d_s": round(t_1d, 4),
-        "serial_2d_s": round(t_s2d, 4),
-        "pipelined_2d_s": round(t_p2d, 4),
-        "pipelined_eq_serial_2d": (
-            res_p2d.tobytes() == res_s2d.tobytes()
-        ),
-        "pipelined_eq_1d": res_p2d.tobytes() == res_1d.tobytes(),
-        "profile": str(dec.get("profile", "")),
-        "plan_uid": str(dec.get("plan_uid", "-")),
-        "modeled_hidden_us": float(dec.get("modeled_hidden_us", -1.0)),
-        "modeled_hidden_frac": float(
-            brief.get("modeled_hidden_frac", 0.0)),
-        "measured_speedup": round(t_s2d / max(t_p2d, 1e-9), 4),
-        "wall_backend": str(jax.default_backend()),
-        "wall_is_overlap_evidence": jax.default_backend() == "tpu",
-    }
-
-
-def _vc2d_pipeline_lane_subprocess(scale: int) -> dict:
-    """Run the lane in a fresh CPU process with a forced 4-device host
-    platform (same pattern as the partition2d lane)."""
-    import subprocess
-
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    flags = env.get("XLA_FLAGS", "")
-    if "host_platform_device_count" not in flags:
-        env["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=4"
-        ).strip()
-    r = subprocess.run(
-        [sys.executable, os.path.abspath(__file__),
-         "--vc2d-pipeline-lane", str(scale)],
-        capture_output=True, text=True, timeout=900, env=env,
-    )
-    if r.returncode != 0:
-        raise RuntimeError(
-            f"vc2d-pipeline-lane subprocess failed: "
-            f"{r.stderr.strip()[-500:]}"
-        )
-    return json.loads(r.stdout.strip().splitlines()[-1])
 
 
 def obs_gang_lane() -> dict:
@@ -882,8 +604,8 @@ PARTITION_TIE_BAND = 0.25
 
 def _partition2d_lane_subprocess(scale: int) -> dict:
     """Run the lane in a fresh CPU process with a forced 4-device
-    host platform (same pattern as the pipeline lane: the CPU-fallback
-    bench holds a 1-device backend, frozen at init)."""
+    host platform (the CPU-fallback bench holds a 1-device backend,
+    frozen at init)."""
     import subprocess
 
     env = dict(os.environ)
@@ -902,31 +624,6 @@ def _partition2d_lane_subprocess(scale: int) -> dict:
         raise RuntimeError(
             f"partition2d-lane subprocess failed: "
             f"{r.stderr.strip()[-500:]}"
-        )
-    return json.loads(r.stdout.strip().splitlines()[-1])
-
-
-def _pipeline_lane_subprocess(scale: int) -> dict:
-    """Run the lane in a fresh CPU process with a forced 2-device host
-    platform (the CPU-fallback bench itself holds a 1-device backend,
-    and the device count is frozen at backend init)."""
-    import subprocess
-
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    flags = env.get("XLA_FLAGS", "")
-    if "host_platform_device_count" not in flags:
-        env["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=2"
-        ).strip()
-    r = subprocess.run(
-        [sys.executable, os.path.abspath(__file__),
-         "--pipeline-lane", str(scale)],
-        capture_output=True, text=True, timeout=900, env=env,
-    )
-    if r.returncode != 0:
-        raise RuntimeError(
-            f"pipeline-lane subprocess failed: {r.stderr.strip()[-500:]}"
         )
     return json.loads(r.stdout.strip().splitlines()[-1])
 
@@ -1319,7 +1016,7 @@ def main():
     # window A/B — W in {1, 4} at batch sizes {1, 8, 32} over the
     # serve-scale twin WITH a concurrent barrier-ingested delta stream
     # (serve/pipeline.py, docs/SERVING.md).  Unlike the modeled
-    # pipeline/2-D wins, this one is MEASURED even on CPU fallback:
+    # 2-D win, this one is MEASURED even on CPU fallback:
     # the window overlaps host admission/state-build/extraction with
     # device execution (JAX async dispatch runs XLA on its own
     # threads), so qps@p99 moves without a TPU in the loop.  Gated on
@@ -2114,62 +1811,6 @@ def main():
                 file=sys.stderr,
             )
 
-    # superstep-pipelining lane (r9, ROADMAP item 3): serial vs
-    # pipelined wall at fnum>=2 with the byte-identity verdict, the
-    # modeled hidden-exchange fraction, the boundary-set sizes and the
-    # cost model's overlap recount (parallel/pipeline.py,
-    # docs/PIPELINE.md).  The fnum=1 bench backend can't host the A/B,
-    # so the CPU fallback re-invokes the lane in a forced 2-device
-    # subprocess.  GRAPE_BENCH_NO_PIPELINE=1 skips;
-    # GRAPE_BENCH_PIPELINE_SCALE sizes the twin.
-    pipeline_mismatch = None
-    if not os.environ.get("GRAPE_BENCH_NO_PIPELINE"):
-        try:
-            pipe_scale = int(os.environ.get(
-                "GRAPE_BENCH_PIPELINE_SCALE", min(SCALE, 12)))
-            if jax.device_count() >= 2:
-                pipe_block = pipeline_lane(pipe_scale)
-            else:
-                pipe_block = _pipeline_lane_subprocess(pipe_scale)
-            record["pipeline"] = pipe_block
-            _emit_record(record)
-            print(
-                f"[bench] pipeline: serial={pipe_block['serial_s']}s "
-                f"pipelined={pipe_block['pipelined_s']}s "
-                f"byte_identical={pipe_block['byte_identical']} "
-                f"hidden_frac={pipe_block['modeled_hidden_frac']} "
-                f"({pipe_block['boundary_vertices']} boundary / "
-                f"{pipe_block['interior_vertices']} interior vertices)",
-                file=sys.stderr,
-            )
-            # the SAME tolerance as the op-budget ledger gate (the
-            # docs declare them identical — no private constant copy)
-            scripts = os.path.join(
-                os.path.dirname(os.path.abspath(__file__)), "scripts")
-            if scripts not in sys.path:
-                sys.path.insert(0, scripts)
-            from pack_cost_model import MISMATCH_TOLERANCE as _TOL
-
-            if pipe_block["overlap_recount_mismatch"] > _TOL:
-                pipeline_mismatch = pipe_block["overlap_recount_mismatch"]
-            if not pipe_block["byte_identical"]:
-                pipeline_mismatch = 1.0
-            if not pipe_block["engaged"]:
-                # the lane FORCES engagement, so engaged=false is a
-                # regression that silently disabled pipelining — the
-                # vacuously-identical A/B must not read as green
-                pipeline_mismatch = 1.0
-                print(
-                    "[bench] pipeline: lane ran FORCED but the plan "
-                    "did not engage — see the decline reason above",
-                    file=sys.stderr,
-                )
-        except Exception as e:  # the lane must not cost the bench
-            print(
-                f"[bench] pipeline lane failed: {type(e).__name__}: {e}",
-                file=sys.stderr,
-            )
-
     # 2-D vertex-cut partition lane (r10, ROADMAP item 2): the
     # hub-heavy RMAT A/B at fnum 4 (k=2) — max-tile vs the raw hub
     # fragment, modeled exchange bytes, serial-vs-2D wall, byte/eps
@@ -2222,61 +1863,6 @@ def main():
         except Exception as e:  # the lane must not cost the bench
             print(
                 f"[bench] partition2d lane failed: "
-                f"{type(e).__name__}: {e}",
-                file=sys.stderr,
-            )
-
-    # pipelined-SUMMA lane (PR 19): 2-D SSSP pipelined vs unpipelined
-    # vs the 1-D baseline, byte-compared per oid; the decision record
-    # must carry the rate-profile label and the modeled hidden-µs per
-    # round.  GRAPE_BENCH_NO_VC2D_PIPELINE=1 skips;
-    # GRAPE_BENCH_VC2D_PIPELINE_SCALE sizes the twin.
-    vc2dp_mismatch = None
-    if not os.environ.get("GRAPE_BENCH_NO_VC2D_PIPELINE"):
-        try:
-            vc2dp_scale = int(os.environ.get(
-                "GRAPE_BENCH_VC2D_PIPELINE_SCALE", min(SCALE, 12)))
-            if jax.device_count() >= 4:
-                vc2dp = vc2d_pipeline_lane(vc2dp_scale)
-            else:
-                vc2dp = _vc2d_pipeline_lane_subprocess(vc2dp_scale)
-            record["vc2d_pipeline"] = vc2dp
-            _emit_record(record)
-            print(
-                f"[bench] vc2d_pipeline: 1d={vc2dp['serial_1d_s']}s "
-                f"2d={vc2dp['serial_2d_s']}s "
-                f"2d-pipelined={vc2dp['pipelined_2d_s']}s "
-                f"eq_2d={vc2dp['pipelined_eq_serial_2d']} "
-                f"eq_1d={vc2dp['pipelined_eq_1d']} "
-                f"hidden_us={vc2dp['modeled_hidden_us']} "
-                f"profile={vc2dp['profile']} "
-                f"(wall on {vc2dp['wall_backend']}: "
-                + ("overlap evidence"
-                   if vc2dp["wall_is_overlap_evidence"]
-                   else "correctness proxy only — collectives are "
-                        "synchronous off-TPU") + ")",
-                file=sys.stderr,
-            )
-            for bad, why in (
-                (not vc2dp["engaged"],
-                 "lane ran FORCED but the vc2d plan did not engage — "
-                 "see the decline reason above"),
-                (not vc2dp["pipelined_eq_serial_2d"],
-                 "pipelined 2-D diverged from the unpipelined 2-D "
-                 "round"),
-                (not vc2dp["pipelined_eq_1d"],
-                 "2-D result diverged from the 1-D baseline"),
-                (not vc2dp["profile"],
-                 "decision record is missing the rate-profile label"),
-                (vc2dp["modeled_hidden_us"] < 0,
-                 "decision record is missing modeled_hidden_us"),
-            ):
-                if bad:
-                    vc2dp_mismatch = why
-                    break
-        except Exception as e:  # the lane must not cost the bench
-            print(
-                f"[bench] vc2d_pipeline lane failed: "
                 f"{type(e).__name__}: {e}",
                 file=sys.stderr,
             )
@@ -2348,10 +1934,8 @@ def main():
     # (rates + residual + fallback notes) so PERF_NOTES can table
     # pinned-vs-fitted.  GRAPE_BENCH_NO_CALIBRATION=1 skips.
     calibration_mismatch = None
-    truth_mismatch = None
     if not os.environ.get("GRAPE_BENCH_NO_CALIBRATION"):
         try:
-            from libgrape_lite_tpu.obs import truth
             from libgrape_lite_tpu.ops import calibration as calib
 
             spath = os.environ.get("GRAPE_CALIBRATION_SAMPLES")
@@ -2373,13 +1957,6 @@ def main():
                 fitted_prof = prof
                 notes = [f"fit failed: {e}"]
                 residual_pct = -1.0
-            # the overlap truth meter over THIS process's span history
-            # (the pipeline lane reconciles its own run; this row
-            # covers any pipelined query the main bench dispatched).
-            # Informational on the CPU-fallback host; gated below only
-            # under an explicit GRAPE_RATE_PROFILE — same condition as
-            # the rate-drift gate, and for the same reason.
-            trep = truth.truth_report(obs.history())
             record["calibration"] = {
                 "profile": prof.label(),
                 "fingerprint": calib.backend_fingerprint(),
@@ -2404,13 +1981,10 @@ def main():
                 "unfitted": sorted(fitted_prof.unfitted),
                 "fallback_notes": notes,
                 "surfaces": rep["surfaces"],
-                "overlap_truth": truth.block_brief(trep),
             }
             _emit_record(record)
             if os.environ.get(calib.PROFILE_ENV) and not rep["drift_ok"]:
                 calibration_mismatch = rep["drift_pct"]
-            if os.environ.get(calib.PROFILE_ENV) and not trep["ok"]:
-                truth_mismatch = trep["max_claim_frac"]
         except Exception as e:  # the lane must not cost the bench
             print(
                 f"[bench] calibration lane failed: "
@@ -2510,26 +2084,10 @@ def main():
                 file=sys.stderr,
             )
 
-    if pipeline_mismatch is not None:
-        print(
-            f"[bench] FATAL: pipeline overlap term drifted "
-            f"{pipeline_mismatch:.1%} from the shipped-plan recount "
-            "(or the pipelined run was not byte-identical) — see the "
-            "pipeline block above",
-            file=sys.stderr,
-        )
-        sys.exit(2)
     if p2d_mismatch is not None:
         print(
             f"[bench] FATAL: partition2d lane verdict failed: "
             f"{p2d_mismatch} — see the partition2d block above",
-            file=sys.stderr,
-        )
-        sys.exit(2)
-    if vc2dp_mismatch is not None:
-        print(
-            f"[bench] FATAL: vc2d_pipeline lane verdict failed: "
-            f"{vc2dp_mismatch} — see the vc2d_pipeline block above",
             file=sys.stderr,
         )
         sys.exit(2)
@@ -2570,16 +2128,6 @@ def main():
             file=sys.stderr,
         )
         sys.exit(2)
-    if truth_mismatch is not None:
-        print(
-            f"[bench] FATAL: the modeled overlap claim is "
-            f"{truth_mismatch:.2f}x the measured round wall (> the "
-            "claim limit) — the pipeline model claims to hide more "
-            "exchange than the round took; see calibration."
-            "overlap_truth above",
-            file=sys.stderr,
-        )
-        sys.exit(2)
     if obs_gang_mismatch is not None:
         print(
             f"[bench] FATAL: obs_gang lane verdict failed: "
@@ -2598,18 +2146,10 @@ def main():
 
 
 if __name__ == "__main__":
-    if "--pipeline-lane" in sys.argv:
-        # subprocess entrypoint for the CPU-fallback pipeline A/B (the
+    if "--partition2d-lane" in sys.argv:
+        # subprocess entrypoint for the 1-D vs 2-D partition A/B (the
         # parent's backend is frozen at 1 device); prints ONE json line
-        _i = sys.argv.index("--pipeline-lane")
-        print(json.dumps(pipeline_lane(int(sys.argv[_i + 1]))))
-    elif "--partition2d-lane" in sys.argv:
-        # subprocess entrypoint for the 1-D vs 2-D partition A/B
         _i = sys.argv.index("--partition2d-lane")
         print(json.dumps(partition2d_lane(int(sys.argv[_i + 1]))))
-    elif "--vc2d-pipeline-lane" in sys.argv:
-        # subprocess entrypoint for the pipelined-SUMMA A/B
-        _i = sys.argv.index("--vc2d-pipeline-lane")
-        print(json.dumps(vc2d_pipeline_lane(int(sys.argv[_i + 1]))))
     else:
         main()

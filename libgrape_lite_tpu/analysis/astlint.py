@@ -653,193 +653,6 @@ def _check_r5(module: _Scope, path: str,
 
 
 # ---------------------------------------------------------------------------
-# R6 — pipelined-window carry reads vs the worker pipeline contract
-# ---------------------------------------------------------------------------
-
-
-def _window_contract():
-    """The shipped pipeline contract (exact names + '*'-suffixed
-    prefixes + audited whole-carry callees).  Imported from the
-    runtime module rather than re-parsed: the contract IS the worker's
-    declaration, and the lint must judge fixtures and the tree against
-    the same set."""
-    try:
-        from libgrape_lite_tpu.parallel.pipeline import (
-            PIPELINE_WINDOW_CALLEES,
-            PIPELINE_WINDOW_READS,
-        )
-    except Exception:  # pragma: no cover — partial checkouts
-        return frozenset(), (), frozenset()
-    exact = frozenset(c for c in PIPELINE_WINDOW_READS
-                      if not c.endswith("*"))
-    prefixes = tuple(c[:-1] for c in PIPELINE_WINDOW_READS
-                     if c.endswith("*"))
-    return exact, prefixes, frozenset(PIPELINE_WINDOW_CALLEES)
-
-
-def _check_r6(module: _Scope, path: str, findings: List[Finding]) -> None:
-    """R6 pipeline-window-read.  The double-buffered superstep pipeline
-    (parallel/pipeline.py, r9) kicks off the next round's halo exchange
-    mid-round and overlaps interior compute with the in-flight
-    collective.  Every read of the query carry inside that window is
-    only safe because the kickoff writes a fresh buffer and never
-    aliases live state; each must be audited against the worker
-    pipeline contract.  Audited forms:
-
-    * a constant-keyed subscript of a carry-dict parameter after the
-      kickoff line, or a load of a variable bound from one BEFORE the
-      kickoff — the key must be named in PIPELINE_WINDOW_READS;
-    * the WHOLE carry dict passed as a call argument after the kickoff
-      (R6 cannot see the callee's body) — the callee must be named in
-      PIPELINE_WINDOW_CALLEES;
-    * reads inside a NESTED function that captures the carry dict —
-      audited position-independently (its call time is unknowable
-      statically), same two rules.
-
-    An unnamed read is the aliasing bug class the double buffering
-    exists to prevent, fossilized before it can ship (zero-entry
-    baseline).  "Carry-dict parameter" = a parameter subscripted with
-    a string constant anywhere in the function (frag/ctx params never
-    are, so they don't trip the escape rule)."""
-    exact, prefixes, callees = _window_contract()
-
-    def named(key: str) -> bool:
-        return key in exact or (
-            bool(prefixes) and key.startswith(prefixes)
-        )
-
-    def callee_of(call: ast.Call):
-        f = call.func
-        if isinstance(f, ast.Attribute):
-            return f.attr
-        if isinstance(f, ast.Name):
-            return f.id
-        return None
-
-    for s in _all_scopes(module):
-        if s.kind != "function":
-            continue
-        kick_line = None
-        for n in _shallow(s.node):
-            if (
-                isinstance(n, ast.Call)
-                and isinstance(n.func, ast.Attribute)
-                and n.func.attr == "kickoff"
-            ):
-                kick_line = (
-                    n.lineno if kick_line is None
-                    else min(kick_line, n.lineno)
-                )
-        if kick_line is None:
-            continue
-        # parameters actually USED as carry dicts: subscripted with a
-        # string constant somewhere in the function (incl. nested)
-        dict_params: Set[str] = set()
-        for n in ast.walk(s.node):
-            if (
-                isinstance(n, ast.Subscript)
-                and isinstance(n.value, ast.Name)
-                and n.value.id in s.params
-                and isinstance(n.slice, ast.Constant)
-                and isinstance(n.slice.value, str)
-            ):
-                dict_params.add(n.value.id)
-        # carry aliases bound before the kickoff: x = state["key"]
-        aliases: Dict[str, str] = {}
-        for n in _shallow(s.node):
-            if (
-                isinstance(n, ast.Assign)
-                and getattr(n, "lineno", 0) <= kick_line
-                and isinstance(n.value, ast.Subscript)
-                and isinstance(n.value.value, ast.Name)
-                and n.value.value.id in s.params
-                and isinstance(n.value.slice, ast.Constant)
-                and isinstance(n.value.slice.value, str)
-            ):
-                for t in n.targets:
-                    if isinstance(t, ast.Name):
-                        aliases[t.id] = n.value.slice.value
-        seen: Set[str] = set()
-
-        def flag(key: str, line: int, what: str) -> None:
-            if key in seen:
-                return
-            seen.add(key)
-            findings.append(Finding(
-                "R6", path, line, s.qualname,
-                f"{what} inside the pipelined window (after the "
-                "exchange kickoff) is not named in the worker "
-                "pipeline contract (parallel/pipeline."
-                "PIPELINE_WINDOW_READS / PIPELINE_WINDOW_CALLEES) — "
-                "audit it as double-buffer-safe and declare it, or "
-                "move the read before the kickoff",
-            ))
-
-        def check_nodes(nodes, in_window, params) -> None:
-            for n in nodes:
-                post = in_window(n)
-                if (
-                    post
-                    and isinstance(n, ast.Subscript)
-                    and isinstance(n.ctx, ast.Load)
-                    and isinstance(n.value, ast.Name)
-                    and n.value.id in params
-                    and isinstance(n.slice, ast.Constant)
-                    and isinstance(n.slice.value, str)
-                    and not named(n.slice.value)
-                ):
-                    flag(n.slice.value, n.lineno,
-                         f"carry read {n.slice.value!r}")
-                elif (
-                    post
-                    and isinstance(n, ast.Name)
-                    and isinstance(n.ctx, ast.Load)
-                    and n.id in aliases
-                    and not named(aliases[n.id])
-                ):
-                    flag(aliases[n.id], n.lineno,
-                         f"carry read {aliases[n.id]!r} (via alias "
-                         f"{n.id!r})")
-                elif post and isinstance(n, ast.Call):
-                    cn = callee_of(n)
-                    if cn in callees:
-                        continue
-                    args = list(n.args) + [k.value for k in n.keywords]
-                    for a in args:
-                        if (
-                            isinstance(a, ast.Name)
-                            and a.id in params
-                            and a.id in dict_params
-                        ):
-                            flag(f"<{a.id} -> {cn}()>", n.lineno,
-                                 f"whole carry dict {a.id!r} passed "
-                                 f"to unaudited callee {cn!r}")
-
-        # (1) the kickoff function's own body, after the kickoff line
-        check_nodes(
-            _shallow(s.node),
-            lambda n: getattr(n, "lineno", 0) > kick_line,
-            s.params,
-        )
-        # (2) nested functions capturing a carry dict: call time is
-        # unknowable, so every read is window-audited (a nested def
-        # re-binding the name as its own param shadows it — own scope)
-        for child in s.children:
-            if child.kind != "function":
-                continue
-            free = dict_params - child.params
-            if not free:
-                continue
-            check_nodes(
-                (n for n in ast.walk(child.node)
-                 if not isinstance(n, (ast.FunctionDef,
-                                       ast.AsyncFunctionDef))),
-                lambda n: True,
-                free,
-            )
-
-
-# ---------------------------------------------------------------------------
 # R7 — host syncs on the async pump's dispatch stage
 # ---------------------------------------------------------------------------
 
@@ -850,8 +663,8 @@ _R7_DISPATCH_RE = re.compile(r"^_?(dispatch|fill)")
 def _pump_harvest_contract():
     """The audited harvest contract: the pump module's own declaration
     of which methods may force a host sync.  Imported from the runtime
-    module (like R6's window contract) so the lint judges fixtures and
-    the shipped tree against one set."""
+    module so the lint judges fixtures and the shipped tree against one
+    set."""
     try:
         from libgrape_lite_tpu.serve.pipeline import PUMP_HARVEST_SYNCS
     except Exception:  # pragma: no cover — partial checkouts
@@ -1249,110 +1062,14 @@ def _check_r11(module: _Scope, path: str,
             ))
 
 
-#: a dict key that states a MODELED overlap claim (the planner's
-#: side of the truth-meter join)
-_R12_MODELED_RE = re.compile(r"^(modeled_|hidden_us)")
-
-#: the sanctioned correlation keys the truth meter joins on
-_R12_JOIN_KEYS = ("plan_uid", "trace_key")
-
-
-def _r12_scopes(tree: ast.AST):
-    """Module + every function def, each walked WITHOUT descending
-    into nested function bodies (those are their own scopes)."""
-    def shallow(node):
-        for c in ast.iter_child_nodes(node):
-            if isinstance(c, (ast.FunctionDef, ast.AsyncFunctionDef)):
-                continue
-            yield c
-            yield from shallow(c)
-
-    for n in ast.walk(tree):
-        if isinstance(n, (ast.Module, ast.FunctionDef,
-                          ast.AsyncFunctionDef)):
-            yield n, list(shallow(n))
-
-
-def _r12_str_keys(d: ast.Dict):
-    return {k.value for k in d.keys
-            if isinstance(k, ast.Constant) and isinstance(k.value, str)}
-
-
-def _check_r12(module: _Scope, path: str,
-               findings: List[Finding]) -> None:
-    """R12 unkeyed-modeled-claim.  A dict that carries a modeled
-    overlap claim (``modeled_*`` / ``hidden_us*`` key) next to an
-    ``engaged`` verdict is a pipeline/2-D decision record or span
-    brief — the exact records obs/truth.py joins against measured
-    device waits, and the join key is ``plan_uid`` (or ``trace_key``)
-    riding in the SAME record.  Two forms are audited per scope: a
-    dict literal holding both keys inline, and a name bound to a dict
-    literal whose claim/verdict keys arrive via later subscript
-    assignments (the decision-record idiom in parallel/pipeline.py).
-    The union of literal + subscript-assigned keys must include a
-    correlation key."""
-    for _, nodes in _r12_scopes(module.node):
-        # (a) self-contained literals (span_brief-style records)
-        literal_of: dict = {}
-        keys_of: dict = {}
-        first_line: dict = {}
-        bound_literals: set = set()
-        for n in nodes:
-            if isinstance(n, ast.Assign) and isinstance(n.value, ast.Dict):
-                for t in n.targets:
-                    if isinstance(t, ast.Name):
-                        literal_of[t.id] = n
-                        keys_of.setdefault(t.id, set()).update(
-                            _r12_str_keys(n.value))
-                        first_line.setdefault(t.id, n.lineno)
-                        # audited via the key-union path below, where
-                        # a later subscript may supply the join key
-                        bound_literals.add(id(n.value))
-            elif isinstance(n, ast.Dict) and id(n) not in bound_literals:
-                keys = _r12_str_keys(n)
-                if ("engaged" in keys
-                        and any(_R12_MODELED_RE.match(k) for k in keys)
-                        and not any(j in keys for j in _R12_JOIN_KEYS)):
-                    findings.append(Finding(
-                        "R12", path, n.lineno, "<dict>",
-                        "modeled overlap claim next to an `engaged` "
-                        "verdict without a plan_uid/trace_key — the "
-                        "overlap truth meter cannot join this record "
-                        "against measured device waits; stamp the "
-                        "plan uid into the same dict",
-                    ))
-            elif (isinstance(n, ast.Assign)
-                  and len(n.targets) == 1
-                  and isinstance(n.targets[0], ast.Subscript)
-                  and isinstance(n.targets[0].value, ast.Name)
-                  and isinstance(n.targets[0].slice, ast.Constant)
-                  and isinstance(n.targets[0].slice.value, str)):
-                name = n.targets[0].value.id
-                keys_of.setdefault(name, set()).add(
-                    n.targets[0].slice.value)
-        # (b) decision-record idiom: literal + subscript assignments
-        for name, node in literal_of.items():
-            keys = keys_of.get(name, set())
-            if ("engaged" in keys
-                    and any(_R12_MODELED_RE.match(k) for k in keys)
-                    and not any(j in keys for j in _R12_JOIN_KEYS)):
-                findings.append(Finding(
-                    "R12", path, first_line[name], name,
-                    f"decision record {name!r} claims modeled overlap "
-                    "(modeled_*/hidden_us* key) next to `engaged` but "
-                    "never stamps plan_uid/trace_key in this scope — "
-                    "the truth meter cannot join the claim against "
-                    "measured device waits",
-                ))
-
-
 # ---------------------------------------------------------------------------
 # driver
 # ---------------------------------------------------------------------------
 
 
 def lint_source(src: str, relpath: str) -> List[Finding]:
-    """All R1-R12 findings for one module's source text."""
+    """All R1-R11 findings (R6 is retired) for one module's source
+    text."""
     relpath = relpath.replace(os.sep, "/")
     try:
         tree = ast.parse(src)
@@ -1374,13 +1091,11 @@ def lint_source(src: str, relpath: str) -> List[Finding]:
     _check_r3(module, relpath, findings)
     _check_r4(module, relpath, findings)
     _check_r5(module, relpath, findings)
-    _check_r6(module, relpath, findings)
     _check_r7(module, relpath, findings)
     _check_r8(module, relpath, findings)
     _check_r9(module, relpath, findings)
     _check_r10(module, relpath, findings)
     _check_r11(module, relpath, findings)
-    _check_r12(module, relpath, findings)
     return findings
 
 

@@ -27,8 +27,7 @@ from typing import Dict, List, Optional
 
 # severity is advisory (every unsuppressed finding fails the gate);
 # it orders the human report so the compile-visible classes lead
-_SEVERITY = {"R1": 0, "R2": 1, "R3": 2, "R4": 3, "R5": 4, "R6": 3,
-             "R7": 1,
+_SEVERITY = {"R1": 0, "R2": 1, "R3": 2, "R4": 3, "R5": 4, "R7": 1,
              "A1": 0, "A2": 1, "A3": 1}
 
 

@@ -84,22 +84,6 @@ RULES: Dict[str, Rule] = {
             "(bool is an int subclass)",
         ),
         Rule(
-            "R6", "pipeline-window-read",
-            "code between the exchange kickoff and the join point of a "
-            "pipelined superstep reads a query-carry key (or a carry "
-            "alias bound before the kickoff, or — position-"
-            "independently — inside a nested function capturing the "
-            "carry) that is not named in the worker pipeline contract "
-            "(parallel/pipeline.PIPELINE_WINDOW_READS), or passes the "
-            "whole carry dict to a callee not named in "
-            "PIPELINE_WINDOW_CALLEES",
-            "r9 (preventive): the double-buffered pipeline exists "
-            "because an in-flight exchange aliasing the live carry "
-            "reads torn state; every window read must be audited as "
-            "double-buffer-safe and named in the contract, so the "
-            "aliasing class is un-shippable instead of re-findable",
-        ),
-        Rule(
             "R7", "sync-in-pump",
             "a host-sync forcer (block_until_ready, jax.device_get, "
             "np/jnp.asarray, or int()/float() on a non-literal value) "
@@ -120,7 +104,8 @@ RULES: Dict[str, Rule] = {
             "obs.federation.register in its defining module — the "
             "ledger is invisible to federation.snapshot(), the live "
             "/metrics exporter, and every postmortem bundle",
-            "PR 15: PLAN/SPGEMM/PARTITION/PIPELINE_STATS were four "
+            "PR 15: PLAN/SPGEMM/PARTITION_STATS and the superstep "
+            "pipeline's ledger (gone since PR 42) were four "
             "hand-rolled module dicts and PUMP/FLEET_STATS two ad-hoc "
             "classes, each with its own snapshot/reset idiom; a "
             "scrape could not see them and a new one would have "
@@ -171,22 +156,6 @@ RULES: Dict[str, Rule] = {
             "every collective's correctness now hangs on the axis "
             "names matching mesh2d()'s, so the string form is "
             "fossilized out of models/ (zero-entry baseline)",
-        ),
-        Rule(
-            "R12", "unkeyed-modeled-claim",
-            "a decision/brief dict that carries a modeled overlap "
-            "claim (a modeled_* or hidden_us* key) next to an "
-            "`engaged` verdict does not also carry the correlation "
-            "key (`plan_uid` or `trace_key`) — the overlap truth "
-            "meter (obs/truth.py) cannot join the claim against the "
-            "tracer's measured device waits, so the modeled headline "
-            "is unauditable",
-            "PR 20 (preventive): every pipeline/2-D engagement "
-            "headline in this tree is modeled, and until the truth "
-            "meter landed nothing reconciled the claims against "
-            "measured walls; the join hangs entirely on the plan uid "
-            "riding in the same record, so an unkeyed claim is "
-            "fossilized out (zero-entry baseline)",
         ),
         Rule(
             "A1", "constant-bloat",

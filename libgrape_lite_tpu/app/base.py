@@ -143,7 +143,7 @@ class AppBase:
     # state keys that are read-only trace INPUTS, not loop state: they
     # enter the jitted superstep sharded like normal leaves but are
     # excluded from the while_loop carry and from the outputs (the
-    # mirror and pipeline plans' per-shard tables ride in this way —
+    # mirror plan's per-shard tables ride in this way —
     # constants can't, because closing over an array under shard_map
     # replicates it to every device)
     ephemeral_keys: FrozenSet[str] = frozenset()
@@ -195,44 +195,6 @@ class AppBase:
         chunk state, P("vcrow") / P("vccol")).  These leaves pass into
         the traced step as their per-shard blocks, unsqueezed."""
         return {}
-
-    # ---- superstep pipelining (parallel/pipeline.py, r9) ----
-    #
-    # Apps whose round is "exchange -> pull-reduce -> fold" can run
-    # software-pipelined: compute the boundary slice, kick off the next
-    # round's halo exchange, overlap the interior slice with the
-    # in-flight collective, join at the fold.  `init_state` resolves
-    # the plan (resolve_pipeline — env gate + byte threshold + app
-    # eligibility) into `self._pipeline` and merges its host entries
-    # into the ephemeral state; the worker routes the fused/chunked
-    # loop through `inceval_pipelined` when a plan resolved.  The
-    # SERIAL inceval stays untouched either way — stepwise, batched
-    # and dyn paths keep it, and byte-identity between the two bodies
-    # is the pinned contract (tests/test_pipeline.py).
-    pipeline_state_key: str | None = None  # the exchanged carry leaf
-    _pipeline = None                       # resolved PipelinePlan | None
-
-    def pipeline_exchange(self, ctx: StepContext, frag, state):
-        """The halo exchange producing round k+1's pull inputs from the
-        current carry — the worker calls this once at loop entry (and
-        at every guarded-chunk re-entry: the buffer is a pure function
-        of the carry, so the re-derived value is bitwise the in-flight
-        one and the observable cut never moves)."""
-        return self._pipeline.exchange(
-            ctx, state[self.pipeline_state_key], state
-        )
-
-    def inceval_pipelined(self, ctx: StepContext, frag, state, xbuf):
-        """One pipelined superstep: (state', active, xbuf') — the
-        double-buffered form of `inceval`.  Only called when
-        `self._pipeline` resolved; results must be byte-identical to
-        `inceval` (the reads inside the post-kickoff window are audited
-        against parallel/pipeline.PIPELINE_WINDOW_READS by grape-lint
-        R6)."""
-        raise NotImplementedError(
-            f"{type(self).__name__} resolved a pipeline plan but "
-            "implements no inceval_pipelined"
-        )
 
     # ---- a round that follows its frontier (worker `_frontier_loop`) ----
     #

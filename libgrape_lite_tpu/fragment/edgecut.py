@@ -57,83 +57,6 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
-# ---- boundary / interior vertex split (superstep pipelining, r9) ----------
-#
-# A local vertex of fragment f is *boundary* for a pull direction when
-# some OTHER fragment's edges over that direction reference it — its
-# post-round value must travel in the halo exchange before the next
-# round can start anywhere.  Everything else is *interior*: its value
-# is only ever read locally, so its compute can overlap the in-flight
-# exchange (the communication-avoiding split the reference's message
-# manager implies and parallel/pipeline.py exploits).  The read sets
-# here are exactly the mirror request lists of parallel/mirror.py —
-# the two classifications MUST agree, or the pipelined kickoff would
-# ship stale rows (pinned by tests/test_pipeline.py).
-
-_BOUNDARY_CACHE = None
-
-
-def boundary_split(frag, directions=("ie",)) -> np.ndarray:
-    """[fnum, vp] bool — True where the vertex is boundary for a pull
-    over `directions` (cached per fragment + direction set).  Padding
-    rows are never boundary."""
-    global _BOUNDARY_CACHE
-    import weakref
-
-    if _BOUNDARY_CACHE is None:
-        _BOUNDARY_CACHE = weakref.WeakKeyDictionary()
-    per_frag = _BOUNDARY_CACHE.setdefault(frag, {})
-    key = tuple(sorted(directions))
-    if key in per_frag:
-        return per_frag[key]
-    from libgrape_lite_tpu import obs
-
-    fnum, vp = frag.fnum, frag.vp
-    # the miss: once per fragment and direction set, a set-up phase
-    with obs.tracer().span("derived.boundary_split", fnum=fnum,
-                           directions="+".join(key)):
-        read = np.zeros((fnum, vp), dtype=bool)
-        for d in key:
-            csrs = frag.host_ie if d == "ie" else frag.host_oe
-            for g in range(fnum):
-                h = csrs[g]
-                nbr = h.edge_nbr[h.edge_mask].astype(np.int64)
-                owner = nbr // vp
-                remote = owner != g
-                read[owner[remote], nbr[remote] % vp] = True
-        bmask = np.logical_and(read, frag.host_inner_mask())
-    per_frag[key] = bmask
-    return bmask
-
-
-def boundary_stats(frag, bmask: np.ndarray, direction: str = "ie") -> dict:
-    """Per-fragment boundary/interior vertex + edge counts for one pull
-    direction (edges classified by their DESTINATION row: a boundary
-    edge feeds a boundary vertex's fold, so it belongs to the slice
-    that must finish before the exchange kickoff).  Surfaced through
-    PIPELINE_STATS, Worker.pack_ledger() and trace_report."""
-    inner = frag.host_inner_mask()
-    csrs = frag.host_ie if direction == "ie" else frag.host_oe
-    per_frag = []
-    for f in range(frag.fnum):
-        h = csrs[f]
-        src = h.edge_src[h.edge_mask]
-        is_b = bmask[f][src]
-        bv = int(bmask[f].sum())
-        per_frag.append({
-            "boundary_vertices": bv,
-            "interior_vertices": int(inner[f].sum()) - bv,
-            "boundary_edges": int(is_b.sum()),
-            "interior_edges": int(len(src) - is_b.sum()),
-        })
-    tot = {
-        k: sum(p[k] for p in per_frag)
-        for k in per_frag[0]
-    } if per_frag else {}
-    return {"per_fragment": per_frag, "totals": tot,
-            "direction": direction}
-
-
 def _next_pow2(x: int) -> int:
     return 1 << max(0, int(np.ceil(np.log2(max(x, 1)))))
 

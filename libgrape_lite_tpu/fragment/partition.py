@@ -13,8 +13,8 @@ cost ledger and choose — `GRAPE_PARTITION`:
   * "auto"                    — engage 2-D only when the modeled round
     cost wins.
 
-Cost model (constants shared with parallel/pipeline.py — one set of
-modeled rates, not private copies):
+Cost model (rates from the active RateProfile, ops/calibration.py —
+one set of modeled rates, not private copies):
 
   t_1d = max_shard_edges_padded * ops_per_edge / VPU_rate
          + gather_bytes / ICI          (mirror.exchange_bytes_ledger)
@@ -26,8 +26,7 @@ every shard/tile pays the most-loaded one's capacity — exactly the
 hub pathology being priced (docs/SCALE_NOTES.md: a degree-correlated
 1-D cut pads every shard to the hub shard's Ep; the vertex-cut splits
 each hub's edges across its tile column).  Decisions and decline
-reasons land in PARTITION_STATS — like resolve_pipeline, never
-silent.
+reasons land in PARTITION_STATS, never silent.
 """
 
 from __future__ import annotations
@@ -41,13 +40,15 @@ from libgrape_lite_tpu.parallel.mirror import (
     vc2d_exchange_bytes,
 )
 from libgrape_lite_tpu.ops.calibration import active_profile
-from libgrape_lite_tpu.parallel.pipeline import DEFAULT_OPS_PER_EDGE
+
+# op COUNT per edge of a pull round (XLA gather + segment fold): a
+# counting convention, not a rate; stays literal
+DEFAULT_OPS_PER_EDGE = 30.0
 
 # 1-D app name -> its registered 2-D vertex-cut twin.  min-fold apps
 # are byte-identical to the 1-D pull; PageRankVC's sum fold is
-# eps-identical (float partials regroup — the documented pipeline-SUM
-# class of decline, accepted here because PageRank is verified by eps
-# everywhere already).
+# eps-identical (float partials regroup, accepted here because
+# PageRank is verified by eps everywhere already).
 VC2D_APPS = {
     "sssp": "sssp_vc",
     "bfs": "bfs_vc",
@@ -203,7 +204,7 @@ def resolve_partition(app_name: str, fnum: int, src: np.ndarray,
     """The partition decision for one (app, graph, fnum) — returns the
     recorded decision dict ({"mode": "1d"|"2d", "engaged": bool,
     "costs": ..., "reason": ...}); every 2d/auto request that lands on
-    1-D carries its decline reason (resolve_pipeline discipline).
+    1-D carries its decline reason.
     `eligible=False` + `reason` lets a caller record a decline the
     planner cannot see itself (e.g. a delta-mutation load)."""
     from libgrape_lite_tpu.utils import logging as glog

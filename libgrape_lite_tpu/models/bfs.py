@@ -6,8 +6,8 @@ over int32 depths, the same level assignment.  Two rounds give it.  The
 dense round (`inceval`) relaxes every entry and folds every row
 whatever the frontier: masked dense work that XLA keeps on the VPU, the
 right round where a level holds much of the graph, and the only one of
-the batched, pipelined, chunked, stepwise and dyn-overlay runners.  The
-round that follows its frontier (`inceval_frontier`,
+the batched, chunked, stepwise and dyn-overlay runners.  The round
+that follows its frontier (`inceval_frontier`,
 `ops/segment.frontier_relax`) pushes from the rows that improved last
 round alone, at static shapes: the fused serial loop carries their list
 and takes that round wherever the list fits `_FRONTIER_ROWS` rows and
@@ -59,8 +59,6 @@ class BFS(ParallelAppBase):
     dyn_overlay_support = True
     inc_mode = "monotone-min"
     inc_seed_keys = {"depth": "min"}
-    # r9: unit-weight tropical relax — min folds split bit-stably
-    pipeline_state_key = "depth"
 
     def init_state(self, frag, source=0):
         from libgrape_lite_tpu.app.base import source_lane_array
@@ -91,27 +89,12 @@ class BFS(ParallelAppBase):
         if self._mx is not None:
             eph_entries.update(self._mx.state_entries("mx_"))
         self._mx_uid = self._mx.uid if self._mx is not None else -1
-        # superstep pipelining (r9): after the exchange decision,
-        # which the pipelined round reuses verbatim (see SSSP)
-        self._pipeline = None
-        if not batched and not self._dyn:
-            from libgrape_lite_tpu.parallel.pipeline import resolve_pipeline
-
-            self._pipeline = resolve_pipeline(
-                frag, app_name="BFS", key="depth", direction="ie",
-                mirror=self._mx, mx_prefix="mx_", with_weights=False,
-            )
-            if self._pipeline is not None:
-                eph_entries.update(self._pipeline.host_entries)
-        self._pipeline_uid = (
-            self._pipeline.uid if self._pipeline is not None else -1
-        )
         # a round that follows its frontier (worker `_make_runner`): one
         # fragment's unbatched state read straight from `depth`, and a
         # graph on which a dense round costs more than a budget-sized one
         offered = (
             frag.fnum == 1 and not batched and not self._dyn
-            and self._mx is None and self._pipeline is None
+            and self._mx is None
             and frag.dev.ie.edge_nbr.shape[-1]
             >= _DENSE_FLOOR * _FRONTIER_ENTRIES
         )
@@ -181,41 +164,6 @@ class BFS(ParallelAppBase):
             add=1, absent=jnp.int32(_SENTINEL),
         )
         return {"depth": depth}, active, front
-
-    def inceval_pipelined(self, ctx: StepContext, frag, state, xbuf):
-        """Double-buffered round (parallel/pipeline.py; see SSSP):
-        boundary relax, exchange kickoff, interior relax overlapping
-        the collective, join at the boundary mask — bit-identical to
-        the serial min relax."""
-        pl = self._pipeline
-        depth = state["depth"]
-        sent = jnp.int32(_SENTINEL)
-        full = pl.splice(ctx, depth, state, xbuf)
-        bmask = state["pl_bmask"]
-        cand_b = pull_gather(
-            full, state["pl_b_nbr"], state["pl_b_val"], sent,
-            add=1, absent=sent,
-        )
-        rel_b = self.segment_reduce(
-            cand_b, state["pl_b_src"], frag.vp, "min"
-        )
-        new_b = jnp.minimum(depth, rel_b)
-        xbuf2 = pl.kickoff(ctx, jnp.where(bmask, new_b, depth), state)
-        # ---- pipelined window: carry reads below are named in
-        # parallel/pipeline.PIPELINE_WINDOW_READS (grape-lint R6) ----
-        cand_i = pull_gather(
-            full, state["pl_i_nbr"], state["pl_i_val"], sent,
-            add=1, absent=sent,
-        )
-        rel_i = self.segment_reduce(
-            cand_i, state["pl_i_src"], frag.vp, "min"
-        )
-        with jax.named_scope("grape.app.update"):
-            new_i = jnp.minimum(depth, rel_i)
-            new = jnp.where(bmask, new_b, new_i)
-            changed = jnp.logical_and(new < depth, frag.inner_mask)
-            active = ctx.sum(changed.sum().astype(jnp.int32))
-        return {"depth": new}, active, xbuf2
 
     def invariants(self, frag, state):
         # levels live in [0, SENTINEL] and only ever improve (pull-mode

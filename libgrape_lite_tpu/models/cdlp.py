@@ -65,11 +65,6 @@ class CDLP(ParallelAppBase):
     message_strategy = MessageStrategy.kAlongOutgoingEdgeToOuterVertex
     result_format = "int"
     replicated_keys = frozenset({"step", "lut", "universe"})
-    # r9: the mode fold is per-row multiset arithmetic — splitting the
-    # edge set by destination row (boundary/interior) and folding each
-    # part separately reproduces every row's (src,label) run structure
-    # exactly, so the double-buffered round is byte-identical
-    pipeline_state_key = "labels"
 
     def __init__(self, max_round: int = 10, label_dtype=np.int64):
         self.max_round = max_round
@@ -109,21 +104,6 @@ class CDLP(ParallelAppBase):
         universe = np.full((max(self.max_round, 1),), -1, np.int32)
         state = {"labels": labels, "step": np.int32(0), "lut": lut,
                  "universe": universe}
-        # superstep pipelining (r9): gather exchange, oe pull; CDLPOpt
-        # inherits (its shortcut only replaces peval — round 1 runs
-        # serial on either path)
-        from libgrape_lite_tpu.parallel.pipeline import resolve_pipeline
-
-        self._pipeline = resolve_pipeline(
-            frag, app_name=type(self).__name__, key="labels",
-            direction="oe", mirror=None, with_weights=False,
-        )
-        if self._pipeline is not None:
-            state.update(self._pipeline.host_entries)
-            self.ephemeral_keys = frozenset(self._pipeline.host_entries)
-        self._pipeline_uid = (
-            self._pipeline.uid if self._pipeline is not None else -1
-        )
         return state
 
     def _sort_plan(self, n_pad: int, vp: int):
@@ -206,12 +186,8 @@ class CDLP(ParallelAppBase):
 
     def _mode_fold(self, src, val, lut, vp, n_live, table, row_ptr=None):
         """Per-row mode label from one (src, value) edge multiset:
-        sort, then count and choose by scans over the sorted pairs —
-        the TPU counting kernel shared by the serial round and both
-        pipelined parts (the fold only ever groups edges of equal src,
-        so any edge subset CLOSED over destination rows — the full
-        set, the boundary part, the interior part — yields the per-row
-        result of the full fold for the rows it covers).
+        sort, then count and choose by scans over the sorted pairs:
+        the TPU counting kernel.
 
         `val` is what the pull's gather read for each entry from
         `_live_labels`' `values`, and `n_live` and `table` are that
@@ -237,8 +213,8 @@ class CDLP(ParallelAppBase):
         knows them: for a whole padded CSR its `indptr`, because the
         CSR's contract (graph/csr.py: real edges sorted by row, every
         masked entry behind the last row) makes the sorted `ss` equal
-        `edge_src`.  Without it (the pipelined slices) the offsets are
-        looked up in `ss` (`ops/segment.segment_top_label`).
+        `edge_src`.  Without it the offsets are looked up in `ss`
+        (`ops/segment.segment_top_label`); the round always has it.
 
         Named for the device trace (metadata only, like the pull's):
         `grape.cdlp.sort` on key building, the sort (whichever branch
@@ -349,57 +325,6 @@ class CDLP(ParallelAppBase):
             return jnp.where(
                 jnp.logical_or(keep, new_lab == big), labels, new_lab
             ), n_live
-
-    def inceval_pipelined(self, ctx: StepContext, frag, state, xbuf):
-        """Double-buffered round (parallel/pipeline.py, r9): fold the
-        mode over the BOUNDARY rows' edges, kick off the next round's
-        label exchange from them, fold the interior rows' edges under
-        the in-flight collective, join.  Byte-identical to inceval:
-        the edge split is closed over destination rows, so each part's
-        (src,label) run structure matches the full fold row-for-row
-        (see _mode_fold); the live universe is built once a round and
-        serves both parts."""
-        pl = self._pipeline
-        labels = state["labels"]
-        lut = state["lut"]
-        vp = frag.vp
-        dt = labels.dtype
-        big = jnp.asarray(np.iinfo(np.dtype(dt).name).max, dt)
-        step = state["step"] + 1
-        bmask = state["pl_bmask"]
-        has_out = frag.out_degree > 0
-        keep = jnp.logical_or(~frag.inner_mask, ~has_out)
-        full = pl.splice(ctx, labels, state, xbuf)
-        n_live, values, fill, table = self._live_labels(full, vp)
-        universe = _note_pass(state["universe"], state["step"], n_live)
-        val_b = pull_gather(
-            values, state["pl_b_nbr"], mask=state["pl_b_val"], fill=fill
-        )
-        fold_b = self._mode_fold(
-            state["pl_b_src"], val_b, lut, vp, n_live, table
-        )
-        new_b = jnp.where(
-            jnp.logical_or(keep, fold_b == big), labels, fold_b
-        )
-        xbuf2 = pl.kickoff(ctx, jnp.where(bmask, new_b, labels), state)
-        # ---- pipelined window: carry reads below are named in
-        # parallel/pipeline.PIPELINE_WINDOW_READS (grape-lint R6) ----
-        val_i = pull_gather(
-            values, state["pl_i_nbr"], mask=state["pl_i_val"], fill=fill
-        )
-        fold_i = self._mode_fold(
-            state["pl_i_src"], val_i, lut, vp, n_live, table
-        )
-        new_i = jnp.where(
-            jnp.logical_or(keep, fold_i == big), labels, fold_i
-        )
-        new = jnp.where(bmask, new_b, new_i)
-        active = jnp.where(
-            step >= jnp.int32(self.max_round), jnp.int32(0),
-            jnp.int32(1),
-        )
-        return {"labels": new, "step": step, "lut": lut,
-                "universe": universe}, active, xbuf2
 
     def peval(self, ctx: StepContext, frag, state):
         # reference PEval: step=1, one propagation (cdlp.h PEval)

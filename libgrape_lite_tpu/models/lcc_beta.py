@@ -209,8 +209,8 @@ class LCCBeta(ParallelAppBase):
         """The oriented adjacency of `frag` as placed device arrays,
         built once per (fragment, effective orientation,
         degree_threshold, requested tier widths) and kept with the
-        fragment, as `fragment/edgecut._BOUNDARY_CACHE` keeps the
-        boundary split: it is a property of the resident graph, not of
+        fragment, as `parallel/mirror._FRAG_MIRROR_CACHE` keeps the
+        mirror plans: it is a property of the resident graph, not of
         a query, so a second query builds nothing on the host and
         copies nothing to the device (`put_global` hands a placed
         array through).  A mutated or rebuilt fragment is another

@@ -145,20 +145,6 @@ class PageRank(BatchShuffleAppBase):
         if eph_entries:
             state.update(eph_entries)
             self.ephemeral_keys = frozenset(eph_entries)
-        # superstep pipelining (r9) needs a fold that splits bit-stably
-        # into a boundary and an interior slice.  No serial sum here
-        # does: the scan of ops/segment.py groups a row's addends by
-        # tile, and tile partial sums regroup under a split.  So
-        # PageRank declines, on the record, and runs its serial round
-        # whatever GRAPE_PIPELINE says (pinned in tests/test_pipeline.py)
-        if not batched:
-            from libgrape_lite_tpu.parallel.pipeline import resolve_pipeline
-
-            resolve_pipeline(
-                frag, app_name="PageRank", key="rank", eligible=False,
-                reason="the serial sum folds by tile (tile partial "
-                       "sums regroup under a split)",
-            )
         return state
 
     def peval(self, ctx: StepContext, frag, state):

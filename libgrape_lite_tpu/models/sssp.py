@@ -36,9 +36,6 @@ class SSSP(ParallelAppBase):
     dyn_overlay_support = True
     inc_mode = "monotone-min"
     inc_seed_keys = {"dist": "min"}
-    # r9: the tropical relax pipelines — min folds are any-order exact,
-    # so the boundary/interior split is bit-stable on both backends
-    pipeline_state_key = "dist"
 
     def init_state(self, frag, source=0):
         import jax
@@ -99,23 +96,6 @@ class SSSP(ParallelAppBase):
                      np.asarray(np.inf, frag.host_ie[f].edge_w.dtype))
             for f in range(frag.fnum)
         ])
-        # superstep pipelining (r9): resolved AFTER the exchange mode,
-        # because the pipelined round must reuse that decision
-        # verbatim for byte-identity; batched lanes keep the
-        # serial body (the vmapped runner is not pipelined)
-        self._pipeline = None
-        if not batched and not self._dyn:
-            from libgrape_lite_tpu.parallel.pipeline import resolve_pipeline
-
-            self._pipeline = resolve_pipeline(
-                frag, app_name="SSSP", key="dist", direction="ie",
-                mirror=self._mx, mx_prefix="mx_", with_weights=True,
-            )
-            if self._pipeline is not None:
-                eph_entries.update(self._pipeline.host_entries)
-        self._pipeline_uid = (
-            self._pipeline.uid if self._pipeline is not None else -1
-        )
         state.update(eph_entries)
         self.ephemeral_keys = frozenset(eph_entries)
         return state
@@ -157,42 +137,6 @@ class SSSP(ParallelAppBase):
             changed = jnp.logical_and(new < dist, frag.inner_mask)
             active = ctx.sum(changed.sum().astype(jnp.int32))
         return {"dist": new}, active
-
-    def inceval_pipelined(self, ctx: StepContext, frag, state, xbuf):
-        """Double-buffered round (parallel/pipeline.py): boundary relax
-        first, exchange kickoff, interior relax overlapping the
-        collective, join at the boundary mask.  min is associative and
-        commutative, so each row's fold over its own (order-preserved)
-        edge subset is bit-identical to the serial relax."""
-        pl = self._pipeline
-        dist = state["dist"]
-        full = pl.splice(ctx, dist, state, xbuf)
-        inf = jnp.asarray(jnp.inf, dist.dtype)
-        bmask = state["pl_bmask"]
-        cand_b = pull_gather(
-            full, state["pl_b_nbr"], state["pl_b_val"], inf,
-            add=state["pl_b_w"],
-        )
-        rel_b = self.segment_reduce(
-            cand_b, state["pl_b_src"], frag.vp, "min"
-        )
-        new_b = jnp.minimum(dist, rel_b)
-        xbuf2 = pl.kickoff(ctx, jnp.where(bmask, new_b, dist), state)
-        # ---- pipelined window: every carry read below is named in
-        # parallel/pipeline.PIPELINE_WINDOW_READS (grape-lint R6) ----
-        cand_i = pull_gather(
-            full, state["pl_i_nbr"], state["pl_i_val"], inf,
-            add=state["pl_i_w"],
-        )
-        rel_i = self.segment_reduce(
-            cand_i, state["pl_i_src"], frag.vp, "min"
-        )
-        with jax.named_scope("grape.app.update"):
-            new_i = jnp.minimum(dist, rel_i)
-            new = jnp.where(bmask, new_b, new_i)
-            changed = jnp.logical_and(new < dist, frag.inner_mask)
-            active = ctx.sum(changed.sum().astype(jnp.int32))
-        return {"dist": new}, active, xbuf2
 
     def invariants(self, frag, state):
         # distances are tropical-min state: never negative, never NaN

@@ -35,8 +35,7 @@ associative and commutative, and every candidate `value[src] (+ w)`
 is computed from exactly the operands the 1-D pull uses — regrouping
 the fold across tiles is bit-exact, so SSSP/BFS/WCC results are
 byte-identical to the 1-D path.  (Sum folds — PageRankVC — regroup
-float partials and are eps-identical instead, the same documented
-decline as the pipeline SUM split.)
+float partials and are eps-identical instead.)
 """
 
 from __future__ import annotations
@@ -64,24 +63,6 @@ def vc_transpose(x, k):
         return x
     perm = [(i * k + j, j * k + i) for i in range(k) for j in range(k)]
     return lax.ppermute(x, (VC_ROW_AXIS, VC_COL_AXIS), perm)
-
-
-def _phase_view(frag, lo, hi):
-    """A STATIC slice of the traced tile's COO edge ring — the
-    phase-0/phase-1 halves of the pipelined SUMMA round.  Pure python
-    slicing of the per-shard [Ep] leaves (lo/hi are host ints from the
-    resolved plan), so both phases fold the identical segment machinery
-    over disjoint slot ranges of the same arrays; pad slots carry
-    mask=False and fold to the identity either side of the cut."""
-    import dataclasses
-
-    return dataclasses.replace(
-        frag,
-        src=frag.src[lo:hi],
-        dst=frag.dst[lo:hi],
-        w=None if frag.w is None else frag.w[lo:hi],
-        mask=frag.mask[lo:hi],
-    )
 
 
 def vc_source_carry(frag, source, app_name: str, fill, hit, dtype):
@@ -160,22 +141,6 @@ class VC2DMinAppBase(GatherScatterAppBase):
         # decided on the HOST fragment (the traced VCDeviceFragment
         # carries only geometry); a primitive, so it rides trace_key
         self._src_pull = self._wants_src_pull(frag)
-        from libgrape_lite_tpu.parallel.pipeline import (
-            resolve_vc2d_pipeline,
-        )
-
-        self._pipeline = resolve_vc2d_pipeline(
-            frag, app_name=type(self).__name__,
-            src_pull=self._src_pull,
-            dtype_bytes=int(np.dtype(carry.dtype).itemsize),
-        )
-        self._pipeline_uid = (
-            self._pipeline.uid if self._pipeline is not None else "-"
-        )
-        # the truth meter joins measured device waits against modeled
-        # overlap by plan uid; the partition record is how the 2-D
-        # path's key reaches the obs partition surface
-        self._partition_stats["plan_uid"] = self._pipeline_uid
         state.update(eph_entries)
         self.ephemeral_keys = frozenset(eph_entries)
         return state
@@ -219,41 +184,6 @@ class VC2DMinAppBase(GatherScatterAppBase):
         # over vcrow IS the global changed count, identical everywhere
         active = lax.psum(changed.sum().astype(jnp.int32), VC_ROW_AXIS)
         return {self.state_key: new}, active
-
-    # ---- the pipelined SUMMA round (VC2DPipelinePlan) ----
-
-    def pipeline_exchange(self, ctx: StepContext, frag, state):
-        """The SUMMA round has no cross-round halo table: the carry's
-        row replication along the column axis IS the broadcast, and the
-        row-axis pmin completes inside the round.  The worker's
-        pipelined loop still carries an exchange buffer, so hand it an
-        inert scalar — re-derived at every chunk entry to the same
-        constant, keeping the observable cut contract vacuously."""
-        return jnp.int32(0)
-
-    def inceval_pipelined(self, ctx: StepContext, frag, state, xbuf):
-        """The two-phase round: fold phase 0, kick its row-axis pmin,
-        fold phase 1 UNDER the in-flight collective, complete with the
-        second pmin and merge.  min(pmin(fold0), pmin(fold1)) is
-        bitwise pmin(fold(all slots)) — min regrouping over disjoint
-        static slices of the same edge arrays is exact (ints and IEEE
-        floats; no float addition crosses the cut), so the result is
-        byte-identical to `inceval` (the directed src-pull form never
-        resolves a plan, see resolve_vc2d_pipeline)."""
-        k = frag.k
-        pl = self._pipeline
-        val = state[self.state_key]  # [vc] chunk i (row copy)
-        f0 = _phase_view(frag, 0, pl.split)
-        f1 = _phase_view(frag, pl.split, None)
-        p0 = self._dst_partial(ctx, f0, val, state)
-        r0 = lax.pmin(p0, VC_ROW_AXIS)  # kicked; phase 1 overlaps it
-        p1 = self._dst_partial(ctx, f1, val, state)
-        r1 = lax.pmin(p1, VC_ROW_AXIS)
-        relax_row = vc_transpose(jnp.minimum(r0, r1), k)
-        new = jnp.minimum(val, relax_row)
-        changed = jnp.logical_and(new < val, state["vmask_row"])
-        active = lax.psum(changed.sum().astype(jnp.int32), VC_ROW_AXIS)
-        return {self.state_key: new}, active, xbuf
 
     def finalize(self, frag, state):
         return vc_finalize_rows(frag, np.asarray(state[self.state_key]))

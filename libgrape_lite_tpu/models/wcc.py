@@ -33,14 +33,6 @@ class WCC(ParallelAppBase):
     dyn_overlay_support = True
     inc_mode = "monotone-min"
     inc_seed_keys = {"comp": "min"}
-    # r9: min-gid propagation pipelines in BOTH graph forms.  The
-    # undirected round is the canonical single-pull split; the
-    # directed round runs the two-kickoff double-pull form — the oe
-    # exchange is kicked from the ie BOUNDARY fold (complete at every
-    # remotely-read row under the joint ie+oe boundary mask) and
-    # rides under the ie INTERIOR fold, then the next round's ie
-    # exchange kicks from the oe boundary fold symmetrically
-    pipeline_state_key = "comp"
 
     def init_state(self, frag, **_):
         vp = frag.vp
@@ -76,28 +68,6 @@ class WCC(ParallelAppBase):
                 if self._mx_oe is not None:
                     eph_entries.update(self._mx_oe.state_entries("mx_oe_"))
         self._mx_uid = self._mx_ie.uid if self._mx_ie is not None else -1
-        # superstep pipelining (r9): undirected single-pull split, or
-        # the directed two-kickoff double-pull form (leg 2 = oe)
-        self._pipeline = None
-        if not self._dyn:
-            from libgrape_lite_tpu.parallel.pipeline import resolve_pipeline
-
-            self._pipeline = resolve_pipeline(
-                frag, app_name="WCC", key="comp", direction="ie",
-                mirror=self._mx_ie, mx_prefix="mx_ie_",
-                with_weights=False,
-                direction2="oe" if frag.directed else None,
-                mirror2=self._mx_oe if frag.directed else None,
-                eligible=(type(self)._post_pull is WCC._post_pull),
-                reason="_post_pull overrides (WCCOpt pointer jumping) "
-                       "gather the folded labels again — a dependent "
-                       "third exchange the split cannot hide",
-            )
-            if self._pipeline is not None:
-                eph_entries.update(self._pipeline.host_entries)
-        self._pipeline_uid = (
-            self._pipeline.uid if self._pipeline is not None else -1
-        )
         if eph_entries:
             state.update(eph_entries)
             self.ephemeral_keys = frozenset(eph_entries)
@@ -153,103 +123,6 @@ class WCC(ParallelAppBase):
             changed = jnp.logical_and(new < comp, frag.inner_mask)
             active = ctx.sum(changed.sum().astype(jnp.int32))
         return {"comp": new}, active
-
-    def inceval_pipelined(self, ctx: StepContext, frag, state, xbuf):
-        """Double-buffered round (parallel/pipeline.py; see SSSP) for
-        the undirected single-pull form: boundary label fold, exchange
-        kickoff, interior fold under the in-flight collective, join —
-        bit-identical (min-gid is any-order exact).  Directed graphs
-        run the two-kickoff double-pull form instead."""
-        pl = self._pipeline
-        if pl.mode2 is not None:
-            return self._inceval_pipelined_directed(ctx, frag, state,
-                                                    xbuf)
-        comp = state["comp"]
-        big = jnp.int32(np.iinfo(np.int32).max)
-        full = pl.splice(ctx, comp, state, xbuf)
-        bmask = state["pl_bmask"]
-        cand_b = pull_gather(
-            full, state["pl_b_nbr"], state["pl_b_val"], big
-        )
-        rel_b = self.segment_reduce(
-            cand_b, state["pl_b_src"], frag.vp, "min"
-        )
-        new_b = jnp.minimum(comp, rel_b)
-        xbuf2 = pl.kickoff(ctx, jnp.where(bmask, new_b, comp), state)
-        # ---- pipelined window: carry reads below are named in
-        # parallel/pipeline.PIPELINE_WINDOW_READS (grape-lint R6) ----
-        cand_i = pull_gather(
-            full, state["pl_i_nbr"], state["pl_i_val"], big
-        )
-        rel_i = self.segment_reduce(
-            cand_i, state["pl_i_src"], frag.vp, "min"
-        )
-        with jax.named_scope("grape.app.update"):
-            new_i = jnp.minimum(comp, rel_i)
-            new = jnp.where(bmask, new_b, new_i)
-            changed = jnp.logical_and(new < comp, frag.inner_mask)
-            active = ctx.sum(changed.sum().astype(jnp.int32))
-        return {"comp": new}, active, xbuf2
-
-    def _inceval_pipelined_directed(self, ctx: StepContext, frag,
-                                    state, xbuf):
-        """Two-kickoff double-pull round for directed graphs.  The
-        serial round's oe pull reads the ie-folded labels — a
-        dependent second exchange.  It pipelines anyway because the
-        joint ie+oe boundary mask makes the ie BOUNDARY fold complete
-        at every remotely-read row: the oe exchange kicks right after
-        it and hides under the ie INTERIOR fold; symmetrically, the
-        NEXT round's ie exchange kicks from the oe boundary fold and
-        hides under the oe interior fold.  Joins are min over disjoint
-        row sets — bit-identical to the serial two-pull round."""
-        pl = self._pipeline
-        comp = state["comp"]
-        big = jnp.int32(np.iinfo(np.int32).max)
-        bmask = state["pl_bmask"]
-        # leg 1 (ie): last round kicked this exchange; splice + fold
-        # the boundary rows' edges first
-        full1 = pl.splice(ctx, comp, state, xbuf)
-        cand = pull_gather(
-            full1, state["pl_b_nbr"], state["pl_b_val"], big
-        )
-        rel1_b = self.segment_reduce(
-            cand, state["pl_b_src"], frag.vp, "min"
-        )
-        new1_b = jnp.minimum(comp, rel1_b)
-        x_oe = pl.kickoff(
-            ctx, jnp.where(bmask, new1_b, comp), state, leg=2
-        )
-        # ---- pipelined window: carry reads below are named in
-        # parallel/pipeline.PIPELINE_WINDOW_READS (grape-lint R6) ----
-        cand = pull_gather(
-            full1, state["pl_i_nbr"], state["pl_i_val"], big
-        )
-        rel1_i = self.segment_reduce(
-            cand, state["pl_i_src"], frag.vp, "min"
-        )
-        new1 = jnp.where(bmask, new1_b, jnp.minimum(comp, rel1_i))
-        # leg 2 (oe): remote rows of full2 come from x_oe, current at
-        # every remotely-read row (all boundary); local rows are live
-        full2 = pl.splice(ctx, new1, state, x_oe, leg=2)
-        cand = pull_gather(
-            full2, state["pl2_b_nbr"], state["pl2_b_val"], big
-        )
-        rel2_b = self.segment_reduce(
-            cand, state["pl2_b_src"], frag.vp, "min"
-        )
-        new2_b = jnp.minimum(new1, rel2_b)
-        xbuf2 = pl.kickoff(ctx, jnp.where(bmask, new2_b, new1), state)
-        cand = pull_gather(
-            full2, state["pl2_i_nbr"], state["pl2_i_val"], big
-        )
-        rel2_i = self.segment_reduce(
-            cand, state["pl2_i_src"], frag.vp, "min"
-        )
-        with jax.named_scope("grape.app.update"):
-            new = jnp.where(bmask, new2_b, jnp.minimum(new1, rel2_i))
-            changed = jnp.logical_and(new < comp, frag.inner_mask)
-            active = ctx.sum(changed.sum().astype(jnp.int32))
-        return {"comp": new}, active, xbuf2
 
     def inc_value_map(self, key, values, old_frag, new_frag):
         """Component labels are PIDS, so a repack (which renumbers the

@@ -35,15 +35,12 @@ The gang plane (PR 20) extends all of it across ranks: `gang`
 (per-rank sidecar files, a clock-offset handshake over the existing
 host allgather, a rank-0 assembler producing ONE merged Perfetto
 timeline, and the distributed flight recorder dumping every rank's
-postmortem under one shared incident id) and `truth` (the overlap
-truth meter reconciling modeled `hidden_us_per_round` against the
-tracer's measured `device_wait_us`, joined per plan uid).
+postmortem under one shared incident id).
 `scripts/trace_report.py --gang` renders the merged timeline.
 """
 
 from libgrape_lite_tpu.obs import federation
 from libgrape_lite_tpu.obs import gang
-from libgrape_lite_tpu.obs import truth
 from libgrape_lite_tpu.obs.config import (
     METRICS_ENV,
     TRACE_ENV,
@@ -80,7 +77,6 @@ from libgrape_lite_tpu.obs.tracer import Span, Tracer
 __all__ = [
     "federation",
     "gang",
-    "truth",
     "slo",
     "FederatedStats",
     "METRICS_PORT_ENV",

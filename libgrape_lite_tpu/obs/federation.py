@@ -2,9 +2,10 @@
 
 Before this module, operational truth was scattered over six
 module-level registries, each with a private snapshot convention:
-``PLAN_STATS`` / ``SPGEMM_STATS`` / ``PARTITION_STATS`` /
-``PIPELINE_STATS`` were raw mutable dicts copied ad hoc, while
-``PUMP_STATS`` / ``FLEET_STATS`` were classes with ``snapshot()``.
+``PLAN_STATS`` / ``SPGEMM_STATS`` / ``PARTITION_STATS`` and the
+superstep pipeline's (gone since) were raw mutable dicts copied ad
+hoc, while ``PUMP_STATS`` / ``FLEET_STATS`` were classes with
+``snapshot()``.
 The federation gives them one namespace-keyed ``snapshot()`` /
 ``reset()`` API, and ``self_check()`` kills declared-but-unwired
 namespaces the same way ``check_bench_schema.self_check()`` kills
@@ -45,7 +46,6 @@ _LOCK = threading.Lock()
 EXPECTED: Dict[str, str] = {
     "spgemm": "libgrape_lite_tpu.ops.spgemm_pack",
     "partition": "libgrape_lite_tpu.fragment.partition",
-    "pipeline": "libgrape_lite_tpu.parallel.pipeline",
     "pump": "libgrape_lite_tpu.serve.pipeline",
     "fleet": "libgrape_lite_tpu.fleet.budget",
     "slo": "libgrape_lite_tpu.obs.slo",

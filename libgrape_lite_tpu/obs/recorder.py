@@ -32,7 +32,7 @@ Bundle schema (``grape-postmortem-v1``, rendered by the CLI
   row in the flushed Chrome trace's ``traceEvents`` — the postmortem
   and the timeline can be joined row-for-row,
 * ``federation`` — the full stats-federation snapshot (plan/spgemm/
-  partition/pipeline/pump/fleet/slo/recorder ledgers),
+  partition/pump/fleet/slo/recorder ledgers),
 * ``guard`` — the guard bundle when the trigger was a breach,
 * ``extra`` — trigger-specific context (fence versions, expired ids).
 """

@@ -32,10 +32,9 @@ device-execution estimate) in its args.  The first round after a
 compile still includes trace+compile time in `dispatch_us`; spans
 never try to hide that — instead the worker calls
 `span.mark("compiled")` on any round whose runner came out of a jit
-cache MISS, so the span carries `compiled_us` and downstream readers
-(the overlap truth meter, trace_report) can EXCLUDE compile rounds
-from overlap accounting rather than silently folding compile time
-into the measurement.
+cache MISS, so the span carries `compiled_us` and a reader can keep
+compile rounds out of a round's wall rather than silently folding
+compile time into the measurement.
 
 One span system, two sinks, one clock: every span is also a
 `jax.profiler.TraceAnnotation` named `grape.<name>` with the span's
@@ -95,8 +94,7 @@ SETUP_PHASES = frozenset({
     "load_graph", "read_edges", "partition", "build_fragment",
     "deserialize", "serialize", "load.place",
     "native.build", "compile_cache", "runner.compile",
-    "derived.mirror_plan", "derived.boundary_split",
-    "derived.lcc_adjacency", "derived.place",
+    "derived.mirror_plan", "derived.lcc_adjacency", "derived.place",
 })
 #: the phases that place arrays or load an executable: their records
 #: carry `bytes_in_use` of the fullest local device at open and at

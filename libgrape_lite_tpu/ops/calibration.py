@@ -3,9 +3,8 @@
 Every auto-selector in the stack prices its decision from a cost
 model — the 1-D/2-D partition ledger (fragment/partition.py), the
 ``GRAPE_LCC_BACKEND=auto`` intersect-vs-spgemm choice
-(ops/spgemm_pack.py), the pipeline engage model
-(parallel/pipeline.overlap_model), autopilot admission
-(autopilot/admission.py) and the fleet HBM budget (fleet/budget.py).
+(ops/spgemm_pack.py), autopilot admission (autopilot/admission.py)
+and the fleet HBM budget (fleet/budget.py).
 Until r17 each carried its own private copy of the hand-pinned v5e
 rates; this module makes ONE :class:`RateProfile` the single source of pricing
 constants, and adds the machinery to *fit* those rates from measured
@@ -140,8 +139,7 @@ class RateProfile:
     def label(self) -> str:
         """The fingerprint label decision records carry — a decision
         made under a stale profile is attributable in
-        PARTITION_STATS / PIPELINE_STATS / SPGEMM_STATS / autopilot
-        records."""
+        PARTITION_STATS / SPGEMM_STATS / autopilot records."""
         return f"{self.name}@{self.fingerprint}"
 
     # ---- (de)serialization ----------------------------------------------
@@ -799,41 +797,6 @@ def harvest_dispatch(stages: Optional[dict], totals: Optional[dict],
         "mxu_ops": int(totals.get("mxu_ops", 0)) * rounds,
         "gather_rows": int(totals.get("gather_rows", 0)) * rounds,
         "hbm_bytes": int(totals.get("hbm_bytes", 0)) * rounds,
-    }
-    if sample["vpu_ops"] == 0 and sample["hbm_bytes"] == 0:
-        return None
-    _HARVEST.append(sample)
-    if len(_HARVEST) > _HARVEST_MAX:
-        del _HARVEST[: _HARVEST_MAX // 2]
-    return sample
-
-
-def harvest_overlap(plan_brief: Optional[dict],
-                    measured_round_us: float,
-                    rounds: int) -> Optional[dict]:
-    """Overlap-truth reconciliation row: the truth meter's measured
-    per-round device wall joined against the pipeline brief's edge /
-    exchange-byte columns (obs/truth.py is the producer).  The row
-    rides the same harvest buffer `fit_rates` consumes — surface
-    ``overlap`` — and additionally carries the plan uid and the
-    modeled per-round hidden µs so a later fit (or a human) can see
-    exactly which modeled claim the wall was reconciled against."""
-    if not plan_brief or rounds <= 0:
-        return None
-    if not measured_round_us or measured_round_us <= 0:
-        return None
-    edges = (int(plan_brief.get("boundary_edges", 0))
-             + int(plan_brief.get("interior_edges", 0)))
-    sample = {
-        "surface": "overlap",
-        "plan_uid": plan_brief.get("plan_uid") or "-",
-        "wall_s": measured_round_us * rounds / 1e6,
-        "vpu_ops": edges * rounds,
-        "mxu_ops": 0,
-        "gather_rows": 0,
-        "hbm_bytes": int(plan_brief.get("exchange_bytes", 0)) * rounds,
-        "modeled_hidden_us_per_round": float(
-            plan_brief.get("hidden_us_per_round") or 0.0),
     }
     if sample["vpu_ops"] == 0 and sample["hbm_bytes"] == 0:
         return None

@@ -17,18 +17,17 @@ hand, the fold is a scan written out in dense XLA: `_segmented_scan`
 over tiles of 128 places, then one V-wide gather of the row ends
 (0.83 ns an element at scale 21).  Everything else keeps the scatter:
 ids that are not sorted and streams without offsets (the dyn overlay,
-the pull apps' pipelined boundary and interior slices).  Query lanes
-under `jax.vmap` (the batched runners) fold as their single queries
-do, one lane after another, wherever those queries' gather is the
-kernel below; where it is not, the lanes of an exact fold keep the
-scatter, into which XLA fuses their gather, and a float sum's lanes
-scan, because its bits depend on the grouping and a lane answers with
-its single query's bytes.  CDLP's count is a scan as well, and never a
-scatter:
-`run_position` and `segment_top_label` work on the (row, label) pairs
-its sort has just put in order, with the CSR's offsets where the whole
-CSR is folded and offsets found by a binary search of the sorted rows
-where a pipelined slice is.
+`exchange_base`, the 2-D tiles of `vc2d`, `bc`, `kcore`, 64-bit
+lanes).  Query lanes under `jax.vmap` (the batched runners) fold as
+their single queries do, one lane after another, wherever those
+queries' gather is the kernel below; where it is not, the lanes of an
+exact fold keep the scatter, into which XLA fuses their gather, and a
+float sum's lanes scan, because its bits depend on the grouping and a
+lane answers with its single query's bytes.  CDLP's count is a scan as
+well, and never a scatter: `run_position` and `segment_top_label` work
+on the (row, label) pairs its sort has just put in order, with the
+CSR's offsets, which every round has (a caller without them gets them
+by a binary search of the sorted rows: no round today).
 
 The module makes a third choice, in `pull_gather`: how `full[nbr]` is
 read.  XLA's gather also steps through its indices one at a time (8.6

@@ -41,11 +41,10 @@ def exchange_bytes_ledger(fnum: int, vp: int, m: int | None = None,
                           itemsize: int = 4) -> dict:
     """THE per-round exchange-bytes model, in one place.
 
-    Both consumers read this function — `resolve_mirror_plan`'s
-    auto-mode engagement gate AND the superstep-pipelining threshold
-    (parallel/pipeline.py) — instead of keeping private copies of
-    "exchange bytes" that could drift apart (the r9 bugfix: the auto
-    gate used to count only the all_gather it replaces, inline).
+    `resolve_mirror_plan`'s auto-mode engagement gate, `MirrorPlan`'s
+    byte properties and the 1-D side of the partition planner
+    (fragment/partition.py) read this function instead of keeping
+    private copies of "exchange bytes" that could drift apart.
 
     Returns {"gather": per-device ICI bytes of the full-state
     all_gather, "mirror": bytes of the mirror all_to_all (None when no
@@ -69,22 +68,6 @@ def vc2d_exchange_bytes(k: int, vc: int, itemsize: int = 4,
         return 0
     per_pull = (2 * (k - 1) / k + (1 - 1 / k)) * vc * itemsize
     return int(round(pulls * per_pull))
-
-
-def pipelined_round_s(compute_interior_s: float, exchange_s: float,
-                      compute_boundary_s: float) -> float:
-    """The software-pipelined round's modeled wall time:
-
-        t = max(compute_interior, exchange) + compute_boundary
-
-    — the exchange for round k+1 overlaps round k's interior slice
-    and joins at the fold; only the boundary slice (which produces the
-    exchange payload) stays on the critical path.  MAX, not SUM: under
-    pipelining, shrinking the exchange below the interior-compute time
-    buys nothing, which is why the mirror auto-mode decision and the
-    pipeline engagement threshold must share this one model
-    (docs/PIPELINE.md)."""
-    return max(compute_interior_s, exchange_s) + compute_boundary_s
 
 
 @dataclass

@@ -53,8 +53,8 @@ _TERMINATE_SCOPE = "grape.worker.terminate"
 # (for BFS: the widest level, and the reached vertices less the
 # source).  `frontier_rounds` counts the rounds that followed their
 # frontier (`_frontier_loop`; 0 where the app offers no such round).
-# A query through any other runner (pipelined, batched, chunked,
-# stepwise, host) leaves the initial values.
+# A query through any other runner (batched, chunked, stepwise, host)
+# leaves the initial values.
 ROUND_STATS = _FedStats("rounds", {
     "app": "", "rounds": 0, "active_bits": [], "active_max": 0,
     "active_sum": 0, "frontier_rounds": 0,
@@ -183,10 +183,7 @@ def _jit_with_chunk_digest(sm, state, eph):
     carry, so they are value-identical to the monitor's own probe
     (same carry_digest function, same masked-residual rule) and the
     guarded-fused path pays no extra device dispatch for them (ROADMAP
-    "Watchdog on device").  ONE wrapper shared by the serial and the
-    software-pipelined chunk runners: the digest/residual contract is
-    a consistent-cut guarantee (docs/PIPELINE.md), and two private
-    copies of it could drift apart."""
+    "Watchdog on device")."""
     from libgrape_lite_tpu.guard.watchdog import carry_digest
 
     float_keys = sorted(
@@ -509,36 +506,68 @@ class Worker:
         }
         return specs, squeezed
 
-    def _make_runner(self, max_rounds: int):
-        app = self.app
+    def _shard_mapped(self, stepper, key_specs, state, extra_in=(),
+                      out_tail=()):
+        """`stepper` under `shard_map` for a state of this structure:
+        every runner's `compile_for` but its wrap.  `stepper` takes the
+        fragment, the carry, the ephemeral leaves (apart, so that no
+        wrap donates them: they are stripped from the outputs and
+        could never alias) and one argument for each of `extra_in`,
+        and returns the carry and one value for each of `out_tail`;
+        `key_specs` is `_key_specs` or its batched twin."""
+        eph = frozenset(getattr(self.app, "ephemeral_keys", ()) or ())
         mesh, frag_spec = self._mesh_layout()
-        eph = frozenset(getattr(app, "ephemeral_keys", ()) or ())
+        specs, squeezed = key_specs(state)
+        carry_specs = {k: v for k, v in specs.items() if k not in eph}
+        eph_specs = {k: v for k, v in specs.items() if k in eph}
+        return compat.shard_map(
+            partial(stepper, squeezed=squeezed),
+            mesh=mesh,
+            in_specs=(frag_spec, carry_specs, eph_specs, *extra_in),
+            out_specs=(carry_specs, *out_tail),
+            check_vma=False,
+        )
 
-        def stepper(frag_stacked, state, eph_state, squeezed):
-            frag = frag_stacked.local()
-            # ephemeral leaves (mirror/plan stream tables etc.) ride in a
-            # separate, NON-donated argument: they are stripped from the
-            # outputs, so donating them could never alias and would only
-            # draw 'unusable donation' warnings on the largest buffers
-            st_all = _squeeze_state({**state, **eph_state}, squeezed)
-            eph_vals = {k: st_all[k] for k in eph}
+    def _stepper_parts(self, max_rounds, frag_stacked, state, eph_state,
+                       squeezed):
+        """What the fused and the chunk runner's steppers share, in the
+        traced shard: `(frag, st, peval, inceval, limit, running)`.
+        `st` is the squeezed carry; `peval(frag, s)` and `inceval(frag,
+        s)` are `_lane_stepper_parts`' closures, a round of a carry
+        without its ephemeral leaves; `running(bound)` is the loop
+        condition of a carry `(state, active, round, ...)` that stops
+        at `bound`."""
+        eph = frozenset(getattr(self.app, "ephemeral_keys", ()) or ())
+        frag = frag_stacked.local()
+        st_all = _squeeze_state({**state, **eph_state}, squeezed)
+        peval, inceval = self._lane_stepper_parts(
+            {k: st_all[k] for k in eph})
+        st = {k: v for k, v in st_all.items() if k not in eph}
+        limit = jnp.int32(max_rounds if max_rounds > 0 else _INT32_MAX)
 
-            def strip(s):
-                return {k: v for k, v in s.items() if k not in eph}
-
-            ctx = StepContext()
-            st, active = app.peval(ctx, frag, st_all)
-            st = strip(st)
-            limit = jnp.int32(max_rounds if max_rounds > 0 else _INT32_MAX)
-
+        def running(bound):
             def cond(carry):
                 _, act, r, *_ = carry
                 with jax.named_scope(_TERMINATE_SCOPE):
-                    return jnp.logical_and(act > 0, r < limit)
+                    return jnp.logical_and(act > 0, r < bound)
 
-            def inceval(frag, s):
-                s2, a2 = app.inceval(ctx, frag, {**s, **eph_vals})
-                return strip(s2), jnp.int32(a2)
+            return cond
+
+        return frag, st, peval, inceval, limit, running
+
+    def _make_runner(self, max_rounds: int):
+        """The fused runner: PEval, then IncEval to the fixed point (or
+        `max_rounds`) in one `shard_map(while_loop)`.  It alone carries
+        the round record, offers the loop that follows a frontier, and
+        donates its carry."""
+        app = self.app
+
+        def stepper(frag_stacked, state, eph_state, squeezed):
+            frag, st, peval, inceval, limit, running = (
+                self._stepper_parts(max_rounds, frag_stacked, state,
+                                    eph_state, squeezed))
+            st, active = peval(frag, st)
+            cond = running(limit)
 
             def body(carry):
                 s, _, r, rec = carry
@@ -548,8 +577,8 @@ class Worker:
             # the record of the rounds' votes (ROUND_STATS) rides in the
             # loop's carry, not in an app's state: any app's `active`
             # is recorded, and the apps' own states, which the batched,
-            # pipelined, incremental and checkpointed paths share, stay
-            # as they were
+            # incremental and checkpointed paths share, stay as they
+            # were
             budget = app.frontier_budget
             if budget is None:
                 st, active, rounds, record = lax.while_loop(
@@ -564,83 +593,13 @@ class Worker:
                     jnp.stack(record))
 
         def compile_for(state):
-            specs, squeezed = self._key_specs(state)
-            carry_specs = {k: v for k, v in specs.items() if k not in eph}
-            eph_specs = {k: v for k, v in specs.items() if k in eph}
-            sm = compat.shard_map(
-                partial(stepper, squeezed=squeezed),
-                mesh=mesh,
-                in_specs=(frag_spec, carry_specs, eph_specs),
-                out_specs=(carry_specs, P(), P(), P()),
-                check_vma=False,
-            )
+            sm = self._shard_mapped(stepper, self._key_specs, state,
+                                    out_tail=(P(), P(), P()))
             # donate the placed carry state: every query places fresh
             # buffers (query -> _place_state), so XLA may alias them
             # into the loop carry instead of holding input + output
             # copies in HBM (fragment CSRs and ephemeral tables are
             # reused / output-less and stay un-donated)
-            return jax.jit(sm, donate_argnums=(1,))
-
-        return compile_for
-
-    def _make_pipelined_runner(self, max_rounds: int):
-        """Software-pipelined twin of `_make_runner` (r9, parallel/
-        pipeline.py): the loop carry additionally threads the exchange
-        double buffer `xbuf` — created from the post-PEval carry at
-        loop entry, advanced by each round's kickoff, DROPPED at exit.
-        The jitted interface (and therefore the observable cut: the
-        carry the caller, guard digests and checkpoints see) is
-        identical to the serial runner's; `xbuf` is a pure function of
-        the carry, so dropping and re-deriving it is bitwise free.
-        Only reached when the app resolved `_pipeline`; with
-        GRAPE_PIPELINE off `_runner_for` routes to `_make_runner`,
-        whose trace is bit-for-bit untouched (lowered-HLO pinned)."""
-        app = self.app
-        mesh, frag_spec = self._mesh_layout()
-        eph = frozenset(getattr(app, "ephemeral_keys", ()) or ())
-
-        def stepper(frag_stacked, state, eph_state, squeezed):
-            frag = frag_stacked.local()
-            st_all = _squeeze_state({**state, **eph_state}, squeezed)
-            eph_vals = {k: st_all[k] for k in eph}
-
-            def strip(s):
-                return {k: v for k, v in s.items() if k not in eph}
-
-            ctx = StepContext()
-            st, active = app.peval(ctx, frag, st_all)
-            st = strip(st)
-            xbuf = app.pipeline_exchange(ctx, frag, {**st, **eph_vals})
-            limit = jnp.int32(max_rounds if max_rounds > 0 else _INT32_MAX)
-
-            def cond(carry):
-                _, _, act, r = carry
-                with jax.named_scope(_TERMINATE_SCOPE):
-                    return jnp.logical_and(act > 0, r < limit)
-
-            def body(carry):
-                s, xb, _, r = carry
-                s2, a2, xb2 = app.inceval_pipelined(
-                    ctx, frag, {**s, **eph_vals}, xb
-                )
-                return strip(s2), xb2, jnp.int32(a2), r + jnp.int32(1)
-
-            st, _, active, rounds = lax.while_loop(
-                cond, body, (st, xbuf, jnp.int32(active), jnp.int32(0))
-            )
-            return _unsqueeze_state(st, squeezed), rounds, active
-
-        def compile_for(state):
-            specs, squeezed = self._key_specs(state)
-            carry_specs = {k: v for k, v in specs.items() if k not in eph}
-            eph_specs = {k: v for k, v in specs.items() if k in eph}
-            sm = compat.shard_map(
-                partial(stepper, squeezed=squeezed),
-                mesh=mesh,
-                in_specs=(frag_spec, carry_specs, eph_specs),
-                out_specs=(carry_specs, P(), P()),
-                check_vma=False,
-            )
             return jax.jit(sm, donate_argnums=(1,))
 
         return compile_for
@@ -653,115 +612,31 @@ class Worker:
         compose, and (c) does NOT donate the carry — the guard probe
         reads the pre-chunk carry for the consecutive-carry invariants
         (monotone distances etc.), so guarded execution holds two carry
-        generations in HBM by design."""
-        app = self.app
-        mesh, frag_spec = self._mesh_layout()
-        eph = frozenset(getattr(app, "ephemeral_keys", ()) or ())
+        generations in HBM by design.  The dispatch emits the carry's
+        digest and residual beside it (`_jit_with_chunk_digest`)."""
+        eph = frozenset(getattr(self.app, "ephemeral_keys", ()) or ())
 
         def stepper(frag_stacked, state, eph_state, active0, r0, squeezed):
-            frag = frag_stacked.local()
-            st_all = _squeeze_state({**state, **eph_state}, squeezed)
-            eph_vals = {k: st_all[k] for k in eph}
-
-            def strip(s):
-                return {k: v for k, v in s.items() if k not in eph}
-
-            ctx = StepContext()
-            st = strip(st_all)
-            limit = jnp.int32(max_rounds if max_rounds > 0 else _INT32_MAX)
+            frag, st, _, inceval, limit, running = (
+                self._stepper_parts(max_rounds, frag_stacked, state,
+                                    eph_state, squeezed))
             stop = jnp.minimum(jnp.int32(r0) + jnp.int32(chunk), limit)
-
-            def cond(carry):
-                _, act, r = carry
-                with jax.named_scope(_TERMINATE_SCOPE):
-                    return jnp.logical_and(act > 0, r < stop)
 
             def body(carry):
                 s, _, r = carry
-                s2, a2 = app.inceval(ctx, frag, {**s, **eph_vals})
-                return strip(s2), jnp.int32(a2), r + jnp.int32(1)
+                s2, a2 = inceval(frag, s)
+                return s2, a2, r + jnp.int32(1)
 
             st, active, rounds = lax.while_loop(
-                cond, body, (st, jnp.int32(active0), jnp.int32(r0))
+                running(stop), body,
+                (st, jnp.int32(active0), jnp.int32(r0)),
             )
             return _unsqueeze_state(st, squeezed), rounds, active
 
         def compile_for(state):
-            specs, squeezed = self._key_specs(state)
-            carry_specs = {k: v for k, v in specs.items() if k not in eph}
-            eph_specs = {k: v for k, v in specs.items() if k in eph}
-            sm = compat.shard_map(
-                partial(stepper, squeezed=squeezed),
-                mesh=mesh,
-                in_specs=(frag_spec, carry_specs, eph_specs, P(), P()),
-                out_specs=(carry_specs, P(), P()),
-                check_vma=False,
-            )
-
-            return _jit_with_chunk_digest(sm, state, eph)
-
-        return compile_for
-
-    def _make_pipelined_chunk_runner(self, chunk: int, max_rounds: int):
-        """Software-pipelined twin of `_make_chunk_runner` (r9): the
-        exchange double buffer is re-derived from the entering carry at
-        every chunk entry (it is a pure function of the carry, so the
-        re-derivation is bitwise the value the previous chunk dropped)
-        and dropped at exit — chunk boundaries therefore remain the
-        SAME consistent cut as the serial chunked loop, and the
-        watchdog digest / masked residual emitted by this dispatch
-        observe the post-join carry (docs/PIPELINE.md).  Guard probes,
-        checkpoint snapshots and fault hooks all sit at that cut."""
-        app = self.app
-        mesh, frag_spec = self._mesh_layout()
-        eph = frozenset(getattr(app, "ephemeral_keys", ()) or ())
-
-        def stepper(frag_stacked, state, eph_state, active0, r0, squeezed):
-            frag = frag_stacked.local()
-            st_all = _squeeze_state({**state, **eph_state}, squeezed)
-            eph_vals = {k: st_all[k] for k in eph}
-
-            def strip(s):
-                return {k: v for k, v in s.items() if k not in eph}
-
-            ctx = StepContext()
-            st = strip(st_all)
-            xbuf = app.pipeline_exchange(ctx, frag, {**st, **eph_vals})
-            limit = jnp.int32(max_rounds if max_rounds > 0 else _INT32_MAX)
-            stop = jnp.minimum(jnp.int32(r0) + jnp.int32(chunk), limit)
-
-            def cond(carry):
-                _, _, act, r = carry
-                with jax.named_scope(_TERMINATE_SCOPE):
-                    return jnp.logical_and(act > 0, r < stop)
-
-            def body(carry):
-                s, xb, _, r = carry
-                s2, a2, xb2 = app.inceval_pipelined(
-                    ctx, frag, {**s, **eph_vals}, xb
-                )
-                return strip(s2), xb2, jnp.int32(a2), r + jnp.int32(1)
-
-            st, _, active, rounds = lax.while_loop(
-                cond, body,
-                (st, xbuf, jnp.int32(active0), jnp.int32(r0)),
-            )
-            return _unsqueeze_state(st, squeezed), rounds, active
-
-        def compile_for(state):
-            specs, squeezed = self._key_specs(state)
-            carry_specs = {k: v for k, v in specs.items() if k not in eph}
-            eph_specs = {k: v for k, v in specs.items() if k in eph}
-            sm = compat.shard_map(
-                partial(stepper, squeezed=squeezed),
-                mesh=mesh,
-                in_specs=(frag_spec, carry_specs, eph_specs, P(), P()),
-                out_specs=(carry_specs, P(), P()),
-                check_vma=False,
-            )
-            # the SAME post-join digest/residual contract as the
-            # serial chunk runner — one shared wrapper, so the two
-            # guarded paths cannot drift apart
+            sm = self._shard_mapped(stepper, self._key_specs, state,
+                                    extra_in=(P(), P()),
+                                    out_tail=(P(), P()))
             return _jit_with_chunk_digest(sm, state, eph)
 
         return compile_for
@@ -771,10 +646,9 @@ class Worker:
         (serve/ asserts zero-recompile reuse through these counters)."""
         hit = key in self._runner_cache
         self.runner_cache_stats["hits" if hit else "misses"] += 1
-        # the overlap truth meter (obs/truth.py) must EXCLUDE rounds
-        # whose dispatch included trace+compile: the span sites read
-        # this flag right after the first dispatch of a fresh runner
-        # and stamp `mark("compiled")`
+        # a miss means the first dispatch traces and compiles: the
+        # span sites read this flag right after it and stamp
+        # `mark("compiled")`, and `_enqueue` opens `runner.compile`
         self._last_runner_miss = not hit
         if not hit:
             self._runner_cache[key] = build()
@@ -783,25 +657,14 @@ class Worker:
     def _state_struct(self, state):
         return state_struct(state)
 
-    def _pipelined(self):
-        """The app's resolved pipeline plan (r9), or None — the single
-        routing predicate for the fused/chunked loop bodies.  The plan
-        uid rides in `trace_key` (apps set `_pipeline_uid`), so serial
-        and pipelined compiles never share a cache entry."""
-        return getattr(self.app, "_pipeline", None)
-
     def _chunk_runner_for(self, chunk: int, max_rounds: int, state):
         key = (
             "chunk", chunk, max_rounds,
             self.app.trace_key(),
             self._state_struct(state),
         )
-        make = (
-            self._make_pipelined_chunk_runner
-            if self._pipelined() is not None else self._make_chunk_runner
-        )
         return self._cached_runner(
-            key, lambda: make(chunk, max_rounds)(state)
+            key, lambda: self._make_chunk_runner(chunk, max_rounds)(state)
         )
 
     def _runner_for(self, max_rounds: int, state):
@@ -817,12 +680,8 @@ class Worker:
             self.app.trace_key(),
             self._state_struct(state),
         )
-        make = (
-            self._make_pipelined_runner
-            if self._pipelined() is not None else self._make_runner
-        )
         return self._cached_runner(
-            key, lambda: make(max_rounds)(state)
+            key, lambda: self._make_runner(max_rounds)(state)
         )
 
     def _staged(self, make_state, place, runner_for):
@@ -934,9 +793,9 @@ class Worker:
         }
 
     def _lane_stepper_parts(self, eph_vals):
-        """(strip, lane_peval, lane_inc): one lane's superstep closures
-        over the shared per-shard fragment + ephemeral streams — the
-        exact bodies of _make_runner, reused under vmap."""
+        """(lane_peval, lane_inc): one query's superstep closures over
+        the shared per-shard fragment + ephemeral streams: the bodies
+        of every runner, the batched ones' under vmap."""
         app = self.app
         eph = frozenset(getattr(app, "ephemeral_keys", ()) or ())
         ctx = StepContext()
@@ -952,7 +811,7 @@ class Worker:
             s2, a = app.inceval(ctx, frag, {**s, **eph_vals})
             return strip(s2), jnp.int32(a)
 
-        return strip, lane_peval, lane_inc
+        return lane_peval, lane_inc
 
     @staticmethod
     def _lane_body(lane_inc, frag, batch: int):
@@ -980,6 +839,37 @@ class Worker:
 
         return body
 
+    def _lane_loop_parts(self, max_rounds, batch, frag_stacked, state,
+                         eph_state, squeezed):
+        """What the batched and the batched chunk runner's steppers
+        share, in the traced shard: `(frag, st, lane_peval, limit,
+        running, body)`.  `st` is the squeezed lanes' carry;
+        `running(bound)` is the loop condition of a carry `(state,
+        active[B], rounds[B], round)` that stops at `bound` or when
+        every lane's vote has settled; `body` is `_lane_body`'s."""
+        custom = frozenset(self.app.custom_specs())
+        frag = frag_stacked.local()
+        # custom-spec ephemeral leaves (vc2d vmask_row) arrive as
+        # their raw per-shard block — no unit frag dim to strip
+        eph_vals = {
+            k: (v if k in custom else v[0])
+            for k, v in eph_state.items()
+        }
+        st = _squeeze_lane_state(state, squeezed)
+        lane_peval, lane_inc = self._lane_stepper_parts(eph_vals)
+        limit = jnp.int32(max_rounds if max_rounds > 0 else _INT32_MAX)
+
+        def running(bound):
+            def cond(carry):
+                _, act, _, r = carry
+                with jax.named_scope(_TERMINATE_SCOPE):
+                    return jnp.logical_and(jnp.any(act > 0), r < bound)
+
+            return cond
+
+        return (frag, st, lane_peval, limit, running,
+                self._lane_body(lane_inc, frag, batch))
+
     def _make_batched_runner(self, max_rounds: int, batch: int):
         """Fused multi-source runner: the SAME PEval+IncEval loop as
         _make_runner, vmapped over a leading lane axis of the carry.
@@ -989,48 +879,22 @@ class Worker:
         while_loop runs until EVERY lane's active vote has settled, and
         the freeze mask (see _lane_body) keeps finished lanes pinned so
         raggedness never perturbs results."""
-        app = self.app
-        mesh, frag_spec = self._mesh_layout()
-        eph = frozenset(getattr(app, "ephemeral_keys", ()) or ())
-        custom = frozenset(app.custom_specs())
 
         def stepper(frag_stacked, state, eph_state, squeezed):
-            frag = frag_stacked.local()
-            # custom-spec ephemeral leaves (vc2d vmask_row) arrive as
-            # their raw per-shard block — no unit frag dim to strip
-            eph_vals = {
-                k: (v if k in custom else v[0])
-                for k, v in eph_state.items()
-            }
-            st = _squeeze_lane_state(state, squeezed)
-            _, lane_peval, lane_inc = self._lane_stepper_parts(eph_vals)
+            frag, st, lane_peval, limit, running, body = (
+                self._lane_loop_parts(max_rounds, batch, frag_stacked,
+                                      state, eph_state, squeezed))
             st, active = jax.vmap(lambda s: lane_peval(frag, s))(st)
-            limit = jnp.int32(max_rounds if max_rounds > 0 else _INT32_MAX)
-
-            def cond(carry):
-                _, act, _, r = carry
-                with jax.named_scope(_TERMINATE_SCOPE):
-                    return jnp.logical_and(jnp.any(act > 0), r < limit)
-
-            body = self._lane_body(lane_inc, frag, batch)
             st, active, rounds_v, _ = lax.while_loop(
-                cond, body,
+                running(limit), body,
                 (st, active, jnp.zeros((batch,), jnp.int32),
                  jnp.int32(0)),
             )
             return _unsqueeze_lane_state(st, squeezed), rounds_v, active
 
         def compile_for(state):
-            specs, squeezed = self._key_specs_batch(state)
-            carry_specs = {k: v for k, v in specs.items() if k not in eph}
-            eph_specs = {k: v for k, v in specs.items() if k in eph}
-            sm = compat.shard_map(
-                partial(stepper, squeezed=squeezed),
-                mesh=mesh,
-                in_specs=(frag_spec, carry_specs, eph_specs),
-                out_specs=(carry_specs, P(), P()),
-                check_vma=False,
-            )
+            sm = self._shard_mapped(stepper, self._key_specs_batch, state,
+                                    out_tail=(P(), P()))
             return jax.jit(sm, donate_argnums=(1,))
 
         return compile_for
@@ -1043,47 +907,25 @@ class Worker:
         emitting a per-lane carry digest + masked residual as extra
         outputs of the same dispatch.  No carry donation — the per-lane
         guard probes read the pre-chunk carry."""
-        app = self.app
-        mesh, frag_spec = self._mesh_layout()
-        eph = frozenset(getattr(app, "ephemeral_keys", ()) or ())
-        custom = frozenset(app.custom_specs())
+        eph = frozenset(getattr(self.app, "ephemeral_keys", ()) or ())
 
         def stepper(frag_stacked, state, eph_state, active0, rv0, r0,
                     squeezed):
-            frag = frag_stacked.local()
-            eph_vals = {
-                k: (v if k in custom else v[0])
-                for k, v in eph_state.items()
-            }
-            st = _squeeze_lane_state(state, squeezed)
-            _, _, lane_inc = self._lane_stepper_parts(eph_vals)
-            limit = jnp.int32(max_rounds if max_rounds > 0 else _INT32_MAX)
+            _, st, _, limit, running, body = self._lane_loop_parts(
+                max_rounds, batch, frag_stacked, state, eph_state,
+                squeezed)
             stop = jnp.minimum(jnp.int32(r0) + jnp.int32(chunk), limit)
-
-            def cond(carry):
-                _, act, _, r = carry
-                with jax.named_scope(_TERMINATE_SCOPE):
-                    return jnp.logical_and(jnp.any(act > 0), r < stop)
-
-            body = self._lane_body(lane_inc, frag, batch)
             st, active, rv, r = lax.while_loop(
-                cond, body,
+                running(stop), body,
                 (st, jnp.asarray(active0, jnp.int32),
                  jnp.asarray(rv0, jnp.int32), jnp.int32(r0)),
             )
             return _unsqueeze_lane_state(st, squeezed), rv, active, r
 
         def compile_for(state):
-            specs, squeezed = self._key_specs_batch(state)
-            carry_specs = {k: v for k, v in specs.items() if k not in eph}
-            eph_specs = {k: v for k, v in specs.items() if k in eph}
-            sm = compat.shard_map(
-                partial(stepper, squeezed=squeezed),
-                mesh=mesh,
-                in_specs=(frag_spec, carry_specs, eph_specs, P(), P(), P()),
-                out_specs=(carry_specs, P(), P(), P()),
-                check_vma=False,
-            )
+            sm = self._shard_mapped(stepper, self._key_specs_batch, state,
+                                    extra_in=(P(), P(), P()),
+                                    out_tail=(P(), P(), P()))
 
             from libgrape_lite_tpu.guard.watchdog import carry_digest
 
@@ -1458,22 +1300,14 @@ class Worker:
                     place,
                     lambda st: self._runner_for(mr, st),
                 )
-                if tr.enabled and self._pipelined() is not None:
-                    # modeled overlap next to the measured dispatch/
-                    # device split, in the same record (r9):
-                    # trace_report derives overlap_hidden_us from it
-                    sp.set(pipeline=self._pipelined().span_brief())
                 with tr.span("worker.enqueue"):
-                    # the serial runner hands its record back too, the
-                    # pipelined one has none
-                    out_state, rounds, active, *record = self._enqueue(
+                    out_state, rounds, active, record = self._enqueue(
                         runner, "fused", 1, frag.dev, carry, eph_part,
                     )
                 t_enq = _time.perf_counter_ns()
                 if self._last_runner_miss:
-                    # fresh compile rode inside this enqueue: stamp it
-                    # so truth.py excludes the query from the measured
-                    # round wall (compile would launder the claim)
+                    # fresh compile rode inside this enqueue: stamp it,
+                    # so a reader can keep it out of a round's wall
                     sp.mark("compiled")
                 sp.mark("dispatched")
                 with tr.span("worker.wait"):
@@ -1491,19 +1325,12 @@ class Worker:
                     obs.metrics().counter(
                         "grape_supersteps_total"
                     ).inc(self.rounds + 1)
-                    if self._pipelined() is not None:
-                        # the modeled hidden-exchange split next to
-                        # the measured dispatch/device marks (r9):
-                        # trace_report's overlap column reads this
-                        sp.set(overlap_hidden_us=round(
-                            self._pipelined().hidden_us_per_round()
-                            * self.rounds, 1))
                 self._finish_query_obs(sp)
         finally:
             if tr.enabled:
                 obs.flush()
         self._result_state = out_state
-        self._round_record = (out_state, record[0]) if record else None
+        self._round_record = (out_state, record)
         self._result_fragment = self.fragment
         return out_state
 
@@ -1635,10 +1462,6 @@ class Worker:
                 "tile_skew": part["tile_skew"],
                 "per_tile": part["per_tile"],
             }
-            if "plan_uid" in part:
-                # the R12 correlation key: the truth meter joins this
-                # record against the modeled pipeline decision
-                record["plan_uid"] = part["plan_uid"]
             sp.set(partition=record)
         # guard probe/breach/rollback counts live in the counters the
         # monitor itself maintains at the event sites — no duplicate
@@ -1732,8 +1555,6 @@ class Worker:
         try:
             with tr.span("query", mode="guarded-fused",
                          app=type(app).__name__) as qsp:
-                if tr.enabled and self._pipelined() is not None:
-                    qsp.set(pipeline=self._pipelined().span_brief())
                 peval_fn = self._single_step_for("peval", state)
                 prev = carry_of(state)
                 with tr.span("peval") as sp:
@@ -1844,10 +1665,6 @@ class Worker:
                         fault_plan.on_superstep(rounds, ckpt)
                 self.rounds = rounds
                 self._terminate_code = min(0, int(active))
-                if tr.enabled and self._pipelined() is not None:
-                    qsp.set(overlap_hidden_us=round(
-                        self._pipelined().hidden_us_per_round()
-                        * self.rounds, 1))
                 self._finish_query_obs(qsp)
         finally:
             # flush in finally: a halt-policy breach raises out of the
@@ -1922,7 +1739,7 @@ class Worker:
             s = _squeeze_lane_state(
                 {k: v for k, v in st.items() if k not in eph}, squeezed
             )
-            _, lane_peval, lane_inc = self._lane_stepper_parts(eph_vals)
+            lane_peval, lane_inc = self._lane_stepper_parts(eph_vals)
             lane = lane_peval if kind == "peval" else lane_inc
             s2, active = jax.vmap(lambda x: lane(lf, x))(s)
             return _unsqueeze_lane_state(s2, squeezed), active
@@ -2004,11 +1821,6 @@ class Worker:
         try:
             with tr.span("query", mode="stepwise",
                          app=type(self.app).__name__) as sp:
-                if self._pipelined() is not None:
-                    # same record the fused path emits: the overlap
-                    # truth meter joins the superstep spans inside
-                    # this query window against this modeled brief
-                    sp.set(pipeline=self._pipelined().span_brief())
                 out = self._query_stepwise_impl(
                     max_rounds, checkpoint_every=checkpoint_every,
                     checkpoint_dir=checkpoint_dir, fault_plan=fault_plan,
@@ -2186,7 +1998,7 @@ class Worker:
         inc_fn = self._single_step_for("inceval", state)
         # a fresh-compiled inc_fn means the FIRST superstep dispatch
         # below includes trace+compile: that round's span gets a
-        # `compiled` mark so the overlap truth meter can exclude it
+        # `compiled` mark
         inc_fresh = getattr(self, "_last_runner_miss", False)
         # ephemeral leaves drop out of each step's outputs; re-merge the
         # placed originals so the next step's inputs stay complete
@@ -2296,7 +2108,6 @@ class Worker:
             with tr.span("peval", round=0) as sp:
                 out = peval_fn(frag.dev, state)
                 if getattr(self, "_last_runner_miss", False):
-                    # truth.py excludes compile-bearing rounds
                     sp.mark("compiled")
                 sp.mark("dispatched")
                 state, active = jax.block_until_ready(out)
@@ -2397,8 +2208,7 @@ class Worker:
                 with tr.span("superstep", round=rounds + 1) as sp:
                     out = inc_fn(frag.dev, state)
                     if inc_fresh:
-                        # first dispatch since (re)compile: truth.py
-                        # excludes this round's wait from the join
+                        # first dispatch since (re)compile
                         sp.mark("compiled")
                         inc_fresh = False
                     sp.mark("dispatched")

@@ -105,23 +105,6 @@ echo "== load validation gate (fnum=2) =="
 echo "== guarded run, goldens unchanged (fnum=2) =="
 run 2 sssp --sssp_source=6 --guard=halt; verify exact p2p-31-SSSP
 
-echo "== superstep pipelining: byte-identity vs serial (fnum=2) =="
-# GRAPE_PIPELINE=1 (auto) through the real CLI with the byte threshold
-# floored so the small p2p graph engages; the merged result files must
-# be bit-identical to the serial run's (parallel/pipeline.py,
-# docs/PIPELINE.md — min folds split exactly, and the exchange double
-# buffer never aliases the live carry)
-for app_spec in "sssp --sssp_source=6" "bfs --bfs_source=6"; do
-  set -- $app_spec
-  echo "$1 pipelined"
-  run 2 "$@"
-  cp "$OUT/merged.res" "$OUT/serial.res"
-  ( export GRAPE_PIPELINE=1 GRAPE_PIPELINE_MIN_BYTES=1; run 2 "$@" )
-  cmp "$OUT/serial.res" "$OUT/merged.res" \
-    || { echo "PIPELINED RESULT DIVERGED FROM SERIAL ($1)" >&2; exit 1; }
-  echo "  OK (byte-identical to serial)"
-done
-
 echo "== 2-D vertex-cut partition: cmp-identical to 1-D (sssp, fnum=4) =="
 # GRAPE_PARTITION=2d routes sssp through the k x k vertex-cut mesh
 # (fragment/partition.py + models/vc2d.py); min folds regroup exactly

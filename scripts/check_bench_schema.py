@@ -107,7 +107,7 @@ _SERVE_POINT = {
 # `overlay_recompiles` counts XLA compiles during the measured
 # overlay-only ingests (must be 0 — compile_events), and qps_win_b8
 # is the headline: measured W=4 / W=1 qps at b=8.  Verdict fields are
-# DECLARED bool, like the pipeline lane's.
+# DECLARED bool.
 _SERVE_ASYNC = {
     "scale": (int, True),
     "app": (str, True),
@@ -139,60 +139,12 @@ _DYN = {
     "inc_speedup": (_NUM, False),
 }
 
-# the r9 superstep-pipelining lane (parallel/pipeline.py,
-# docs/PIPELINE.md): serial vs pipelined wall at fnum>=2 with the
-# byte-identity verdict, the modeled hidden-exchange fraction from the
-# overlap term (t = max(compute_interior, exchange) + compute_boundary)
-# and the boundary-set sizes, plus the cost model's recount drift
-# (>5% fails the bench like the pack-ledger gate).  `byte_identical`
-# and `engaged` are DECLARED bool — everywhere else bool-in-numeric
-# stays rejected.
-_PIPELINE = {
-    "scale": (int, True),
-    "fnum": (int, True),
-    "app": (str, True),
-    "engaged": (bool, True),
-    "mode": (str, True),
-    # the truth meter's join key (grape-lint R12): every modeled
-    # claim in this block is auditable only through this uid
-    "plan_uid": (str, True),
-    "serial_s": (_NUM, True),
-    "pipelined_s": (_NUM, True),
-    "byte_identical": (bool, True),
-    "modeled_hidden_frac": (_NUM, True),
-    "exchange_bytes": (int, True),
-    "boundary_vertices": (int, True),
-    "interior_vertices": (int, True),
-    "boundary_edges": (int, True),
-    "interior_edges": (int, True),
-    "overlap_recount_mismatch": (_NUM, True),
-    "overlap_truth": (dict, True),
-}
-
-# the PR 20 modeled-vs-measured reconciliation (obs/truth.py
-# block_brief): the pipeline lane's modeled hidden_us_per_round joined
-# against the tracer's measured device waits per plan uid; rides the
-# `pipeline` block (the lane's own run) and the `calibration` block
-# (the main bench's history).  `claim_frac` above the claim limit
-# fails the bench under an explicit GRAPE_RATE_PROFILE.
-_OVERLAP_TRUTH = {
-    "queries": (int, True),
-    "joined": (int, True),
-    "plan_uid": (str, True),
-    "modeled_hidden_us_per_round": (_NUM, True),
-    "measured_round_us": (_NUM, True),
-    "claim_frac": (_NUM, True),
-    "compile_rounds_excluded": (int, True),
-    "ok": (bool, True),
-}
-
 # the r10 2-D vertex-cut partition lane (fragment/partition.py,
 # models/vc2d.py, docs/PARTITION2D.md): hub-heavy RMAT A/B at fnum 4
 # (k=2) — max-tile vs the raw 1-D hub fragment, modeled exchange
 # bytes under the shared ledgers, serial-vs-2D wall, byte/eps
 # identity verdicts, and the planner's recorded auto decision vs the
-# measured winner.  Verdict fields are DECLARED bool, like the
-# pipeline lane's.
+# measured winner.  Verdict fields are DECLARED bool.
 _PARTITION2D = {
     "scale": (int, True),
     "fnum": (int, True),
@@ -223,36 +175,6 @@ _PARTITION2D = {
     "tile_recount_mismatch": (_NUM, False),
 }
 
-# the PR 19 pipelined-SUMMA lane (parallel/pipeline.py
-# VC2DPipelinePlan, models/vc2d.py, docs/PARTITION2D.md "Overlapped
-# round"): 2-D SSSP pipelined vs unpipelined vs the 1-D baseline,
-# byte-compared per oid; the decision record's rate-profile label and
-# modeled hidden-µs per round are REQUIRED (the lane gates on both),
-# and the wall's backend is declared so a CPU correctness proxy can
-# never read as overlap evidence.  Verdict fields are DECLARED bool.
-_VC2D_PIPELINE = {
-    "scale": (int, True),
-    "fnum": (int, True),
-    "k": (int, True),
-    "app": (str, True),
-    "engaged": (bool, True),
-    "phase_split": (int, True),
-    "edge_slots": (int, True),
-    "exchange_bytes": (int, True),
-    "serial_1d_s": (_NUM, True),
-    "serial_2d_s": (_NUM, True),
-    "pipelined_2d_s": (_NUM, True),
-    "pipelined_eq_serial_2d": (bool, True),
-    "pipelined_eq_1d": (bool, True),
-    "profile": (str, True),
-    "plan_uid": (str, True),
-    "modeled_hidden_us": (_NUM, True),
-    "modeled_hidden_frac": (_NUM, True),
-    "measured_speedup": (_NUM, True),
-    "wall_backend": (str, True),
-    "wall_is_overlap_evidence": (bool, True),
-}
-
 # the r11 masked-SpGEMM lane (ops/spgemm_pack.py, docs/SPGEMM.md):
 # LCC intersect-vs-spgemm wall A/B at the lane geometry with the
 # bit-exactness verdict and the shipped-plan ledger recount (the 5%
@@ -260,7 +182,7 @@ _VC2D_PIPELINE = {
 # spgemm MXU elems + VPU lanes per oriented mask edge against the
 # popcount sweep's word-ops, priced into modeled seconds with the
 # win verdict and the ledger-auto decision.  Verdict fields are
-# DECLARED bool, like the pipeline lane's.
+# DECLARED bool.
 _SPGEMM = {
     "scale": (int, True),
     "bench_scale": (int, True),
@@ -295,7 +217,7 @@ _SPAN_ROLLUP = {
 # ROADMAP's stated target bench), the byte-identity verdict vs the
 # undrained R=1 run (bench exits 2 when it breaks), the
 # dropped-query count (must be 0), and the budget/eviction counters.
-# Verdict fields are DECLARED bool, like the pipeline lane's.
+# Verdict fields are DECLARED bool.
 _FLEET = {
     "scale": (int, True),
     "replicas": (int, True),
@@ -357,7 +279,7 @@ _STAGE_POINT = {
 # answered from the cache with ZERO XLA compiles, then one
 # fence-bumping ingest invalidates the epoch and the post-ingest
 # answers are byte-identical to a cold run on the mutated graph.
-# Verdict fields are DECLARED bool, like the pipeline lane's.
+# Verdict fields are DECLARED bool.
 _AUTOPILOT = {
     "scale": (int, True),
     "queries": (int, True),
@@ -403,7 +325,6 @@ _CALIBRATION = {
     "unfitted": (list, False),
     "fallback_notes": (list, False),
     "surfaces": (dict, False),
-    "overlap_truth": (dict, True),
 }
 
 _CALIB_SURFACE = {
@@ -465,9 +386,7 @@ _BLOCKS = {
     "serve": _SERVE,
     "serve_async": _SERVE_ASYNC,
     "dyn": _DYN,
-    "pipeline": _PIPELINE,
     "partition2d": _PARTITION2D,
-    "vc2d_pipeline": _VC2D_PIPELINE,
     "spgemm": _SPGEMM,
     "fleet": _FLEET,
     "telemetry": _TELEMETRY,
@@ -670,12 +589,6 @@ def validate_record(record) -> list:
                 errors.append(f"{where}: expected object")
                 continue
             _check_block(point, _STAGE_POINT, where, errors)
-    for holder in ("pipeline", "calibration"):
-        blk = record.get(holder)
-        if isinstance(blk, dict) and isinstance(
-                blk.get("overlap_truth"), dict):
-            _check_block(blk["overlap_truth"], _OVERLAP_TRUTH,
-                         f"{holder}.overlap_truth", errors)
     cb = record.get("calibration")
     if isinstance(cb, dict):
         rates = cb.get("rates")

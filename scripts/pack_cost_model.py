@@ -2,14 +2,12 @@
 """Independent recounts of the planners' static ledgers.
 
 What is left of the analytic cost model after the pack pipeline went
-(PR 28): the two gates a bench lane or a test still imports, and the
-pricing they share.
+(PR 28) and the superstep pipeline (PR 42): the gate a bench lane and
+a test still import, and the pricing beside it.
 
   * `spgemm_recount` — the masked-SpGEMM plan's op-budget ledger
     (ops/spgemm_pack.py) against a recount from the SHIPPED device
     streams;
-  * `overlap_recount` — the superstep pipeline's boundary/interior
-    split (parallel/pipeline.py) against the arrays that dispatch;
   * `price` — ledger totals to seconds under the shared RateProfile
     (ops/calibration.py), the same rates `price_backends` reads.
 
@@ -102,48 +100,3 @@ def price(totals: dict, profile=None) -> dict:
     return dict(t_vpu_ms=round(vpu_s * 1e3, 2),
                 t_mxu_ms=round(mxu_s * 1e3, 2),
                 t_hbm_ms=round(hbm_s * 1e3, 2))
-
-
-def overlap_recount(plan) -> dict:
-    """The exchange-overlap term (r9, parallel/pipeline.py), recounted
-    from the SHIPPED pipeline plan: the planner's boundary/interior
-    stats are annotations, so the boundary/interior edge counts are
-    re-read from the arrays that actually dispatch (the `pl_{b,i}_val`
-    validity planes) and the exchange bytes from the plan's mode +
-    geometry, NOT from `plan.stats`.  Returns the recounted overlap
-    model plus `overlap_recount_mismatch`, gated at MISMATCH_TOLERANCE
-    by bench.py."""
-    from libgrape_lite_tpu.parallel.pipeline import overlap_model
-
-    b_edges = int(np.asarray(plan.host_entries["pl_b_val"]).sum())
-    i_edges = int(np.asarray(plan.host_entries["pl_i_val"]).sum())
-    # exchange bytes from mode + geometry (f32 payload convention,
-    # the same itemsize the shared mirror ledger prices)
-    if plan.mode == "mirror":
-        xbytes = plan.fnum * plan.m * 4
-    else:
-        xbytes = plan.fnum * plan.vp * 4
-    modeled = overlap_model(b_edges, i_edges, xbytes)
-    t = plan.stats.get("totals", {})
-    planned = overlap_model(
-        t.get("boundary_edges", 0), t.get("interior_edges", 0),
-        plan.exchange_bytes,
-    )
-    mismatch = max(
-        abs(b_edges - t.get("boundary_edges", 0))
-        / max(1, t.get("boundary_edges", 0)),
-        abs(i_edges - t.get("interior_edges", 0))
-        / max(1, t.get("interior_edges", 0)),
-        abs(xbytes - plan.exchange_bytes)
-        / max(1, plan.exchange_bytes),
-        abs(modeled["hidden_frac"] - planned["hidden_frac"])
-        / max(1e-9, planned["hidden_frac"] or 1.0),
-    )
-    return {
-        "boundary_edges": b_edges,
-        "interior_edges": i_edges,
-        "exchange_bytes": xbytes,
-        "modeled_hidden_frac": modeled["hidden_frac"],
-        "modeled_round_speedup": modeled["round_speedup"],
-        "overlap_recount_mismatch": round(mismatch, 4),
-    }

@@ -42,17 +42,6 @@ timeout 3600 python bench.py \
   2> "$OUT/bench.err" | tee "$OUT/bench.json" \
   || { tail -20 "$OUT/bench.err" >&2; exit 1; }
 
-echo "== pipeline A/B (GRAPE_PIPELINE=0 vs 1 — superstep software
-pipelining, parallel/pipeline.py; the bench's own pipeline lane runs
-the serial-vs-pipelined pair at fnum>=2 and gates on byte identity +
-the overlap-term recount; docs/PIPELINE.md) =="
-GRAPE_PIPELINE=0 timeout 3600 python bench.py \
-  2> "$OUT/bench_pipe0.err" | tee "$OUT/bench_pipe0.json" || true
-GRAPE_PIPELINE=1 timeout 3600 python bench.py \
-  2> "$OUT/bench_pipe1.err" | tee "$OUT/bench_pipe1.json" || true
-grep -h "\[bench\] pipeline" "$OUT/bench_pipe0.err" \
-  "$OUT/bench_pipe1.err" | tail -4 || true
-
 echo "== lcc backend A/B (GRAPE_LCC_BACKEND=intersect vs spgemm —
 tiled masked SpGEMM on the MXU, ops/spgemm_pack.py; the bench's own
 spgemm lane runs the pair at lane geometry and gates on bit-identity

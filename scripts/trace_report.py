@@ -16,12 +16,6 @@ GRAPE_TRACE / --trace / obs.configure and prints:
   cost is per-round constant, so the ratio is wall-time-per-modeled-
   unit: a flagged round ran slower (or faster) than the same modeled
   work did in the median round — the supersteps worth profiling.
-* the superstep-pipeline split when the query span carries one (r9,
-  parallel/pipeline.py): boundary/interior vertex+edge counts, the
-  exchange mode and bytes, the modeled hidden-exchange fraction, an
-  `ovl_ms` overlap column (hidden-exchange time per superstep), and a
-  PIPELINE DRIFT flag when pipelining is armed but hides <10% of the
-  exchange;
 * the 2-D vertex-cut tile table when the query span carries one
   (r10, docs/PARTITION2D.md): one labeled row per (row, col) tile
   with its edge count and share of the max tile, plus the
@@ -146,22 +140,6 @@ def query_partition(events):
             if "partition" in args:
                 pt = args["partition"]
     return pt
-
-
-def query_pipeline(events):
-    """The superstep-pipeline brief of the last query span that
-    carried one (r9: the worker attaches `pipeline` when a plan is
-    engaged, plus `overlap_hidden_us` once the round count is known),
-    or None."""
-    pl = None
-    for ev in events:
-        if ev.get("ph") == "X" and ev.get("name") == "query":
-            args = ev.get("args") or {}
-            if "pipeline" in args:
-                pl = dict(args["pipeline"])
-                if "overlap_hidden_us" in args:
-                    pl["overlap_hidden_us"] = args["overlap_hidden_us"]
-    return pl
 
 
 def serve_pump_rows(events):
@@ -373,31 +351,22 @@ def render(events, drift_x: float = DRIFT_X, out=None):
     rows = superstep_rows(events)
     attach_verdicts(rows, events)
     led = query_ledger(events)
-    pipe = query_pipeline(events)
-    hidden_us = (pipe or {}).get("hidden_us_per_round")
     print("superstep table (wall/device from synced spans; "
           "docs/OBSERVABILITY.md):", file=out)
     hdr = (f"{'round':>5} {'phase':>9} {'wall_ms':>10} {'disp_ms':>10} "
-           f"{'dev_ms':>10} {'ovl_ms':>10} {'active':>9} "
+           f"{'dev_ms':>10} {'active':>9} "
            f"{'x_med':>6}  guard")
     print(hdr, file=out)
     drift_flags(rows, drift_x)
     flagged = 0
-    pipe_flagged = 0
     for r in rows:
         flag = "  DRIFT" if r.get("flag") else ""
         flagged += bool(r.get("flag"))
         verd = ",".join(r["verdicts"]) or "-"
         act = r["active"] if r["active"] is not None else "-"
-        # overlap column: modeled hidden-exchange µs per superstep
-        # when the pipeline is armed (constant per round — the static
-        # split; PEval is pre-pipeline, so round 0 shows '-')
-        ovl = (hidden_us if hidden_us is not None
-               and r["name"] == "superstep" else None)
         print(
             f"{r['round']:>5} {r['name']:>9} {_fmt_ms(r['wall_us'])} "
             f"{_fmt_ms(r['dispatch_us'])} {_fmt_ms(r['device_us'])} "
-            f"{_fmt_ms(ovl)} "
             f"{act:>9} {r.get('drift', 0):>6.2f}  {verd}{flag}",
             file=out,
         )
@@ -415,31 +384,6 @@ def render(events, drift_x: float = DRIFT_X, out=None):
             f"{e} edges",
             file=out,
         )
-    if pipe:
-        print(
-            "\npipeline split (query span, parallel/pipeline.py): "
-            f"{pipe.get('boundary_vertices', 0)} boundary / "
-            f"{pipe.get('interior_vertices', 0)} interior vertices "
-            f"({pipe.get('boundary_edges', 0)} / "
-            f"{pipe.get('interior_edges', 0)} edges), "
-            f"{pipe.get('mode', '?')} exchange "
-            f"{pipe.get('exchange_bytes', 0)} B/round, modeled hidden "
-            f"frac {pipe.get('modeled_hidden_frac', 0.0):.2%}"
-            + (f", {pipe['overlap_hidden_us']:.1f} µs hidden over the "
-               "query" if "overlap_hidden_us" in pipe else ""),
-            file=out,
-        )
-        if pipe.get("modeled_hidden_frac", 0.0) < 0.10:
-            pipe_flagged = 1
-            print(
-                "  PIPELINE DRIFT: pipelining is armed but hides "
-                f"<10% of the exchange "
-                f"({pipe.get('modeled_hidden_frac', 0.0):.2%}) — the "
-                "interior slice is too small to cover the collective "
-                "(hub-heavy cut? see docs/PIPELINE.md: the split "
-                "costs a dispatch and buys almost nothing here)",
-                file=out,
-            )
     part = query_partition(events)
     if part:
         # 2-D vertex-cut tile table (r10, docs/PARTITION2D.md): one
@@ -481,11 +425,10 @@ def render(events, drift_x: float = DRIFT_X, out=None):
             f"  {name:<20} n={r['count']:<4} total={r['total_s']:.4f}s "
             f"mean={r['mean_s']:.4f}s max={r['max_s']:.4f}s", file=out,
         )
-    # superstep x_med drift, the pipeline <10%-hidden flag, and the
-    # serve-pump <10%-hidden flag are counted separately (the summary
-    # above names only the first); callers get the total so any kind
-    # reads as "worth a look"
-    return flagged + pipe_flagged + pump_flagged
+    # superstep x_med drift and the serve-pump <10%-hidden flag are
+    # counted separately (the summary above names only the first);
+    # callers get the total so either kind reads as "worth a look"
+    return flagged + pump_flagged
 
 
 def render_gang_summary(summary, out=None):

@@ -270,149 +270,6 @@ def test_r5_passes_with_explicit_bool_rejection():
     assert "R5" not in _rules(src)
 
 
-# ---- R6: pipelined-window carry reads -------------------------------------
-
-
-def test_r6_trips_on_unnamed_window_read():
-    # state["frontier"] is read AFTER the exchange kickoff and is not
-    # named in parallel/pipeline.PIPELINE_WINDOW_READS — the aliasing
-    # class the double buffer exists to prevent
-    src = """
-    def inceval_pipelined(self, ctx, frag, state, xbuf):
-        new_b = state["dist"] + 1
-        xbuf2 = self._pipeline.kickoff(ctx, new_b, state)
-        fr = state["frontier"]
-        return {"dist": new_b + fr}, 1, xbuf2
-    """
-    assert "R6" in _rules(src)
-
-
-def test_r6_trips_on_pre_kickoff_alias_read_in_window():
-    # the carry leaf is bound to a local BEFORE the kickoff and read
-    # after it — same unaudited window read, via an alias
-    src = """
-    def inceval_pipelined(self, ctx, frag, state, xbuf):
-        shadow = state["scratch"]
-        xbuf2 = self._pipeline.kickoff(ctx, state["dist"], state)
-        return {"dist": shadow}, 1, xbuf2
-    """
-    assert "R6" in _rules(src)
-
-
-def test_r6_passes_on_contract_named_reads():
-    # every window read is in the shipped contract: the carry leaf
-    # ("dist"), the join mask ("pl_bmask"), the interior streams
-    # ("pl_i_*") and the second leg's prefix ("pl2_*")
-    src = """
-    def inceval_pipelined(self, ctx, frag, state, xbuf):
-        dist = state["dist"]
-        xbuf2 = self._pipeline.kickoff(ctx, dist, state)
-        cand = state["pl_i_nbr"] + state["pl2_i_nbr"]
-        new = cand * state["pl_bmask"] + dist
-        return {"dist": new}, 1, xbuf2
-    """
-    assert "R6" not in _rules(src)
-
-
-def test_r6_trips_on_nested_closure_read():
-    # the unnamed read hides inside a nested helper that CAPTURES the
-    # carry dict; its call lands after the kickoff, so the read is a
-    # window read even though its source line is earlier — audited
-    # position-independently
-    src = """
-    def inceval_pipelined(self, ctx, frag, state, xbuf):
-        def helper():
-            return state["frontier"]
-        pre = state["dist"]
-        xbuf2 = self._pipeline.kickoff(ctx, pre, state)
-        return {"dist": helper()}, 1, xbuf2
-    """
-    assert "R6" in _rules(src)
-
-
-def test_r6_trips_on_whole_carry_escape():
-    # passing the ENTIRE carry dict to a callee the contract does not
-    # name: R6 cannot see the callee's body, so the escape itself is
-    # the finding
-    src = """
-    def inceval_pipelined(self, ctx, frag, state, xbuf):
-        new_b = state["dist"] + 1
-        xbuf2 = self._pipeline.kickoff(ctx, new_b, state)
-        out = self.mystery_fold(frag, state)
-        return {"dist": out}, 1, xbuf2
-    """
-    assert "R6" in _rules(src)
-
-
-def test_r6_passes_on_audited_callees():
-    # kickoff and splice are named in PIPELINE_WINDOW_CALLEES —
-    # whole-carry passes to them are audited, in the main body and in
-    # nested helpers alike (the directed round's second leg)
-    src = """
-    def inceval_pipelined(self, ctx, frag, state, xbuf):
-        def second_leg(new1, x_oe):
-            return self._pipeline.splice(ctx, new1, state, x_oe, leg=2)
-        full = self._pipeline.splice(ctx, state["dist"], state, xbuf)
-        x_oe = self._pipeline.kickoff(ctx, state["dist"], state, leg=2)
-        cur = second_leg(full, x_oe)
-        xbuf2 = self._pipeline.kickoff(ctx, cur, state)
-        return {"dist": cur}, 1, xbuf2
-    """
-    assert "R6" not in _rules(src)
-
-
-def test_r6_non_dict_params_do_not_trip_escape():
-    # frag/ctx are never subscripted with string keys, so passing them
-    # whole to helpers is not a carry escape
-    src = """
-    def inceval_pipelined(self, ctx, frag, state, xbuf):
-        xbuf2 = self._pipeline.kickoff(ctx, state["dist"], state)
-        deg = self.degree_of(frag, ctx)
-        return {"dist": state["dist"] + deg}, 1, xbuf2
-    """
-    assert "R6" not in _rules(src)
-
-
-def test_r6_ignores_functions_without_kickoff():
-    # no pipelined window, no rule: the serial inceval reads the carry
-    # freely
-    src = """
-    def inceval(self, ctx, frag, state):
-        return {"dist": state["anything_at_all"]}, 1
-    """
-    assert "R6" not in _rules(src)
-
-
-def test_r6_reads_before_kickoff_are_free():
-    # the boundary slice (before the kickoff) may read any carry leaf:
-    # the exchange has not been kicked off yet, nothing is in flight
-    src = """
-    def inceval_pipelined(self, ctx, frag, state, xbuf):
-        pre = state["unnamed_leaf"] + state["another_one"]
-        xbuf2 = self._pipeline.kickoff(ctx, pre, state)
-        return {"dist": state["dist"]}, 1, xbuf2
-    """
-    assert "R6" not in _rules(src)
-
-
-def test_r6_shipped_incevals_are_clean():
-    # zero-entry baseline: every shipped inceval_pipelined's window
-    # reads are named in the worker pipeline contract
-    import os
-
-    import libgrape_lite_tpu
-
-    root = os.path.dirname(libgrape_lite_tpu.__file__)
-    for mod in ("models/sssp.py", "models/bfs.py", "models/wcc.py",
-                "models/cdlp.py"):
-        path = os.path.join(root, mod)
-        with open(path) as fh:
-            src = fh.read()
-        assert "inceval_pipelined" in src
-        r6 = [f for f in lint_source(src, mod) if f.rule == "R6"]
-        assert not r6, f"{mod}: {[f.message for f in r6]}"
-
-
 # ---- R7: host syncs on the async pump's dispatch stage --------------------
 
 _PUMP_PATH = "libgrape_lite_tpu/serve/pipeline.py"
@@ -752,84 +609,6 @@ def test_r11_shipped_models_are_clean():
             src = fh.read()
         r11 = [f for f in lint_source(src, rel) if f.rule == "R11"]
         assert not r11, (rel, [f.message for f in r11])
-
-
-# ---- R12: modeled overlap claims must carry a join key --------------------
-
-
-def test_r12_trips_on_unkeyed_literal():
-    src = """
-    def span_brief():
-        return {"engaged": True, "hidden_us_per_round": 12.5}
-    """
-    assert "R12" in _rules(src, "libgrape_lite_tpu/parallel/pipe.py")
-
-
-def test_r12_passes_with_plan_uid():
-    src = """
-    def span_brief():
-        return {
-            "engaged": True,
-            "hidden_us_per_round": 12.5,
-            "plan_uid": "gather:2:128",
-        }
-    """
-    assert "R12" not in _rules(src, "libgrape_lite_tpu/parallel/pipe.py")
-
-
-def test_r12_trips_on_decision_record_without_key():
-    # the pipeline.py idiom: a bound literal grown by subscript
-    # assignments — the union of keys must still carry the join key
-    src = """
-    def decide(plan):
-        dec = {"engaged": False}
-        dec["modeled_exchange_us"] = plan.cost()
-        return dec
-    """
-    assert "R12" in _rules(src, "libgrape_lite_tpu/parallel/pipe.py")
-
-
-def test_r12_passes_when_subscript_supplies_key():
-    src = """
-    def decide(plan):
-        dec = {"engaged": False}
-        dec["modeled_exchange_us"] = plan.cost()
-        dec["plan_uid"] = plan.uid
-        return dec
-    """
-    assert "R12" not in _rules(src, "libgrape_lite_tpu/parallel/pipe.py")
-
-
-def test_r12_accepts_trace_key_and_ignores_unengaged():
-    keyed = """
-    REC = {"engaged": True, "modeled_round_us": 3.0, "trace_key": "t"}
-    """
-    assert "R12" not in _rules(keyed, "libgrape_lite_tpu/models/m.py")
-    # a modeled_* dict that renders no `engaged` verdict is a cost
-    # table, not a decision record — out of scope
-    silent = """
-    COSTS = {"modeled_round_us": 3.0, "hidden_us_per_round": 1.0}
-    """
-    assert "R12" not in _rules(silent, "libgrape_lite_tpu/models/m.py")
-
-
-def test_r12_shipped_decision_records_are_keyed():
-    # zero-entry baseline over the live producers of modeled claims
-    import os
-
-    import libgrape_lite_tpu
-
-    root = os.path.dirname(os.path.dirname(
-        os.path.abspath(libgrape_lite_tpu.__file__)))
-    for rel in (
-        "libgrape_lite_tpu/parallel/pipeline.py",
-        "libgrape_lite_tpu/models/vc2d.py",
-        "libgrape_lite_tpu/worker/worker.py",
-    ):
-        with open(os.path.join(root, rel)) as fh:
-            src = fh.read()
-        r12 = [f for f in lint_source(src, rel) if f.rule == "R12"]
-        assert not r12, (rel, [f.message for f in r12])
 
 
 # ---- baseline round-trip --------------------------------------------------

@@ -3,8 +3,8 @@
 The contract surface:
   * ONE source of pricing constants: the default RateProfile IS the
     pinned v5e rates, and every consumer (pack_cost_model, spgemm
-    price_backends, the partition ledger, the pipeline overlap model,
-    autopilot admission) prices from the same profile object — the
+    price_backends, the partition ledger, autopilot admission)
+    prices from the same profile object — the
     dedupe regression pins that two call sites cannot drift apart;
   * the fitter: synthetic round-trip within 1%, ill-conditioned or
     under-determined sample sets FAIL loudly, a negative intercept is
@@ -407,44 +407,6 @@ def test_partition_decision_carries_profile_label():
     assert "costs" in dec  # auto mode actually priced
 
 
-def test_pipeline_decision_carries_profile_label(monkeypatch):
-    from libgrape_lite_tpu.parallel.pipeline import (
-        PIPELINE_STATS,
-        resolve_pipeline,
-    )
-
-    monkeypatch.setenv("GRAPE_PIPELINE", "1")
-    frag = _ring_frag(96, chords=16, fnum=1)
-    assert resolve_pipeline(frag, app_name="sssp", key="dist") is None
-    dec = PIPELINE_STATS["last_decision"]
-    assert dec["profile"] == "v5e-pinned@pinned"
-    assert "fnum==1" in dec["reason"]
-
-
-def test_pipeline_min_hidden_floor_prices_from_profile(monkeypatch):
-    """The GRAPE_PIPELINE_MIN_HIDDEN_US floor declines from the
-    overlap model priced at the ACTIVE profile, and the decline names
-    both the modeled number and the profile it came from."""
-    from libgrape_lite_tpu.parallel.pipeline import (
-        PIPELINE_STATS,
-        resolve_pipeline,
-    )
-
-    monkeypatch.setenv("GRAPE_PIPELINE", "1")
-    monkeypatch.setenv("GRAPE_PIPELINE_MIN_BYTES", "1")
-    monkeypatch.setenv("GRAPE_PIPELINE_MIN_HIDDEN_US", "1e9")
-    rng = np.random.default_rng(11)
-    n = 600
-    frag = build_fragment(rng.integers(0, n, 4000),
-                          rng.integers(0, n, 4000), None, n, 2)
-    assert resolve_pipeline(frag, app_name="sssp", key="dist") is None
-    dec = PIPELINE_STATS["last_decision"]
-    assert dec["profile"] == "v5e-pinned@pinned"
-    assert dec["modeled_hidden_us"] >= 0
-    assert "v5e-pinned@pinned" in dec["reason"]
-    assert "MIN_HIDDEN_US" in dec["reason"]
-
-
 def test_admission_shed_record_carries_profile(monkeypatch):
     from libgrape_lite_tpu.autopilot.admission import (
         AdmissionConfig,
@@ -538,7 +500,6 @@ def test_lcc_auto_flips_under_swapped_profile(tmp_path, monkeypatch):
 
 def test_partition_and_overlap_reprice_under_profile():
     from libgrape_lite_tpu.fragment.partition import modeled_costs
-    from libgrape_lite_tpu.parallel.pipeline import overlap_model
 
     rng = np.random.default_rng(7)
     n = 1024
@@ -553,13 +514,6 @@ def test_partition_and_overlap_reprice_under_profile():
     assert slow["1d"]["t_round_s"] > base["1d"]["t_round_s"]
     assert slow["2d"]["t_round_s"] > base["2d"]["t_round_s"]
     assert slow["1d"]["max_shard_edges"] == base["1d"]["max_shard_edges"]
-
-    om_base = overlap_model(10_000, 500_000, 1 << 22, profile=pinned)
-    om_slow = overlap_model(10_000, 500_000, 1 << 22, profile=slow_ici)
-    assert om_slow["exchange_s"] == pytest.approx(
-        om_base["exchange_s"] * 1e4
-    )
-    assert om_slow["hidden_frac"] < om_base["hidden_frac"]
 
 
 # ---- degree-weighted rebalancing (satellite c) ----------------------------
@@ -692,7 +646,6 @@ def test_r10_zero_findings_in_migrated_modules():
     root = os.path.join(os.path.dirname(__file__), "..")
     for rel in (
         "libgrape_lite_tpu/fragment/partition.py",
-        "libgrape_lite_tpu/parallel/pipeline.py",
         "libgrape_lite_tpu/ops/spgemm_pack.py",
         "libgrape_lite_tpu/autopilot/admission.py",
         "libgrape_lite_tpu/fleet/budget.py",
@@ -724,12 +677,6 @@ def _good_calibration_block():
         "fallback_notes": ["const+vpu_ops+mxu_ops: x"],
         "surfaces": {"spmv": {"modeled_s": 0.1, "measured_s": 0.11,
                               "samples": 5, "drift_pct": 2.4}},
-        "overlap_truth": {
-            "queries": 0, "joined": 0, "plan_uid": "-",
-            "modeled_hidden_us_per_round": 0.0,
-            "measured_round_us": 0.0, "claim_frac": 0.0,
-            "compile_rounds_excluded": 0, "ok": True,
-        },
     }
 
 

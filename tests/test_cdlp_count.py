@@ -11,7 +11,7 @@ one run) or, since PR 37, the ranks the dynamic branch folds (an empty
 row's sentinel, the table's last slots, the budget's edge), in each sort
 branch, with the CSR's offsets and without; whole
 queries against `benchmarks/references/cdlp.py` on graphs of the same
-shapes, on one fragment and four, serial and pipelined; `CDLPOpt` equal
+shapes, on one fragment, two and four; `CDLPOpt` equal
 to `CDLP`; the contract the offsets rest on (no masked entry inside a
 row of `oe`); and that the serial round lowers without a scatter and
 counts itself in FOLD_STATS as scans.
@@ -40,7 +40,7 @@ from tests.test_segment_fold import _folds_traced
 T = SCAN_TILE  # a second-level tile of the scan holds T * T = 16,384 places
 
 BRANCHES = {
-    # name -> the test hooks that force it (tests/test_pipeline.py)
+    # name -> the test hooks that force it
     "packed": {},
     "dynamic": {"_force_dynamic": True},
     "dynamic_wide_arm": {"_force_dynamic": True, "_u_budget_override": 16},
@@ -293,33 +293,28 @@ def shaped(tmp_path_factory):
     return get
 
 
-# (fnum, GRAPE_PIPELINE): the serial round folds with the CSR's offsets,
-# the pipelined slices look theirs up
-RUNS = [(1, "0"), (4, "0"), (4, "force")]
+# the cuts: one fragment, four, and two (a fragment's labels cross to
+# one peer only)
+RUNS = [1, 4, 2]
 
 
-@pytest.mark.parametrize("fnum,pipeline", RUNS,
-                         ids=["1", "4", "4-pipelined"])
+@pytest.mark.parametrize("fnum", RUNS, ids=["1", "4", "2"])
 @pytest.mark.parametrize("branch", ["packed", "dynamic", "wide"])
 @pytest.mark.parametrize("shape", GRAPHS)
 def test_queries_are_exact_against_the_benchmarks_reference(
-        shape, branch, fnum, pipeline, shaped, monkeypatch):
-    monkeypatch.setenv("GRAPE_PIPELINE", pipeline)
+        shape, branch, fnum, shaped):
     frag, graph = shaped(shape, fnum)
     app = _app(branch)
     rounds = 3
     got = labels_by_id(frag, app, rounds)
-    assert (app._pipeline is not None) == (pipeline == "force")
     want = cdlp_reference.reference(graph, {"max_round": rounds})
     assert (got != want).sum() == 0
 
 
-@pytest.mark.parametrize("fnum,pipeline", RUNS,
-                         ids=["1", "4", "4-pipelined"])
-def test_cdlp_opt_is_cdlp(fnum, pipeline, graph_cache, monkeypatch):
+@pytest.mark.parametrize("fnum", RUNS, ids=["1", "4", "2"])
+def test_cdlp_opt_is_cdlp(fnum, graph_cache):
     """The first-round minimum (by scan too, over the whole CSR) and
     the inherited count: CDLP's bytes on the simple graph."""
-    monkeypatch.setenv("GRAPE_PIPELINE", pipeline)
     frag = graph_cache(fnum)
 
     def run(name):
@@ -376,14 +371,10 @@ def test_serial_round_lowers_without_a_scatter(branch, fnum, graph_cache):
     assert "stablehlo.scatter" not in lowered(_app(branch), frag, False)
 
 
-@pytest.mark.parametrize("name,pipeline,want", [
-    # PEval's first-round minimum and the loop's count
-    ("cdlp_opt", "0", {"scan": 2, "scatter": 0}),
-    # PEval's whole-CSR pass, then a boundary and an interior slice
-    ("cdlp", "force", {"scan": 3, "scatter": 0}),
-    ("cdlp_opt", "force", {"scan": 3, "scatter": 0}),
-])
-def test_every_cdlp_fold_counts_as_a_scan(name, pipeline, want, graph_cache,
-                                          monkeypatch):
-    monkeypatch.setenv("GRAPE_PIPELINE", pipeline)
-    assert _folds_traced(Worker(APP_REGISTRY[name](), graph_cache(4))) == want
+@pytest.mark.parametrize("name,fnum", [
+    ("cdlp_opt", 4), ("cdlp", 2), ("cdlp_opt", 2)])
+def test_every_cdlp_fold_counts_as_a_scan(name, fnum, graph_cache):
+    """PEval's pass (`cdlp_opt`: its first-round minimum) and the
+    loop's count, whatever the cut."""
+    assert _folds_traced(Worker(APP_REGISTRY[name](), graph_cache(fnum))) == {
+        "scan": 2, "scatter": 0}

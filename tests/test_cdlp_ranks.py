@@ -6,7 +6,7 @@ the `lax.cond`'s predicate, the runs before a value its rank, the pull's
 gather fetches ranks, both arms sort, count and fold them, and the fold's
 answer is decoded a row at a time.  Pinned here: whole queries through
 `LoadGraph -> Worker.query` against `benchmarks/references/cdlp.py` on the
-two families' graphs, on one fragment and four, serial and pipelined, with
+two families' graphs, on one fragment, two and four, with
 the budget under, inside and over the universe's fall (the two-key arm
 alone, both arms in one query, the packed arm alone), `cdlp` and `cdlp_opt`;
 the `universe` leaf against the reference's distinct labels pass by pass;
@@ -72,13 +72,11 @@ def _app(name: str, budget):
     return app
 
 
-@pytest.mark.parametrize("fnum,pipeline", RUNS, ids=["1", "4", "4-pipelined"])
+@pytest.mark.parametrize("fnum", RUNS, ids=["1", "4", "2"])
 @pytest.mark.parametrize("arms", sorted(BUDGETS))
 @pytest.mark.parametrize("graph,name", [
     ("surrogate", "cdlp"), ("surrogate", "cdlp_opt"), ("kronecker", "cdlp")])
-def test_ranks_are_exact_in_both_arms(graph, name, arms, fnum, pipeline,
-                                      family, monkeypatch):
-    monkeypatch.setenv("GRAPE_PIPELINE", pipeline)
+def test_ranks_are_exact_in_both_arms(graph, name, arms, fnum, family):
     frag, ref_graph, seen = family(graph, fnum)
     budget = BUDGETS[arms]
     if budget is None:
@@ -86,7 +84,6 @@ def test_ranks_are_exact_in_both_arms(graph, name, arms, fnum, pipeline,
         budget = (seen[1] + seen[-1]) // 2
     app = _app(name, budget)
     got = labels_by_id(frag, app, ROUNDS)
-    assert (app._pipeline is not None) == (pipeline == "force")
     want = cdlp_reference.reference(ref_graph, {"max_round": ROUNDS})
     assert (got != want).sum() == 0
     stats = CDLP_STATS.snapshot()
@@ -96,12 +93,6 @@ def test_ranks_are_exact_in_both_arms(graph, name, arms, fnum, pipeline,
     counted = [s + pad for s in seen]
     if name == "cdlp_opt":
         counted[0] = -1  # its first pass is a neighbour minimum: no predicate
-    if pipeline == "force":
-        # a pipelined round's table holds the other fragments' interior rows
-        # as of the round before: labels nobody reads, and still counted
-        assert stats["universe"][0] == counted[0]
-        assert all(u >= c for u, c in zip(stats["universe"], counted))
-        return
     assert stats["universe"] == counted
     packed = sum(0 <= c <= budget for c in counted)
     assert stats["packed_passes"] == packed

@@ -306,14 +306,14 @@ def test_apps_that_offer_no_such_round_keep_the_parents_runner(name, params, gra
     assert f"tensor<{worker_module._RECORD_WORDS}xui32>" in got
 
 
-def test_bfs_other_runners_never_see_the_round(graph_cache, budgets, monkeypatch):
-    """The batched, chunked and pipelined runners and the serial runner under
-    a dyn overlay lower to one text whether the serial runner of the same
-    graph would follow its frontier or not, and none holds a conditional."""
+def test_bfs_other_runners_never_see_the_round(graph_cache, budgets):
+    """The batched and chunked runners and the serial runner under a dyn
+    overlay lower to one text whether the serial runner of the same graph
+    would follow its frontier or not, and none holds a conditional."""
     from libgrape_lite_tpu.dyn import DynGraph, RepackPolicy
     from tests.test_dyn import ADDS, _mutable_fragment
 
-    one, four = graph_cache(1), graph_cache(4)
+    one = graph_cache(1)
     dg = DynGraph(_mutable_fragment(), RepackPolicy(threshold=0.9, capacity=64))
     assert dg.ingest(ADDS)["mode"] == "overlay"
 
@@ -327,12 +327,6 @@ def test_bfs_other_runners_never_see_the_round(graph_cache, budgets, monkeypatch
         state = w._place_state(w.app.init_state(one, source=6))
         out["chunked"] = w._chunk_runner_for(4, 0, state).lower(
             one.dev, *split(w, state), jnp.int32(1), jnp.int32(0)).as_text()
-        monkeypatch.setenv("GRAPE_PIPELINE", "force")
-        w = Worker(APP_REGISTRY["bfs"](), four)
-        state = w._place_state(w.app.init_state(four, source=6))
-        assert w._pipelined() is not None and w.app.frontier_budget is None
-        out["pipelined"] = w._runner_for(0, state).lower(four.dev, *split(w, state)).as_text()
-        monkeypatch.delenv("GRAPE_PIPELINE")
         out["overlay"] = serial_text(APP_REGISTRY["bfs"](), dg.fragment, source=0)
         assert is_parents(out["overlay"], "bfs_overlay")
         return out

@@ -1,9 +1,8 @@
 """Gang-wide telemetry (PR 20): the clock handshake, per-rank
 sidecars + the rank-0 assembler, breach-vote flow riders + the shared
 incident id, the distributed flight recorder's byte-verified gang
-bundle, the overlap truth meter, and the single-process byte-identity
-guarantees (solo events carry no rank stamp; the first fused dispatch
-marks `compiled` so truth.py can exclude it)."""
+bundle, and the single-process byte-identity guarantees (solo events
+carry no rank stamp; the first fused dispatch marks `compiled`)."""
 
 import json
 import os
@@ -15,7 +14,6 @@ import pytest
 
 from libgrape_lite_tpu import obs
 from libgrape_lite_tpu.obs import gang
-from libgrape_lite_tpu.obs import truth
 from libgrape_lite_tpu.obs.tracer import Tracer
 
 _SCRIPTS = os.path.join(os.path.dirname(__file__), "..", "scripts")
@@ -273,103 +271,7 @@ def test_incident_id_deterministic():
     assert a != gang.incident_id({"votes": [[4, 4, 0]], "rounds": 3})
 
 
-# ---- overlap truth meter --------------------------------------------------
-
-
-def _q(pipe, rounds, **args):
-    a = {"pipeline": pipe, "rounds": rounds}
-    a.update(args)
-    return {"ph": "X", "name": "query", "pid": 0, "tid": 0,
-            "ts": 1000.0, "dur": 5000.0, "args": a}
-
-
-_PIPE = {"engaged": True, "plan_uid": "p1", "mode": "spmv",
-         "hidden_us_per_round": 50.0}
-
-
-def test_truth_fused_join_and_claim():
-    rep = truth.truth_report([_q(_PIPE, 4, device_wait_us=1000.0)])
-    assert rep["queries"] == 1 and rep["joined"] == 1
-    row = rep["rows"][0]
-    assert row["plan_uid"] == "p1"
-    assert row["measured_round_us"] == 200.0  # 1000 / (4 rounds + peval)
-    assert row["claim_frac"] == 0.25
-    assert rep["ok"] is True
-    brief = truth.block_brief(rep)
-    assert brief["plan_uid"] == "p1" and brief["ok"] is True
-    assert brief["measured_round_us"] == 200.0
-
-
-def test_truth_excludes_compile_rounds():
-    rep = truth.truth_report(
-        [_q(_PIPE, 4, device_wait_us=1000.0, compiled_us=9000.0)])
-    assert rep["joined"] == 0
-    assert rep["compile_rounds_excluded"] == 1
-    assert rep["ok"] is True  # vacuously: nothing joined, nothing lied
-
-
-def test_truth_overclaim_fails():
-    pipe = dict(_PIPE, hidden_us_per_round=500.0)
-    rep = truth.truth_report([_q(pipe, 4, device_wait_us=1000.0)])
-    assert rep["rows"][0]["claim_frac"] == 2.5
-    assert rep["ok"] is False
-    assert truth.block_brief(rep)["ok"] is False
-
-
-def test_truth_stepwise_joins_superstep_medians():
-    q = _q(dict(_PIPE, plan_uid="p2"), 3)  # no fused device split
-    steps = [
-        {"ph": "X", "name": "superstep", "pid": 0, "tid": 0,
-         "ts": 1500.0 + i * 500, "dur": 400.0,
-         "args": {"device_wait_us": w}}
-        for i, w in enumerate((100.0, 200.0, 300.0))
-    ]
-    # a compile-carrying superstep inside the window is excluded
-    steps.append({"ph": "X", "name": "superstep", "pid": 0, "tid": 0,
-                  "ts": 1400.0, "dur": 50.0,
-                  "args": {"device_wait_us": 9999.0, "compiled_us": 1.0}})
-    # another rank's superstep never joins this query's window
-    steps.append({"ph": "X", "name": "superstep", "pid": 1, "tid": 0,
-                  "ts": 1600.0, "dur": 50.0,
-                  "args": {"device_wait_us": 7777.0}})
-    rep = truth.truth_report([q] + steps)
-    assert rep["joined"] == 1
-    assert rep["rows"][0]["measured_round_us"] == 200.0  # the median
-    assert rep["rows"][0]["rounds_measured"] == 3
-    assert rep["compile_rounds_excluded"] == 1
-
-
-def test_truth_harvest_rows(monkeypatch):
-    from libgrape_lite_tpu.ops import calibration as calib
-
-    monkeypatch.setenv(calib.HARVEST_ENV, "1")
-    calib.reset_harvest()
-    try:
-        events = [_q(_PIPE, 4, device_wait_us=1000.0)]
-        brief = {"plan_uid": "p1", "hidden_us_per_round": 50.0,
-                 "boundary_edges": 10, "interior_edges": 90,
-                 "exchange_bytes": 4096}
-        assert truth.harvest_report(events, pipe_brief=brief) == 1
-        rows = [s for s in calib.harvested_samples()
-                if s["surface"] == "overlap"]
-        assert len(rows) == 1
-        assert rows[0]["plan_uid"] == "p1"
-        # fused: 4 rounds + peval = 5 measured dispatch units
-        assert rows[0]["vpu_ops"] == (10 + 90) * 5
-        assert rows[0]["modeled_hidden_us_per_round"] == 50.0
-    finally:
-        calib.reset_harvest()
-
-
-def test_truth_harvest_noop_disarmed(monkeypatch):
-    from libgrape_lite_tpu.ops import calibration as calib
-
-    monkeypatch.delenv(calib.HARVEST_ENV, raising=False)
-    events = [_q(_PIPE, 4, device_wait_us=1000.0)]
-    assert truth.harvest_report(events, pipe_brief={"plan_uid": "p1"}) == 0
-
-
-# ---- worker compile marks (the honesty rule's producer) -------------------
+# ---- worker compile marks --------------------------------------------------
 
 
 def test_fused_first_query_marks_compiled():
@@ -384,8 +286,8 @@ def test_fused_first_query_marks_compiled():
     qs = [e for e in obs.history()
           if e["ph"] == "X" and e["name"] == "query"]
     assert len(qs) == 2
-    # the first dispatch carried trace+compile: stamped so truth.py
-    # excludes it from the measured round wall
+    # the first dispatch carried trace+compile: stamped, so a reader
+    # can keep it out of a round's wall
     assert "compiled_us" in qs[0]["args"]
     assert "compiled_us" not in qs[1]["args"]
     assert "device_wait_us" in qs[1]["args"]
@@ -437,19 +339,3 @@ def test_bench_schema_declares_gang_blocks():
     bad = dict(rec, obs_gang=dict(rec["obs_gang"], complete=1))
     assert any("obs_gang.complete" in e
                for e in cbs.validate_record(bad))
-
-
-def test_bench_schema_checks_nested_overlap_truth():
-    _scripts_path()
-    import check_bench_schema as cbs
-
-    rec = {
-        "metric": "m", "value": 1.0, "unit": "s", "vs_baseline": 1.0,
-        "pipeline": {"overlap_truth": {"queries": "three"}},
-    }
-    errs = cbs.validate_record(rec)
-    assert any(e.startswith("pipeline.overlap_truth.queries")
-               for e in errs)
-    assert any("missing required field" in e
-               and e.startswith("pipeline.overlap_truth")
-               for e in errs)

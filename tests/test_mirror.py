@@ -72,6 +72,24 @@ def test_mirror_bytes_win(graph_cache):
     assert plan.bytes_mirror < plan.bytes_all_gather
 
 
+def test_exchange_bytes_one_ledger():
+    """MirrorPlan's byte properties read `exchange_bytes_ledger`, the
+    one model the auto gate and the partition planner price from: no
+    private copy of "exchange bytes" that can drift apart."""
+    from libgrape_lite_tpu.parallel.mirror import (
+        build_mirror_plan,
+        exchange_bytes_ledger,
+    )
+
+    frag = _rand_frag(4, n=700, e=5000, seed=23)
+    plan = build_mirror_plan(frag, "ie")
+    assert plan is not None
+    led = exchange_bytes_ledger(frag.fnum, frag.vp, plan.m)
+    assert plan.bytes_all_gather == led["gather"]
+    assert plan.bytes_mirror == led["mirror"]
+    assert exchange_bytes_ledger(frag.fnum, frag.vp)["mirror"] is None
+
+
 def test_mirror_auto_gate(monkeypatch, graph_cache):
     """Default (auto) engages mirrors only on a clear ICI-bytes win at
     a size where bytes dominate; env forces override both ways."""

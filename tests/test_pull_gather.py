@@ -30,7 +30,7 @@ from tests.conftest import GATHER_BUDGET as BUDGET, gather_took
 
 # name -> (dtype, table length, stream length).  A CSR's stream is
 # whole 128s (the loader's rule) but not whole 1024s on a shard; the
-# dyn overlay's and a pipelined slice's may be anything.
+# dyn overlay's may be anything.
 KERNEL_CASES = {
     "f32_whole_1024s": ("float32", 40 * 128, 2 * 1024),
     "s32_table_ragged_whole_128s": ("int32", 5000, 5 * 128),

@@ -7,8 +7,8 @@ exchange, must take the kernel for every pull, hand it a table and a
 stream it can take, and answer with the bytes of the same query through
 XLA's gather, inside `shard_map(while_loop)`.  tests/test_pull_gather.py
 pins the kernel's own bits and the choice; the kernel's other callers
-(pipelined slices, the dyn overlay, the batched runner's lanes) are
-pinned beside their own tests.
+(the dyn overlay, the batched runner's lanes) are pinned beside their
+own tests.
 """
 
 import numpy as np

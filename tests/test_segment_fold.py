@@ -344,37 +344,3 @@ def test_dyn_overlay_folds_by_scatter(app):
     w = Worker(APP_REGISTRY[app](), dg.fragment)
     kw = {} if app == "wcc" else {"source": 0}
     assert _folds_traced(w, **kw) == {"scan": 1, "scatter": 1}
-
-
-@pytest.mark.parametrize("app", ["sssp", "bfs", "wcc"])
-def test_pipelined_slices_fold_by_scatter(app, monkeypatch):
-    """The boundary and interior slices of a pipelined round have no
-    offsets of their own: two scatters, no scan, and (min being exact
-    under any grouping) the bytes of the serial round's scan."""
-    from tests.test_pipeline import _rand_frag, _run
-
-    frag = _rand_frag(2)
-    monkeypatch.setenv("GRAPE_PIPELINE", "force")
-    w = Worker(APP_REGISTRY[app](), frag)
-    kw = {} if app == "wcc" else {"source": 0}
-    assert _folds_traced(w, **kw) == {"scan": 0, "scatter": 2}
-    assert w.app._pipeline is not None
-    serial, _, _ = _run(app, frag, monkeypatch, "0")
-    piped, _, _ = _run(app, frag, monkeypatch, "force")
-    assert piped == serial
-
-
-def test_pipelined_pagerank_declines_with_its_reason(monkeypatch):
-    """A float sum that groups by tile regroups under a split, so
-    PageRank declines the pipeline and runs its serial round."""
-    from libgrape_lite_tpu.parallel.pipeline import PIPELINE_STATS
-    from tests.test_pipeline import _rand_frag, _run
-
-    frag = _rand_frag(2)
-    serial, _, _ = _run("pagerank", frag, monkeypatch, "0")
-    piped, _, app = _run("pagerank", frag, monkeypatch, "force")
-    assert app._pipeline is None
-    assert piped == serial
-    decision = PIPELINE_STATS["last_decision"]
-    assert decision["app"] == "PageRank" and not decision["engaged"]
-    assert "tile partial sums regroup under a split" in decision["reason"]

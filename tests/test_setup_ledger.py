@@ -250,28 +250,18 @@ def test_nested_trace_durations_are_merged_not_summed():
 # ---- per-fragment structures: the miss branch only -------------------------
 
 
-@pytest.mark.parametrize("what", ["mirror_plan", "boundary_split"])
+@pytest.mark.parametrize("what", ["mirror_plan"])
 def test_derived_structure_records_its_miss_alone(what):
+    from libgrape_lite_tpu.parallel.mirror import build_mirror_plan
+
     frag = rand_frag(4)
     SETUP_LEDGER.reset()
-    if what == "mirror_plan":
-        from libgrape_lite_tpu.parallel.mirror import build_mirror_plan
-
-        def build():
-            return build_mirror_plan(frag, "ie")
-    else:
-        from libgrape_lite_tpu.fragment.edgecut import boundary_split
-
-        def build():
-            return boundary_split(frag, ("ie",))
-
-    first = build()
+    first = build_mirror_plan(frag, "ie")
     (rec,) = records()
     assert rec["name"] == "derived." + what and rec["parent"] is None
     assert rec["args"]["fnum"] == 4
-    if what == "mirror_plan":
-        assert rec["args"]["m"] == first.m
-    assert build() is first
+    assert rec["args"]["m"] == first.m
+    assert build_mirror_plan(frag, "ie") is first
     assert len(SETUP_LEDGER) == 1
 
 

@@ -1,114 +1,27 @@
-"""First-class 2-D path (PR 19): pipelined SUMMA rounds + serve/fleet.
+"""The 2-D (vertex-cut) fragment as a fleet citizen (PR 19).
 
-Pins the tentpole contracts:
-
-* the pipelined SUMMA round (phase-split tile fold, row-psum of chunk
-  j's partials overlapped with chunk j+1's fold) is BYTE-identical to
-  the unpipelined 2-D round AND to the 1-D edge-cut pull for SSSP/BFS/
-  WCC at fnum 4 (k=2) — min regrouping over disjoint static phase
-  slices is exact;
-* every resolve decision (engage or decline) carries the rate-profile
-  label and the modeled hidden-µs — the bench `vc2d_pipeline` lane
-  gates on both, so the record can never go silent;
-* vc2d fragments are fleet citizens: release/restore_device round-trips
-  the tile buffers byte-identically, re-admission compiles nothing,
+* release/restore_device round-trips the tile buffers byte for byte,
+  host reads survive an eviction, re-admission compiles nothing,
   `fragment_bytes` prices the host tile blocks, and `mesh_kind` keys
   session compatibility so a 2-D app can never coalesce with a 1-D one;
 * batched vc2d dispatch (the `vc_source_carry` batch_query_key path)
-  stays lane-identical to sequential queries;
+  stays lane-identical to sequential queries, and a dyn session on a
+  vertex-cut fragment is refused at construction;
 * `tile_stats` publishes the fill / pad-waste profile into the
-  "vc_tiles" federation namespace (satellite: 2-D skew is scrapeable).
+  "vc_tiles" federation namespace.
+
+Moved unchanged from tests/test_vc2d_pipeline.py when the superstep
+pipeline went (PR 42): none of these cases touched it.
 """
 
 import numpy as np
 import pytest
 
 from tests.test_partition2d import (
-    _apps_2d,
     _assert_byte_identical,
     _result_dict,
     _vc_frag,
 )
-
-
-def _vc_run(app_cls, frag, monkeypatch, pipeline, **kw):
-    monkeypatch.setenv("GRAPE_PIPELINE", pipeline)
-    out, w = _result_dict(app_cls(), frag, **kw)
-    return out, w
-
-
-# ---- the three-way identity sweep (tentpole acceptance) -------------------
-
-
-@pytest.mark.parametrize("app_name", ["sssp", "bfs", "wcc"])
-def test_vc2d_pipelined_three_way_identity(graph_cache, app_name,
-                                           monkeypatch):
-    """Pipelined 2-D == unpipelined 2-D == 1-D, per oid, at fnum 4
-    (k=2), with matching round counts — the phase regrouping argument
-    made executable."""
-    cls1, cls2, kw, weighted = _apps_2d()[app_name]
-    frag2d = _vc_frag(4, weighted)
-    r1d, w1 = _result_dict(cls1(), graph_cache(4), **kw)
-    r2d, w2 = _vc_run(cls2, frag2d, monkeypatch, "0", **kw)
-    rp, wp = _vc_run(cls2, frag2d, monkeypatch, "force", **kw)
-    assert wp.app._pipeline is not None
-    assert wp.app._pipeline.mode == "vc2d"
-    _assert_byte_identical(rp, r2d)
-    _assert_byte_identical(rp, r1d)
-    assert w1.rounds == w2.rounds == wp.rounds
-
-
-def test_vc2d_decision_carries_profile_and_hidden_us(monkeypatch):
-    """Engaged or declined, the decision record names the active rate
-    profile and the modeled hidden-µs (the bench lane's exit-2 gate
-    reads both) and the span brief carries the phase geometry."""
-    from libgrape_lite_tpu.models import SSSPVC2D
-    from libgrape_lite_tpu.parallel.pipeline import PIPELINE_STATS
-
-    frag = _vc_frag(4, weighted=True)
-    _, w = _vc_run(SSSPVC2D, frag, monkeypatch, "force", source=6)
-    pl = w.app._pipeline
-    assert pl is not None
-    dec = pl.decision
-    assert dec["engaged"] is True
-    assert dec["profile"] and isinstance(dec["profile"], str)
-    assert dec["modeled_hidden_us"] >= 0.0
-    brief = pl.span_brief()
-    assert brief["mode"] == "vc2d"
-    assert brief["engaged"] is True
-    assert 0.0 <= brief["modeled_hidden_frac"] <= 1.0
-    assert pl.split % 128 == 0 and 0 < pl.split
-    # a decline is recorded too — k==1 has no row psum to hide
-    f1 = _vc_frag(1, weighted=True)
-    _, w1 = _vc_run(SSSPVC2D, f1, monkeypatch, "force", source=6)
-    assert w1.app._pipeline is None
-    dec = PIPELINE_STATS["last_decision"]
-    assert dec["engaged"] is False
-    assert "k==1" in dec["reason"]
-    assert "profile" in dec
-
-
-def test_vc2d_pipelined_runner_cached_separately(monkeypatch):
-    """Serial and pipelined 2-D compiles never share a runner-cache
-    entry (the plan uid rides trace_key), and the uid is a stable
-    content fingerprint — repeat queries reuse the compiled runner."""
-    from libgrape_lite_tpu.models import SSSPVC2D
-    from libgrape_lite_tpu.worker.worker import Worker
-
-    frag = _vc_frag(4, weighted=True)
-    _, ws = _vc_run(SSSPVC2D, frag, monkeypatch, "0", source=6)
-    _, wp = _vc_run(SSSPVC2D, frag, monkeypatch, "force", source=6)
-    assert ws.app._pipeline_uid == "-"
-    assert wp.app._pipeline_uid == wp.app._pipeline.uid
-    assert ws.app.trace_key() != wp.app.trace_key()
-
-    monkeypatch.setenv("GRAPE_PIPELINE", "force")
-    w = Worker(SSSPVC2D(), frag)
-    w.query(source=6)
-    misses = w.runner_cache_stats["misses"]
-    w.query(source=6)
-    assert w.runner_cache_stats["misses"] == misses
-    assert w.runner_cache_stats["hits"] >= 1
 
 
 # ---- vertexcut residency + device reads (satellite a) ---------------------

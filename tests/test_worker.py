@@ -270,3 +270,81 @@ def test_a_failed_batch_and_the_last_batch(fails_in, monkeypatch):
         monkeypatch.undo()
     w.query_batch([{"source": 5}, {"source": 6}])
     assert (w.batch_result_values(1) != last).any()
+
+
+# ---- one round, three loops: fused, chunked, stepwise ------------------------
+
+# the five pull apps, their query, and the cut-independent graph they run on
+# (tests/conftest.rand_frag: 900 ids, 7,000 edges, f32 weights)
+PULL_APPS = {
+    "sssp": {"source": 0},
+    "bfs": {"source": 0},
+    "wcc": {},
+    "pagerank": {"delta": 0.85, "max_round": 10},
+    "cdlp": {"max_round": 10},
+}
+
+
+@pytest.fixture(scope="module")
+def fused_run():
+    """(fragment, values' bytes, rounds, terminate info) of the fused
+    query, once per app and cut."""
+    from libgrape_lite_tpu.models import APP_REGISTRY
+    from libgrape_lite_tpu.worker.worker import Worker
+    from tests.conftest import rand_frag
+
+    frags, made = {}, {}
+
+    def get(app, fnum):
+        if (app, fnum) not in made:
+            if fnum not in frags:
+                frags[fnum] = rand_frag(fnum)
+            w = Worker(APP_REGISTRY[app](), frags[fnum])
+            w.query(**PULL_APPS[app])
+            made[app, fnum] = (
+                frags[fnum], np.asarray(w.result_values()).tobytes(),
+                w.rounds, w.get_terminate_info())
+        return made[app, fnum]
+
+    return get
+
+
+@pytest.mark.parametrize("chunk", [1, 3])
+@pytest.mark.parametrize("fnum", [1, 2, 4])
+@pytest.mark.parametrize("app", sorted(PULL_APPS))
+def test_chunked_segments_compose_to_the_fused_run(app, fnum, chunk,
+                                                   fused_run):
+    """`_make_chunk_runner`'s contract: segments of `chunk` rounds, each
+    entered at the (active, round) the last one left, as `_query_guarded`
+    drives them, end where the fused loop ends, with its bytes."""
+    from libgrape_lite_tpu.guard import GuardConfig
+    from libgrape_lite_tpu.models import APP_REGISTRY
+    from libgrape_lite_tpu.worker.worker import Worker
+
+    frag, want, rounds, terminate = fused_run(app, fnum)
+    w = Worker(APP_REGISTRY[app](), frag)
+    w.query(guard=GuardConfig(policy="halt", every=chunk), **PULL_APPS[app])
+    # through the chunk runner of this cadence, and no fused runner
+    kinds = {k[0] if isinstance(k[0], str) else "fused"
+             for k in w._runner_cache}
+    assert "chunk" in kinds and "fused" not in kinds
+    assert {k[1] for k in w._runner_cache if k[0] == "chunk"} == {chunk}
+    assert np.asarray(w.result_values()).tobytes() == want
+    assert (w.rounds, w.get_terminate_info()) == (rounds, terminate)
+    assert w.guard_report["probes"] >= -(-rounds // chunk)
+    assert not w.guard_report["breaches"]
+
+
+@pytest.mark.parametrize("fnum", [1, 4])
+@pytest.mark.parametrize("app", sorted(PULL_APPS))
+def test_stepwise_equals_fused(app, fnum, fused_run):
+    """One jitted round a dispatch (`_compile_single_step`) is the fused
+    loop's round: the same bytes after the same number of rounds."""
+    from libgrape_lite_tpu.models import APP_REGISTRY
+    from libgrape_lite_tpu.worker.worker import Worker
+
+    frag, want, rounds, terminate = fused_run(app, fnum)
+    w = Worker(APP_REGISTRY[app](), frag)
+    w.query_stepwise(**PULL_APPS[app])
+    assert np.asarray(w.result_values()).tobytes() == want
+    assert (w.rounds, w.get_terminate_info()) == (rounds, terminate)
