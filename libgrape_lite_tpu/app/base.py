@@ -206,30 +206,50 @@ class AppBase:
     # entries; the loop carries the list beside the state and takes
     # that round wherever the last vote and the list's entries fit, the
     # dense `inceval` elsewhere (ops/segment.py has the round itself).
-    # Both rounds give the same state and the same vote.  What the
-    # offer rests on is the app's to observe in `init_state`,
-    # statically; every other runner keeps `inceval`.
+    # Without a threshold both rounds give the same state and the same
+    # vote (BFS).  An offer may carry a threshold as well (SSSP's
+    # near/far): with `frontier_step` set to a bucket's width the loop
+    # also carries a scalar `below`, lists only the improved rows whose
+    # value (`frontier_values`) is under it, and where that list runs
+    # empty moves `below` to the end of the first bucket that holds a
+    # row at or over it and refills the list from the state; the vote
+    # is then the list's length, 0 where no row is left at any value,
+    # and the fixed point is the dense loop's, reached in another
+    # order.  What the offer rests on is the app's to observe in
+    # `init_state`, statically; every other runner keeps `inceval`.
     frontier_budget = None
+    frontier_step = None
 
     def frontier_mask(self, state, new_state=None):
         """V-wide mask of the rows whose proposals no round has applied
         yet: of the state PEval returned, the rows that hold a value
         (one such row, a query's source, is the first list; more start
         with a dense round); given the state a dense round made of
-        `state`, the rows that round improved, the list after it."""
+        `state`, the rows that round improved, the list after it (with
+        a threshold: those of them under it)."""
+        raise NotImplementedError
+
+    def frontier_values(self, state):
+        """The V-wide values the round relaxes, floats, +inf where a
+        row holds none: what a loop that carries a threshold
+        (`frontier_step`) compares with it."""
         raise NotImplementedError
 
     def frontier_csr(self, frag):
         """The CSR the round pushes along.  Here and in
         `inceval_frontier` `frag` is the shard's block as the runner
         receives it, `[1, ...]` leaves unsqueezed
-        (`ops/segment._block_at`)."""
+        (`ops/segment._block_at`); `state` is the carried leaves, so
+        an app that makes the offer keeps no ephemeral ones."""
         raise NotImplementedError
 
-    def inceval_frontier(self, frag, state, front, lo, count):
+    def inceval_frontier(self, frag, state, front, lo, count, below=None):
         """`inceval` from the rows `front` lists (`lo`, `count`: their
         `ops/segment.frontier_spans` in `frontier_csr`):
-        `(state', active, front')`, state and vote `inceval`'s own."""
+        `(state', active, front')`, the carried leaves alone.  Without
+        a threshold state and vote are `inceval`'s own; with `below`,
+        `front'` holds the improved rows under it and `active` counts
+        them."""
         raise NotImplementedError
 
     # 0 means "run until the termination vote fires"

@@ -455,16 +455,20 @@ def segment_top_label(count, label, segment_ids, num_rows: int,
 # ---- a round whose work follows its frontier -----------------------------
 #
 # The folds above touch every entry and every row whatever a round has
-# to do.  A monotone min relaxation (BFS, and by the same rule SSSP and
+# to do.  A monotone min relaxation (BFS, SSSP, and by the same rule
 # WCC) needs only the rows that improved last round to propose again:
 # `frontier_spans`, `frontier_relax` and `frontier_rows` are that round
 # at static shapes, a list of at most B rows whose adjacency holds at
 # most C entries, for one fragment's unbatched state.  Nothing in them
 # is wider than C but the update of the V-wide values in place, and
 # `frontier_rows`, which a loop runs where it turns from dense rounds to
-# these (models/bfs.py, worker/worker.py `_make_runner`).
+# these and where it refills the list from the state (models/bfs.py,
+# models/sssp.py, worker/worker.py `_frontier_loop`).
 
 FRONTIER_SCOPE = "grape.frontier.compact"
+# the threshold's step and the refill that follows it (a loop whose
+# list holds the rows under a threshold only: SSSP's near/far)
+ADVANCE_SCOPE = "grape.frontier.advance"
 
 
 def _at(table, idx):
@@ -502,8 +506,13 @@ def frontier_spans(front, row_ptr):
         return lo, count, count.sum()
 
 
+def _bits(x, dtype):
+    """`x`'s 32 bits as `dtype`: nothing where that is what it is."""
+    return x if x.dtype == dtype else lax.bitcast_convert_type(x, dtype)
+
+
 def frontier_relax(values, front, lo, count, edge_nbr, entries: int,
-                   add=1, absent=None):
+                   add=1, absent=None, below=None):
     """One push round of a min relaxation from the rows `front` lists:
     `(values', front', active)`.
 
@@ -514,17 +523,25 @@ def frontier_relax(values, front, lo, count, edge_nbr, entries: int,
     updates) and comes down to the row's other slots by a running
     maximum, as `run_position`'s openers do.  A slot then reads its
     row's value and first entry (one gather of pairs from the B-wide
-    table), its neighbour `edge_nbr[entry]` and what the neighbour
-    holds; the candidate is the row's value plus the constant `add`,
-    nothing where the row holds `absent`; `edge_nbr` may be a shard's
-    block `[1, Ep]` (see `_block_at`).  Candidates that improve a
-    neighbour are folded into `values` by one `.at[].min` of C updates
-    (pads fall out of bounds and are dropped, the trash row of
-    `segment_reduce`), and the neighbours that improved, each once and
-    ascending, are the next list: a sort of the C targets, the first of
-    each run kept, and a second sort that moves those to the front.
-    `active` counts them: the rows a dense round of the same relaxation
-    would find changed.
+    table; two gathers where the value is 64 bits wide), its neighbour
+    `edge_nbr[entry]` and what the neighbour holds; the candidate is
+    the row's value plus `add`, a constant or a value an entry (an
+    array over the CSR's entries, read at the C slots: a weighted
+    push), nothing where the row holds `absent`; `edge_nbr` and such an
+    `add` may be a shard's block `[1, Ep]` (see `_block_at`).
+    Candidates that improve a neighbour are folded into
+    `values` by one `.at[].min` of C updates (pads fall out of bounds
+    and are dropped, the trash row of `segment_reduce`), and the
+    neighbours that improved, each once and ascending, are the next
+    list: a sort of the C targets, the first of each run kept, and a
+    second sort that moves those to the front.  Given `below`, a scalar
+    threshold, the next list keeps only the improved rows whose new
+    value is under it (a row's new value is its least candidate, so it
+    is under the threshold where any candidate that improves it is);
+    the others are improved all the same and left to the caller, who
+    finds them by their values when the threshold moves on.
+    `active` counts the list: without a threshold the rows a dense
+    round of the same relaxation would find changed.
     Where `active` exceeds the list's places the list is cut short and
     the caller's next round has to be a dense one."""
     rows, cap = values.shape[0], front.shape[0]
@@ -539,18 +556,35 @@ def frontier_relax(values, front, lo, count, edge_nbr, entries: int,
         live = slot < upto[-1]
     with jax.named_scope("grape.pull.gather"):
         held = _at(values, jnp.minimum(front, rows - 1))
-        pair = _at(jnp.stack([lo - first, held], axis=1), owner)
-        entry = jnp.where(live, pair[:, 0] + slot, 0)
+        # the row's first entry and its value in one gather where the
+        # value is 32 bits wide (its bits ride as `int32`): what a
+        # gather costs here is its indices (PERF.md section 6, PR 40)
+        if held.dtype.itemsize == 4:
+            pair = _at(jnp.stack([lo - first, _bits(held, jnp.int32)],
+                                 axis=1), owner)
+        else:
+            pair = _at(lo - first, owner), _at(held, owner)
+
+        def column(i):
+            # sliced anew at each use: BFS's lowered text, which reads
+            # the pairs' second column twice, stays what it was
+            return pair[i] if isinstance(pair, tuple) else pair[:, i]
+
+        entry = jnp.where(live, column(0) + slot, 0)
         nbr = _block_at(edge_nbr, entry)
-        cand = pair[:, 1] + add
+        cand = _bits(column(1), held.dtype) + (
+            add if jnp.ndim(add) == 0 else _block_at(add, entry))
         if absent is not None:
-            live = jnp.logical_and(live, pair[:, 1] != absent)
+            live = jnp.logical_and(
+                live, _bits(column(1), held.dtype) != absent)
         old = _at(values, jnp.minimum(nbr, rows - 1))
         target = jnp.where(
             jnp.logical_and(live, cand < old), nbr, rows)
     with jax.named_scope("grape.pull.fold"):
         values = values.at[target].min(cand, mode="drop")
     with jax.named_scope(FRONTIER_SCOPE):
+        if below is not None:
+            target = jnp.where(cand < below, target, rows)
         hit = lax.sort(target, is_stable=False)
         opener = jnp.logical_and(hit != _shift(hit, 1, -1), hit < rows)
     with jax.named_scope("grape.app.update"):
@@ -562,10 +596,11 @@ def frontier_relax(values, front, lo, count, edge_nbr, entries: int,
     return values, front, active
 
 
-def frontier_rows(mask, cap: int):
+def frontier_rows(mask, cap: int, scope: str = FRONTIER_SCOPE):
     """The first `cap` set rows of the V-wide `mask`, ascending, the
-    list padded with the row count: what a loop needs once, where it
-    turns from dense rounds to `frontier_relax`.
+    list padded with the row count: what a loop needs where it turns
+    from dense rounds to `frontier_relax`, and where it refills its
+    list from the state (under `scope`, so that a trace tells the two).
 
     No V-wide gather, scatter or sort: the set rows are counted a tile
     of SCAN_TILE rows at a time (dense), the tiles' running sum is
@@ -574,7 +609,7 @@ def frontier_rows(mask, cap: int):
     the mask) and the place's row in it is where the tile's own running
     count reaches what the tiles before it lack."""
     rows = mask.shape[0]
-    with jax.named_scope(FRONTIER_SCOPE):
+    with jax.named_scope(scope):
         pad = -rows % SCAN_TILE
         tiles = (jnp.pad(mask, (0, pad)) if pad else mask).reshape(
             -1, SCAN_TILE).astype(jnp.int32)

@@ -53,11 +53,18 @@ _TERMINATE_SCOPE = "grape.worker.terminate"
 # (for BFS: the widest level, and the reached vertices less the
 # source).  `frontier_rounds` counts the rounds that followed their
 # frontier (`_frontier_loop`; 0 where the app offers no such round).
+# Where that loop carries a threshold (SSSP's near/far) a round's vote
+# is the length of the list it leaves, the pending rows under the
+# threshold, `advances` counts the threshold's steps and `pushed_sum`
+# the rows the rounds started from, a row as often as it pushed (over
+# the vertices: 1 is label-setting, and a hop-synchronous Bellman-Ford
+# on the road graph's weights reads 39); both are 0 where no threshold
+# is carried (BFS, where a reached row pushes once: `active_sum` + 1).
 # A query through any other runner (batched, chunked, stepwise, host)
 # leaves the initial values.
 ROUND_STATS = _FedStats("rounds", {
     "app": "", "rounds": 0, "active_bits": [], "active_max": 0,
-    "active_sum": 0, "frontier_rounds": 0,
+    "active_sum": 0, "frontier_rounds": 0, "advances": 0, "pushed_sum": 0,
 })
 _RECORD_SCOPE = "grape.worker.record"
 _RECORD_BITS = 33  # bit lengths 0..32, one bucket each
@@ -86,11 +93,12 @@ def _note_round(record, active):
         )
 
 
-def _frontier_loop(app, frag_stacked, inceval, cond, st, active, budget):
+def _frontier_loop(app, frag_stacked, inceval, cond, st, active):
     """`_make_runner`'s loop for an app that offers a round that follows
     its frontier (app/base.py): `(state, active, rounds, record)`, the
-    record one word longer, the rounds that took that round; `cond` is
-    the plain loop's own.
+    record one word longer, the rounds that took that round (with a
+    threshold four: behind it the threshold's steps and the rows
+    pushed, in two words); `cond` is the plain loop's own.
 
     The carry holds, beside the state, the list of the rows whose
     proposals are pending (`int32[B]`, padded with `vp`) and its length;
@@ -105,12 +113,29 @@ def _frontier_loop(app, frag_stacked, inceval, cond, st, active, budget):
     other round reads them as they come, `[1, Ep]`: on the chip the
     squeeze is a copy into another tiling, and squeezed once before the
     loop the copies a dense round reads stand in HBM for the whole loop
-    beside rounds that read a few thousand entries of them."""
+    beside rounds that read a few thousand entries of them.
+
+    With a threshold (`app.frontier_step`, a bucket's width: near/far)
+    the carry gains the scalar `below` and the list holds the pending
+    rows under it alone.  Rows at or over it wait: such a row has never
+    pushed the value it holds (a row pushes only while under the
+    threshold of its time, and values only fall), so where a push
+    leaves the list empty the next round is the conditional's third
+    arm: it finds the least value at or over `below`, moves `below` to
+    the end of that value's bucket and lists every row between the two
+    (`grape.frontier.advance`; a bucket that outgrows B makes the next
+    round a dense one, which pushes from every row and so is a correct
+    step at any threshold).  A round's vote is the length of the list
+    it leaves, and the loop goes on past an empty list until that arm
+    finds no row left at any value: the dense loop's fixed point.
+    `rounds` counts every iteration, the threshold's steps and the last
+    look among them."""
     from libgrape_lite_tpu.ops.segment import (
-        FRONTIER_SCOPE, frontier_rows, frontier_spans,
+        ADVANCE_SCOPE, FRONTIER_SCOPE, frontier_rows, frontier_spans,
     )
 
-    rows, entries = budget
+    rows, entries = app.frontier_budget
+    step = app.frontier_step
     row_ptr = app.frontier_csr(frag_stacked).indptr
     # the first list, from the state PEval returned: a query's source,
     # the one row that holds a value.  A state with more pending rows
@@ -121,39 +146,114 @@ def _frontier_loop(app, frag_stacked, inceval, cond, st, active, budget):
     pending = app.frontier_mask(st)
     with jax.named_scope(FRONTIER_SCOPE):
         held = pending.sum().astype(jnp.int32)
-        n0 = jnp.where(held <= 1, held, jnp.int32(rows + 1))
+        # more than B says "a dense round first"; with a threshold the
+        # true count, which `pushed_sum` adds up
+        many = (jnp.int32(rows + 1) if step is None
+                else jnp.maximum(held, rows + 1))
+        n0 = jnp.where(held <= 1, held, many)
         front0 = jnp.full((rows,), pending.shape[0], jnp.int32).at[0].set(
             jnp.where(held == 1, jnp.argmax(pending).astype(jnp.int32),
                       pending.shape[0]))
 
-    def sparse(s, front, lo, count):
-        return app.inceval_frontier(frag_stacked, s, front, lo, count)
+    def bucket_end(least):
+        """The end of the bucket `least` lies in; past `least` even
+        where a bucket is narrower than the values' spacing there."""
+        end = jnp.floor(least / step) * step + step
+        return jnp.maximum(end, jnp.nextafter(least, jnp.inf))
 
-    def dense(s, front, lo, count):
+    def sparse(s, front, lo, count, *below):
+        return app.inceval_frontier(
+            frag_stacked, s, front, lo, count, *below)
+
+    def dense(s, front, lo, count, *below):
         s2, a2 = inceval(frag_stacked.local(), s)
+
+        def improved():
+            mask = app.frontier_mask(s, s2)
+            if step is None:
+                return mask
+            # the list after it holds the improved rows under the
+            # threshold alone, and the vote counts those
+            return jnp.logical_and(mask, app.frontier_values(s2) < below[0])
+
+        if step is not None:
+            # the mask is made here and again inside the `cond` below,
+            # not made once and handed in: a V-wide operand of that
+            # `cond` moves the loop's values and the edge blocks out of
+            # VMEM for every arm, and a push then costs 725 us for 385
+            # (PERF.md section 6, PR 43;
+            # tests/test_lanes_compile_v5e.py holds the placement)
+            with jax.named_scope("grape.app.update"):
+                a2 = improved().sum().astype(jnp.int32)
         front2 = lax.cond(
             a2 <= rows,
-            lambda: frontier_rows(app.frontier_mask(s, s2), rows),
+            lambda: frontier_rows(improved(), rows),
             lambda: front,
         )
         return s2, a2, front2
 
+    def advance(s, front, lo, count, below):
+        with jax.named_scope(ADVANCE_SCOPE):
+            values = app.frontier_values(s)
+            far = values >= below
+            least = jnp.min(jnp.where(far, values, jnp.inf))
+            some = least < jnp.inf
+            upto = jnp.where(some, bucket_end(least), below)
+            bucket = jnp.logical_and(far, values < upto)
+            n = bucket.sum().astype(jnp.int32)
+        front2 = lax.cond(
+            n <= rows,
+            lambda: frontier_rows(bucket, rows, ADVANCE_SCOPE),
+            lambda: front,
+        )
+        return s, n, front2, upto, some.astype(jnp.uint32)
+
     def body(carry):
-        s, _, r, rec, front, n, took = carry
+        s, _, r, rec, front, n, took, *far = carry
         lo, count, total = frontier_spans(front, row_ptr)
         fits = jnp.logical_and(n <= rows, total <= entries)
-        s2, a2, front2 = lax.cond(fits, sparse, dense, s, front, lo, count)
-        with jax.named_scope(_RECORD_SCOPE):
-            took = took + fits.astype(jnp.uint32)
-        return (s2, a2, r + jnp.int32(1), _note_round(rec, a2), front2, a2,
-                took)
+        if step is None:
+            s2, a2, front2 = lax.cond(fits, sparse, dense, s, front, lo, count)
+            with jax.named_scope(_RECORD_SCOPE):
+                took = took + fits.astype(jnp.uint32)
+            return (s2, a2, r + jnp.int32(1), _note_round(rec, a2), front2,
+                    a2, took)
+        below, stepped, lo_word, hi_word = far
 
-    st, active, rounds, record, _, _, took = lax.while_loop(
+        def push(arm):
+            return lambda *operands: (*arm(*operands), operands[-1],
+                                      jnp.uint32(0))
+
+        # one conditional a round, the threshold's step its third arm:
+        # an iteration is a push, a dense round or a step, and `rounds`
+        # counts each
+        which = jnp.where(n == 0, 2, jnp.where(fits, 0, 1))
+        s2, a2, front2, below, moved = lax.switch(
+            which, [push(sparse), push(dense), advance],
+            s, front, lo, count, below)
+        with jax.named_scope(_RECORD_SCOPE):
+            took = took + (which == 0).astype(jnp.uint32)
+            pushed = n.astype(jnp.uint32)
+            lo_word = lo_word + pushed
+            hi_word = hi_word + (lo_word < pushed).astype(jnp.uint32)
+            # a push that leaves its list empty says nothing of the rows
+            # over the threshold: the next round looks
+            active = jnp.where(n == 0, a2, jnp.maximum(a2, 1))
+        return (s2, active, r + jnp.int32(1), _note_round(rec, a2), front2,
+                a2, took, below, stepped + moved, lo_word, hi_word)
+
+    far0 = ()
+    if step is not None:
+        with jax.named_scope(ADVANCE_SCOPE):
+            values = app.frontier_values(st)
+            far0 = (bucket_end(jnp.min(jnp.where(pending, values, jnp.inf))),
+                    jnp.uint32(0), jnp.uint32(0), jnp.uint32(0))
+    st, active, rounds, record, _, _, took, *far = lax.while_loop(
         cond, body,
         (st, jnp.int32(active), jnp.int32(0),
-         (jnp.uint32(0),) * _RECORD_WORDS, front0, n0, jnp.uint32(0)),
+         (jnp.uint32(0),) * _RECORD_WORDS, front0, n0, jnp.uint32(0), *far0),
     )
-    return st, active, rounds, (*record, took)
+    return st, active, rounds, (*record, took, *far[1:])
 
 
 def _squeeze_state(state, squeezed):
@@ -588,7 +688,7 @@ class Worker:
                 )
             else:
                 st, active, rounds, record = _frontier_loop(
-                    app, frag_stacked, inceval, cond, st, active, budget)
+                    app, frag_stacked, inceval, cond, st, active)
             return (_unsqueeze_state(st, squeezed), rounds, active,
                     jnp.stack(record))
 
@@ -2409,13 +2509,18 @@ class Worker:
             return
         # replicated: every device holds all of it
         words = np.asarray(rec[1].addressable_data(0)).astype(np.int64)
+        # behind the plain loop's words one from the loop that can
+        # follow its frontier, and three more where it carries a
+        # threshold
+        took, advances, pushed_lo, pushed_hi = (
+            words[_RECORD_WORDS:].tolist() + [0] * 4)[:4]
         ROUND_STATS.update(
             app=type(self.app).__name__, rounds=int(self.rounds),
             active_bits=words[:_RECORD_BITS].tolist(),
             active_max=int(words[_RECORD_MAX]),
             active_sum=int(words[_RECORD_HI] << 32 | words[_RECORD_LO]),
-            # one word more from the loop that can follow its frontier
-            frontier_rounds=int(words[_RECORD_WORDS:].sum()),
+            frontier_rounds=took, advances=advances,
+            pushed_sum=pushed_hi << 32 | pushed_lo,
         )
 
     def output(self, prefix: str) -> None:

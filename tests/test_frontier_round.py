@@ -22,6 +22,7 @@ import pytest
 import scipy.sparse as sp
 
 import libgrape_lite_tpu.models.bfs as bfs_module
+import libgrape_lite_tpu.models.sssp as sssp_module
 from benchmarks.graphs import kronecker, road_like
 from benchmarks.references import bfs as bfs_reference
 from libgrape_lite_tpu.fragment.edgecut import ShardedEdgecutFragment
@@ -219,10 +220,15 @@ def test_the_frontier_loop_is_the_dense_loop(case, loaded, budgets):
         assert any(fits) and not all(fits)  # the middle levels are dense ones
 
 
-@pytest.mark.parametrize("add", [1, 3])
-def test_the_primitive_alone(add):
+@pytest.mark.parametrize("add,below,dtype", [
+    (1, None, np.int32), (3, None, np.int32),
+    # a value an entry, and a threshold the next list stays under: SSSP's push
+    ("weights", None, np.float32), ("weights", 40, np.float32), ("weights", 9, np.float64),
+    ("block", 25, np.float32), (2, 5, np.int32)])
+def test_the_primitive_alone(add, below, dtype):
     """`frontier_spans`, `frontier_relax` and `frontier_rows` with no loop
-    around them, the candidate a row's value plus a constant, against a plain
+    around them, the candidate a row's value plus a constant or plus a value
+    an entry, the next list whole or cut at a threshold, against a plain
     relaxation."""
     rng = np.random.default_rng(3)
     n, e, cap, room = 200, 700, 200, 768
@@ -230,28 +236,40 @@ def test_the_primitive_alone(add):
     dst = rng.integers(0, n, e)
     indptr = np.r_[0, np.cumsum(np.bincount(src, minlength=n))].astype(np.int32)
     nbr = jnp.asarray(np.r_[dst, np.zeros(1024 - e, dst.dtype)].astype(np.int32))
-    dist = np.full(n, SENTINEL, np.int32)
+    absent = SENTINEL if dtype == np.int32 else np.inf
+    dist = np.full(n, absent, dtype)
     dist[5] = 0
+    weight = np.full(e, add, dtype) if isinstance(add, int) else rng.integers(1, 12, e).astype(dtype)
+    given = add
+    if not isinstance(add, int):
+        given = jnp.asarray(np.r_[weight, np.zeros(1024 - e, dtype)])
+        given = given[None] if add == "block" else given  # a shard's block
 
     @jax.jit
     def step(values, front):
         lo, count, total = segment.frontier_spans(front, jnp.asarray(indptr))
-        return (*segment.frontier_relax(values, front, lo, count, nbr, room,
-                                        add=add, absent=SENTINEL), total)
+        return (*segment.frontier_relax(
+            values, front, lo, count, nbr, room, add=given, below=below,
+            absent=SENTINEL if dtype == np.int32 else None), total)
 
     values = jnp.asarray(dist)
-    front = segment.frontier_rows(values != SENTINEL, cap)
+    assert values.dtype == dtype
+    listed = np.flatnonzero(dist != absent)
+    front = segment.frontier_rows(values != absent, cap)
     for _ in range(8):
         values, front, active, total = step(values, front)
         assert active <= cap and total <= room
-        new = dist.astype(np.int64)
-        ok = dist[src] != SENTINEL
-        np.minimum.at(new, dst[ok], dist[src][ok].astype(np.int64) + add)
-        assert int(active) == (new < dist).sum()
-        assert (np.asarray(front)[:int(active)] == np.flatnonzero(new < dist)).all()
+        # the rows listed push; a row off the list (over the threshold) waits
+        new = dist.astype(np.float64)
+        ok = np.isin(src, listed)
+        np.minimum.at(new, dst[ok], dist[src][ok].astype(np.float64) + weight[ok])
+        near = np.flatnonzero((new < dist) & (new < (np.inf if below is None else below)))
+        assert int(active) == len(near)
+        assert (np.asarray(front)[:int(active)] == near).all()
         assert (np.asarray(front)[int(active):] == n).all()
-        dist = new.astype(np.int32)
-        assert (np.asarray(values) == dist).all()
+        dist, listed = new.astype(dtype), near
+        assert np.asarray(values).dtype == dtype and (np.asarray(values) == dist).all()
+    assert below is None or (dist[dist != absent] >= below).any()  # the threshold cut something off
 
 
 # ---- what the offer leaves alone -------------------------------------------
@@ -294,12 +312,15 @@ def serial_text(app, frag, **params):
     ("pagerank", {}), ("cdlp", {}), ("lcc", {}), ("sssp", {"source": 6}), ("wcc", {}),
     ("bfs", {"source": 6})])
 def test_apps_that_offer_no_such_round_keep_the_parents_runner(name, params, graph_cache, loaded,
-                                                              budgets):
-    """Byte for byte, budgets within reach or not: PageRank, CDLP, LCC, SSSP
-    and WCC have no frontier round, and BFS offers none, at the budgets it
-    ships with, on a graph where a dense round is the cheaper one."""
+                                                              budgets, monkeypatch):
+    """Byte for byte, budgets within reach or not: PageRank, CDLP, LCC and
+    WCC have no frontier round, and BFS offers none, at the budgets it ships
+    with, on a graph where a dense round is the cheaper one; nor does SSSP
+    under its dense floor (p2p-31 lies over the floor it ships with)."""
     if name != "bfs":
         budgets(8, 32)
+    if name == "sssp":
+        monkeypatch.setattr(sssp_module, "_DENSE_FLOOR", 1 << 30)
     frag = loaded("road10")[0] if name == "bfs" else graph_cache(1)
     got = serial_text(APP_REGISTRY[name](), frag, **params)
     assert is_parents(got, name) and ("stablehlo.case" not in got or name == "cdlp")
@@ -437,5 +458,8 @@ def test_frontier_rounds_is_federated_with_the_other_counts(graph_cache, budgets
     assert 0 < stats["frontier_rounds"] < stats["rounds"] == w.rounds == want.rounds
     assert federation.EXPECTED["rounds"] == "libgrape_lite_tpu.worker.worker"
     assert federation.snapshot("rounds") == stats and not federation.self_check()
-    assert list(stats) == ["app", "rounds", "active_bits", "active_max", "active_sum", "frontier_rounds"]
+    assert list(stats) == ["app", "rounds", "active_bits", "active_max", "active_sum", "frontier_rounds",
+                           "advances", "pushed_sum"]
+    # BFS carries no threshold: nothing steps, and the pushes are not counted
+    assert stats["advances"] == stats["pushed_sum"] == 0
     json.dumps(stats)

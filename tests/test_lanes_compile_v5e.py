@@ -75,3 +75,115 @@ def test_unarmed_lanes_compile_to_the_fused_scatter(one_chip):
     text = _compiled(one_chip, jnp.dtype("float32"))
     assert "tpu_custom_call" not in text
     assert " scatter(" in text
+
+
+# ---- the round that follows its frontier, at the road cells' shapes ----
+
+ROAD_V, ROAD_EP, ROWS, ENTRIES = 1 << 20, 2517504, 2048, 8192
+
+
+@pytest.mark.parametrize("values,weighted", [("int32", False),
+                                             ("float32", True)])
+def test_the_frontier_round_compiles(values, weighted, one_chip):
+    """BFS's push (a constant, no threshold) and SSSP's (a weight an
+    entry read from the shard's `[1, Ep]` block, the next list cut at a
+    threshold), with the refill of the list from the state: the chip's
+    compiler takes both, and nothing in the push is as wide as the
+    graph but the update of the values in place."""
+    from libgrape_lite_tpu.ops.segment import (
+        ADVANCE_SCOPE, frontier_relax, frontier_rows, frontier_spans,
+    )
+
+    def shaped(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt), sharding=one_chip)
+
+    def push(dist, front, ptr, nbr, w, below):
+        lo, count, total = frontier_spans(front, ptr)
+        return (*frontier_relax(
+            dist, front, lo, count, nbr, ENTRIES,
+            add=w if weighted else 1, below=below if weighted else None,
+            absent=None if weighted else jnp.iinfo(jnp.int32).max), total)
+
+    def refill(dist, below):
+        return frontier_rows(dist >= below, ROWS, ADVANCE_SCOPE)
+
+    with jax.enable_x64(False):
+        text = jax.jit(push).lower(
+            shaped((ROAD_V,), values), shaped((ROWS,), "int32"),
+            shaped((1, ROAD_V + 1), "int32"), shaped((1, ROAD_EP), "int32"),
+            shaped((1, ROAD_EP), values), shaped((), values),
+        ).compile().as_text()
+        jax.jit(refill).lower(
+            shaped((ROAD_V,), values), shaped((), values)).compile()
+    assert " sort(" in text and " scatter(" in text
+    # no copy of an E-wide block: the slots read it where it lies
+    assert f"[{ROAD_EP}]" not in text.replace(f"[1,{ROAD_EP}]", "")
+
+
+# ---- the whole fused runner at the road cells' size: where the loop's
+# buffers live ----
+
+
+@pytest.fixture(scope="module")
+def road20():
+    """The road cells' graph, as one float32 fragment (the chip's x32)."""
+    import numpy as np
+
+    from tests.test_sssp_frontier import fragment, road
+
+    n, src, dst, w = road(20)
+    return fragment(n, src, dst, w.astype(np.float32))
+
+
+@pytest.mark.parametrize("name,values", [("bfs", "s32"), ("sssp", "f32")])
+def test_the_road_runner_keeps_its_values_in_vmem(name, values, one_chip,
+                                                  road20, monkeypatch):
+    """A frontier round's C-wide gathers cost 7 ns an index from a
+    table the compiler keeps in VMEM and 14 to 25 from HBM, and which
+    it is hangs on the whole loop's shape, not on the round's lines: a
+    V-wide operand handed to the dense arm's inner `cond` moved SSSP's
+    distances and edge blocks out, 725 us a round for 385 (my chip
+    runs, PR 43; PR 40 met the same with BFS).  So the runner of each
+    road cell is compiled here as the chip compiles it, and the loop's
+    values (memory space 1) and the fetches of the edge blocks into it
+    have to be there."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+
+    from libgrape_lite_tpu.models import APP_REGISTRY
+    from libgrape_lite_tpu.parallel.comm_spec import FRAG_AXIS
+    from libgrape_lite_tpu.worker.worker import Worker
+
+    monkeypatch.setattr(segment, "use_pallas", lambda: True)
+    monkeypatch.setattr(segment, "gather_table_budget", lambda: 64 << 20)
+    mesh = Mesh(np.array([one_chip._device]), (FRAG_AXIS,))
+    with jax.enable_x64(False):
+        w = Worker(APP_REGISTRY[name](), road20)
+        w.comm_spec.mesh = mesh
+        state = w.app.init_state(road20, source=5)
+        assert w.app.frontier_budget == (ROWS, ENTRIES)
+        specs, _ = w._key_specs(state)
+
+        def shaped(x, spec):
+            x = np.asarray(x) if not hasattr(x, "dtype") else x
+            return jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=NamedSharding(mesh, spec))
+
+        carried = {k: shaped(v, specs[k]) for k, v in state.items()}
+        assert not w.app.ephemeral_keys
+        _, frag_spec = w._mesh_layout()
+        dev = jax.tree_util.tree_map(
+            lambda x: shaped(x, frag_spec), road20.dev)
+        text = w._make_runner(w.app.max_rounds)(state).lower(
+            dev, carried, {}).compile().as_text()
+    loops = [line for line in text.splitlines()
+             if re.search(r" while\(", line) and f"{values}[{ROAD_V}]" in line]
+    assert loops and all(
+        re.search(rf"= \({values}\[{ROAD_V}\]\{{0:T\(1024\)S\(1\)\}}", line)
+        for line in loops)
+    # the push's update writes them where they live
+    assert re.search(
+        rf"= {values}\[{ROAD_V}\]\{{0:T\(1024\)S\(1\)\}} fusion\(.*scatter-min",
+        text)

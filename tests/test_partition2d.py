@@ -97,12 +97,18 @@ def test_min_fold_byte_identical_1d_vs_2d(graph_cache, app_name, fnum):
     are byte-identical to the 1-D edge-cut pull (min regrouping is
     exact; gpid order is oid order, so the WCC representative
     coincides too) — and the fused 2-D while_loop runs the same
-    number of rounds."""
+    number of rounds, wherever the 1-D loop is the dense one: one
+    fragment's `sssp` on p2p-31 follows a near/far frontier since PR 43
+    (models/sssp.py), whose rounds count pushes, the threshold's steps
+    and a last look, and reach the same distances in another order."""
     cls1, cls2, kw, weighted = _apps_2d()[app_name]
     r1, w1 = _result_dict(cls1(), graph_cache(fnum), **kw)
     r2, w2 = _result_dict(cls2(), _vc_frag(fnum, weighted), **kw)
     _assert_byte_identical(r1, r2)
-    assert w1.rounds == w2.rounds
+    if getattr(w1.app, "frontier_step", None) is None:
+        assert w1.rounds == w2.rounds
+    else:
+        assert (app_name, fnum) == ("sssp", 1) and w1.rounds > w2.rounds
 
 
 @pytest.mark.parametrize("fnum", [1, 4])

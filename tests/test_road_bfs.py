@@ -160,7 +160,7 @@ def test_other_runners_leave_no_record(surrogate, path):
     got = bfs_reference.to_reference_form(by_id(frag, w.result_values()))
     assert (got != want).sum() == 0
     initial = {"app": "", "rounds": 0, "active_bits": [], "active_max": 0, "active_sum": 0,
-               "frontier_rounds": 0}
+               "frontier_rounds": 0, "advances": 0, "pushed_sum": 0}
     assert ROUND_STATS.snapshot() == initial
 
 
