@@ -783,9 +783,9 @@ def main():
                 "value": round(ss_mteps, 1),
                 "unit": "MTEPS/chip",
                 "variant": picked,
-                # r6: the dense pull pre-masks the weight stream at
-                # init (one gather pass/round instead of gather +
-                # mask-select); sssp_delta has no fused form
+                # the dense pull is one gather pass a round (weights
+                # and mask read from the fragment); sssp_delta has no
+                # fused form
                 "fused_pull": picked == "sssp",
                 "vs_baseline":
                     round(ss_mteps / SSSP_BASELINE_MTEPS_PER_CHIP, 3),

@@ -11,9 +11,12 @@ outer-vertex sync) and relaxes *all* in-edges with one gather +
 change contribute no improvement.  It is the right round where a round
 improves much of the graph (a Graph500 graph's few rounds), and the
 only one of the batched, chunked, stepwise and dyn-overlay runners, of
-several fragments and of directed ones.  `min` is associative, so the
-result is bit-exact regardless of reduction order — matching the
-reference's atomic_min semantics and golden outputs.  Termination:
+several fragments and of directed ones.  Its weights and its mask are
+the fragment's own `edge_w` and `edge_mask`: no query holds a second
+copy of the weights, and a state is `dist` and what a mirror plan or a
+dyn overlay adds.  `min` is associative, so the result is bit-exact
+regardless of reduction order — matching the reference's atomic_min
+semantics and golden outputs.  Termination:
 `psum` of the per-shard changed-count (the reference's 2-int
 MPI_Allreduce, `parallel_message_manager.h:123-138`).
 
@@ -101,8 +104,9 @@ class SSSP(ParallelAppBase):
         from libgrape_lite_tpu.app.base import source_lane_array
 
         # a SEQUENCE of sources builds the batched [k, fnum, vp] carry
-        # for the serve/ vmapped multi-source dispatch — the ephemeral
-        # streams below are built once and shared across lanes
+        # for the serve/ vmapped multi-source dispatch; the ephemeral
+        # entries below (a mirror plan's, an overlay's) are shared
+        # across lanes
         batched, dist = source_lane_array(
             frag, source, "SSSP", np.inf, 0.0, dtype
         )
@@ -156,25 +160,6 @@ class SSSP(ParallelAppBase):
         )
         # the bucket's width, from the fragment's own weights
         self.frontier_step = _BUCKET_WEIGHTS * heaviest if offered else None
-        if not offered:
-            # fused dense pull (r6): pre-mask the weight stream ONCE at
-            # init (inf at masked edges), so the per-round relax is one
-            # gather + one add with no separate edge_mask select pass
-            # (x + inf == inf; distances never reach -inf, so no NaN).
-            # The host CSRs are already padded to the device Ep, so the
-            # stream stacks uniformly.  Where the frontier round is
-            # offered the stream is not built: that round reads the
-            # fragment's own `edge_w` at a few thousand entries (a
-            # row's entries are real ones: graph/csr.py's padding
-            # contract), its rare dense rounds pay the select, and the
-            # query holds no second copy of the weights in HBM and
-            # builds and places none
-            eph_entries["wf_eff"] = np.stack([
-                np.where(frag.host_ie[f].edge_mask,
-                         frag.host_ie[f].edge_w,
-                         np.asarray(np.inf, frag.host_ie[f].edge_w.dtype))
-                for f in range(frag.fnum)
-            ])
         state.update(eph_entries)
         self.ephemeral_keys = frozenset(eph_entries)
         return state
@@ -193,15 +178,11 @@ class SSSP(ParallelAppBase):
         else:
             full = ctx.gather_state(dist)
             nbr = ie.edge_nbr
-        if "wf_eff" in state:
-            # one gather pass: the pre-masked weight stream (wf_eff, inf
-            # at masked edges) folds the relax-mask select into the add
-            cand = pull_gather(full, nbr, add=state["wf_eff"])
-        else:
-            # a dense round of the loop that follows its frontier
-            cand = pull_gather(full, nbr, ie.edge_mask,
-                               jnp.asarray(jnp.inf, dist.dtype),
-                               add=ie.edge_w)
+        # one gather pass a round, masked entries filled with inf; the
+        # weights and the mask are the fragment's own, whatever the
+        # query (under a mirror plan `mx_nbr` keeps `ie`'s layout)
+        cand = pull_gather(full, nbr, ie.edge_mask,
+                           jnp.asarray(jnp.inf, dist.dtype), add=ie.edge_w)
         relaxed = self.segment_reduce(cand, ie.edge_src, frag.vp, "min",
                                       row_ptr=ie.indptr)
         if "dyn_ie_nbr" in state:

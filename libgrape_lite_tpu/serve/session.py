@@ -256,9 +256,10 @@ class ServeSession:
     def _adopt_fragment(self) -> None:
         """Point the session and every resident worker at the rebuilt
         fragment.  Stale compiled runners stay in the caches but miss
-        naturally: the apps' re-resolved plan/mirror uids enter the
-        trace key, so the first post-repack query of each shape is a
-        counted compile."""
+        naturally: the fragment's structure closes every runner key
+        (`Worker._cached_runner`) and the apps' re-resolved plan/mirror
+        uids enter the trace key, so the first post-repack query of
+        each shape is a counted compile."""
         self.fragment = self.dyn.fragment
         for w in self._workers.values():
             w.fragment = self.dyn.fragment

@@ -743,7 +743,15 @@ class Worker:
 
     def _cached_runner(self, key, build):
         """One compiled-runner cache lookup with hit/miss accounting
-        (serve/ asserts zero-recompile reuse through these counters)."""
+        (serve/ asserts zero-recompile reuse through these counters).
+        A runner is traced against the fragment it is handed as much
+        as against the state, so the fragment's structure (its static
+        sizes, its leaves' shapes and dtypes) closes every key: a
+        worker handed a fragment rebuilt to other shapes (a dyn
+        repack) misses here and its compile is a counted one, where
+        the cached `jit` would trace anew and tell no counter."""
+        leaves, treedef = jtu.tree_flatten(self.fragment.dev)
+        key = (*key, treedef, tuple((x.shape, x.dtype) for x in leaves))
         hit = key in self._runner_cache
         self.runner_cache_stats["hits" if hit else "misses"] += 1
         # a miss means the first dispatch traces and compiles: the
@@ -975,7 +983,7 @@ class Worker:
         _make_runner, vmapped over a leading lane axis of the carry.
         Each lane is an independent query against the shared HBM-
         resident fragment and ephemeral streams (plan tables, mirror
-        send tables, pre-masked weights ride once, not per lane); the
+        send tables, an overlay's staged edges ride once, not per lane); the
         while_loop runs until EVERY lane's active vote has settled, and
         the freeze mask (see _lane_body) keeps finished lanes pinned so
         raggedness never perturbs results."""

@@ -283,7 +283,9 @@ PARENT_TEXT = {
     "pagerank": "c505e22e4bcc26553357c3585c2463998be321f91feae0d3cf483078387bd8cb",
     "cdlp": "2019197f2c118ea799032ba6c8ff6d2b5a49c88a4cd7adfbcd45da81e751c815",
     "lcc": "b13a0f96b90a8d67991545a4bbff3367173179e43ee9a1c6a04feee4a798cafb",
-    "sssp": "f56f61880056245b1e1fba0ac66ef8ebef3e47191ac9d387099e77dbf20b9f62",
+    # since PR 44: the dense round reads the fragment's weights under its
+    # mask, and the runner is handed no pre-masked copy of them
+    "sssp": "3ec33ce7f0c86554f5692cf7974f03ee0959ddacd609d1e6fc725ba8ba68c228",
     "wcc": "32a688aaa47087505676a0425ff8a4f9be78c1972e592422e663cf0a5b9c0dba",
     "bfs": "8a957b1fd2d6780eb2efe01a64303a8016d9477fdba856efe0c1e3efd69f868b",  # road10
     "bfs_overlay": "7dba0f9665967474b85e6050bf768be2f052280ef93254a5f612966827b711b9",

@@ -135,6 +135,29 @@ def road20():
     return fragment(n, src, dst, w.astype(np.float32))
 
 
+def _described(w, frag, state, key_specs, one_chip):
+    """`(dev, carried)`: the fragment and a host state as shapes on the
+    described chip, for `runner.lower`; the worker's mesh becomes that
+    chip's."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+
+    from libgrape_lite_tpu.parallel.comm_spec import FRAG_AXIS
+
+    mesh = Mesh(np.array([one_chip._device]), (FRAG_AXIS,))
+    w.comm_spec.mesh = mesh
+    specs, _ = key_specs(state)
+
+    def shaped(x, spec):
+        x = np.asarray(x) if not hasattr(x, "dtype") else x
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, spec))
+
+    _, frag_spec = w._mesh_layout()
+    dev = jax.tree_util.tree_map(lambda x: shaped(x, frag_spec), frag.dev)
+    return dev, {k: shaped(v, specs[k]) for k, v in state.items()}
+
+
 @pytest.mark.parametrize("name,values", [("bfs", "s32"), ("sssp", "f32")])
 def test_the_road_runner_keeps_its_values_in_vmem(name, values, one_chip,
                                                   road20, monkeypatch):
@@ -149,33 +172,17 @@ def test_the_road_runner_keeps_its_values_in_vmem(name, values, one_chip,
     have to be there."""
     import re
 
-    import numpy as np
-    from jax.sharding import Mesh, NamedSharding
-
     from libgrape_lite_tpu.models import APP_REGISTRY
-    from libgrape_lite_tpu.parallel.comm_spec import FRAG_AXIS
     from libgrape_lite_tpu.worker.worker import Worker
 
     monkeypatch.setattr(segment, "use_pallas", lambda: True)
     monkeypatch.setattr(segment, "gather_table_budget", lambda: 64 << 20)
-    mesh = Mesh(np.array([one_chip._device]), (FRAG_AXIS,))
     with jax.enable_x64(False):
         w = Worker(APP_REGISTRY[name](), road20)
-        w.comm_spec.mesh = mesh
         state = w.app.init_state(road20, source=5)
         assert w.app.frontier_budget == (ROWS, ENTRIES)
-        specs, _ = w._key_specs(state)
-
-        def shaped(x, spec):
-            x = np.asarray(x) if not hasattr(x, "dtype") else x
-            return jax.ShapeDtypeStruct(
-                x.shape, x.dtype, sharding=NamedSharding(mesh, spec))
-
-        carried = {k: shaped(v, specs[k]) for k, v in state.items()}
         assert not w.app.ephemeral_keys
-        _, frag_spec = w._mesh_layout()
-        dev = jax.tree_util.tree_map(
-            lambda x: shaped(x, frag_spec), road20.dev)
+        dev, carried = _described(w, road20, state, w._key_specs, one_chip)
         text = w._make_runner(w.app.max_rounds)(state).lower(
             dev, carried, {}).compile().as_text()
     loops = [line for line in text.splitlines()
@@ -187,3 +194,60 @@ def test_the_road_runner_keeps_its_values_in_vmem(name, values, one_chip,
     assert re.search(
         rf"= {values}\[{ROAD_V}\]\{{0:T\(1024\)S\(1\)\}} fusion\(.*scatter-min",
         text)
+
+
+# ---- the batched SSSP runner at the serving cell's size: what it is
+# handed, and which pull it takes ----
+
+SERVE_V, SERVE_EP = 1 << 18, 1 << 23
+
+
+@pytest.fixture(scope="module")
+def kron18():
+    """`serve-g500-s18`'s graph, as one float32 fragment (the chip's x32)."""
+    import numpy as np
+
+    from benchmarks.graphs import kronecker
+    from tests.test_sssp_frontier import KRON, fragment
+
+    src, dst, w = kronecker.edges(KRON, 18)
+    return fragment(SERVE_V, src, dst, w.astype(np.float32))
+
+
+def test_the_serving_sssp_lanes_read_the_fragments_own_weights(
+        one_chip, kron18, monkeypatch):
+    """Four SSSP lanes at `serve-g500-s18.keys8`'s shapes, compiled as the
+    chip compiles them: the kernel a lane and the tile scan (PR 41), and
+    of E-wide float blocks the runner is handed one, the fragment's
+    `edge_w`: a batch builds and places its lanes and no stream beside
+    them (a pre-masked copy of the weights was 33.5 MB and 0.18 s of
+    host time a batch; PERF.md section 6, PR 44)."""
+    import re
+
+    from libgrape_lite_tpu.models import APP_REGISTRY
+    from libgrape_lite_tpu.ops.segment import FOLD_STATS, GATHER_STATS
+    from libgrape_lite_tpu.worker.worker import Worker
+
+    monkeypatch.setattr(segment, "use_pallas", lambda: True)
+    monkeypatch.setattr(segment, "gather_table_budget", lambda: 64 << 20)
+    assert kron18.vp == SERVE_V
+    assert kron18.dev.ie.edge_nbr.shape == (1, SERVE_EP)
+    with jax.enable_x64(False):
+        w = Worker(APP_REGISTRY["sssp"](), kron18)
+        state = w.app.init_state(kron18, source=[5, 6, 7, 9])
+        assert set(state) == {"dist"} and not w.app.ephemeral_keys
+        assert state["dist"].shape == (LANES, 1, SERVE_V)
+        dev, carried = _described(w, kron18, state, w._key_specs_batch,
+                                  one_chip)
+        gathers, folds = GATHER_STATS.snapshot(), FOLD_STATS.snapshot()
+        text = w._make_batched_runner(w.app.max_rounds, LANES)(state).lower(
+            dev, carried, {}).compile().as_text()
+    took = {k: v - gathers[k] for k, v in GATHER_STATS.snapshot().items()}
+    assert took == {"kernel": 1, "xla": 0}
+    took = {k: v - folds[k] for k, v in FOLD_STATS.snapshot().items()}
+    assert took == {"scan": 1, "scatter": 0}
+    assert "vmem_gather" in text and " scatter(" not in text
+    entry = text[text.index("\nENTRY "):]
+    handed = re.findall(r"= (\w+\[[0-9,]*\])\S* parameter\(", entry)
+    assert f"f32[{LANES},1,{SERVE_V}]" in handed
+    assert handed.count(f"f32[1,{SERVE_EP}]") == 1

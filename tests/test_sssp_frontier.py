@@ -216,8 +216,11 @@ def test_a_wider_bucket_trades_advances_for_pushes(loaded, budgets):
 # ---- what the offer rests on, and what it leaves alone ---------------------
 
 
-def test_the_offer_holds_no_second_copy_of_the_weights(loaded, budgets):
+def test_no_query_holds_a_second_copy_of_the_weights(loaded, budgets):
+    """Offered the frontier round or not, a state is the distances alone:
+    the dense round reads the fragment's own weights under its own mask."""
     frag = loaded("road10")[0]
+    ep = frag.dev.ie.edge_nbr.shape[-1]
     budgets(64, 256)
     app = APP_REGISTRY["sssp"]()
     state = app.init_state(frag, source=5)
@@ -225,9 +228,9 @@ def test_the_offer_holds_no_second_copy_of_the_weights(loaded, budgets):
     assert not app.ephemeral_keys
     budgets(64, 256, floor=NEVER)
     state = app.init_state(frag, source=5)
-    assert app.frontier_budget is None and set(state) == {"dist", "wf_eff"}
-    assert app.ephemeral_keys == {"wf_eff"}
-    assert np.isinf(state["wf_eff"][0][int(frag.host_ie[0].indptr[-1]):]).all()
+    assert app.frontier_budget is None and set(state) == {"dist"}
+    assert not app.ephemeral_keys
+    assert all(np.shape(v)[-1] < ep for v in state.values())
 
 
 def test_zero_weights_are_offered_nothing(budgets):
@@ -267,11 +270,12 @@ def test_every_other_query_keeps_the_dense_round(how, budgets):
             state = worker._place_state(worker.app.init_state(frag, source=6))
             lowered = worker._chunk_runner_for(4, 0, state).lower(
                 frag.dev, *split(worker, state), jnp.int32(1), jnp.int32(0))
-            return lowered.as_text(), worker.app.frontier_budget
         else:
             state = worker._place_state(worker.app.init_state(frag, source=0))
             lowered = worker._make_runner(0)(state).lower(frag.dev, *split(worker, state))
-        assert "wf_eff" in state
+        ep = frag.dev.ie.edge_nbr.shape[-1]
+        assert all(v.shape[-1] < ep for v in state.values())
+        assert worker.app.ephemeral_keys == {k for k in state if k.startswith("dyn_ie_")}
         return lowered.as_text(), worker.app.frontier_budget
 
     budgets(64, 256, floor=NEVER)
@@ -280,12 +284,90 @@ def test_every_other_query_keeps_the_dense_round(how, budgets):
     budgets(64, 256)
     got, offer = text()
     assert "stablehlo.case" not in got
-    if how == "chunked":
-        # the state is the offered one, without the second copy of the
-        # weights; the runner holds the dense round alone
-        assert offer == (64, 256) and got != shipped
+    # the chunked runner never asks for the offer its state was made under,
+    # and that state is the dense one's: the distances
+    assert offer == ((64, 256) if how == "chunked" else None) and got == shipped
+
+
+def pushing_csr(frag, state):
+    """The fragments' own pull entries, and the overlay's staged ones, turned
+    about into one CSR over pids whose rows push along their entries: what
+    `tests/sssp_oracles.py` relaxes.  Pads are cut off."""
+    vp, rows, nbrs, ws = frag.vp, [], [], []
+    for f in range(frag.fnum):
+        ie = frag.host_ie[f]
+        real = int(ie.indptr[-1])
+        assert ie.edge_mask[:real].all() and not ie.edge_mask[real:].any()
+        rows.append(f * vp + np.repeat(np.arange(vp), np.diff(ie.indptr)))
+        nbrs.append(ie.edge_nbr[:real].astype(np.int64))
+        ws.append(ie.edge_w[:real])
+        if "dyn_ie_nbr" in state:
+            live = np.asarray(state["dyn_ie_mask"][f], bool)
+            rows.append(f * vp + np.asarray(state["dyn_ie_src"][f], np.int64)[live])
+            nbrs.append(np.asarray(state["dyn_ie_nbr"][f], np.int64)[live])
+            ws.append(np.asarray(state["dyn_ie_w"][f])[live])
+    rows, nbrs, ws = map(np.concatenate, (rows, nbrs, ws))
+    order = np.argsort(nbrs, kind="stable")
+    indptr = np.r_[0, np.cumsum(np.bincount(nbrs, minlength=frag.fnum * vp))]
+    return indptr, rows[order], ws[order]
+
+
+@pytest.mark.parametrize("how", ["single", "batched", "chunked", "two_fragments",
+                                 "two_fragments_mirrored", "overlay"])
+def test_a_state_is_its_lanes_and_the_answer_the_oracles(how, monkeypatch):
+    """Whatever runs the query, `init_state` hands the host a lane of
+    distances a source and what a mirror plan or an overlay adds, nothing of
+    the fragment's own (its weights are read where they lie), and the
+    distances are the plain Bellman-Ford's bit for bit."""
+    from libgrape_lite_tpu.dyn import DynGraph, RepackPolicy
+    from libgrape_lite_tpu.dyn.ingest import overlay_state_entries
+    from libgrape_lite_tpu.guard.config import GuardConfig
+    from tests.test_dyn import ADDS, _mutable_fragment
+
+    monkeypatch.setenv("GRAPE_EXCHANGE", "mirror" if how.endswith("mirrored") else "gather")
+    if how == "overlay":
+        dg = DynGraph(_mutable_fragment(), RepackPolicy(threshold=0.9, capacity=64))
+        assert dg.ingest(ADDS)["mode"] == "overlay"
+        frag = dg.fragment
     else:
-        assert offer is None and got == shipped
+        n, src, dst, w = road(10)
+        frag = fragment(n, src, dst, w.astype(np.float32),
+                        fnum=2 if how.startswith("two_fragments") else 1)
+    sources = [6, 0, 17] if how == "batched" else [6]
+    worker = Worker(APP_REGISTRY["sssp"](), frag)
+    app = worker.app
+
+    state = app.init_state(frag, source=sources if how == "batched" else sources[0])
+    dist = state["dist"]
+    assert dist.shape == (len(sources),) * (how == "batched") + (frag.fnum, frag.vp)
+    added = {}
+    if how == "overlay":
+        added = overlay_state_entries(frag, "ie", dist.dtype, "dyn_ie_")
+    elif how.endswith("mirrored"):
+        added = app._mx.state_entries("mx_")
+    assert set(state) == {"dist"} | set(added) and app.ephemeral_keys == set(added)
+    assert (how in ("overlay", "two_fragments_mirrored")) == bool(added)
+    assert sum(np.asarray(v).nbytes for v in state.values()) <= (
+        len(sources) * frag.fnum * frag.vp * dist.dtype.itemsize
+        + sum(np.asarray(v).nbytes for v in added.values()))
+
+    if how == "batched":
+        worker.query_batch([{"source": s} for s in sources])
+        got = [worker.batch_result_values(lane) for lane in range(len(sources))]
+    elif how == "chunked":
+        worker.query(guard=GuardConfig(policy="halt", every=3), source=sources[0])
+        assert "chunk" in {k[0] for k in worker._runner_cache}
+        got = [worker.result_values()]
+    else:
+        worker.query(source=sources[0])
+        got = [worker.result_values()]
+    indptr, nbr, weight = pushing_csr(frag, state)
+    for source, values in zip(sources, got):
+        pid = int(frag.oid_to_pid(np.array([source]))[0])
+        want, *_ = bellman_ford(indptr, nbr, weight.astype(dist.dtype), pid)
+        assert values.dtype == want.dtype
+        assert np.asarray(values).reshape(-1).tobytes() == want.tobytes()
+        assert np.isfinite(want).sum() > frag.vp // 2
 
 
 # ---- what the round is made of ---------------------------------------------
