@@ -446,6 +446,7 @@ def stage_c(on_tpu: bool) -> dict:
     }}
     log(f"C intersect_count: {out['intersect_count']}")
     out["vmem_gather"] = gather_check(on_tpu)
+    out["vmem_row_gather"] = row_ends_check(on_tpu)
     return out
 
 
@@ -484,6 +485,51 @@ def gather_check(on_tpu: bool) -> dict:
                 "stage C vmem_gather: not compiled")
     out.update(spy_summary(calls))
     log(f"C vmem_gather: {out}")
+    return out
+
+
+def row_ends_check(on_tpu: bool) -> dict:
+    """`segment_reduce(row_ptr=)` as the apps call it, on the shapes of
+    the x4 cell's shard (524,288 rows, a third of them empty, over
+    17,192,832 places: five slices, the last ragged): on the TPU the
+    row ends must be read by the kernel that was traced, compiled, and
+    the fold equal the scatter's bit for bit (a min: exact under any
+    grouping).  Off it (a rehearsal) the choice is XLA's gather."""
+    import jax
+    import jax.numpy as jnp
+
+    from libgrape_lite_tpu.ops.segment import ROW_END_STATS, segment_reduce
+
+    rows, n = 524_288, 134_319 * 128
+    rng = np.random.default_rng(45)
+    deg = np.where(rng.random(rows) < 0.35, 0, rng.geometric(0.025, rows))
+    deg[rows // 2] += n - 4096 - deg.sum()  # a hub takes what is left
+    ptr = np.concatenate([[0], np.cumsum(deg)]).astype(np.int32)
+    ids = np.full(n, rows, np.int32)
+    ids[:ptr[-1]] = np.repeat(np.arange(rows, dtype=np.int32), deg)
+    out = {"ok": True, "rows": rows, "places": n}
+    before = ROW_END_STATS.snapshot()
+    with pallas_spy() as calls:
+        for dtype in (np.float32, np.int32):
+            vals = jnp.asarray(rng.integers(-9, 9, n).astype(dtype))
+            (got, wall) = timed(
+                jax.jit(lambda v, i, p: segment_reduce(
+                    v, i, rows, "min", row_ptr=p)), vals, ids, ptr)
+            want = jax.jit(lambda v, i: segment_reduce(
+                v, i, rows, "min"))(vals, ids)
+            require(bool(jnp.array_equal(got, want)),
+                    f"stage C vmem_row_gather: {np.dtype(dtype).name} scan "
+                    "fold is not the scatter's")
+            out[f"{np.dtype(dtype).name}_cold_wall_s"] = wall
+    took = ROW_END_STATS.snapshot()
+    out["took"] = {k: took[k] - before[k] for k in took}
+    if on_tpu:
+        require(out["took"] == {"kernel": 2, "xla": 0},
+                f"stage C vmem_row_gather: the choice was {out['took']}")
+        require(len(calls) == 2 and not any(calls),
+                "stage C vmem_row_gather: not compiled")
+    out.update(spy_summary(calls))
+    log(f"C vmem_row_gather: {out}")
     return out
 
 

@@ -20,6 +20,12 @@ for the length of the call: XLA's gather steps through its indices one
 at a time, twelve bundles an element whatever the table's size, where
 one vector load of a table row per index is the work.
 `ops/segment.pull_gather` chooses between the two.
+
+`vmem_row_gather` is the same loads for a table that is too long to
+stay, read by indices that do not decrease: a fold's row ends out of
+its scanned stream.  The table passes through VMEM a slice at a time
+and each block of indices meets the slices it spans
+(`ops/segment._row_end_gather` chooses).
 """
 
 from __future__ import annotations
@@ -128,6 +134,30 @@ def gather_table_budget() -> int:
     return pltpu.get_tpu_info().vmem_capacity_bytes // 2
 
 
+def _gather_chunk(tab, rows, slot: int, lanes):
+    """One output vreg: the 8 x 128 table values whose rows of `tab`
+    (a table, or a slice of one, in VMEM) stand in SMEM slot `slot` of
+    `rows` and whose places in those rows are `lanes`.  The arithmetic
+    `_gather_kernel` describes, shared by the two gather kernels."""
+    lane = lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 1)
+    sub = lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 0)
+
+    def lane_step(j, acc):
+        t = jnp.zeros((SUBLANES, LANES), tab.dtype)
+        for s in range(SUBLANES):
+            t = jnp.where(sub == s, tab[pl.ds(rows[slot, s, j], 1), :], t)
+        return jnp.where(lane == j,
+                         jnp.take_along_axis(t, lanes, axis=1), acc)
+
+    # unrolled where the kernel is lowered, not where it is traced:
+    # `j` becomes a constant there, so the SMEM offsets are static,
+    # and the trace holds one step, not 128 (traced out in Python
+    # the body cost every process 24 s on the chip's host)
+    return lax.fori_loop(0, LANES, lane_step,
+                         jnp.zeros((SUBLANES, LANES), tab.dtype),
+                         unroll=True)
+
+
 def _gather_kernel(idx_ref, tab_hbm, out_ref, tab, tab_sem, stage, rows,
                    sems, *, v: int, pairs: int):
     """One grid step: `pairs` times two chunks of 1024 indices.
@@ -168,27 +198,10 @@ def _gather_kernel(idx_ref, tab_hbm, out_ref, tab, tab_sem, stage, rows,
         stage[slot] = norm(idx_ref[pl.ds(r0, SUBLANES), :]) >> 7
         slot_copy(slot).start()
 
-    lane = lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 1)
-    sub = lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 0)
-
     def chunk(slot, r0):
         lanes = norm(idx_ref[pl.ds(r0, SUBLANES), :]) & (LANES - 1)
-
-        def lane_step(j, acc):
-            t = jnp.zeros((SUBLANES, LANES), tab.dtype)
-            for s in range(SUBLANES):
-                t = jnp.where(sub == s, tab[pl.ds(rows[slot, s, j], 1), :],
-                              t)
-            return jnp.where(lane == j,
-                             jnp.take_along_axis(t, lanes, axis=1), acc)
-
-        # unrolled where the kernel is lowered, not where it is traced:
-        # `j` becomes a constant there, so the SMEM offsets are static,
-        # and the trace holds one step, not 128 (traced out in Python
-        # the body cost every process 24 s on the chip's host)
-        out_ref[pl.ds(r0, SUBLANES), :] = lax.fori_loop(
-            0, LANES, lane_step,
-            jnp.zeros((SUBLANES, LANES), tab.dtype), unroll=True)
+        out_ref[pl.ds(r0, SUBLANES), :] = _gather_chunk(
+            tab, rows, slot, lanes)
 
     send(0, 0)
 
@@ -255,5 +268,122 @@ def vmem_gather(full, nbr, interpret: bool = False):
         interpret=interpret,
         name="vmem_gather",
     )(idx, tab)
+    out = out.reshape(-1)
+    return out[:n] if npad else out
+
+
+# ---- the fold's row ends, from slices of the scanned stream in VMEM -------
+
+# Rows of 128 in a slice of the stream: 4 MiB, two of which the
+# pipeline holds; a Graph500 scale-21 stream is 64 of them
+_SLICE_ROWS = 8192
+# Rows of 128 in a block of row ends: 4,096 indices, four chunks
+_END_ROWS = 32
+
+
+def _row_gather_kernel(blk_ref, sl_ref, idx_ref, tab_ref, out_ref, stage,
+                       rows, sem, *, slice_rows: int, chunks: int):
+    """One work item: the index block `blk_ref[k]` against the slice
+    `sl_ref[k]` of the table, both placed by the pipeline.  A block's
+    items follow one another, so its output block stays in VMEM from
+    the first to the last of them and each fills in the indices that
+    lie in its slice.  `_gather_kernel`'s chunk with one SMEM slot:
+    the indices are a row's width of the fold's stream, and the wait
+    for the slot is what the second one would hide."""
+    k = pl.program_id(0)
+    before = jnp.maximum(k - 1, 0)
+
+    # items past the real ones repeat the last: nothing moves, nothing
+    # to do
+    @pl.when((k == 0) | (blk_ref[k] != blk_ref[before])
+             | (sl_ref[k] != sl_ref[before]))
+    def _():
+        span = slice_rows * LANES
+        base = sl_ref[k] * span
+        cp = pltpu.make_async_copy(stage.at[0], rows.at[0], sem)
+
+        def chunk(c, carry):
+            at = pl.ds(pl.multiple_of(c * SUBLANES, SUBLANES), SUBLANES)
+            i = idx_ref[at, :] - base
+            inside = (i >= 0) & (i < span)
+            i = jnp.clip(i, 0, span - 1)
+            stage[0] = i >> 7
+            cp.start()
+            cp.wait()
+            got = _gather_chunk(tab_ref, rows, 0, i & (LANES - 1))
+            out_ref[at, :] = jnp.where(inside, got, out_ref[at, :])
+            return carry
+
+        lax.fori_loop(0, chunks, chunk, 0)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("interpret", "slice_rows", "end_rows"))
+def vmem_row_gather(table, idx, interpret: bool = False,
+                    slice_rows: int = _SLICE_ROWS,
+                    end_rows: int = _END_ROWS):
+    """`table[idx]` for a 1-D 32-bit table of any length and int32
+    indices that are in bounds and do not decrease (the caller's
+    promise), bit for bit.
+
+    The table, viewed `[ceil(E / 128), 128]`, passes through VMEM a
+    slice at a time; the indices come in blocks, and because they are
+    sorted a block meets only the slices from its first index's to its
+    last's.  The grid runs over those (block, slice) pairs, at most
+    blocks + slices - 1 of them whatever the indices are, block by
+    block: two scalar-prefetch arrays name each step's pair, the block
+    specs read them, and a slice or an output block that stays from
+    one step to the next is not copied again.  `slice_rows` and
+    `end_rows` (rows of 128, whole 8s) are the tests', which cannot
+    afford the real ones' slices."""
+    e, n = table.shape[0], idx.shape[0]
+    epad, npad = -e % LANES, -n % LANES
+    tab = (jnp.pad(table, (0, epad)) if epad else table).reshape(-1, LANES)
+    idx = (jnp.pad(idx, (0, npad), mode="edge") if npad else idx).reshape(
+        -1, LANES)
+    nrows = idx.shape[0]
+    end_rows = min(end_rows, pl.cdiv(nrows, SUBLANES) * SUBLANES)
+    slice_rows = min(slice_rows, tab.shape[0])
+    nb, nc = pl.cdiv(nrows, end_rows), pl.cdiv(tab.shape[0], slice_rows)
+
+    # the pairs, from each block's first and last index
+    opens = jnp.arange(nb, dtype=jnp.int32) * end_rows
+    closes = jnp.minimum(opens + end_rows, nrows) - 1
+    lo = jnp.clip(idx[opens, 0] // (slice_rows * LANES), 0, nc - 1)
+    hi = jnp.clip(idx[closes, LANES - 1] // (slice_rows * LANES), lo, nc - 1)
+    upto = jnp.cumsum(hi - lo + 1, dtype=jnp.int32)
+    k = jnp.minimum(jnp.arange(nb + nc, dtype=jnp.int32), upto[-1] - 1)
+    blk = jnp.searchsorted(upto, k, side="right").astype(jnp.int32)
+    sl = hi[blk] - (upto[blk] - 1 - k)
+
+    out = pl.pallas_call(
+        functools.partial(_row_gather_kernel, slice_rows=slice_rows,
+                          chunks=end_rows // SUBLANES),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(nb + nc,),
+            in_specs=[
+                pl.BlockSpec((end_rows, LANES), lambda k, b, s: (b[k], 0)),
+                pl.BlockSpec((slice_rows, LANES), lambda k, b, s: (s[k], 0)),
+            ],
+            out_specs=pl.BlockSpec((end_rows, LANES),
+                                   lambda k, b, s: (b[k], 0)),
+            scratch_shapes=[
+                pltpu.VMEM((1, SUBLANES, LANES), jnp.int32),
+                pltpu.SMEM((1, SUBLANES, LANES), jnp.int32),
+                pltpu.SemaphoreType.DMA(()),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(
+            (nrows, LANES), table.dtype,
+            vma=jax.typeof(idx).vma | jax.typeof(tab).vma),
+        compiler_params=pltpu.CompilerParams(
+            # an output block is carried over its items
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=2 * slice_rows * LANES * 4 + (4 << 20),
+        ),
+        interpret=interpret,
+        name="vmem_row_gather",
+    )(blk, sl, idx, tab)
     out = out.reshape(-1)
     return out[:n] if npad else out

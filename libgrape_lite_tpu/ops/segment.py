@@ -14,8 +14,13 @@ ns an element at Graph500 scale 10 as at scale 21, sorted ids or not
 (PERF.md, section 5): `indices_are_sorted` does not turn it into a
 scan.  So where the rows are a CSR's, sorted and with their offsets at
 hand, the fold is a scan written out in dense XLA: `_segmented_scan`
-over tiles of 128 places, then one V-wide gather of the row ends
-(0.83 ns an element at scale 21).  Everything else keeps the scatter:
+over tiles of 128 places, then each row's fold from the row's last
+place: V sorted reads of the E-wide scanned stream.  XLA's gather
+takes 18.1 ns a row for them at scale 21 (every read misses: a third
+of a dense round), so where the values are 32 bits wide on the TPU
+backend `ops/pallas_kernels.vmem_row_gather` reads them from slices of
+the stream that pass through VMEM (`_row_end_gather`; ROW_END_STATS
+counts which of the two a call took).  Everything else keeps the scatter:
 ids that are not sorted and streams without offsets (the dyn overlay,
 `exchange_base`, the 2-D tiles of `vc2d`, `bc`, `kcore`, 64-bit
 lanes).  Query lanes under `jax.vmap` (the batched runners) fold as
@@ -27,7 +32,8 @@ lane answers with its single query's bytes.  CDLP's count is a scan as
 well, and never a scatter: `run_position` and `segment_top_label` work
 on the (row, label) pairs its sort has just put in order, with the
 CSR's offsets, which every round has (a caller without them gets them
-by a binary search of the sorted rows: no round today).
+by a binary search of the sorted rows, and XLA's gather of the row
+ends: no round today).
 
 The module makes a third choice, in `pull_gather`: how `full[nbr]` is
 read.  XLA's gather also steps through its indices one at a time (8.6
@@ -62,6 +68,8 @@ XLA fuses the gather into the fold the whole fusion reads as the fold.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import jax.ops as jops
@@ -73,6 +81,7 @@ from libgrape_lite_tpu.ops.pallas_kernels import (
     gather_table_budget,
     use_pallas,
     vmem_gather,
+    vmem_row_gather,
 )
 
 
@@ -91,16 +100,19 @@ def _recount(stats, took: list, to: str) -> None:
         took[0] = to
 
 
+def _kernel_values(dtype) -> bool:
+    """Whether values of `dtype` are the gather kernels' kind here:
+    the TPU backend, 32 bits."""
+    return use_pallas() and jnp.dtype(dtype).itemsize == 4
+
+
 def _kernel_table(dtype, rows: int) -> bool:
     """Whether a 1-D table of `rows` values of `dtype` is one
-    `pallas_kernels.vmem_gather` keeps in VMEM: the TPU backend, 32-bit
-    values, the kernel's VMEM budget.  A single call's gather and both
+    `pallas_kernels.vmem_gather` keeps in VMEM: the kernels' kind of
+    value, the kernel's VMEM budget.  A single call's gather and both
     `vmap` rules (the gather's and the fold's) rest on it; everything
     in it is read off shapes, dtypes and the backend at trace time."""
-    return (
-        use_pallas() and jnp.dtype(dtype).itemsize == 4
-        and 0 < rows * 4 <= gather_table_budget()
-    )
+    return _kernel_values(dtype) and 0 < rows * 4 <= gather_table_budget()
 
 
 def _kernel_gathers(full, nbr) -> bool:
@@ -114,23 +126,24 @@ def _kernel_gathers(full, nbr) -> bool:
     )
 
 
-def _kernel_gather():
-    """The gather of one `pull_gather` call that chose the kernel, with
+def _kernel_gather(stats, kernel):
+    """The gather of one call that chose `kernel` (`vmem_gather` for a
+    pull's `full[nbr]`, `vmem_row_gather` for a fold's row ends), with
     its own rule under `jax.vmap`.  Made anew for each call, because it
-    carries the call's entry in GATHER_STATS."""
+    carries the call's entry in `stats`."""
     took = ["kernel"]
-    GATHER_STATS["kernel"] += 1
+    stats["kernel"] += 1
 
     @custom_vmap
     def gather(full, nbr):
-        return vmem_gather(full, nbr)
+        return kernel(full, nbr)
 
     @gather.def_vmap
     def lanes(axis_size, in_batched, full, nbr):
         if in_batched[1]:
             # lanes that bring their own indices (no caller does) keep
             # XLA's gather, lane by lane
-            _recount(GATHER_STATS, took, "xla")
+            _recount(stats, took, "xla")
             axes = tuple(0 if b else None for b in in_batched)
             return jax.vmap(lambda f, i: f[i], in_axes=axes)(full, nbr), True
         # Query lanes over one CSR (the batched runner): the single
@@ -140,7 +153,7 @@ def _kernel_gather():
         # `[lanes, Ep]` block; XLA's own vmapped gather writes that
         # block lanes minor, padded 32-fold (PERF.md section 6, PR 25
         # (3)).  One traced body in a loop, not a copy a lane: code is
-        # HBM (PR 40).  The call stays `kernel` in GATHER_STATS.
+        # HBM (PR 40).  The call stays `kernel` in its stats.
         return lax.map(lambda f: gather(f, nbr), full), True
 
     return gather
@@ -162,7 +175,7 @@ def pull_gather(full, nbr, mask=None, fill=None, add=None, absent=None):
     took."""
     with jax.named_scope("grape.pull.gather"):
         if _kernel_gathers(full, nbr):
-            vals = _kernel_gather()(full, nbr)
+            vals = _kernel_gather(GATHER_STATS, vmem_gather)(full, nbr)
         else:
             GATHER_STATS["xla"] += 1
             vals = full[nbr]
@@ -282,18 +295,48 @@ def _scatter_fold(values, segment_ids, num_rows: int, kind: str,
     return out[:num_rows]
 
 
-def _scan_rows(values, segment_ids, row_ptr, num_rows: int, kind: str):
+# which gather read each scan's row ends, counted where it is decided:
+# at trace time, once per call site per traced program
+ROW_END_STATS = _FedStats("row_ends", {"kernel": 0, "xla": 0})
+
+
+def _row_end_gather(dtype, offsets: bool = True):
+    """How one call reads its row ends out of its scanned stream of
+    `dtype`, V sorted reads of an E-wide table (the module's docstring
+    has the prices): `pallas_kernels.vmem_row_gather` where the values
+    are the kernels' kind (`_kernel_values`), query lanes under
+    `jax.vmap` one after another; XLA's gather everywhere else, and
+    for a caller whose `row_ptr` is not a CSR's `offsets` but its own
+    search of the ids.  Both move the same bits.  Made once for each
+    call, however often its fold is traced (`_scan_fold`), because it
+    carries the call's entry in ROW_END_STATS."""
+    if offsets and _kernel_values(dtype):
+        return _kernel_gather(ROW_END_STATS, vmem_row_gather)
+    ROW_END_STATS["xla"] += 1
+    return lambda scanned, at: scanned.at[at].get(
+        mode="promise_in_bounds", indices_are_sorted=True)
+
+
+def _row_ends(scanned, row_ptr, num_rows: int, empty, gather):
+    """Each row's fold out of the scanned stream: what stands at the
+    row's last place, read by `gather` (`_row_end_gather`'s), `empty`
+    for a row with no place."""
+    # an empty row has no last place
+    last = row_ptr[1:num_rows + 1] - 1
+    out = gather(scanned, jnp.maximum(last, 0))
+    return jnp.where(last >= row_ptr[:num_rows], out,
+                     jnp.asarray(empty, scanned.dtype))
+
+
+def _scan_rows(values, segment_ids, row_ptr, num_rows: int, kind: str,
+               ends):
     """The scan fold of one lane: `_segmented_scan`, then each row's
-    fold from the row's last place."""
+    fold from the row's last place, read by `ends(dtype)`."""
     _, combine, ident = _FOLDS[kind]
     identity = ident(values.dtype)
     scanned = _segmented_scan(values, segment_ids, combine, identity)
-    # an empty row has no last place
-    last = row_ptr[1:num_rows + 1] - 1
-    out = scanned.at[jnp.maximum(last, 0)].get(
-        mode="promise_in_bounds", indices_are_sorted=True)
-    return jnp.where(last >= row_ptr[:num_rows], out,
-                     jnp.asarray(identity, values.dtype))
+    return _row_ends(scanned, row_ptr, num_rows, identity,
+                     ends(scanned.dtype))
 
 
 def _grouping_shows(kind: str, dtype) -> bool:
@@ -306,7 +349,9 @@ def _grouping_shows(kind: str, dtype) -> bool:
 def _scan_fold(num_rows: int, kind: str):
     """The fold of one `segment_reduce` call that came with offsets:
     the scan, with its own rule under `jax.vmap`.  Made anew for each
-    call, because it carries the call's entry in FOLD_STATS.
+    call, because it carries the call's entries in FOLD_STATS and in
+    ROW_END_STATS (the latter is the single query's choice, and stays
+    where the rule below sends the lanes to the scatter).
 
     Under `jax.vmap` (query lanes over one CSR) the rule asks the
     question the gather's rule answered, of what it can see itself:
@@ -327,10 +372,13 @@ def _scan_fold(num_rows: int, kind: str):
     no cell and no test holds such a graph."""
     took = ["scan"]
     FOLD_STATS["scan"] += 1
+    # the call's one gather of row ends, however often it is traced
+    ends = functools.cache(_row_end_gather)
 
     @custom_vmap
     def fold(values, segment_ids, row_ptr):
-        return _scan_rows(values, segment_ids, row_ptr, num_rows, kind)
+        return _scan_rows(values, segment_ids, row_ptr, num_rows, kind,
+                          ends)
 
     @fold.def_vmap
     def lanes(axis_size, in_batched, values, segment_ids, row_ptr):
@@ -349,7 +397,7 @@ def _scan_fold(num_rows: int, kind: str):
             # bits (docs/SERVING.md)
             return jax.vmap(
                 lambda v: _scan_rows(v, segment_ids, row_ptr, num_rows,
-                                     kind)
+                                     kind, ends)
             )(values), True
         # no kernel for these lanes' tables (other backends, 64-bit
         # values, tables over the budget): XLA gathers all lanes of an
@@ -438,18 +486,15 @@ def segment_top_label(count, label, segment_ids, num_rows: int,
     FOLD_STATS["scan"] += 1
     empty = jnp.iinfo(label.dtype).max
     with jax.named_scope("grape.pull.fold"):
-        if row_ptr is None:
+        offsets = row_ptr is not None
+        if not offsets:
             row_ptr = jnp.searchsorted(
                 segment_ids,
                 jnp.arange(num_rows + 1, dtype=segment_ids.dtype))
         _, best = _segmented_scan_pair(
             (count, label), segment_ids, _more_then_smaller, (0, empty))
-        # an empty row has no last place
-        last = row_ptr[1:num_rows + 1] - 1
-        out = best.at[jnp.maximum(last, 0)].get(
-            mode="promise_in_bounds", indices_are_sorted=True)
-        return jnp.where(last >= row_ptr[:num_rows], out,
-                         jnp.asarray(empty, label.dtype))
+        return _row_ends(best, row_ptr, num_rows, empty,
+                         _row_end_gather(label.dtype, offsets))
 
 
 # ---- a round whose work follows its frontier -----------------------------

@@ -86,11 +86,28 @@ def pull_kernel(monkeypatch):
     kernel's bits: tests/test_pull_gather.py pins those).  Returns the
     list the kernel's calls are noted in, as (table dtype, table
     shape, stream shape); either way a call refuses what the real
-    kernel cannot take."""
+    kernel cannot take.  The fold's row ends follow the same choice
+    (`segment._row_end_gather`): `rows="interpreted"` puts
+    `pallas_kernels.vmem_row_gather` in interpret mode there, at
+    small slices (10 s of XLA:CPU compile an instance); the default is
+    its plain stand-in, so that the cases that were here before it pay
+    for one interpreted kernel, as they did."""
     from libgrape_lite_tpu.ops import pallas_kernels, segment
 
-    def arm(kind: str) -> list:
+    def arm(kind: str, rows: str = "stand_in") -> list:
         calls = []
+
+        def row_kernel(table, idx):
+            assert table.ndim == 1 and table.dtype.itemsize == 4, table
+            assert idx.ndim == 1 and idx.dtype == np.int32, idx
+            if rows == "interpreted":
+                # slices of 2,048 places and blocks of 1,024 ends, so
+                # that a test's CSR spans several of each
+                return pallas_kernels.vmem_row_gather(
+                    table, idx, interpret=True, slice_rows=16, end_rows=8)
+            assert rows == "stand_in", rows
+            return table.at[idx].get(mode="promise_in_bounds",
+                                     indices_are_sorted=True)
 
         def kernel(full, nbr):
             assert full.ndim == 1 and full.dtype.itemsize == 4, full
@@ -105,6 +122,7 @@ def pull_kernel(monkeypatch):
         monkeypatch.setattr(segment, "gather_table_budget",
                             lambda: GATHER_BUDGET)
         monkeypatch.setattr(segment, "vmem_gather", kernel)
+        monkeypatch.setattr(segment, "vmem_row_gather", row_kernel)
         return calls
 
     return arm

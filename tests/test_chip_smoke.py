@@ -52,12 +52,16 @@ def test_smoke_rehearsal_runs_every_stage():
     assert all(s["stage_b"][a]["compiles_warm"] == 0
                for a in ("pagerank", "sssp", "bfs", "wcc"))
     assert s["stage_b"]["serve"]["queries"] == 8
-    assert set(s["stage_c"]) == {"intersect_count", "vmem_gather"}
+    assert set(s["stage_c"]) == {"intersect_count", "vmem_gather",
+                                 "vmem_row_gather"}
     # off the TPU the intersect kernel's dispatcher takes the jnp path
     assert s["stage_c"]["intersect_count"]["pallas_calls"] == 0
     # and the pull's gather is XLA's, checked against itself
     assert s["stage_c"]["vmem_gather"]["pallas_calls"] == 0
     assert s["stage_c"]["vmem_gather"]["took"] == {"kernel": 0, "xla": 2}
+    # as are the fold's row ends, under a fold checked against the scatter
+    assert s["stage_c"]["vmem_row_gather"]["pallas_calls"] == 0
+    assert s["stage_c"]["vmem_row_gather"]["took"] == {"kernel": 0, "xla": 2}
     assert not any(v["compiled"] for v in s["stage_c"].values())
 
 

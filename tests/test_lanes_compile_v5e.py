@@ -11,6 +11,8 @@ is described inside a fixture, in this one file, so that only the worker
 that is handed the file loads the TPU's library.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -24,14 +26,18 @@ LANES, V, EP = 4, 262144, 1 << 20
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
 
     try:
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -68,7 +74,11 @@ def test_armed_lanes_compile_to_kernel_and_scan(dtype, one_chip,
     assert "tpu_custom_call" in text and "vmem_gather" in text
     assert " scatter(" not in text
     assert "while(" in text  # the lanes are a loop, not four copies
-    assert text.count("tpu_custom_call") == 1
+    # one instance of each kernel: the pull's gather and, since PR 45,
+    # the fold's row ends; XLA's V-wide gather of them is gone
+    assert text.count("tpu_custom_call") == 2
+    assert "vmem_row_gather" in text
+    assert not re.search(rf"= \w+\[{V}\]\S* fusion\(.*kind=kCustom", text)
 
 
 def test_unarmed_lanes_compile_to_the_fused_scatter(one_chip):
@@ -135,6 +145,19 @@ def road20():
     return fragment(n, src, dst, w.astype(np.float32))
 
 
+# Generated code of PR 44's runners, compiled here as below (bytes;
+# `scratch/runner_code45.py` on the parent's export, PR 45): the row-end
+# kernel takes the place of one `kCustom` fusion and may add this much
+# and no more, because code is HBM and `hbm_peak_bytes` is bounded at 1%
+PARENT_CODE = {"road.bfs": 6_392_320, "road.sssp": 7_554_048,
+               "lanes.bfs": 3_321_344, "lanes.sssp": 3_538_944}
+
+
+def _within(compiled, parent: int, room: int):
+    code = compiled.memory_analysis().generated_code_size_in_bytes
+    assert code <= parent + room, (code, parent)
+
+
 def _described(w, frag, state, key_specs, one_chip):
     """`(dev, carried)`: the fragment and a host state as shapes on the
     described chip, for `runner.lower`; the worker's mesh becomes that
@@ -170,8 +193,6 @@ def test_the_road_runner_keeps_its_values_in_vmem(name, values, one_chip,
     road cell is compiled here as the chip compiles it, and the loop's
     values (memory space 1) and the fetches of the edge blocks into it
     have to be there."""
-    import re
-
     from libgrape_lite_tpu.models import APP_REGISTRY
     from libgrape_lite_tpu.worker.worker import Worker
 
@@ -183,8 +204,17 @@ def test_the_road_runner_keeps_its_values_in_vmem(name, values, one_chip,
         assert w.app.frontier_budget == (ROWS, ENTRIES)
         assert not w.app.ephemeral_keys
         dev, carried = _described(w, road20, state, w._key_specs, one_chip)
-        text = w._make_runner(w.app.max_rounds)(state).lower(
-            dev, carried, {}).compile().as_text()
+        ends = segment.ROW_END_STATS.snapshot()
+        compiled = w._make_runner(w.app.max_rounds)(state).lower(
+            dev, carried, {}).compile()
+    text = compiled.as_text()
+    # the dense arm reads its row ends by the kernel (it never runs in
+    # the road cells, but its code is HBM there: `hbm_peak_bytes` is
+    # bounded at 1% of 67.7 / 68.8 MB)
+    assert segment.ROW_END_STATS.snapshot() == {
+        **ends, "kernel": ends["kernel"] + 1}
+    assert "vmem_row_gather" in text
+    _within(compiled, PARENT_CODE["road." + name], 600_000)
     loops = [line for line in text.splitlines()
              if re.search(r" while\(", line) and f"{values}[{ROAD_V}]" in line]
     assert loops and all(
@@ -222,8 +252,6 @@ def test_the_serving_sssp_lanes_read_the_fragments_own_weights(
     `edge_w`: a batch builds and places its lanes and no stream beside
     them (a pre-masked copy of the weights was 33.5 MB and 0.18 s of
     host time a batch; PERF.md section 6, PR 44)."""
-    import re
-
     from libgrape_lite_tpu.models import APP_REGISTRY
     from libgrape_lite_tpu.ops.segment import FOLD_STATS, GATHER_STATS
     from libgrape_lite_tpu.worker.worker import Worker
@@ -240,8 +268,10 @@ def test_the_serving_sssp_lanes_read_the_fragments_own_weights(
         dev, carried = _described(w, kron18, state, w._key_specs_batch,
                                   one_chip)
         gathers, folds = GATHER_STATS.snapshot(), FOLD_STATS.snapshot()
-        text = w._make_batched_runner(w.app.max_rounds, LANES)(state).lower(
-            dev, carried, {}).compile().as_text()
+        compiled = w._make_batched_runner(
+            w.app.max_rounds, LANES)(state).lower(dev, carried, {}).compile()
+    text = compiled.as_text()
+    _within(compiled, PARENT_CODE["lanes.sssp"], 700_000)
     took = {k: v - gathers[k] for k, v in GATHER_STATS.snapshot().items()}
     assert took == {"kernel": 1, "xla": 0}
     took = {k: v - folds[k] for k, v in FOLD_STATS.snapshot().items()}
@@ -251,3 +281,79 @@ def test_the_serving_sssp_lanes_read_the_fragments_own_weights(
     handed = re.findall(r"= (\w+\[[0-9,]*\])\S* parameter\(", entry)
     assert f"f32[{LANES},1,{SERVE_V}]" in handed
     assert handed.count(f"f32[1,{SERVE_EP}]") == 1
+
+
+def test_the_serving_bfs_lanes_hold_their_code(one_chip, kron18,
+                                               monkeypatch):
+    """The serving cell's other batched runner: one instance of each
+    kernel in the lanes' loops, the row ends counted once as `kernel`,
+    and its code within the parent's + 0.7 MB (the cell has 1.56 MB of
+    room for its two runners; PERF.md section 6, PR 45)."""
+    from libgrape_lite_tpu.models import APP_REGISTRY
+    from libgrape_lite_tpu.worker.worker import Worker
+
+    monkeypatch.setattr(segment, "use_pallas", lambda: True)
+    monkeypatch.setattr(segment, "gather_table_budget", lambda: 64 << 20)
+    with jax.enable_x64(False):
+        w = Worker(APP_REGISTRY["bfs"](), kron18)
+        state = w.app.init_state(kron18, source=[5, 6, 7, 9])
+        dev, carried = _described(w, kron18, state, w._key_specs_batch,
+                                  one_chip)
+        eph = frozenset(w.app.ephemeral_keys or ())
+        ends = segment.ROW_END_STATS.snapshot()
+        compiled = w._make_batched_runner(
+            w.app.max_rounds, LANES)(state).lower(
+                dev, {k: v for k, v in carried.items() if k not in eph},
+                {k: v for k, v in carried.items() if k in eph}).compile()
+    assert segment.ROW_END_STATS.snapshot() == {
+        **ends, "kernel": ends["kernel"] + 1}
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 and " scatter(" not in text
+    assert "vmem_gather" in text and "vmem_row_gather" in text
+    _within(compiled, PARENT_CODE["lanes.bfs"], 700_000)
+
+
+# ---- the row-end kernel alone, at the cells' shapes ----
+
+ROW_END_SHAPES = {
+    # name: (dtype, rows, entries, devices)
+    "g500_s21": ("float32", 1 << 21, 1 << 26, 1),
+    "g500_s21_bfs": ("int32", 1 << 21, 1 << 26, 1),
+    "cdlp_s19": ("int32", 1 << 19, 1 << 24, 1),
+    # a shard of four, inside the `shard_map`: a ragged last slice
+    "g500_s21_x4": ("float32", 1 << 19, 17192832, 4),
+}
+
+
+@pytest.mark.parametrize("name", sorted(ROW_END_SHAPES))
+def test_the_row_end_kernel_compiles_at_the_cells_shapes(name, topo,
+                                                         one_chip):
+    """`vmem_row_gather` through the chip's own compiler at the streams
+    the cells hold (two 4 MiB slices in VMEM, 64 of them at scale 21),
+    on one chip and per shard of a four-chip mesh."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from libgrape_lite_tpu.ops.pallas_kernels import vmem_row_gather
+
+    dtype, rows, entries, devices = ROW_END_SHAPES[name]
+    if devices == 1:
+        fn = vmem_row_gather
+        args = (jax.ShapeDtypeStruct((entries,), dtype, sharding=one_chip),
+                jax.ShapeDtypeStruct((rows,), "int32", sharding=one_chip))
+    else:
+        mesh = Mesh(np.array(topo.devices), ("f",))
+        fn = jax.shard_map(
+            lambda t, i: vmem_row_gather(t[0], i[0])[None], mesh=mesh,
+            in_specs=(P("f"), P("f")), out_specs=P("f"))
+        over = NamedSharding(mesh, P("f"))
+        args = (jax.ShapeDtypeStruct((devices, entries), dtype,
+                                     sharding=over),
+                jax.ShapeDtypeStruct((devices, rows), "int32",
+                                     sharding=over))
+    with jax.enable_x64(False):
+        compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "vmem_row_gather" in text
+    # the stream is read where it lies: no copy of it beside the kernel
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20

@@ -110,3 +110,51 @@ def test_vmem_gather_lowers_for_tpu():
     for name in ("S21", "X4"):
         for dt in ("float32", "int32"):
             assert f"VMEM_GATHER_LOWERED_{name}_{dt}" in r.stdout
+
+
+# the fold's row ends at the cells' shapes: a Graph500 scale-21 stream
+# (64 slices), a four-chip shard's (ragged last slice, and inside a
+# shard_map that checks varying axes), the serving cell's
+SCRIPT4 = r"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh, PartitionSpec as P
+
+import sys
+sys.path.insert(0, %(repo)r)
+
+from libgrape_lite_tpu.ops.pallas_kernels import vmem_row_gather
+
+for name, v, e in (("S21", 2097152, 67108864), ("X4", 524288, 17192832),
+                   ("S18", 262144, 8388608)):
+    for dt in (jnp.float32, jnp.int32):
+        low = jax.jit(vmem_row_gather).trace(
+            jax.ShapeDtypeStruct((e,), dt),
+            jax.ShapeDtypeStruct((v,), jnp.int32),
+        ).lower(lowering_platforms=('tpu',))
+        assert "tpu_custom_call" in low.as_text()
+        print(f"VMEM_ROW_GATHER_LOWERED_{name}_{jnp.dtype(dt).name}")
+
+mesh = Mesh(np.array(jax.devices()[:4]), ("f",))
+low = jax.jit(jax.shard_map(
+    lambda t, i: vmem_row_gather(t[0], i[0])[None], mesh=mesh,
+    in_specs=(P("f"), P("f")), out_specs=P("f"),
+)).trace(
+    jax.ShapeDtypeStruct((4, 17192832), jnp.float32),
+    jax.ShapeDtypeStruct((4, 524288), jnp.int32),
+).lower(lowering_platforms=('tpu',))
+assert "tpu_custom_call" in low.as_text()
+print("VMEM_ROW_GATHER_LOWERED_SHARD_MAP")
+"""
+
+
+def test_vmem_row_gather_lowers_for_tpu():
+    r = _run_offline(
+        SCRIPT4 % {"repo": REPO},
+        XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-4000:]
+    assert "VMEM_ROW_GATHER_LOWERED_SHARD_MAP" in r.stdout
+    for name in ("S21", "X4", "S18"):
+        for dt in ("float32", "int32"):
+            assert f"VMEM_ROW_GATHER_LOWERED_{name}_{dt}" in r.stdout

@@ -26,8 +26,10 @@ import pytest
 from libgrape_lite_tpu.models import APP_REGISTRY
 from libgrape_lite_tpu.ops.segment import (
     FOLD_STATS,
+    ROW_END_STATS,
     SCAN_TILE,
     segment_reduce,
+    segment_top_label,
 )
 from libgrape_lite_tpu.worker.worker import Worker
 
@@ -344,3 +346,198 @@ def test_dyn_overlay_folds_by_scatter(app):
     w = Worker(APP_REGISTRY[app](), dg.fragment)
     kw = {} if app == "wcc" else {"source": 0}
     assert _folds_traced(w, **kw) == {"scan": 1, "scatter": 1}
+
+
+# ---- the row ends: by the kernel where the values are its kind -----------
+
+
+def _skewed_csr(rows=3000, ep=200 * T, seed=3):
+    """A CSR with a few hubs, many empty rows and padding behind the
+    last row; 25,600 places: thirteen of the armed kernel's slices,
+    three of its blocks of row ends."""
+    rng = np.random.default_rng(seed)
+    deg = np.where(rng.random(rows) < 0.4, 0, rng.geometric(0.25, rows))
+    # rows that span slices, the second behind a run of empty ones
+    deg[[100, rows // 2, rows - 100]] = 3000, 5000, 2500
+    deg[rows // 2 - 40:rows // 2] = 0
+    ptr = np.zeros(rows + 1, np.int32)
+    ptr[1:] = np.cumsum(deg)
+    ids = np.full(ep, rows, np.int32)
+    ids[:ptr[-1]] = np.repeat(np.arange(rows, dtype=np.int32), deg)
+    assert ptr[-1] < ep - 300 and (deg == 0).sum() > rows // 3
+    return rows, ptr, ids
+
+
+def _row_ends_took(fn):
+    before = ROW_END_STATS.snapshot()
+    out = fn()
+    return out, {k: v - before[k] for k, v in ROW_END_STATS.snapshot().items()}
+
+
+def _top_label_scatter(count, label, ids, rows):
+    """`segment_top_label` by scatters: the largest count of a row,
+    then the smallest label that has it."""
+    top = jops.segment_max(count, ids, num_segments=rows + 1)
+    best = jnp.where(count == top[ids], label, jnp.iinfo(label.dtype).max)
+    return jops.segment_min(best, ids, num_segments=rows + 1)[:rows]
+
+
+ROW_END_FOLDS = {
+    "sum_f32": ("sum", "float32"), "min_s32": ("min", "int32"),
+    "max_f32": ("max", "float32"), "top_label": ("top", "int32"),
+}
+
+
+@pytest.mark.parametrize("fold,how", [
+    (f, h) for f in sorted(ROW_END_FOLDS)
+    for h in ("single", "vmap4", "shard_map2")
+    # no caller batches CDLP's fold
+    if (f, h) != ("top_label", "vmap4")])
+def test_row_ends_by_the_kernel(fold, how, pull_kernel):
+    """With the choice steered as the TPU backend steers it and the
+    row-end kernel interpreted behind it, the scan fold and CDLP's
+    `segment_top_label` equal the scatter fold on a skewed CSR with
+    empty rows: a single call, query lanes under `jax.vmap` (each with
+    its single call's bytes) and the shards of a two-fragment
+    `shard_map`; each call site counts once, as `kernel`."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    kind, dtype = ROW_END_FOLDS[fold]
+    pull_kernel("stand_in", rows="interpreted")
+    rows, ptr, ids = _skewed_csr()
+    ep = ids.shape[0]
+    lead = {"single": (), "vmap4": (4,), "shard_map2": (2,)}[how]
+    rng = np.random.default_rng(ep)
+    label = rng.integers(0, 50, lead + (ep,)).astype(np.int32)
+    count = rng.integers(1, 9, lead + (ep,)).astype(np.int32)
+    vals = _values(kind, dtype, lead + (ep,), seed=7) if kind != "top" \
+        else label
+    jids, jptr = jnp.asarray(ids), jnp.asarray(ptr)
+
+    def one(v, c):
+        if kind == "top":
+            return segment_top_label(c, v, jids, rows, row_ptr=jptr)
+        return segment_reduce(v, jids, rows, kind, row_ptr=jptr)
+
+    def scatter(v, c):
+        if kind == "top":
+            return _top_label_scatter(c, v, jids, rows)
+        return segment_reduce(v, jids, rows, kind)
+
+    def over(f):
+        if how == "vmap4":
+            return jax.vmap(f)
+        if how == "shard_map2":
+            mesh = Mesh(np.array(jax.devices()[:2]), ("f",))
+            return jax.shard_map(
+                lambda v, c: f(v[0], c[0])[None], mesh=mesh,
+                in_specs=(P("f"), P("f")), out_specs=P("f"))
+        return f
+
+    got, took = _row_ends_took(
+        lambda: np.asarray(jax.jit(over(one))(vals, count)))
+    assert took == {"kernel": 1, "xla": 0}
+    want = np.asarray(jax.jit(over(scatter))(vals, count))
+    assert got.dtype == want.dtype and got.shape == lead + (rows,)
+    if lead:
+        single = jax.jit(one)
+        assert got.tobytes() == np.stack([
+            np.asarray(single(v, c)) for v, c in zip(vals, count)]).tobytes()
+    if kind == "sum":
+        # groups by tile, so not the scatter's bits
+        np.testing.assert_allclose(got, want, rtol=1e-5)
+        np.testing.assert_array_equal(got == 0, want == 0)
+    else:
+        assert got.tobytes() == want.tobytes()
+
+
+@pytest.mark.parametrize("name,dtype,armed,offsets", [
+    ("f64_on_tpu", "float64", True, True),
+    ("s64_on_tpu", "int64", True, True),
+    ("f32_off_tpu", "float32", False, True),
+    ("s32_off_tpu", "int32", False, True),
+    ("top_label_looks_its_offsets_up", "int32", True, False),
+])
+def test_row_ends_by_xla_lower_as_before(name, dtype, armed, offsets,
+                                         pull_kernel):
+    """64-bit values, other backends and a `segment_top_label` that has
+    to look its offsets up keep XLA's gather of the row ends: counted
+    as `xla`, and lowered to the text they lowered to before there was
+    a choice (the old lines, spelled out here)."""
+    from libgrape_lite_tpu.ops import segment
+
+    if armed:
+        pull_kernel("stand_in")
+    rows, ep = 37, 2 * T
+    top = not offsets
+
+    def old_ends(scanned, row_ptr, empty):
+        last = row_ptr[1:rows + 1] - 1
+        out = scanned.at[jnp.maximum(last, 0)].get(
+            mode="promise_in_bounds", indices_are_sorted=True)
+        return jnp.where(last >= row_ptr[:rows], out,
+                         jnp.asarray(empty, scanned.dtype))
+
+    def before(values, ids, ptr):
+        with jax.named_scope("grape.pull.fold"):
+            if top:
+                empty = jnp.iinfo(values.dtype).max
+                ptr = jnp.searchsorted(
+                    ids, jnp.arange(rows + 1, dtype=ids.dtype))
+                _, best = segment._segmented_scan_pair(
+                    (values, values), ids, segment._more_then_smaller,
+                    (0, empty))
+                return old_ends(best, ptr, empty)
+            identity = segment._FOLDS["min"][2](values.dtype)
+            scanned = segment._segmented_scan(
+                values, ids, jnp.minimum, identity)
+            return old_ends(scanned, ptr, identity)
+
+    def fold(values, ids, ptr):
+        if top:
+            return segment_top_label(values, values, ids, rows)
+        return segment_reduce(values, ids, rows, "min", row_ptr=ptr)
+
+    before.__name__ = before.__qualname__ = "fold"
+    args = (jax.ShapeDtypeStruct((ep,), jnp.dtype(dtype)),
+            jax.ShapeDtypeStruct((ep,), jnp.int32),
+            jax.ShapeDtypeStruct((rows + 1,), jnp.int32))
+    text, took = _row_ends_took(lambda: jax.jit(fold).lower(*args).as_text())
+    assert took == {"kernel": 0, "xla": 1}
+    assert text == jax.jit(before).lower(*args).as_text()
+
+
+@pytest.mark.parametrize("name,kind,dtype,budget,fold,ends", [
+    # the kernel's lanes: one scan, one gather of row ends, however
+    # often the `vmap` rules trace the fold
+    ("exact_lanes", "min", "int32", 1 << 20, "scan", "kernel"),
+    # a table over the gather kernel's budget: a float sum's lanes scan
+    # behind XLA's gather and still read their row ends by the kernel,
+    # one lane after another
+    ("float_sum_over_budget", "sum", "float32", 0, "scan", "kernel"),
+    # and an exact fold's lanes go back to the scatter, which reads no
+    # row ends: the entry stays, as the choice the call made before
+    # its `vmap` rule ran (the single query it was traced as)
+    ("exact_lanes_over_budget", "min", "int32", 0, "scatter", "kernel"),
+])
+def test_lanes_count_their_row_ends_once(name, kind, dtype, budget, fold,
+                                         ends, pull_kernel, monkeypatch):
+    from libgrape_lite_tpu.ops import segment
+
+    pull_kernel("stand_in")
+    monkeypatch.setattr(segment, "gather_table_budget", lambda: budget)
+    rows, ptr, ids = _csr("empty_rows")
+    vals = _values(kind, dtype, (4, ids.shape[0]), seed=9)
+
+    def one(v):
+        return segment_reduce(v, jnp.asarray(ids), rows, kind,
+                              row_ptr=jnp.asarray(ptr))
+
+    folds = FOLD_STATS.snapshot()
+    got, took = _row_ends_took(
+        lambda: np.asarray(jax.jit(jax.vmap(one))(vals)))
+    assert FOLD_STATS.snapshot() == {**folds, fold: folds[fold] + 1}
+    assert took == {"kernel": int(ends == "kernel"), "xla": 0}
+    single = jax.jit(one)
+    assert got.tobytes() == np.stack(
+        [np.asarray(single(v)) for v in vals]).tobytes()
