@@ -491,14 +491,21 @@ def gather_check(on_tpu: bool) -> dict:
 def row_ends_check(on_tpu: bool) -> dict:
     """`segment_reduce(row_ptr=)` as the apps call it, on the shapes of
     the x4 cell's shard (524,288 rows, a third of them empty, over
-    17,192,832 places: five slices, the last ragged): on the TPU the
-    row ends must be read by the kernel that was traced, compiled, and
-    the fold equal the scatter's bit for bit (a min: exact under any
-    grouping).  Off it (a rehearsal) the choice is XLA's gather."""
+    17,192,832 places: five slices of the scanned stream, the last
+    ragged, and 66 blocks of tiles, the last ragged): on the TPU the
+    scan's first level and the row ends must go by the kernels that
+    were traced, compiled, and the fold equal the scatter's bit for
+    bit (a min: exact under any grouping).  A float sum's bits hang on
+    the grouping, so `tile_scan` is also held, alone, to XLA's seven
+    steps bit for bit.  Off the TPU (a rehearsal) the choice is XLA's
+    steps and XLA's gather."""
     import jax
     import jax.numpy as jnp
 
-    from libgrape_lite_tpu.ops.segment import ROW_END_STATS, segment_reduce
+    from libgrape_lite_tpu.ops import segment
+    from libgrape_lite_tpu.ops.segment import (
+        ROW_END_STATS, SCAN_STATS, segment_reduce,
+    )
 
     rows, n = 524_288, 134_319 * 128
     rng = np.random.default_rng(45)
@@ -508,7 +515,7 @@ def row_ends_check(on_tpu: bool) -> dict:
     ids = np.full(n, rows, np.int32)
     ids[:ptr[-1]] = np.repeat(np.arange(rows, dtype=np.int32), deg)
     out = {"ok": True, "rows": rows, "places": n}
-    before = ROW_END_STATS.snapshot()
+    before = ROW_END_STATS.snapshot(), SCAN_STATS.snapshot()
     with pallas_spy() as calls:
         for dtype in (np.float32, np.int32):
             vals = jnp.asarray(rng.integers(-9, 9, n).astype(dtype))
@@ -521,15 +528,32 @@ def row_ends_check(on_tpu: bool) -> dict:
                     f"stage C vmem_row_gather: {np.dtype(dtype).name} scan "
                     "fold is not the scatter's")
             out[f"{np.dtype(dtype).name}_cold_wall_s"] = wall
-    took = ROW_END_STATS.snapshot()
-    out["took"] = {k: took[k] - before[k] for k in took}
+    took = ROW_END_STATS.snapshot(), SCAN_STATS.snapshot()
+    out["took"], out["scan_took"] = (
+        {k: t[k] - b[k] for k in t} for t, b in zip(took, before))
     if on_tpu:
         require(out["took"] == {"kernel": 2, "xla": 0},
                 f"stage C vmem_row_gather: the choice was {out['took']}")
-        require(len(calls) == 2 and not any(calls),
-                "stage C vmem_row_gather: not compiled")
+        require(out["scan_took"] == {"kernel": 2, "xla": 0},
+                f"stage C tile_scan: the choice was {out['scan_took']}")
+        require(len(calls) == 4 and not any(calls),
+                "stage C tile_scan, vmem_row_gather: not compiled")
+        # the float sum, whose grouping shows: the kernel alone against
+        # the steps it stands for
+        from libgrape_lite_tpu.ops.pallas_kernels import tile_scan
+
+        vals = jnp.asarray((rng.standard_normal(n) * 10.0 ** rng.integers(
+            -3, 6, n)).astype(np.float32)).reshape(-1, segment.SCAN_TILE)
+        tiles = jnp.asarray(ids).reshape(-1, segment.SCAN_TILE)
+        got = jax.jit(lambda v, i: tile_scan(v, i, jnp.add))(vals, tiles)
+        want = jax.jit(lambda v, i: segment._tile_steps(
+            v, i, jnp.add, 0))(vals, tiles)
+        require(np.asarray(got).tobytes() == np.asarray(want).tobytes(),
+                "stage C tile_scan: a float sum's tiles are not the "
+                "XLA steps' bit for bit")
+        out["float_sum_bit_equal"] = True
     out.update(spy_summary(calls))
-    log(f"C vmem_row_gather: {out}")
+    log(f"C tile_scan, vmem_row_gather: {out}")
     return out
 
 

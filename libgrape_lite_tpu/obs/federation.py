@@ -56,6 +56,7 @@ EXPECTED: Dict[str, str] = {
     "fold": "libgrape_lite_tpu.ops.segment",
     "gather": "libgrape_lite_tpu.ops.segment",
     "row_ends": "libgrape_lite_tpu.ops.segment",
+    "scan": "libgrape_lite_tpu.ops.segment",
     "lcc": "libgrape_lite_tpu.models.lcc_beta",
     "cdlp": "libgrape_lite_tpu.models.cdlp",
     "setup": "libgrape_lite_tpu.obs.tracer",

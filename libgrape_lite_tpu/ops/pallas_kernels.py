@@ -26,6 +26,13 @@ stay, read by indices that do not decrease: a fold's row ends out of
 its scanned stream.  The table passes through VMEM a slice at a time
 and each block of indices meets the slices it spans
 (`ops/segment._row_end_gather` chooses).
+
+`tile_scan` is the first level of that fold's segmented scan: the
+seven steps that scan a tile of 128 places stay inside the tile's 128
+lanes, so a block of tiles is read once, scanned in registers by lane
+rotations and selects, and written once, where XLA's seven fusions
+read and write the whole stream each (`ops/segment._first_level`
+chooses).
 """
 
 from __future__ import annotations
@@ -387,3 +394,92 @@ def vmem_row_gather(table, idx, interpret: bool = False,
     )(blk, sl, idx, tab)
     out = out.reshape(-1)
     return out[:n] if npad else out
+
+
+# ---- the scan's first level, in one pass through VMEM ---------------------
+
+# Rows of 128 in a block of the stream: 1 MiB a stream, and the
+# pipeline holds two each of the values, the ids and the output
+_SCAN_ROWS = 2048
+# Rows of 128 the seven steps are done on at a time, in registers: 16
+# vregs of values and 16 of ids.  A step waits for its rotations, so a
+# chunk takes about 0.59 us whatever it holds up to here (8.77 ms at
+# Graph500 scale 21 with 32 rows, 2.41 with 128, 1.84 with 256, whose
+# code is a third larger: PERF.md section 6, PR 47)
+_SCAN_CHUNK = 128
+
+
+def tile_scan_floor() -> int:
+    """Bytes of a scan's three streams (the values, the ids and the
+    scanned values) over which `tile_scan` takes their first level:
+    what the device reports as its VMEM (`pltpu.get_tpu_info()`; 128
+    MiB on the v5e).  Streams that fit there XLA keeps there between
+    its seven steps, with their neighbours fused in: alone the kernel
+    still wins on them (0.33 ms for 0.46 on a serving lane's 8.4M
+    entries), inside a runner it does not (`serve-g500-s18.keys8`
+    -0.7%, `road-like-cc.wcc` +0.4%; PERF.md section 6, PR 47), so
+    they keep XLA's steps and their runners the text they had."""
+    return pltpu.get_tpu_info().vmem_capacity_bytes
+
+
+def _tile_scan_kernel(v_ref, i_ref, out_ref, *, combine, chunk: int,
+                      chunks: int):
+    """One block of tiles: `ops/segment._segmented_scan`'s first level,
+    its seven distances in its order with its operands, on `chunk` rows
+    at a time.  At distance d a lane takes in the lane d below it (a
+    rotation along the lanes, of the values and of the ids) where the
+    ids agree; a lane under d sees the id -1 there, as `_shift` fills
+    it, and keeps its value.  A tile is a row, so nothing passes from
+    one row to the next, and the rows a ragged last block brings along
+    cost nothing but their time."""
+    lane = lax.broadcasted_iota(jnp.int32, (chunk, LANES), 1)
+
+    def step(c, carry):
+        at = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+        v, i = v_ref[at, :], i_ref[at, :]
+        d = 1
+        while d < LANES:
+            below = jnp.where(lane >= d, pltpu.roll(i, d, 1), -1)
+            v = jnp.where(i == below, combine(v, pltpu.roll(v, d, 1)), v)
+            d *= 2
+        out_ref[at, :] = v
+        return carry
+
+    lax.fori_loop(0, chunks, step, 0)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("combine", "interpret", "block_rows", "chunk"))
+def tile_scan(values, ids, combine, interpret: bool = False,
+              block_rows: int = _SCAN_ROWS, chunk: int = _SCAN_CHUNK):
+    """The first level of `ops/segment._segmented_scan`, bit for bit:
+    each tile of 128 (a row of the `[E / 128, 128]` views `values`, 32
+    bits wide, and `ids`, int32) scanned by `combine` (`jnp.add`,
+    `jnp.minimum`, ...: the fold's own), restarting where the ids
+    change.
+
+    The two streams pass through VMEM once in blocks of `block_rows`
+    tiles and the scanned block leaves once; the seven steps between
+    stay in registers.  The last block may be ragged.  `block_rows`
+    and `chunk` (whole 8s, the one a multiple of the other) are the
+    tests', which scan a few tiles."""
+    nrows = values.shape[0]
+    chunk = min(chunk, pl.cdiv(nrows, SUBLANES) * SUBLANES)
+    block_rows = min(block_rows, pl.cdiv(nrows, chunk) * chunk)
+    spec = pl.BlockSpec((block_rows, LANES), lambda g: (g, 0))
+    return pl.pallas_call(
+        functools.partial(_tile_scan_kernel, combine=combine, chunk=chunk,
+                          chunks=block_rows // chunk),
+        grid=(pl.cdiv(nrows, block_rows),),
+        in_specs=[spec, spec],
+        out_specs=spec,
+        # inside a `shard_map` that checks them, the output varies over
+        # the mesh axes its operands vary over
+        out_shape=jax.ShapeDtypeStruct(
+            values.shape, values.dtype,
+            vma=jax.typeof(values).vma | jax.typeof(ids).vma),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="tile_scan",
+    )(values, ids)

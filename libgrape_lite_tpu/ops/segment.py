@@ -13,14 +13,26 @@ on the TPU v5e a scatter steps through its updates one at a time, 8.7
 ns an element at Graph500 scale 10 as at scale 21, sorted ids or not
 (PERF.md, section 5): `indices_are_sorted` does not turn it into a
 scan.  So where the rows are a CSR's, sorted and with their offsets at
-hand, the fold is a scan written out in dense XLA: `_segmented_scan`
-over tiles of 128 places, then each row's fold from the row's last
-place: V sorted reads of the E-wide scanned stream.  XLA's gather
-takes 18.1 ns a row for them at scale 21 (every read misses: a third
-of a dense round), so where the values are 32 bits wide on the TPU
-backend `ops/pallas_kernels.vmem_row_gather` reads them from slices of
-the stream that pass through VMEM (`_row_end_gather`; ROW_END_STATS
-counts which of the two a call took).  Everything else keeps the scatter:
+hand, the fold is a scan: `_segmented_scan` over tiles of 128 places,
+then each row's fold from the row's last place: V sorted reads of the
+E-wide scanned stream.  The scan's first level is seven steps a tile,
+at distances 1 to 64, none of which leaves the tile's 128 lanes.
+Written out in dense XLA they are seven E-wide fusions that each read
+the values and the ids and write the values again (13.2 ms at scale
+21, the stream seven times through the HBM), so where the values are
+32 bits wide on the TPU backend `ops/pallas_kernels.tile_scan` does
+them in registers on one pass of the stream through VMEM, the same
+distances in the same order with the same operands, so that a float
+sum keeps its grouping and its bits (`_first_level`; SCAN_STATS counts
+which of the two a call took; the levels above, `Ep / 128` places and
+fewer, keep XLA's steps, and so does a stream whose values, ids and
+scanned values fit the device's VMEM together, 11.2M entries on the
+v5e: XLA holds those there between its steps).  XLA's gather takes
+18.1 ns a row for the row ends at scale 21 (every read misses: a third
+of a dense round), so for 32-bit values on the TPU backend
+`ops/pallas_kernels.vmem_row_gather` reads them from slices of the
+stream that pass through VMEM (`_row_end_gather`; ROW_END_STATS counts
+which of the two a call took).  Everything else keeps the scatter:
 ids that are not sorted and streams without offsets (the dyn overlay,
 `exchange_base`, the 2-D tiles of `vc2d`, `bc`, `kcore`, 64-bit
 lanes), and since PR 46 the one V-wide scatter whose targets are data:
@@ -32,11 +44,12 @@ queries' gather is the kernel below; where it is not, the lanes of an
 exact fold keep the scatter, into which XLA fuses their gather, and a
 float sum's lanes scan, because its bits depend on the grouping and a
 lane answers with its single query's bytes.  CDLP's count is a scan as
-well, and never a scatter: `run_position` and `segment_top_label` work
-on the (row, label) pairs its sort has just put in order, with the
-CSR's offsets, which every round has (a caller without them gets them
-by a binary search of the sorted rows, and XLA's gather of the row
-ends: no round today).
+well, and never a scatter: `run_position` and `segment_top_label`
+(both keep XLA's steps: see `run_position`) work on the (row, label)
+pairs its
+sort has just put in order, with the CSR's offsets, which every round
+has (a caller without them gets them by a binary search of the sorted
+rows, and XLA's gather of the row ends: no round today).
 
 The module makes a third choice, in `pull_gather`: how `full[nbr]` is
 read.  XLA's gather also steps through its indices one at a time (8.6
@@ -82,6 +95,8 @@ from jax.custom_batching import custom_vmap
 from libgrape_lite_tpu.obs.federation import FederatedStats as _FedStats
 from libgrape_lite_tpu.ops.pallas_kernels import (
     gather_table_budget,
+    tile_scan,
+    tile_scan_floor,
     use_pallas,
     vmem_gather,
     vmem_row_gather,
@@ -228,17 +243,58 @@ def _shift(x, d: int, fill):
     return lax.pad(x, jnp.asarray(fill, x.dtype), cfg)
 
 
-def _segmented_scan(values, ids, combine, identity):
+# which way each scan's first level went, counted where it is decided:
+# at trace time, once per call site per traced program
+SCAN_STATS = _FedStats("scan", {"kernel": 0, "xla": 0})
+
+
+def _tile_steps(v, i, combine, identity):
+    """The first level of `_segmented_scan` in dense XLA: the
+    `[tiles, SCAN_TILE]` views scanned side by side, one E-wide fusion
+    a distance."""
+    d = 1
+    while d < SCAN_TILE:
+        v = jnp.where(i == _shift(i, d, -1),
+                      combine(v, _shift(v, d, identity)), v)
+        d *= 2
+    return v
+
+
+def _first_level(combine, dtype, entries: int, ids_dtype):
+    """How one call scans the tiles of its stream (`entries` values of
+    `dtype` under `combine`, ids of `ids_dtype`):
+    `pallas_kernels.tile_scan`, one pass through VMEM, where the
+    values are the kernels' kind (`_kernel_values`), the ids int32,
+    the stream whole tiles, and the values, the ids and the scanned
+    values together more than the device's VMEM holds
+    (`tile_scan_floor`: under it XLA keeps them there between its
+    steps); `None`, XLA's seven steps, everywhere else.  Both move the
+    same bits.  Made once for each call, however often its fold is
+    traced (`_scan_fold`), because it carries the call's entry in
+    SCAN_STATS."""
+    if (_kernel_values(dtype) and jnp.dtype(ids_dtype) == jnp.int32
+            and entries % SCAN_TILE == 0
+            and 3 * 4 * entries > tile_scan_floor()):
+        SCAN_STATS["kernel"] += 1
+        return functools.partial(tile_scan, combine=combine)
+    SCAN_STATS["xla"] += 1
+    return None
+
+
+def _segmented_scan(values, ids, combine, identity, tiles=None):
     """Inclusive scan of the 1-D `values` that restarts where the
     sorted, non-negative `ids` change.
 
     Tiles of SCAN_TILE places are scanned side by side in
     log2(SCAN_TILE) dense steps: at distance d a place takes in the
     one d below it when both hold one id (the ids are sorted, so the
-    places between do too).  Each tile's last place is then a partial
-    of the row that leaves the tile; the scan of those partials, one
-    level up, is what every tile still lacks from the tiles before it,
-    and it is folded into the places of the tile's first row."""
+    places between do too).  `tiles` does those steps for the whole
+    stream where the caller chose a kernel for them (`_first_level`);
+    the levels above are small and keep XLA's.  Each tile's last place
+    is then a partial of the row that leaves the tile; the scan of
+    those partials, one level up, is what every tile still lacks from
+    the tiles before it, and it is folded into the places of the
+    tile's first row."""
     n = ids.shape[0]
     pad = -n % SCAN_TILE
     if pad:
@@ -248,11 +304,7 @@ def _segmented_scan(values, ids, combine, identity):
         ids = lax.pad(ids, ids[-1], [(0, pad, 0)])
     v = values.reshape(-1, SCAN_TILE)
     i = ids.reshape(-1, SCAN_TILE)
-    d = 1
-    while d < SCAN_TILE:
-        v = jnp.where(i == _shift(i, d, -1),
-                      combine(v, _shift(v, d, identity)), v)
-        d *= 2
+    v = _tile_steps(v, i, combine, identity) if tiles is None else tiles(v, i)
     if v.shape[0] > 1:
         tail_i = i[:, -1]
         above = _segmented_scan(v[:, -1], tail_i, combine, identity)
@@ -340,12 +392,15 @@ def _row_ends(scanned, row_ptr, num_rows: int, empty, gather):
 
 
 def _scan_rows(values, segment_ids, row_ptr, num_rows: int, kind: str,
-               ends):
-    """The scan fold of one lane: `_segmented_scan`, then each row's
-    fold from the row's last place, read by `ends(dtype)`."""
+               ends, tiles):
+    """The scan fold of one lane: `_segmented_scan`, its first level
+    by `tiles(combine, ...)`, then each row's fold from the row's last
+    place, read by `ends(dtype)`."""
     _, combine, ident = _FOLDS[kind]
     identity = ident(values.dtype)
-    scanned = _segmented_scan(values, segment_ids, combine, identity)
+    scanned = _segmented_scan(
+        values, segment_ids, combine, identity,
+        tiles(combine, values.dtype, values.shape[0], segment_ids.dtype))
     return _row_ends(scanned, row_ptr, num_rows, identity,
                      ends(scanned.dtype))
 
@@ -360,9 +415,10 @@ def _grouping_shows(kind: str, dtype) -> bool:
 def _scan_fold(num_rows: int, kind: str):
     """The fold of one `segment_reduce` call that came with offsets:
     the scan, with its own rule under `jax.vmap`.  Made anew for each
-    call, because it carries the call's entries in FOLD_STATS and in
-    ROW_END_STATS (the latter is the single query's choice, and stays
-    where the rule below sends the lanes to the scatter).
+    call, because it carries the call's entries in FOLD_STATS, in
+    SCAN_STATS and in ROW_END_STATS (the latter two are the single
+    query's choices, and stay where the rule below sends the lanes to
+    the scatter).
 
     Under `jax.vmap` (query lanes over one CSR) the rule asks the
     question the gather's rule answered, of what it can see itself:
@@ -383,13 +439,15 @@ def _scan_fold(num_rows: int, kind: str):
     no cell and no test holds such a graph."""
     took = ["scan"]
     FOLD_STATS["scan"] += 1
-    # the call's one gather of row ends, however often it is traced
+    # the call's one first level and one gather of row ends, however
+    # often it is traced
     ends = functools.cache(_row_end_gather)
+    tiles = functools.cache(_first_level)
 
     @custom_vmap
     def fold(values, segment_ids, row_ptr):
         return _scan_rows(values, segment_ids, row_ptr, num_rows, kind,
-                          ends)
+                          ends, tiles)
 
     @fold.def_vmap
     def lanes(axis_size, in_batched, values, segment_ids, row_ptr):
@@ -408,7 +466,7 @@ def _scan_fold(num_rows: int, kind: str):
             # bits (docs/SERVING.md)
             return jax.vmap(
                 lambda v: _scan_rows(v, segment_ids, row_ptr, num_rows,
-                                     kind, ends)
+                                     kind, ends, tiles)
             )(values), True
         # no kernel for these lanes' tables (other backends, 64-bit
         # values, tables over the budget): XLA gathers all lanes of an
@@ -493,7 +551,17 @@ def run_position(segment_ids, label):
     largest); the scan restarts with the sorted `segment_ids`, which
     changes nothing, since a row's first place opens a run, and lets
     `_segmented_scan` do it.  The position is monotone inside a run
-    and equals the run's length at its last place."""
+    and equals the run's length at its last place.
+
+    The scan keeps XLA's steps at every size.  `tile_scan` does the
+    first level of this one in 0.54 ms a pass for 2.37 at CDLP's scale
+    19 (`cdlp_count_ns_entry` 0.1855 -> 0.0840), and the scan of a
+    pair that follows (`segment_top_label`) then took 1.89 ms a pass
+    more (`cdlp_fold_ns_entry` 0.3105 -> 0.4231): `proc_time_s` +0.6%
+    in `g500-cdlp.cdlp-10r` (my chip run, PR 47; PERF.md section 6).
+    The two scans are one chain of fusions to XLA, and a kernel in
+    the middle of it is worth its pass only with the pair's scan a
+    kernel too (ROADMAP S2d)."""
     first = jnp.logical_or(segment_ids != _shift(segment_ids, 1, -1),
                            label != _shift(label, 1, 0))
     idx = jnp.arange(segment_ids.shape[0], dtype=jnp.int32)

@@ -91,11 +91,29 @@ def pull_kernel(monkeypatch):
     `pallas_kernels.vmem_row_gather` in interpret mode there, at
     small slices (10 s of XLA:CPU compile an instance); the default is
     its plain stand-in, so that the cases that were here before it pay
-    for one interpreted kernel, as they did."""
+    for one interpreted kernel, as they did.  So does the scan's first
+    level (`segment._first_level`): `scan="interpreted"` puts
+    `pallas_kernels.tile_scan` in interpret mode there, at blocks of
+    16 tiles; the default is XLA's seven steps.  The stream size from
+    which the kernel is chosen (`tile_scan_floor`) is brought down to
+    nothing, so that a test's few tiles take it."""
     from libgrape_lite_tpu.ops import pallas_kernels, segment
 
-    def arm(kind: str, rows: str = "stand_in") -> list:
+    def arm(kind: str, rows: str = "stand_in",
+            scan: str = "stand_in") -> list:
         calls = []
+
+        def scan_kernel(values, ids, combine):
+            assert values.ndim == 2 and values.dtype.itemsize == 4, values
+            assert ids.shape == values.shape and ids.dtype == np.int32, ids
+            assert values.shape[1] == segment.SCAN_TILE, values
+            if scan == "interpreted":
+                return pallas_kernels.tile_scan(
+                    values, ids, combine, interpret=True, block_rows=16,
+                    chunk=8)
+            assert scan == "stand_in", scan
+            # the fill a step shifts in never meets a place's own id
+            return segment._tile_steps(values, ids, combine, 0)
 
         def row_kernel(table, idx):
             assert table.ndim == 1 and table.dtype.itemsize == 4, table
@@ -123,6 +141,8 @@ def pull_kernel(monkeypatch):
                             lambda: GATHER_BUDGET)
         monkeypatch.setattr(segment, "vmem_gather", kernel)
         monkeypatch.setattr(segment, "vmem_row_gather", row_kernel)
+        monkeypatch.setattr(segment, "tile_scan", scan_kernel)
+        monkeypatch.setattr(segment, "tile_scan_floor", lambda: 0)
         return calls
 
     return arm

@@ -25,6 +25,16 @@ from tests.conftest import GATHER_BUDGET
 LANES, V, EP = 4, 262144, 1 << 20
 
 
+def _steer(monkeypatch, budget: int = 64 << 20):
+    """The choices as the TPU backend steers them on a v5e (the process
+    itself is a CPU's): the kernels' backend, half of the chip's 128
+    MiB of VMEM for a gathered table, all of it as the size from which
+    a scan's streams go through `tile_scan`."""
+    monkeypatch.setattr(segment, "use_pallas", lambda: True)
+    monkeypatch.setattr(segment, "gather_table_budget", lambda: budget)
+    monkeypatch.setattr(segment, "tile_scan_floor", lambda: 128 << 20)
+
+
 @pytest.fixture(scope="module")
 def topo():
     from jax.experimental import topologies
@@ -66,18 +76,16 @@ def _compiled(one_chip, dtype) -> str:
 @pytest.mark.parametrize("dtype", ["int32", "float32"])
 def test_armed_lanes_compile_to_kernel_and_scan(dtype, one_chip,
                                                 monkeypatch):
-    # as the TPU backend steers the choice (the process itself is a CPU's)
-    monkeypatch.setattr(segment, "use_pallas", lambda: True)
-    monkeypatch.setattr(segment, "gather_table_budget",
-                        lambda: GATHER_BUDGET)
+    _steer(monkeypatch, GATHER_BUDGET)
     text = _compiled(one_chip, jnp.dtype(dtype))
     assert "tpu_custom_call" in text and "vmem_gather" in text
     assert " scatter(" not in text
     assert "while(" in text  # the lanes are a loop, not four copies
     # one instance of each kernel: the pull's gather and, since PR 45,
-    # the fold's row ends; XLA's V-wide gather of them is gone
+    # the fold's row ends; XLA's V-wide gather of them is gone.  The
+    # lanes' streams fit the VMEM, so their scan keeps XLA's steps
     assert text.count("tpu_custom_call") == 2
-    assert "vmem_row_gather" in text
+    assert "vmem_row_gather" in text and "tile_scan" not in text
     assert not re.search(rf"= \w+\[{V}\]\S* fusion\(.*kind=kCustom", text)
 
 
@@ -145,12 +153,16 @@ def road20():
     return fragment(n, src, dst, w.astype(np.float32))
 
 
-# Generated code of PR 44's runners, compiled here as below (bytes;
-# `scratch/runner_code45.py` on the parent's export, PR 45): the row-end
-# kernel takes the place of one `kCustom` fusion and may add this much
-# and no more, because code is HBM and `hbm_peak_bytes` is bounded at 1%
-PARENT_CODE = {"road.bfs": 6_392_320, "road.sssp": 7_554_048,
-               "lanes.bfs": 3_321_344, "lanes.sssp": 3_538_944}
+# Generated code of PR 46's runners, compiled here as below (bytes;
+# `scratch/runner_code47.py` on the parent's export, PR 47): a later
+# change may add `CODE_ROOM` and no more, because code is HBM and
+# `hbm_peak_bytes` is bounded at 1%.  (PR 47's tile-scan kernel does not
+# enter these runners: their streams fit the VMEM, `tile_scan_floor`.
+# Where it does it takes the place of seven E-wide fusions, 0.9 MB of
+# code for 0.07.)
+PARENT_CODE = {"road.bfs": 6_772_224, "road.sssp": 7_943_168,
+               "lanes.bfs": 3_704_320, "lanes.sssp": 3_923_968}
+CODE_ROOM = 250_000
 
 
 def _within(compiled, parent: int, room: int):
@@ -196,8 +208,7 @@ def test_the_road_runner_keeps_its_values_in_vmem(name, values, one_chip,
     from libgrape_lite_tpu.models import APP_REGISTRY
     from libgrape_lite_tpu.worker.worker import Worker
 
-    monkeypatch.setattr(segment, "use_pallas", lambda: True)
-    monkeypatch.setattr(segment, "gather_table_budget", lambda: 64 << 20)
+    _steer(monkeypatch)
     with jax.enable_x64(False):
         w = Worker(APP_REGISTRY[name](), road20)
         state = w.app.init_state(road20, source=5)
@@ -205,16 +216,20 @@ def test_the_road_runner_keeps_its_values_in_vmem(name, values, one_chip,
         assert not w.app.ephemeral_keys
         dev, carried = _described(w, road20, state, w._key_specs, one_chip)
         ends = segment.ROW_END_STATS.snapshot()
+        scans = segment.SCAN_STATS.snapshot()
         compiled = w._make_runner(w.app.max_rounds)(state).lower(
             dev, carried, {}).compile()
     text = compiled.as_text()
     # the dense arm reads its row ends by the kernel (it never runs in
     # the road cells, but its code is HBM there: `hbm_peak_bytes` is
-    # bounded at 1% of 67.7 / 68.8 MB)
+    # bounded at 1% of 67.7 / 68.8 MB) and scans by XLA's steps: the
+    # road graph's 2.5M entries are 30 MB of streams
     assert segment.ROW_END_STATS.snapshot() == {
         **ends, "kernel": ends["kernel"] + 1}
-    assert "vmem_row_gather" in text
-    _within(compiled, PARENT_CODE["road." + name], 600_000)
+    assert segment.SCAN_STATS.snapshot() == {
+        **scans, "xla": scans["xla"] + 1}
+    assert "vmem_row_gather" in text and "tile_scan" not in text
+    _within(compiled, PARENT_CODE["road." + name], CODE_ROOM)
     loops = [line for line in text.splitlines()
              if re.search(r" while\(", line) and f"{values}[{ROAD_V}]" in line]
     assert loops and all(
@@ -250,8 +265,7 @@ def test_the_hook_round_fits_the_road_cells_room(one_chip, road20,
     class LabelWCC(WCC):
         hook_round = False
 
-    monkeypatch.setattr(segment, "use_pallas", lambda: True)
-    monkeypatch.setattr(segment, "gather_table_budget", lambda: 64 << 20)
+    _steer(monkeypatch)
 
     def runner(app):
         with jax.enable_x64(False):
@@ -307,8 +321,7 @@ def test_the_serving_sssp_lanes_read_the_fragments_own_weights(
     from libgrape_lite_tpu.ops.segment import FOLD_STATS, GATHER_STATS
     from libgrape_lite_tpu.worker.worker import Worker
 
-    monkeypatch.setattr(segment, "use_pallas", lambda: True)
-    monkeypatch.setattr(segment, "gather_table_budget", lambda: 64 << 20)
+    _steer(monkeypatch)
     assert kron18.vp == SERVE_V
     assert kron18.dev.ie.edge_nbr.shape == (1, SERVE_EP)
     with jax.enable_x64(False):
@@ -322,7 +335,7 @@ def test_the_serving_sssp_lanes_read_the_fragments_own_weights(
         compiled = w._make_batched_runner(
             w.app.max_rounds, LANES)(state).lower(dev, carried, {}).compile()
     text = compiled.as_text()
-    _within(compiled, PARENT_CODE["lanes.sssp"], 700_000)
+    _within(compiled, PARENT_CODE["lanes.sssp"], CODE_ROOM)
     took = {k: v - gathers[k] for k, v in GATHER_STATS.snapshot().items()}
     assert took == {"kernel": 1, "xla": 0}
     took = {k: v - folds[k] for k, v in FOLD_STATS.snapshot().items()}
@@ -337,14 +350,15 @@ def test_the_serving_sssp_lanes_read_the_fragments_own_weights(
 def test_the_serving_bfs_lanes_hold_their_code(one_chip, kron18,
                                                monkeypatch):
     """The serving cell's other batched runner: one instance of each
-    kernel in the lanes' loops, the row ends counted once as `kernel`,
-    and its code within the parent's + 0.7 MB (the cell has 1.56 MB of
-    room for its two runners; PERF.md section 6, PR 45)."""
+    gather kernel in the lanes' loops, the row ends counted once as
+    `kernel`, the scan's first level once as `xla` (a lane's 8.4M
+    entries are 101 MB of streams, under the VMEM line), and its code
+    within the parent's + 0.25 MB (the cell has 1.56 MB of room for its
+    two runners; PERF.md section 6, PR 45 and PR 47)."""
     from libgrape_lite_tpu.models import APP_REGISTRY
     from libgrape_lite_tpu.worker.worker import Worker
 
-    monkeypatch.setattr(segment, "use_pallas", lambda: True)
-    monkeypatch.setattr(segment, "gather_table_budget", lambda: 64 << 20)
+    _steer(monkeypatch)
     with jax.enable_x64(False):
         w = Worker(APP_REGISTRY["bfs"](), kron18)
         state = w.app.init_state(kron18, source=[5, 6, 7, 9])
@@ -352,16 +366,20 @@ def test_the_serving_bfs_lanes_hold_their_code(one_chip, kron18,
                                   one_chip)
         eph = frozenset(w.app.ephemeral_keys or ())
         ends = segment.ROW_END_STATS.snapshot()
+        scans = segment.SCAN_STATS.snapshot()
         compiled = w._make_batched_runner(
             w.app.max_rounds, LANES)(state).lower(
                 dev, {k: v for k, v in carried.items() if k not in eph},
                 {k: v for k, v in carried.items() if k in eph}).compile()
     assert segment.ROW_END_STATS.snapshot() == {
         **ends, "kernel": ends["kernel"] + 1}
+    assert segment.SCAN_STATS.snapshot() == {
+        **scans, "xla": scans["xla"] + 1}
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 2 and " scatter(" not in text
     assert "vmem_gather" in text and "vmem_row_gather" in text
-    _within(compiled, PARENT_CODE["lanes.bfs"], 700_000)
+    assert "tile_scan" not in text
+    _within(compiled, PARENT_CODE["lanes.bfs"], CODE_ROOM)
 
 
 # ---- the row-end kernel alone, at the cells' shapes ----
@@ -408,3 +426,69 @@ def test_the_row_end_kernel_compiles_at_the_cells_shapes(name, topo,
     assert "tpu_custom_call" in text and "vmem_row_gather" in text
     # the stream is read where it lies: no copy of it beside the kernel
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+# ---- the tile-scan kernel alone, at the cells' shapes ----
+
+TILE_SCAN_SHAPES = {
+    # name: (kind, dtype, entries, devices)
+    "g500_s21": ("sum", "float32", 1 << 26, 1),
+    "g500_s21_bfs": ("min", "int32", 1 << 26, 1),
+    "cdlp_s19_count": ("max", "int32", 1 << 24, 1),
+    "datagen_count": ("max", "int32", 18950272, 1),
+    "serving_lane_sssp": ("min", "float32", 1 << 23, 1),
+    "road": ("min", "int32", 2517504, 1),
+    # (the two above are under the VMEM line: the choice leaves them
+    # to XLA, and the kernel takes them where a caller hands them over)
+    # a shard of four, inside the `shard_map`: 134,319 tiles, a ragged
+    # last block
+    "g500_s21_x4": ("sum", "float32", 17192832, 4),
+}
+
+
+@pytest.mark.parametrize("name", sorted(TILE_SCAN_SHAPES))
+def test_the_tile_scan_kernel_compiles_at_the_cells_shapes(name, topo,
+                                                           one_chip):
+    """`tile_scan` through the chip's own compiler at the streams the
+    cells hold (blocks of 2,048 tiles, 256 of them at scale 21), on one
+    chip and per shard of a four-chip mesh: the streams are read where
+    they lie, and the kernel's code is a twentieth of the seven
+    fusions' it stands for."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from libgrape_lite_tpu.ops.pallas_kernels import tile_scan
+
+    kind, dtype, entries, devices = TILE_SCAN_SHAPES[name]
+
+    def scan(v, i):
+        # the 1-D streams in the views `_segmented_scan` hands over
+        return tile_scan(v.reshape(-1, segment.SCAN_TILE),
+                         i.reshape(-1, segment.SCAN_TILE),
+                         segment._FOLDS[kind][1]).reshape(-1)
+
+    if devices == 1:
+        fn = scan
+        args = tuple(
+            jax.ShapeDtypeStruct((entries,), dt, sharding=one_chip)
+            for dt in (dtype, "int32"))
+    else:
+        mesh = Mesh(np.array(topo.devices), ("f",))
+        fn = jax.shard_map(
+            lambda v, i: scan(v[0], i[0])[None], mesh=mesh,
+            in_specs=(P("f"), P("f")), out_specs=P("f"))
+        over = NamedSharding(mesh, P("f"))
+        args = tuple(
+            jax.ShapeDtypeStruct((devices, entries), dt, sharding=over)
+            for dt in (dtype, "int32"))
+    with jax.enable_x64(False):
+        compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "tile_scan" in text
+    if devices == 1:
+        # no copy of a stream beside the kernel.  (A shard's `[1, Ep]`
+        # blocks are squeezed by a copy here, as the ids' block is in
+        # every four-chip runner: `reduce.37`, ROADMAP S7.)
+        memory = compiled.memory_analysis()
+        assert memory.temp_size_in_bytes < 1 << 20
+        assert memory.generated_code_size_in_bytes < 100_000

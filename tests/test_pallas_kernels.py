@@ -130,3 +130,76 @@ def test_row_gather_bit_equal(case):
         jnp.asarray(table), jnp.asarray(idx), interpret=True, **sizes))
     assert got.dtype == table.dtype and got.shape == idx.shape
     assert got.tobytes() == table[idx].tobytes()
+
+
+# ---- the scan's first level: seven steps a tile, one pass ----
+
+T = 128
+# Blocks of 16 tiles and chunks of 8 where the shipped kernel has 2,048
+# and 32: the same grid and loop, at the interpreter's size.
+# name -> (row degrees, tiles, keywords); places behind the last row
+# are padding with id = the row count
+SCAN_SMALL = {"block_rows": 16, "chunk": 8}
+TILE_SCAN_SHAPES = {
+    "rows_inside_a_tile": ([3, 1, 0, 40, 7, 0, 0, 25, 30, 9, 2], 1,
+                           SCAN_SMALL),
+    "rows_span_tiles_and_blocks": (
+        [50, 3 * T + 40, 7, 20 * T + 3, 1, 0, 15 * T, 90], 48, SCAN_SMALL),
+    "a_row_fills_a_tile": ([T, T, 60, T - 60, T, 2 * T], 7, SCAN_SMALL),
+    "padding_ids_at_the_end": ([9, 0, 33, 70], 5, SCAN_SMALL),
+    "one_tile": ([40, 0, 50, 20], 1, SCAN_SMALL),
+    # 37 tiles in blocks of 16: the last block holds five
+    "ragged_last_block": ([11] * 300 + [9 * T + 5, 1, 1], 37, SCAN_SMALL),
+    "degree_one": ([1] * (3 * T), 3, SCAN_SMALL),
+    # the shipped sizes: two blocks of 2,048 tiles and a ragged third
+    "shipped_sizes": ([700] * 700 + [40 * T] + [3] * 1000, 4500, {}),
+}
+TILE_SCAN_FOLDS = [("sum", "float32"), ("min", "int32"), ("min", "float32"),
+                   ("max", "int32")]
+
+
+def _scan_stream(shape):
+    deg, tiles, _ = TILE_SCAN_SHAPES[shape]
+    ids = np.full(tiles * T, len(deg), np.int32)
+    filled = int(np.sum(deg))
+    assert filled <= ids.size
+    ids[:filled] = np.repeat(np.arange(len(deg), dtype=np.int32), deg)
+    return ids.reshape(-1, T)
+
+
+@pytest.mark.parametrize("shape,kind,dtype", [
+    (s, k, d) for s in sorted(TILE_SCAN_SHAPES) for k, d in TILE_SCAN_FOLDS
+    # the shipped sizes once a kind of value
+    if s != "shipped_sizes" or (k, d) in TILE_SCAN_FOLDS[:2]])
+def test_tile_scan_bit_equal(shape, kind, dtype):
+    """`tile_scan` is the first level of `_segmented_scan` as XLA's
+    seven steps write it, bit for bit: the same distances in the same
+    order with the same operands, so a float sum keeps its grouping."""
+    import jax.numpy as jnp
+
+    from libgrape_lite_tpu.ops import segment
+    from libgrape_lite_tpu.ops.pallas_kernels import tile_scan
+
+    ids = _scan_stream(shape)
+    rng = np.random.default_rng(ids.size + len(shape))
+    if dtype == "float32":
+        # magnitudes far apart: a regrouped sum would show
+        vals = (rng.standard_normal(ids.shape)
+                * 10.0 ** rng.integers(-3, 6, ids.shape)).astype(dtype)
+    else:
+        vals = rng.integers(-2**31, 2**31 - 1, ids.shape).astype(dtype)
+    _, combine, ident = segment._FOLDS[kind]
+    want = np.asarray(segment._tile_steps(
+        jnp.asarray(vals), jnp.asarray(ids), combine,
+        ident(jnp.dtype(dtype))))
+    got = np.asarray(tile_scan(jnp.asarray(vals), jnp.asarray(ids), combine,
+                               interpret=True, **TILE_SCAN_SHAPES[shape][2]))
+    assert got.dtype == vals.dtype and got.shape == vals.shape
+    assert got.tobytes() == want.tobytes()
+    if shape == "rows_span_tiles_and_blocks":
+        # the steps did something: a tile of one row holds its scan
+        row = vals[30]
+        assert (ids[30] == ids[30, 0]).all()
+        if kind != "sum":
+            np.testing.assert_array_equal(
+                got[30], getattr(np, kind + "imum").accumulate(row))

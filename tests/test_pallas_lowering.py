@@ -158,3 +158,55 @@ def test_vmem_row_gather_lowers_for_tpu():
     for name in ("S21", "X4", "S18"):
         for dt in ("float32", "int32"):
             assert f"VMEM_ROW_GATHER_LOWERED_{name}_{dt}" in r.stdout
+
+
+# the scan's first level at the cells' five shapes (Graph500 scale 21,
+# its four-chip shard with a ragged last block, the two CDLP cells, a
+# serving lane, the road graph), and inside a shard_map that checks
+# varying axes
+SCRIPT5 = r"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh, PartitionSpec as P
+
+import sys
+sys.path.insert(0, %(repo)r)
+
+from libgrape_lite_tpu.ops.pallas_kernels import tile_scan
+
+for name, e in (("S21", 67108864), ("X4", 17192832), ("CDLP19", 16777216),
+                ("DATAGEN", 18950272), ("S18", 8388608), ("ROAD", 2517504)):
+    for kind, combine, dt in (("sum", jnp.add, jnp.float32),
+                              ("min", jnp.minimum, jnp.int32),
+                              ("min", jnp.minimum, jnp.float32),
+                              ("max", jnp.maximum, jnp.int32)):
+        low = jax.jit(lambda v, i: tile_scan(v, i, combine)).trace(
+            jax.ShapeDtypeStruct((e // 128, 128), dt),
+            jax.ShapeDtypeStruct((e // 128, 128), jnp.int32),
+        ).lower(lowering_platforms=('tpu',))
+        assert "tpu_custom_call" in low.as_text()
+        print(f"TILE_SCAN_LOWERED_{name}_{kind}_{jnp.dtype(dt).name}")
+
+mesh = Mesh(np.array(jax.devices()[:4]), ("f",))
+low = jax.jit(jax.shard_map(
+    lambda v, i: tile_scan(v[0], i[0], jnp.add)[None], mesh=mesh,
+    in_specs=(P("f"), P("f")), out_specs=P("f"),
+)).trace(
+    jax.ShapeDtypeStruct((4, 134319, 128), jnp.float32),
+    jax.ShapeDtypeStruct((4, 134319, 128), jnp.int32),
+).lower(lowering_platforms=('tpu',))
+assert "tpu_custom_call" in low.as_text()
+print("TILE_SCAN_LOWERED_SHARD_MAP")
+"""
+
+
+def test_tile_scan_lowers_for_tpu():
+    r = _run_offline(
+        SCRIPT5 % {"repo": REPO},
+        XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-4000:]
+    assert "TILE_SCAN_LOWERED_SHARD_MAP" in r.stdout
+    for name in ("S21", "X4", "CDLP19", "DATAGEN", "S18", "ROAD"):
+        for fold in ("sum_float32", "min_int32", "min_float32", "max_int32"):
+            assert f"TILE_SCAN_LOWERED_{name}_{fold}" in r.stdout

@@ -15,6 +15,11 @@ with unsorted ids is refused; that a call without
 `row_ptr` lowers to the text it lowered to before; and, through the
 trace-time counter `FOLD_STATS`, which fold each app's round takes,
 PageRank's being this one and no other whatever the backend says.
+Since PR 47 also the scan's first level: by the tile-scan kernel where
+the choice is steered as the TPU backend steers it, with the scatter's
+answer and the XLA steps' bytes, counted once a call site in
+`SCAN_STATS`; by XLA's seven steps, in the text they lowered to before,
+everywhere else.
 """
 
 import jax
@@ -27,6 +32,7 @@ from libgrape_lite_tpu.models import APP_REGISTRY
 from libgrape_lite_tpu.ops.segment import (
     FOLD_STATS,
     ROW_END_STATS,
+    SCAN_STATS,
     SCAN_TILE,
     segment_reduce,
     segment_top_label,
@@ -538,6 +544,251 @@ def test_lanes_count_their_row_ends_once(name, kind, dtype, budget, fold,
         lambda: np.asarray(jax.jit(jax.vmap(one))(vals)))
     assert FOLD_STATS.snapshot() == {**folds, fold: folds[fold] + 1}
     assert took == {"kernel": int(ends == "kernel"), "xla": 0}
+    single = jax.jit(one)
+    assert got.tobytes() == np.stack(
+        [np.asarray(single(v)) for v in vals]).tobytes()
+
+
+# ---- the scan's first level: by the kernel where the values are its kind --
+
+
+def _scans_took(fn):
+    before = SCAN_STATS.snapshot()
+    out = fn()
+    return out, {k: v - before[k] for k, v in SCAN_STATS.snapshot().items()}
+
+
+FIRST_LEVEL_FOLDS = {
+    "sum_f32": ("sum", "float32"), "min_s32": ("min", "int32"),
+    "min_f32": ("min", "float32"), "max_s32": ("max", "int32"),
+}
+
+
+@pytest.mark.parametrize("fold,how", [
+    (f, h) for f in sorted(FIRST_LEVEL_FOLDS)
+    for h in ("single", "vmap4", "shard_map2")])
+def test_first_level_by_the_kernel(fold, how, pull_kernel):
+    """With the choice steered as the TPU backend steers it and the
+    tile-scan kernel interpreted behind it, the scan fold equals the
+    scatter (exact folds) and, byte for byte, the scan whose first
+    level is XLA's seven steps (a float sum keeps its grouping): a
+    single call, query lanes under `jax.vmap` and the shards of a
+    two-fragment `shard_map`; each call site counts once, as
+    `kernel`, beside its row ends.  (Under a `shard_map` that checks
+    varying axes the interpreter cannot evaluate a kernel's loop over
+    its refs, so the shards run the stand-in and the kernel itself is
+    lowered for the TPU there, where the check is the same one.)"""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from libgrape_lite_tpu.ops import pallas_kernels, segment
+
+    kind, dtype = FIRST_LEVEL_FOLDS[fold]
+    rows, ptr, ids = _skewed_csr()
+    ep = ids.shape[0]
+    lead = {"single": (), "vmap4": (4,), "shard_map2": (2,)}[how]
+    vals = _values(kind, dtype, lead + (ep,), seed=11)
+    jids, jptr = jnp.asarray(ids), jnp.asarray(ptr)
+
+    def one(v):
+        return segment_reduce(v, jids, rows, kind, row_ptr=jptr)
+
+    def scatter(v):
+        return segment_reduce(v, jids, rows, kind)
+
+    def over(f):
+        if how == "vmap4":
+            return jax.vmap(f)
+        if how == "shard_map2":
+            mesh = Mesh(np.array(jax.devices()[:2]), ("f",))
+            return jax.shard_map(lambda v: f(v[0])[None], mesh=mesh,
+                                 in_specs=(P("f"),), out_specs=P("f"))
+        # a function of its own: `jax.jit` remembers one it has traced
+        return lambda v: f(v)
+
+    # the parent's scan: nothing armed, XLA's steps and XLA's row ends
+    parent, took = _scans_took(lambda: np.asarray(jax.jit(over(one))(vals)))
+    float_sum = kind == "sum"
+    # off the TPU an exact fold's lanes go back to the scatter; the
+    # entry stays, as the single query's choice
+    assert took == {"kernel": 0, "xla": 1}
+    sharded = how == "shard_map2"
+    pull_kernel("stand_in", scan="stand_in" if sharded else "interpreted")
+    ends = ROW_END_STATS.snapshot()
+    got, took = _scans_took(lambda: np.asarray(jax.jit(over(one))(vals)))
+    assert took == {"kernel": 1, "xla": 0}
+    assert ROW_END_STATS.snapshot() == {**ends, "kernel": ends["kernel"] + 1}
+    if sharded:
+        # x32, as a chip run is: Mosaic's lowering refuses this lane's x64
+        with jax.enable_x64(False):
+            text = jax.jit(over(lambda v: pallas_kernels.tile_scan(
+                v.reshape(-1, T), jids.reshape(-1, T),
+                segment._FOLDS[kind][1]).reshape(-1))
+            ).trace(vals).lower(lowering_platforms=("tpu",)).as_text()
+        assert text.count("tpu_custom_call") == 1 and "tile_scan" in text
+    assert got.dtype == parent.dtype and got.shape == lead + (rows,)
+    assert got.tobytes() == parent.tobytes()
+    if not float_sum:
+        assert got.tobytes() == np.asarray(
+            jax.jit(over(scatter))(vals)).tobytes()
+    if lead:
+        single = jax.jit(one)
+        assert got.tobytes() == np.stack(
+            [np.asarray(single(v)) for v in vals]).tobytes()
+
+
+def test_run_position_keeps_xlas_steps(pull_kernel):
+    """CDLP's count scans by XLA's steps even where the choice is
+    steered as the TPU backend steers it (the scan of a pair behind it
+    paid for the kernel with more than it gave: `run_position`): no
+    first level is chosen or counted, and the positions are what the
+    definition says."""
+    from libgrape_lite_tpu.ops.segment import run_position
+
+    rows, _, ids = _skewed_csr()
+    label = np.random.default_rng(5).integers(0, 4, ids.shape[0]).astype(
+        np.int32)
+    # a run is a (row, label) pair in order
+    order = np.lexsort((label, ids))
+    label = label[order]
+    args = jnp.asarray(ids), jnp.asarray(label)
+    text = jax.jit(run_position).lower(*args).as_text()
+    pull_kernel("stand_in", scan="interpreted")
+    armed = jax.jit(lambda s, v: run_position(s, v))
+    got, took = _scans_took(lambda: np.asarray(armed(*args)))
+    assert took == {"kernel": 0, "xla": 0}
+    lowered = armed.lower(*args).as_text()
+    assert lowered.replace("jit__lambda", "jit_run_position") == text
+    opens = np.r_[True, (ids[1:] != ids[:-1]) | (label[1:] != label[:-1])]
+    at = np.arange(ids.shape[0])
+    np.testing.assert_array_equal(
+        got, at - np.maximum.accumulate(np.where(opens, at, 0)) + 1)
+
+
+@pytest.mark.parametrize("name,dtype,ids_dtype,entries,took", [
+    ("f32", "float32", "int32", 2 * T, "kernel"),
+    ("s32_one_tile", "int32", "int32", T, "kernel"),
+    ("f64", "float64", "int32", 2 * T, "xla"),
+    ("s64_ids", "float32", "int64", 2 * T, "xla"),
+    # the levels above the first: whatever the tiles' tails come to
+    ("not_whole_tiles", "float32", "int32", 2 * T + 5, "xla"),
+    ("nothing", "float32", "int32", 0, "xla"),
+    # three streams of 4,096 bytes: over a VMEM of 12,287, not of 12,288
+    ("over_the_vmem_line", "float32", "int32", 8 * T, "kernel"),
+    ("on_the_vmem_line", "int32", "int32", 8 * T, "xla"),
+])
+def test_first_level_reads_what_the_call_can_see(name, dtype, ids_dtype,
+                                                 entries, took, pull_kernel,
+                                                 monkeypatch):
+    """On the TPU backend (steered) the kernel takes 32-bit values with
+    int32 ids in whole tiles whose three streams do not fit the
+    device's VMEM together, and nothing else; off it, nothing."""
+    from libgrape_lite_tpu.ops import segment
+
+    def choose():
+        return segment._first_level(jnp.minimum, jnp.dtype(dtype), entries,
+                                    jnp.dtype(ids_dtype))
+
+    chosen, counted = _scans_took(choose)
+    assert chosen is None and counted == {"kernel": 0, "xla": 1}
+    pull_kernel("stand_in")
+    if "vmem_line" in name:
+        monkeypatch.setattr(
+            segment, "tile_scan_floor",
+            lambda: 3 * 4 * entries - (name == "over_the_vmem_line"))
+    chosen, counted = _scans_took(choose)
+    assert (chosen is not None) == (took == "kernel")
+    assert counted == {"kernel": 0, "xla": 0, took: 1}
+
+
+@pytest.mark.parametrize("name,dtype,armed", [
+    ("f64_on_tpu", "float64", True),
+    ("s64_on_tpu", "int64", True),
+    ("f32_off_tpu", "float32", False),
+    ("s32_off_tpu", "int32", False),
+])
+def test_first_level_by_xla_lowers_as_before(name, dtype, armed, pull_kernel):
+    """64-bit values and other backends keep XLA's seven steps: counted
+    as `xla`, and lowered to the text they lowered to before there was
+    a choice (the old function, spelled out here)."""
+    from libgrape_lite_tpu.ops import segment
+    from libgrape_lite_tpu.ops.segment import _shift
+
+    if armed:
+        pull_kernel("stand_in")
+    rows, entries = 37, 2 * T
+
+    def old_scan(values, ids, combine, identity):
+        n = ids.shape[0]
+        pad = -n % T
+        if pad:
+            values = jax.lax.pad(values, jnp.asarray(identity, values.dtype),
+                                 [(0, pad, 0)])
+            ids = jax.lax.pad(ids, ids[-1], [(0, pad, 0)])
+        v = values.reshape(-1, T)
+        i = ids.reshape(-1, T)
+        d = 1
+        while d < T:
+            v = jnp.where(i == _shift(i, d, -1),
+                          combine(v, _shift(v, d, identity)), v)
+            d *= 2
+        if v.shape[0] > 1:
+            tail_i = i[:, -1]
+            above = old_scan(v[:, -1], tail_i, combine, identity)
+            carry_i = _shift(tail_i, 1, -1)[:, None]
+            carry = _shift(above, 1, identity)[:, None]
+            v = jnp.where(i == carry_i, combine(carry, v), v)
+        return v.reshape(-1)[:n]
+
+    def before(values, ids, ptr):
+        with jax.named_scope("grape.pull.fold"):
+            identity = segment._FOLDS["min"][2](values.dtype)
+            scanned = old_scan(values, ids, jnp.minimum, identity)
+            return segment._row_ends(
+                scanned, ptr, rows, identity,
+                lambda s, at: s.at[at].get(mode="promise_in_bounds",
+                                           indices_are_sorted=True))
+
+    def fold(values, ids, ptr):
+        return segment_reduce(values, ids, rows, "min", row_ptr=ptr)
+
+    before.__name__ = before.__qualname__ = "fold"
+    args = (jax.ShapeDtypeStruct((entries,), jnp.dtype(dtype)),
+            jax.ShapeDtypeStruct((entries,), jnp.int32),
+            jax.ShapeDtypeStruct((rows + 1,), jnp.int32))
+    text, took = _scans_took(lambda: jax.jit(fold).lower(*args).as_text())
+    assert took == {"kernel": 0, "xla": 1}
+    assert text == jax.jit(before).lower(*args).as_text()
+
+
+@pytest.mark.parametrize("name,kind,dtype,budget,fold", [
+    # the kernel's lanes: one scan, one first level, however often the
+    # `vmap` rules trace the fold
+    ("exact_lanes", "min", "int32", 1 << 20, "scan"),
+    # a table over the gather kernel's budget: a float sum's lanes scan
+    # behind XLA's gather, the kernel batched over them
+    ("float_sum_over_budget", "sum", "float32", 0, "scan"),
+    # and an exact fold's lanes go back to the scatter, which scans
+    # nothing: the entry stays, as the choice the call made before its
+    # `vmap` rule ran (the single query it was traced as)
+    ("exact_lanes_over_budget", "min", "int32", 0, "scatter"),
+])
+def test_lanes_count_their_first_level_once(name, kind, dtype, budget, fold,
+                                            pull_kernel, monkeypatch):
+    from libgrape_lite_tpu.ops import segment
+
+    pull_kernel("stand_in", scan="interpreted")
+    monkeypatch.setattr(segment, "gather_table_budget", lambda: budget)
+    rows, ptr, ids = _csr("hub_three_tiles")
+    vals = _values(kind, dtype, (4, ids.shape[0]), seed=9)
+
+    def one(v):
+        return segment_reduce(v, jnp.asarray(ids), rows, kind,
+                              row_ptr=jnp.asarray(ptr))
+
+    folds = FOLD_STATS.snapshot()
+    got, took = _scans_took(lambda: np.asarray(jax.jit(jax.vmap(one))(vals)))
+    assert FOLD_STATS.snapshot() == {**folds, fold: folds[fold] + 1}
+    assert took == {"kernel": 1, "xla": 0}
     single = jax.jit(one)
     assert got.tobytes() == np.stack(
         [np.asarray(single(v)) for v in vals]).tobytes()
