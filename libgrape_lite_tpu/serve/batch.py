@@ -122,7 +122,8 @@ def run_guarded_batch(worker, args_list, mr: int, guard_cfg, *,
                 lane_slices(carry_of(state), b) for b in range(batch)
             ]
             with tr.span("peval", batch=batch) as sp:
-                out = peval_fn(frag.dev, state)
+                out = worker._enqueue(
+                    peval_fn, "guarded-batched", batch, frag.dev, state)
                 sp.mark("dispatched")
                 carry, active = jax.block_until_ready(out)
             active = np.asarray(active).copy()

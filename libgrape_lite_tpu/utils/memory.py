@@ -8,10 +8,12 @@ from /proc.
 
 from __future__ import annotations
 
+import math
 import os
 from dataclasses import dataclass
 
 import jax
+import numpy as np
 
 
 @dataclass
@@ -65,3 +67,38 @@ def fullest_bytes_in_use() -> int | None:
     if not in_use or any(b is None for b in in_use):
         return None
     return max(in_use)
+
+
+#: a compiled executable's memory analysis under the set-up ledger's
+#: names (phase `runner.compile`): name -> `CompiledMemoryStats` field
+EXECUTABLE_BYTES = {
+    "code_bytes": "generated_code_size_in_bytes",
+    "temp_bytes": "temp_size_in_bytes",
+    "argument_bytes": "argument_size_in_bytes",
+    "output_bytes": "output_size_in_bytes",
+    "alias_bytes": "alias_size_in_bytes",
+}
+
+
+def executable_bytes(compiled) -> dict:
+    """What one device holds for `compiled` (a `jax.stages.Compiled`):
+    the program's code, its temporaries, the arguments it reads, its
+    outputs and the part of them that lies in donated arguments, by
+    `memory_analysis()`.  Empty where the backend or the executable
+    gives no analysis: an unknown size is left out, never 0."""
+    analysis = compiled.memory_analysis()
+    if analysis is None:
+        return {}
+    return {
+        name: int(getattr(analysis, field))
+        for name, field in EXECUTABLE_BYTES.items()
+    }
+
+
+def shard_bytes(tree) -> int:
+    """Bytes one device holds of the placed arrays of `tree`, from
+    shapes: a sharded leaf's shard, a replicated leaf whole."""
+    return sum(
+        math.prod(x.sharding.shard_shape(x.shape)) * np.dtype(x.dtype).itemsize
+        for x in jax.tree_util.tree_leaves(tree)
+    )

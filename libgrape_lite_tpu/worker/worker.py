@@ -819,15 +819,29 @@ class Worker:
         lowers and compiles (or fetches) before it enqueues: that one
         is set-up phase `runner.compile`, with what JAX's monitoring
         events give for it as args (the listener lives for this call
-        only); a hit opens nothing."""
+        only); a hit opens nothing.
+
+        The miss compiles ahead of its call, so that the phase can say
+        what the executable holds on one device (`executable_bytes`:
+        code, temporaries, arguments, outputs, aliased; left out where
+        no analysis is given) and what the state operands handed to it
+        do (`state_bytes`: every operand after the fragment).  The call
+        that follows finds that executable and compiles nothing
+        (tests/test_setup_ledger.py pins one backend compile a miss)."""
         if not self._last_runner_miss:
             return runner(*operands)
         from libgrape_lite_tpu.analysis.artifact import compile_events
+        from libgrape_lite_tpu.utils.memory import (
+            executable_bytes, shard_bytes,
+        )
 
         with obs.tracer().span(
             "runner.compile", app=type(self.app).__name__, mode=mode,
             batch=batch,
         ) as sp, compile_events() as ev:
+            compiled = runner.lower(*operands).compile()
+            sp.set(**executable_bytes(compiled),
+                   state_bytes=shard_bytes(operands[1:]))
             out = runner(*operands)
             sp.set(**ev.phase_seconds())
         return out
@@ -1666,7 +1680,8 @@ class Worker:
                 peval_fn = self._single_step_for("peval", state)
                 prev = carry_of(state)
                 with tr.span("peval") as sp:
-                    out = peval_fn(frag.dev, state)
+                    out = self._enqueue(
+                        peval_fn, "guarded-fused", 1, frag.dev, state)
                     sp.mark("dispatched")
                     carry, active = jax.block_until_ready(out)
                     sp.set(active=int(active))
@@ -2214,7 +2229,8 @@ class Worker:
             # time is wall including device execution — not the async
             # dispatch-only time a naive t1-t0 around the call measures
             with tr.span("peval", round=0) as sp:
-                out = peval_fn(frag.dev, state)
+                out = self._enqueue(
+                    peval_fn, "stepwise", 1, frag.dev, state)
                 if getattr(self, "_last_runner_miss", False):
                     sp.mark("compiled")
                 sp.mark("dispatched")

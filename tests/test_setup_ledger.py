@@ -9,6 +9,7 @@ miss of a per-fragment structure, on a runner miss, in the native
 loader's build and where the compile cache is placed.
 """
 
+import contextlib
 import json
 import os
 
@@ -161,19 +162,31 @@ def _query_twice(kind):
             assert all(r.ok for r in out)
 
         return lambda: pump([0, 5]), lambda: pump([17, 33])
+    if kind == "guarded_batch":
+        w = Worker(APP_REGISTRY["sssp"](), rand_frag(1))
+        return tuple(
+            (lambda s=s: w.query_batch(
+                [{"source": s}, {"source": s + 1}], guard="halt"))
+            for s in (0, 5))
     app, frag, kw = {
         "query": ("sssp", rand_frag(2), {"source": 0}),
         "lcc": ("lcc", rand_frag(2, weighted=False), {}),
+        "guarded": ("sssp", rand_frag(2), {"source": 0, "guard": "halt"}),
+        "stepwise": ("sssp", rand_frag(2), {"source": 0}),
     }[kind]
     w = Worker(APP_REGISTRY[app](), frag)
-    return (lambda: w.query(**kw)), (lambda: w.query(**kw))
+    ask = w.query_stepwise if kind == "stepwise" else w.query
+    return (lambda: ask(**kw)), (lambda: ask(**kw))
 
 
-@pytest.mark.parametrize("kind", ["query", "serve_pump", "lcc"])
+@pytest.mark.parametrize("kind", ["query", "serve_pump", "lcc", "guarded",
+                                  "stepwise", "guarded_batch"])
 def test_a_warm_query_appends_nothing(kind):
     """The first query pays the runner (and LCC its adjacency); a second
     `Worker.query`, a second `ServeSession` pump and a second LCC query
-    open no phase: the ledger's length stands."""
+    open no phase: the ledger's length stands.  The same of the guarded,
+    the stepwise and the guarded batched query, whose PEval's first call
+    goes through `Worker._enqueue` too."""
     first, second = _query_twice(kind)
     SETUP_LEDGER.reset()  # the fragment's own build is not under test
     first()
@@ -225,6 +238,152 @@ def test_runner_miss_records_one_compile_and_a_hit_none(mode):
     assert w.runner_cache_stats["hits"] == 1
     assert names().count("runner.compile") == 1
     assert len(monitoring.get_event_duration_listeners()) == listeners
+
+
+# ---- what a runner holds on the chip ----------------------------------------
+
+STAMPED = ("code_bytes", "temp_bytes", "argument_bytes", "output_bytes",
+           "alias_bytes", "state_bytes")
+
+
+def _ask(w, mode, s=0):
+    """One query of `mode` from source `s`: the five dispatches whose
+    first call goes through `Worker._enqueue`."""
+    pair = [{"source": s}, {"source": s + 1}]
+    if mode == "fused":
+        w.query(source=s)
+    elif mode == "batched":
+        w.query_batch(pair)
+    elif mode == "guarded-fused":
+        w.query(source=s, guard="halt")
+    elif mode == "stepwise":
+        w.query_stepwise(source=s)
+    else:
+        w.query_batch(pair, guard="halt")
+
+
+def _bfs_worker(fnum=2):
+    from libgrape_lite_tpu.models import APP_REGISTRY
+    from libgrape_lite_tpu.worker.worker import Worker
+
+    return Worker(APP_REGISTRY["bfs"](), rand_frag(fnum))
+
+
+def _compile_records():
+    return [r for r in records() if r["name"] == "runner.compile"]
+
+
+@pytest.mark.parametrize("mode", ["fused", "batched"])
+def test_a_miss_stamps_what_the_executable_holds(mode):
+    """The six fields of a `runner.compile` record are the compiled
+    runner's own `memory_analysis()` and the state operands' shards,
+    all per device; the record stays JSON and the federation clean."""
+    from libgrape_lite_tpu.utils.memory import EXECUTABLE_BYTES
+
+    w = _bfs_worker()
+    frag, app = w.fragment, w.app
+    SETUP_LEDGER.reset()
+    _ask(w, mode)
+    (rec,) = _compile_records()
+    args = rec["args"]
+    assert set(STAMPED) <= set(args)
+    # the same runner, lowered again for a state of the same structure:
+    # the executable the dispatch ran
+    if mode == "fused":
+        state = w._place_state(w._seeded(app.init_state(frag, source=0)))
+        runner = w._runner_for(app.max_rounds, state)
+    else:
+        state = w._place_state_batch(
+            app.init_state_batch(frag, [{"source": 0}, {"source": 1}]))
+        runner = w._batched_runner_for(app.max_rounds, 2, state)
+    assert not w._last_runner_miss
+    eph = frozenset(app.ephemeral_keys or ())
+    carry = {k: v for k, v in state.items() if k not in eph}
+    eph_part = {k: v for k, v in state.items() if k in eph}
+    analysis = runner.lower(frag.dev, carry, eph_part).compile() \
+        .memory_analysis()
+    for name, field in EXECUTABLE_BYTES.items():
+        assert args[name] == getattr(analysis, field), name
+    assert args["temp_bytes"] > 0 and args["argument_bytes"] > 0
+    # a donated carry comes back in place
+    assert 0 < args["alias_bytes"] <= args["output_bytes"]
+    # one device's shard of every state leaf
+    shards = sum(x.addressable_shards[0].data.nbytes for x in state.values())
+    assert args["state_bytes"] == shards > 0
+    assert args["state_bytes"] <= args["argument_bytes"]
+    json.dumps(federation.snapshot("setup"))
+    assert federation.self_check() == []
+
+
+@pytest.mark.parametrize("mode", ["fused", "batched", "guarded-fused",
+                                  "stepwise", "guarded-batched"])
+def test_a_miss_compiles_once_as_the_parents_did(mode, monkeypatch):
+    """Compiling ahead of the call adds no compile: the phase holds as
+    many lowerings, backend compiles and cache events as the parent's
+    `_enqueue` (a plain first call under the listener) for the same
+    query."""
+    from libgrape_lite_tpu.analysis import artifact
+    from libgrape_lite_tpu.worker.worker import Worker
+
+    def parents(self, runner, mode, batch, *operands):
+        if not self._last_runner_miss:
+            return runner(*operands)
+        with obs.tracer().span(
+            "runner.compile", app=type(self.app).__name__, mode=mode,
+            batch=batch,
+        ) as sp, artifact.compile_events() as ev:
+            out = runner(*operands)
+            sp.set(**ev.phase_seconds())
+        return out
+
+    seen = []  # every listener a phase opened, in order
+    listen = artifact.compile_events
+
+    @contextlib.contextmanager
+    def keeping():
+        with listen() as ev:
+            seen.append(ev)
+            yield ev
+
+    monkeypatch.setattr(artifact, "compile_events", keeping)
+
+    def counts(enqueue):
+        if enqueue is not None:
+            monkeypatch.setattr(Worker, "_enqueue", enqueue)
+        del seen[:]
+        SETUP_LEDGER.reset()
+        _ask(_bfs_worker(), mode)
+        (rec,) = [r for r in _compile_records() if r["args"]["mode"] == mode]
+        (ev,) = seen
+        names = [name.rsplit("/", 1)[-1] for name, _ in ev.events]
+        return rec["args"], {
+            k: names.count(k) for k in (
+                "jaxpr_to_mlir_module_duration", "backend_compile_duration",
+                "cache_hits", "cache_misses")}
+
+    _ask(_bfs_worker(), mode)  # the helpers' own jits, once a process
+    args, change = counts(None)
+    parent_args, parent = counts(parents)
+    assert change == parent
+    assert change["backend_compile_duration"] == 1
+    assert change["jaxpr_to_mlir_module_duration"] == 1
+    assert set(STAMPED) <= set(args) and not set(STAMPED) & set(parent_args)
+    assert set(args) - set(STAMPED) == set(parent_args)
+
+
+def test_an_executable_without_an_analysis_leaves_the_fields_out(
+        monkeypatch):
+    """`memory_analysis()` is None on a backend that gives none: the
+    five fields it would fill are absent, never 0; the state's bytes
+    come from shapes and stay."""
+    import jax
+
+    monkeypatch.setattr(jax.stages.Compiled, "memory_analysis",
+                        lambda self: None)
+    _ask(_bfs_worker(1), "fused")
+    (rec,) = _compile_records()
+    assert not set(STAMPED[:5]) & set(rec["args"])
+    assert rec["args"]["state_bytes"] > 0 and rec["args"]["backend_s"] > 0
 
 
 def test_nested_trace_durations_are_merged_not_summed():
