@@ -40,6 +40,7 @@ import numpy as np
 from jax import lax
 
 from libgrape_lite_tpu.fragment.edgecut import DeviceFragment
+from libgrape_lite_tpu.ops.segment import table_gather
 from libgrape_lite_tpu.parallel.comm_spec import FRAG_AXIS
 from libgrape_lite_tpu.parallel.communicator import (
     Communicator,
@@ -75,9 +76,17 @@ class StepContext(Communicator):
         [fnum, m] send table (rows ordered by receiver, from
         `parallel/mirror.MirrorPlan`).  Returns the compact
         [vp + fnum*m] table addressed by the plan's `nbr_compact`
-        columns — O(vp + mirrors) instead of O(fnum*vp)."""
+        columns — O(vp + mirrors) instead of O(fnum*vp).
+
+        The send buffer is packed by `ops/segment.table_gather`: the
+        VMEM gather kernel where the call shows it can read the shard's
+        state (the TPU backend, a 1-D 32-bit state within the kernel's
+        budget), `x_local[send_idx]` by XLA's gather everywhere else;
+        both move the same bits.  The table's `m` is a whole number of
+        128s, so its `[fnum * m]` stream is the kernel's `[rows, 128]`
+        view as it lies."""
         with jax.named_scope("grape.exchange.pack"):
-            vals = x_local[send_idx]
+            vals = table_gather(x_local, send_idx)
         with collective_scope():
             recv = lax.all_to_all(
                 vals, FRAG_AXIS, split_axis=0, concat_axis=0, tiled=True

@@ -60,6 +60,9 @@ VMEM is gathered by `ops/pallas_kernels.vmem_gather`, which keeps the
 table in VMEM for the length of the call and streams the indices
 through it (0.80 ns an index).  Everything else keeps `full[nbr]`:
 other backends, 64-bit tables, tables of rows, tables over the budget.
+`table_gather` is that choice for a caller outside a pull: WCC's
+pointer jumps, and the mirror exchange's pack of its send buffer, whose
+`[fnum, m]` index block the kernel reads as one stream.
 The query lanes of a batched call under `jax.vmap` take what a lane's
 single call takes: the kernel, once a lane, where the lanes share
 their indices (every caller's do), so that a lane runs its single
@@ -135,10 +138,10 @@ def _kernel_table(dtype, rows: int) -> bool:
 
 def _kernel_gathers(full, nbr) -> bool:
     """Whether `full[nbr]` goes through `pallas_kernels.vmem_gather`:
-    a 1-D table the kernel takes (`_kernel_table`) and a 1-D int32
-    stream."""
+    a 1-D table the kernel takes (`_kernel_table`) and int32 indices,
+    a 1-D stream or a block the kernel reads as one."""
     return (
-        full.ndim == 1 and nbr.ndim == 1 and nbr.shape[0] > 0
+        full.ndim == 1 and nbr.ndim >= 1 and nbr.size > 0
         and nbr.dtype == jnp.int32
         and _kernel_table(full.dtype, full.size)
     )
@@ -180,11 +183,15 @@ def _kernel_gather(stats, kernel):
 def table_gather(full, nbr):
     """`full[nbr]` by the gather the call can see is the cheaper
     (`_kernel_gathers`), under the caller's scope: a pull's E-wide half
-    (`pull_gather`), and a V-wide table read by V-wide indices outside
-    any pull (models/wcc.py's pointer jumps).  GATHER_STATS counts
-    which one a call took."""
+    (`pull_gather`), a V-wide table read by V-wide indices outside
+    any pull (models/wcc.py's pointer jumps), and a shard's state read
+    by its send table (app/base.py's `exchange_mirrors`).  The kernel
+    reads a block of indices as the 1-D stream it is in memory and the
+    values get the block's shape back; XLA's gather is `full[nbr]` as
+    written.  GATHER_STATS counts which one a call took."""
     if _kernel_gathers(full, nbr):
-        return _kernel_gather(GATHER_STATS, vmem_gather)(full, nbr)
+        return _kernel_gather(GATHER_STATS, vmem_gather)(
+            full, nbr.reshape(-1)).reshape(nbr.shape)
     GATHER_STATS["xla"] += 1
     return full[nbr]
 

@@ -17,9 +17,12 @@ TPU formulation (static shapes, one collective):
   edge column is remapped into the COMPACT index space
   [vp local | g0 mirrors | g1 mirrors | ...] of length vp + fnum*M.
 
-  per round (inside shard_map): one gather x_local[send_idx] ->
-  [fnum, M], one `all_to_all`, one concat -> x_compact.  ICI bytes
-  drop from fnum*vp to fnum*M per device per round; state never
+  per round (inside shard_map): one gather of x_local by send_idx ->
+  [fnum, M] (`ops/segment.table_gather`: the VMEM gather kernel reads
+  the table as its [fnum*M] stream where the shard's state is one it
+  takes, XLA's x_local[send_idx] elsewhere; M is a whole number of
+  128s for that), one `all_to_all`, one concat -> x_compact.  ICI
+  bytes drop from fnum*vp to fnum*M per device per round; state never
   materialises at O(fnum*vp).
 """
 

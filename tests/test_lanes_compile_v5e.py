@@ -173,16 +173,16 @@ def _within(compiled, parent: int, room: int):
     assert code <= parent + room, (code, parent)
 
 
-def _described(w, frag, state, key_specs, one_chip):
+def _described(w, frag, state, key_specs, one_chip, devices=None):
     """`(dev, carried)`: the fragment and a host state as shapes on the
-    described chip, for `runner.lower`; the worker's mesh becomes that
-    chip's."""
+    described chip (or on `devices`, a fragment each), for
+    `runner.lower`; the worker's mesh becomes theirs."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding
 
     from libgrape_lite_tpu.parallel.comm_spec import FRAG_AXIS
 
-    mesh = Mesh(np.array([one_chip._device]), (FRAG_AXIS,))
+    mesh = Mesh(np.array(devices or [one_chip._device]), (FRAG_AXIS,))
     w.comm_spec.mesh = mesh
     specs, _ = key_specs(state)
 
@@ -382,6 +382,67 @@ def test_the_serving_bfs_lanes_hold_their_code(one_chip, kron18,
     assert "vmem_gather" in text and "vmem_row_gather" in text
     assert "tile_scan" not in text
     _within(compiled, PARENT_CODE["lanes.bfs"], CODE_ROOM)
+
+
+# ---- four fragments with a mirror plan: which gather packs the send
+# buffer ----
+
+
+@pytest.fixture(scope="module")
+def cut4():
+    """A random graph cut over four fragments (the chip's x32 state)."""
+    from tests.conftest import rand_frag
+
+    return rand_frag(4, n=20000, e=200000, seed=5, weighted=False)
+
+
+@pytest.mark.parametrize("armed", [True, False], ids=["armed", "unarmed"])
+def test_the_exchange_packs_by_the_gather_it_can_see(armed, topo, one_chip,
+                                                     cut4, monkeypatch):
+    """PageRank's fused runner on four fragments under the mirror
+    exchange, compiled for the described 2x2 as the chip compiles it:
+    steered as the TPU backend steers it, `grape.exchange.pack` holds a
+    `vmem_gather` instance (the shard's `[vp]` rank read by the
+    `[fnum * m]` send stream, 7.1 ns an index by XLA's gather, 0.8 by
+    the kernel: PERF.md section 6, PR 49) and no gather fusion as wide
+    as the stream; unsteered it is the fusion it was."""
+    from libgrape_lite_tpu.models import APP_REGISTRY
+    from libgrape_lite_tpu.ops.segment import GATHER_STATS
+    from libgrape_lite_tpu.worker.worker import Worker
+
+    monkeypatch.setenv("GRAPE_EXCHANGE", "mirror")
+    if armed:
+        _steer(monkeypatch)
+    with jax.enable_x64(False):
+        w = Worker(APP_REGISTRY["pagerank"](), cut4)
+        state = w.app.init_state(cut4, max_round=3)
+        plan, eph = w.app._mx, frozenset(w.app.ephemeral_keys)
+        assert plan is not None and eph == {"mx_send", "mx_nbr"}
+        dev, carried = _described(w, cut4, state, w._key_specs, one_chip,
+                                  devices=topo.devices)
+        gathers = GATHER_STATS.snapshot()
+        text = w._make_runner(w.app.max_rounds)(state).lower(
+            dev, {k: v for k, v in carried.items() if k not in eph},
+            {k: v for k, v in carried.items() if k in eph},
+        ).compile().as_text()
+    took = {k: v - gathers[k] for k, v in GATHER_STATS.snapshot().items()}
+    stream = plan.fnum * plan.m
+    packs = [line for line in text.splitlines()
+             if "grape.exchange.pack" in line]
+    fused = [line for line in packs if re.search(
+        rf"= f32\[{stream}\]\S* fusion\(.*kind=kCustom", line)]
+    kernels = [line for line in packs
+               if "tpu_custom_call" in line and "vmem_gather" in line]
+    assert " all-to-all(" in text
+    if armed:
+        # the pull's gather and the pack's
+        assert took == {"kernel": 2, "xla": 0}
+        assert len(kernels) == 1 and not fused, (kernels, fused)
+        assert f"f32[{stream // 128},128]" in kernels[0]
+    else:
+        assert took == {"kernel": 0, "xla": 2}
+        assert "tpu_custom_call" not in text
+        assert len(fused) == 1 and not kernels
 
 
 # ---- the row-end kernel alone, at the cells' shapes ----

@@ -11,13 +11,22 @@ from `grape/fragment/edgecut_fragment_base.h:569-602`); here that is
   fnum {2,4,8} against `dataset/p2p-31-*`,
 * mirror against all_gather, byte for byte, on a random multigraph:
   {pagerank, sssp, bfs, wcc} x fnum {2,4} (the exchange feeds the
-  same per-edge operands in the same order to the one fold).
+  same per-edge operands in the same order to the one fold),
+* the send buffer's pack, steered as the TPU backend steers it
+  (`pull_kernel`, tests/conftest.py): the same apps hand the gather
+  kernel their own `[vp]` state and the plan's `[fnum * m]` stream and
+  answer with the unarmed run's bytes; a 64-bit state keeps XLA's
+  gather.
 """
 
 import numpy as np
 import pytest
 
-from tests.conftest import dataset_path, rand_frag as _rand_frag
+from tests.conftest import (
+    dataset_path,
+    gather_took,
+    rand_frag as _rand_frag,
+)
 from tests.verifiers import (
     collect_worker_result as run_worker,
     eps_verify,
@@ -191,3 +200,147 @@ def test_mirror_byte_identical(monkeypatch, app_name, fnum):
     got = wk.result_values()
     assert got.dtype == ref.dtype
     assert got.tobytes() == ref.tobytes()
+
+
+# ---- the send buffer's pack, by the gather the call can see ----
+
+
+# what each app's exchanged state is on `_rand_frag`'s f32 weights
+_STATE_DTYPE = {"pagerank": "float32", "sssp": "float32",
+                "bfs": "int32", "wcc": "int32"}
+
+
+def _mirror_query(app_name, frag):
+    """`(app, answer)` of one query of `app_name` under the exchange."""
+    from libgrape_lite_tpu.models import APP_REGISTRY
+    from libgrape_lite_tpu.worker.worker import Worker
+
+    name, kwargs, _, _ = _IDENTITY_APPS[app_name]
+    app = APP_REGISTRY[name]()
+    wk = Worker(app, frag)
+    wk.query(**kwargs)
+    return app, wk.result_values()
+
+
+@pytest.mark.parametrize("fnum", [2, 4])
+@pytest.mark.parametrize("app_name", sorted(_IDENTITY_APPS))
+def test_pack_through_the_kernel(monkeypatch, pull_kernel, app_name, fnum):
+    """Armed as on the TPU backend, `exchange_mirrors` packs its send
+    buffer by the kernel: it is handed the shard's own `[vp]` state and
+    the send table as one `[fnum * m]` stream, `kernel` moved for it,
+    and the answer has the unarmed run's bytes."""
+    weighted, mx_attr = _IDENTITY_APPS[app_name][2:]
+    frag = _rand_frag(fnum, seed=110 + fnum, weighted=weighted)
+    monkeypatch.setenv("GRAPE_EXCHANGE", "mirror")
+    _, ref = _mirror_query(app_name, frag)
+
+    calls = pull_kernel("stand_in")
+    got = []
+    took = gather_took(lambda: got.append(_mirror_query(app_name, frag)))
+    app, values = got[0]
+    plan = getattr(app, mx_attr)
+    assert plan is not None, "mirror plan not engaged"
+    packs = [c for c in calls if c[1:] == ((frag.vp,), (fnum * plan.m,))]
+    # one pack a traced round; the pulls are the other calls, from the
+    # compact table
+    assert packs and {c[0] for c in packs} == {_STATE_DTYPE[app_name]}, calls
+    assert {c[1] for c in calls if c not in packs} == {(plan.n_compact,)}
+    assert took == {"kernel": len(calls), "xla": 0}
+    assert values.dtype == ref.dtype
+    assert values.tobytes() == ref.tobytes()
+
+
+def test_pack_by_the_interpreted_kernel(monkeypatch, pull_kernel):
+    """The kernel's own bits through the exchange: PageRank on two
+    fragments packs and pulls by `vmem_gather` itself, interpreted,
+    inside `shard_map(while_loop)`."""
+    frag = _rand_frag(2, n=300, e=2000, seed=112, weighted=False)
+    monkeypatch.setenv("GRAPE_EXCHANGE", "mirror")
+    _, ref = _mirror_query("pagerank", frag)
+    calls = pull_kernel("interpreted")
+    app, values = _mirror_query("pagerank", frag)
+    assert ("float32", (frag.vp,), (2 * app._mx.m,)) in calls
+    assert values.tobytes() == ref.tobytes()
+
+
+def _exchange(frag, plan, x):
+    """`exchange_mirrors` alone under the fragment's `shard_map`: the
+    compact tables `[fnum, vp + fnum * m]` of the state `x` `[fnum,
+    vp]`, or of each of its query lanes `[lanes, fnum, vp]` under
+    `jax.vmap` (the lanes share the send table)."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from libgrape_lite_tpu import compat
+    from libgrape_lite_tpu.app.base import StepContext
+    from libgrape_lite_tpu.parallel.comm_spec import FRAG_AXIS
+
+    lanes = x.ndim == 3
+
+    def shard(x, send):
+        def one(v):
+            return StepContext.exchange_mirrors(v, send[0])
+
+        return jax.vmap(one)(x[:, 0])[:, None] if lanes else one(x[0])[None]
+
+    spec = P(None, FRAG_AXIS) if lanes else P(FRAG_AXIS)
+    fn = compat.shard_map(
+        shard, mesh=frag.comm_spec.mesh, in_specs=(spec, P(FRAG_AXIS)),
+        out_specs=spec, check_vma=False)
+    return np.asarray(jax.jit(fn)(x, plan.send_idx))
+
+
+def _compact(plan, x, f):
+    """Receiver `f`'s compact table of the state `x` `[fnum, vp]`: its
+    own block, then what every sender's table names for it."""
+    return np.concatenate(
+        [x[f]] + [x[g][plan.send_idx[g, f]] for g in range(plan.fnum)])
+
+
+@pytest.mark.parametrize("dtype,took", [
+    ("float32", "kernel"), ("int32", "kernel"),
+    # what the kernel does not take keeps `x_local[send_idx]`
+    ("float64", "xla"), ("int64", "xla"),
+])
+def test_pack_chooses_by_the_state(pull_kernel, dtype, took):
+    """`exchange_mirrors` alone, armed: a 32-bit state is packed by the
+    kernel, a 64-bit one by XLA's gather, and each shard's compact
+    table is its own block, then what every sender's table names."""
+    from libgrape_lite_tpu.parallel.mirror import build_mirror_plan
+
+    fnum = 4
+    frag = _rand_frag(fnum, n=700, e=5000, seed=23)
+    plan = build_mirror_plan(frag, "ie")
+    rng = np.random.default_rng(3)
+    x = rng.integers(0, 1 << 20, (fnum, frag.vp)).astype(dtype)
+    calls = pull_kernel("stand_in")
+    out = []
+    moved = gather_took(lambda: out.append(_exchange(frag, plan, x)))
+    assert moved == {"kernel": 0, "xla": 0, took: 1}
+    assert calls == ([(dtype, (frag.vp,), (fnum * plan.m,))]
+                     if took == "kernel" else [])
+    assert out[0].dtype == x.dtype
+    for f in range(fnum):
+        np.testing.assert_array_equal(out[0][f], _compact(plan, x, f))
+
+
+def test_pack_of_query_lanes(pull_kernel):
+    """Query lanes under `jax.vmap` share the send table: a lane's pack
+    is its single call's, the kernel handed one lane's 1-D state, and
+    the send buffer gets its `[fnum, m]` shape back lane by lane."""
+    from libgrape_lite_tpu.parallel.mirror import build_mirror_plan
+
+    fnum, lanes = 2, 3
+    frag = _rand_frag(fnum, n=700, e=5000, seed=23)
+    plan = build_mirror_plan(frag, "ie")
+    x = np.random.default_rng(4).random(
+        (lanes, fnum, frag.vp)).astype(np.float32)
+    calls = pull_kernel("stand_in")
+    out = []
+    moved = gather_took(lambda: out.append(_exchange(frag, plan, x)))
+    assert moved == {"kernel": 1, "xla": 0}
+    assert set(calls) == {("float32", (frag.vp,), (fnum * plan.m,))}
+    for b in range(lanes):
+        for f in range(fnum):
+            np.testing.assert_array_equal(
+                out[0][b, f], _compact(plan, x[b], f))

@@ -141,6 +141,43 @@ def test_choice_off_tpu_is_xla(name, monkeypatch):
     assert text(lambda f, i, m: pull_gather(f, i, m, 0, add=1)) == text(parent)
 
 
+@pytest.mark.parametrize("block,want", [
+    ((4, 128), "kernel"), ((2, 3, 64), "kernel"),
+    ((), "xla"), ((4, 0), "xla"),
+])
+def test_choice_reads_an_index_block_as_its_stream(block, want, on_tpu):
+    """`table_gather` handed a block of indices (the mirror exchange's
+    `[fnum, m]` send table): the kernel reads it as the 1-D stream it
+    is in memory and the values get the block's shape back; a scalar
+    index and an empty block keep `full[nbr]`."""
+    from libgrape_lite_tpu.ops.segment import table_gather
+
+    rng = np.random.default_rng(9)
+    full = jnp.asarray(rng.standard_normal(1000).astype(np.float32))
+    nbr = jnp.asarray(rng.integers(0, 1000, block).astype(np.int32))
+    out = []
+    took = gather_took(lambda: out.append(table_gather(full, nbr)))
+    assert took == {"kernel": 0, "xla": 0, want: 1}
+    assert on_tpu == ([("float32", (1000,), (nbr.size,))]
+                      if want == "kernel" else [])
+    assert out[0].shape == block
+    assert np.asarray(out[0]).tobytes() == np.asarray(full[nbr]).tobytes()
+
+
+def test_an_index_block_off_tpu_is_the_parents_text():
+    """Off the TPU backend a block's gather is `full[nbr]` as written:
+    no reshape beside it."""
+    from libgrape_lite_tpu.ops.segment import table_gather
+
+    full = jax.ShapeDtypeStruct((4096,), jnp.float32)
+    nbr = jax.ShapeDtypeStruct((4, 256), jnp.int32)
+
+    def text(gather):
+        return jax.jit(lambda *a: gather(*a)).lower(full, nbr).as_text()
+
+    assert text(table_gather) == text(lambda f, i: f[i])
+
+
 def test_empty_stream_is_xla(on_tpu):
     full = jnp.arange(256, dtype=jnp.float32)
     took = gather_took(lambda: pull_gather(full, jnp.zeros((0,), jnp.int32)))

@@ -90,8 +90,12 @@ def test_app_through_the_kernel(app, fnum, exchange, pull_kernel,
     if exchange == "mirror":
         plan = getattr(got.app, mx_attr)
         assert plan is not None, "mirror plan not engaged"
-        # the table is the exchange's compact one, not fnum * vp wide
-        assert {c[1] for c in calls} == {(plan.n_compact,)}
+        # the pull's table is the exchange's compact one, not fnum *
+        # vp wide, and the exchange packed it by the kernel too: the
+        # shard's own state read by the plan's `[fnum * m]` stream
+        assert {c[1] for c in calls} == {(plan.n_compact,), (frag.vp,)}
+        assert {c[2] for c in calls if c[1] == (frag.vp,)} == {
+            (plan.fnum * plan.m,)}
     else:
         assert {c[1] for c in calls} == {(frag.fnum * frag.vp,)}
 
