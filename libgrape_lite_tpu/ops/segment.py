@@ -34,11 +34,11 @@ of a dense round), so for 32-bit values on the TPU backend
 stream that pass through VMEM (`_row_end_gather`; ROW_END_STATS counts
 which of the two a call took).  Everything else keeps the scatter:
 ids that are not sorted and streams without offsets (the dyn overlay,
-`exchange_base`, the 2-D tiles of `vc2d`, `bc`, `kcore`, 64-bit
-lanes), and since PR 46 the one V-wide scatter whose targets are data:
-`hook_min`, FastSV's hook onto the parents in the default `wcc`'s
-round on one fragment (its docstring has the reading of the scatter
-against a sort).  Query lanes under `jax.vmap` (the batched runners) fold as
+`exchange_base`, the 2-D tiles of `vc2d`, `kcore`, 64-bit lanes), and
+since PR 46 the one V-wide scatter whose targets are data: `hook_min`,
+FastSV's hook onto the parents in the default `wcc`'s round on one
+fragment (its docstring has the reading of the scatter against a
+sort).  Query lanes under `jax.vmap` (the batched runners) fold as
 their single queries do, one lane after another, wherever those
 queries' gather is the kernel below; where it is not, the lanes of an
 exact fold keep the scatter, into which XLA fuses their gather, and a

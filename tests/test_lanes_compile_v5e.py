@@ -384,6 +384,54 @@ def test_the_serving_bfs_lanes_hold_their_code(one_chip, kron18,
     _within(compiled, PARENT_CODE["lanes.bfs"], CODE_ROOM)
 
 
+# ---- BC's two sweeps on a Kronecker graph: a level is PageRank's pull ----
+
+
+def test_a_bc_level_is_the_gather_kernel_and_the_scan_fold(
+        one_chip, kron18, monkeypatch):
+    """The registry's `bc` on a float32 Kronecker fragment, compiled as
+    the chip compiles it: each of PEval's two loops holds one instance
+    of the gather kernel, of the tile scan and of the row ends' kernel
+    (the cell's streams are over the VMEM line at every scale of its
+    list, 15.5M entries and more; this graph's 8.4M are brought over it
+    by lowering the line), no scatter, and no XLA gather as wide as the
+    graph or as its vertices: the level's mask, the quotient and the
+    updates are V-wide element work."""
+    from libgrape_lite_tpu.models import APP_REGISTRY
+    from libgrape_lite_tpu.worker.worker import Worker
+
+    def choices():
+        return [s.snapshot() for s in (
+            segment.GATHER_STATS, segment.FOLD_STATS, segment.SCAN_STATS,
+            segment.ROW_END_STATS)]
+
+    _steer(monkeypatch)
+    monkeypatch.setattr(segment, "tile_scan_floor", lambda: 64 << 20)
+    with jax.enable_x64(False):
+        w = Worker(APP_REGISTRY["bc"](), kron18)
+        state = w.app.init_state(kron18, source=5)
+        assert {k: str(v.dtype) for k, v in state.items()} == {
+            "depth": "int32", "pn": "float32", "delta": "float32"}
+        dev, carried = _described(w, kron18, state, w._key_specs, one_chip)
+        before = choices()
+        compiled = w._make_runner(w.app.max_rounds)(state).lower(
+            dev, carried, {}).compile()
+    # one call site a loop, each the kernels' kind
+    assert choices() == [
+        {**before[0], "kernel": before[0]["kernel"] + 2},
+        {**before[1], "scan": before[1]["scan"] + 2},
+        {**before[2], "kernel": before[2]["kernel"] + 2},
+        {**before[3], "kernel": before[3]["kernel"] + 2}]
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 6 and " scatter(" not in text
+    assert all(k in text for k in ("vmem_gather", "tile_scan",
+                                   "vmem_row_gather"))
+    assert len(re.findall(r" while\(", text)) >= 2
+    wide = [line for line in text.splitlines()
+            if re.search(rf"\[({SERVE_EP}|{SERVE_V})\]\S* gather\(", line)]
+    assert not wide, wide[:2]
+
+
 # ---- four fragments with a mirror plan: which gather packs the send
 # buffer ----
 
