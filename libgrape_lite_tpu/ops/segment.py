@@ -618,11 +618,16 @@ def segment_top_label(count, label, segment_ids, num_rows: int,
 # WCC) needs only the rows that improved last round to propose again:
 # `frontier_spans`, `frontier_relax` and `frontier_rows` are that round
 # at static shapes, a list of at most B rows whose adjacency holds at
-# most C entries, for one fragment's unbatched state.  Nothing in them
-# is wider than C but the update of the V-wide values in place, and
+# most C entries, for one fragment's unbatched state.  A sum over the
+# in-edges of a table that is zero off a listed few rows (a level of
+# BC's sweeps, models/bc.py) is the same push with a sum for its fold:
+# `frontier_sum`, from the same list and spans, into a table of zeros
+# and with no next list.  Nothing in them is wider than C but the
+# update of the V-wide values in place (`frontier_sum`'s zeros), and
 # `frontier_rows`, which a loop runs where it turns from dense rounds to
 # these and where it refills the list from the state (models/bfs.py,
-# models/sssp.py, worker/worker.py `_frontier_loop`).
+# models/sssp.py, worker/worker.py `_frontier_loop`) and BC before
+# every push, since a level's rows are read off the depths.
 
 FRONTIER_SCOPE = "grape.frontier.compact"
 # the threshold's step and the refill that follows it (a loop whose
@@ -673,7 +678,8 @@ def _bits(x, dtype):
 def frontier_relax(values, front, lo, count, edge_nbr, entries: int,
                    add=1, absent=None, below=None):
     """One push round of a min relaxation from the rows `front` lists:
-    `(values', front', active)`.
+    `(values', front', active)`.  (`frontier_sum` is the push whose
+    fold is a sum.)
 
     `lo`, `count` are `frontier_spans`' and the entries they cover are
     at most `entries` (C; the caller's to see to).  The listed rows'
@@ -755,7 +761,67 @@ def frontier_relax(values, front, lo, count, edge_nbr, entries: int,
     return values, front, active
 
 
-def frontier_rows(mask, cap: int, scope: str = FRONTIER_SCOPE):
+def frontier_sum(values, front, lo, count, edge_nbr, entries: int):
+    """The push whose fold is a sum: `acc[v]`, the sum of `values[u]`
+    over the entries `u -> v` of the rows `front` lists, zero at a row
+    no listed row points at.  Where `values` is zero off the listed
+    rows this is the whole pull `segment_reduce(pull_gather(values,
+    ie.edge_nbr, ie.edge_mask, 0), ..., "sum")` over the mirrored CSR,
+    from the source side: an entry `u -> v` of the CSR `edge_nbr`
+    belongs to is the entry `v <- u` of its mirror, a multigraph's
+    repeated entry twice in both.
+
+    `front`, `lo`, `count` are `frontier_rows`' and `frontier_spans`'
+    and the entries they cover are at most `entries` (C; the caller's
+    to see to).  The entries are laid out in C slots as
+    `frontier_relax` lays them, a row's offset the counts' running sum;
+    a slot finds the place of the list it belongs to by comparing its
+    number with every running sum (a dense C x B reduction), then reads
+    the row's first entry and value (two gathers from B-wide tables)
+    and its neighbour `edge_nbr[entry]`, and the values are folded into
+    a table of zeros by one `.at[].add` of C updates, pads out of
+    bounds and dropped.  No sort, no dedupe and no next list: a level's
+    list comes from the depths again.  What differs from
+    `frontier_relax`'s layout is paid in time there and in code here: a
+    runner's code is HBM at the peak, BC's loops hold two copies of
+    this, and compiled for the v5e the openers' scatter with its
+    running maximum is 0.4 MB a copy more than the comparison, the one
+    gather of (entry, value) pairs 0.5 MB more than the two gathers
+    (PERF.md section 6, PR 51).
+
+    `edge_nbr` may be a shard's block `[1, Ep]` (see `_block_at`) and
+    holds row ids: one fragment.  The CSR's `edge_mask` is not read: it
+    is false on the padding behind the last row's entries alone
+    (graph/csr.py's contract, `CSR.validate`), which no row's span
+    covers; `pull_gather` reads it because its stream is every entry
+    and the padding with them.  (A fragment under a `dyn/` overlay
+    stages its edges beside the CSR: its apps keep the pull.)  A float
+    sum's order is the slots', not the scan's: the same bits where the
+    sum is exact in any order (BC's path counts), the last few
+    otherwise."""
+    rows, cap = values.shape[0], front.shape[0]
+    slot = jnp.arange(entries, dtype=jnp.int32)
+    with jax.named_scope(FRONTIER_SCOPE):
+        upto = jnp.cumsum(count)
+        # the first place whose running sum passes the slot's number: a
+        # place that lists no entry is passed over with the one before
+        owner = jnp.minimum(
+            jnp.searchsorted(upto, slot, side="right",
+                             method="compare_all").astype(jnp.int32),
+            cap - 1)
+        live = slot < upto[-1]
+    with jax.named_scope("grape.pull.gather"):
+        held = _at(values, jnp.minimum(front, rows - 1))
+        entry = jnp.where(live, _at(lo - (upto - count), owner) + slot, 0)
+        target = jnp.where(live, _block_at(edge_nbr, entry), rows)
+        pushed = _at(held, owner)
+    with jax.named_scope("grape.pull.fold"):
+        return jnp.zeros((rows,), held.dtype).at[target].add(
+            pushed, mode="drop")
+
+
+def frontier_rows(mask, cap: int, scope: str = FRONTIER_SCOPE,
+                  search: str = "scan"):
     """The first `cap` set rows of the V-wide `mask`, ascending, the
     list padded with the row count: what a loop needs where it turns
     from dense rounds to `frontier_relax`, and where it refills its
@@ -766,7 +832,16 @@ def frontier_rows(mask, cap: int, scope: str = FRONTIER_SCOPE):
     searched for each place of the list (`cap` binary searches of V /
     128 sums), the tile found is read whole (one gather of `cap` rows of
     the mask) and the place's row in it is where the tile's own running
-    count reaches what the tiles before it lack."""
+    count reaches what the tiles before it lack.
+
+    `search` is `jnp.searchsorted`'s method for that search.  `scan`,
+    the binary searches, is a loop of cap-wide element gathers, one a
+    halving: 14 of them at 8,192 places and 8,192 sums, 58 us each on
+    the v5e, 0.81 ms of a push's 1.6 in BC's loops.  `compare_all` is
+    one dense comparison of every place with every sum, cap x V / 128
+    pairs, 44 us for 8,192 x 8,192: the cheaper one where a caller
+    lists rows often, as BC does before every push (PERF.md section 6,
+    PR 51).  The loops that refill a list now and then keep `scan`."""
     rows = mask.shape[0]
     with jax.named_scope(scope):
         pad = -rows % SCAN_TILE
@@ -774,8 +849,9 @@ def frontier_rows(mask, cap: int, scope: str = FRONTIER_SCOPE):
             -1, SCAN_TILE).astype(jnp.int32)
         upto = jnp.cumsum(tiles.sum(axis=1))
         want = jnp.arange(1, cap + 1, dtype=jnp.int32)
-        tile = jnp.minimum(jnp.searchsorted(upto, want).astype(jnp.int32),
-                           upto.shape[0] - 1)
+        tile = jnp.minimum(
+            jnp.searchsorted(upto, want, method=search).astype(jnp.int32),
+            upto.shape[0] - 1)
         before = jnp.where(tile > 0, _at(upto, jnp.maximum(tile - 1, 0)), 0)
         # a tile's running count by one product with a triangle of ones
         # (exact: counts to 128 in f32); a `cumsum` along the lanes is

@@ -48,22 +48,28 @@ def drawn(kind):
     return n, src, dst, types.SimpleNamespace(minw=minw, mult=mult), roots
 
 
-@functools.cache
-def worker(kind, fnum, narrow):
+def narrow_bc():
+    """The registry's `bc` with the state the chip holds, under this
+    lane's x64."""
     from libgrape_lite_tpu.models import APP_REGISTRY
-    from libgrape_lite_tpu.worker.worker import Worker
 
     class Narrow(APP_REGISTRY["bc"]):
-        """The state the chip holds, under this lane's x64."""
-
         def init_state(self, frag, source=0):
             state = super().init_state(frag, source=source)
             return {k: v.astype(np.float32) if v.dtype == np.float64 else v
                     for k, v in state.items()}
 
+    return Narrow
+
+
+@functools.cache
+def worker(kind, fnum, narrow):
+    from libgrape_lite_tpu.models import APP_REGISTRY
+    from libgrape_lite_tpu.worker.worker import Worker
+
     n, src, dst, _, _ = drawn(kind)
     frag = build_fragment(src, dst, None, n, fnum)
-    return Worker((Narrow if narrow else APP_REGISTRY["bc"])(), frag), frag
+    return Worker((narrow_bc() if narrow else APP_REGISTRY["bc"])(), frag), frag
 
 
 CASES = [(kind, fnum, root) for kind in ("simple", "parallel")
@@ -98,9 +104,13 @@ def test_the_app_answers_as_the_reference(kind, fnum, root, narrow):
     want = {"edge": None, "isolated": 1, "small": 5}[root]
     assert want is None or len(np.concatenate(levels)) == want
     deep = len(levels) - 1
-    assert BC_STATS.snapshot() == {
+    stats = BC_STATS.snapshot()
+    assert stats["pulls"] + stats["pushes"] == 2 * deep + 1
+    # under the dense floor the program has no push arm
+    # (tests/test_bc_push.py brings the budgets down and counts both)
+    assert stats == {
         "levels": deep, "reached": int((depth >= 0).sum()),
-        "pulls": 2 * deep + 1}
+        "pulls": 2 * deep + 1, "pushes": 0}
     assert int(w.rounds) == 0  # both sweeps are loops inside PEval
     if root == "edge":
         assert deep >= 3

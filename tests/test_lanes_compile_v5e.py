@@ -162,7 +162,12 @@ def road20():
 # Where it does it takes the place of seven E-wide fusions, 0.9 MB of
 # code for 0.07.)
 PARENT_CODE = {"road.bfs": 6_772_224, "road.sssp": 7_943_168,
-               "lanes.bfs": 3_704_320, "lanes.sssp": 3_923_968}
+               "lanes.bfs": 3_704_320, "lanes.sssp": 3_923_968,
+               # PR 51's `bc` on the Kronecker scale-18 fragment, both
+               # arms (3,374,592 before the push arms: the two cost
+               # 2.82 MB here, 3.58 MB at `g500-bc.bc-key1`'s shapes,
+               # which has 5.42 MB of room)
+               "kron18.bc": 6_194_688}
 CODE_ROOM = 250_000
 
 
@@ -394,10 +399,15 @@ def test_a_bc_level_is_the_gather_kernel_and_the_scan_fold(
     of the gather kernel, of the tile scan and of the row ends' kernel
     (the cell's streams are over the VMEM line at every scale of its
     list, 15.5M entries and more; this graph's 8.4M are brought over it
-    by lowering the line), no scatter, and no XLA gather as wide as the
-    graph or as its vertices: the level's mask, the quotient and the
-    updates are V-wide element work."""
+    by lowering the line), and no XLA gather as wide as the graph or as
+    its vertices: the level's mask, the quotient and the updates are
+    V-wide element work.  Since PR 51 each loop's level is a
+    conditional between that pull and a push (`models/bc._level_sum`;
+    this graph is over the dense floor): one scatter a loop, the push's
+    C-wide add into a V-wide table of zeros, none as wide as the graph,
+    no sort, and the runner's code under a stated line."""
     from libgrape_lite_tpu.models import APP_REGISTRY
+    from libgrape_lite_tpu.models.bc import _PUSH_ENTRIES, _PUSH_ROWS
     from libgrape_lite_tpu.worker.worker import Worker
 
     def choices():
@@ -410,6 +420,7 @@ def test_a_bc_level_is_the_gather_kernel_and_the_scan_fold(
     with jax.enable_x64(False):
         w = Worker(APP_REGISTRY["bc"](), kron18)
         state = w.app.init_state(kron18, source=5)
+        assert w.app.push_budget == (_PUSH_ROWS, _PUSH_ENTRIES)
         assert {k: str(v.dtype) for k, v in state.items()} == {
             "depth": "int32", "pn": "float32", "delta": "float32"}
         dev, carried = _described(w, kron18, state, w._key_specs, one_chip)
@@ -423,13 +434,22 @@ def test_a_bc_level_is_the_gather_kernel_and_the_scan_fold(
         {**before[2], "kernel": before[2]["kernel"] + 2},
         {**before[3], "kernel": before[3]["kernel"] + 2}]
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 6 and " scatter(" not in text
+    assert text.count("tpu_custom_call") == 6
     assert all(k in text for k in ("vmem_gather", "tile_scan",
                                    "vmem_row_gather"))
     assert len(re.findall(r" while\(", text)) >= 2
+    assert len(re.findall(r" conditional\(", text)) == 2
+    assert " sort(" not in text
+    scatters = [line for line in text.splitlines() if " scatter(" in line]
+    assert len(scatters) == 2, scatters
+    for line in scatters:  # the add of C values into the V-wide zeros
+        assert re.search(rf"scatter-add\S* = f32\[{SERVE_V}\]", line), line
+    updates = re.findall(rf"f32\[{_PUSH_ENTRIES}\]", text)
+    assert updates and f"f32[{SERVE_EP}]" in text  # the pull's candidates
     wide = [line for line in text.splitlines()
             if re.search(rf"\[({SERVE_EP}|{SERVE_V})\]\S* gather\(", line)]
     assert not wide, wide[:2]
+    _within(compiled, PARENT_CODE["kron18.bc"], CODE_ROOM)
 
 
 # ---- four fragments with a mirror plan: which gather packs the send
