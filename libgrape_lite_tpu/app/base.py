@@ -459,3 +459,17 @@ class GatherScatterAppBase(AppBase):
     """Vertex-cut app (reference `gather_scatter_app_base.h:30-61`)."""
 
     message_strategy = MessageStrategy.kGatherScatter
+    # the tiles' device form the app's round reads
+    # (fragment/vertexcut.py: "coo" | "pull"); the runner loads it and
+    # `check_tiles` refuses a fragment built for the other family
+    tile_layout = "coo"
+
+    def check_tiles(self, frag) -> None:
+        if getattr(frag, "layout", "coo") != self.tile_layout:
+            raise ValueError(
+                f"{type(self).__name__} reads the vertex-cut tiles' "
+                f"{self.tile_layout!r} form and this fragment holds "
+                f"{frag.layout!r}: build it with "
+                f"layout={self.tile_layout!r} (fragment/vertexcut.py; "
+                "raw storage's default is 'pull', symmetrised "
+                "storage's 'coo')")

@@ -126,10 +126,10 @@ def fragment_bytes(frag) -> int:
     the fleet for bytes that are never placed."""
     tiles = getattr(frag, "_host_tiles", None)
     if tiles is not None:
-        s_arr, d_arr, w_arr, m_arr = tiles
-        total = s_arr.nbytes + d_arr.nbytes + m_arr.nbytes
-        if w_arr is not None:
-            total += w_arr.nbytes
+        # what is placed: the tiles' pull CSRs where that is the
+        # device form, else the COO tiles themselves
+        placed = getattr(frag, "_host_pull", None) or tiles
+        total = sum(a.nbytes for a in placed if a is not None)
         # per-device vertex planes: carry mask [k*vc] (bool) on the
         # row axis + oid plane (i64) + ivnum scalar per tile
         k, vc = frag.k, frag.vc

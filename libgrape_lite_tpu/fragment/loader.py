@@ -50,11 +50,21 @@ class LoadGraphSpec:
     # rebuild-on-mutate and the dyn/ repack path (deserialize-path
     # loads cannot retain it: the cache stores only the built shards)
     retain_edge_list: bool = False
+    # the reference's --vc (run_app_vc.h): `LoadGraph` hands the load
+    # to `LoadVertexcutGraph`, a k x k vertex cut (fnum = k^2) as an
+    # `ImmutableVertexcutFragment`
+    vertex_cut: bool = False
 
 
-def _cache_dir(efile: str, vfile: str, spec: LoadGraphSpec, fnum: int) -> str:
+def _cache_dir(efile: str, vfile: str, spec: LoadGraphSpec, fnum: int,
+               cut: dict | None = None) -> str:
+    """(directory, signature) of a load's serialization cache.  `cut`
+    holds what a vertex-cut load's fragment depends on besides (its
+    storage and device form) and sets it apart from the edge cut's;
+    an edge-cut load's signature is what it always was."""
     sig = json.dumps(
         {
+            **({} if cut is None else {"vertex_cut": cut}),
             "efile": os.path.abspath(efile),
             "vfile": os.path.abspath(vfile) if vfile else "",
             "esize": os.path.getsize(efile),
@@ -74,7 +84,8 @@ def _cache_dir(efile: str, vfile: str, spec: LoadGraphSpec, fnum: int) -> str:
             "rebalance": spec.rebalance,
             "string_id": spec.string_id,
             "rebalance_vertex_factor": spec.rebalance_vertex_factor,
-            "type": "ShardedEdgecutFragment",
+            "type": ("ShardedEdgecutFragment" if cut is None
+                     else "ImmutableVertexcutFragment"),
         },
         sort_keys=True,
     )
@@ -145,6 +156,27 @@ def _validate_load(frag: ShardedEdgecutFragment) -> ShardedEdgecutFragment:
     return frag
 
 
+def read_graph_files(efile: str, vfile: str | None, spec: LoadGraphSpec):
+    """(src, dst, w | None, oids) of a load's files: the `read_edges`
+    phase of both loaders (the native parser where it built)."""
+    src, dst, w = read_edge_file(
+        efile, weighted=spec.weighted, string_id=spec.string_id
+    )
+    if not spec.weighted:
+        w = None
+    if vfile:
+        oids = read_vertex_file(vfile, string_id=spec.string_id)
+    else:
+        # efile-only loading (basic_efile_fragment_loader.h):
+        # vertex universe = the set of edge endpoints.
+        # np.unique yields them in sorted oid order (NOT the
+        # reference's first-appearance order); lids therefore
+        # differ, but all output is oid-keyed so results are
+        # unaffected.
+        oids = np.unique(np.concatenate([src, dst]))
+    return src, dst, w, oids
+
+
 def LoadGraph(
     efile: str,
     vfile: str | None,
@@ -162,6 +194,8 @@ def LoadGraph(
     load delays."""
     from libgrape_lite_tpu import obs
 
+    if spec is not None and spec.vertex_cut:
+        return LoadVertexcutGraph(efile, vfile, comm_spec, spec)
     spec = _fold_rebalance_env(spec or LoadGraphSpec())
     tr = obs.tracer()
 
@@ -179,21 +213,7 @@ def LoadGraph(
             return _validate_load(frag)
 
         with tr.span("read_edges"):
-            src, dst, w = read_edge_file(
-                efile, weighted=spec.weighted, string_id=spec.string_id
-            )
-            if not spec.weighted:
-                w = None
-            if vfile:
-                oids = read_vertex_file(vfile, string_id=spec.string_id)
-            else:
-                # efile-only loading (basic_efile_fragment_loader.h):
-                # vertex universe = the set of edge endpoints.
-                # np.unique yields them in sorted oid order (NOT the
-                # reference's first-appearance order); lids therefore
-                # differ, but all output is oid-keyed so results are
-                # unaffected.
-                oids = np.unique(np.concatenate([src, dst]))
+            src, dst, w, oids = read_graph_files(efile, vfile, spec)
         lsp.set(edges=int(len(src)), vertices=int(len(oids)))
 
         with tr.span("partition", kind=spec.partitioner_type):
@@ -247,6 +267,98 @@ def LoadGraph(
             with tr.span("serialize", cache=cache):
                 _serialize_fragment(frag, cache, sig)
         return _validate_load(frag)
+
+
+def LoadVertexcutGraph(
+    efile: str,
+    vfile: str | None,
+    comm_spec: CommSpec,
+    spec: LoadGraphSpec | None = None,
+    *,
+    symmetrize: bool = False,
+    layout: str | None = None,
+    edges=None,
+):
+    """The vertex-cut load, mirroring `LoadVertexcutGraph<FRAG_T>`
+    (`loader.h:42-53`; `run_app --vc`): the files' edges cut into
+    k x k tiles (`fnum = k^2`, `VCPartitioner`) as an
+    `ImmutableVertexcutFragment`.  `LoadGraph` hands a spec with
+    `vertex_cut` here.
+
+    The same set-up phases as the edge-cut load: `load_graph` with
+    `read_edges` / `partition` (the cut: one stable sort of the tile
+    ids) / `build_fragment` (the tiles' device form) / `deserialize` /
+    `serialize` children and, under `build_fragment` or `deserialize`,
+    the placement `load.place`; the same serialization cache, its
+    signature set apart by the cut.
+
+    `symmetrize` stores both orientations of every edge and `layout`
+    names the device form (fragment/vertexcut.py: raw storage's
+    default is the pull's CSR, PageRankVC's; symmetrised storage's the
+    COO tiles the `*_vc` min-fold apps read).  `edges` is
+    `read_graph_files`' tuple where the caller has read the files
+    already (runner.py's partition probe)."""
+    from libgrape_lite_tpu import obs
+    from libgrape_lite_tpu.fragment.vertexcut import (
+        ImmutableVertexcutFragment,
+        default_layout,
+    )
+
+    spec = spec or LoadGraphSpec(vertex_cut=True)
+    if spec.string_id:
+        raise ValueError(
+            "string ids are not supported with vertex-cut storage (the "
+            "reference's VC fragment is specialized to uint64 oids, "
+            "immutable_vertexcut_fragment.h)"
+        )
+    layout = layout or default_layout(symmetrize)
+    fnum = comm_spec.fnum
+    tr = obs.tracer()
+
+    with tr.span("load_graph", efile=efile, fnum=fnum, cut="vertex") as lsp:
+        cache = None
+        if (spec.serialize or spec.deserialize) and spec.serialization_prefix:
+            cache, sig = _cache_dir(
+                efile, vfile or "", spec, fnum,
+                cut={"symmetrize": symmetrize, "layout": layout},
+            )
+
+        if spec.deserialize and cache and os.path.exists(
+            os.path.join(cache, "sig")
+        ):
+            with tr.span("deserialize", cache=cache):
+                frag = _deserialize_vertexcut(cache, comm_spec, spec)
+            lsp.set(path="deserialize")
+        else:
+            with tr.span("read_edges"):
+                src, dst, w, oids = (
+                    edges if edges is not None
+                    else read_graph_files(efile, vfile, spec)
+                )
+                if not spec.weighted:
+                    w = None
+            lsp.set(edges=int(len(src)), vertices=int(len(oids)))
+
+            with tr.span("partition", kind="vertex_cut"):
+                k, vc, chunk, tiles = ImmutableVertexcutFragment.cut_tiles(
+                    fnum, oids, src, dst, w, spec.edata_dtype, symmetrize
+                )
+
+            with tr.span("build_fragment"):
+                frag = ImmutableVertexcutFragment.from_tiles(
+                    comm_spec, oids, k, vc, chunk, tiles, len(src),
+                    directed=spec.directed, symmetrized=symmetrize,
+                    layout=layout,
+                )
+
+            if spec.serialize and cache:
+                with tr.span("serialize", cache=cache):
+                    _serialize_vertexcut(frag, cache, sig)
+        frag.load_spec = spec
+        # the tiles' fill profile, published with the load
+        # (VC_TILE_STATS; the apps attach it to their query spans)
+        frag.tile_stats()
+        return frag
 
 
 # ---- archive-backed cache format (utils/archive.py) ---------------------
@@ -712,4 +824,123 @@ def _deserialize_fragment(
     )
     return ShardedEdgecutFragment(
         comm_spec, vm, dev, host_oe, host_ie, directed, weighted
+    )
+
+
+# ---- the vertex cut's cache: one `frag.garc` of tiles ------------------
+
+_GAVC_MAGIC = 0x47415643  # "GAVC"
+
+
+def _serialize_vertexcut(frag, cache: str, sig: str):
+    """A vertex-cut fragment's tiles, real entries only (the padding is
+    the header's `ep`), and where the device form is the pull's its
+    CSRs' offsets and neighbours (row ids and masks follow from the
+    offsets), so that a deserialize sorts nothing."""
+    from libgrape_lite_tpu.utils.archive import InArchive
+
+    os.makedirs(cache, exist_ok=True)
+    s_arr, d_arr, w_arr, m_arr = frag._host_tiles
+    ar = InArchive()
+    ar.add_scalar(_GAVC_MAGIC)
+    ar.add_scalar(1)  # format version
+    pull = frag._host_pull
+    for v in (
+        frag.fnum, frag.k, frag.vc, frag.chunk, frag.total_enum,
+        int(frag.directed), int(w_arr is not None),
+        int(frag.symmetrized), int(pull is not None), s_arr.shape[1],
+        0 if pull is None else pull[1].shape[1],
+    ):
+        ar.add_scalar(int(v))
+    _put_array(ar, frag._oids)
+    for f in range(frag.fnum):
+        n = int(m_arr[f].sum())
+        _put_array(ar, s_arr[f, :n])
+        _put_array(ar, d_arr[f, :n])
+        if w_arr is not None:
+            _put_array(ar, w_arr[f, :n])
+        if pull is not None:
+            _put_array(ar, pull[0][f])
+            _put_array(ar, pull[2][f, :2 * n])
+    with open(os.path.join(cache, "frag.garc"), "wb") as fh:
+        fh.write(ar.get_buffer())
+    with open(os.path.join(cache, "sig"), "w") as f:
+        f.write(sig)
+
+
+def _deserialize_vertexcut(cache: str, comm_spec: CommSpec,
+                           spec: LoadGraphSpec):
+    from libgrape_lite_tpu.fragment.vertexcut import (
+        ImmutableVertexcutFragment,
+    )
+    from libgrape_lite_tpu.utils.archive import OutArchive
+
+    oa = OutArchive(_read_cache_file(os.path.join(cache, "frag.garc")))
+    if oa.get_scalar() != _GAVC_MAGIC:
+        raise ValueError("bad vertex-cut garc magic")
+    version = oa.get_scalar()
+    if version != 1:
+        raise ValueError(f"unsupported vertex-cut garc version {version}")
+    (fnum, k, vc, chunk, total_enum, directed, weighted, symmetrized,
+     has_pull, ep, width) = (oa.get_scalar() for _ in range(11))
+    if fnum != comm_spec.fnum:
+        raise ValueError(
+            f"serialized fnum={fnum} != requested {comm_spec.fnum}"
+        )
+    if spec.weighted and not weighted:
+        raise ValueError(
+            "serialized fragment has no edge weights but the load "
+            "asks for them (spec.weighted=True); re-serialize from a "
+            "weighted load"
+        )
+    if bool(directed) != bool(spec.directed):
+        raise ValueError(
+            f"serialized directed={bool(directed)} != requested "
+            f"{spec.directed}"
+        )
+    oids = _get_array(oa)
+    s_arr = np.zeros((fnum, ep), dtype=np.int32)
+    d_arr = np.zeros((fnum, ep), dtype=np.int32)
+    w_arr = None
+    m_arr = np.zeros((fnum, ep), dtype=bool)
+    pull = None
+    if has_pull:
+        rows = 2 * vc
+        pull = (
+            np.zeros((fnum, rows + 1), dtype=np.int32),
+            np.full((fnum, width), rows, dtype=np.int32),
+            np.zeros((fnum, width), dtype=np.int32),
+            np.zeros((fnum, width), dtype=bool),
+        )
+    for f in range(fnum):
+        src = _get_array(oa)
+        n = len(src)
+        if n > ep:
+            raise ValueError("corrupt tile in the vertex-cut garc")
+        s_arr[f, :n] = src
+        d_arr[f, :n] = _get_array(oa)
+        if weighted:
+            w = _get_array(oa)
+            if w_arr is None:
+                w_arr = np.zeros((fnum, ep), dtype=w.dtype)
+            w_arr[f, :n] = w
+        m_arr[f, :n] = True
+        if has_pull:
+            indptr = _get_array(oa)
+            nbr = _get_array(oa)
+            if (len(indptr) != rows + 1 or len(nbr) != 2 * n
+                    or 2 * n > width or int(indptr[-1]) != 2 * n):
+                raise ValueError("corrupt pull CSR in the vertex-cut garc")
+            pull[0][f] = indptr
+            pull[1][f, :2 * n] = np.repeat(
+                np.arange(rows, dtype=np.int32), np.diff(indptr))
+            pull[2][f, :2 * n] = nbr
+            pull[3][f, :2 * n] = True
+    if not oa.empty():  # not an assert: must survive `python -O`
+        raise ValueError("trailing bytes in the vertex-cut frag.garc")
+    return ImmutableVertexcutFragment.from_tiles(
+        comm_spec, oids, k, vc, chunk, (s_arr, d_arr, w_arr, m_arr),
+        total_enum, directed=bool(directed),
+        symmetrized=bool(symmetrized),
+        layout="pull" if has_pull else "coo", host_pull=pull,
     )

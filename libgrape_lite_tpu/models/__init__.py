@@ -29,7 +29,6 @@ from libgrape_lite_tpu.models.pagerank_local import PageRankLocal
 from libgrape_lite_tpu.models.kclique import KClique
 from libgrape_lite_tpu.models.pagerank_vc import (
     PageRankVC,
-    PageRankVCReplicated,
 )
 from libgrape_lite_tpu.models.vc2d import BFSVC2D, SSSPVC2D, WCCVC2D
 from libgrape_lite_tpu.models.lcc_directed import LCCDirected
@@ -109,10 +108,8 @@ APP_REGISTRY = {
     "core_decomposition": CoreDecomposition,
     "pagerank_local": PageRankLocal,
     "pagerank_local_parallel": PageRankLocal,
-    # pagerank_vc = SUMMA-sharded master state (O(N/k) per device);
-    # _rep keeps the mesh-replicated round-1 formulation for A/B
+    # pagerank_vc = SUMMA-sharded master state (O(N/k) per device)
     "pagerank_vc": PageRankVC,
-    "pagerank_vc_rep": PageRankVCReplicated,
     # 2-D vertex-cut min-fold apps (models/vc2d.py, ROADMAP item 2):
     # byte-identical to the 1-D pulls; selected by GRAPE_PARTITION
     # via fragment/partition.resolve_partition

@@ -7,7 +7,7 @@ Re-design of `examples/analytical_apps/pagerank/pagerank_vc.h` +
   * degree = # of appearances as src or dst (the stored edge list is
     the raw directed file; accumulation flows both directions,
     `pagerank_vc.h` IncEval),
-  * per-round: every fragment scatter-adds `curr[src] -> next[dst]` and
+  * per-round: every fragment adds `curr[src] -> next[dst]` and
     `curr[dst] -> next[src]` over its edge block, partial sums are
     gathered to masters (`GatherMasterVertices` with NumericSum),
   * master update `(base + d·sum)/deg` (final round: `d·sum + base`),
@@ -21,27 +21,36 @@ chunk j (column copy) — O(N/k) per device, realizing the 2-D
 partition's memory advantage
 (`immutable_vertexcut_fragment.h:82-148`).  Per round:
 
-  * scatter into dst: partials psum over `vcrow` → complete chunk-j
-    sums, column-sharded (the GatherToMaster segment-reduce);
-  * scatter into src: partials psum over `vccol` → row-sharded, then
-    ONE transpose `ppermute` ((i,j)→(j,i)) aligns them column-sharded;
-  * the master update runs on the column copy; a second transpose
+  * both directions are ONE pull over the tile's CSR
+    (fragment/vertexcut.py `VCPullFragment`): `pull_gather` reads the
+    table `[row copy; column copy]`, `segment_reduce(row_ptr=)` folds
+    2 vc rows by scan — rows 0..vc-1 the sums into the tile's
+    destinations, rows vc..2 vc-1 into its sources; no E-wide scatter
+    and no XLA element gather (`grape.pull.gather`, `grape.pull.fold`);
+  * `grape.vc.gather_master`: the destination sums psum over `vcrow`
+    → complete chunk-j sums, column-sharded; the source sums psum over
+    `vccol` → row-sharded (the GatherToMaster segment-reduce);
+  * `grape.vc.scatter`: ONE transpose `ppermute` ((i,j)→(j,i)) aligns
+    the row-sharded sums with the column copy, and after the master
+    update on the column copy (`grape.app.update`) a second one
     refreshes the row copy (ScatterToFragment).
 
-PageRankVCReplicated keeps the round-1 mesh-replicated formulation for
-A/B (`pagerank_vc_rep`).
+PEval takes the degrees from the CSR's offsets.  The state is 32-bit
+where x64 is off (the chip), as `PageRank`'s, so the gather is the
+VMEM kernel's.
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
-import jax.ops as jops
 import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from libgrape_lite_tpu.app.base import GatherScatterAppBase, StepContext
 from libgrape_lite_tpu.models.vc2d import vc_transpose as _transpose
+from libgrape_lite_tpu.ops.segment import pull_gather, segment_reduce
 from libgrape_lite_tpu.parallel.comm_spec import VC_COL_AXIS, VC_ROW_AXIS
 from libgrape_lite_tpu.utils.types import LoadStrategy, MessageStrategy
 
@@ -51,6 +60,7 @@ class PageRankVC(GatherScatterAppBase):
     message_strategy = MessageStrategy.kGatherScatter
     result_format = "float"
     mesh_kind = "vc2d"
+    tile_layout = "pull"
     replicated_keys = frozenset({"step", "dangling_sum", "total_dangling"})
 
     def __init__(self, delta: float = 0.85, max_round: int = 10):
@@ -60,8 +70,7 @@ class PageRankVC(GatherScatterAppBase):
     def custom_specs(self):
         return {
             "rank_col": P(VC_COL_AXIS), "rank_row": P(VC_ROW_AXIS),
-            "deg_col": P(VC_COL_AXIS), "deg_row": P(VC_ROW_AXIS),
-            "vmask_col": P(VC_COL_AXIS), "vmask_row": P(VC_ROW_AXIS),
+            "deg_col": P(VC_COL_AXIS), "vmask_col": P(VC_COL_AXIS),
         }
 
     def init_state(self, frag, delta: float | None = None,
@@ -70,6 +79,7 @@ class PageRankVC(GatherScatterAppBase):
             self.delta = delta
         if max_round is not None:
             self.max_round = max_round
+        self.check_tiles(frag)
         # partition fingerprint (r10): keys the runner cache apart
         # from any 1-D compile and feeds the obs query span's tile
         # record (trace_report's tile table)
@@ -78,55 +88,60 @@ class PageRankVC(GatherScatterAppBase):
         self._partition_stats = frag.tile_stats()
         n_pad = frag.dev.n_pad
         vmask = frag.vertex_mask()
+        # 32 bits where x64 is off, as PageRank's state: the kernels'
+        # kind of value; f64 under x64 (the CPU golden lanes)
+        dt = np.float64 if jax.config.jax_enable_x64 else np.float32
         return {
             # global [k*vc] leaves; placement shards them into [vc]
             # row/col chunk copies per device
-            "rank_col": np.zeros(n_pad, dtype=np.float64),
-            "rank_row": np.zeros(n_pad, dtype=np.float64),
+            "rank_col": np.zeros(n_pad, dtype=dt),
+            "rank_row": np.zeros(n_pad, dtype=dt),
             "deg_col": np.zeros(n_pad, dtype=np.int32),
-            "deg_row": np.zeros(n_pad, dtype=np.int32),
             "vmask_col": vmask,
-            "vmask_row": vmask,
             "step": np.int32(0),
-            "dangling_sum": np.float64(0),
-            "total_dangling": np.float64(0),
+            "dangling_sum": dt(0),
+            "total_dangling": dt(0),
         }
+
+    @staticmethod
+    def _gather_master(k, into_dst, into_src):
+        """A tile's partial sums `[vc]` by destination and by source ->
+        the complete sums of the device's column chunk."""
+        with jax.named_scope("grape.vc.gather_master"):
+            into_dst = lax.psum(into_dst, VC_ROW_AXIS)
+            into_src = lax.psum(into_src, VC_COL_AXIS)
+        with jax.named_scope("grape.vc.scatter"):
+            return into_dst + _transpose(into_src, k)
 
     def peval(self, ctx: StepContext, frag, state):
         k, vc = frag.k, frag.vc
         dt = state["rank_col"].dtype
         vmask_col = state["vmask_col"]
 
-        ones = jnp.where(frag.mask, 1, 0)
-        # degree: appearances as dst (column copy) + as src (row copy)
-        dd = lax.psum(
-            jops.segment_sum(ones, frag.dst % vc, num_segments=vc),
-            VC_ROW_AXIS,
-        )
-        ds = lax.psum(
-            jops.segment_sum(ones, frag.src % vc, num_segments=vc),
-            VC_COL_AXIS,
-        )
-        deg_col = (dd + _transpose(ds, k)).astype(jnp.int32)
-        deg_row = _transpose(deg_col, k)
+        # degree: appearances as dst (rows 0..vc-1 of the tile's CSR)
+        # + as src (rows vc..2 vc-1), read off the offsets
+        per_row = jnp.diff(frag.pull.indptr)
+        deg_col = self._gather_master(
+            k, per_row[:vc], per_row[vc:]).astype(jnp.int32)
 
-        # global vertex count: each column chunk counted once per row
-        n = lax.psum(vmask_col.sum(), VC_COL_AXIS).astype(dt)
-        p = jnp.asarray(1.0, dt) / n
-        dangling = jnp.logical_and(vmask_col, deg_col == 0)
-        total_dangling = lax.psum(dangling.sum(), VC_COL_AXIS).astype(dt)
-
-        rank_col = jnp.where(
-            vmask_col,
-            jnp.where(deg_col > 0, p / jnp.maximum(deg_col, 1).astype(dt), p),
-            jnp.asarray(0, dt),
-        )
+        with jax.named_scope("grape.app.update"):
+            p = jnp.asarray(1.0 / max(frag.total_vnum, 1), dt)
+            dangling = jnp.logical_and(vmask_col, deg_col == 0)
+            total_dangling = lax.psum(
+                dangling.sum(), VC_COL_AXIS).astype(dt)
+            rank_col = jnp.where(
+                vmask_col,
+                jnp.where(deg_col > 0,
+                          p / jnp.maximum(deg_col, 1).astype(dt), p),
+                jnp.asarray(0, dt),
+            )
+        with jax.named_scope("grape.vc.scatter"):
+            rank_row = _transpose(rank_col, k)
         state = dict(
             state,
             rank_col=rank_col,
-            rank_row=_transpose(rank_col, k),
+            rank_row=rank_row,
             deg_col=deg_col,
-            deg_row=deg_row,
             dangling_sum=p * total_dangling,
             total_dangling=total_dangling,
             step=jnp.int32(0),
@@ -136,44 +151,45 @@ class PageRankVC(GatherScatterAppBase):
     def inceval(self, ctx: StepContext, frag, state):
         k, vc = frag.k, frag.vc
         dt = state["rank_col"].dtype
-        vmask_col = state["vmask_col"]
-        deg_col = state["deg_col"]
-        n = lax.psum(vmask_col.sum(), VC_COL_AXIS).astype(dt)
-        d = self.delta
-
-        step = state["step"] + 1
-        base = jnp.asarray(1.0 - d, dt) / n + jnp.asarray(d, dt) * state["dangling_sum"] / n
-        dangling_sum = base * state["total_dangling"]
-
         zero = jnp.asarray(0, dt)
-        # src-side ranks flow to dst (column direction) and vice versa
-        c_src = jnp.where(frag.mask, state["rank_row"][frag.src % vc], zero)
-        c_dst = jnp.where(frag.mask, state["rank_col"][frag.dst % vc], zero)
-        into_dst = lax.psum(
-            jops.segment_sum(c_src, frag.dst % vc, num_segments=vc),
-            VC_ROW_AXIS,
-        )
-        into_src = lax.psum(
-            jops.segment_sum(c_dst, frag.src % vc, num_segments=vc),
-            VC_COL_AXIS,
-        )
-        gathered = into_dst + _transpose(into_src, k)
+        pull = frag.pull
 
-        is_last = step >= jnp.int32(self.max_round)
-        iter_val = jnp.where(
-            deg_col > 0,
-            (base + jnp.asarray(d, dt) * gathered)
-            / jnp.maximum(deg_col, 1).astype(dt),
-            base,
-        )
-        final_val = gathered * jnp.asarray(d, dt) + base
-        rank_col = jnp.where(
-            vmask_col, jnp.where(is_last, final_val, iter_val), zero
-        )
+        # both directions in one pull: src-side ranks (row copy) flow
+        # to the tile's destinations, dst-side ranks (column copy) to
+        # its sources
+        table = jnp.concatenate([state["rank_row"], state["rank_col"]])
+        contrib = pull_gather(table, pull.edge_nbr, pull.edge_mask, zero)
+        sums = segment_reduce(
+            contrib, pull.edge_src, 2 * vc, "sum", row_ptr=pull.indptr
+        ).astype(dt)
+        gathered = self._gather_master(k, sums[:vc], sums[vc:])
+
+        with jax.named_scope("grape.app.update"):
+            n = max(frag.total_vnum, 1)
+            d = self.delta
+            vmask_col = state["vmask_col"]
+            deg_col = state["deg_col"]
+            step = state["step"] + 1
+            base = (jnp.asarray((1.0 - d) / n, dt)
+                    + jnp.asarray(d / n, dt) * state["dangling_sum"])
+            dangling_sum = base * state["total_dangling"]
+            is_last = step >= jnp.int32(self.max_round)
+            iter_val = jnp.where(
+                deg_col > 0,
+                (base + jnp.asarray(d, dt) * gathered)
+                / jnp.maximum(deg_col, 1).astype(dt),
+                base,
+            )
+            final_val = gathered * jnp.asarray(d, dt) + base
+            rank_col = jnp.where(
+                vmask_col, jnp.where(is_last, final_val, iter_val), zero
+            )
+        with jax.named_scope("grape.vc.scatter"):
+            rank_row = _transpose(rank_col, k)
         state = dict(
             state,
             rank_col=rank_col,
-            rank_row=_transpose(rank_col, k),
+            rank_row=rank_row,
             step=step,
             dangling_sum=dangling_sum,
         )
@@ -183,123 +199,6 @@ class PageRankVC(GatherScatterAppBase):
         # compact the gpid-space rank into [fnum, vc] rows aligned with
         # inner_oids order (masters = diagonal fragments)
         rank = np.asarray(state["rank_col"]).reshape(frag.k, frag.vc)
-        out = np.zeros((frag.fnum, frag.vc), dtype=rank.dtype)
-        for c in range(frag.k):
-            oids = frag.inner_oids(c * frag.k + c)
-            offs = oids % frag.chunk
-            out[c * frag.k + c, : len(oids)] = rank[c, offs]
-        return out
-
-
-class PageRankVCReplicated(GatherScatterAppBase):
-    """Round-1 formulation: master state mesh-replicated ([n_pad] per
-    device), gather = one psum over the frag axis.  O(N) memory per
-    device — kept for A/B against the SUMMA-sharded default."""
-
-    load_strategy = LoadStrategy.kNullLoadStrategy
-    message_strategy = MessageStrategy.kGatherScatter
-    result_format = "float"
-
-    def __init__(self, delta: float = 0.85, max_round: int = 10):
-        self.delta = delta
-        self.max_round = max_round
-
-    @property
-    def replicated_keys(self):
-        return frozenset(
-            {"rank", "deg", "vmask", "step", "dangling_sum", "total_dangling"}
-        )
-
-    def init_state(self, frag, delta: float | None = None,
-                   max_round: int | None = None):
-        if delta is not None:
-            self.delta = delta
-        if max_round is not None:
-            self.max_round = max_round
-        n_pad = frag.dev.n_pad
-        return {
-            "rank": np.zeros(n_pad, dtype=np.float64),
-            "deg": np.zeros(n_pad, dtype=np.int64),
-            "vmask": frag.vertex_mask(),
-            "step": np.int32(0),
-            "dangling_sum": np.float64(0),
-            "total_dangling": np.float64(0),
-        }
-
-    def peval(self, ctx: StepContext, frag, state):
-        n_pad = frag.n_pad
-        dt = state["rank"].dtype
-        ones = jnp.where(frag.mask, 1, 0)
-        local_deg = jops.segment_sum(
-            ones, frag.dst, num_segments=n_pad
-        ) + jops.segment_sum(ones, frag.src, num_segments=n_pad)
-        # int32 is plenty for degree counts and avoids x64-dependent dtypes
-        deg = ctx.sum(local_deg).astype(jnp.int32)
-
-        vmask = state["vmask"]
-        n = vmask.sum().astype(dt)
-        p = jnp.asarray(1.0, dt) / n
-        dangling = jnp.logical_and(vmask, deg == 0)
-        rank = jnp.where(
-            vmask,
-            jnp.where(deg > 0, p / jnp.maximum(deg, 1).astype(dt), p),
-            jnp.asarray(0, dt),
-        )
-        # the dangling count is over masters globally; vmask is
-        # replicated so no psum is needed (communicator.h Sum is the
-        # MPI form of the same aggregate)
-        total_dangling = dangling.sum().astype(dt)
-        state = dict(
-            state,
-            rank=rank,
-            deg=deg,
-            dangling_sum=p * total_dangling,
-            total_dangling=total_dangling,
-            step=jnp.int32(0),
-        )
-        return state, jnp.int32(1 if self.max_round > 0 else 0)
-
-    def inceval(self, ctx: StepContext, frag, state):
-        n_pad = frag.n_pad
-        rank = state["rank"]
-        dt = rank.dtype
-        vmask = state["vmask"]
-        deg = state["deg"]
-        n = vmask.sum().astype(dt)
-        d = self.delta
-
-        step = state["step"] + 1
-        base = jnp.asarray(1.0 - d, dt) / n + jnp.asarray(d, dt) * state["dangling_sum"] / n
-        dangling_sum = base * state["total_dangling"]
-
-        zero = jnp.asarray(0, dt)
-        c_src = jnp.where(frag.mask, rank[frag.src], zero)
-        c_dst = jnp.where(frag.mask, rank[frag.dst], zero)
-        partial = jops.segment_sum(
-            c_src, frag.dst, num_segments=n_pad
-        ) + jops.segment_sum(c_dst, frag.src, num_segments=n_pad)
-        gathered = ctx.sum(partial)  # GatherMasterVertices<NumericSum>
-
-        is_last = step >= jnp.int32(self.max_round)
-        iter_val = jnp.where(
-            deg > 0,
-            (base + jnp.asarray(d, dt) * gathered)
-            / jnp.maximum(deg, 1).astype(dt),
-            base,
-        )
-        final_val = gathered * jnp.asarray(d, dt) + base
-        new_rank = jnp.where(
-            vmask, jnp.where(is_last, final_val, iter_val), zero
-        )
-        state = dict(
-            state, rank=new_rank, step=step, dangling_sum=dangling_sum
-        )
-        return state, jnp.where(is_last, jnp.int32(0), jnp.int32(1))
-
-    def finalize(self, frag, state):
-        # compact the replicated gpid-space rank into [fnum, vc] rows
-        # aligned with inner_oids order (masters = diagonal fragments)
-        rank = np.asarray(state["rank"]).reshape(frag.k, frag.vc)
         out = np.zeros((frag.fnum, frag.vc), dtype=rank.dtype)
         for c in range(frag.k):
             oids = frag.inner_oids(c * frag.k + c)

@@ -133,6 +133,7 @@ class VC2DMinAppBase(GatherScatterAppBase):
         that key the compiled-runner cache (a 1-D and a 2-D compile
         must never share an entry — `k` and the mode ride in trace_key
         as primitive attributes)."""
+        self.check_tiles(frag)
         state = {self.state_key: carry}
         eph_entries = {"vmask_row": frag.vertex_mask()}
         self._partition = "2d"

@@ -58,6 +58,25 @@ def put_global(x, sharding: NamedSharding):
     )
 
 
+def _by_coords(devices: list, k: int) -> list:
+    """`devices` row-major over the k x k block of chip coordinates
+    they fill (y down the rows, x along them), or as listed where they
+    carry no `coords`, share a chip, or fill no such block."""
+    at = {}
+    for d in devices:
+        c = getattr(d, "coords", None)
+        if c is None or len(c) < 2 or getattr(d, "core_on_chip", 0):
+            return list(devices)
+        at[(tuple(c[2:]), c[1], c[0])] = d
+    keys = sorted(at)
+    xs = sorted({key[2] for key in keys})
+    ys = sorted({key[1] for key in keys})
+    if (len(at) != len(devices) or len({key[0] for key in keys}) != 1
+            or len(xs) != k or len(ys) != k):
+        return list(devices)
+    return [at[key] for key in keys]
+
+
 class CommSpec:
     @classmethod
     def init_distributed(cls, coordinator_address: str | None = None,
@@ -168,21 +187,42 @@ class CommSpec:
         self.fnum = fnum
         self.devices = list(devices[:fnum])
         self.mesh = Mesh(np.array(self.devices), (FRAG_AXIS,))
+        self._mesh2d = None  # built on first use (`mesh2d`)
         self.worker_num = fnum
         self.worker_id = jax.process_index()
 
     def mesh2d(self) -> Mesh:
-        """k x k (row, col) mesh over the same devices in the same
-        order (fid = i*k + j) — the SUMMA view for vertex-cut apps
-        (reference `VCPartitioner`'s 2-D fragment grid,
+        """k x k (row, col) mesh over the same devices, fragment
+        fid = i*k + j on device (i, j) — the SUMMA view for vertex-cut
+        apps (reference `VCPartitioner`'s 2-D fragment grid,
         `partitioner.h:269-330`).  psum over one axis reduces a row or
-        column of fragments; a transpose is one `ppermute`."""
-        k = int(round(np.sqrt(self.fnum)))
-        if k * k != self.fnum:
-            raise ValueError(f"2-D mesh needs fnum = k^2, got {self.fnum}")
-        return Mesh(
-            np.array(self.devices).reshape(k, k), (VC_ROW_AXIS, VC_COL_AXIS)
-        )
+        column of fragments; a transpose is one `ppermute`.
+
+        Where the devices say where they sit (a TPU's `coords`) and
+        fill a k x k block of one host's chips, a mesh row is a row of
+        that block and a mesh column a column of it, so that both
+        axes' collectives run between ICI neighbours whatever order
+        `jax.devices()` lists them in; everywhere else (the CPU's
+        virtual devices, a block that is not k x k) the list's order
+        stands."""
+        if self._mesh2d is None:
+            k = int(round(np.sqrt(self.fnum)))
+            if k * k != self.fnum:
+                raise ValueError(
+                    f"2-D mesh needs fnum = k^2, got {self.fnum}")
+            self._mesh2d = Mesh(
+                np.array(_by_coords(self.devices, k)).reshape(k, k),
+                (VC_ROW_AXIS, VC_COL_AXIS),
+            )
+        return self._mesh2d
+
+    def sharded2d(self) -> NamedSharding:
+        """NamedSharding of a stacked `[fnum, ...]` array over
+        `mesh2d`: block fid = i*k + j on device (i, j) — where the
+        vertex-cut tiles are placed, so that the apps' `shard_map`
+        finds them at home."""
+        return NamedSharding(
+            self.mesh2d(), P((VC_ROW_AXIS, VC_COL_AXIS)))
 
     def frag_to_worker(self, fid: int) -> int:
         return fid  # identity, like the reference

@@ -7,6 +7,7 @@ writes per-fragment results via `GetResultFilename`).
 
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass, field
 from typing import Any, Dict
 
@@ -206,20 +207,16 @@ def run_app(args: QueryArgs, comm_spec: CommSpec | None = None) -> Worker:
                     eligible=False,
                     reason="delta-mutation load has no vertex-cut path",
                 )
-            elif args.serialize or args.deserialize or not args.efile:
-                # the garc serialization cache is an edge-cut artifact
-                # (loader.py writes/reads it inside LoadGraph, which
-                # the 2-D path bypasses) — and a deserialize run may
-                # carry no edge file at all; decline with the reason
-                # recorded rather than crash or silently skip the
-                # cache write
+            elif not args.efile:
+                # a deserialize run may carry no edge file at all, and
+                # the probe prices the cut from the edge list; decline
+                # with the reason recorded rather than crash
                 resolve_partition(
                     name, comm_spec.fnum, empty, empty, empty,
                     directed=args.directed, string_id=args.string_id,
                     eligible=False,
-                    reason="serialization cache flags (or no edge "
-                           "file): the vertex-cut fragment has no "
-                           "serialized form",
+                    reason="no edge file: the partition probe reads "
+                           "the edge list",
                 )
             elif precheck_partition(
                 name, comm_spec.fnum, directed=args.directed,
@@ -233,19 +230,13 @@ def run_app(args: QueryArgs, comm_spec: CommSpec | None = None) -> Worker:
                     directed=args.directed, string_id=args.string_id,
                 )
             else:
-                from libgrape_lite_tpu.io.line_parser import (
-                    read_edge_file,
-                    read_vertex_file,
+                from libgrape_lite_tpu.fragment.loader import (
+                    read_graph_files,
                 )
 
                 with timer.phase("partition probe"):
-                    p_src, p_dst, p_w = read_edge_file(
-                        args.efile, weighted=weighted
-                    )
-                    p_oids = (
-                        read_vertex_file(args.vfile)
-                        if args.vfile
-                        else np.unique(np.concatenate([p_src, p_dst]))
+                    p_src, p_dst, p_w, p_oids = read_graph_files(
+                        args.efile, args.vfile or None, spec
                     )
                     decision = resolve_partition(
                         name, comm_spec.fnum, p_src, p_dst, p_oids,
@@ -279,42 +270,25 @@ def run_app(args: QueryArgs, comm_spec: CommSpec | None = None) -> Worker:
         )
 
     with timer.phase("load graph"):
-        if vc2d_inputs is not None:
-            from libgrape_lite_tpu.fragment.vertexcut import (
-                ImmutableVertexcutFragment,
+        if vc2d_inputs is not None or is_vc:
+            from libgrape_lite_tpu.fragment.loader import (
+                LoadVertexcutGraph,
             )
 
-            src, dst, w, oids = vc2d_inputs
-            # min-fold pulls get symmetrised tiles (the 1-D loader's
+            # one builder for --vc and GRAPE_PARTITION=2d.  Min-fold
+            # pulls get symmetrised COO tiles (the 1-D loader's
             # undirected-CSR convention; WCC symmetrises even when
             # directed — weak connectivity IS the undirected
-            # traversal); pagerank_vc keeps raw storage and
-            # accumulates both directions in-app
-            sym = (
-                name == "wcc_vc"
-                or (name != "pagerank_vc" and not args.directed)
-            )
-            frag = ImmutableVertexcutFragment.build(
-                comm_spec, oids, src, dst, w if weighted else None,
-                directed=args.directed, symmetrize=sym,
-            )
-        elif is_vc:
-            from libgrape_lite_tpu.fragment.vertexcut import (
-                ImmutableVertexcutFragment,
-            )
-            from libgrape_lite_tpu.io.line_parser import (
-                read_edge_file,
-                read_vertex_file,
-            )
-
-            src, dst, w = read_edge_file(args.efile, weighted=weighted)
-            oids = (
-                read_vertex_file(args.vfile)
-                if args.vfile
-                else np.unique(np.concatenate([src, dst]))
-            )
-            frag = ImmutableVertexcutFragment.build(
-                comm_spec, oids, src, dst, w if weighted else None
+            # traversal) and keep COO tiles on raw directed storage;
+            # pagerank_vc keeps raw storage, accumulates both
+            # directions in-app and reads the tiles' pull CSRs
+            layout = type(app).tile_layout
+            sym = layout == "coo" and (
+                name == "wcc_vc" or not args.directed)
+            frag = LoadVertexcutGraph(
+                args.efile, args.vfile or None, comm_spec,
+                dataclasses.replace(spec, vertex_cut=True),
+                symmetrize=sym, layout=layout, edges=vc2d_inputs,
             )
         elif args.delta_efile or args.delta_vfile:
             from libgrape_lite_tpu.fragment.mutation import LoadGraphAndMutate

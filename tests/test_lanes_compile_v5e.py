@@ -623,3 +623,90 @@ def test_the_tile_scan_kernel_compiles_at_the_cells_shapes(name, topo,
         memory = compiled.memory_analysis()
         assert memory.temp_size_in_bytes < 1 << 20
         assert memory.generated_code_size_in_bytes < 100_000
+
+
+# ---- the vertex cut's round on the 2 x 2 mesh, at its cell's shapes ----
+
+VC_CHUNK, VC_WIDTH = 1 << 20, 16_793_600  # g500-s21-vc2x2: vc, the stream
+
+
+def test_the_vertex_cut_round_is_one_pull(topo, monkeypatch):
+    """`pagerank_vc`'s fused runner compiled for the described 2 x 2 as
+    the chip compiles it, at the shapes of `g500-s21-vc2x2.pagerank`
+    (grown from a small fragment's: nothing is placed): a round's two
+    directions are one `vmem_gather` over the 8 MiB table `[row copy;
+    column copy]` and one scan fold whose 16.8M-entry stream lies over
+    `tile_scan`'s floor, the row ends by their kernel; no scatter and
+    no XLA gather as wide as the stream; the two axis sums and the
+    transposes are the round's collectives."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+
+    from libgrape_lite_tpu.fragment.vertexcut import (
+        ImmutableVertexcutFragment,
+    )
+    from libgrape_lite_tpu.models import APP_REGISTRY
+    from libgrape_lite_tpu.ops.segment import (
+        FOLD_STATS, GATHER_STATS, ROW_END_STATS, SCAN_STATS,
+    )
+    from libgrape_lite_tpu.parallel.comm_spec import (
+        CommSpec, VC_COL_AXIS, VC_ROW_AXIS,
+    )
+    from libgrape_lite_tpu.worker.worker import Worker
+
+    _steer(monkeypatch)
+    rng = np.random.default_rng(3)
+    n, e = 4000, 30000
+    frag = ImmutableVertexcutFragment.build(
+        CommSpec(fnum=4), np.arange(n), rng.integers(0, n, e),
+        rng.integers(0, n, e))
+    small = frag.vc
+    counted = (GATHER_STATS, FOLD_STATS, SCAN_STATS, ROW_END_STATS)
+    with jax.enable_x64(False):
+        w = Worker(APP_REGISTRY["pagerank_vc"](), frag)
+        state = w.app.init_state(frag, max_round=10)
+        mesh = Mesh(np.array(topo.devices).reshape(2, 2),
+                    (VC_ROW_AXIS, VC_COL_AXIS))
+        w.comm_spec._mesh2d = mesh
+        specs, _ = w._key_specs(state)
+        _, frag_spec = w._mesh_layout()
+
+        def grown(x, spec):
+            # a chunk-wide axis becomes the cell's, the stream's too
+            x = np.asarray(x) if not hasattr(x, "dtype") else x
+            shape = tuple(
+                {2 * small + 1: 2 * VC_CHUNK + 1, 2 * small: 2 * VC_CHUNK,
+                 frag.dev.pull.edge_src.shape[1]: VC_WIDTH}.get(d, d)
+                for d in x.shape)
+            return jax.ShapeDtypeStruct(
+                shape, x.dtype, sharding=NamedSharding(mesh, spec))
+
+        dev = jax.tree_util.tree_map(lambda x: grown(x, frag_spec), frag.dev)
+        dev = type(dev)(pull=dev.pull, fnum=4, k=2, vc=VC_CHUNK,
+                        chunk=VC_CHUNK, total_vnum=2 * VC_CHUNK)
+        before = [s.snapshot() for s in counted]
+        compiled = w._make_runner(w.app.max_rounds)(state).lower(
+            dev, {k: grown(v, specs[k]) for k, v in state.items()}, {},
+        ).compile()
+    text = compiled.as_text()
+    took = [{k: v - b[k] for k, v in s.snapshot().items()}
+            for s, b in zip(counted, before)]
+    assert took == [{"kernel": 1, "xla": 0}, {"scan": 1, "scatter": 0},
+                    {"kernel": 1, "xla": 0}, {"kernel": 1, "xla": 0}], took
+    assert " scatter(" not in text
+    for kernel in ("vmem_gather", "tile_scan", "vmem_row_gather"):
+        assert kernel in text, kernel
+    wide = [line for line in text.splitlines()
+            if re.search(rf"\[{VC_WIDTH}\]\S* gather\(", line)]
+    assert not wide, wide[:2]
+    assert " all-reduce(" in text or " all-reduce-start(" in text
+    assert " collective-permute" in text
+    for scope in ("grape.vc.gather_master", "grape.vc.scatter",
+                  "grape.pull.gather", "grape.pull.fold",
+                  "grape.app.update"):
+        assert scope in text, scope
+    # what a chip holds beside the graph: 4.7 MB of code here (4.72 at
+    # PR 53), and a state of O(N / k)
+    mem = executable_bytes(compiled)
+    print(mem)
+    assert mem["code_bytes"] < 4_720_640 + CODE_ROOM, mem

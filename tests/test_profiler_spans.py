@@ -188,19 +188,47 @@ def test_armed_span_and_its_mirror_agree(tmp_path, graph_cache):
                    - (p[1] - q_prof[1]) / 1e3) < 1e3, name
 
 
+class _Bare:
+    """A context manager that does nothing: what `with ... as sp:
+    sp.mark(...)` costs by itself on this machine, now."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def mark(self, name):
+        pass
+
+
 def test_disarmed_span_stays_within_budget_with_the_mirror_in_place():
     """The existing 1 µs budget (tests/test_obs.py) holds for a span with
     a keyword argument and a mark: with no profiler session the disarmed
-    tracer asks the profiler and makes no annotation."""
+    tracer asks the profiler and makes no annotation.  The budget is held
+    over what a bare context manager costs in the same loop, the two
+    timed turn by turn and each by its best of twenty short turns: an
+    absolute wall-clock line failed on a machine whose other five
+    workers were compiling (the driver's run of PR 51's tree)."""
     tr = obs.tracer()
     assert not tr.enabled
     assert tr.span("superstep") is tr.span("worker.runner", lane=1)
-    n = 50_000
-    best = float("inf")
-    for _ in range(5):
-        t0 = time.perf_counter()
-        for _ in range(n):
-            with tr.span("worker.runner", lane=1) as sp:
-                sp.mark("dispatched")
-        best = min(best, (time.perf_counter() - t0) / n)
-    assert best < 1e-6, f"disarmed span costs {best * 1e9:.0f} ns > 1 µs"
+    bare = _Bare()
+    n = 10_000
+    best = {"span": float("inf"), "bare": float("inf")}
+    for _ in range(20):
+        for what in ("span", "bare"):
+            t0 = time.perf_counter()
+            if what == "span":
+                for _ in range(n):
+                    with tr.span("worker.runner", lane=1) as sp:
+                        sp.mark("dispatched")
+            else:
+                for _ in range(n):
+                    with bare as sp:
+                        sp.mark("dispatched")
+            best[what] = min(best[what], (time.perf_counter() - t0) / n)
+    over = best["span"] - best["bare"]
+    assert over < 1e-6, (
+        f"disarmed span costs {over * 1e9:.0f} ns > 1 µs over a bare "
+        f"context manager's {best['bare'] * 1e9:.0f} ns")
